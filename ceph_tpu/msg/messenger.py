@@ -331,9 +331,7 @@ class Connection:
             except asyncio.CancelledError:
                 # asyncio.streams can cancel the close waiter internally
                 # when the transport dies mid-close; only propagate when
-                # OUR task is actually being cancelled (being_cancelled
-                # degrades safely on 3.10, where Task.cancelling() does
-                # not exist — the old direct call raised AttributeError)
+                # OUR task is actually being cancelled
                 if being_cancelled():
                     raise
             except Exception:

@@ -1,14 +1,15 @@
 """ctypes loader for the native C++ runtime kernels (native/*.cc).
 
-Builds `libec_native.so` on first use with g++ (cached by source mtime) —
-the framework's analog of the reference's vendored SIMD libraries, but
-compiled from our own sources. Import `ec_native` for the GF(2^8) host codec
+Builds `libec_native.<digest>.so` on first use with g++ (named after its
+source's sha256) — the framework's analog of the reference's vendored SIMD
+libraries, but compiled from our own sources. Import `ec_native` for the GF(2^8) host codec
 and `crc32c` helpers; both raise NativeUnavailable cleanly if no compiler
 exists so pure-Python/JAX paths can fall back.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,7 +18,6 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 _REPO = os.path.dirname(os.path.dirname(_HERE))
 _SRC = os.path.join(_REPO, "native", "ec_native.cc")
 _BUILD_DIR = os.path.join(_REPO, "native", "_build")
-_SO = os.path.join(_BUILD_DIR, "libec_native.so")
 
 _lock = threading.Lock()
 _lib = None
@@ -28,17 +28,27 @@ class NativeUnavailable(RuntimeError):
 
 
 def _build() -> str:
+    """The library is named after the digest of its source, so one that
+    exists is current: a copied or unpacked tree does not keep mtimes
+    in order, and they cannot tell a stale `.so` from a fresh one."""
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _SO]
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(_BUILD_DIR, f"libec_native.{digest}.so")
+    if os.path.exists(so):
+        return so
+    # build beside the target and rename into place: worker processes
+    # that race the first import each install a complete library
+    tmp = f"{so}.{os.getpid()}"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
     except (OSError, subprocess.SubprocessError) as e:
         detail = getattr(e, "stderr", b"") or b""
         raise NativeUnavailable(
-            f"building {_SO} failed: {e} {detail.decode(errors='replace')}") from e
-    return _SO
+            f"building {so} failed: {e} {detail.decode(errors='replace')}") from e
+    os.replace(tmp, so)
+    return so
 
 
 def load() -> ctypes.CDLL:
@@ -57,7 +67,8 @@ def load() -> ctypes.CDLL:
                                           ctypes.c_size_t, ctypes.c_uint32,
                                           u32p]
             # msgr2 frame codec (present in rebuilt libraries; a stale
-            # .so predating it rebuilds via the source-mtime check above)
+            # .so predating it is never picked: the name carries the source
+            # digest)
             u64p = ctypes.POINTER(ctypes.c_uint64)
             if hasattr(lib, "frame_pack"):
                 lib.frame_pack.restype = ctypes.c_uint64

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ceph_tpu.ec import gf256
 
@@ -97,12 +96,12 @@ def sharded_encode_fn(mesh: Mesh, k: int, m: int, coding: np.ndarray | None = No
         csum = jax.lax.psum(csum, ("stripe", "shard"))
         return parity_local, csum
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P("shard", None), P("stripe", None, None)),
         out_specs=(P("stripe", "shard", None), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit

@@ -129,3 +129,7 @@ class AioDispatcher:
         while self._inflight:
             await asyncio.gather(*list(self._inflight),
                                  return_exceptions=True)
+            # gather over already-finished tasks completes without
+            # suspending; the discard callbacks that empty _inflight
+            # only run once the loop gets a turn
+            await asyncio.sleep(0)

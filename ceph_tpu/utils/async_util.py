@@ -2,16 +2,11 @@
 
 Every daemon in this stack ends the same way: cancel background tasks,
 await them, swallow the expected CancelledError. Hand-rolled versions of
-that dance keep re-growing the same two bugs radoslint's
-cancellation-swallow rule exists for:
-
-  * `except (asyncio.CancelledError, Exception): pass` swallows OUR OWN
-    cancellation too — a teardown coroutine that is itself cancelled
-    (test timeout, parent daemon dying) silently keeps running instead
-    of unwinding, which is exactly how half-dead daemons linger;
-  * `Task.cancelling()` is 3.11+; calling it on 3.10 raises
-    AttributeError from inside the except handler (seen latent in the
-    messenger's transport close path).
+that dance keep re-growing the bug radoslint's cancellation-swallow
+rule exists for: `except (asyncio.CancelledError, Exception): pass`
+swallows OUR OWN cancellation too — a teardown coroutine that is itself
+cancelled (test timeout, parent daemon dying) silently keeps running
+instead of unwinding, which is exactly how half-dead daemons linger.
 
 `reap()` centralizes the correct version: cancel the task, await it,
 swallow only the CancelledError that belongs to the reaped task, and
@@ -24,18 +19,9 @@ from typing import Iterable
 
 
 def being_cancelled() -> bool:
-    """True when the current task has a pending cancellation request.
-
-    Uses Task.cancelling() on 3.11+; on 3.10 there is no reliable
-    signal, so this degrades to False (matching the historical swallow
-    behavior instead of crashing on a missing attribute)."""
+    """True when the current task has a pending cancellation request."""
     task = asyncio.current_task()
-    if task is None:
-        return False
-    cancelling = getattr(task, "cancelling", None)
-    if cancelling is None:
-        return False
-    return bool(cancelling())
+    return task is not None and task.cancelling() > 0
 
 
 async def reap(task: asyncio.Task | None) -> None:
@@ -51,10 +37,9 @@ async def reap(task: asyncio.Task | None) -> None:
     try:
         # shield: a cancel aimed at US must not be delivered by
         # cancelling `task` (Task.cancel() cancels the awaited future —
-        # without the shield that IS `task`, which then finishes
-        # cancelled and makes our own cancellation indistinguishable
-        # from the reaped task's on 3.10, where being_cancelled() is
-        # blind). With the shield, `task.done()` is a reliable witness.
+        # without the shield that IS `task`). With the shield,
+        # `task.done()` is a reliable witness of whose cancellation
+        # this is.
         await asyncio.shield(task)
     except asyncio.CancelledError:
         # two sources: the reaped task finishing cancelled (swallow) or
@@ -113,7 +98,7 @@ async def drain(task: asyncio.Task | None) -> None:
         # shield, for two reasons: cancelling US must not collaterally
         # cancel the task we promised to await WITHOUT cancelling, and
         # (as in reap) it keeps `task.done()` a reliable witness of
-        # whose CancelledError this is on 3.10.
+        # whose CancelledError this is.
         await asyncio.shield(task)
     except asyncio.CancelledError:
         if being_cancelled() or not task.done():

@@ -65,8 +65,8 @@ _current: contextvars.ContextVar[tuple[int, int, int] | None] = \
 #: task -> NAME of the span it is currently inside. The loop profiler
 #: attributes sampled wall time to this ("which span kind was running
 #: when the loop stalled") by reading the loop's current task from its
-#: sampler thread — a contextvar can't serve that on 3.10 (no
-#: Task.get_context), so the span CM mirrors its name here. Weak keys:
+#: sampler thread, which looks the name up here (the span CM mirrors
+#: it) instead of reaching into another task's context. Weak keys:
 #: a finished task drops its entry with it. Mirrored ONLY while a
 #: sampler is armed (`set_task_naming`): three WeakKeyDictionary ops +
 #: current_task() per span is real money on the always-on tail path,

@@ -176,7 +176,7 @@ def test_generation_guard_catches_staging_reuse():
     from ceph_tpu.offload.service import _DeviceSlot, _DeviceState
     sanitizer.set_view_guards(True)
     try:
-        slot = _DeviceSlot(_DeviceState("device:0", None), depth=2)
+        slot = _DeviceSlot(_DeviceState("host", None), depth=2)
         page = slot.get_staging(4096)
         view = sanitizer.guard_view(memoryview(page), buf=page,
                                     label="staging")

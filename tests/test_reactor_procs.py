@@ -153,6 +153,36 @@ def test_proc_cluster_roundtrip_bit_identical_vs_single_loop():
     assert g1 == g2                 # and identical across runtimes
 
 
+def test_tpu_plugin_pool_on_workers_that_share_one_cpu_device(monkeypatch):
+    """`vstart --procs 2` and the hermetic failure_storm drill: no
+    XLA_FLAGS, so each worker sees ONE cpu device and worker 2's
+    partition slice of it is empty — it serves from that device all the
+    same (only a chip belongs to one process)."""
+    monkeypatch.delenv("XLA_FLAGS")         # workers inherit at spawn
+
+    async def body():
+        from ceph_tpu.tools.cluster_boot import ephemeral_cluster
+        payloads = {f"o{i}": bytes([i + 1]) * 65536 for i in range(6)}
+        async with ephemeral_cluster(
+                6, prefix="proc1dev-",
+                reactor_procs=2) as (client, osds, _mon):
+            await client.command({
+                "prefix": "osd erasure-code-profile set",
+                "name": "tpuprof",
+                "profile": {"plugin": "tpu", "k": "2", "m": "1"}})
+            await client.pool_create("onedev", pg_num=8,
+                                     pool_type="erasure",
+                                     erasure_code_profile="tpuprof")
+            io = client.ioctx("onedev")
+            # a worker that refused to serve would leave its primaries'
+            # writes to the op timeout, which raises here
+            await asyncio.gather(*[io.write_full(oid, data)
+                                   for oid, data in payloads.items()])
+            for oid, data in payloads.items():
+                assert await io.read(oid) == data
+    run(body())
+
+
 # ---------------------------------------------------------------------------
 # SIGKILL drill: crash verb -> supervisor reap -> mark-down -> respawn
 # ---------------------------------------------------------------------------
