@@ -26,7 +26,6 @@ construction-level compatible, not verified against jerasure binaries.
 """
 from __future__ import annotations
 
-import time
 from typing import Iterable, Mapping
 
 import jax
@@ -59,37 +58,6 @@ def _device_of(arr) -> str:
         return f"{d.platform}:{d.id}"
     except Exception:
         return "unknown"
-
-
-def _profiled_roundtrip(kernel, host_batch, timings: list) -> np.ndarray:
-    """One serialized H2D -> kernel -> D2H round trip, accumulating the
-    three stage durations into `timings` ([h2d_s, kernel_s, d2h_s]).
-    Attribution-mode only (tracer.set_profile_dispatch): the explicit
-    block_until_ready per stage forfeits the transfer/compute overlap
-    to make the splits real."""
-    t0 = time.perf_counter()
-    dev = jax.block_until_ready(jnp.asarray(host_batch))
-    t1 = time.perf_counter()
-    res = jax.block_until_ready(kernel(dev))
-    t2 = time.perf_counter()
-    out = np.asarray(res)
-    t3 = time.perf_counter()
-    timings[0] += t1 - t0
-    timings[1] += t2 - t1
-    timings[2] += t3 - t2
-    return out
-
-
-def _record_roundtrip(timings: list, in_bytes: int, out_bytes: int,
-                      sp) -> None:
-    """Feed accumulated round-trip timings to the copy ledger and the
-    dispatch span (the attribution waterfall's h2d/kernel/d2h buckets)."""
-    h2d_s, kernel_s, d2h_s = timings
-    copytrack.copied("h2d", in_bytes, h2d_s)
-    copytrack.copied("d2h", out_bytes, d2h_s)
-    sp.set_tag("h2d_us", round(h2d_s * 1e6, 1))
-    sp.set_tag("kernel_us", round(kernel_s * 1e6, 1))
-    sp.set_tag("d2h_us", round(d2h_s * 1e6, 1))
 
 
 class ErasureCodeTpu(ErasureCodeJerasure):
@@ -143,19 +111,12 @@ class ErasureCodeTpu(ErasureCodeJerasure):
             if device_resident:
                 return self._encoder.apply_batch_device(data)
             return self._encode_host_pipelined(
-                np.ascontiguousarray(data, dtype=np.uint8), sp=sp)
+                np.ascontiguousarray(data, dtype=np.uint8))
 
-    def _encode_host_pipelined(self, data: np.ndarray,
-                               sp=None) -> np.ndarray:
+    def _encode_host_pipelined(self, data: np.ndarray) -> np.ndarray:
         b = data.shape[0]
         depth = min(self.pipeline_depth, b)
         splits = np.array_split(np.arange(b), depth)
-        if sp is not None and tracer.profile_dispatch():
-            # attribution mode (tracer.set_profile_dispatch): serialize
-            # each pipeline stage so the span carries REAL h2d/kernel/
-            # d2h splits — costs the transfer/compute overlap, so it
-            # never rides plain tracer_enabled
-            return self._encode_host_profiled(data, splits, sp)
         # enqueue all transfers+dispatches first (async), then collect —
         # XLA/PJRT overlaps H2D of later sub-batches with earlier compute
         outs = []
@@ -167,20 +128,6 @@ class ErasureCodeTpu(ErasureCodeJerasure):
         out = np.concatenate([np.asarray(o) for o in outs], axis=0)
         copytrack.copied("h2d", int(data.nbytes))
         copytrack.copied("d2h", int(out.nbytes))
-        return out
-
-    def _encode_host_profiled(self, data: np.ndarray, splits,
-                              sp) -> np.ndarray:
-        outs = []
-        timings = [0.0, 0.0, 0.0]
-        for idx in splits:
-            if len(idx) == 0:
-                continue
-            outs.append(_profiled_roundtrip(
-                self._encoder.apply_batch_device,
-                data[idx[0]: idx[-1] + 1], timings))
-        out = np.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
-        _record_roundtrip(timings, int(data.nbytes), int(out.nbytes), sp)
         return out
 
     def decode_stripes(self, avail_ids: tuple[int, ...], want_ids: tuple[int, ...],
@@ -202,13 +149,6 @@ class ErasureCodeTpu(ErasureCodeJerasure):
             if device_resident:
                 return codec.apply_batch_device(chunks)
             chunks = np.ascontiguousarray(chunks, dtype=np.uint8)
-            if sp is not None and tracer.profile_dispatch():
-                timings = [0.0, 0.0, 0.0]
-                out = _profiled_roundtrip(codec.apply_batch_device,
-                                          chunks, timings)
-                _record_roundtrip(timings, int(chunks.nbytes),
-                                  int(out.nbytes), sp)
-                return out
             dev = jnp.asarray(chunks)
             out = np.asarray(codec.apply_batch_device(dev))
             copytrack.copied("h2d", int(chunks.nbytes))

@@ -79,6 +79,11 @@ from ceph_tpu.utils.perf_counters import (TYPE_GAUGE, TYPE_HISTOGRAM,
                                           PerfCountersCollection)
 from ceph_tpu.utils.throttle import Throttle
 
+#: the hops of one staged dispatch that `_device_call` stamps on the
+#: `offload_batch` span, in order; `scatter_us` closes the span after them
+_HOPS = ("pool_wait_us", "h2d_submit_us", "launch_us", "result_wait_us",
+         "resume_us")
+
 # -- module-wide defaults (mirrored by the ec_offload_* config options) ------
 
 _DEFAULTS: dict[str, Any] = {
@@ -1097,6 +1102,7 @@ class OffloadService:
             # the semaphore wait is INSIDE the try: a cancel delivered
             # while queued behind full staging slots must still cancel
             # the job futures, or their submitters hang forever
+            t_sem = time.perf_counter()
             async with slot.sem:
                 self.perf.inc("inflight_batches")
                 try:
@@ -1109,8 +1115,6 @@ class OffloadService:
                             j.span.finish()
                     stacked, staging, stack_s = self._stack(slot, jobs)
                     nbytes = int(stacked.nbytes)
-                    stack_us = round(stack_s * 1e6, 1) if staging \
-                        is not None else 0.0
                     with tracer.span("offload_batch") as sp:
                         if sp is not None:
                             # span links (tracing v2): the coalesced
@@ -1121,6 +1125,14 @@ class OffloadService:
                                 if j.span is not None and \
                                         j.span.trace_id != sp.trace_id:
                                     sp.add_link(j.span.context())
+                            # the two hops before the span opens: the
+                            # slot semaphore (inside the riders'
+                            # offload_queue_wait, after the linger) and
+                            # the stacking copy
+                            sp.set_tag("sem_wait_us",
+                                       round((now - t_sem) * 1e6, 1))
+                            sp.set_tag("stack_us",
+                                       round(stack_s * 1e6, 1))
                         out, on_device = await self._dispatch(
                             bucket, slot, stacked, len(jobs), sp,
                             token)
@@ -1131,13 +1143,19 @@ class OffloadService:
                             sp.set_tag("copy_bytes",
                                        nbytes if staging is not None
                                        else 0)
-                            sp.set_tag("copy_us", stack_us)
+                        row = 0
+                        for j in jobs:
+                            if not j.fut.done():
+                                j.fut.set_result(out[row:row + j.rows])
+                            row += j.rows
+                        if sp is not None and "resume_us" in sp.tags:
+                            # the last of the six hops inside the span
+                            # (`_device_call` stamps the other five):
+                            # the riders' futures resolved
+                            sp.set_tag("scatter_us", round(
+                                (time.perf_counter() - sp.t0) * 1e6
+                                - sum(sp.tags[h] for h in _HOPS), 1))
                     self._note_batch(len(jobs), nbytes)
-                    row = 0
-                    for j in jobs:
-                        if not j.fut.done():
-                            j.fut.set_result(out[row:row + j.rows])
-                        row += j.rows
                 except asyncio.CancelledError:
                     raise
                 except Exception as e:
@@ -1179,37 +1197,36 @@ class OffloadService:
         (from the reused staging buffer — the steady-state link rate),
         the bucket kernel on the committed device array, D2H of the
         result. The ledger gets the h2d/d2h byte flow the plugin can no
-        longer see (it receives a device-resident array). Under
-        tracer.set_profile_dispatch each leg is serialized so the batch
-        span carries real h2d/kernel/d2h splits (attribution mode only —
-        it forfeits the transfer/compute overlap)."""
+        longer see (it receives a device-resident array). The batch
+        span gets the hand-offs (`_HOPS`), from timestamps taken where
+        the work happens and without serializing anything:
+        `pool_wait_us` (the span opens -> `run` starts on the
+        staging-pool thread), `h2d_submit_us` (device_put returns),
+        `launch_us` (`fn(dev)` returns), `result_wait_us` (np.asarray
+        returns: kernel and D2H), `resume_us` (`run` returns -> this
+        coroutine runs again on the loop)."""
         import jax
         nbytes = int(stacked.nbytes)
-        profile = sp is not None and tracer.profile_dispatch()
+        t = [sp.t0 if sp is not None else 0.0]
 
         def run(batch: np.ndarray) -> np.ndarray:
-            if profile:
-                t0 = time.perf_counter()
-                dev = jax.block_until_ready(jax.device_put(batch,
-                                                           slot.jdev))
-                t1 = time.perf_counter()
-                res = jax.block_until_ready(fn(dev))
-                t2 = time.perf_counter()
-                out = np.asarray(res)
-                t3 = time.perf_counter()
-                copytrack.copied("h2d", nbytes, t1 - t0)
-                copytrack.copied("d2h", int(out.nbytes), t3 - t2)
-                sp.set_tag("h2d_us", round((t1 - t0) * 1e6, 1))
-                sp.set_tag("kernel_us", round((t2 - t1) * 1e6, 1))
-                sp.set_tag("d2h_us", round((t3 - t2) * 1e6, 1))
-                return out
+            t.append(time.perf_counter())
             dev = jax.device_put(batch, slot.jdev)
-            out = np.asarray(fn(dev))
+            t.append(time.perf_counter())
+            res = fn(dev)
+            t.append(time.perf_counter())
+            out = np.asarray(res)
+            t.append(time.perf_counter())
             copytrack.copied("h2d", nbytes)
             copytrack.copied("d2h", int(out.nbytes))
             return out
 
-        return await self._in_staging_pool(run, stacked)
+        out = await self._in_staging_pool(run, stacked)
+        if sp is not None:
+            t.append(time.perf_counter())
+            for name, a, b in zip(_HOPS, t, t[1:]):
+                sp.set_tag(name, round((b - a) * 1e6, 1))
+        return out
 
     def _mesh_apply(self, cache_key: tuple, M: np.ndarray,
                     batch: np.ndarray) -> np.ndarray:

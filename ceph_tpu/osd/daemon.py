@@ -228,9 +228,9 @@ class OSD(Dispatcher):
         # task spawn-site tracking): `config set sanitizer_enabled
         # true` arms the running loop live
         sanitizer.register_config(self.config)
-        # event-loop sampling profiler (`profile dump` over the admin
-        # socket): loop-busy-fraction + top stall sites, hot-togglable
-        # via `config set profiler_enabled true`
+        # the loop account (`profile dump` over the admin socket): loop
+        # time by layer, lag and pauses, hot-togglable via `config set
+        # profiler_enabled true` (full tracing arms it too)
         loopprof.register_config(self.config)
         # deterministic fault injection (fault_inject_*): `config set
         # fault_inject_enabled true` over the admin socket arms the

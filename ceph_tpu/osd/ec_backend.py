@@ -762,9 +762,9 @@ class ECBackend(PGBackend):
                     fut.cancel()
             return futs
 
+        topped_up = False
         try:
             pending = await send_round(rounds[0])
-            topped_up = False
             half = deadline - READ_TIMEOUT / 2
             # early exit at k decodable chunks: one slow-but-up shard must
             # not stall every read for the full timeout
@@ -843,7 +843,9 @@ class ECBackend(PGBackend):
         any_shard = next(iter(shards.values()))
         return got, any_shard[1], {"version": ver,
                                    "rolled_back": rolled_back,
-                                   "uattrs": uattrs_by.get(ver, {})}
+                                   "uattrs": uattrs_by.get(ver, {}),
+                                   "asked": len(waits),
+                                   "rounds": 1 + topped_up}
 
     async def _gather_prev_pass(self, oid: str, exclude_osds: frozenset,
                                 chunk_off: int, chunk_len: int,
@@ -907,14 +909,19 @@ class ECBackend(PGBackend):
         else:
             last = -(-(offset + length) // w)
             chunk_off, chunk_len = first * c, (last - first) * c
-        got, ec_size, _ = await self._gather_chunks(
-            oid, chunk_off=chunk_off, chunk_len=chunk_len, snap=snap)
-        data = await ec_util.decode_concat_async(
-            self.sinfo, self.ec_impl, got, service=self._offload_svc())
-        start = offset - first * w
-        end = (ec_size if length <= 0 else min(offset + length, ec_size)) \
-            - first * w
-        return data[start:max(start, end)]
+        with tracer.span("ec_read", f"osd.{self.host.whoami}") as sp:
+            got, ec_size, meta = await self._gather_chunks(
+                oid, chunk_off=chunk_off, chunk_len=chunk_len, snap=snap)
+            data = await ec_util.decode_concat_async(
+                self.sinfo, self.ec_impl, got, service=self._offload_svc())
+            start = offset - first * w
+            end = (ec_size if length <= 0
+                   else min(offset + length, ec_size)) - first * w
+            if sp is not None:
+                sp.set_tag("bytes", max(0, end - start))
+                sp.set_tag("shards_asked", meta["asked"])
+                sp.set_tag("rounds", meta["rounds"])
+            return data[start:max(start, end)]
 
     async def gather_snapset(self, oid: str, authoritative: bool = False):
         """The object's SnapSet. Default (read path): local snapdir

@@ -263,7 +263,15 @@ async def encode_async(sinfo: StripeInfo, ec_impl,
             sp.set_tag("batched", True)
             sp.set_tag("offload", True)
         parity = np.asarray(await service.encode(ec_impl, stripes))
-        return _encode_assemble(stripes, parity, k, want, sp=sp)
+        t0 = time.perf_counter()
+        out = _encode_assemble(stripes, parity, k, want, sp=sp)
+        if sp is not None:
+            # what follows the rider's future on the loop: with
+            # offload_queue_wait, the batch's stack_us and offload_batch
+            # it makes up this span but for the rider's wait for its turn
+            sp.set_tag("assemble_us",
+                       round((time.perf_counter() - t0) * 1e6, 1))
+        return out
 
 
 def _reconstruct_stack(ec_impl, stacked: Mapping[int, np.ndarray],

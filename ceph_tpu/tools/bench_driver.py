@@ -2396,17 +2396,16 @@ def attribution_from_spans(spans: list[dict]) -> dict:
             elif name == "offload_queue_wait":
                 buckets["queue_wait"] += s["duration_us"]
             elif name in ("ec_encode", "ec_decode", "offload_batch"):
-                buckets["copy"] += float(tags.get("copy_us") or 0.0)
-            # offload_batch carries the h2d/kernel/d2h splits when the
-            # service staged the dispatch itself (mesh fan-out hands the
-            # plugin a device-resident array, so the plugin spans no
-            # longer see the transfers); plugin device-mode spans carry
-            # no timing tags, so the two sources never double-count
-            if name in ("tpu_encode_dispatch", "tpu_decode_dispatch",
-                        "offload_batch"):
-                buckets["h2d"] += float(tags.get("h2d_us") or 0.0)
-                buckets["kernel"] += float(tags.get("kernel_us") or 0.0)
-                buckets["d2h"] += float(tags.get("d2h_us") or 0.0)
+                buckets["copy"] += float(tags.get("copy_us") or 0.0) \
+                    + float(tags.get("stack_us") or 0.0)
+            # offload_batch carries the hops of the staged dispatch
+            # (the service stamps them without serializing anything):
+            # device_put returning, the kernel call returning, and the
+            # result arriving, which is the kernel's end and the D2H
+            if name == "offload_batch":
+                buckets["h2d"] += float(tags.get("h2d_submit_us") or 0.0)
+                buckets["kernel"] += float(tags.get("launch_us") or 0.0)
+                buckets["d2h"] += float(tags.get("result_wait_us") or 0.0)
         commits = [s["duration_us"] for s in ss
                    if s["name"] == "store_commit"]
         if commits:
@@ -2425,7 +2424,8 @@ def attribution_from_spans(spans: list[dict]) -> dict:
     }
 
 
-def stage_attribution() -> dict:
+def stage_attribution(seconds: float = 2.0, ab_seconds: float = 1.5,
+                      ab_reps: int = 3) -> dict:
     """The data-path attribution profiler, end to end on a live
     cluster: tracer + copy ledger + loop profiler armed around a timed
     EC write window (plugin=tpu), then the span stream decomposed into
@@ -2443,13 +2443,13 @@ def stage_attribution() -> dict:
     results: dict = {"attribution_platform": platform}
     KA, MA = 2, 1
     OBJ = KA * 4096
-    SECONDS, CONC = 2.0, 8
+    SECONDS, CONC = seconds, 8
 
     async def body():
         from ceph_tpu import offload
         from ceph_tpu.tools.cluster_boot import ephemeral_cluster
         from ceph_tpu.tools.rados_bench import _phase
-        from ceph_tpu.utils import copytrack, loopprof, reactor, tracer
+        from ceph_tpu.utils import copytrack, loopprof, tracer
 
         # profile the SHARDED runtime (capped by the bench knob): the
         # stage then reports loop_busy_fraction per reactor shard plus
@@ -2458,7 +2458,6 @@ def stage_attribution() -> dict:
         async with ephemeral_cluster(KA + MA, prefix="bench-attr-",
                                      reactor_shards=n_shards) \
                 as (client, osds, _mon):
-            pool = reactor.current_pool()
             try:
                 await client.command({
                     "prefix": "osd erasure-code-profile set",
@@ -2474,21 +2473,12 @@ def stage_attribution() -> dict:
                 # warm: XLA compiles + sessions open outside the window
                 await asyncio.gather(*[io.write_full(f"warm-{i}", payload)
                                        for i in range(4)])
-                # arm every instrument, zeroed, for the measured window
-                # (profile_dispatch serializes traced device dispatches
-                # so spans carry real h2d/kernel/d2h splits —
-                # attribution-only, never plain tracer_enabled)
+                # arm every instrument, zeroed, for the measured window:
+                # tracer.enable() arms the loop account on this loop and
+                # on every reactor shard at its first span
                 tracer.enable(max_spans=65536)
-                tracer.set_profile_dispatch(True)
                 tracer.reset()
                 copytrack.reset()
-                if pool is not None:
-                    # arm the sampler ON every reactor shard (install
-                    # reads the loop thread's ident on that thread)
-                    await pool.run_on_each(
-                        lambda: loopprof.install(sample_hz=200))
-                else:
-                    loopprof.install(sample_hz=200)
                 loopprof.reset()
                 dev_base = svc.device_snapshot()
                 counts: dict = {}
@@ -2498,10 +2488,6 @@ def stage_attribution() -> dict:
                 window_s = time.perf_counter() - t_win
                 tracer.disable()
                 prof = loopprof.dump()
-                if pool is not None:
-                    await pool.run_on_each(loopprof.uninstall)
-                else:
-                    loopprof.uninstall()
                 bytes_written = w["ops"] * OBJ
                 att = attribution_from_spans(tracer.collector().spans())
                 att["copy_amplification"] = \
@@ -2521,9 +2507,7 @@ def stage_attribution() -> dict:
                 att["per_shard"] = prof.get("shards", {})
                 att["shard_busy_skew"] = prof.get("shard_busy_skew", 0.0)
                 results["shard_busy_skew"] = att["shard_busy_skew"]
-                att["executor_queue_depth"] = \
-                    prof["executor_queue_depth"]
-                att["top_stalls"] = prof["top_stalls"][:5]
+                att["loop_labels_us"] = prof["labels_us"]
                 att["per_device"] = {}
                 for dev, d in svc.device_snapshot().items():
                     base = dev_base.get(dev, {})
@@ -2584,12 +2568,8 @@ def stage_attribution() -> dict:
                 # allocator effects, and best-of-reps on the ratio
                 # drops one-off stall windows (compaction, GC) that
                 # would otherwise land on whichever mode drew them.
-                # profile_dispatch is OFF for both modes — sampling
-                # must never imply the serialized attribution mode, and
-                # this measures that claim. The guarded key is the
-                # production config.
-                tracer.set_profile_dispatch(False)
-                AB_SECONDS, AB_REPS = 1.5, 3
+                # The guarded key is the production config.
+                AB_SECONDS, AB_REPS = ab_seconds, ab_reps
 
                 def _arm_off() -> None:
                     tracer.disable()
@@ -2655,11 +2635,6 @@ def stage_attribution() -> dict:
             finally:
                 tracer.disable()
                 tracer.set_sampling(rate=0.0, tail_slow_ms=0.0)
-                tracer.set_profile_dispatch(False)
-                try:
-                    loopprof.uninstall()
-                except Exception:
-                    pass
 
     asyncio.run(asyncio.wait_for(body(), 150))
     results["elapsed_s"] = round(time.perf_counter() - t0, 1)

@@ -104,12 +104,12 @@ class AdminSocket:
         from ceph_tpu.utils import loopprof
         self.register_command(
             "profile dump",
-            lambda req: loopprof.dump(req.get("top")),
-            "loop profiler: busy fraction, executor depth, top stall "
-            "sites (arm with config set profiler_enabled true)")
+            lambda req: loopprof.dump(),
+            "loop account: loop time by layer, busy fraction, lag, "
+            "pauses (arm with config set profiler_enabled true)")
         self.register_command("profile reset",
                               lambda req: loopprof.reset(),
-                              "zero the loop profiler's samples")
+                              "zero the loop account's books")
         from ceph_tpu.utils import sanitizer
         self.register_command(
             "deadlock dump",

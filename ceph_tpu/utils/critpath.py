@@ -15,13 +15,16 @@ proportionally so the identity still holds exactly.
 Stage sources:
   * queue_wait — `queue_wait_us` tags (OSD op-queue) plus
     `offload_queue_wait` span durations (the batcher's linger).
-  * h2d/kernel/d2h — the profiled splits on device-dispatch spans
-    (`offload_batch`, `tpu_*_dispatch`) when `profile_dispatch` was
-    on; an UNPROFILED dispatch attributes its whole duration to
+  * h2d/kernel/d2h — the hop tags the offload service stamps on
+    `offload_batch` without serializing anything: `h2d_submit_us`
+    (device_put returns), `launch_us` (the kernel call returns) and
+    `result_wait_us` (np.asarray returns: the kernel's end and the
+    D2H, which lands in `d2h`). A dispatch span without them
+    (`tpu_*_dispatch`, a host batch) attributes its whole duration to
     `kernel` (device wall time — the honest aggregate).
   * encode — EC compute spans (`ec_encode`/`ec_decode`/`ec_write`/
     `ec_recover`) minus the offload time nested inside them, plus the
-    host staging copies (`copy_us`).
+    host staging copies (`stack_us`, `copy_us`).
   * commit — the slowest `store_commit` (shards commit in parallel;
     the serial path waits for the slowest).
   * other — everything unnamed: messenger hops, PG bookkeeping,
@@ -113,16 +116,17 @@ def critical_path(spans: list[dict]) -> dict[str, Any]:
         elif name == "store_commit":
             commit_max = max(commit_max, dur)
         elif name in _DISPATCH_SPANS:
-            h2d = _num(tags.get("h2d_us"))
-            ker = _num(tags.get("kernel_us"))
-            d2h = _num(tags.get("d2h_us"))
+            h2d = _num(tags.get("h2d_submit_us"))
+            ker = _num(tags.get("launch_us"))
+            d2h = _num(tags.get("result_wait_us"))
             if h2d or ker or d2h:
                 claims["h2d"] += h2d
                 claims["kernel"] += ker
                 claims["d2h"] += d2h
             else:
-                claims["kernel"] += dur     # unprofiled: device wall time
-            claims["encode"] += _num(tags.get("copy_us"))
+                claims["kernel"] += dur     # no hops: device wall time
+            claims["encode"] += _num(tags.get("stack_us")) \
+                + _num(tags.get("copy_us"))
         elif name in _ENCODE_SPANS:
             claims["encode"] += dur
     claims["commit"] = commit_max

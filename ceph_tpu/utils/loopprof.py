@@ -1,500 +1,498 @@
-"""Event-loop sampling profiler: where OSD loop wall time actually goes.
+"""The loop account: where an event loop's wall time goes, by layer.
 
-BENCH_r05's 450x device-vs-cluster gap is event-loop-bound as much as
-transfer-bound, but the sanitizer only reports callbacks that exceed a
-threshold — it cannot say what FRACTION of the loop's time each code
-path eats, which is the number the sharded-OSD work will be judged on.
-This module is the missing instrument: a wall-clock sampling profiler
-(the py-spy idea, scoped to registered event loops) built on the same
-task-factory hooks as `utils/sanitizer.py`.
-
-How it works:
-
-  * `install()` registers the RUNNING loop (recording its thread id)
-    and arms the sanitizer's task factory when none is set, so every
-    sampled task carries its spawn site;
-  * one daemon sampler thread wakes at `profiler_sample_hz` and reads
-    each registered loop thread's current Python frame via
-    `sys._current_frames()`:
-      - a frame parked in `selectors.select` is an IDLE sample;
-      - anything else is a BUSY sample, attributed to the innermost
-        frame outside loop machinery (the stall site) and to the span
-        kind the loop's current task is inside (tracer.task_span_name
-        — populated whenever tracing is on);
-  * `dump()` renders loop-busy-fraction, executor queue depth, and the
-    top-N stall sites with their span-kind mix — the admin-socket
-    `profile dump` / `profile reset` commands on every daemon.
-
-Config-gated and hot-togglable (`profiler_enabled`,
-`profiler_sample_hz`), same observer discipline as the sanitizer: a
-`config set` from the admin-socket thread marshals onto every tracked
-loop. Sampling costs one _current_frames() walk per tick on the
-SAMPLER thread; the loop itself pays nothing per sample.
+While armed it times every callback the loop runs (`Handle._run` is the
+seam ready queue, timers and I/O all pass through) and charges it to a
+LABEL: the innermost open tracer span of the task being stepped (kept
+on the task as `loop_label`; the span CM closes the running interval and
+opens the next, so span time is SELF time), else the package of the
+callback's code (`LABEL_OF_*`, the one table of layers). Collector
+pauses go to `gc`, time parked in `select` is `idle`, the loop's own
+machinery to the callback it follows. Every 50 ms a `loop_slice` span
+and a `loop_slice50` annotation in the profiler's trace; a 10 Hz
+watchdog catches a callback that holds the loop 0.5 s: a `loop_pause`.
+`tracer.enable()` arms the running loop, or the first a mapped span is
+entered on; `profiler_enabled` arms without tracing. Disarmed, nothing
+is installed: no hook, no `gc.callbacks` entry, no thread.
 """
 from __future__ import annotations
 
 import asyncio
+import bisect
+import collections
+import gc
 import sys
 import threading
 import time
+import traceback
+import types
 import weakref
+from asyncio import events as _events
 
-from ceph_tpu.utils import sanitizer, tracer
-from ceph_tpu.utils.dout import dout
-from ceph_tpu.utils.perf_counters import (TYPE_GAUGE, PerfCounters,
-                                          PerfCountersCollection)
+from ceph_tpu.utils import flight, tracer
+from ceph_tpu.utils.perf_counters import TYPE_GAUGE, PerfCountersCollection
 
-DEFAULT_HZ = 100.0
-TOP_N = 10
+LABELS = ("msgr", "client", "osd", "offload", "store", "harness",
+          "background", "gc", "unattributed")
+IDLE = "idle"
+LABEL_OF_SPAN = {
+    "ms_send": "msgr", "ms_dispatch": "msgr", "rados_op": "client",
+    "aio_op": "client", "osd_op": "osd", "pg_op": "osd", "ec_write": "osd",
+    "ec_read": "osd", "ec_encode": "osd",
+    "ec_decode": "osd", "ec_recover": "osd", "offload_batch": "offload",
+    "store_commit": "store"}
+#: `ms_dispatch` covers the handler: the receiving daemon's, where known
+LABEL_OF_SERVICE = {"osd": "osd", "client": "client", "mon": "background",
+                    "mgr": "background"}
+#: first match wins: the sockets are the messenger's (asyncio's stream
+#: transport reads them), the op queue the OSD's, the ticker our own
+LABEL_OF_PATH = (
+    ("/ceph_tpu/msg/", "msgr"), ("/asyncio/selector_events.py", "msgr"),
+    ("/asyncio/streams.py", "msgr"), ("/ceph_tpu/rados/", "client"),
+    ("/ceph_tpu/osd/", "osd"), ("/ceph_tpu/utils/work_queue.py", "osd"),
+    ("/ceph_tpu/offload/", "offload"), ("/ceph_tpu/objectstore/", "store"),
+    ("/ceph_tpu/mon/", "background"), ("/ceph_tpu/mgr/", "background"),
+    ("/ceph_tpu/utils/loopprof.py", "background"),
+    ("/benchmarks/", "harness"))
+OSD_TIMERS = ("_heartbeat", "_scrub_loop")       # background, in osd/
 
-#: frames from these paths are loop/executor machinery, never the stall
-#: site an operator can act on
-_SKIP_PARTS = ("/asyncio/", "/selectors.py", "/concurrent/futures/",
-               "/threading.py", "loopprof.py")
+SLICE50_NS = 50_000_000         # a `loop_slice` span, and its annotation
+TICK_S = 0.010                  # the lag ticker
+LONG_NS = 10_000_000            # a callback worth a `loop:<label>` mark
+PAUSE_NS = 500_000_000          # a callback that is a `loop_pause`
+WATCH_NS = 100_000_000          # the watchdog's period
+LAG_EDGES_MS = tuple(2.0 ** i for i in range(-2, 12))  # and one above
 
+_ORIG_RUN = _events.Handle._run
+_now, _current_task = time.perf_counter_ns, asyncio.current_task
 _lock = threading.Lock()
-#: loop -> {"thread_id", "owns_factory"}; strong keys on purpose — the
-#: sampler prunes closed loops each tick, and teardown asserts emptiness
-_loops: dict = {}
-#: loops that registered via maybe_install(): config changes from the
-#: admin-socket thread are marshalled onto these (sanitizer pattern)
-_tracked_loops: "weakref.WeakSet[asyncio.AbstractEventLoop]" = \
-    weakref.WeakSet()
-_thread: threading.Thread | None = None
-_interval = 1.0 / DEFAULT_HZ
-
-_samples = 0
-_busy_samples = 0
-_sites: dict[str, dict] = {}    # site -> {"samples": n, "kinds": {...}}
-#: per-loop sample counts, keyed by the loop's shard label (the sharded
-#: reactor's "shard0"/"shard1"... when the loop belongs to a pool, else
-#: a stable "loop<N>" fallback): the per-shard loop_busy_fraction the
-#: sharded-OSD work is graded on rides these through dump() and the
-#: exporter mirror
-_per_loop: dict[str, dict] = {}     # label -> {"samples", "busy"}
-_loop_seq = 0
+_states: dict = {}              # loop -> _Acct, while armed
+_tracked_loops = weakref.WeakSet()  # where a `config set` is marshalled to
+_by_tracer = False              # tracer.enable() wants every loop armed
+_books: dict[str, dict] = {}    # shard label -> ns by label, since reset
+_gc_t0 = 0                      # the running collection's start, and
+_gcs: collections.deque = collections.deque(maxlen=64)  # (end, ns) of late
+_code_labels: dict = {}         # id(code object or type) -> (label, it)
+_watchdog = None                # (thread, its stop event) while any loop
 
 
-# -- sampling ----------------------------------------------------------------
+class _Acct:
+    """One armed loop: every ns up to `mark` is charged in `acc`. A
+    callback is charged when the next one starts (so is the loop's own
+    machinery after it): the hook does nothing once the callback ran."""
+    __slots__ = (               # one object, no dict: the hook's are first
+        "acc", "cur", "mark", "t_cb", "handle", "n", "loop", "label",
+        "owners", "thread_id", "cpu_clock", "acc50", "t50", "t1", "n50",
+        "lag", "lag50", "cpus", "due", "selector", "parked",
+        # the watchdog's: `n` last seen, its catch, worst lateness, last wake
+        "seen", "pause", "late", "woke")
 
-def _site(frame) -> str:
-    fn = frame.f_code.co_filename
-    short = "/".join(fn.split("/")[-2:])
-    return f"{short}:{frame.f_lineno} in {frame.f_code.co_name}"
+    def __init__(self, loop, label: str):
+        self.loop, self.label, self.owners = loop, label, set()
+        self.cur, self.handle, self.n, self.n50 = "unattributed", None, 0, 0
+        self.due, self.selector, self.parked = 0.0, None, False
+        self.seen, self.pause, self.late, self.woke = -1, None, 0, 0
+        self.thread_id = threading.get_ident()
+        self.cpu_clock = time.pthread_getcpuclockid(self.thread_id)
+        self.acc = _books.setdefault(label,
+                                     dict.fromkeys(LABELS + (IDLE,), 0))
+        self.acc50 = dict(self.acc)
+        self.mark = self.t_cb = self.t50 = self.t1 = now = _now()
+        self.lag = [0] * (len(LAG_EDGES_MS) + 1)    # since a reset
+        self.lag50 = list(self.lag)
+        self.cpus: collections.deque = collections.deque(
+            [(now, time.clock_gettime_ns(self.cpu_clock))], maxlen=16)
 
-
-def _classify(frame) -> tuple[bool, str]:
-    """(busy, stall_site) for one sampled thread frame. A loop parked
-    in the selector poll is idle; anything else is busy, attributed to
-    the innermost frame outside loop machinery."""
-    g = frame
-    while g is not None:
-        code = g.f_code
-        if code.co_filename.endswith("selectors.py") and \
-                code.co_name == "select":
-            return False, ""
-        g = g.f_back
-    g = frame
-    while g is not None:
-        fn = g.f_code.co_filename
-        if not any(p in fn for p in _SKIP_PARTS):
-            return True, _site(g)
-        g = g.f_back
-    return True, _site(frame)
-
-
-def _task_kind(loop) -> str:
-    """Span kind (or coroutine identity) of the loop's current task,
-    read cross-thread: asyncio keeps the per-loop current task in a
-    plain dict the GIL makes safe to read."""
-    task = None
-    try:
-        task = asyncio.tasks._current_tasks.get(loop)
-    except Exception:
-        pass
-    kind = tracer.task_span_name(task)
-    if kind is None and task is not None:
-        coro = task.get_coro()
-        kind = getattr(coro, "__qualname__", None) or task.get_name()
-    return kind or "unattributed"
+    def switch(self, label: str) -> None:
+        now = _now()
+        self.acc[self.cur] += now - self.mark
+        self.mark, self.cur = now, label
 
 
-def _record(loop, frame) -> None:
-    global _samples, _busy_samples
-    busy, site = _classify(frame)
-    kind = _task_kind(loop) if busy else ""
-    with _lock:
-        _samples += 1
-        st = _loops.get(loop)
-        label = st["label"] if st is not None else "loop?"
-        per = _per_loop.get(label)
-        if per is None:
-            per = _per_loop[label] = {"samples": 0, "busy": 0}
-        per["samples"] += 1
-        if not busy:
-            return
-        _busy_samples += 1
-        per["busy"] += 1
-        d = _sites.get(site)
-        if d is None:
-            d = _sites[site] = {"samples": 0, "kinds": {}}
-        d["samples"] += 1
-        d["kinds"][kind] = d["kinds"].get(kind, 0) + 1
+def _label_of(cb, owner) -> str:
+    """Label of a callback by its code's package; a task (`owner` of its
+    step or wake-up) keeps it as `loop_label`, where an open span
+    overrides it."""
+    task = owner if isinstance(owner, asyncio.Task) else None
+    if task is not None:
+        key = getattr(task.get_coro(), "cr_code", None) or type(task)
+    else:
+        fn = cb.__func__ if type(cb) is types.MethodType else \
+            getattr(cb, "func", cb)             # a partial's, or itself
+        key = getattr(fn, "__code__", None) or type(cb)
+    hit = _code_labels.get(id(key))     # hashing a code object walks it
+    if hit is None:             # the table is walked once per key
+        path = getattr(key, "co_filename", None) or \
+            "/" + getattr(key, "__module__", "").replace(".", "/") + "/"
+        label = next((lab for part, lab in LABEL_OF_PATH if part in path),
+                     "unattributed")
+        if label == "osd" and getattr(key, "co_name", "") in OSD_TIMERS:
+            label = "background"
+        hit = _code_labels[id(key)] = (label, key)  # `key` lives: id is its
+    label = hit[0]
+    if task is not None:
+        task.loop_label = label
+    return label
 
 
-def _sample_loop() -> None:
-    global _thread
+def _span_enter(span):
+    """Span CM enter: switch to the span's label (None: it moves none)."""
+    label = LABEL_OF_SPAN.get(span.name)
+    loop = _events._get_running_loop()
+    if label is None or loop is None:
+        return None
+    st = _states.get(loop)
+    if st is None:
+        if not _by_tracer:
+            return None
+        st = install(loop, owner="tracer")      # deferred arming
+    if span.name == "ms_dispatch":
+        label = LABEL_OF_SERVICE.get(span.service.partition(".")[0], label)
+    task = _current_task(loop)
+    if task is not None:        # kept on the task for when it resumes
+        back = getattr(task, "loop_label", None) or _label_of(None, task)
+        task.loop_label = label
+    else:
+        back = st.cur
+    st.switch(label)
+    return st, task, back
+
+
+def _span_exit(token) -> None:
+    st, task, back = token
+    if task is not None:
+        task.loop_label = back
+    st.switch(back)
+
+
+def _run(self):
+    """Installed as `asyncio.events.Handle._run` while any loop is armed."""
+    st = _states.get(self._loop)
+    if st is not None:
+        now = _now()            # the callback before, and what followed
+        st.acc[st.cur] += now - st.mark
+        if now - st.t_cb > LONG_NS:
+            _long_callback(st, now, now - st.t_cb)
+        cb = self._callback
+        owner = getattr(cb, "__self__", None)
+        st.cur = getattr(owner, "loop_label", None) or _label_of(cb, owner)
+        st.mark = st.t_cb = now
+        st.handle = self
+        st.n += 1
+    return _ORIG_RUN(self)
+
+
+def _wrap_select(st: _Acct) -> None:
+    sel, orig = st.loop._selector, st.loop._selector.select
+
+    def select(timeout=None):
+        if timeout == 0:
+            return orig(timeout)
+        st.parked, t0 = True, _now()
+        events = orig(timeout)
+        idle = _now() - t0      # not the callback's before it
+        st.acc[IDLE] += idle
+        st.mark += idle
+        st.t_cb += idle
+        st.parked = False
+        return events
+    sel.select = select
+    st.selector = (sel, select)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = _now()
+        return
+    now = _now()
+    _gcs.append((now, now - _gc_t0))
+    st = _states.get(_events._get_running_loop())
+    if st is not None:          # out of the interrupted label, into gc
+        st.acc["gc"] += now - _gc_t0
+        st.mark += now - _gc_t0
+
+
+def _tick(st: _Acct) -> None:
+    """How late the ticker runs is what every hop of an op pays."""
+    loop = _events._get_running_loop()
+    if _states.get(loop) is not st:
+        return                  # disarmed: the ticker stops itself
+    now = loop.time()
+    st.lag[bisect.bisect_left(LAG_EDGES_MS, (now - st.due) * 1e3)] += 1
+    if st.mark - st.t50 >= SLICE50_NS:      # charged up to this tick
+        _roll(st, st.mark)
+    st.due = max(st.due + TICK_S, now + TICK_S / 2)
+    loop.call_at(st.due, _tick, st)
+
+
+def _annotation():
+    """jax's TraceAnnotation, never imported here: parents stay off jax."""
+    jax = sys.modules.get("jax")
+    return getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+
+
+def _roll(st: _Acct, now: int) -> None:
+    """50 ms: the slice as a `loop_slice` span and, for the profiler's
+    trace, a `loop_slice50` annotation; once a second the gauges."""
+    us = {k + "_us": (v - st.acc50[k]) / 1e3 for k, v in st.acc.items()}
+    mark = _annotation()
+    if mark is not None:
+        with mark("loop_slice50", len_us=(now - st.t50) // 1000, pc_ns=now,
+                  **{k: int(v) for k, v in us.items()}):
+            pass
+    tracer.record_span(
+        "loop_slice", st.t50 / 1e9, (now - st.t50) / 1e3,
+        dict(us, callbacks=st.n - st.n50, lag_edges_ms=LAG_EDGES_MS,
+             lag_hist=[a - b for a, b in zip(st.lag, st.lag50)]),
+        service=st.label)
+    st.t50, st.acc50, st.n50, st.lag50 = now, dict(st.acc), st.n, list(st.lag)
+    if now - st.t1 >= 1e9:
+        st.t1 = now
+        _publish()
+
+
+def _long_callback(st: _Acct, now: int, took: int) -> None:
+    """The callback that just ended (`st.handle`, `st.cur`) held the loop
+    over 10 ms: a `loop:<label>` mark in the profiler's trace (its stats
+    carry the interval: a TraceMe cannot be backdated). Over 0.5 s: a
+    `loop_pause` (was it the collector, Python, a block, the machine)."""
+    mark = _annotation()
+    if mark is not None:
+        with mark(f"loop:{st.cur}", dur_us=took // 1000, pc_ns=now):
+            pass
+    if took < PAUSE_NS:
+        return
+    caught, st.pause = st.pause or {}, None
+    cpu0 = caught.get("cpu0")   # sampled up to 0.1 s before the pause
+    facts = {
+        "duration_s": round(took / 1e9, 4), "label": st.cur,
+        "callback": repr(st.handle), "stack": caught.get("stack"),
+        "gc_s": round(sum(ns for end, ns in _gcs if end > st.t_cb) / 1e9, 4),
+        "cpu_s": None if cpu0 is None else
+        round((time.clock_gettime_ns(st.cpu_clock) - cpu0) / 1e9, 4),
+        # a watchdog that has not woken since is late by that much too
+        "watchdog_late_s": round(max(
+            st.late, now - max(st.woke, st.t_cb) - WATCH_NS, 0) / 1e9, 4)}
+    flight.record("loop_pause", st.label, **facts)
+    tracer.record_span("loop_pause", st.t_cb / 1e9, took / 1e3, facts,
+                       service=st.label)
+
+
+def _watch(stop: threading.Event) -> None:
+    """10 Hz: prune closed loops, sample CPU clocks, catch a held loop."""
     while True:
-        time.sleep(_interval)
-        with _lock:
-            for lp in [lp for lp in _loops if lp.is_closed()]:
-                del _loops[lp]
-            if not _loops:
-                _thread = None
-                return
-            targets = [(st["thread_id"], lp)
-                       for lp, st in _loops.items()]
-        frames = sys._current_frames()
-        for tid, lp in targets:
-            f = frames.get(tid)
-            if f is not None:
-                _record(lp, f)
+        t0 = _now()
+        if stop.wait(WATCH_NS / 1e9):
+            return
+        now = _now()
+        for loop, st in list(_states.items()):
+            if loop.is_closed():
+                uninstall(loop, owner=None)
+                continue
+            cpu = time.clock_gettime_ns(st.cpu_clock)
+            if st.n != st.seen or st.parked:    # the loop turns, or rests
+                st.seen, st.pause, st.late = st.n, None, 0
+            elif st.pause is None and now - st.t_cb >= PAUSE_NS:
+                before = [c for t, c in st.cpus if t <= st.t_cb]
+                st.pause = {
+                    "stack": [     # innermost first, less loop machinery
+                        f"{f.filename}:{f.lineno} in {f.name}"
+                        for f in reversed(traceback.extract_stack(
+                            sys._current_frames().get(st.thread_id)))
+                        if "/asyncio/" not in f.filename][:12],
+                    "cpu0": before[-1] if before else None}
+            st.late, st.woke = max(st.late, now - t0 - WATCH_NS), now
+            st.cpus.append((now, cpu))
 
-
-# -- lifecycle ---------------------------------------------------------------
 
 def install(loop: asyncio.AbstractEventLoop | None = None,
-            sample_hz: float = DEFAULT_HZ) -> None:
-    """Arm the profiler on `loop` (default: the running loop). Must run
-    on the loop's own thread — the sampler needs its thread id.
-    Idempotent per loop; stats are process-wide."""
-    global _thread, _interval
-    if loop is None:
-        loop = asyncio.get_running_loop()
-    _tracked_loops.add(loop)
-    _interval = 1.0 / max(1.0, float(sample_hz))
-    global _loop_seq
-    try:
-        from ceph_tpu.utils import reactor
-        label = reactor.shard_label(loop)
-    except Exception:
-        label = None
+            owner: str = "operator") -> _Acct:
+    """Arm `loop` (default: the running one) for `owner`, on its thread."""
+    global _watchdog
+    loop = loop or asyncio.get_running_loop()
+    from ceph_tpu.utils import reactor
     with _lock:
-        if loop not in _loops:
-            owns = loop.get_task_factory() is None
-            if owns:
-                # ride the sanitizer's factory: sampled tasks then carry
-                # their spawn site for the stall report
-                loop.set_task_factory(sanitizer.task_factory)
-            if label is None:
-                label = f"loop{_loop_seq}"
-                _loop_seq += 1
-            _loops[loop] = {"thread_id": threading.get_ident(),
-                            "owns_factory": owns, "label": label}
-        start_thread = _thread is None
-        if start_thread:
-            _thread = threading.Thread(target=_sample_loop, daemon=True,
-                                       name="loopprof-sampler")
-        # span CMs mirror their name per-task only while a sampler can
-        # read it — the mirror costs weak-dict ops on the tracing hot
-        # path, so the tracer keeps it off otherwise
-        tracer.set_task_naming(True)
-    if start_thread:
-        _thread.start()
-    perf()
-    dout("prof", 1, f"loop profiler armed at {1.0 / _interval:.0f} Hz")
+        st = _states.get(loop)
+        if st is None:          # loops outside a reactor share one label
+            st = _Acct(loop, reactor.shard_label(loop) or "loop0")
+            _wrap_select(st)
+            st.due = loop.time() + TICK_S
+            loop.call_at(st.due, _tick, st)
+            if not _states:
+                _events.Handle._run = _run
+                gc.callbacks.append(_on_gc)
+                tracer.set_account(_span_enter, _span_exit)
+                stop = threading.Event()
+                _watchdog = (threading.Thread(
+                    target=_watch, args=(stop,), daemon=True,
+                    name="loopprof-watchdog"), stop)
+                _watchdog[0].start()
+            _states[loop] = st
+        st.owners.add(owner)
+    return st
 
 
-def uninstall(loop: asyncio.AbstractEventLoop | None = None) -> None:
-    """Disarm `loop`: stop sampling it and unwind the task factory we
-    installed (leaving a sanitizer-armed factory in place)."""
-    if loop is None:
-        loop = asyncio.get_running_loop()
+def uninstall(loop: asyncio.AbstractEventLoop | None = None,
+              owner: str | None = "operator") -> None:
+    """Disarm `loop` for `owner` (None: everyone); any thread may."""
+    global _watchdog
+    loop = loop or asyncio.get_running_loop()
     with _lock:
-        st = _loops.pop(loop, None)
-        if not _loops:
-            tracer.set_task_naming(False)
-    if st and st["owns_factory"] and not loop.is_closed() \
-            and loop.get_task_factory() is sanitizer.task_factory \
-            and not sanitizer.armed(loop):
-        loop.set_task_factory(None)
+        st = _states.get(loop)
+        if st is None:
+            return
+        st.owners.discard(owner)
+        if st.owners and owner is not None:
+            return
+        del _states[loop]
+        st.switch(st.cur)       # the running callback, so far
+        if st.selector is not None and \
+                st.selector[0].__dict__.get("select") is st.selector[1]:
+            del st.selector[0].select
+        if _states:
+            return
+        _events.Handle._run = _ORIG_RUN
+        gc.callbacks.remove(_on_gc)
+        if not _by_tracer:
+            tracer.set_account(None, None)
+        (thread, stop), _watchdog = _watchdog, None
+    stop.set()
+    if thread is not threading.current_thread():
+        thread.join(2.0)
+
+
+def tracer_armed(on: bool) -> None:
+    """tracer.enable()/disable(): arm this loop now, others lazily."""
+    global _by_tracer
+    _by_tracer = bool(on)
+    loop = _events._get_running_loop()
+    if on and loop is not None:
+        install(loop, owner="tracer")
+    for lp in [] if on else list(_states):
+        uninstall(lp, owner="tracer")
+    if on or not _states:
+        tracer.set_account(*((_span_enter, _span_exit) if on
+                             else (None, None)))
 
 
 def installed_loops() -> list:
-    """Live (non-closed) loops the sampler is armed on — the conftest
-    leak gate asserts this is empty after every test."""
-    with _lock:
-        return [lp for lp in _loops if not lp.is_closed()]
+    """Live loops still armed: the conftest leak gate wants none."""
+    return [lp for lp in list(_states) if not lp.is_closed()]
 
 
-def parked_tasks(limit: int = 64) -> list[dict]:
-    """Census of pending tasks across every tracked loop, each with its
-    spawn site and current suspension point: the deadlock watchdog's
-    `deadlock dump` lays this next to the registered lock/grant waits so
-    an operator sees what ELSE is parked around a cycle. Best-effort
-    cross-thread read — all_tasks retries its weak-set snapshot and the
-    coroutine frame walk is a GIL-safe peek."""
-    loops: set = set()
-    with _lock:
-        loops.update(lp for lp in _loops if not lp.is_closed())
-    loops.update(lp for lp in list(_tracked_loops) if not lp.is_closed())
-    out: list[dict] = []
-    for lp in loops:
-        try:
-            tasks = asyncio.all_tasks(lp)
-        except RuntimeError:
-            continue
-        for t in tasks:
-            if t.done():
-                continue
-            entry = {"task": t.get_name(),
-                     "spawn_site": sanitizer.spawn_site(t)}
-            try:
-                frames = t.get_stack(limit=1)
-                if frames:
-                    f = frames[-1]
-                    entry["parked_at"] = (
-                        f"{f.f_code.co_filename}:{f.f_lineno} "
-                        f"in {f.f_code.co_name}")
-            except Exception:
-                pass
-            out.append(entry)
-            if len(out) >= limit:
-                return out
-    return out
-
-
-# -- surfaces ----------------------------------------------------------------
-
-def _executor_depth() -> int:
-    """Best-effort queued-work depth across the offload staging pool
-    and each tracked loop's default executor."""
-    depth = 0
-    try:
-        from ceph_tpu.offload import service as _offload_svc
-        pool = _offload_svc._pool
-        if pool is not None:
-            depth += pool._work_queue.qsize()
-    except Exception:
-        pass
-    with _lock:
-        loops = list(_loops)
-    for lp in loops:
-        q = getattr(getattr(lp, "_default_executor", None),
-                    "_work_queue", None)
-        if q is not None:
-            try:
-                depth += q.qsize()
-            except Exception:
-                pass
-    return depth
+def _shard(wall_us: float, busy_us: float) -> dict:
+    return {"wall_us": round(wall_us, 1), "busy_us": round(busy_us, 1),
+            "loop_busy_fraction": round(busy_us / (wall_us or 1), 4)}
 
 
 def shard_stats() -> dict[str, dict]:
-    """Per-shard (per sampled loop) busy fractions — the shard-local
-    registries, merged: {"shard0": {"samples", "busy_samples",
-    "loop_busy_fraction"}, ...}."""
-    with _lock:
-        per = {label: dict(d) for label, d in _per_loop.items()}
-    return {label: {
-        "samples": d["samples"],
-        "busy_samples": d["busy"],
-        "loop_busy_fraction": round(d["busy"] / d["samples"], 4)
-        if d["samples"] else 0.0}
-        for label, d in sorted(per.items())}
+    """{"shard0": {"wall_us", "busy_us", "loop_busy_fraction"}, ...}."""
+    return {lbl: _shard(sum(d.values()) / 1e3,
+                        (sum(d.values()) - d[IDLE]) / 1e3)
+            for lbl, d in sorted(_books.items())}
 
 
 def merge_shard_stats(*parts: dict[str, dict]) -> dict[str, dict]:
-    """Merge per-process `shard_stats()` snapshots into one pool-wide
-    view, keyed by shard label. Under the process-backed reactor each
-    worker samples its OWN loop and labels it with the pool-wide shard
-    index (`reactor.adopt_worker_shard`), so the parent can fetch every
-    worker's stats over the control channel and hand the union to
-    `shard_busy_skew` — the cross-process number the bench trend guard
-    watches. Same-label snapshots (a respawned worker's fresh process)
-    sum counters and recompute the fraction."""
-    merged: dict[str, dict] = {}
+    """Per-process `shard_stats()` merged by shard label (same: summed)."""
+    merged: dict[str, list] = {}
     for part in parts:
-        for label, d in (part or {}).items():
-            m = merged.setdefault(label, {"samples": 0, "busy_samples": 0})
-            m["samples"] += int(d.get("samples", 0))
-            m["busy_samples"] += int(d.get("busy_samples", 0))
-    return {label: {
-        "samples": m["samples"],
-        "busy_samples": m["busy_samples"],
-        "loop_busy_fraction": round(m["busy_samples"] / m["samples"], 4)
-        if m["samples"] else 0.0}
-        for label, m in sorted(merged.items())}
+        for lbl, d in (part or {}).items():
+            m = merged.setdefault(lbl, [0.0, 0.0])
+            m[0] += float(d.get("wall_us", 0))
+            m[1] += float(d.get("busy_us", 0))
+    return {lbl: _shard(*m) for lbl, m in sorted(merged.items())}
 
 
 def shard_busy_skew(shards: dict[str, dict] | None = None) -> float:
-    """(max-min)/max busy fraction across sampled shards: 0 = balanced
-    load, 1 = one shard saturated while another idles. The trend guard
-    flags rises — a placement/affinity regression shows up here before
-    it shows up in MB/s."""
-    if shards is None:
-        shards = shard_stats()
+    """(max-min)/max busy fraction across shards: 0 is balanced."""
+    shards = shard_stats() if shards is None else shards
     fr = [d["loop_busy_fraction"] for d in shards.values()
-          if d["samples"] > 0]
+          if d["wall_us"] > 0]
     if len(fr) < 2 or max(fr) <= 0:
         return 0.0
     return round((max(fr) - min(fr)) / max(fr), 4)
 
 
-def dump(top_n: int | None = None) -> dict:
-    """Admin-socket `profile dump`: merged busy fraction, per-shard
-    busy fractions + skew, executor depth, and the top stall sites with
-    their span-kind mix."""
-    with _lock:
-        samples, busy = _samples, _busy_samples
-        sites = {s: {"samples": d["samples"], "kinds": dict(d["kinds"])}
-                 for s, d in _sites.items()}
-        enabled = any(not lp.is_closed() for lp in _loops)
-        hz = 1.0 / _interval
-    top = sorted(sites.items(), key=lambda kv: -kv[1]["samples"])
-    top = top[:top_n if top_n else TOP_N]
+def dump() -> dict:
+    """`profile dump`: us by label, busy fraction, the armed loops' lag."""
+    st = _states.get(_events._get_running_loop())
+    if st is not None:
+        st.switch(st.cur)       # the running callback, so far
+    labels = {k: round(sum(d[k] for d in _books.values()) / 1e3, 1)
+              for k in LABELS + (IDLE,)}
+    wall = sum(labels.values())
     shards = shard_stats()
-    return {
-        "enabled": enabled,
-        "sample_hz": round(hz, 1),
-        "samples": samples,
-        "busy_samples": busy,
-        "loop_busy_fraction": round(busy / samples, 4) if samples
-        else 0.0,
-        "shards": shards,
-        "shard_busy_skew": shard_busy_skew(shards),
-        "executor_queue_depth": _executor_depth(),
-        "top_stalls": [
-            {"site": s, "samples": d["samples"],
-             "pct": round(100.0 * d["samples"] / busy, 1) if busy
-             else 0.0,
-             "span_kinds": dict(sorted(d["kinds"].items(),
-                                       key=lambda kv: -kv[1]))}
-            for s, d in top],
-    }
+    live = list(_states.values())
+    return {"enabled": bool(installed_loops()), "labels_us": labels,
+            "wall_us": round(wall, 1),
+            "loop_busy_fraction": round((wall - labels[IDLE]) / wall, 4)
+            if wall else 0.0,
+            "callbacks": sum(st.n for st in live),
+            "shards": shards, "shard_busy_skew": shard_busy_skew(shards),
+            "lag_edges_ms": list(LAG_EDGES_MS),
+            "lag_hist": [sum(c) for c in zip(*(st.lag for st in live))]}
 
 
 def reset() -> dict:
-    """Admin-socket `profile reset`: zero samples and stall sites."""
-    global _samples, _busy_samples
+    """Admin-socket `profile reset`: zero the books."""
+    cleared = dump()["wall_us"]
     with _lock:
-        cleared = _samples
-        _samples = 0
-        _busy_samples = 0
-        _sites.clear()
-        _per_loop.clear()
-    return {"cleared_samples": cleared}
+        for books in list(_books.values()) + \
+                [st.acc50 for st in _states.values()]:
+            books.update(dict.fromkeys(books, 0))
+        for st in _states.values():
+            st.lag, st.lag50 = [0] * len(st.lag), [0] * len(st.lag)
+    return {"cleared_wall_us": cleared}
 
 
-class _LoopprofCounters(PerfCounters):
-    """Pull-model mirror: values sync from the sample store at dump()
-    time so they ride the MgrClient report path and /metrics."""
-
-    def __init__(self):
-        super().__init__("loopprof")
-        self.add("loop_samples",
-                 description="profiler samples taken on this process's "
-                             "event loops")
-        self.add("loop_busy_samples",
-                 description="samples that caught the loop executing "
-                             "(not parked in the selector)")
-        self.add("loop_busy_fraction", type=TYPE_GAUGE,
-                 description="busy samples / total samples since reset")
-        self.add("executor_queue_depth", type=TYPE_GAUGE,
-                 description="work items queued behind the staging/"
-                             "default executors")
-        self.add("shard_busy_skew", type=TYPE_GAUGE,
-                 description="(max-min)/max loop busy fraction across "
-                             "reactor shards (0 = balanced)")
-
-    def dump(self) -> dict:
-        with _lock:
-            samples, busy = _samples, _busy_samples
-        self.set("loop_samples", samples)
-        self.set("loop_busy_samples", busy)
-        self.set("loop_busy_fraction",
-                 round(busy / samples, 4) if samples else 0.0)
-        self.set("executor_queue_depth", _executor_depth())
-        shards = shard_stats()
-        self.set("shard_busy_skew", shard_busy_skew(shards))
-        for label, d in shards.items():
-            key = f"loop_busy_fraction_{label}"
-            if key not in self._types:
-                # per-shard gauges materialize as shards appear: the
-                # exporter then renders one family per reactor shard.
-                # Concurrent dumpers (exporter scrape + admin perf
-                # dump) can race the check — the loser's add is a no-op
-                try:
-                    self.add(key, type=TYPE_GAUGE,
-                             description=f"busy fraction of reactor "
-                                         f"{label}'s event loop")
-                except ValueError:
-                    pass
-            self.set(key, d["loop_busy_fraction"])
-        return super().dump()
-
-
-def perf() -> PerfCounters:
+def perf():
+    """The `loopprof` logger: gauges published each second while armed."""
     coll = PerfCountersCollection.instance()
-    pc = coll.get("loopprof")
-    if pc is None:
-        try:
-            pc = coll.register(_LoopprofCounters())
-        except ValueError:
-            pc = coll.get("loopprof")   # another shard loop won the race
+    with _lock:
+        pc = coll.get("loopprof") or coll.create("loopprof")
+        for name in ["loop_busy_fraction", "shard_busy_skew"] + \
+                [f"loop_busy_fraction_{label}" for label in _books]:
+            if name not in pc._types:
+                pc.add(name, type=TYPE_GAUGE, description="busy/accounted "
+                       "wall time: all loops, a shard's, (max-min)/max")
     return pc
 
 
-# -- config ------------------------------------------------------------------
+def _publish() -> None:
+    pc, shards = perf(), shard_stats()
+    pc.set("loop_busy_fraction", dump()["loop_busy_fraction"])
+    pc.set("shard_busy_skew", shard_busy_skew(shards))
+    for label, d in shards.items():
+        pc.set(f"loop_busy_fraction_{label}", d["loop_busy_fraction"])
+
 
 def register_config(config) -> None:
-    """Declare the profiler options on `config` (idempotent) and watch
-    them — `config set profiler_enabled true` over the admin socket
-    arms the running loop live, matching sanitizer/tracer hot reload."""
+    """Declare `profiler_enabled` and arm live on a `config set`."""
     from ceph_tpu.utils.config import ConfigError, Option
-    for opt in (Option("profiler_enabled", "bool", False,
-                       "arm the event-loop sampling profiler "
-                       "(loop-busy-fraction, top stall sites)"),
-                Option("profiler_sample_hz", "float", DEFAULT_HZ,
-                       "loop profiler sampling frequency",
-                       minimum=1.0)):
-        try:
-            config.declare(opt)
-        except ConfigError:
-            pass                        # already declared by another daemon
+    try:
+        config.declare(Option("profiler_enabled", "bool", False,
+                              "arm the loop account (loop time by layer, "
+                              "lag, pauses) without full tracing"))
+    except ConfigError:
+        pass                            # already declared by another daemon
 
-    def _apply(loop: asyncio.AbstractEventLoop, name: str, value) -> None:
-        global _interval
-        if name == "profiler_enabled":
-            install(loop, config.get("profiler_sample_hz")) \
-                if value else uninstall(loop)
-        elif name == "profiler_sample_hz":
-            _interval = 1.0 / max(1.0, float(value))
+    def _on_change(_name: str, value) -> None:
+        arm = install if value else uninstall
+        loop = _events._get_running_loop()
+        if loop is not None:
+            return arm(loop)
+        for lp in list(_tracked_loops):     # admin-socket thread: marshal
+            if not lp.is_closed():
+                lp.call_soon_threadsafe(arm, lp)
 
-    def _on_change(name: str, value) -> None:
-        try:
-            _apply(asyncio.get_running_loop(), name, value)
-        except RuntimeError:
-            # admin-socket thread: no loop here — marshal onto every
-            # registered daemon loop (install must read the loop
-            # thread's ident on that thread)
-            for loop in list(_tracked_loops):
-                if not loop.is_closed():
-                    loop.call_soon_threadsafe(_apply, loop, name, value)
-
-    config.add_observer(("profiler_enabled", "profiler_sample_hz"),
-                        _on_change)
+    config.add_observer(("profiler_enabled",), _on_change)
 
 
 def maybe_install(config=None) -> None:
-    """Arm the profiler on the running loop when enabled; always track
-    the loop so a later `config set profiler_enabled true` from the
-    admin-socket thread knows where to arm."""
-    if config is None:
-        return
+    """Arm the running loop when enabled; track it for a later set."""
     try:
         _tracked_loops.add(asyncio.get_running_loop())
         if config.get("profiler_enabled"):
-            install(sample_hz=config.get("profiler_sample_hz"))
+            install()
     except Exception:
-        pass                            # options not declared on this config
+        pass                            # no config, or no such option

@@ -309,7 +309,7 @@ class ShardPool:
         finally:
             try:
                 from ceph_tpu.utils import loopprof
-                loopprof.uninstall(loop)     # defensive: sampler unarm
+                loopprof.uninstall(loop, owner=None)   # defensive
             except Exception:
                 pass
             _unregister(loop, self)

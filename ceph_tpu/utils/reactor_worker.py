@@ -232,7 +232,7 @@ class _Worker:
                             if t is not cur])
             try:
                 from ceph_tpu.utils import loopprof
-                loopprof.uninstall(self.loop)
+                loopprof.uninstall(self.loop, owner=None)
             except Exception:
                 pass
             dout("reactor", 1, f"worker shard{self.index} down")

@@ -1062,6 +1062,37 @@ def wait_annotations(entity: str | None = None,
     return out
 
 
+def parked_tasks(limit: int = 64) -> list[dict]:
+    """Census of pending tasks across every tracked loop, each with its
+    spawn site and current suspension point: `deadlock dump` lays this
+    next to the registered lock/grant waits. Best-effort cross-thread
+    read — all_tasks retries its weak-set snapshot and the coroutine
+    frame walk is a GIL-safe peek."""
+    out: list[dict] = []
+    for lp in [lp for lp in list(_tracked_loops) if not lp.is_closed()]:
+        try:
+            tasks = asyncio.all_tasks(lp)
+        except RuntimeError:
+            continue
+        for t in tasks:
+            if t.done():
+                continue
+            entry = {"task": t.get_name(), "spawn_site": spawn_site(t)}
+            try:
+                frames = t.get_stack(limit=1)
+                if frames:
+                    f = frames[-1]
+                    entry["parked_at"] = (
+                        f"{f.f_code.co_filename}:{f.f_lineno} "
+                        f"in {f.f_code.co_name}")
+            except Exception:
+                pass
+            out.append(entry)
+            if len(out) >= limit:
+                return out
+    return out
+
+
 def deadlock_dump() -> dict:
     """The `deadlock dump` admin-socket verb: lockdep graph stats,
     retained inversions, live waits/holders with task spawn sites, the
@@ -1077,20 +1108,14 @@ def deadlock_dump() -> dict:
     for w in waits:
         w["age_s"] = round(now - w.pop("since"), 3)
         w.pop("ctx", None)
-    # parked-task census from the loopprof/task-factory mirrors: shows
-    # what ELSE is parked next to the registered waits
-    try:
-        from ceph_tpu.utils import loopprof
-        parked = loopprof.parked_tasks()
-    except Exception:
-        parked = []
     return {"lockdep": _lockdep_on,
             "stuck_wait_s": _stuck_wait_s,
             "order_edges": n_edges,
             "inversions": inversions,
             "waits": waits,
             "holders": holders,
-            "parked_tasks": parked,
+            # what ELSE is parked next to the registered waits
+            "parked_tasks": parked_tasks(),
             "last_detection": last,
             "scan": deadlock_scan()}
 

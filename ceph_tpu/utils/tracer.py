@@ -37,21 +37,18 @@ Design:
         p99 outliers are captured at ~100% without full-trace cost.
     Promotion is the ONLY transition (never eager drop): a client's
     reply `ms_dispatch` is a local root that finishes long before the
-    `rados_op` above it. None of this implies `profile_dispatch` — the
-    serialized-pipeline attribution mode stays a deliberate opt-in.
+    `rados_op` above it.
   * gating: `enabled()` is the legacy always-sample switch;
     `active()` is what hot paths gate on (any regime but off).
 """
 from __future__ import annotations
 
-import asyncio
 import collections
 import contextvars
 import os
 import random
 import threading
 import time
-import weakref
 from typing import Any, Iterator
 
 #: context flag: this trace was head-sampled at its root — every span
@@ -62,24 +59,19 @@ FLAG_SAMPLED = 1
 _current: contextvars.ContextVar[tuple[int, int, int] | None] = \
     contextvars.ContextVar("trace_ctx", default=None)
 
-#: task -> NAME of the span it is currently inside. The loop profiler
-#: attributes sampled wall time to this ("which span kind was running
-#: when the loop stalled") by reading the loop's current task from its
-#: sampler thread, which looks the name up here (the span CM mirrors
-#: it) instead of reaching into another task's context. Weak keys:
-#: a finished task drops its entry with it. Mirrored ONLY while a
-#: sampler is armed (`set_task_naming`): three WeakKeyDictionary ops +
-#: current_task() per span is real money on the always-on tail path,
-#: and nobody reads the mirror unless loopprof is sampling.
-_task_spans: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
-_name_tasks = False
+#: loopprof's `_span_enter(span) -> token | None` / `_span_exit(token)`
+#: while an account is armed on any loop, else None: a span CM then
+#: closes the loop's running interval and opens the next, and keeps the
+#: label of the innermost mapped span a task is inside on the task
+#: itself (`task.loop_label`), which is what the account charges the
+#: task's next step to when it resumes
+_acct_enter = _acct_exit = None
 
 
-def set_task_naming(on: bool) -> None:
-    """Armed by loopprof while any stall sampler is installed; the span
-    CM skips the task-name mirror entirely when this is off."""
-    global _name_tasks
-    _name_tasks = bool(on)
+def set_account(enter, exit) -> None:
+    """Armed by loopprof with its span hooks, disarmed with None."""
+    global _acct_enter, _acct_exit
+    _acct_enter, _acct_exit = enter, exit
 
 _enabled = False
 _sample_rate = 0.0
@@ -98,18 +90,6 @@ def boot_token() -> str:
     if pid != _boot_pid:
         _boot_pid, _boot = pid, f"{pid:x}.{os.urandom(4).hex()}"
     return _boot
-
-
-def task_span_name(task) -> str | None:
-    """Name of the span `task` is currently inside (None when it isn't
-    in one, or tracing is off). Safe to call from a foreign thread —
-    the sampler reads the loop's current task through this."""
-    if task is None:
-        return None
-    try:
-        return _task_spans.get(task)
-    except Exception:
-        return None
 
 
 def _new_id() -> int:
@@ -191,6 +171,12 @@ class Span:
     @property
     def start(self) -> float:
         return _WALL_ANCHOR + self._t0
+
+    @property
+    def t0(self) -> float:
+        """The start on `time.perf_counter()`, the clock spans are
+        stamped on: a hop measured from it lies inside the span."""
+        return self._t0
 
     def set_tag(self, key: str, value: Any) -> None:
         self.tags[key] = value
@@ -603,7 +589,7 @@ _NOOP = _NoopSpanCM()
 class _SpanCM:
     """Context manager making a live span the current trace context."""
 
-    __slots__ = ("span", "_token", "_task", "_prev_name")
+    __slots__ = ("span", "_token", "_acct")
 
     def __init__(self, span: Span):
         self.span = span
@@ -611,25 +597,14 @@ class _SpanCM:
     def __enter__(self) -> Span:
         self._token = _current.set((self.span.trace_id, self.span.span_id,
                                     self.span.flags))
-        self._task = self._prev_name = None
-        if _name_tasks:                 # only while loopprof samples
-            try:
-                task = asyncio.current_task()
-            except RuntimeError:
-                task = None
-            if task is not None:
-                self._task = task
-                self._prev_name = _task_spans.get(task)
-                _task_spans[task] = self.span.name
+        enter = _acct_enter             # only while a loop account is armed
+        self._acct = enter(self.span) if enter is not None else None
         return self.span
 
     def __exit__(self, et, ev, tb) -> bool:
         _current.reset(self._token)
-        if self._task is not None:
-            if self._prev_name is None:
-                _task_spans.pop(self._task, None)
-            else:
-                _task_spans[self._task] = self._prev_name
+        if self._acct is not None and _acct_exit is not None:
+            _acct_exit(self._acct)
         if et is not None:
             self.span.tags.setdefault("error", f"{et.__name__}: {ev}")
         self.span.finish()
@@ -703,6 +678,21 @@ def span(name: str, service: str = "", parent=None):
     if s is None:                       # deactivated raced mid-call
         return _NOOP
     return _SpanCM(s)
+
+
+def record_span(name: str, start: float, duration_us: float, tags: dict,
+                service: str = "") -> None:
+    """A finished root span with a given start (`time.perf_counter()`
+    seconds, the clock every span is stamped on) and length: what the
+    loop account closes every 50 ms. Always a root of its own, and
+    kept only where a root is sampled: nothing while tracing is off."""
+    flags = _root_flags() if active() else 0
+    if not flags:
+        return
+    s = Span(name, service, _new_id(), None, flags)
+    s._t0, s.duration_us, s._done = start, duration_us, True
+    s.tags.update(tags)
+    _route(s)
 
 
 class _CtxCM:
@@ -801,14 +791,20 @@ def active() -> bool:
 
 
 def enable(max_spans: int | None = None) -> None:
+    """Collect every span, and arm the loop account: on the running
+    loop now, on any other at the first mapped span entered there."""
     global _enabled
     if max_spans is not None:
         _collector.set_max_spans(max_spans)
     _enabled = True
+    from ceph_tpu.utils import loopprof
+    loopprof.tracer_armed(True)
 
 
 def disable() -> None:
     global _enabled
+    from ceph_tpu.utils import loopprof
+    loopprof.tracer_armed(False)
     _enabled = False
 
 
@@ -826,25 +822,6 @@ def sampling() -> dict:
     return {"enabled": _enabled, "sample_rate": _sample_rate,
             "tail_slow_ms": _tail_slow_ms,
             "reservoir": _reservoir.status()}
-
-
-#: attribution-profiler mode: when set, the tpu plugin's traced
-#: dispatches synchronize each pipeline stage so spans carry REAL
-#: h2d/kernel/d2h splits — at the cost of the transfer/compute overlap.
-#: Deliberately NOT implied by `tracer_enabled` (nor by the v2 sampling
-#: knobs): routine tracing must stay cheap enough to leave on, so only
-#: the bench attribution stage (or an operator who wants the waterfall)
-#: opts in.
-_profile_dispatch = False
-
-
-def profile_dispatch() -> bool:
-    return _profile_dispatch
-
-
-def set_profile_dispatch(on: bool) -> None:
-    global _profile_dispatch
-    _profile_dispatch = bool(on)
 
 
 def register_config(config) -> None:

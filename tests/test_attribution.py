@@ -1,8 +1,9 @@
 """Attribution-profiler tests (ISSUE 6): byte-exact copy-ledger
-accounting over a known pipeline, the event-loop sampling profiler
-(synthetic blocking callback surfaces in `profile dump`, hot-toggle via
-config, task-factory unwind), per-device offload utilization (fallback
-batches attributed to `host`), the bench attribution waterfall math
+accounting over a known pipeline, the loop account (a synthetic loop's
+time by label, collections, pauses, slices, nothing left installed,
+hot-toggle via config), per-device offload utilization (fallback
+batches attributed to `host`), the hand-offs of a staged dispatch as
+tags on `offload_batch`, the bench attribution waterfall math
 (buckets + residual sum to op_total), and the report→exporter contract
 (`ceph_device`-labeled families, every report-merged logger renderable).
 """
@@ -20,8 +21,9 @@ from ceph_tpu.mgr.daemon import DaemonStateIndex
 from ceph_tpu.mgr.exporter import render_metrics
 from ceph_tpu.msg.frames import Frame, Tag
 from ceph_tpu.tools.bench_driver import (ATTRIBUTION_BUCKETS,
-                                         attribution_from_spans)
-from ceph_tpu.utils import copytrack, loopprof
+                                         attribution_from_spans,
+                                         stage_attribution)
+from ceph_tpu.utils import copytrack, loopprof, tracer
 from ceph_tpu.utils.admin_socket import AdminSocket
 from ceph_tpu.utils.buffer import BufferList
 from ceph_tpu.utils.config import Config
@@ -118,41 +120,204 @@ def test_ledger_perf_counter_mirror_syncs_on_dump():
 
 
 # ---------------------------------------------------------------------------
-# event-loop sampling profiler
+# the loop account
 # ---------------------------------------------------------------------------
 
-def test_sampler_blocking_callback_shows_in_profile_dump():
+def _spin(seconds: float) -> None:
+    t_end = time.perf_counter() + seconds
+    while time.perf_counter() < t_end:
+        pass
+
+
+def _account(body) -> tuple[dict, float, list[dict]]:
+    """Run `body()` on a fresh loop under full tracing; returns the
+    account's microseconds by label, the wall microseconds it was armed
+    for, and the spans it closed."""
+    async def main():
+        tracer.reset()
+        tracer.enable(max_spans=65536)
+        loopprof.reset()
+        await asyncio.sleep(0)      # the arming turn ends unhooked
+        t0 = time.perf_counter()
+        await body()
+        await asyncio.sleep(0)
+        d = loopprof.dump()
+        wall = (time.perf_counter() - t0) * 1e6
+        tracer.disable()
+        return d["labels_us"], wall, tracer.collector().spans()
+    try:
+        return asyncio.run(main())
+    finally:
+        tracer.disable()
+        tracer.reset()
+
+
+def test_account_charges_a_synthetic_loop_to_its_labels():
+    """Self time by label: `ms_dispatch` stops being charged the moment
+    `osd_op` opens inside it, a bare callback falls to its code's
+    package, and the labels sum, with `idle`, to the wall clock."""
     async def body():
         loop = asyncio.get_running_loop()
-        assert loop.get_task_factory() is None
-        loopprof.install(sample_hz=400)
-        loopprof.reset()
-        # synthetic blocking callback: hot-spin on the loop thread in
-        # slices until the sampler has caught us in the act
-        t_end = time.perf_counter() + 3.0
-        while time.perf_counter() < t_end:
-            t_slice = time.perf_counter() + 0.05
-            while time.perf_counter() < t_slice:
+        with tracer.span("ms_dispatch"):
+            _spin(0.050)
+            with tracer.span("osd_op"):
+                _spin(0.030)
+                await asyncio.sleep(0.02)       # parked: idle
+        done = loop.create_future()
+
+        def bare():
+            time.sleep(0.040)
+            done.set_result(None)
+        loop.call_soon(bare)
+        await done
+
+    labels, wall, _spans = _account(body)
+    assert labels["msgr"] == pytest.approx(50_000, abs=5_000)
+    assert labels["osd"] == pytest.approx(30_000, abs=5_000)
+    assert labels["unattributed"] == pytest.approx(40_000, abs=5_000)
+    assert labels["idle"] == pytest.approx(20_000, abs=5_000)
+    assert sum(labels.values()) == pytest.approx(wall, rel=0.01)
+    assert set(labels) == set(loopprof.LABELS) | {"idle"}
+
+
+def test_account_takes_a_collection_out_of_the_label_it_interrupted():
+    import gc
+
+    async def body():
+        with tracer.span("osd_op"):
+            t0 = time.perf_counter()
+            gc.collect()
+            took.append((time.perf_counter() - t0) * 1e6)
+
+    junk = [[i] for i in range(300_000)]    # something to walk
+    took: list[float] = []
+    labels, _wall, _spans = _account(body)
+    assert junk and took[0] > 2_000
+    assert labels["gc"] == pytest.approx(took[0], rel=0.2)
+    assert labels["osd"] < 0.2 * took[0]
+
+
+def test_account_records_one_pause_with_its_four_facts():
+    """`time.sleep(0.7)` on the loop: exactly one `loop_pause`, whose
+    stack names the sleeping line, with CPU ~ 0 (blocked, not working),
+    no collection inside it and a punctual watchdog."""
+    from ceph_tpu.utils import flight
+
+    async def body():
+        await asyncio.sleep(0.25)   # a fresh sample of the CPU clock
+        loop = asyncio.get_running_loop()
+        done = loop.create_future()
+
+        def sleeper():
+            time.sleep(0.7)
+            done.set_result(None)
+        loop.call_soon(sleeper)
+        await done
+
+    cursor = flight.last_seq()
+    _labels, _wall, spans = _account(body)
+    pauses = [s for s in spans if s["name"] == "loop_pause"]
+    assert len(pauses) == 1
+    facts = pauses[0]["tags"]
+    assert facts["duration_s"] == pytest.approx(0.7, abs=0.05)
+    assert pauses[0]["duration_us"] == pytest.approx(700_000, rel=0.1)
+    assert "sleeper" in facts["callback"]
+    assert "test_attribution.py" in facts["stack"][0]
+    assert "in sleeper" in facts["stack"][0]
+    assert facts["cpu_s"] < 0.15
+    assert facts["gc_s"] < 0.05
+    assert facts["watchdog_late_s"] < 0.2
+    events = [e for e in flight.events_since(cursor)["events"]
+              if e["type"] == "loop_pause"]
+    assert len(events) == 1
+    assert events[0]["detail"]["duration_s"] == facts["duration_s"]
+
+
+def test_account_closes_slices_that_add_up_to_their_length():
+    async def body():
+        for _ in range(30):
+            _spin(0.005)
+            await asyncio.sleep(0.01)
+
+    _labels, _wall, spans = _account(body)
+    slices = [s for s in spans if s["name"] == "loop_slice"]
+    assert len(slices) >= 3
+    for s in slices:
+        tags = s["tags"]
+        by = sum(tags[k + "_us"] for k in loopprof.LABELS + ("idle",))
+        assert by == pytest.approx(s["duration_us"], rel=0.01)
+        assert tags["callbacks"] > 0
+        assert len(tags["lag_hist"]) == len(tags["lag_edges_ms"]) + 1
+        assert set(tags) == {k + "_us" for k in loopprof.LABELS + ("idle",)} \
+            | {"callbacks", "lag_hist", "lag_edges_ms"}
+        assert s["parent_id"] is None
+    assert sum(sum(s["tags"]["lag_hist"]) for s in slices) > 10
+
+
+def test_disarmed_account_leaves_nothing_installed():
+    """With the tracer disabled `Handle._run` is asyncio's own,
+    `gc.callbacks` is as found, no loopprof thread lives, and
+    `tracer.span()` is the shared no-op."""
+    import gc
+    import threading
+    run_before = asyncio.events.Handle._run
+    gc_before = list(gc.callbacks)
+
+    async def main():
+        loop = asyncio.get_running_loop()
+        tracer.enable()
+        assert loop in loopprof.installed_loops()
+        assert asyncio.events.Handle._run is not run_before
+        assert len(gc.callbacks) == len(gc_before) + 1
+        assert any(t.name == "loopprof-watchdog"
+                   for t in threading.enumerate())
+        select = loop._selector.select
+        with tracer.span("osd_op"):
+            await asyncio.sleep(0.02)
+        tracer.disable()
+        assert loop._selector.select is not select
+        assert loopprof.installed_loops() == []
+
+    try:
+        asyncio.run(main())
+    finally:
+        tracer.disable()
+        tracer.reset()
+    assert asyncio.events.Handle._run is run_before
+    assert gc.callbacks == gc_before
+    assert not any(t.name.startswith("loopprof")
+                   for t in threading.enumerate())
+    assert tracer.span("osd_op") is tracer.span("pg_op")    # the no-op
+
+
+def test_tracer_enable_without_a_loop_arms_at_the_first_span():
+    """`tracer.enable()` with no running loop installs nothing; the
+    first mapped span entered on a loop arms that loop, and a loop
+    left armed is pruned when it closes."""
+    import threading
+    tracer.enable()
+    try:
+        assert loopprof.installed_loops() == []
+        assert asyncio.events.Handle._run is loopprof._ORIG_RUN
+
+        async def main():
+            with tracer.span("bench_open"):     # unmapped: moves no label
                 pass
-            if loopprof.dump()["busy_samples"] >= 5:
-                break
-        d = loopprof.dump(top_n=20)
-        loopprof.uninstall()
-        # factory unwound with the loop (the conftest leak gate asserts
-        # installed_loops() empties; this asserts the factory half)
-        assert loop.get_task_factory() is None
-        return d
-
-    d = asyncio.run(body())
-    assert d["busy_samples"] >= 5
-    assert 0.0 < d["loop_busy_fraction"] <= 1.0
-    assert d["sample_hz"] == 400.0
-    sites = [s["site"] for s in d["top_stalls"]]
-    assert any("test_attribution.py" in s for s in sites), sites
-    assert loopprof.installed_loops() == []
+            assert loopprof.installed_loops() == []
+            with tracer.span("rados_op"):
+                assert loopprof.installed_loops() == \
+                    [asyncio.get_running_loop()]
+        asyncio.run(main())                     # left armed, loop closed
+        assert loopprof.installed_loops() == []
+    finally:
+        tracer.disable()
+        tracer.reset()
+    assert asyncio.events.Handle._run is loopprof._ORIG_RUN
+    assert not any(t.name.startswith("loopprof")
+                   for t in threading.enumerate())
 
 
-def test_sampler_hot_toggle_via_config_and_reset():
+def test_account_hot_toggle_via_config_and_reset():
     cfg = Config()
     loopprof.register_config(cfg)
     assert cfg.get("profiler_enabled") is False
@@ -163,22 +328,27 @@ def test_sampler_hot_toggle_via_config_and_reset():
         assert loop not in loopprof.installed_loops()
         cfg.set("profiler_enabled", True)    # observer arms live
         assert loop in loopprof.installed_loops()
+        tracer.enable()                      # a second owner...
+        tracer.disable()                     # ...leaves the operator's
+        assert loop in loopprof.installed_loops()
+        await asyncio.sleep(0.02)
         cfg.set("profiler_enabled", False)   # ... and disarms live
         assert loop not in loopprof.installed_loops()
 
     asyncio.run(body())
+    assert loopprof.dump()["wall_us"] > 0
     cleared = loopprof.reset()
-    assert cleared["cleared_samples"] >= 0
-    assert loopprof.dump()["samples"] == 0
+    assert cleared["cleared_wall_us"] > 0
+    assert loopprof.dump()["wall_us"] == 0
 
 
 def test_profile_dump_admin_socket_command(tmp_path):
     asok = AdminSocket(str(tmp_path / "t.asok"))
     out = asok.execute({"prefix": "profile dump"})["result"]
-    assert set(out) >= {"enabled", "loop_busy_fraction", "samples",
-                        "executor_queue_depth", "top_stalls"}
+    assert set(out) >= {"enabled", "loop_busy_fraction", "labels_us",
+                        "wall_us", "shards", "lag_hist"}
     assert asok.execute({"prefix": "profile reset"})[
-        "result"]["cleared_samples"] >= 0
+        "result"]["cleared_wall_us"] >= 0
 
 
 # ---------------------------------------------------------------------------
@@ -221,6 +391,47 @@ def test_device_batches_and_fallback_attribution():
     asyncio.run(body())
 
 
+def test_offload_batch_hops_sum_to_the_span():
+    """The six hops `_device_call` and `_run_batch` stamp on the batch
+    span from where the work happens, without serializing anything,
+    account for its duration; the two before it are tags as well."""
+    from ceph_tpu.offload.service import _HOPS
+    from ceph_tpu.osd import ec_util
+
+    async def body():
+        impl = _impl()
+        svc = offload.get_service()
+        stripes = np.zeros((64, 4, 4096), dtype=np.uint8)
+        await svc.encode(impl, stripes)             # compile outside
+        tracer.reset()
+        tracer.enable(max_spans=4096)
+        for _ in range(5):
+            await svc.encode(impl, stripes)
+        sinfo = ec_util.StripeInfo(4, 4 * 4096)
+        await ec_util.encode_async(sinfo, impl, bytes(4 * 4 * 4096),
+                                   service=svc)
+        tracer.disable()
+        return tracer.collector().spans()
+
+    try:
+        spans = asyncio.run(body())
+    finally:
+        tracer.disable()
+        tracer.reset()
+    batches = [s for s in spans if s["name"] == "offload_batch"]
+    assert len(batches) == 6
+    for s in batches:
+        tags = s["tags"]
+        inside = sum(tags[h] for h in _HOPS + ("scatter_us",))
+        assert inside == pytest.approx(s["duration_us"], rel=0.02)
+        assert all(tags[h] >= 0 for h in _HOPS + ("scatter_us",))
+        assert tags["sem_wait_us"] >= 0 and tags["stack_us"] >= 0
+        assert tags["device"].startswith("cpu:")
+    enc = [s for s in spans if s["name"] == "ec_encode"]
+    assert len(enc) == 1 and enc[0]["tags"]["assemble_us"] >= 0
+    assert enc[0]["tags"]["assemble_us"] < enc[0]["duration_us"]
+
+
 # ---------------------------------------------------------------------------
 # bench attribution waterfall math
 # ---------------------------------------------------------------------------
@@ -233,9 +444,9 @@ def _span(trace, name, dur, **tags):
 def test_attribution_buckets_sum_to_op_total():
     spans = [
         _span("t1", "osd_op", 1000.0, queue_wait_us=200.0),
-        _span("t1", "offload_batch", 300.0, copy_us=50.0),
-        _span("t1", "tpu_encode_dispatch", 400.0, h2d_us=100.0,
-              kernel_us=250.0, d2h_us=50.0),
+        _span("t1", "offload_batch", 700.0, stack_us=50.0,
+              pool_wait_us=200.0, h2d_submit_us=100.0, launch_us=250.0,
+              result_wait_us=50.0, resume_us=80.0, scatter_us=20.0),
         _span("t1", "store_commit", 150.0),
         _span("t1", "store_commit", 120.0),     # parallel shard: max wins
         _span("t2", "offload_batch", 10.0),     # orphan trace: ignored
@@ -256,6 +467,26 @@ def test_attribution_buckets_sum_to_op_total():
     assert att["attributed_fraction"] == pytest.approx(800.0 / 1200.0,
                                                        abs=1e-4)
     assert sum(att["bucket_pct"].values()) == pytest.approx(100.0, abs=0.5)
+
+
+def test_stage_attribution_runs_small_on_the_cpu_backend():
+    """The operator's stage end to end: the account's labels, the hop
+    tags as h2d/kernel/d2h buckets, shards, and nothing left armed."""
+    out = stage_attribution(seconds=0.5, ab_seconds=0.2, ab_reps=1)
+    att = out["attribution"]
+    assert att["ops"] > 0
+    labels = att["loop_labels_us"]
+    assert set(labels) == set(loopprof.LABELS) | {loopprof.IDLE}
+    assert labels["msgr"] > 0 and labels["osd"] > 0
+    busy = sum(labels.values()) - labels[loopprof.IDLE]
+    assert att["loop_busy_fraction"] == pytest.approx(
+        busy / sum(labels.values()), abs=1e-3)
+    b = att["buckets_us"]
+    assert b["h2d"] > 0 and b["kernel"] > 0 and b["d2h"] >= 0
+    assert sum(b.values()) == pytest.approx(att["op_total_us"], rel=0.10)
+    assert att["per_shard"] and att["reactor_shards"] >= 1
+    assert set(out["tracing_ab_mb_s"]) == {"off", "sampled_tail", "full"}
+    assert not tracer.enabled() and not loopprof.installed_loops()
 
 
 def test_attribution_empty_and_multi_op():

@@ -247,7 +247,7 @@ def test_worker_crash_reap_markdown_respawn():
 # ---------------------------------------------------------------------------
 
 def test_cross_process_profile_stats_use_pool_wide_shard_labels():
-    """Each worker samples its own loop but labels it with the
+    """Each worker accounts its own loop but labels it with the
     POOL-WIDE shard index (reactor.adopt_worker_shard), so the parent's
     merge is keyed shard0/shard1/shard2 — not three pid-local 'loop0's
     — and the cross-process busy skew is computable."""
@@ -265,16 +265,18 @@ def test_cross_process_profile_stats_use_pool_wide_shard_labels():
                 io = client.ioctx("p")
                 for i in range(8):
                     await io.write_full(f"o{i}", b"z" * 8192)
-                await asyncio.sleep(0.3)    # sampler ticks everywhere
+                await asyncio.sleep(0.3)    # every loop turns
                 prof = await pool.profile_stats()
                 shards = prof["shards"]
                 assert {"shard0", "shard1", "shard2"} <= set(shards)
-                assert all(d["samples"] > 0 for d in shards.values())
+                assert all(d["wall_us"] > 0 for d in shards.values())
+                assert all(0 < d["busy_us"] <= d["wall_us"]
+                           for d in shards.values())
                 assert 0.0 <= prof["shard_busy_skew"] <= 1.0
                 # merge helper: same-label parts sum, fractions recompute
                 merged = loopprof.merge_shard_stats(
-                    {"shard1": {"samples": 10, "busy_samples": 5}},
-                    {"shard1": {"samples": 10, "busy_samples": 0}})
+                    {"shard1": {"wall_us": 10.0, "busy_us": 5.0}},
+                    {"shard1": {"wall_us": 10.0, "busy_us": 0.0}})
                 assert merged["shard1"]["loop_busy_fraction"] == 0.25
                 await pool.config_set("profiler_enabled", False)
             finally:

@@ -135,17 +135,18 @@ def test_promoted_trace_routes_later_spans_directly():
 
 def test_sampling_knobs_hot_toggle_via_config():
     """`config set tracer_sample_rate 0.5` applies live through the
-    observer — and never flips the serialized profiled-dispatch mode."""
+    observer — and never arms the loop account, which is full tracing's."""
+    from ceph_tpu.utils import loopprof
     from ceph_tpu.utils.config import Config
     cfg = Config()
     tracer.register_config(cfg)
     assert not tracer.active()
     cfg.set("tracer_sample_rate", 1.0)
     assert tracer.active() and tracer.sampling()["sample_rate"] == 1.0
-    assert not tracer.profile_dispatch()
+    assert not loopprof._by_tracer and not loopprof.installed_loops()
     cfg.set("tracer_tail_slow_ms", 25.0)
     assert tracer.sampling()["tail_slow_ms"] == 25.0
-    assert not tracer.profile_dispatch()
+    assert not loopprof._by_tracer and not loopprof.installed_loops()
     cfg.set("tracer_sample_rate", 0.0)
     cfg.set("tracer_tail_slow_ms", 0.0)
     assert not tracer.active()
@@ -344,8 +345,10 @@ def test_critical_path_stages_sum_exactly_to_total():
         _mkspan("t1", "o", "r", "osd_op", 0.001, 8_000,
                 {"queue_wait_us": 1_500.0}),
         _mkspan("t1", "e", "o", "ec_encode", 0.002, 3_000),
-        _mkspan("t1", "d", "e", "tpu_encode_dispatch", 0.003, 2_000,
-                {"h2d_us": 400.0, "kernel_us": 1_000.0, "d2h_us": 300.0}),
+        _mkspan("t1", "d", "e", "offload_batch", 0.003, 2_000,
+                {"pool_wait_us": 200.0, "h2d_submit_us": 400.0,
+                 "launch_us": 1_000.0, "result_wait_us": 300.0,
+                 "resume_us": 90.0, "scatter_us": 10.0}),
         _mkspan("t1", "c", "o", "store_commit", 0.004, 2_500),
     ]
     cp = critpath.critical_path(spans)
