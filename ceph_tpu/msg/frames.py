@@ -232,12 +232,15 @@ class Frame:
 
     @classmethod
     async def read(cls, reader) -> "Frame":
-        """Read one frame from an asyncio StreamReader. The preamble is
-        read and validated separately from the body, and segments come
-        back as MEMORYVIEWS over the single body buffer — the receive
-        side never re-slices payload bytes into fresh objects (the
-        frame_rx copy the PR-6 ledger indicted; it now meters as
-        referenced, not copied)."""
+        """Read one frame from anything with `await readexactly(n)`
+        (the messenger's transport.Endpoint; asyncio's stream reader in
+        tests). The preamble is read and validated separately from the
+        body, which is asked for in one read at its whole length — the
+        endpoint has the kernel fill that buffer in place — and
+        segments come back as read-only MEMORYVIEWS over it: the
+        receive side never re-slices payload bytes into fresh objects
+        (the frame_rx copy the PR-6 ledger indicted; it meters as
+        referenced, not copied), and the buffer is never reused."""
         fixed = await reader.readexactly(_PRE_FIXED.size)
         magic, tag, nseg = _PRE_FIXED.unpack(fixed)
         if magic != MAGIC:
@@ -257,7 +260,8 @@ class Frame:
             tag = Tag(tag)
         except ValueError as e:
             raise FrameError(f"unknown tag {tag}") from e
-        return cls(tag, cls._parse_segments(seg_lens, memoryview(body)))
+        return cls(tag, cls._parse_segments(
+            seg_lens, memoryview(body).toreadonly()))
 
     @classmethod
     def _parse_segments(cls, seg_lens: list[int],
@@ -273,10 +277,11 @@ class Frame:
         if _frame_native is not None:
             base = body.obj if isinstance(body, memoryview) else None
             # the streamed-read path hands a view over EXACTLY the body
-            # bytes: pass the bytes object itself (ctypes converts it
+            # (bytes out of the spill, the bytearray a large read
+            # filled): pass the object itself (ctypes converts either
             # without the numpy fallback the sliced decode path needs)
-            buf = base if type(base) is bytes and len(base) == want \
-                else body[:want]
+            buf = base if type(base) in (bytes, bytearray) \
+                and len(base) == want else body[:want]
             bad = _frame_native.verify_body(buf, seg_lens)
             if bad >= 0:
                 raise FrameError("segment crc mismatch")
@@ -336,8 +341,8 @@ class Frame:
             tag = Tag(tag)
         except ValueError as e:
             raise FrameError(f"unknown tag {tag}") from e
-        return cls(tag, cls._parse_segments(seg_lens,
-                                            memoryview(blob)[off:]))
+        return cls(tag, cls._parse_segments(
+            seg_lens, memoryview(blob).toreadonly()[off:]))
 
 
 class Onwire:

@@ -22,6 +22,13 @@ are loop-bound objects in the loop-affinity sense and must never be
 driven from another shard without a threadsafe handoff);
 coroutine-per-connection instead of a hand-rolled state machine; the
 banner/HELLO exchange carries JSON instead of dencoded structs.
+Transport: every Connection, initiated or accepted, rides one
+msg/transport.py Endpoint, an asyncio.BufferedProtocol the messenger
+owns (no asyncio stream pair): small reads come out of one fixed spill
+buffer per connection, and a frame's body is received by the kernel
+straight into the buffer its segments stay views of — one copy per
+payload byte, counted in the msgr logger (rx_direct_bytes,
+rx_spill_bytes, rx_recvs).
 Auth: `none` by default, cephx-lite mutual HMAC when
 an auth_key is set; on top of that the handshake can negotiate AES-GCM
 secure mode and/or zlib on-wire compression (frames.Onwire), with the
@@ -43,10 +50,10 @@ from typing import Awaitable, Callable
 from ceph_tpu.msg import messages as _messages
 from ceph_tpu.msg.frames import BANNER, Frame, FrameError, Tag, Onwire
 from ceph_tpu.msg.messages import Message, _json_seg
+from ceph_tpu.msg.transport import Endpoint
 from ceph_tpu.qa import faultinject, interleave
 from ceph_tpu.utils import tracer
-from ceph_tpu.utils.async_util import being_cancelled, drain_all, reap, \
-    reap_all
+from ceph_tpu.utils.async_util import drain_all, reap, reap_all
 from ceph_tpu.utils.dout import dout
 from ceph_tpu.utils.perf_counters import (TYPE_HISTOGRAM,
                                           PerfCountersCollection)
@@ -106,6 +113,18 @@ def msgr_perf():
                            "instead of their own frame")
         pc.add("batch_ops", type=TYPE_HISTOGRAM,
                description="messages coalesced per batch envelope")
+        pc.add("rx_direct_bytes",
+               description="bytes the kernel wrote straight into a "
+                           "frame's own body buffer (recv_into, no "
+                           "further copy)")
+        pc.add("rx_spill_bytes",
+               description="bytes received into a connection's spill "
+                           "buffer and copied out of it (small reads, "
+                           "and the head of a body that arrived with "
+                           "its preamble)")
+        pc.add("rx_recvs",
+               description="recv_into calls that returned data "
+                           "(buffer_updated callbacks)")
         return pc
 
 
@@ -326,16 +345,7 @@ class Connection:
         writer, self._reader, self._writer = self._writer, None, None
         if writer is not None:
             writer.close()
-            try:
-                await writer.wait_closed()
-            except asyncio.CancelledError:
-                # asyncio.streams can cancel the close waiter internally
-                # when the transport dies mid-close; only propagate when
-                # OUR task is actually being cancelled
-                if being_cancelled():
-                    raise
-            except Exception:
-                pass
+            await writer.wait_closed()
 
     def _attach(self, reader, writer) -> None:
         self._reader, self._writer = reader, writer
@@ -356,11 +366,13 @@ class Connection:
 
     async def _open_transport(self, reconnect: bool) -> None:
         host, port = self.peer_addr
-        reader, writer = await asyncio.open_connection(host, port)
+        perf = self.messenger.perf
+        _, ep = await asyncio.get_running_loop().create_connection(
+            lambda: Endpoint(perf), host, port)
         try:
-            await self._handshake(reader, writer, reconnect)
+            await self._handshake(ep, ep, reconnect)
         except BaseException:
-            writer.close()
+            ep.close()
             raise
 
     async def _handshake(self, reader, writer, reconnect: bool) -> None:
@@ -856,6 +868,8 @@ class Messenger:
         # during teardown is destroyed while pending and leaks the
         # connection's dispatch loop (the BENCH_r05 tail spam)
         self._bg_tasks: set[asyncio.Task] = set()
+        # handshakes of accepted endpoints not attached to a session yet
+        self._accepting: set[asyncio.Task] = set()
         self._closed = False
 
     def _spawn_bg(self, coro) -> None:
@@ -869,7 +883,8 @@ class Messenger:
     # -- server side ---------------------------------------------------------
 
     async def bind(self, host: str = "127.0.0.1", port: int = 0) -> tuple[str, int]:
-        self._server = await asyncio.start_server(self._on_accept, host, port)
+        self._server = await asyncio.get_running_loop().create_server(
+            lambda: Endpoint(self.perf, self._accept), host, port)
         self.my_addr = self._server.sockets[0].getsockname()[:2]
         dout("ms", 10, f"{self.entity_name} listening on {self.my_addr}")
         return self.my_addr
@@ -882,6 +897,25 @@ class Messenger:
                 "secure": (bool(want.get("secure")) and self.secure
                            and self.auth_key is not None
                            and bool(info.get("auth_nonce")))}
+
+    def _accept(self, ep: Endpoint) -> None:
+        """An accepted endpoint's `connection_made`: run its handshake
+        as a task of ours. One that fails or is reaped at shutdown
+        closes the socket."""
+        task = asyncio.get_running_loop().create_task(
+            self._on_accept(ep, ep))
+        self._accepting.add(task)
+
+        def _done(t: asyncio.Task) -> None:
+            self._accepting.discard(t)
+            if t.cancelled():
+                ep.close()
+            elif t.exception() is not None:
+                dout("ms", 5, f"{self.entity_name} accept failed: "
+                              f"{t.exception()!r}")
+                ep.close()
+
+        task.add_done_callback(_done)
 
     async def _on_accept(self, reader, writer) -> None:
         try:
@@ -1065,6 +1099,7 @@ class Messenger:
         self._closed = True
         if self._server is not None:
             self._server.close()
+        await reap_all(list(self._accepting))
         # connections first: since 3.12 Server.wait_closed() waits for all
         # accepted transports, which only die when we close them
         for conn in list(self._conns.values()) + list(self._accepted.values()) \

@@ -1,0 +1,260 @@
+"""The messenger's socket endpoint: an `asyncio.BufferedProtocol` that
+lets the kernel write a frame's body straight into the buffer the frame
+keeps.
+
+A read of `n` bytes goes one of two ways, chosen from `n` against the
+spill's size and from nothing else:
+
+  * small (`n <= SPILL_SIZE`: the preamble, lengths and crc, ACK and
+    keepalive frames, the handshake, control messages and small ops)
+    is served from one fixed spill buffer per connection. Nothing is
+    allocated but the bytes handed back.
+  * large (a data segment's body) allocates the destination once, at
+    its length, copies across whatever head of it already sits in the
+    spill, and from then on `get_buffer` hands the kernel the unfilled
+    tail: one `recv_into` takes whatever the socket holds, up to the
+    whole rest of the body. The buffer is never resized, pooled or
+    reused; it lives as long as a view of it does.
+
+What a recv lands in the spill in front of a large body is copied a
+second time, so how much of the spill the kernel is offered follows
+the traffic: on a new connection and after a large body only `NARROW`
+bytes, enough for the next preamble and little of what follows it;
+once small reads have taken that much with no large one between them,
+all of it, so that a run of small frames comes in one recv as it did
+through a stream reader. Reading is paused only when the spill is full
+of unread bytes, never in the middle of a body.
+
+The write side is the transport's own buffer with `drain()` on its
+high-water mark, as asyncio's stream writer had it. One object is both
+ends: the messenger keeps it as reader and writer.
+"""
+from __future__ import annotations
+
+import asyncio
+
+#: bytes of spill per connection, and the size above which a read gets
+#: a buffer of its own
+SPILL_SIZE = 65536
+#: the part of it offered between large bodies: a 512 KiB sub-op whose
+#: head arrives with its preamble has at most 0.8% of itself copied
+NARROW = SPILL_SIZE // 16
+
+
+class Endpoint(asyncio.BufferedProtocol):
+    """One TCP transport's two ends. Read side: `await readexactly(n)`.
+    Write side: `write`, `writelines`, `drain`, `close`, `wait_closed`,
+    `get_extra_info`, `.transport`."""
+
+    def __init__(self, perf, on_connect=None):
+        self._perf = perf
+        self._on_connect = on_connect       # acceptor: called once made
+        self.transport: asyncio.Transport | None = None
+        self._loop: asyncio.AbstractEventLoop | None = None
+        # the endpoint's own window on its own spill, the one buffer
+        # here that is reused: nothing leaves it but bytes() copies
+        # radoslint: disable-next=view-escape
+        self._spill_mv = memoryview(bytearray(SPILL_SIZE))
+        self._rpos = 0              # spill[_rpos:_wpos] is unread
+        self._wpos = 0
+        self._dest: memoryview | None = None    # body being filled
+        self._dest_pos = 0
+        self._need = 0              # spill bytes the parked read wants
+        self._small_run = 0         # bytes of small reads since a body
+        self._read_waiter: asyncio.Future | None = None
+        self._reading_paused = False
+        self._eof = False
+        self._exc: BaseException | None = None
+        self._lost = False
+        self._writing_paused = False
+        self._drain_waiters: list[asyncio.Future] = []
+        self._closed: asyncio.Future | None = None
+
+    # -- protocol callbacks --------------------------------------------------
+
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+        self._loop = asyncio.get_running_loop()
+        self._closed = self._loop.create_future()
+        if self._on_connect is not None:
+            self._on_connect(self)
+
+    def get_buffer(self, sizehint: int) -> memoryview:
+        dest = self._dest
+        if dest is not None:
+            return dest[self._dest_pos:]
+        if self._wpos == SPILL_SIZE:
+            # the head is consumed: a spill full of unread bytes has
+            # paused reading and is not asked
+            self._compact()
+        end = SPILL_SIZE
+        if self._small_run < NARROW:
+            end = self._rpos + max(NARROW, self._need)
+        if not self._wpos < end <= SPILL_SIZE:
+            # a window already full (its reader woken, and not here
+            # yet) or past the end gives way to the room there is
+            end = SPILL_SIZE
+        return self._spill_mv[self._wpos:end]
+
+    def buffer_updated(self, nbytes: int) -> None:
+        perf = self._perf
+        perf.inc("rx_recvs")
+        dest = self._dest
+        if dest is not None:
+            perf.inc("rx_direct_bytes", nbytes)
+            self._dest_pos += nbytes
+            if self._dest_pos == len(dest):
+                # back to the spill before the next get_buffer: an
+                # empty view would be fatal to the transport
+                self._dest = None
+                self._wake_reader()
+            return
+        perf.inc("rx_spill_bytes", nbytes)
+        self._wpos += nbytes
+        have = self._wpos - self._rpos
+        if have >= self._need:
+            self._wake_reader()
+        if have == SPILL_SIZE:
+            # full, and whoever reads has all it asked for: no recv
+            # until it is back for more
+            self._reading_paused = True
+            self.transport.pause_reading()
+
+    def eof_received(self) -> bool:
+        self._eof = True
+        self._wake_reader()
+        return True     # the write side stays ours to close
+
+    def connection_lost(self, exc) -> None:
+        self._lost = True
+        self._eof = True
+        if exc is not None and self._exc is None:
+            self._exc = exc
+        self._wake_reader()
+        self._wake_drainers()
+        if not self._closed.done():
+            self._closed.set_result(None)
+
+    def pause_writing(self) -> None:
+        self._writing_paused = True
+
+    def resume_writing(self) -> None:
+        self._writing_paused = False
+        self._wake_drainers()
+
+    def _wake_drainers(self) -> None:
+        for w in self._drain_waiters:
+            if not w.done():
+                w.set_result(None)
+        self._drain_waiters.clear()
+
+    # -- read side -----------------------------------------------------------
+
+    def _compact(self) -> None:
+        have = self._wpos - self._rpos
+        self._spill_mv[:have] = self._spill_mv[self._rpos:self._wpos]
+        self._rpos, self._wpos = 0, have
+
+    def _wake_reader(self) -> None:
+        w = self._read_waiter
+        if w is not None:
+            self._read_waiter = None
+            if not w.done():
+                w.set_result(None)
+
+    async def _wait_for_data(self) -> None:
+        if self._read_waiter is not None:
+            raise RuntimeError("readexactly() called while another "
+                               "read waits on this endpoint")
+        if self._reading_paused:
+            self._reading_paused = False
+            self.transport.resume_reading()
+        self._read_waiter = self._loop.create_future()
+        try:
+            await self._read_waiter
+        finally:
+            self._read_waiter = None
+
+    def _read_failed(self, partial: bytes, n: int) -> BaseException:
+        if self._exc is not None:
+            return self._exc
+        return asyncio.IncompleteReadError(partial, n)
+
+    async def readexactly(self, n: int) -> bytes | bytearray:
+        """Exactly `n` bytes: `bytes` out of the spill for a small read,
+        a fresh `bytearray` the kernel filled for a large one. EOF or a
+        lost connection short of `n` raises as asyncio's streams do."""
+        if n > SPILL_SIZE:
+            return await self._read_body(n)
+        if self._rpos + n > SPILL_SIZE:
+            self._compact()
+        self._need = n
+        while self._wpos - self._rpos < n:
+            if self._eof:
+                raise self._read_failed(
+                    bytes(self._spill_mv[self._rpos:self._wpos]), n)
+            await self._wait_for_data()
+        if self._small_run < NARROW:
+            self._small_run += n
+        start = self._rpos
+        out = bytes(self._spill_mv[start:start + n])
+        if start + n == self._wpos:
+            self._rpos = self._wpos = 0
+        else:
+            self._rpos = start + n
+        return out
+
+    async def _read_body(self, n: int) -> bytearray:
+        buf = bytearray(n)
+        have = self._wpos - self._rpos      # < n: the spill is smaller
+        if have:
+            buf[:have] = self._spill_mv[self._rpos:self._wpos]
+            self._rpos = self._wpos = 0
+        self._small_run = 0
+        self._dest_pos = have
+        # the kernel's window on `buf` while it fills, dropped when it
+        # is full; `buf` itself is never reused
+        # radoslint: disable-next=view-escape
+        self._dest = memoryview(buf)
+        try:
+            while self._dest is not None:
+                if self._eof:
+                    raise self._read_failed(
+                        bytes(buf[:self._dest_pos]), n)
+                await self._wait_for_data()
+        finally:
+            # a cancelled or failed read gives the socket back to the
+            # spill; the bytes it had taken are lost with the transport
+            self._dest = None
+        return buf
+
+    # -- write side ----------------------------------------------------------
+
+    def write(self, data) -> None:
+        self.transport.write(data)
+
+    def writelines(self, parts) -> None:
+        self.transport.writelines(parts)
+
+    async def drain(self) -> None:
+        if not self._lost and self.transport.is_closing():
+            # let connection_lost() run, so a write loop on a closing
+            # transport faults instead of spinning
+            await asyncio.sleep(0)
+        if not self._lost and self._writing_paused:
+            w = self._loop.create_future()
+            self._drain_waiters.append(w)
+            await w
+        if self._lost:
+            raise self._exc or ConnectionResetError("connection lost")
+
+    def close(self) -> None:
+        self.transport.close()
+
+    async def wait_closed(self) -> None:
+        # shielded: a waiter that is cancelled must not cancel the
+        # future the others wait on
+        await asyncio.shield(self._closed)
+
+    def get_extra_info(self, name: str, default=None):
+        return self.transport.get_extra_info(name, default)

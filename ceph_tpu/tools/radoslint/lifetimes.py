@@ -2,9 +2,15 @@
 static half.
 
 PR 9/12 bought their speed by replacing copies with MEMORYVIEWS over
-buffers that get *recycled* — `Frame.read` segments window the receive
-body, offload staging pages are reused warm across batches, bufferlist
-fragments alias caller arrays — and PR 9's ShardPool put mutable
+buffers that outlive or get *recycled* under them — offload staging
+pages are reused warm across batches, bufferlist fragments alias
+caller arrays, and `Frame.read` segments window the receive body. A
+frame's body itself is never recycled: msg/transport.py allocates it
+once, at the body's length, has the kernel fill it, and it lives as
+long as any segment view does. What IS reused on the receive side is
+each connection's small spill buffer, and nothing leaves that but
+`bytes()` copies; the `segments` rule stays, so that a body pool
+could never be introduced silently — and PR 9's ShardPool put mutable
 service state (`shared()` objects, the offload device topology) in
 reach of N OS threads at once. Both disciplines were hand-audited;
 these rules make the audit mechanical, the way `loop-affinity` froze
