@@ -33,6 +33,18 @@ class ErasureCodeError(Exception):
     """Raised for profile/argument errors (stand-in for -EINVAL etc.)."""
 
 
+def decode_batch_tags(avail_ids: Sequence[int],
+                      want_ids: Sequence[int]) -> dict:
+    """How a span names one batched reconstruction, in one place: the
+    offload service's `offload_batch` span of a `dec` bucket and the
+    plugin's `tpu_decode_dispatch` span carry exactly these. `r` is the
+    number of chunks rebuilt; `pattern` is the survivors in stacking
+    order, `>`, the chunks wanted."""
+    return {"kind": "dec", "r": len(want_ids),
+            "pattern": ",".join(map(str, avail_ids)) + ">"
+            + ",".join(map(str, want_ids))}
+
+
 class ErasureCodeInterface:
     """Abstract systematic erasure-code API (ErasureCodeInterface.h:170)."""
 

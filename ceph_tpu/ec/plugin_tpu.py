@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ceph_tpu.ec import gf256
-from ceph_tpu.ec.interface import ErasureCodeError
+from ceph_tpu.ec.interface import ErasureCodeError, decode_batch_tags
 from ceph_tpu.ec.plugin_jerasure import ErasureCodeJerasure
 from ceph_tpu.ec.registry import (ERASURE_CODE_VERSION, ErasureCodePlugin,
                                   ErasureCodePluginRegistry)
@@ -108,10 +108,11 @@ class ErasureCodeTpu(ErasureCodeJerasure):
                     # which mesh slot this batch landed on (the offload
                     # service's device-affine routing made the choice)
                     sp.set_tag("device", _device_of(data))
-            if device_resident:
-                return self._encoder.apply_batch_device(data)
-            return self._encode_host_pipelined(
-                np.ascontiguousarray(data, dtype=np.uint8))
+            with jax.profiler.TraceAnnotation(f"rs_encode_r{self.m}"):
+                if device_resident:
+                    return self._encoder.apply_batch_device(data)
+                return self._encode_host_pipelined(
+                    np.ascontiguousarray(data, dtype=np.uint8))
 
     def _encode_host_pipelined(self, data: np.ndarray) -> np.ndarray:
         b = data.shape[0]
@@ -133,9 +134,24 @@ class ErasureCodeTpu(ErasureCodeJerasure):
     def decode_stripes(self, avail_ids: tuple[int, ...], want_ids: tuple[int, ...],
                        chunks: np.ndarray | jax.Array) -> np.ndarray | jax.Array:
         """Batched reconstruction: `chunks` is (batch, k, S) holding the
-        available chunks stacked in `avail_ids` order; returns the
-        reconstructed `want_ids` chunks as (batch, len(want), S)."""
+        available chunks stacked in `avail_ids` order; the reconstructed
+        `want_ids` chunks come back in that order.
+
+        The recovery matrix is padded with zero rows to m, so a decode
+        runs the ENCODE program of its batch shape (the bitmatrix is a
+        run-time argument of one program per (batch, r, S)): whatever
+        has written stripes of a shape has compiled what reads them
+        degraded, for any number of lost chunks up to m, and no erasure
+        pattern compiles anything. The price is m - r idle output rows.
+        numpy in => numpy (batch, len(want), S) out. Device array in =>
+        device array (batch, max(len(want), m), S) out, the rows past
+        len(want) zero: slicing them off on the device would be one more
+        program a shape, so the caller slices after its D2H."""
+        r = len(want_ids)
         R = rs_codec.recovery_matrix(self.coding_matrix, avail_ids, want_ids)
+        if r < self.m:
+            R = np.concatenate(
+                [R, np.zeros((self.m - r, self.k), dtype=np.uint8)])
         codec = rs_codec.MatrixCodec.get(R)
         device_resident = isinstance(chunks, jax.Array)
         with tracer.span("tpu_decode_dispatch") as sp:
@@ -143,17 +159,20 @@ class ErasureCodeTpu(ErasureCodeJerasure):
                 sp.set_tag("mode", "device" if device_resident else "host")
                 sp.set_tag("batch", int(chunks.shape[0]))
                 sp.set_tag("bytes", int(chunks.size))
-                sp.set_tag("want", list(want_ids))
+                sp.tags.update(decode_batch_tags(avail_ids, want_ids))
                 if device_resident:
                     sp.set_tag("device", _device_of(chunks))
-            if device_resident:
-                return codec.apply_batch_device(chunks)
-            chunks = np.ascontiguousarray(chunks, dtype=np.uint8)
-            dev = jnp.asarray(chunks)
-            out = np.asarray(codec.apply_batch_device(dev))
+            # the device trace shows one program for both directions;
+            # this names the launch on the host's line, at its true r
+            with jax.profiler.TraceAnnotation(f"rs_decode_r{r}"):
+                if device_resident:
+                    return codec.apply_batch_device(chunks)
+                chunks = np.ascontiguousarray(chunks, dtype=np.uint8)
+                dev = jnp.asarray(chunks)
+                out = np.asarray(codec.apply_batch_device(dev))
             copytrack.copied("h2d", int(chunks.nbytes))
             copytrack.copied("d2h", int(out.nbytes))
-            return out
+            return out[:, :r]
 
 
 class ErasureCodePluginTpu(ErasureCodePlugin):

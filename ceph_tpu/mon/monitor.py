@@ -75,7 +75,7 @@ class OSDMonitor:
     """The OSDMap service (src/mon/OSDMonitor.cc essentials)."""
 
     MIN_DOWN_REPORTERS = 2      # mon_osd_min_down_reporters (OSDMonitor.cc:2868)
-    DOWN_OUT_INTERVAL = 30.0
+    DOWN_OUT_INTERVAL = 600.0   # mon_osd_down_out_interval (upstream's default)
     KEEP_EPOCHS = 64            # bounded full-map/inc history window
 
     def __init__(self, mon: "Monitor"):

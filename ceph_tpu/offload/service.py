@@ -72,6 +72,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from ceph_tpu.ec.interface import decode_batch_tags
 from ceph_tpu.qa import faultinject, interleave
 from ceph_tpu.utils import copytrack, flight, sanitizer, tracer
 from ceph_tpu.utils.dout import dout
@@ -526,7 +527,9 @@ class OffloadService:
         self.stats = {"jobs": 0, "batches": 0, "coalesced_ops": 0,
                       "fallback_ops": 0, "breaker_trips": 0,
                       "batched_ops": 0, "mesh_batches": 0,
-                      "device_spills": 0, "device_failovers": 0}
+                      "device_spills": 0, "device_failovers": 0,
+                      "dec_jobs": 0, "dec_batches": 0, "dec_bytes": 0,
+                      "dec_out_bytes": 0}
         # per-device utilization: busy wall time / bytes / batches per
         # dispatch target; fallback and host-native batches are
         # attributed to "host". Keys are the slot labels plus "host".
@@ -738,7 +741,13 @@ class OffloadService:
         """(S, k, C) available chunks (stacked in avail_ids order) ->
         (S, len(want), C) reconstructed chunks. Jobs coalesce only with
         the same erasure pattern — a different survivor set is a
-        different recovery matrix, hence a different bucket."""
+        different recovery matrix, hence a different bucket. A decode
+        batch is named the same everywhere (`ec.interface.
+        decode_batch_tags`: `kind` "dec", `r`, `pattern`) on its
+        `offload_batch` span and on the plugin's `tpu_decode_dispatch`,
+        and counted in `stats` as `dec_jobs`, `dec_batches`, `dec_bytes`
+        (input) and `dec_out_bytes` (what the dispatch returned: the
+        tpu plugin pads r up to m rows, and they cross the link)."""
         avail_ids, want_ids = tuple(avail_ids), tuple(want_ids)
         key = ("dec", ec_impl.coding_matrix.tobytes(), avail_ids, want_ids,
                chunks.shape[2])
@@ -758,8 +767,9 @@ class OffloadService:
         def shard_dispatch(batch: np.ndarray) -> np.ndarray:
             return self._mesh_apply(key[:4], _recovery(), batch)
 
-        return await self._submit(key, chunks, dispatch, fallback,
-                                  shard_dispatch=shard_dispatch)
+        rec = await self._submit(key, chunks, dispatch, fallback,
+                                 shard_dispatch=shard_dispatch)
+        return rec[:, :len(want_ids)]
 
     async def crc32c_blocks(self, blocks, block_size: int) -> np.ndarray:
         """(N, block_size) uint8 — or a LIST of such arrays (a scatter
@@ -1133,6 +1143,12 @@ class OffloadService:
                                        round((now - t_sem) * 1e6, 1))
                             sp.set_tag("stack_us",
                                        round(stack_s * 1e6, 1))
+                            # enc / dec / crc / rep; a decode batch
+                            # also carries its r and its pattern
+                            sp.set_tag("kind", bucket.key[0])
+                            if bucket.key[0] == "dec":
+                                sp.tags.update(decode_batch_tags(
+                                    *bucket.key[2:4]))
                         out, on_device = await self._dispatch(
                             bucket, slot, stacked, len(jobs), sp,
                             token)
@@ -1156,6 +1172,11 @@ class OffloadService:
                                 (time.perf_counter() - sp.t0) * 1e6
                                 - sum(sp.tags[h] for h in _HOPS), 1))
                     self._note_batch(len(jobs), nbytes)
+                    if bucket.key[0] == "dec":
+                        self.stats["dec_jobs"] += len(jobs)
+                        self.stats["dec_batches"] += 1
+                        self.stats["dec_bytes"] += nbytes
+                        self.stats["dec_out_bytes"] += int(out.nbytes)
                 except asyncio.CancelledError:
                     raise
                 except Exception as e:

@@ -78,18 +78,23 @@ def _apply_bitmatrix_batched_jit(B_i8: jax.Array, data: jax.Array, r: int, k: in
     one fused kernel — the batching site named in SURVEY §2.2)."""
     b, _, n = data.shape
     bits = jnp.asarray(_BITS)
-    planes = ((data[:, :, None, :] >> bits[None, None, :, None]) & 1).astype(jnp.int8)
-    planes = planes.reshape(b, k * 8, n)
-    acc = jax.lax.dot_general(
-        B_i8,
-        planes,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )  # (r*8, batch, N)
-    out_planes = (acc & 1).astype(jnp.uint8).reshape(r, 8, b, n)
-    out = jnp.sum(out_planes << bits[None, :, None, None], axis=1,
-                  dtype=jnp.int32).astype(jnp.uint8)
-    return out.transpose(1, 0, 2)  # (batch, r, N)
+    # one program serves encode and every decode of its shape (the
+    # bitmatrix is an argument), so the scope can name the rows it
+    # computes and not the direction; the plugin names the direction
+    # on the host's line of the trace (`rs_encode_r3`, `rs_decode_r1`)
+    with jax.named_scope(f"rs_apply_r{r}"):
+        planes = ((data[:, :, None, :] >> bits[None, None, :, None]) & 1).astype(jnp.int8)
+        planes = planes.reshape(b, k * 8, n)
+        acc = jax.lax.dot_general(
+            B_i8,
+            planes,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )  # (r*8, batch, N)
+        out_planes = (acc & 1).astype(jnp.uint8).reshape(r, 8, b, n)
+        out = jnp.sum(out_planes << bits[None, :, None, None], axis=1,
+                      dtype=jnp.int32).astype(jnp.uint8)
+        return out.transpose(1, 0, 2)  # (batch, r, N)
 
 
 class MatrixCodec:

@@ -406,12 +406,25 @@ async def decode_concat_async(sinfo: StripeInfo, ec_impl,
             sp.set_tag("missing", missing)
             sp.set_tag("stripes", n_stripes)
             sp.set_tag("offload", True)
+        t0 = time.perf_counter()
         use, src = _reconstruct_stack(ec_impl, stacked, avail_ids)
+        t1 = time.perf_counter()
         rec = np.asarray(await service.decode(ec_impl, use,
                                               tuple(missing), src))
+        t2 = time.perf_counter()
         recovered = _reconstruct_unstack(rec, missing)
-        return _decode_concat_assemble(sinfo, stacked, recovered, want,
-                                       k, n_stripes)
+        out = _decode_concat_assemble(sinfo, stacked, recovered, want,
+                                      k, n_stripes)
+        if sp is not None:
+            # the span's three legs: stacking the survivors (a copy on
+            # the loop), the offload service (linger, hand-offs, device
+            # call: its `offload_queue_wait` and `offload_batch` spans
+            # split it), and the interleave plus `tobytes` (two copies)
+            sp.set_tag("stack_us", round((t1 - t0) * 1e6, 1))
+            sp.set_tag("offload_us", round((t2 - t1) * 1e6, 1))
+            sp.set_tag("assemble_us",
+                       round((time.perf_counter() - t2) * 1e6, 1))
+        return out
 
 
 def _decode_shards_frame(sinfo: StripeInfo, ec_impl,
