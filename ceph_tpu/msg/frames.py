@@ -42,9 +42,10 @@ _PRE_FIXED = struct.Struct("<HBB")
 _U32 = struct.Struct("<I")
 
 # -- native frame codec selection --------------------------------------------
-# The frame hot path (preamble pack/parse + the crc32c-over-scatter-list
-# pass) runs as ONE GIL-releasing C call when native/ec_native.cc is
-# available; the pure-Python path below stays the bit-identical fallback
+# The frame hot path (preamble build + the crc32c-over-scatter-list pass,
+# with or without the copy into one blob; a received body's crcs) runs as
+# ONE GIL-releasing C call when native/ec_native.cc is available; the
+# pure-Python path below stays the bit-identical fallback
 # (and the reference the fuzz tests hold the native codec to). Chosen at
 # import like the ec_native probe; CEPH_TPU_FRAME_NATIVE=0 force-disables
 # (the tier-1 fallback suite runs under exactly that).
@@ -86,6 +87,16 @@ def _seg_len(seg) -> int:
     if isinstance(seg, (list, tuple)):
         return sum(len(p) for p in seg)
     return len(seg)
+
+
+def _live_parts(seg):
+    """The non-empty parts of a segment, plain or scatter: an empty one
+    is no byte of the wire, and an empty buffer at the end of a
+    transport's queue would never be sent."""
+    for p in seg if isinstance(seg, (list, tuple)) else (seg,):
+        if len(p):
+            yield p
+
 
 # trace-context TLV segment (the Message.h otel_trace analog): an
 # OPTIONAL trailing frame segment `magic u16 | trace_id u64 | span_id
@@ -150,16 +161,20 @@ class Frame:
 
     MAX_SEGMENT_SIZE = 128 << 20   # sanity bound; a segment is <= one op
 
-    def _parts(self) -> list:
-        """Wire form as a scatter list: [preamble, seg0, crc0, seg1,
-        crc1, ...] — the preamble/crc trailers are fresh small bytes,
-        every segment is passed BY REFERENCE (no ledger accounting
-        here; encode/encode_parts meter their own copy behavior).
-        Scatter segments flatten into consecutive parts under one
-        chained crc — their bytes never join before the transport."""
+    def _check_count(self) -> None:
         if not 0 <= len(self.segments) <= MAX_SEGMENTS:
             raise FrameError(f"{len(self.segments)} segments (max "
                              f"{MAX_SEGMENTS})")
+
+    def _parts(self) -> list:
+        """Wire form as a scatter list, the pure-Python codec's:
+        [preamble, seg0, crc0, seg1, crc1, ...] — the preamble/crc
+        trailers are fresh small bytes, every segment is passed BY
+        REFERENCE (no ledger accounting here; encode/encode_parts meter
+        their own copy behavior). Scatter segments flatten into
+        consecutive parts under one chained crc; empty parts are left
+        out."""
+        self._check_count()
         pre = bytearray(_PRE_FIXED.pack(MAGIC, int(self.tag),
                                         len(self.segments)))
         for seg in self.segments:
@@ -167,55 +182,71 @@ class Frame:
         pre += _U32.pack(crc32c(bytes(pre)))
         parts: list = [bytes(pre)]
         for seg in self.segments:
-            if isinstance(seg, (list, tuple)):
-                crc = 0
-                for p in seg:
-                    parts.append(p)
-                    crc = crc32c(p, crc)
-                parts.append(_U32.pack(crc))
-            else:
-                parts.append(seg)
-                parts.append(_U32.pack(crc32c(seg)))
+            crc = 0
+            for p in _live_parts(seg):
+                parts.append(p)
+                crc = crc32c(p, crc)
+            parts.append(_U32.pack(crc))
         return parts
 
-    def _payload_len(self) -> int:
+    def payload_len(self) -> int:
+        """Bytes of all segments: what the messenger's write loop
+        chooses between `encode_parts` and `encode` on."""
         return sum(_seg_len(s) for s in self.segments)
 
     def encode_parts(self) -> list:
-        """Scatter-gather wire form for the plain-crc transport path:
-        the write loop hands these buffers to the transport
-        (writelines), whose single outbound join is the ONE copy each
-        segment pays — down from two in the old assemble-then-bytes()
-        encode(). Metered as one tx copy either way; with the native
-        codec the preamble build + every crc pass + the single copy
-        happen in ONE GIL-releasing C call and the transport gets the
-        finished blob."""
-        if _frame_native is not None:
-            if not 0 <= len(self.segments) <= MAX_SEGMENTS:
-                raise FrameError(f"{len(self.segments)} segments (max "
-                                 f"{MAX_SEGMENTS})")
-            t0 = time.perf_counter()
-            blob = _frame_native.pack(MAGIC, int(self.tag), self.segments)
-            copytrack.copied("frame_tx", self._payload_len(),
-                             time.perf_counter() - t0)
-            return [blob]
-        parts = self._parts()
-        copytrack.copied("frame_tx", self._payload_len())
+        """Wire form BY REFERENCE, for the plain-crc transport path:
+        [preamble, segment 0's parts, crc 0, ...], to be handed to the
+        transport's scatter `sendmsg` (writelines). No payload byte is
+        copied here or there: every segment part in the list is the
+        caller's own object, read once for its crc (with the native
+        codec the preamble and all crcs come from ONE GIL-releasing C
+        call that copies nothing), and the transport keeps what a
+        partial send leaves as views of the same objects.
+
+        Ownership: the list, and after it the transport's queue, holds
+        a reference to every part until the kernel has taken its last
+        byte, so the buffers stay alive; whoever handed them in must
+        leave them UNWRITTEN that long (a write in between sends bytes
+        the crc does not cover, and the peer faults the connection).
+        That holds for everything the messenger frames: `Message.data`
+        is `bytes`, a read-only view of an rx body that is never
+        reused, or an encode result nobody writes again, and a lossless
+        session keeps the message itself for replay. `b"".join` of the
+        list is `encode()` byte for byte."""
+        if _frame_native is None:
+            parts = self._parts()
+        else:
+            self._check_count()
+            # the preamble, then 4 bytes of crc a segment, in one
+            # buffer of this frame's own: the slices below are all
+            # that ever sees it
+            hdr = memoryview(_frame_native.crcs(MAGIC, int(self.tag),
+                                                self.segments))
+            off = len(hdr) - 4 * len(self.segments)
+            parts = [hdr[:off]]
+            for seg in self.segments:
+                parts.extend(_live_parts(seg))
+                parts.append(hdr[off:off + 4])
+                off += 4
+        copytrack.referenced("frame_tx", self.payload_len())
         return parts
 
     def encode(self) -> bytes | bytearray:
+        """Wire form as ONE packed blob: each payload byte is copied
+        once into it (metered as a `frame_tx` copy). The write loop
+        sends small frames this way (a 1 KB record in four iovecs
+        costs more than its copy), and the secure/compressed `Onwire`
+        transforms and the handshake need a whole frame."""
         if _frame_native is not None:
             # the packed bytearray is returned AS-IS (bytes-like):
             # every consumer — transport write, Onwire compress/
             # encrypt/concat — takes a buffer, and a bytes() round
-            # trip here would re-copy the whole frame on exactly the
-            # hot path the native codec exists to shrink
+            # trip here would re-copy the whole frame
             t0 = time.perf_counter()
-            if not 0 <= len(self.segments) <= MAX_SEGMENTS:
-                raise FrameError(f"{len(self.segments)} segments (max "
-                                 f"{MAX_SEGMENTS})")
+            self._check_count()
             blob = _frame_native.pack(MAGIC, int(self.tag), self.segments)
-            copytrack.copied("frame_tx", self._payload_len(),
+            copytrack.copied("frame_tx", self.payload_len(),
                              time.perf_counter() - t0)
             return blob
         # crcs/preamble are built OUTSIDE the timed window: the
@@ -224,9 +255,7 @@ class Frame:
         parts = self._parts()
         t0 = time.perf_counter()
         blob = b"".join(parts)
-        # one join: each segment byte is copied exactly once into the
-        # wire blob (the old bytearray-accumulate + bytes() paid twice)
-        copytrack.copied("frame_tx", self._payload_len(),
+        copytrack.copied("frame_tx", self.payload_len(),
                          time.perf_counter() - t0)
         return blob
 

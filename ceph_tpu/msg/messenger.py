@@ -28,7 +28,12 @@ owns (no asyncio stream pair): small reads come out of one fixed spill
 buffer per connection, and a frame's body is received by the kernel
 straight into the buffer its segments stay views of — one copy per
 payload byte, counted in the msgr logger (rx_direct_bytes,
-rx_spill_bytes, rx_recvs).
+rx_spill_bytes, rx_recvs). The way out mirrors it: a frame whose
+payload is the spill's size or more leaves by reference, its segments
+read once for their crc and sent by the transport's scatter sendmsg
+from where they lie; a smaller one, and every frame of a secure or
+compressed session, as one packed blob (tx_direct_bytes,
+tx_copied_bytes).
 Auth: `none` by default, cephx-lite mutual HMAC when
 an auth_key is set; on top of that the handshake can negotiate AES-GCM
 secure mode and/or zlib on-wire compression (frames.Onwire), with the
@@ -39,6 +44,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import functools
 import hashlib
 import hmac
 import json
@@ -47,10 +53,11 @@ import threading
 import time
 from typing import Awaitable, Callable
 
-from ceph_tpu.msg import messages as _messages
+from ceph_tpu.msg import frames as _frames, messages as _messages
 from ceph_tpu.msg.frames import BANNER, Frame, FrameError, Tag, Onwire
 from ceph_tpu.msg.messages import Message, _json_seg
-from ceph_tpu.msg.transport import Endpoint
+from ceph_tpu.msg.transport import SPILL_SIZE, Endpoint
+from ceph_tpu.native import ec_native
 from ceph_tpu.qa import faultinject, interleave
 from ceph_tpu.utils import tracer
 from ceph_tpu.utils.async_util import drain_all, reap, reap_all
@@ -125,7 +132,27 @@ def msgr_perf():
         pc.add("rx_recvs",
                description="recv_into calls that returned data "
                            "(buffer_updated callbacks)")
+        pc.add("tx_direct_bytes",
+               description="payload bytes of frames the write loop "
+                           "sent by reference (Frame.encode_parts: "
+                           "sendmsg from where the segments lie, no "
+                           "copy in user space)")
+        pc.add("tx_copied_bytes",
+               description="payload bytes of frames the write loop "
+                           "sent through a packed blob (Frame.encode: "
+                           "frames under the spill size, and every "
+                           "frame of a secure or compressed session)")
         return pc
+
+
+@functools.cache
+def _log_codec() -> None:
+    """Once a process, at its first Messenger: which crc32c kernel and
+    which frame codec every payload of this process passes. Both are
+    chosen from what the host has and engage always or never, so one
+    line says it for the whole run."""
+    dout("ms", 1, f"crc32c kernel {ec_native.crc32c_impl()}, frame codec "
+                  f"{'native' if _frames.native_active() else 'python'}")
 
 
 def MSGR_OPTIONS():
@@ -795,14 +822,25 @@ class Connection:
                 frame = Frame(Tag.KEEPALIVE_ACK, [])
             else:  # pragma: no cover
                 continue
-            if onwire is not None:
-                writer.write(onwire.wrap(frame.encode()))
-            else:
-                # plain crc mode: scatter-write the frame parts — the
-                # transport's outbound join is the single tx copy, and
-                # data segments (zero-copy views from upper layers)
-                # never get assembled into an intermediate blob here
+            nbytes = frame.payload_len()
+            if onwire is None and nbytes >= SPILL_SIZE:
+                # plain crc mode, a payload the receiver will take
+                # into a body of its own: sent from where its bytes
+                # lie. The parts stay referenced by the transport's
+                # queue until the kernel has them (and, on a lossless
+                # session, by the message in _sent until it is acked).
                 writer.writelines(frame.encode_parts())
+                perf.inc("tx_direct_bytes", nbytes)
+            else:
+                # a small frame costs less copied into one blob than
+                # as an iovec a part; the onwire transforms need the
+                # whole frame
+                blob = frame.encode()
+                if onwire is None:
+                    writer.writelines((blob,))
+                else:
+                    writer.write(onwire.wrap(blob))
+                perf.inc("tx_copied_bytes", nbytes)
             await writer.drain()
 
     def _trim_sent(self, acked_seq: int) -> None:
@@ -855,6 +893,7 @@ class Messenger:
         # frame/batch counters (process-wide "msgr" logger shared by
         # every messenger; the bench reads it for frames-per-write)
         self.perf = msgr_perf()
+        _log_codec()
         self.dispatchers: list[Dispatcher] = []
         self._server: asyncio.base_events.Server | None = None
         self.my_addr: tuple[str, int] | None = None

@@ -25,9 +25,15 @@ all of it, so that a run of small frames comes in one recv as it did
 through a stream reader. Reading is paused only when the spill is full
 of unread bytes, never in the middle of a body.
 
-The write side is the transport's own buffer with `drain()` on its
-high-water mark, as asyncio's stream writer had it. One object is both
-ends: the messenger keeps it as reader and writer.
+The write side is the transport's own queue with `drain()` on its
+high-water mark, as asyncio's stream writer had it. `writelines` puts a
+frame's parts there by reference and the transport sends them with one
+scatter `sendmsg`; what a partial send leaves stays views of the same
+objects. The messenger's write loop sends a frame that way when its
+payload is `SPILL_SIZE` or more, the same line the read side draws
+between the spill and a body of its own, and as one packed blob below
+it. One object is both ends: the messenger keeps it as reader and
+writer.
 """
 from __future__ import annotations
 
@@ -234,7 +240,21 @@ class Endpoint(asyncio.BufferedProtocol):
         self.transport.write(data)
 
     def writelines(self, parts) -> None:
-        self.transport.writelines(parts)
+        """Queue `parts` for one scatter `sendmsg`, each by reference:
+        the transport keeps a view of every part until the kernel has
+        taken its last byte, and what a partial send leaves stays views
+        of the same objects, never a joined copy. So the caller leaves
+        the buffers unwritten until `drain()` has returned with the
+        queue empty, or for good (`Frame.encode_parts` says who does).
+
+        Nothing is queued on a transport that is closing or lost (the
+        next `drain()` raises): asyncio's `writelines`, unlike its
+        `write`, would queue there all the same and register the dead
+        socket's number for writing, and the next socket to be given
+        that number, this session's reconnect, would then never learn
+        that it is connected."""
+        if not self.transport.is_closing():
+            self.transport.writelines(parts)
 
     async def drain(self) -> None:
         if not self._lost and self.transport.is_closing():

@@ -66,6 +66,9 @@ def load() -> ctypes.CDLL:
             lib.crc32c_blocks.argtypes = [u8p, ctypes.c_size_t,
                                           ctypes.c_size_t, ctypes.c_uint32,
                                           u32p]
+            lib.ec_native_crc32c_sw.restype = ctypes.c_uint32
+            lib.ec_native_crc32c_sw.argtypes = lib.crc32c.argtypes
+            lib.ec_native_crc32c_impl.restype = ctypes.c_char_p
             # msgr2 frame codec (present in rebuilt libraries; a stale
             # .so predating it is never picked: the name carries the source
             # digest)
@@ -76,6 +79,8 @@ def load() -> ctypes.CDLL:
                     ctypes.c_uint32, ctypes.c_uint32, ctypes.c_int,
                     u64p, ctypes.POINTER(ctypes.c_char_p), u64p,
                     ctypes.c_void_p]
+                lib.frame_crcs.restype = ctypes.c_uint64
+                lib.frame_crcs.argtypes = lib.frame_pack.argtypes
                 lib.frame_verify_body.restype = ctypes.c_int
                 lib.frame_verify_body.argtypes = [ctypes.c_void_p, u64p,
                                                   ctypes.c_int]

@@ -99,6 +99,22 @@ def crc32c(data: bytes | np.ndarray, crc: int = 0xFFFFFFFF) -> int:
                                     arr.size))
 
 
+def crc32c_impl() -> str:
+    """The kernel `crc32c` runs on this host: "hw3" (the crc32
+    instruction, three interleaved chains) or "sw" (slice-by-8 tables).
+    It is chosen from the cpu's features alone, so it engages always or
+    never."""
+    return native.load().ec_native_crc32c_impl().decode()
+
+
+def crc32c_sw(data: bytes | np.ndarray, crc: int = 0xFFFFFFFF) -> int:
+    """`crc32c` through the table kernel whatever the host has: what
+    the tests hold the dispatched kernel to."""
+    arr = np.frombuffer(data, dtype=np.uint8)
+    return int(native.load().ec_native_crc32c_sw(
+        ctypes.c_uint32(crc), _ptr(arr), arr.size))
+
+
 def crc32c_blocks(data: np.ndarray, block_size: int,
                   seed: int = 0xFFFFFFFF) -> np.ndarray:
     """Per-block CRCs of a (nblocks*block_size,) or (nblocks, block_size)

@@ -1,19 +1,25 @@
 """ctypes wrapper over the native msgr2 frame codec (native/ec_native.cc
-`frame_pack` / `frame_verify_body`).
+`frame_pack` / `frame_crcs` / `frame_verify_body`).
 
-One C call packs a whole frame — preamble build, every segment copy, and
-every crc32c pass — or verifies a received body's per-segment crcs, in
-place of the per-segment Python/ctypes loop frames.py otherwise runs.
-The call releases the GIL (plain ctypes CDLL semantics), which is what
-lets reactor shards overlap their frame hot paths. The wire layout is
-bit-identical to the pure-Python path; frames.py probes `available()`
-at import and silently keeps the Python fallback when the library (or a
-compiler to build it) is missing.
+One C call does a whole frame's codec work in place of the per-segment
+Python/ctypes loop frames.py otherwise runs: `pack` builds the preamble,
+copies every segment and crcs it into one wire blob (small frames, and
+whatever an onwire transform wraps); `crcs` builds the preamble and every
+segment's crc and copies nothing (frames sent by reference, see
+`Frame.encode_parts`); `verify_body` checks a received body's crcs. The
+calls release the GIL (plain ctypes CDLL semantics), and they stay on
+the event loop all the same: moving `pack` and `verify_body` of frames
+of 64 KiB and more to a helper thread (`run_in_executor`) read 8% FEWER
+ops a second in `rb4m_seqread` (CPU container, PR 28's issue) — the GIL
+hand-offs and the extra loop turns cost more than the overlap frees.
+The wire layout is bit-identical to the pure-Python path; frames.py
+probes `available()` at import and silently keeps the Python fallback
+when the library (or a compiler to build it) is missing.
 
 Segments are bytes-likes or LISTS of bytes-likes (scatter segments, the
 sub-op batch envelope's concatenated message datas): parts are flattened
-into one pointer array so each byte is copied exactly once, straight
-into the wire blob.
+into one pointer array, so a scatter segment is crc-chained (and, in
+`pack`, copied exactly once) without an intermediate join.
 
 This wrapper is on the per-frame hot path, so pointer extraction avoids
 numpy where it can: bytes ride ctypes' native c_char_p conversion
@@ -48,7 +54,7 @@ def available() -> bool:
         lib = native.load()
     except Exception:
         return False
-    if not hasattr(lib, "frame_pack"):
+    if not hasattr(lib, "frame_crcs"):
         return False
     _lib = lib
     return True
@@ -72,10 +78,10 @@ def _fill_ptr(ptrs, i, part, keep) -> None:
     ptrs[i] = _cast(_addressof(c), _c_char_p)
 
 
-def pack(magic: int, tag: int, segments: list) -> bytearray:
-    """Wire form of one frame: preamble + segments with trailing crcs,
-    built in a single native call. A segment may be a list/tuple of
-    parts (scatter segment); its crc chains across the parts."""
+def _flatten(segments: list):
+    """(parts a segment, part pointers, part lengths, payload bytes,
+    keep-alives) of a frame's segments for the two native calls:
+    scatter segments flattened into one pointer array."""
     nseg = len(segments)
     seg_parts = (_c_u64 * nseg)() if nseg else None
     flat: list = []
@@ -90,18 +96,44 @@ def pack(magic: int, tag: int, segments: list) -> bytearray:
     ptrs = (_c_char_p * n)() if n else None
     lens = (_c_u64 * n)() if n else None
     keep: list = []
-    total = 8 + 8 * nseg
+    payload = 0
     for i, part in enumerate(flat):
         ln = len(part)
         lens[i] = ln
-        total += ln
+        payload += ln
         if ln:
             _fill_ptr(ptrs, i, part, keep)
+    return seg_parts, ptrs, lens, payload, keep
+
+
+def pack(magic: int, tag: int, segments: list) -> bytearray:
+    """Wire form of one frame: preamble + segments with trailing crcs,
+    built in a single native call. A segment may be a list/tuple of
+    parts (scatter segment); its crc chains across the parts."""
+    nseg = len(segments)
+    seg_parts, ptrs, lens, payload, _keep = _flatten(segments)
+    total = 8 + 8 * nseg + payload
     out = bytearray(total)
     wrote = _lib.frame_pack(
         magic, tag, nseg, seg_parts, ptrs, lens,
         _addressof(_c_char.from_buffer(out)))
     assert wrote == total, (wrote, total)
+    return out
+
+
+def crcs(magic: int, tag: int, segments: list) -> bytearray:
+    """What a frame sent by reference needs besides its segments, in a
+    single native call that copies nothing: the preamble (8 + 4*nseg
+    bytes) followed by each segment's crc (4 bytes each, chained across
+    a scatter segment's parts). The caller slices it and sends
+    [preamble, segment 0's parts, crc 0, ...]."""
+    nseg = len(segments)
+    seg_parts, ptrs, lens, _payload, _keep = _flatten(segments)
+    out = bytearray(8 + 8 * nseg)
+    wrote = _lib.frame_crcs(
+        magic, tag, nseg, seg_parts, ptrs, lens,
+        _addressof(_c_char.from_buffer(out)))
+    assert wrote == len(out), (wrote, len(out))
     return out
 
 

@@ -136,7 +136,7 @@ def _msgr_frame_microbench() -> dict:
                 n = 4000
                 t0 = time.perf_counter()
                 for _ in range(n):
-                    frame.encode_parts()
+                    frame.encode()      # 32 KiB: the write loop packs it
                     Frame.decode(blob)
                 rate = n / (time.perf_counter() - t0)
                 out[f"msgr_frames_per_s_{label}"] = round(rate, 1)

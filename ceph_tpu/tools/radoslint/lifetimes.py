@@ -10,7 +10,14 @@ once, at the body's length, has the kernel fill it, and it lives as
 long as any segment view does. What IS reused on the receive side is
 each connection's small spill buffer, and nothing leaves that but
 `bytes()` copies; the `segments` rule stays, so that a body pool
-could never be introduced silently — and PR 9's ShardPool put mutable
+could never be introduced silently. The send side holds views too:
+`Frame.encode_parts` hands a large frame's segments to the transport
+by reference, and its queue keeps a view of each until the kernel has
+the last byte, across any number of awaits. That is safe for the same
+reason and no other: what becomes `Message.data` is `bytes`, a view of
+an rx body, or an array nobody writes again, never a window onto a
+recycled source — which is what `view-escape` keeps off object
+attributes, `msg.data` among them. And PR 9's ShardPool put mutable
 service state (`shared()` objects, the offload device topology) in
 reach of N OS threads at once. Both disciplines were hand-audited;
 these rules make the audit mechanical, the way `loop-affinity` froze

@@ -9,6 +9,8 @@ at every point where bytes either move (copied) or merely change hands
 (referenced):
 
   frame_tx           message segments assembled into a wire frame blob
+                     (copied), or handed to the transport's scatter send
+                     from where they lie (referenced: frames of 64 KiB up)
   frame_rx           wire blob sliced back into frame segment buffers
   frame_to_buffer    message data handed to the codec-facing buffer
                      (np.frombuffer = referenced; bytes() = copied)

@@ -50,25 +50,21 @@ def test_ledger_frame_tx_rx_exact_bytes():
     # assemble-then-bytes() path paid 2x)
     assert snap["frame_tx"]["copied_bytes"] == 768
     assert snap["frame_tx"]["events"] == 1
-    # the scatter path (plain crc transport) also meters one copy: the
-    # transport's outbound join under the pure-Python codec (segments
-    # by reference), the in-call pack under the native codec (finished
-    # blob) — byte-identical metering either way
+    # the scatter path (plain crc transport, frames at or over the
+    # spill size) copies nothing under either codec: segments go to
+    # the transport by reference and meter as referenced
     from ceph_tpu.msg import frames as frames_mod
     was_native = frames_mod.native_active()
-    frames_mod.set_native(False)
     try:
-        parts = Frame(Tag.MESSAGE, segs).encode_parts()
-        assert parts[1] is segs[0] and parts[3] is segs[1]
+        for n, use_native in enumerate([False] + [True] * was_native, 1):
+            frames_mod.set_native(use_native)
+            parts = Frame(Tag.MESSAGE, segs).encode_parts()
+            assert parts[1] is segs[0] and parts[3] is segs[1]
+            snap = copytrack.snapshot()["stages"]
+            assert snap["frame_tx"]["copied_bytes"] == 768
+            assert snap["frame_tx"]["referenced_bytes"] == n * 768
     finally:
         frames_mod.set_native(was_native)
-    snap = copytrack.snapshot()["stages"]
-    assert snap["frame_tx"]["copied_bytes"] == 2 * 768
-    if was_native:
-        parts = Frame(Tag.MESSAGE, segs).encode_parts()
-        assert len(parts) == 1 and len(parts[0]) == len(blob)
-        snap = copytrack.snapshot()["stages"]
-        assert snap["frame_tx"]["copied_bytes"] == 3 * 768
     assert snap["frame_rx"]["copied_bytes"] == 0
     frame = Frame.decode(blob)
     snap = copytrack.snapshot()["stages"]
