@@ -2,9 +2,7 @@
 EC data path (see service.py for the full design notes)."""
 from ceph_tpu.offload.service import (OFFLOAD_OPTIONS, OffloadService,
                                       get_service, get_service_or_none,
-                                      register_config, service_for,
-                                      set_enabled)
+                                      register_config, service_for)
 
 __all__ = ["OFFLOAD_OPTIONS", "OffloadService", "get_service",
-           "get_service_or_none", "register_config", "service_for",
-           "set_enabled"]
+           "get_service_or_none", "register_config", "service_for"]

@@ -99,7 +99,6 @@ _DEFAULTS: dict[str, Any] = {
     "device_count": 0,
     "device_shard_bytes": 32 << 20,
     "device_spill_threshold": 2,
-    "device_peak_gbps": 0.0,
 }
 
 #: one service per event loop: a loop is one cluster's world (tests and
@@ -187,18 +186,12 @@ def _perf():
                description="bytes admitted and not yet completed")
         pc.add("inflight_batches", type=TYPE_GAUGE,
                description="batches occupying staging slots")
-        # per-kernel achieved bandwidth (EWMA over device batches) and
-        # its fraction of the configured device peak — the roofline
-        # gauges the metrics history trends per daemon. enc/dec/crc/rep
-        # mirror the _Bucket key kinds.
+        # per-kernel achieved bandwidth (EWMA over device batches);
+        # enc/dec/crc/rep mirror the _Bucket key kinds
         for kind in ("enc", "dec", "crc", "rep"):
             pc.add(f"kernel_{kind}_gbps", type=TYPE_GAUGE,
                    description=f"{kind} kernel achieved GB/s "
                                f"(EWMA over device batches)")
-            pc.add(f"kernel_{kind}_roofline_pct", type=TYPE_GAUGE,
-                   description=f"{kind} kernel GB/s as % of "
-                               f"ec_offload_device_peak_gbps (0 when "
-                               f"no peak is configured)")
     return pc
 
 
@@ -515,7 +508,6 @@ class OffloadService:
         self.device_shard_bytes = int(_DEFAULTS["device_shard_bytes"])
         self.device_spill_threshold = max(
             1, int(_DEFAULTS["device_spill_threshold"]))
-        self.device_peak_gbps = float(_DEFAULTS["device_peak_gbps"])
         self._throttle = Throttle("ec_offload_queue",
                                   int(_DEFAULTS["max_queue_bytes"]))
         self._space = asyncio.Event()
@@ -555,7 +547,7 @@ class OffloadService:
         self._host_slot = _DeviceSlot(_DeviceState("host", None),
                                       self.pipeline_depth)
         self._last_error = ""
-        # per-kernel-kind achieved-GB/s EWMA backing the roofline gauges
+        # per-kernel-kind achieved-GB/s EWMA backing the kernel_*_gbps gauges
         self._kernel_gbps: dict[str, float] = {}
 
     @property
@@ -625,8 +617,6 @@ class OffloadService:
             self.device_shard_bytes = int(value)
         elif name == "ec_offload_device_spill_threshold":
             self.device_spill_threshold = max(1, int(value))
-        elif name == "ec_offload_device_peak_gbps":
-            self.device_peak_gbps = max(0.0, float(value))
 
     # -- dispatch topology ---------------------------------------------------
 
@@ -1420,9 +1410,8 @@ class OffloadService:
                 d["fallback_ops"] += n_ops
 
     def _note_kernel(self, kind, nbytes: int, busy_s: float) -> None:
-        """Roofline gauges: achieved GB/s for this kernel kind (EWMA —
-        one tiny linger-flushed batch must not zero a healthy trend)
-        and, when a device peak is configured, its roofline fraction."""
+        """Achieved GB/s for this kernel kind (EWMA — one tiny
+        linger-flushed batch must not zero a healthy trend)."""
         if busy_s <= 0 or kind not in ("enc", "dec", "crc", "rep"):
             return
         gbps = nbytes / busy_s / 1e9
@@ -1430,10 +1419,6 @@ class OffloadService:
         ewma = gbps if prev is None else 0.7 * prev + 0.3 * gbps
         self._kernel_gbps[kind] = ewma
         self.perf.set(f"kernel_{kind}_gbps", round(ewma, 4))
-        peak = self.device_peak_gbps
-        if peak > 0:
-            self.perf.set(f"kernel_{kind}_roofline_pct",
-                          round(100.0 * ewma / peak, 2))
 
     def _note_mesh(self, n_ops: int, nbytes: int, busy_s: float) -> None:
         """A mesh batch occupies every device for its wall time; bytes
@@ -1649,15 +1634,6 @@ def get_service_or_none() -> OffloadService | None:
     return get_service()
 
 
-def set_enabled(flag: bool) -> None:
-    """Module-wide toggle (bench harness): defaults + live instances."""
-    _DEFAULTS["enabled"] = bool(flag)
-    with _instances_lock:
-        services = list(_instances.values())
-    for svc in services:
-        svc.enabled = bool(flag)
-
-
 def OFFLOAD_OPTIONS():
     """The ec_offload_* option schema (declared per daemon Config)."""
     from ceph_tpu.utils.config import Option
@@ -1703,11 +1679,6 @@ def OFFLOAD_OPTIONS():
                "inflight-batch lead over the least-busy device at "
                "which an affine bucket spills off its preferred chip",
                minimum=1),
-        Option("ec_offload_device_peak_gbps", "float",
-               _DEFAULTS["device_peak_gbps"],
-               "device memory-bandwidth peak in GB/s for the roofline "
-               "gauges (kernel_*_roofline_pct); 0 leaves them at zero "
-               "and only the absolute GB/s gauges move", minimum=0.0),
     ]
 
 

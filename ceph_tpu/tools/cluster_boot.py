@@ -1,5 +1,5 @@
-"""Ephemeral mini-cluster boot/teardown shared by the bench stages and
-CLI drivers.
+"""Ephemeral mini-cluster boot/teardown shared by the CLI drivers and
+the tests.
 
 Three call sites used to hand-roll the same sequence — ephemeral port,
 tmpdir, MonMap/Monitor boot, leader wait, OSD loop, client connect,

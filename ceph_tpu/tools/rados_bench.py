@@ -12,8 +12,8 @@ Usage (standalone, boots its own vstart-style cluster):
         [--k 2] [--m 1] [--osds 3] [--backend memstore|filestore]
 Prints one JSON object with write + read phases.
 
-The in-process programmatic entry (`run_bench`) is what bench.py's
-cluster stage and the tests call.
+The in-process programmatic entry (`run_bench`) is what `rados bench`
+(tools/rados_cli.py) calls.
 """
 from __future__ import annotations
 

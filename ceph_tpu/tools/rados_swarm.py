@@ -51,7 +51,7 @@ Usage (standalone, boots its own EC cluster):
     python -m ceph_tpu.tools.rados_swarm [--clients 200] [--seconds 5]
         [--procs 4] [--bullies 8] [--streamers 8] [--spammers 8]
 Programmatic: `await run_swarm(mon_addrs, pool, ...)` against a live
-cluster (what the bench stages and tests call).
+cluster (what the tests call).
 """
 from __future__ import annotations
 

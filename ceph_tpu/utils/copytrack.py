@@ -1,9 +1,9 @@
 """Byte-flow copy ledger: bytes-copied vs bytes-referenced per stage.
 
-The 450x device-vs-cluster gap (BENCH_r05: device encode ~32 GB/s,
-cluster EC write 69.77 MB/s) is transfer- and event-loop-bound, and the
-planned zero-copy buffer discipline needs a before/after meter: without
-one, "we removed a copy" is a code-review claim, not a measurement.
+The gap between the device's encode rate and a cluster's EC write rate
+is transfer- and event-loop-bound, and the zero-copy buffer discipline
+needs a before/after meter: without one, "we removed a copy" is a
+code-review claim, not a measurement.
 This module is that meter — a process-wide ledger the data path feeds
 at every point where bytes either move (copied) or merely change hands
 (referenced):

@@ -1,8 +1,8 @@
 """Sharded reactor runtime: N OS threads, each owning one asyncio loop.
 
-BENCH_r05's attribution stage pins the 450x device-vs-cluster gap on a
-single saturated Python event loop (`loop_busy_fraction` ~1.0 on the
-only loop in the process): every OSD, the mon, the mgr, and the client
+A cluster in one process is bound by a single saturated Python event
+loop (`loop_busy_fraction` ~1.0 on the only loop in the process; the
+benchmark's `loop_busy_pct`): every OSD, the mon, the mgr, and the client
 all contend for the same reactor thread, so the cluster's ceiling is
 one core's worth of frame parsing and dispatch no matter how many
 devices the offload service fans across. This module is the
@@ -794,7 +794,7 @@ class ProcShardPool:
         """Pool-wide loop profiler view: the parent's own shard stats
         merged with every live worker's (`profile dump` over the
         control channel), keyed by POOL-WIDE shard label, plus the
-        cross-process busy skew the bench trend guard watches."""
+        cross-process busy skew."""
         from ceph_tpu.utils import loopprof
         # the parent contributes ONLY its own shard-0 loop: the
         # process-wide _per_loop store can carry stale shard1..N labels

@@ -1,7 +1,7 @@
 """Known-negative decl-use: the flight-recorder / metrics-history
 pattern — an option family applied through a prefix-slicing observer
 (utils/flight.py, mgr_history_* in mgr/daemon.py) and per-kernel
-roofline gauges set through an f-string name (offload/service.py) —
+bandwidth gauges set through an f-string name (offload/service.py) —
 all live uses the lint's prefix-const heuristic must honor."""
 
 _DEFAULTS = {"enabled": True, "capacity": 512}

@@ -3,8 +3,7 @@ accounting over a known pipeline, the loop account (a synthetic loop's
 time by label, collections, pauses, slices, nothing left installed,
 hot-toggle via config), per-device offload utilization (fallback
 batches attributed to `host`), the hand-offs of a staged dispatch as
-tags on `offload_batch`, the bench attribution waterfall math
-(buckets + residual sum to op_total), and the report→exporter contract
+tags on `offload_batch`, and the report→exporter contract
 (`ceph_device`-labeled families, every report-merged logger renderable).
 """
 from __future__ import annotations
@@ -20,9 +19,6 @@ from ceph_tpu.ec import registry
 from ceph_tpu.mgr.daemon import DaemonStateIndex
 from ceph_tpu.mgr.exporter import render_metrics
 from ceph_tpu.msg.frames import Frame, Tag
-from ceph_tpu.tools.bench_driver import (ATTRIBUTION_BUCKETS,
-                                         attribution_from_spans,
-                                         stage_attribution)
 from ceph_tpu.utils import copytrack, loopprof, tracer
 from ceph_tpu.utils.admin_socket import AdminSocket
 from ceph_tpu.utils.buffer import BufferList
@@ -426,77 +422,6 @@ def test_offload_batch_hops_sum_to_the_span():
     enc = [s for s in spans if s["name"] == "ec_encode"]
     assert len(enc) == 1 and enc[0]["tags"]["assemble_us"] >= 0
     assert enc[0]["tags"]["assemble_us"] < enc[0]["duration_us"]
-
-
-# ---------------------------------------------------------------------------
-# bench attribution waterfall math
-# ---------------------------------------------------------------------------
-
-def _span(trace, name, dur, **tags):
-    return {"trace_id": trace, "name": name, "duration_us": dur,
-            "tags": tags}
-
-
-def test_attribution_buckets_sum_to_op_total():
-    spans = [
-        _span("t1", "osd_op", 1000.0, queue_wait_us=200.0),
-        _span("t1", "offload_batch", 700.0, stack_us=50.0,
-              pool_wait_us=200.0, h2d_submit_us=100.0, launch_us=250.0,
-              result_wait_us=50.0, resume_us=80.0, scatter_us=20.0),
-        _span("t1", "store_commit", 150.0),
-        _span("t1", "store_commit", 120.0),     # parallel shard: max wins
-        _span("t2", "offload_batch", 10.0),     # orphan trace: ignored
-    ]
-    att = attribution_from_spans(spans)
-    assert att["ops"] == 1
-    assert att["op_total_us"] == 1200.0          # 1000 span + 200 queued
-    b = att["buckets_us"]
-    assert b["queue_wait"] == 200.0
-    assert b["copy"] == 50.0
-    assert b["h2d"] == 100.0
-    assert b["kernel"] == 250.0
-    assert b["d2h"] == 50.0
-    assert b["commit"] == 150.0
-    assert b["other"] == 400.0                   # explicit residual
-    total = sum(b[k] for k in ATTRIBUTION_BUCKETS)
-    assert total == pytest.approx(att["op_total_us"], rel=0.10)
-    assert att["attributed_fraction"] == pytest.approx(800.0 / 1200.0,
-                                                       abs=1e-4)
-    assert sum(att["bucket_pct"].values()) == pytest.approx(100.0, abs=0.5)
-
-
-def test_stage_attribution_runs_small_on_the_cpu_backend():
-    """The operator's stage end to end: the account's labels, the hop
-    tags as h2d/kernel/d2h buckets, shards, and nothing left armed."""
-    out = stage_attribution(seconds=0.5, ab_seconds=0.2, ab_reps=1)
-    att = out["attribution"]
-    assert att["ops"] > 0
-    labels = att["loop_labels_us"]
-    assert set(labels) == set(loopprof.LABELS) | {loopprof.IDLE}
-    assert labels["msgr"] > 0 and labels["osd"] > 0
-    busy = sum(labels.values()) - labels[loopprof.IDLE]
-    assert att["loop_busy_fraction"] == pytest.approx(
-        busy / sum(labels.values()), abs=1e-3)
-    b = att["buckets_us"]
-    assert b["h2d"] > 0 and b["kernel"] > 0 and b["d2h"] >= 0
-    assert sum(b.values()) == pytest.approx(att["op_total_us"], rel=0.10)
-    assert att["per_shard"] and att["reactor_shards"] >= 1
-    assert set(out["tracing_ab_mb_s"]) == {"off", "sampled_tail", "full"}
-    assert not tracer.enabled() and not loopprof.installed_loops()
-
-
-def test_attribution_empty_and_multi_op():
-    assert attribution_from_spans([])["ops"] == 0
-    spans = [
-        _span("t1", "osd_op", 500.0, queue_wait_us=100.0),
-        _span("t2", "osd_op", 700.0),
-        _span("t2", "store_commit", 200.0),
-    ]
-    att = attribution_from_spans(spans)
-    assert att["ops"] == 2
-    assert att["op_total_us"] == pytest.approx((600.0 + 700.0) / 2)
-    assert att["buckets_us"]["queue_wait"] == pytest.approx(50.0)
-    assert att["buckets_us"]["commit"] == pytest.approx(100.0)
 
 
 # ---------------------------------------------------------------------------

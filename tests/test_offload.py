@@ -20,8 +20,10 @@ from ceph_tpu.ec import registry
 from ceph_tpu.ec.plugin_tpu import ErasureCodeTpu
 from ceph_tpu.msg.messenger import Connection
 from ceph_tpu.mon.paxos import Paxos
+from ceph_tpu.offload import service as offload_service
 from ceph_tpu.osd import ec_util
 from ceph_tpu.osd.daemon import OSD
+from ceph_tpu.utils.config import Config
 
 from tests.test_cluster import ClusterHarness, run
 
@@ -197,18 +199,16 @@ def test_inline_bypass_when_disabled():
         svc = offload.get_service()
         data = bytes(4 * 1024)
         ref = ec_util.encode(sinfo, impl, data)
-        offload.set_enabled(False)
-        try:
-            base = dict(svc.stats)
-            outs = await asyncio.gather(*[
-                ec_util.encode_async(sinfo, impl, data, service=svc)
-                for _ in range(3)])
-            assert all(o == ref for o in outs)
-            d = {k: svc.stats[k] - base[k] for k in base}
-            assert d["batches"] == 3             # one dispatch per op
-            assert d["coalesced_ops"] == 0
-        finally:
-            offload.set_enabled(True)
+        # this loop's service only: it goes with the loop
+        svc.apply_setting("ec_offload_enabled", False)
+        base = dict(svc.stats)
+        outs = await asyncio.gather(*[
+            ec_util.encode_async(sinfo, impl, data, service=svc)
+            for _ in range(3)])
+        assert all(o == ref for o in outs)
+        d = {k: svc.stats[k] - base[k] for k in base}
+        assert d["batches"] == 3             # one dispatch per op
+        assert d["coalesced_ops"] == 0
     run(body(), timeout=60)
 
 
@@ -526,7 +526,7 @@ def test_admin_socket_commands_and_hot_config(tmp_path):
             osd.config.set("ec_offload_enabled", True)
             assert svc.enabled is True
         finally:
-            offload.set_enabled(True)
+            osd.config.set("ec_offload_enabled", True)
             svc.apply_setting("ec_offload_linger_ms", 2.0)
             svc.apply_setting("ec_offload_max_batch_bytes", 8 << 20)
             await harness.stop()
@@ -562,3 +562,42 @@ def test_offload_counters_ride_the_mgr_report(tmp_path):
         finally:
             await harness.stop()
     run(body(), timeout=120)
+
+
+# ---------------------------------------------------------------------------
+# the ec_offload_* schema: nothing declared that nothing reads
+# ---------------------------------------------------------------------------
+
+def _offload_settings(svc) -> dict:
+    st = svc.status()
+    return {"enabled": st["enabled"], **st["settings"]}
+
+
+@pytest.mark.parametrize("key", sorted(offload_service._DEFAULTS))
+def test_every_offload_option_is_declared_and_consumed(key, monkeypatch):
+    """Each default is a declared option, each declared option has a
+    default, and a value set through a daemon Config shows in `ec offload
+    status`: on the live service, or (startup-only options) on the next."""
+    monkeypatch.setattr(offload_service, "_DEFAULTS",
+                        dict(offload_service._DEFAULTS))
+    opts = {o.name: o for o in offload.OFFLOAD_OPTIONS()}
+    assert set(opts) == {"ec_offload_" + k for k in offload_service._DEFAULTS}
+    opt = opts["ec_offload_" + key]
+    if opt.type == "bool":
+        value = not opt.default
+    else:
+        value = type(opt.default)(opt.default * 2 + 1)
+
+    async def set_it():
+        config = Config()
+        offload.register_config(config)
+        svc = offload.get_service()
+        assert _offload_settings(svc)[key] == opt.default
+        config.set(opt.name, value)
+        return _offload_settings(svc)[key]
+
+    async def next_service():
+        return _offload_settings(offload.get_service())[key]
+
+    live = run(set_it(), timeout=60)
+    assert value in (live, run(next_service(), timeout=60))

@@ -1,8 +1,8 @@
 """radoslint analyzer tests: positive+negative fixtures per rule,
 suppression comments, baseline round-trip + ratchet, the lint_tool and
-module entry points, changed-only mode, the runtime sanitizer, the
-bench trend guard — and the tier-1 gate: the full suite over ceph_tpu/
-must produce zero non-baselined findings."""
+module entry points, changed-only mode, the runtime sanitizer — and
+the tier-1 gate: the full suite over ceph_tpu/ must produce zero
+non-baselined findings."""
 import asyncio
 import json
 import os
@@ -406,57 +406,6 @@ def test_sanitizer_toggle_from_foreign_thread():
             sanitizer.uninstall(loop)
 
     asyncio.run(main())
-
-
-# -- bench trend guard -------------------------------------------------------
-
-def test_bench_trend_guard(tmp_path):
-    from ceph_tpu.tools.bench_driver import trend_guard
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(
-        {"parsed": {"platform": "tpu",
-                    "detail": {"tpu_encode": 35.2, "tpu_decode": 36.0}}}))
-    # 9.2% drop: recorded, under the 10% threshold, no warning
-    t = trend_guard({"tpu_encode": 31.96, "tpu_decode": 36.0}, "tpu",
-                    str(tmp_path))
-    assert t["baseline_round"] == "BENCH_r01.json"
-    assert t["regression_pct"] == pytest.approx(9.2, abs=0.05)
-    assert "warning" not in t
-    # 14.8% drop: loud warning naming the metric and the rounds
-    t = trend_guard({"tpu_encode": 30.0, "tpu_decode": 36.0}, "tpu",
-                    str(tmp_path))
-    assert t["regression_pct"] > 10 and "tpu_encode" in t["warning"]
-    # platform change: comparison skipped, recorded as such
-    t = trend_guard({"tpu_encode": 30.0}, "cpu", str(tmp_path))
-    assert "skipped" in t and "regression_pct" not in t
-    # no prior committed round at all: guard stays silent
-    empty = tmp_path / "empty"
-    empty.mkdir()
-    assert trend_guard({"tpu_encode": 30.0}, "tpu", str(empty)) is None
-    # a garbled/failed newest round ("parsed": null, as failed rounds
-    # commit) must fall back to the next-newest, not disarm the guard
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps({"parsed": None}))
-    (tmp_path / "BENCH_r03.json").write_text("not json{")
-    t = trend_guard({"tpu_encode": 30.0, "tpu_decode": 36.0}, "tpu",
-                    str(tmp_path))
-    assert t is not None and t["baseline_round"] == "BENCH_r01.json"
-    # sanitizer-mode overhead is a COST key: a >10% RISE (the qa tier
-    # quietly getting pricier) warns like any throughput drop
-    (tmp_path / "BENCH_r04.json").write_text(json.dumps(
-        {"parsed": {"platform": "tpu",
-                    "detail": {"tpu_encode": 30.0,
-                               "interleave_sanitizer_overhead_pct": 20.0}}}))
-    t = trend_guard({"tpu_encode": 30.0,
-                     "interleave_sanitizer_overhead_pct": 25.0}, "tpu",
-                    str(tmp_path))
-    assert t["regression_pct"] == pytest.approx(25.0, abs=0.1)
-    assert "interleave_sanitizer_overhead_pct" in t["warning"]
-
-
-def test_bench_trend_guard_prefers_newest_round():
-    from ceph_tpu.tools.bench_driver import previous_bench
-    prev = previous_bench(REPO)
-    assert prev is not None
-    assert prev[0] == "BENCH_r06.json"
 
 
 # -- the tier-1 gate: zero non-baselined findings over ceph_tpu/ -------------
