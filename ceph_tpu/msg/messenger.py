@@ -11,7 +11,18 @@ The reference contract this keeps (src/msg/Messenger.h, ProtocolV2.cc):
     messages the other hasn't acked);
   * session semantics: cookie identifies a session across TCP transports;
     in_seq/out_seq + ACK frames bound replay; receivers drop duplicates
-    by seq (ProtocolV2 reconnect/replay, out-of-order-safe).
+    by seq (ProtocolV2 reconnect/replay, out-of-order-safe);
+  * control frames cost nothing of their own while anything else moves
+    (ProtocolV2::write_event): the write loop sends all that is queued
+    for a peer in one writelines per wake-up and appends the ACK the
+    peer is owed (only what a handler has finished), so an ack goes
+    alone only from a connection with nothing to ride on — after
+    ACK_EVERY unacked messages or IDLE_ACK_S of quiet; and a lossless
+    end sends a KEEPALIVE only once it has received nothing for
+    KEEPALIVE_INTERVAL, since any frame shows the peer alive. A dead
+    peer is still faulted KEEPALIVE_TIMEOUT after its last frame
+    (counters: ctrl_frames_tx, ctrl_rode_tx, tx_sends,
+    keepalives_skipped).
 
 Idiomatic divergences: one asyncio event loop per DAEMON (under the
 sharded reactor runtime, utils/reactor.py, each daemon's messenger
@@ -88,6 +99,9 @@ _BATCH_DEFAULTS: dict = {
     "linger_us": 0.0,
 }
 
+#: the write loop's queue items that frame as a bare tag
+_PROBE_TAGS = {"keepalive": Tag.KEEPALIVE, "keepalive_ack": Tag.KEEPALIVE_ACK}
+
 _msgr_perf_lock = threading.Lock()
 
 
@@ -142,6 +156,19 @@ def msgr_perf():
                            "sent through a packed blob (Frame.encode: "
                            "frames under the spill size, and every "
                            "frame of a secure or compressed session)")
+        pc.add("ctrl_frames_tx",
+               description="ACK, KEEPALIVE and KEEPALIVE_ACK frames the "
+                           "write loops framed")
+        pc.add("ctrl_rode_tx",
+               description="those of them that left in a send which "
+                           "also carried a MESSAGE frame")
+        pc.add("tx_sends",
+               description="calls of the write loops into the "
+                           "transport's writelines: one sendmsg each "
+                           "unless the socket is full")
+        pc.add("keepalives_skipped",
+               description="keepalive ticks that sent no probe because "
+                           "a frame had arrived within the interval")
         return pc
 
 
@@ -274,8 +301,11 @@ class Connection:
 
     RECONNECT_BACKOFF = 0.2     # doubles per attempt, capped
     RECONNECT_BACKOFF_MAX = 5.0
-    ACK_EVERY = 16              # coalesce acks; also acked when idle
-    KEEPALIVE_INTERVAL = 1.0    # lossless peers ping this often when idle
+    ACK_EVERY = 16              # unacked messages that force an ack out
+    #                             with nothing to ride on (see IDLE_ACK_S)
+    KEEPALIVE_INTERVAL = 1.0    # a lossless end that has received nothing
+    #                             for this long probes, and again every
+    #                             this often until something arrives
     KEEPALIVE_TIMEOUT = 5.0     # no frames in this long = transport dead
     PARK_TIMEOUT = 30.0         # lossless acceptor gives up waiting for
     #                             the peer's RECONNECT (peer death GC)
@@ -590,17 +620,26 @@ class Connection:
                 raise exc
 
     async def _keepalive_loop(self) -> None:
-        """Lossless peers actively probe liveness: send KEEPALIVE on an
-        interval and fault the transport when nothing (data, acks, or
-        keepalive replies) has arrived within KEEPALIVE_TIMEOUT — the
-        reference's keepalive2 + timeout behavior (ProtocolV2)."""
+        """Lossless peers watch their own receive side: every
+        KEEPALIVE_INTERVAL, fault the transport when nothing (data,
+        acks, or keepalive replies) has arrived within
+        KEEPALIVE_TIMEOUT, and send a KEEPALIVE only when nothing has
+        arrived within the interval — any frame proves the peer alive
+        as well as a probe's answer would (ProtocolV2 sends keepalive2
+        when its owner asks, not on a timer of its own). A peer that
+        dies sends nothing, so the probes start within one interval of
+        its last frame and the timeout falls where it always did."""
+        perf = self.messenger.perf
         while True:
             await asyncio.sleep(self.KEEPALIVE_INTERVAL)
             stale = time.monotonic() - self._last_rx
             if stale > self.KEEPALIVE_TIMEOUT:
                 raise FrameError(
                     f"keepalive timeout ({stale:.1f}s since last frame)")
-            self._out.put_nowait(("keepalive", None))
+            if stale >= self.KEEPALIVE_INTERVAL:
+                self._out.put_nowait(("keepalive", None))
+            else:
+                perf.inc("keepalives_skipped")
 
     async def _read_loop(self, reader, onwire: Onwire | None = None
                          ) -> None:
@@ -794,7 +833,16 @@ class Connection:
 
     async def _write_loop(self, writer,
                           onwire: Onwire | None = None) -> None:
+        """One send per wake-up (ProtocolV2::write_event): frame the
+        item that woke the loop and whatever else is already queued,
+        append the ack the peer is owed, and hand it all to the
+        transport in ONE writelines, so a control frame costs no
+        sendmsg, no wake-up and no segment on the wire of its own when
+        anything else is going the same way. Gathering stops at
+        SPILL_SIZE bytes of payload: a large frame still leaves from
+        where its bytes lie, one at a time."""
         perf = self.messenger.perf
+        out = self._out
         pending: tuple | None = None
         while True:
             if pending is not None:
@@ -803,45 +851,78 @@ class Connection:
                 # plain get — no wait_for wrapper task + timer per
                 # frame (profiled per-frame overhead); idle acks ride
                 # the dispatch loop's lazy _schedule_ack_flush timer
-                item = await self._out.get()
-            kind, arg = item
-            if kind == "msg":
-                arg, pending = await self._coalesce(arg)
-                frame = Frame(Tag.MESSAGE, arg.encode_segments())
-                perf.inc("frames_tx")
-                if type(arg).TYPE in _messages.BATCHABLE_TYPES or \
-                        isinstance(arg, (_messages.MOSDECSubOpBatch,
-                                         _messages.MOSDECSubOpBatchReply)):
-                    perf.inc("data_frames_tx")
-            elif kind == "ack":
-                frame = Frame(Tag.ACK, [json.dumps([arg]).encode()])
-                self._last_acked_in = arg
-            elif kind == "keepalive":
-                frame = Frame(Tag.KEEPALIVE, [])
-            elif kind == "keepalive_ack":
-                frame = Frame(Tag.KEEPALIVE_ACK, [])
-            else:  # pragma: no cover
-                continue
-            nbytes = frame.payload_len()
-            if onwire is None and nbytes >= SPILL_SIZE:
-                # plain crc mode, a payload the receiver will take
-                # into a body of its own: sent from where its bytes
-                # lie. The parts stay referenced by the transport's
-                # queue until the kernel has them (and, on a lossless
-                # session, by the message in _sent until it is acked).
-                writer.writelines(frame.encode_parts())
-                perf.inc("tx_direct_bytes", nbytes)
-            else:
-                # a small frame costs less copied into one blob than
-                # as an iovec a part; the onwire transforms need the
-                # whole frame
-                blob = frame.encode()
-                if onwire is None:
-                    writer.writelines((blob,))
+                item = await out.get()
+            parts: list = []
+            gathered = ctrl = msgs = 0
+            while True:
+                kind, arg = item
+                if kind == "msg":
+                    arg, pending = await self._coalesce(arg)
+                    frame = Frame(Tag.MESSAGE, arg.encode_segments())
+                    msgs += 1
+                    perf.inc("frames_tx")
+                    if type(arg).TYPE in _messages.BATCHABLE_TYPES or \
+                            isinstance(arg, (_messages.MOSDECSubOpBatch,
+                                             _messages.MOSDECSubOpBatchReply)):
+                        perf.inc("data_frames_tx")
+                    gathered += self._frame_into(parts, frame, onwire)
+                elif kind in _PROBE_TAGS:
+                    ctrl += 1
+                    self._frame_into(parts, Frame(_PROBE_TAGS[kind], []),
+                                     onwire)
+                # an ("ack", seq) is a wake-up and no more: what the
+                # peer is owed is read below, once, so one that a ride
+                # has overtaken since it was queued sends nothing
+                if gathered >= SPILL_SIZE:
+                    break
+                if pending is not None:
+                    item, pending = pending, None
+                elif out.empty():
+                    break
                 else:
-                    writer.write(onwire.wrap(blob))
-                perf.inc("tx_copied_bytes", nbytes)
+                    item = out.get_nowait()
+            if self._processed_seq > self._last_acked_in:
+                # the ack rides: only what a handler has FINISHED is
+                # advertised, as when it went alone
+                self._last_acked_in = self._processed_seq
+                if self._ack_timer is not None:
+                    self._ack_timer.cancel()
+                    self._ack_timer = None
+                ctrl += 1
+                self._frame_into(parts, Frame(
+                    Tag.ACK, [b"[%d]" % self._last_acked_in]), onwire)
+            if not parts:
+                continue
+            if ctrl:
+                perf.inc("ctrl_frames_tx", ctrl)
+                if msgs:
+                    perf.inc("ctrl_rode_tx", ctrl)
+            perf.inc("tx_sends")
+            writer.writelines(parts)
             await writer.drain()
+
+    def _frame_into(self, parts: list, frame: Frame,
+                    onwire: Onwire | None) -> int:
+        """Append `frame`'s wire form to `parts`; returns its payload
+        length."""
+        perf = self.messenger.perf
+        nbytes = frame.payload_len()
+        if onwire is None and nbytes >= SPILL_SIZE:
+            # plain crc mode, a payload the receiver will take
+            # into a body of its own: sent from where its bytes
+            # lie. The parts stay referenced by the transport's
+            # queue until the kernel has them (and, on a lossless
+            # session, by the message in _sent until it is acked).
+            parts.extend(frame.encode_parts())
+            perf.inc("tx_direct_bytes", nbytes)
+        else:
+            # a small frame costs less copied into one blob than
+            # as an iovec a part; the onwire transforms need the
+            # whole frame
+            blob = frame.encode()
+            parts.append(blob if onwire is None else onwire.wrap(blob))
+            perf.inc("tx_copied_bytes", nbytes)
+        return nbytes
 
     def _trim_sent(self, acked_seq: int) -> None:
         while self._sent and self._sent[0].seq <= acked_seq:

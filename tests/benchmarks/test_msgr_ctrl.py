@@ -1,0 +1,111 @@
+"""The readers of the messenger's control-frame and send counters
+(`msgr_ctrl_frames_per_op`, `msgr_ctrl_rode_pct`, `msgr_sends_per_op`),
+on hand-built snapshots and in a tiny traced run of each cell."""
+from __future__ import annotations
+
+import types
+
+import pytest
+
+from tests.benchmarks.test_benchmarks import BENCH, CELLS, _tiny
+from tests.benchmarks.test_msgr_rx import ROOT
+from benchmarks import harness
+
+NEW = ["msgr_ctrl_frames_per_op", "msgr_ctrl_rode_pct", "msgr_sends_per_op"]
+SHAPE = {"msgr_ctrl_frames_per_op": ("frames/op", "lower"),
+         "msgr_ctrl_rode_pct": ("%", "higher"),
+         "msgr_sends_per_op": ("sends/op", "lower")}
+
+
+def _reader(name):
+    return harness._load_module(ROOT, "layer_metrics", name)
+
+
+def _ctx(before, after, ops=10):
+    return types.SimpleNamespace(open={"msgr": before},
+                                 close={"msgr": after}, ops=ops)
+
+
+def _counters(ctrl, rode, sends, **more):
+    return dict(ctrl_frames_tx=ctrl, ctrl_rode_tx=rode, tx_sends=sends,
+                frames_tx=5, tx_direct_bytes=1, **more)
+
+
+def test_the_three_entries_are_appended_and_nothing_before_them_moved():
+    """A prefix check (34 entries stood before this PR), so that the
+    next PR's entries do not fail it."""
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert names[34:37] == NEW
+    assert names[24:26] == ["msgr_rx_direct_pct", "msgr_recvs_per_mib"]
+    assert names[32:34] == ["decode_bitmatrix_roofline",
+                            "msgr_tx_direct_pct"]
+    for entry in BENCH["per_layer"][34:37]:
+        unit, better = SHAPE[entry["name"]]
+        assert entry == {"name": entry["name"], "unit": unit,
+                         "better": better, "source": "program_counter",
+                         "layer": "msg/messenger", "moves": "ops_s"}
+        mod = _reader(entry["name"])
+        assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == (
+            entry["name"], unit, "msg/messenger", "ops_s")
+
+
+@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("case", ["no_counters", "one_counter_missing",
+                                  "only_at_close", "no_ops",
+                                  "no_msgr_group"])
+def test_reader_finds_nothing_where_there_is_nothing_to_read(name, case):
+    """The parent commit sends every frame alone and has no such
+    counters: the readers return nothing there and do not raise; a
+    window in which no op completed has no per-op figure."""
+    old = {"frames_tx": 1, "tx_direct_bytes": 9, "tx_copied_bytes": 1}
+    ctx = {
+        "no_counters": _ctx(old, old),
+        "one_counter_missing": _ctx(
+            {k: v for k, v in _counters(0, 0, 0).items()
+             if k != "tx_sends"},
+            {k: v for k, v in _counters(9, 9, 9).items()
+             if k != "tx_sends"}),
+        "only_at_close": _ctx(old, _counters(50, 40, 300)),
+        "no_ops": _ctx(_counters(0, 0, 0), _counters(50, 40, 300), ops=0),
+        "no_msgr_group": types.SimpleNamespace(open={}, close={}, ops=10),
+    }[case]
+    assert _reader(name).read(ctx) is None
+
+
+def test_a_window_without_a_control_frame_has_no_share_but_has_counts():
+    ctx = _ctx(_counters(7, 3, 100), _counters(7, 3, 180), ops=8)
+    assert _reader("msgr_ctrl_rode_pct").read(ctx) is None
+    assert _reader("msgr_ctrl_frames_per_op").read(ctx) == 0.0
+    assert _reader("msgr_sends_per_op").read(ctx) == 10.0
+
+
+@pytest.mark.parametrize("ctrl,rode,sends,ops,want", [
+    (100, 50, 400, 20, (5.0, 50.0, 20.0)),
+    (9, 9, 27, 3, (3.0, 100.0, 9.0)),
+    (8, 0, 8, 16, (0.5, 0.0, 0.5)),
+    (1000, 925, 2700, 100, (10.0, 92.5, 27.0)),
+])
+def test_values_are_the_windows_deltas(ctrl, rode, sends, ops, want):
+    before = _counters(700, 600, 5000)
+    after = _counters(700 + ctrl, 600 + rode, 5000 + sends)
+    got = tuple(_reader(n).read(_ctx(before, after, ops)) for n in NEW)
+    assert got == pytest.approx(want)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_tiny_traced_run_reports_the_control_frames(cell, tmp_path):
+    """Every cell's ops are answered over connections that owe acks:
+    the line of a traced run has all three metrics, control frames do
+    ride, and no op costs fewer sends than one."""
+    done, _cell = _tiny(cell, trace=True, tmp=tmp_path)
+    line = done["result"]
+    assert line["correct"] is True
+    got = {n: line["metrics"][n] for n in NEW}
+    assert {n: g["unit"] for n, g in got.items()} == {
+        n: SHAPE[n][0] for n in NEW}
+    assert got["msgr_ctrl_frames_per_op"]["value"] > 0
+    assert 0.0 < got["msgr_ctrl_rode_pct"]["value"] <= 100.0
+    assert got["msgr_sends_per_op"]["value"] >= 1.0
+    assert got["msgr_sends_per_op"]["value"] <= \
+        line["metrics"]["msgr_frames_per_op"]["value"] \
+        + got["msgr_ctrl_frames_per_op"]["value"]
