@@ -247,10 +247,12 @@ class MemStore(ObjectStore):
     def read(self, cid: CollectionId, oid: Ghobject, offset: int = 0,
              length: int | None = None) -> bytes:
         with self._lock:
-            data = self._obj(cid, oid).data
-            if length is None:
-                return bytes(data[offset:])
-            return bytes(data[offset:offset + length])
+            # through a view: one copy, where a slice of the bytearray
+            # made one and bytes() another
+            with memoryview(self._obj(cid, oid).data) as data:
+                if length is None:
+                    return bytes(data[offset:])
+                return bytes(data[offset:offset + length])
 
     def getattr(self, cid: CollectionId, oid: Ghobject, name: str) -> bytes:
         with self._lock:

@@ -227,10 +227,11 @@ class _Bucket:
     """Pending jobs that can share one device dispatch."""
 
     __slots__ = ("key", "jobs", "nbytes", "dispatch", "fallback",
-                 "shard_dispatch", "linger_task", "uses_device")
+                 "shard_dispatch", "linger_task", "uses_device", "pad_rows")
 
     def __init__(self, key: tuple, dispatch: Callable, fallback: Callable,
-                 uses_device: bool, shard_dispatch: Callable | None = None):
+                 uses_device: bool, shard_dispatch: Callable | None = None,
+                 pad_rows: Callable | None = None):
         self.key = key
         self.jobs: list[_Job] = []
         self.nbytes = 0
@@ -244,6 +245,9 @@ class _Bucket:
         # the circuit breaker entirely: their success says nothing about
         # the device, and must not close a tripped breaker
         self.uses_device = uses_device
+        #: rows -> the rows its batch is staged at (None: as many as the
+        #: jobs have); the results of the rows past the jobs' are dropped
+        self.pad_rows = pad_rows
 
 
 class _DeviceState:
@@ -521,7 +525,13 @@ class OffloadService:
                       "batched_ops": 0, "mesh_batches": 0,
                       "device_spills": 0, "device_failovers": 0,
                       "dec_jobs": 0, "dec_batches": 0, "dec_bytes": 0,
-                      "dec_out_bytes": 0}
+                      "dec_out_bytes": 0,
+                      "crc_jobs": 0, "crc_batches": 0, "crc_bytes": 0}
+        # block sizes whose device crc programs this service keeps
+        # ready while `crc_device` is on (`prepare_crc`)
+        self._crc_block_sizes: set[int] = set()
+        self._crc_warming: asyncio.Task | None = None
+        self._crc_warm_wanted = False
         # per-device utilization: busy wall time / bytes / batches per
         # dispatch target; fallback and host-native batches are
         # attributed to "host". Keys are the slot labels plus "host".
@@ -585,6 +595,7 @@ class OffloadService:
             self.enabled = bool(value)
         elif name == "ec_offload_max_batch_bytes":
             self.max_batch_bytes = int(value)
+            self._warm_crc()        # a larger batch is another program
         elif name == "ec_offload_linger_ms":
             self.linger_ms = float(value)
         elif name == "ec_offload_max_queue_bytes":
@@ -605,6 +616,7 @@ class OffloadService:
             self.breaker_reset_s = float(value)
         elif name == "ec_offload_crc_device":
             self.crc_device = bool(value)
+            self._warm_crc()
         elif name == "ec_offload_device_count":
             self.device_count = int(value)
             # in-flight batches keep their slot refs; new flushes see
@@ -770,9 +782,18 @@ class OffloadService:
         buffer would cross the link for a checksum the native kernel
         computes in place; ec_offload_crc_device moves it to the
         device) — either way the work leaves the event loop and
-        coalesces across callers."""
+        coalesces across callers.
+
+        On the device a batch is staged at `crc32c.batch_rows` of its
+        blocks and a job holds `max_batch_bytes` at most (a larger one
+        is split), so every batch runs one of the few programs
+        `prepare_crc` keeps ready. A device batch is tagged `blocks`,
+        `block_size` and `padded_blocks` on its `offload_batch` span and
+        counted in `stats` as `crc_jobs`, `crc_batches`, `crc_bytes`
+        (unpadded)."""
         key = ("crc", bool(self.crc_device), block_size)
         use_device = self.crc_device
+        pad_rows = None
 
         def dispatch(batch: np.ndarray) -> np.ndarray:
             if use_device:
@@ -788,8 +809,67 @@ class OffloadService:
                       for b in blocks]
         else:
             blocks = np.ascontiguousarray(blocks)
+        if use_device:
+            from ceph_tpu.ops.crc32c import batch_rows as pad_rows
+            if self._crc_warming is not None:
+                # programs are being made ready: a job that came now
+                # would only compile its own shape beside them
+                await asyncio.shield(self._crc_warming)
+            most = self._crc_job_blocks(block_size)
+            if (sum(b.shape[0] for b in blocks) if isinstance(blocks, list)
+                    else blocks.shape[0]) > most:
+                return np.concatenate(await asyncio.gather(*[
+                    self._submit(key, j, dispatch, fallback,
+                                 pad_rows=pad_rows)
+                    for j in _split_rows(blocks, most)]))
         return await self._submit(key, blocks, dispatch, fallback,
-                                  uses_device=use_device)
+                                  uses_device=use_device, pad_rows=pad_rows)
+
+    def _crc_job_blocks(self, block_size: int) -> int:
+        """The most blocks one device crc job holds. A bucket flushes
+        once it holds `max_batch_bytes`, so a batch stays under twice
+        this many."""
+        return max(1, self.max_batch_bytes // block_size)
+
+    def prepare_crc(self, block_size: int) -> None:
+        """A pool checksums blocks of `block_size`: while `crc_device`
+        is on, keep the device programs for it compiled or loaded
+        (`Crc32cDevice.warm`, on every slot, in the staging pool), from
+        now or from the moment the option turns on, so that no batch of
+        a served window compiles. Called on the loop."""
+        if block_size not in self._crc_block_sizes:
+            self._crc_block_sizes.add(block_size)
+            self._warm_crc()
+
+    def _warm_crc(self) -> None:
+        if not self.crc_device or not self._crc_block_sizes \
+                or self._loop.is_closed():
+            return
+        if not self._on_loop():     # an observer on an admin thread
+            self._loop.call_soon_threadsafe(self._warm_crc)
+            return
+        self._crc_warm_wanted = True
+        if self._crc_warming is not None:
+            return                  # the running pass goes round again
+
+        async def warm() -> None:
+            from ceph_tpu.ops import crc32c as crc_dev
+            try:
+                while self._crc_warm_wanted:
+                    self._crc_warm_wanted = False
+                    for slot in self._topology():
+                        for n in sorted(self._crc_block_sizes):
+                            await self._loop.run_in_executor(
+                                _executor(), crc_dev.get_device_crc(n).warm,
+                                slot.jdev, 2 * self._crc_job_blocks(n))
+            except Exception as e:
+                # the first batch of each shape compiles it instead
+                dout("offload", 1, f"crc warm-up failed: "
+                                   f"{type(e).__name__}: {e}")
+            finally:
+                self._crc_warming = None
+        self._crc_warming = self._loop.create_task(warm())
+        self._track(self._crc_warming)
 
     async def repair(self, ec_impl, helpers: tuple[int, ...],
                      want: tuple[int, ...], frags: np.ndarray,
@@ -848,7 +928,8 @@ class OffloadService:
     async def _submit(self, key: tuple, data: np.ndarray,
                       dispatch: Callable, fallback: Callable,
                       uses_device: bool = True,
-                      shard_dispatch: Callable | None = None) -> np.ndarray:
+                      shard_dispatch: Callable | None = None,
+                      pad_rows: Callable | None = None) -> np.ndarray:
         if not self.enabled:
             return self._inline(data, dispatch, fallback, uses_device)
         nbytes = int(sum(f.nbytes for f in data)) \
@@ -862,7 +943,7 @@ class OffloadService:
         if bucket is None:
             bucket = self._buckets[key] = _Bucket(key, dispatch, fallback,
                                                   uses_device,
-                                                  shard_dispatch)
+                                                  shard_dispatch, pad_rows)
             bucket.linger_task = self._loop.create_task(
                 self._linger_flush(key))
             self._track(bucket.linger_task)
@@ -1049,7 +1130,8 @@ class OffloadService:
             # run once the loop gets a turn
             await asyncio.sleep(0)
 
-    def _stack(self, slot: _DeviceSlot, jobs: list[_Job]):
+    def _stack(self, slot: _DeviceSlot, jobs: list[_Job],
+               pad_rows: Callable | None = None):
         """Jobs -> one contiguous batch. A lone single-array job is
         handed through by reference (zero-copy: the memoryview-through
         path from bufferlist to staging); everything else — coalesced
@@ -1057,22 +1139,27 @@ class OffloadService:
         into the slot's REUSED staging array (warm pages, no
         intermediate bufferlist join anywhere on the path; the old
         b"".join the callers did before submitting showed up as an
-        unmetered extra copy of every csum'd byte). Returns
-        (stacked, staging_buf_or_None, stack_seconds)."""
+        unmetered extra copy of every csum'd byte). With `pad_rows` the
+        batch is staged at `pad_rows(rows)` rows: those past the jobs'
+        keep what the page held, and nobody reads their results.
+        Returns (stacked, staging_buf_or_None, stack_seconds)."""
         frags: list[np.ndarray] = []
         for j in jobs:
             if isinstance(j.data, list):
                 frags.extend(j.data)
             else:
                 frags.append(j.data)
-        if len(frags) == 1:
+        rows = sum(f.shape[0] for f in frags)
+        staged = rows if pad_rows is None else pad_rows(rows)
+        if len(frags) == 1 and staged == rows:
             copytrack.referenced("buffer_to_staging", jobs[0].nbytes)
             return frags[0], None, 0.0
         nbytes = sum(int(f.nbytes) for f in frags)
-        rows = sum(f.shape[0] for f in frags)
+        row_bytes = frags[0].itemsize * int(np.prod(frags[0].shape[1:]))
         t0 = time.perf_counter()
-        buf = slot.get_staging(nbytes)
-        view = buf[:nbytes].reshape((rows,) + frags[0].shape[1:])
+        buf = slot.get_staging(staged * row_bytes)
+        view = buf[:staged * row_bytes].reshape(
+            (staged,) + frags[0].shape[1:])
         row = 0
         for f in frags:
             np.copyto(view[row:row + f.shape[0]], f)
@@ -1113,8 +1200,9 @@ class OffloadService:
                         if j.span is not None:
                             j.span.set_tag("batch_ops", len(jobs))
                             j.span.finish()
-                    stacked, staging, stack_s = self._stack(slot, jobs)
-                    nbytes = int(stacked.nbytes)
+                    stacked, staging, stack_s = self._stack(
+                        slot, jobs, bucket.pad_rows)
+                    nbytes = sum(j.nbytes for j in jobs)
                     with tracer.span("offload_batch") as sp:
                         if sp is not None:
                             # span links (tracing v2): the coalesced
@@ -1139,6 +1227,12 @@ class OffloadService:
                             if bucket.key[0] == "dec":
                                 sp.tags.update(decode_batch_tags(
                                     *bucket.key[2:4]))
+                            elif bucket.key[0] == "crc":
+                                sp.set_tag("blocks", sum(j.rows
+                                                         for j in jobs))
+                                sp.set_tag("block_size", bucket.key[2])
+                                sp.set_tag("padded_blocks",
+                                           int(stacked.shape[0]))
                         out, on_device = await self._dispatch(
                             bucket, slot, stacked, len(jobs), sp,
                             token)
@@ -1167,6 +1261,10 @@ class OffloadService:
                         self.stats["dec_batches"] += 1
                         self.stats["dec_bytes"] += nbytes
                         self.stats["dec_out_bytes"] += int(out.nbytes)
+                    elif bucket.key[0] == "crc" and on_device != "host":
+                        self.stats["crc_jobs"] += len(jobs)
+                        self.stats["crc_batches"] += 1
+                        self.stats["crc_bytes"] += nbytes
                 except asyncio.CancelledError:
                     raise
                 except Exception as e:
@@ -1593,6 +1691,24 @@ def _host_apply(M: np.ndarray, batch: np.ndarray) -> np.ndarray:
     out = gf256.mat_vec_apply(np.ascontiguousarray(M, dtype=np.uint8), flat)
     return np.ascontiguousarray(
         out.reshape(M.shape[0], S, C).transpose(1, 0, 2))
+
+
+def _split_rows(blocks, most: int) -> list[list]:
+    """`blocks` (one (n, block) array or a list of them) as scatter jobs
+    of `most` rows at the most each, in order; views, nothing copied."""
+    jobs, cur, n = [], [], 0
+    for f in blocks if isinstance(blocks, list) else [blocks]:
+        while f.shape[0]:
+            take = min(f.shape[0], most - n)
+            cur.append(f[:take])
+            f = f[take:]
+            n += take
+            if n == most:
+                jobs.append(cur)
+                cur, n = [], 0
+    if cur:
+        jobs.append(cur)
+    return jobs
 
 
 def _host_crc(batch: np.ndarray, block_size: int) -> np.ndarray:

@@ -86,10 +86,10 @@ class OSD(Dispatcher):
                    "silence before reporting a peer failed",
                    minimum=0.05),
             Option("osd_scrub_interval", "float", self.SCRUB_INTERVAL,
-                   "seconds between background scrub rounds",
-                   minimum=0.05),
+                   "seconds from the end of a PG's scrub round until "
+                   "its next is due (hot)", minimum=0.05),
             Option("osd_deep_scrub_every", "int", self.DEEP_SCRUB_EVERY,
-                   "every Nth scrub round re-reads data", minimum=1),
+                   "every Nth round of a PG re-reads data", minimum=1),
             Option("osd_scrub_chunk_max", "int", 32,
                    "objects scanned per scrub chunk; each chunk costs "
                    "one QoS grant under the scrub class, so smaller "
@@ -103,10 +103,10 @@ class OSD(Dispatcher):
                    "before a round may gate client writes (the "
                    "reference's scrub reserver; hot)"),
             Option("osd_scrub_reserve_timeout", "float", 10.0,
-                   "seconds a primary waits for a local or remote "
-                   "scrub reservation before aborting the round — the "
-                   "path that breaks crossed-reservation deadlocks "
-                   "(hot)", minimum=0.1),
+                   "seconds a primary waits for a peer's answer to a "
+                   "scrub reservation before giving the round up: the "
+                   "bound on a peer that has gone quiet, since a busy "
+                   "one rejects at once (hot)", minimum=0.1),
             Option("osd_max_scrubs", "int", 1,
                    "concurrent scrub rounds this daemon will take part "
                    "in, as primary or replica (hot: resizes the live "
@@ -460,10 +460,9 @@ class OSD(Dispatcher):
         self.config.add_observer(("osd_max_recovery_in_flight",),
                                  self._on_recovery_slots)
         # host-wide scrub slots (osd_max_scrubs): a round — primary- or
-        # replica-side — holds one for its whole duration. Named, so
-        # when lockdep is armed every park on the pool is a tracked
-        # wait and every holder a tracked task; the entity detail rides
-        # into the mgr deadlock annotations.
+        # replica-side — holds one for its whole duration, taken only
+        # when free (`try_acquire`: nobody parks here). Named, so when
+        # lockdep is armed every holder is a tracked task.
         self.scrub_reservations = AdjustableSemaphore(
             self.config.get("osd_max_scrubs"),
             name=f"osd.{self.whoami}:scrub_reservations")
@@ -471,6 +470,14 @@ class OSD(Dispatcher):
             "entity": f"osd.{self.whoami}"}
         # remote grants held for other primaries: (pool, ps, tid, from)
         self._scrub_remote_grants: set[tuple] = set()
+        # the primaries' PGs in the order they are due, served one
+        # round at a time by `_scrub_loop`; `_scrub_kick` wakes it for
+        # an operator's request or a slot given back
+        self._scrub_queue = scrub_mod.ScrubQueue()
+        self._scrub_kick: asyncio.Event | None = None
+        # its place in the turn: no round of its own before this
+        # (`scrub_slot_freed`; on time.monotonic())
+        self._scrub_hold_until = 0.0
         self.config.add_observer(("osd_max_scrubs",),
                                  self._on_scrub_slots)
         # fault injection: a hang deadline makes dispatch swallow
@@ -727,7 +734,10 @@ class OSD(Dispatcher):
 
     def _on_scrub_slots(self, name: str, value) -> None:
         """osd_max_scrubs observer: resize the live scrub slot pool."""
-        self._run_on_loop(self.scrub_reservations.resize, int(value))
+        def resize(n: int) -> None:
+            self.scrub_reservations.resize(n)
+            self.scrub_slot_freed(0.0)
+        self._run_on_loop(resize, int(value))
 
     def _inject_admin(self, req: dict) -> dict:
         """`inject` admin-socket verbs — the same injector the config
@@ -842,53 +852,54 @@ class OSD(Dispatcher):
                         / prog.objects_total, 4)})
         return out
 
-    def _spawn_scrubs(self, deep: bool) -> dict[str, asyncio.Task]:
-        """One scrub task per primary active PG, each held in _bg_tasks
-        (reaped at stop(), failures crash-recorded) AND returned by
-        handle so callers can await real per-PG results."""
-        tasks: dict[str, asyncio.Task] = {}
-        for pgid, pg in list(self.pgs.items()):
-            if pg.is_primary() and pg.state == "active":
-                task = asyncio.get_running_loop().create_task(
-                    pg.scrub(deep=deep))
-                # hold a strong ref (the loop keeps only a weak one) and
-                # surface repair failures in the log
-                self._bg_tasks.add(task)
-                task.add_done_callback(self._bg_task_done)
-                tasks[f"{pgid.pool}.{pgid.ps}"] = task
-        return tasks
+    def _scrub_primaries(self) -> dict:
+        """pgid -> PG, for the PGs this OSD is the active primary of."""
+        return {pgid: pg for pgid, pg in list(self.pgs.items())
+                if pg.is_primary() and pg.state == "active"}
+
+    def _request_scrubs(self, deep: bool) -> dict[str, asyncio.Future]:
+        """Put every primary PG at the head of the scrub queue, to be
+        scrubbed `deep` or light whatever its turn would have been, and
+        wake the scheduler: the operator's `scrub`. On the loop. Returns
+        a future per PG that resolves to the round's result (None where
+        the round died or the PG left this OSD meanwhile)."""
+        loop = asyncio.get_running_loop()
+        primaries = self._scrub_primaries()
+        self._scrub_queue.sync(primaries, time.monotonic())
+        futs: dict[str, asyncio.Future] = {}
+        for pgid in primaries:
+            futs[f"{pgid.pool}.{pgid.ps}"] = fut = loop.create_future()
+            self._scrub_queue.request(pgid, deep, fut)
+        if self._scrub_kick is not None:
+            self._scrub_kick.set()
+        return futs
 
     def _trigger_scrub(self, deep: bool) -> dict:
-        """Kick a scrub of every primary PG. From the loop the tasks
-        are spawned inline; from an admin-socket thread the spawn hops
-        to the daemon's loop (tasks can only be created there) and the
-        reply lists the PGs that will be scheduled."""
+        """Kick a scrub of every primary PG. From the loop the requests
+        are queued inline; from an admin-socket thread the queueing
+        hops to the daemon's loop (the queue is only coherent there)
+        and the reply lists the PGs that will be scheduled."""
         try:
             on_loop = asyncio.get_running_loop() is self._loop
         except RuntimeError:
             on_loop = False
         if on_loop:
-            pgs = sorted(self._spawn_scrubs(deep))
+            pgs = sorted(self._request_scrubs(deep))
         else:
             pgs = sorted(f"{pgid.pool}.{pgid.ps}"
-                         for pgid, pg in list(self.pgs.items())
-                         if pg.is_primary() and pg.state == "active")
-            self._run_on_loop(self._spawn_scrubs, deep)
+                         for pgid in self._scrub_primaries())
+            self._run_on_loop(self._request_scrubs, deep)
         return {"scheduled": len(pgs), "deep": deep, "pgs": pgs}
 
     async def scrub_all(self, deep: bool = False) -> dict[str, dict]:
         """Scrub every primary PG and return {pg: result} — the awaited
-        form of the fire-and-forget `scrub` admin verb. Waits without
-        cancelling; a failed PG's slot is None (the failure is already
-        crash-recorded by _bg_task_done)."""
-        tasks = self._spawn_scrubs(deep)
-        await drain_all(tasks.values())
-        out: dict[str, dict] = {}
-        for key, task in tasks.items():
-            out[key] = (task.result()
-                        if not task.cancelled()
-                        and task.exception() is None else None)
-        return out
+        form of the fire-and-forget `scrub` admin verb, through the same
+        queue and so one round at a time. A failed PG's slot is None
+        (the failure is already crash-recorded by _bg_task_done); one
+        whose reservations were all rejected reports `reserve_failed`."""
+        futs = self._request_scrubs(deep)
+        await drain_all(futs.values())
+        return {key: fut.result() for key, fut in futs.items()}
 
     def _list_inconsistent(self, pool=None) -> dict:
         """Admin `list-inconsistent-obj`: the per-PG registries of every
@@ -961,27 +972,75 @@ class OSD(Dispatcher):
             # listable via `crash ls`
             crash.record(f"osd.{self.whoami}", e)
 
+    def scrub_slot_freed(self, hold: float) -> None:
+        """A round has given this daemon's slot back: the scheduler
+        may have a turn, `hold` seconds from now (`scrub.turn_hold`:
+        its place behind the primary of the round that ended)."""
+        self._scrub_hold_until = time.monotonic() + hold
+        if self._scrub_kick is not None:
+            self._scrub_kick.set()
+
     async def _scrub_loop(self) -> None:
-        """Background scrub scheduler: every SCRUB_INTERVAL, scrub each
-        PG this OSD is primary of (the reference's OSD::sched_scrub);
-        every DEEP_SCRUB_EVERY-th round re-reads data (deep)."""
-        rounds = 0
-        last = time.monotonic()
+        """Background scrub scheduler (the reference's OSD::sched_scrub):
+        of the PGs this OSD is primary of, the one that has been due
+        longest is scrubbed, ONE round at a time, the next as soon as
+        the last has ended; every `osd_deep_scrub_every`-th round of a
+        PG re-reads data (deep). A round whose reservation was rejected
+        puts its PG back for `retry_delay` and the next PG has its
+        turn. The loop starts no round while this daemon's slots are
+        taken by other primaries' rounds (it would be rejected at
+        home); it looks again when one is given back, after the hold
+        its place in the turn asks for (`scrub_slot_freed`; one place
+        after a rejection: the round that won is taking its slots). An
+        operator's request is not held."""
+        self._scrub_kick = asyncio.Event()
+        queue, slots = self._scrub_queue, self.scrub_reservations
+        loop = asyncio.get_running_loop()
+
+        async def pause(seconds: float) -> None:
+            """`seconds`, or until a slot is freed or an operator asks."""
+            self._scrub_kick.clear()
+            timer = loop.call_later(seconds, self._scrub_kick.set)
+            try:
+                await self._scrub_kick.wait()
+            finally:
+                timer.cancel()
+
         while True:
-            # sleep in short slices so a runtime `config set
-            # osd_scrub_interval` takes effect without waiting out the
-            # previous interval
-            interval = self.config.get("osd_scrub_interval")
-            await asyncio.sleep(min(1.0, interval / 4))
-            if time.monotonic() - last < interval:
+            now = time.monotonic()
+            if slots.locked():
+                await pause(1.0)        # a kick says when one is back
                 continue
-            last = time.monotonic()
-            rounds += 1
-            deep = rounds % self.config.get("osd_deep_scrub_every") == 0
-            # per-PG tasks with real handles: failures are crash-
-            # recorded by _bg_task_done, stragglers are reaped at
-            # stop() via _bg_tasks — nothing fire-and-forget
-            await self.scrub_all(deep=deep)
+            if self._scrub_hold_until > now and not queue.asked():
+                await pause(self._scrub_hold_until - now)
+                continue
+            interval = self.config.get("osd_scrub_interval")
+            primaries = self._scrub_primaries()
+            queue.sync(primaries, now)
+            turn = queue.next(
+                now, interval, self.config.get("osd_deep_scrub_every"))
+            if turn is None:
+                # nothing is due: look again when something can be, in
+                # slices short enough that a runtime `config set
+                # osd_scrub_interval` takes effect without waiting out
+                # the previous interval
+                await pause(max(0.01, min(1.0, interval / 4,
+                                          queue.wait(now, interval))))
+                continue
+            pgid, deep = turn
+            # a task of its own, held in _bg_tasks: a failure is
+            # crash-recorded by _bg_task_done, a straggler reaped at
+            # stop() — nothing fire-and-forget
+            task = loop.create_task(primaries[pgid].scrub(deep=deep))
+            self._bg_tasks.add(task)
+            task.add_done_callback(self._bg_task_done)
+            await drain_all([task])
+            result = task.result() if not task.cancelled() \
+                and task.exception() is None else None
+            queue.done(pgid, time.monotonic(), result)
+            if result is not None and result.get("reserve_failed"):
+                self._scrub_hold_until = time.monotonic() \
+                    + scrub_mod.SCRUB_TURN_S
 
     async def _reboot_until_up(self) -> None:
         """Resend MOSDBoot until the map shows us up again (mirrors the
@@ -1019,6 +1078,8 @@ class OSD(Dispatcher):
             # leak's sibling)
             bg += list(self._bg_tasks) + list(self._notify_tasks)
             await reap_all(bg)
+            # nobody will run what operators still wait for
+            self._scrub_queue.sync((), time.monotonic())
             self._bg_tasks.clear()
             self._notify_tasks.clear()
             for pg in self.pgs.values():
@@ -1316,17 +1377,13 @@ class OSD(Dispatcher):
         if isinstance(msg, MOSDScrubReserve):
             pg = self._pg_of(msg, create=True)
             if pg is not None:
-                if msg.payload.get("op") == "reserve":
-                    # a reserve can park on the slot pool for seconds:
-                    # never on the dispatch loop, or every other
-                    # message from this peer (replication sub-ops,
-                    # heartbeats on shared conns) stalls behind it
-                    t = asyncio.get_running_loop().create_task(
-                        scrub_mod.handle_scrub_reserve(self, pg, msg))
+                answer = scrub_mod.handle_scrub_reserve(self, pg, msg)
+                if answer is not None:
+                    # the slot is decided; the answer's way out is not
+                    # this connection's dispatch loop's to wait for
+                    t = asyncio.get_running_loop().create_task(answer)
                     self._notify_tasks.add(t)
                     t.add_done_callback(self._notify_tasks.discard)
-                else:
-                    await scrub_mod.handle_scrub_reserve(self, pg, msg)
             return True
         from ceph_tpu.msg.messages import MWatchNotifyAck
         if isinstance(msg, MWatchNotifyAck):

@@ -88,6 +88,12 @@ class ECBackend(PGBackend):
         c = self.sinfo.chunk_size
         self._checksummer = Checksummer("crc32c", c) \
             if c & (c - 1) == 0 else None
+        # where the crc work may go to the device (a device-batched
+        # plugin, `ec_offload_crc_device`), its programs for this chunk
+        # size are ready before this pool serves
+        svc = self._offload_svc()
+        if svc is not None and self._checksummer is not None:
+            svc.prepare_crc(c)
         # crc of an all-zero chunk: hole stripes materialize as zeros
         self._zcrc = self._crc32c(b"\x00" * self.sinfo.chunk_size)
         # read gather plumbing: tid -> future resolving to (payload, data)

@@ -906,15 +906,22 @@ class PGInstance:
         # for, unpaced — the primary takes the QoS grant per range and
         # holds the write gate while replies are outstanding, so local
         # pacing here would only stretch the gated window.
-        from ceph_tpu.osd.scrub import build_scrub_map
+        # A request that names a reservation (`release`) is the
+        # round's last: the slot held for it goes back with the map.
+        from ceph_tpu.osd.scrub import build_scrub_map, give_back
         p = msg.payload
         rng = p.get("range")
-        conn.send_message(MOSDRepScrubMap(
-            {"pgid": p["pgid"], "tid": p["tid"], "from": self.host.whoami,
-             "map": await build_scrub_map(
-                 self, p.get("deep", False),
-                 oid_range=tuple(rng) if rng is not None else None,
-                 paced=False)}))
+        try:
+            conn.send_message(MOSDRepScrubMap(
+                {"pgid": p["pgid"], "tid": p["tid"],
+                 "from": self.host.whoami,
+                 "map": await build_scrub_map(
+                     self, p.get("deep", False),
+                     oid_range=tuple(rng) if rng is not None else None,
+                     paced=False)}))
+        finally:
+            if p.get("release") is not None:
+                give_back(self, p["release"], p["from"])
 
     def handle_scrub_map(self, msg) -> None:
         p = msg.payload

@@ -42,10 +42,39 @@ Observability (the continuous-integrity layer):
     mismatches/repairs/aborts drop flight-recorder crumbs and the
     process-wide "scrub" perf logger rides the mgr report leg.
 
-Idiomatic divergences: one round-trip map exchange instead of chunked
-scrub reservations/ranges (PGs here are small); light scrub compares
-size+attrs digests, deep scrub re-reads and re-hashes everything — same
-split as the reference's shallow/deep modes.
+Scheduling and reservations (the reference's OSD::sched_scrub and its
+scrub reserver):
+
+  * each OSD keeps the PGs it is primary of in the order they are due
+    (`ScrubQueue`: last round + `osd_scrub_interval`, an operator's
+    request first) and its `_scrub_loop` starts ONE round at a time;
+  * a round holds one `osd_max_scrubs` slot on every up member of its
+    acting set (`_reserve_acting_set`, MOSDScrubReserve): the lowest
+    id's first, then the others' at once, its own daemon's at its
+    place. Nobody waits for a slot: a daemon whose slots are taken
+    rejects at once, the primary gives back what it holds, the PG is
+    put back `retry_delay` later and the loop goes on to the next one.
+    A peer that does not answer costs one wait of
+    `osd_scrub_reserve_timeout`. A member gives its slot back when it
+    has sent its map of the round's last range (the request says so:
+    no message of its own), the primary when the round has ended;
+  * rounds follow one another without a pause, and the turn goes round
+    the acting set: a daemon whose slot comes back holds its own next
+    round back by SCRUB_TURN_S for every place it stands behind the
+    primary of the round that ended (`turn_hold`), so the next in line
+    asks first and the others find their slot taken before they ask;
+  * a round is one `scrub_round` span (pgid, deep, state, objects,
+    bytes and its legs: `reserve_us`, `grant_wait_us`, `scan_us`,
+    `digest_us`, `compare_us`, and what it found: `errors`,
+    `repaired`), every scan chunk on every OSD that
+    builds a map one `scrub_chunk` span (objects, bytes, blocks).
+
+Idiomatic divergences: one map exchange per range of
+`osd_scrub_chunk_max` names, the range's writes gated meanwhile, where
+the reference's chunky scrub also waits out per-object locks (PGs here
+are small); light scrub compares size+attrs digests, deep scrub
+re-reads and re-hashes everything — same split as the reference's
+shallow/deep modes.
 """
 from __future__ import annotations
 
@@ -60,7 +89,7 @@ import numpy as np
 from ceph_tpu.msg.messages import (MOSDRepScrub, MOSDRepScrubMap,
                                    MOSDScrubReserve)
 from ceph_tpu.objectstore.store import StoreError
-from ceph_tpu.utils import flight, sanitizer
+from ceph_tpu.utils import flight, sanitizer, tracer
 from ceph_tpu.utils.dout import dout
 from ceph_tpu.utils.perf_counters import (TYPE_HISTOGRAM,
                                           PerfCountersCollection)
@@ -75,6 +104,19 @@ SCRUB_PEER_TIMEOUT = 10.0
 #: robustness: the scheduler shapes scrub, it must never wedge it.
 #: On timeout the range proceeds ungranted (counted + crumbed).
 SCRUB_GRANT_TIMEOUT = 5.0
+#: how long a PG whose reservation was rejected is put back (stretched
+#: by up to as much again by `retry_delay`, so that primaries which
+#: were rejected together do not come back together)
+SCRUB_RETRY_S = 0.3
+#: a place in the turn (`turn_hold`): about the time a reservation
+#: takes to reach every member over a loaded loop, so that the daemon
+#: a place further back finds its slot taken when its own hold ends
+SCRUB_TURN_S = 0.1
+#: reservations an operator's request (`scrub_all`) may lose in a row
+#: before it is answered with the last `reserve_failed`
+SCRUB_REQUEST_ATTEMPTS = 40
+#: the legs of a round that its `scrub_round` span carries, as `<leg>_us`
+_LEGS = ("reserve", "grant_wait", "scan", "digest", "compare")
 _SCAN_YIELD_EVERY = 32      # objects hashed between event-loop yields
 _DIGEST_BLOCK = 4096        # replicated-pool digest batch block size
 
@@ -124,9 +166,9 @@ def scrub_perf():
                            "grant timed out (forward-progress escape "
                            "hatch)")
         pc.add("reserve_failures",
-               description="scrub rounds aborted because an acting-set "
-                           "reservation timed out or was rejected (the "
-                           "crossed-reservation deadlock breaker)")
+               description="scrub rounds given up because an acting-set "
+                           "member had no free slot (rejected at once) or "
+                           "did not answer in time")
         pc.add("digest_batch_blocks", type=TYPE_HISTOGRAM,
                description="blocks per offloaded digest batch")
         pc.add("digest_batch_us", type=TYPE_HISTOGRAM,
@@ -139,7 +181,7 @@ class ScrubProgress:
     while the round runs (mgr progress events + admin `last_scrub`)."""
 
     __slots__ = ("pgid", "deep", "state", "objects_total",
-                 "objects_scrubbed", "bytes_hashed", "started_mono")
+                 "objects_scrubbed", "bytes_hashed", "started_mono", "legs")
 
     def __init__(self, pgid, deep: bool):
         self.pgid = str(pgid)
@@ -149,6 +191,7 @@ class ScrubProgress:
         self.objects_scrubbed = 0
         self.bytes_hashed = 0
         self.started_mono = time.monotonic()
+        self.legs = dict.fromkeys(_LEGS, 0.0)   # seconds, on the primary
 
     def finish(self, state: str = "done") -> None:
         self.state = state
@@ -250,7 +293,19 @@ async def build_scrub_map(pg: "PGInstance", deep: bool,
 async def _scan_chunk(pg: "PGInstance", oids: list, deep: bool,
                       out: dict, progress: "ScrubProgress | None") -> None:
     """Scan one chunk of objects: metadata host-side, deep content
-    digests deferred into one `_digest_batch` offload job."""
+    digests deferred into one `_digest_batch` offload job. One
+    `scrub_chunk` span, on whichever OSD builds the map."""
+    with tracer.span("scrub_chunk") as sp:
+        nbytes, nblocks = await _scan_chunk_body(pg, oids, deep, out,
+                                                 progress)
+        if sp is not None:
+            sp.tags.update(objects=len(oids), bytes=nbytes, blocks=nblocks)
+
+
+async def _scan_chunk_body(pg: "PGInstance", oids: list, deep: bool,
+                           out: dict, progress: "ScrubProgress | None"
+                           ) -> tuple[int, int]:
+    """-> (content bytes, blocks) digested for the chunk."""
     from ceph_tpu.native import ec_native
     store = pg.host.store
     cid = pg.backend.coll()
@@ -292,12 +347,12 @@ async def _scan_chunk(pg: "PGInstance", oids: list, deep: bool,
             dout("scrub", 1, f"scrub read {oid}: {e}")
             ent["corrupt"] = True
         out[oid] = ent
-    if pend:
-        await _digest_batch(pg, pend, progress)
+    return await _digest_batch(pg, pend, progress) if pend else (0, 0)
 
 
 async def _digest_batch(pg: "PGInstance", pend: list,
-                        progress: "ScrubProgress | None") -> None:
+                        progress: "ScrubProgress | None"
+                        ) -> tuple[int, int]:
     """Hash one chunk's content as a single crc32c block batch through
     the offload service (host fallback: the same `ec_native`
     slice-by-8 kernel — bit-identical either way). EC shards check the
@@ -342,19 +397,20 @@ async def _digest_batch(pg: "PGInstance", pend: list,
         if ec:
             # the length check already ran; every stored csum entry has
             # a freshly hashed counterpart
-            for s in range(len(csum)):
-                if int(mine[s]) != int(csum[s]):
-                    ent["corrupt"] = True
-                    break
+            if not np.array_equal(mine[:len(csum)],
+                                  np.asarray(csum, dtype=np.uint32)):
+                ent["corrupt"] = True
         else:
             ent["digest"] = _fold_digest(mine, len(data))
     perf.inc("bytes_hashed", total_bytes)
     perf.inc("objects_hashed", len(pend))
     perf.hist_add("digest_batch_blocks", nblocks)
-    perf.hist_add("digest_batch_us",
-                  (time.perf_counter() - t0) * 1e6)
+    dt = time.perf_counter() - t0
+    perf.hist_add("digest_batch_us", dt * 1e6)
     if progress is not None:
         progress.bytes_hashed += total_bytes
+        progress.legs["digest"] += dt
+    return total_bytes, nblocks
 
 
 def _fold_digest(crcs: np.ndarray, total_len: int) -> int:
@@ -412,90 +468,225 @@ def _note_repaired(pg: "PGInstance", oid: str, osd: int, ok: bool,
         entry["repaired"] = True
 
 
+def _drawn(who: int, attempt: int) -> float:
+    """A share in [0, 1) that only `who` and `attempt` decide."""
+    return (who * 2654435761 + attempt * 40503) % 1021 / 1021.0
+
+
+def turn_hold(pg: "PGInstance", primary: int) -> float:
+    """How long this daemon holds its own next round back when a round
+    of `primary` on `pg` has given its slot back: SCRUB_TURN_S for
+    every place it stands behind that primary in the acting set, in
+    the order of the ids and round again, the primary itself last.
+    Daemons cannot see each other's queues; this lets the one next in
+    line ask first (after one place: a member is freed with its last
+    map, a little before the slowest member and the primary are) and
+    the others long enough after it to find their own slot taken,
+    without a round trip to be told so. No daemon is passed over for
+    good, whatever its id, and nothing is drawn: a schedule explorer's
+    replays see the same holds."""
+    me = pg.host.whoami
+    members = sorted({me, primary, *(
+        o for o in pg.acting_peers() if pg.host.osdmap.is_up(o))})
+    behind = (members.index(me) - members.index(primary) - 1) % len(members)
+    return SCRUB_TURN_S * (behind + 1)
+
+
+def retry_delay(who: int, attempt: int) -> float:
+    """SCRUB_RETRY_S stretched by up to as much again. The stretch is
+    drawn from `who` waits (a PG) and from the attempt and from nothing
+    else, so that a schedule explorer's replays see the same delays."""
+    return SCRUB_RETRY_S * (1.0 + _drawn(who, attempt))
+
+
+class ScrubQueue:
+    """The PGs an OSD is primary of, in the order their scrubs are due
+    (the reference's OSD::sched_scrub queue). A PG is due `interval`
+    after its last round ended, or after the queue first saw it; one
+    whose reservation was rejected is put back `retry_delay` later; an
+    operator's request (`request`) is due at once and goes first.
+    Times are `time.monotonic()`; nothing here waits or sends."""
+
+    class Job:
+        __slots__ = ("since", "not_before", "attempts", "rounds",
+                     "request")
+
+        def __init__(self, now: float):
+            self.since = now            # last round's end, or first seen
+            self.not_before = 0.0       # put back until then
+            self.attempts = 0           # reservations lost in a row
+            self.rounds = 0             # rounds finished
+            self.request = None         # (deep, future) of an operator
+
+    def __init__(self):
+        self.jobs: dict = {}            # pgid -> Job
+
+    def sync(self, primaries, now: float) -> None:
+        """`primaries`: the pgids this OSD is the active primary of. A
+        PG that left takes its request with it, unanswered."""
+        for pgid in set(self.jobs) - set(primaries):
+            job = self.jobs.pop(pgid)
+            if job.request is not None and not job.request[1].done():
+                job.request[1].set_result(None)
+        for pgid in primaries:
+            if pgid not in self.jobs:
+                self.jobs[pgid] = self.Job(now)
+
+    def request(self, pgid, deep: bool, fut) -> None:
+        job = self.jobs[pgid]
+        if job.request is not None and not job.request[1].done():
+            job.request[1].set_result(None)     # superseded
+        job.request = (deep, fut)
+        job.not_before = 0.0
+
+    def next(self, now: float, interval: float, deep_every: int):
+        """(pgid, deep) of the round to start now, or None."""
+        ready = [(job.request is None, job.since, pgid)
+                 for pgid, job in self.jobs.items()
+                 if job.not_before <= now
+                 and (job.request is not None
+                      or job.since + interval <= now)]
+        if not ready:
+            return None
+        pgid = min(ready)[2]
+        job = self.jobs[pgid]
+        if job.request is not None:
+            return pgid, job.request[0]
+        return pgid, (job.rounds + 1) % max(1, deep_every) == 0
+
+    def asked(self) -> bool:
+        """An operator is waiting for a round."""
+        return any(job.request is not None for job in self.jobs.values())
+
+    def wait(self, now: float, interval: float) -> float:
+        """Seconds until some PG can be due (0: one is)."""
+        return max(0.0, min(
+            (job.not_before if job.request is not None
+             else max(job.not_before, job.since + interval)
+             for job in self.jobs.values()), default=interval) - now)
+
+    def done(self, pgid, now: float, result: dict | None) -> None:
+        """The round `next` named has ended with `result` (None: it
+        died). A lost reservation puts the PG back; anything else is a
+        round, and answers the operator who asked for it."""
+        job = self.jobs.get(pgid)
+        if job is None:
+            return
+        lost = result is not None and result.get("reserve_failed")
+        if lost:
+            job.attempts += 1
+            job.not_before = now + retry_delay(
+                pgid.pool * 65599 + pgid.ps, job.attempts)
+            if job.request is None \
+                    or job.attempts < SCRUB_REQUEST_ATTEMPTS:
+                return
+        else:
+            job.since = now
+            job.rounds += 1
+        job.attempts = 0
+        if job.request is not None:
+            if not job.request[1].done():
+                job.request[1].set_result(result)
+            job.request = None
+
+
 async def _reserve_acting_set(pg: "PGInstance",
                               tid: int) -> tuple[bool, list[int]]:
-    """Claim one `osd_max_scrubs` slot on self and every up acting
-    peer before the round may gate client writes (the reference's
-    scrub reserver: OSD::sched_scrub + MOSDScrubReserve). Local slot
-    first, then peers in ascending id, every wait bounded by
-    `osd_scrub_reserve_timeout`: crossed reservations between two
-    primaries therefore stall only until one side's timeout fires,
-    releases everything it holds, and retries later — the abort path
-    that breaks the cycle. While a remote wait is parked it is
-    registered with lockdep under the PEER's slot name, which is the
-    inter-OSD edge the in-process watchdog and the mgr's cross-daemon
-    wait-for graph report."""
+    """Claim one `osd_max_scrubs` slot on every up member of the acting
+    set, this daemon among them, before the round may gate client
+    writes (the reference's scrub reserver: OSD::sched_scrub +
+    MOSDScrubReserve). The member with the lowest id is asked first
+    and alone, the others at once when it has granted; this daemon's
+    own slot is taken, not asked for, at its place in that order. And
+    nobody waits for a slot: a daemon whose slots are taken says so at
+    once, and the round gives back what it holds and reports
+    `reserve_failed`. One first stop for all means that primaries which
+    want the same daemons meet there while they hold nothing: where
+    every PG spans every OSD, as on the benchmark's pool, the losers
+    are turned away by the first daemon they ask and the winner is
+    never crossed. The one wait left is for a peer's answer, bounded by
+    `osd_scrub_reserve_timeout`; while it lasts it is registered with
+    lockdep under the PEER's slot name, the inter-OSD edge that the
+    in-process watchdog and the mgr's cross-daemon wait-for graph
+    report for a peer that has gone quiet."""
     host = pg.host
     sem = getattr(host, "scrub_reservations", None)
     if sem is None:
         return True, []
     timeout = float(_cfg(pg, "osd_scrub_reserve_timeout", 10.0))
-    me = f"osd.{host.whoami}"
+    me = host.whoami
+    mine = False                # this daemon's own slot is held
+    asked: list[int] = []       # peers that may hold a slot for us
+
+    async def ask(osd: int) -> str | None:
+        """None: `osd` holds a slot for this round. Else why not."""
+        if osd == me:
+            nonlocal mine
+            mine = sem.try_acquire()
+            return None if mine else "rejected"
+        fut = asyncio.get_running_loop().create_future()
+        pg._reserve_waiters[(tid, osd)] = fut
+        token = sanitizer.lockdep_wait_start(
+            f"osd.{osd}:scrub_reservations", kind="remote_reserve",
+            entity=f"osd.{me}", peer=osd, tid=tid, pgid=str(pg.pgid))
+        try:
+            await host.send_osd(osd, MOSDScrubReserve(
+                {"pgid": [pg.pgid.pool, pg.pgid.ps], "tid": tid,
+                 "from": me, "op": "reserve"}))
+            # from here the peer may grant, heard or not: it gets a
+            # release whatever happens (one it holds nothing for is
+            # ignored there)
+            asked.append(osd)
+            if await asyncio.wait_for(fut, timeout):
+                return None
+            asked.remove(osd)           # it said no: it holds nothing
+            return "rejected"
+        except asyncio.TimeoutError:
+            return "timeout"
+        except Exception as e:
+            return f"{type(e).__name__}: {e}"
+        finally:
+            sanitizer.lockdep_wait_end(token)
+            pg._reserve_waiters.pop((tid, osd), None)
+
+    members = sorted({me, *(o for o in pg.acting_peers()
+                            if host.osdmap.is_up(o))})
     try:
-        await sem.acquire_timeout(timeout)
-    except asyncio.TimeoutError:
-        scrub_perf().inc("reserve_failures")
-        flight.record("scrub_reserve_fail", f"pg.{pg.pgid}", tid=tid,
-                      stage="local", waited_s=timeout)
-        return False, []
-    granted: list[int] = []
-    released = False
-    try:
-        for peer in sorted(pg.acting_peers()):
-            if not host.osdmap.is_up(peer):
-                continue
-            fut = asyncio.get_running_loop().create_future()
-            pg._reserve_waiters[(tid, peer)] = fut
-            token = sanitizer.lockdep_wait_start(
-                f"osd.{peer}:scrub_reservations", kind="remote_reserve",
-                entity=me, peer=peer, tid=tid, pgid=str(pg.pgid))
-            ok, reason = False, "rejected"
-            try:
-                await host.send_osd(peer, MOSDScrubReserve(
-                    {"pgid": [pg.pgid.pool, pg.pgid.ps], "tid": tid,
-                     "from": host.whoami, "op": "reserve"}))
-                ok = bool(await asyncio.wait_for(fut, timeout))
-            except asyncio.TimeoutError:
-                reason = "timeout"
-            except Exception as e:
-                reason = f"{type(e).__name__}: {e}"
-            finally:
-                sanitizer.lockdep_wait_end(token)
-                pg._reserve_waiters.pop((tid, peer), None)
-            if ok:
-                granted.append(peer)
-                continue
-            scrub_perf().inc("reserve_failures")
-            flight.record("scrub_reserve_fail", f"pg.{pg.pgid}", tid=tid,
-                          stage=f"osd.{peer}", reason=reason,
-                          waited_s=timeout)
-            dout("scrub", 2, f"pg {pg.pgid} scrub reservation on "
-                             f"osd.{peer} failed ({reason}): aborting "
-                             f"round")
-            released = True
-            await _release_acting_set(pg, tid, granted)
-            return False, []
+        answers = [await ask(members[0])]
+        if answers[0] is None:
+            answers += await asyncio.gather(*map(ask, members[1:]))
+        refused = [(osd, why) for osd, why in zip(members, answers)
+                   if why is not None]
+        if not refused:
+            return True, asked
+        # given back once: a cancel that lands in the release below
+        # finds nothing left for the handler at the bottom
+        held, mine, asked = (mine, asked), False, []
+        await _release_acting_set(pg, tid, *held)
     except BaseException:
         # a CancelledError (round reaped at daemon stop, drained round
-        # interrupted) is not an Exception: without this the local slot
-        # acquired above — and any grants already collected — would
-        # leak, wedging every later round on this daemon's semaphore
-        if not released:
-            await _release_acting_set(pg, tid, granted)
+        # interrupted) is not an Exception: without this the slots
+        # collected above would leak, wedging every later round on
+        # these daemons' semaphores
+        await _release_acting_set(pg, tid, mine, asked)
         raise
-    return True, granted
+    osd, reason = refused[0]
+    stage = "local" if osd == me else f"osd.{osd}"
+    scrub_perf().inc("reserve_failures")
+    flight.record("scrub_reserve_fail", f"pg.{pg.pgid}", tid=tid,
+                  stage=stage, reason=reason)
+    dout("scrub", 4, f"pg {pg.pgid} scrub reservation on {stage} failed "
+                     f"({reason}): round put back")
+    return False, []
 
 
-async def _release_acting_set(pg: "PGInstance", tid: int,
+async def _release_acting_set(pg: "PGInstance", tid: int, mine: bool,
                               granted: list[int]) -> None:
-    """Return the local slot and every remote grant of this round.
-    Releasing local FIRST unparks any peer's reserve handler queued on
-    our slot — in the crossed-primaries deadlock this is the edge that
-    must break before the other side can make progress."""
+    """Return the local slot (if `mine`) and every remote grant of this
+    round."""
     host = pg.host
-    sem = getattr(host, "scrub_reservations", None)
-    if sem is not None:
-        sem.release()
+    if mine:
+        host.scrub_reservations.release()
     interrupted: asyncio.CancelledError | None = None
     for peer in granted:
         try:
@@ -504,7 +695,7 @@ async def _release_acting_set(pg: "PGInstance", tid: int,
                  "from": host.whoami, "op": "release"}))
         # deferred re-raise below: every granted peer must get its
         # release even when this round is being cancelled, or the
-        # peer's slot stays taken until its own stale-grant churn
+        # peer's slot stays taken for good
         # radoslint: disable-next=cancellation-swallow
         except asyncio.CancelledError as e:
             interrupted = e
@@ -515,73 +706,68 @@ async def _release_acting_set(pg: "PGInstance", tid: int,
         raise interrupted
 
 
-async def handle_scrub_reserve(host, pg: "PGInstance", msg) -> None:
-    """Both halves of the reservation wire protocol.
+def handle_scrub_reserve(host, pg: "PGInstance", msg):
+    """Both halves of the reservation wire protocol, decided where the
+    message is dispatched, so in the order the messages came: a release
+    that follows its reserve on the wire finds the grant.
 
-    Replica (`op=reserve`): park — bounded — on the local slot on the
-    requesting primary's behalf, then grant; a timeout rejects. The
-    park is a real AdjustableSemaphore acquire, so it shows up in this
-    daemon's lockdep waits/holders and in its mgr deadlock
-    annotations.
+    Replica (`op=reserve`): take a local slot on the requesting
+    primary's behalf if one is free; the answer, grant or reject, is
+    owed at once and is returned as a coroutine for the caller to run.
 
-    Primary (`op=grant|reject`): resolve the round's waiter. A grant
-    with no waiter means the round already aborted; the slot is handed
-    straight back (`op=release`) so a slow peer never leaks it.
+    Primary (`op=grant|reject`): resolve the round's waiter. An answer
+    with no waiter comes from a peer the round stopped waiting for,
+    which has been sent its release already.
 
     Anyone (`op=release`): free a slot previously granted to this
-    requester."""
+    requester (`give_back`): what a round that ended early sends; one
+    that ran to its end lets its last request say so."""
     p = msg.payload
     op, tid, frm = p.get("op"), p.get("tid"), p.get("from")
     key = (pg.pgid.pool, pg.pgid.ps, tid, frm)
     sem = getattr(host, "scrub_reservations", None)
     if op == "reserve":
-        granted = True
-        if sem is not None:
-            # wait longer than the requester will: the reject path is
-            # for a genuinely wedged slot, not a normally-busy one —
-            # the primary's own timeout aborts first and the grant
-            # that eventually lands is bounced back as stale
-            timeout = 4.0 * float(_cfg(pg, "osd_scrub_reserve_timeout",
-                                       10.0))
-            try:
-                await sem.acquire_timeout(timeout)
-                host._scrub_remote_grants.add(key)
-            except asyncio.TimeoutError:
-                granted = False
-        try:
-            await host.send_osd(frm, MOSDScrubReserve(
-                {"pgid": [pg.pgid.pool, pg.pgid.ps], "tid": tid,
-                 "from": host.whoami,
-                 "op": "grant" if granted else "reject"}))
-        except asyncio.CancelledError:
-            # handler reaped mid-reply (daemon stop): the grant never
-            # reached the requester, so nobody will ever release it —
-            # hand the slot back before unwinding
-            if granted and sem is not None:
-                host._scrub_remote_grants.discard(key)
-                sem.release()
-            raise
-        except Exception as e:
-            dout("scrub", 2, f"scrub reserve reply to osd.{frm} "
-                             f"failed: {e}")
-            if granted and sem is not None:
-                host._scrub_remote_grants.discard(key)
-                sem.release()
-    elif op in ("grant", "reject"):
+        granted = sem is None or sem.try_acquire()
+        if granted and sem is not None:
+            host._scrub_remote_grants.add(key)
+        return _answer_reserve(host, pg, key, granted)
+    if op in ("grant", "reject"):
         fut = pg._reserve_waiters.get((tid, frm))
         if fut is not None and not fut.done():
             fut.set_result(op == "grant")
-        elif op == "grant":
-            try:
-                await host.send_osd(frm, MOSDScrubReserve(
-                    {"pgid": [pg.pgid.pool, pg.pgid.ps], "tid": tid,
-                     "from": host.whoami, "op": "release"}))
-            except Exception:
-                pass
     elif op == "release":
-        if sem is not None and key in host._scrub_remote_grants:
+        give_back(pg, tid, frm)
+    return None
+
+
+def give_back(pg: "PGInstance", tid: int, primary: int) -> None:
+    """Free the slot this daemon holds for round `tid` of `primary` on
+    `pg`, if it holds one, and let its own scheduler have its turn."""
+    host = pg.host
+    key = (pg.pgid.pool, pg.pgid.ps, tid, primary)
+    if key in host._scrub_remote_grants:
+        host._scrub_remote_grants.discard(key)
+        host.scrub_reservations.release()
+        host.scrub_slot_freed(turn_hold(pg, primary))
+
+
+async def _answer_reserve(host, pg: "PGInstance", key: tuple,
+                          granted: bool) -> None:
+    _pool, _ps, tid, frm = key
+    try:
+        await host.send_osd(frm, MOSDScrubReserve(
+            {"pgid": [pg.pgid.pool, pg.pgid.ps], "tid": tid,
+             "from": host.whoami, "op": "grant" if granted else "reject"}))
+    except BaseException as e:
+        # the grant never reached the requester (this task reaped at
+        # daemon stop, or the send failed), so nobody will ever release
+        # it: hand the slot back
+        if key in host._scrub_remote_grants:
             host._scrub_remote_grants.discard(key)
-            sem.release()
+            host.scrub_reservations.release()
+        if not isinstance(e, Exception):
+            raise
+        dout("scrub", 2, f"scrub reserve reply to osd.{frm} failed: {e}")
 
 
 async def scrub_pg(pg: "PGInstance", deep: bool) -> dict:
@@ -590,22 +776,36 @@ async def scrub_pg(pg: "PGInstance", deep: bool) -> dict:
     client writes are blocked only while ONE range is being scanned,
     compared and repaired on all OSDs — between ranges the gate is
     open, so a colliding write waits out a small chunk, not the whole
-    round. Publishes live progress at `pg.scrub_progress` and crumbs
-    aborted rounds."""
+    round. Publishes live progress at `pg.scrub_progress`, crumbs
+    aborted rounds, and closes one `scrub_round` span whatever the
+    round's end (`state`: done / reserve_failed / aborted)."""
     async with pg._scrub_lock:           # one scrub per PG at a time
         progress = ScrubProgress(pg.pgid, deep)
         pg.scrub_progress = progress
-        try:
-            return await _scrub_locked(pg, deep, progress)
-        except BaseException as e:
-            progress.finish("aborted")
-            scrub_perf().inc("aborts")
-            flight.record("scrub_abort", f"pg.{pg.pgid}", deep=deep,
-                          reason=f"{type(e).__name__}: {e}")
-            raise
-        finally:
-            if progress.state == "scrubbing":
-                progress.finish()
+        result: dict = {}
+        with tracer.span("scrub_round") as sp:
+            try:
+                result = await _scrub_locked(pg, deep, progress)
+                return result
+            except BaseException as e:
+                progress.finish("aborted")
+                scrub_perf().inc("aborts")
+                flight.record("scrub_abort", f"pg.{pg.pgid}", deep=deep,
+                              reason=f"{type(e).__name__}: {e}")
+                raise
+            finally:
+                if progress.state == "scrubbing":
+                    progress.finish()
+                if sp is not None:
+                    sp.tags.update(
+                        pgid=progress.pgid, deep=deep,
+                        state=progress.state,
+                        objects=progress.objects_total,
+                        bytes=progress.bytes_hashed,
+                        errors=result.get("errors", 0),
+                        repaired=result.get("repaired", 0),
+                        **{f"{leg}_us": round(sec * 1e6, 1)
+                           for leg, sec in progress.legs.items()})
 
 
 def _plan_ranges(oids: list, chunk_max: int) -> list:
@@ -626,11 +826,16 @@ def _plan_ranges(oids: list, chunk_max: int) -> list:
 
 
 async def _scrub_range(pg: "PGInstance", deep: bool, oid_range,
-                       progress: "ScrubProgress") -> dict:
+                       progress: "ScrubProgress",
+                       release: tuple | None = None) -> dict:
     """Gather this range's maps from self + up acting peers and
     compare/repair it. Caller holds the write gate, so the slice is
-    frozen across all OSDs while it is judged."""
+    frozen across all OSDs while it is judged. `release` = (tid, peers)
+    on the round's last range: the request tells each of `peers` to
+    give its slot of reservation `tid` back when its map is sent, and
+    takes it off the list of those that still need a message for it."""
     host = pg.host
+    t0, digest0 = time.perf_counter(), progress.legs["digest"]
     maps: dict[int, dict] = {
         host.whoami: await build_scrub_map(pg, deep, progress,
                                            oid_range=oid_range,
@@ -642,12 +847,15 @@ async def _scrub_range(pg: "PGInstance", deep: bool, oid_range,
             continue
         fut = asyncio.get_running_loop().create_future()
         pg._scrub_waiters[(tid, peer)] = fut
+        req = {"pgid": [pg.pgid.pool, pg.pgid.ps], "tid": tid,
+               "from": host.whoami, "deep": deep, "range": list(oid_range)}
+        if release is not None and peer in release[1]:
+            req["release"] = release[0]
         try:
-            await host.send_osd(peer, MOSDRepScrub(
-                {"pgid": [pg.pgid.pool, pg.pgid.ps], "tid": tid,
-                 "from": host.whoami, "deep": deep,
-                 "range": list(oid_range)}))
+            await host.send_osd(peer, MOSDRepScrub(req))
             waits.append((peer, fut))
+            if "release" in req:
+                release[1].remove(peer)
         except Exception as e:
             dout("scrub", 2, f"scrub request to osd.{peer} failed: {e}")
             fut.cancel()
@@ -662,10 +870,15 @@ async def _scrub_range(pg: "PGInstance", deep: bool, oid_range,
         finally:
             pg._scrub_waiters.pop((tid, peer), None)
 
+    # the scan is this OSD's and then the wait for the slowest peer's;
+    # the primary's own digest batch is a leg of its own
+    t1 = time.perf_counter()
+    progress.legs["scan"] += t1 - t0 - (progress.legs["digest"] - digest0)
     if pg.pool.type == "erasure":
         res = await _compare_repair_ec(pg, maps, deep)
     else:
         res = await _compare_repair_replicated(pg, maps, deep)
+    progress.legs["compare"] += time.perf_counter() - t1
     res["osds"] = sorted(maps)
     return res
 
@@ -686,12 +899,14 @@ async def _scrub_locked(pg: "PGInstance", deep: bool,
 
     # reserve one scrub slot per acting-set member for the WHOLE round
     # (sched_scrub's reserver): osd_max_scrubs bounds concurrent rounds
-    # per daemon cluster-wide, and a failed/timed-out reservation
-    # aborts cleanly before any write gate was ever taken
+    # per daemon cluster-wide, and a rejected reservation ends the
+    # round before any write gate was ever taken
     reserve_tid = pg.backend.new_tid()
     reserved, reserved_peers = False, []
     if bool(_cfg(pg, "osd_scrub_reserve", True)):
+        t_res = time.perf_counter()
         ok, reserved_peers = await _reserve_acting_set(pg, reserve_tid)
+        progress.legs["reserve"] = time.perf_counter() - t_res
         reserved = ok and getattr(host, "scrub_reservations",
                                   None) is not None
         if not ok:
@@ -707,10 +922,15 @@ async def _scrub_locked(pg: "PGInstance", deep: bool,
             # between ranges) client writes flow freely — this is where
             # the QoS class actually shapes scrub against foreground
             # load
+            t_wait = time.perf_counter()
             await _qos_grant(pg)
             await pg.block_writes()
+            progress.legs["grant_wait"] += time.perf_counter() - t_wait
+            last = reserved and i + 1 == len(ranges)
             try:
-                r = await _scrub_range(pg, deep, rng, progress)
+                r = await _scrub_range(
+                    pg, deep, rng, progress,
+                    (reserve_tid, reserved_peers) if last else None)
             finally:
                 pg.unblock_writes()
             result["errors"] += r["errors"]
@@ -722,7 +942,8 @@ async def _scrub_locked(pg: "PGInstance", deep: bool,
                 await asyncio.sleep(sleep_s)
     finally:
         if reserved:
-            await _release_acting_set(pg, reserve_tid, reserved_peers)
+            await _release_acting_set(pg, reserve_tid, True, reserved_peers)
+            host.scrub_slot_freed(turn_hold(pg, host.whoami))
 
     result["deep"] = deep
     result["osds"] = sorted(seen_osds)

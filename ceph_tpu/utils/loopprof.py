@@ -39,7 +39,7 @@ LABEL_OF_SPAN = {
     "aio_op": "client", "osd_op": "osd", "pg_op": "osd", "ec_write": "osd",
     "ec_read": "osd", "ec_encode": "osd",
     "ec_decode": "osd", "ec_recover": "osd", "offload_batch": "offload",
-    "store_commit": "store"}
+    "store_commit": "store", "scrub_round": "osd", "scrub_chunk": "osd"}
 #: `ms_dispatch` covers the handler: the receiving daemon's, where known
 LABEL_OF_SERVICE = {"osd": "osd", "client": "client", "mon": "background",
                     "mgr": "background"}
@@ -442,6 +442,9 @@ def reset() -> dict:
             books.update(dict.fromkeys(books, 0))
         for st in _states.values():
             st.lag, st.lag50 = [0] * len(st.lag), [0] * len(st.lag)
+            # the open slice starts anew where its books do: one that
+            # straddled a reset was as long as before and held less
+            st.t50 = st.mark
     return {"cleared_wall_us": cleared}
 
 

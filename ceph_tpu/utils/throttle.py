@@ -69,27 +69,18 @@ class AdjustableSemaphore(asyncio.Semaphore):
             sanitizer.lockdep_locked(self.name)
         return ok
 
-    async def acquire_timeout(self, timeout: float) -> bool:
-        """Bounded acquire that keeps lockdep attribution in THIS
-        context. `asyncio.wait_for(sem.acquire(), t)` runs acquire()
-        inside an ephemeral wrapper task, so the hold would be charged
-        to a context that is already dead — and a wait-for-graph cycle
-        through this semaphore could never close on the real holder.
-        Raises asyncio.TimeoutError like wait_for."""
+    def try_acquire(self) -> bool:
+        """Take a slot if one is free, and say so; never waits, so it
+        adds no edge to lockdep's order graph (a try cannot deadlock),
+        only a holder."""
         if self._owner_loop is None:
             self._owner_loop = asyncio.get_running_loop()
-        if self.name is None or not sanitizer.lockdep_enabled():
-            return await asyncio.wait_for(super().acquire(), timeout)
-        sanitizer.lockdep_will_lock(self.name)
-        token = sanitizer.lockdep_wait_start(self.name, kind="semaphore",
-                                             **self.lockdep_detail)
-        try:
-            ok = await asyncio.wait_for(super().acquire(), timeout)
-        finally:
-            sanitizer.lockdep_wait_end(token)
-        if ok:
+        if self.locked():
+            return False
+        self._value -= 1
+        if self.name is not None and sanitizer.lockdep_enabled():
             sanitizer.lockdep_locked(self.name)
-        return ok
+        return True
 
     @property
     def limit(self) -> int:

@@ -109,3 +109,18 @@ def _no_pending_task_leaks():
         f"the sanitizer — use call_soon_threadsafe (or run_on) to cross "
         f"loops:\n" + "\n".join(
             f"  {s['callback']} -> {s['loop']}" for s in strays[:10]))
+
+
+@pytest.fixture(autouse=True)
+def _offload_defaults_restored():
+    """A daemon's `config set ec_offload_*` also moves the offload
+    module's defaults, which every later cluster of the process
+    inherits. A test that serves a cell whose configuration sets one
+    (`rb4m_scrub_seqread`: `ec_offload_crc_device`; the accepted tests
+    under tests/benchmarks/ serve every cell of BENCHMARK.json) must not
+    hand it to the next file on its worker, where `test_chip_smoke`
+    counts the writes' CrcJobs on the host lane."""
+    from ceph_tpu.offload import service
+    kept = dict(service._DEFAULTS)
+    yield
+    service._DEFAULTS.update(kept)

@@ -5,7 +5,8 @@ crossed-scrub-reservation drill.
 
 Reference contracts: src/common/lockdep.cc (order-graph cycle = bug at
 ACQUIRE time, no deadlock needed), OSD::sched_scrub + MOSDScrubReserve
-(acting-set scrub reservations whose timeout is the deadlock breaker).
+(acting-set scrub reservations: a taken slot rejects at once, so crossed
+reservations cannot hold each other up).
 """
 from __future__ import annotations
 
@@ -361,13 +362,17 @@ def _primary_of(c, whoami, pool="rep"):
     return None
 
 
-def test_crossed_scrub_reservations_detected_and_broken(tmp_path):
-    """Two primaries reserve each other's scrub slot while holding
-    their own: the in-process watchdog sees the cross-OSD cycle while
-    it is live (each side's remote wait is registered under the PEER's
-    slot pool), both OSDs annotate the waits for the mgr path, and the
-    shorter reservation timeout aborts one round — which unparks the
-    other side's reserve handler, so the surviving round completes."""
+def test_crossed_scrub_reservations_are_rejected_not_parked(tmp_path):
+    """Two primaries want each other's scrub slot and their own, at
+    once. Nobody parks on a slot and all ask in one order, lowest id
+    first: both go for osd.0's, its own primary has it, and the other
+    is rejected at once while it holds nothing (far inside
+    `osd_scrub_reserve_timeout`, which only bounds a peer that does not
+    answer). So one round runs to the end and no round waits for
+    another: a rejected reservation leaves no wait-for edge behind (no
+    cycle for the watchdog, no annotation for the mgr) and no slot
+    taken, and the PG that lost scrubs when it comes back, as the
+    scheduler brings it."""
     async def body():
         sanitizer.set_lockdep(True, stuck_wait_s=0.3)
         c = ClusterHarness(tmp_path, n_osds=2)
@@ -381,45 +386,40 @@ def test_crossed_scrub_reservations_detected_and_broken(tmp_path):
             pg0 = _primary_of(c, 0)
             pg1 = _primary_of(c, 1)
             assert pg0 is not None and pg1 is not None
-            # osd.0 aborts first and becomes the deadlock breaker
-            c.osds[0].config.set("osd_scrub_reserve_timeout", 2.0)
-            c.osds[1].config.set("osd_scrub_reserve_timeout", 8.0)
+            for osd in c.osds.values():
+                osd.config.set("osd_scrub_reserve_timeout", 8.0)
+            fails = flight.last_seq()
             t0 = time.monotonic()
             s0 = asyncio.create_task(pg0.scrub(), name="drill-scrub-0")
             s1 = asyncio.create_task(pg1.scrub(), name="drill-scrub-1")
-
-            ring = ["osd.0:scrub_reservations",
-                    "osd.1:scrub_reservations"]
-            want = sanitizer._cycle_digest(ring)
-            scan = None
-            while time.monotonic() - t0 < 2.0:
-                s = sanitizer.deadlock_scan(stuck_s=0.0)
-                if any(cy["digest"] == want for cy in s["cycles"]):
-                    scan = s
-                    break
-                await asyncio.sleep(0.02)
-            assert scan is not None, \
-                "crossed reservation cycle not detected within 2s"
-            (cyc,) = [cy for cy in scan["cycles"]
-                      if cy["digest"] == want]
-            assert set(cyc["resources"]) == set(ring)
-            # full attribution: which OSD waits on whom, for which tid
-            details = {e["detail"]["entity"]: e["detail"]
-                      for e in cyc["edges"]}
-            assert details["osd.0"]["peer"] == 1
-            assert details["osd.1"]["peer"] == 0
-            assert all("tid" in d for d in details.values())
-            # both daemons would ship their half to the mgr
-            for who, peer in ((0, 1), (1, 0)):
-                rows = sanitizer.wait_annotations(entity=f"osd.{who}",
-                                                  min_age_s=0.0)
-                remote = [r for r in rows
-                          if r["kind"] == "remote_reserve"]
-                assert remote and remote[0]["peer"] == peer
             r0, r1 = await asyncio.gather(s0, s1)
-            # the breaker aborted; the survivor's round ran to the end
-            assert r0.get("reserve_failed") is True
-            assert "reserve_failed" not in r1 and r1["errors"] == 0
+            assert time.monotonic() - t0 < 2.0
+            # osd.0's own primary had its slot; the other was turned
+            # away there, and its round never began
+            assert "reserve_failed" not in r0 and r0["errors"] == 0
+            assert r0["osds"] == [0, 1]
+            assert r1.get("reserve_failed") is True
+            crumbs = [e for e in flight.events_since(fails)["events"]
+                      if e["type"] == "scrub_reserve_fail"]
+            assert [(e["entity"], e["detail"]["stage"],
+                     e["detail"]["reason"]) for e in crumbs] == [
+                (f"pg.{pg1.pgid}", "osd.0", "rejected")]
+            # nothing waits, nothing stays held, on either daemon
+            scan = sanitizer.deadlock_scan(stuck_s=0.0)
+            assert scan["cycles"] == []
+            assert not pg0._reserve_waiters and not pg1._reserve_waiters
+            for who in (0, 1):
+                assert sanitizer.wait_annotations(
+                    entity=f"osd.{who}", min_age_s=0.0) == []
+                sem = c.osds[who].scrub_reservations
+                while sem.locked():     # the winner's release, crossing
+                    assert time.monotonic() - t0 < 5.0
+                    await asyncio.sleep(0.01)
+                assert c.osds[who]._scrub_remote_grants == set()
+            # the one that lost comes back and runs to the end
+            res = await pg1.scrub()
+            assert "reserve_failed" not in res and res["errors"] == 0
+            assert res["osds"] == [0, 1]
             assert sanitizer.deadlock_scan()["cycles"] == []
         finally:
             sanitizer.set_lockdep(False)
