@@ -93,8 +93,10 @@ class MethodContext:
         if self.staged and self.staged[0] == "delete":
             raise ClassCallError(-2, "ENOENT (deleted in this call)")
         try:
-            return await self.pg.backend.execute_read(
-                self.oid, offset, length)
+            # a class method parses what it reads (json, int, decode):
+            # it gets bytes, whatever window the store handed out
+            return bytes(await self.pg.backend.execute_read(
+                self.oid, offset, length))
         except Exception:
             raise ClassCallError(-2, f"ENOENT: {self.oid}")
 

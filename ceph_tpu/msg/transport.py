@@ -14,7 +14,11 @@ spill's size and from nothing else:
     spill, and from then on `get_buffer` hands the kernel the unfilled
     tail: one `recv_into` takes whatever the socket holds, up to the
     whole rest of the body. The buffer is never resized, pooled or
-    reused; it lives as long as a view of it does.
+    reused; it lives as long as a view of it does. A body that carries
+    a write is kept for good by the store it is written to (MemStore
+    adopts the read-only view it is handed, `objectstore/memstore.py`),
+    so a recycling body pool, if one is ever built, is for the read
+    direction only: bodies of replies, which die with their message.
 
 What a recv lands in the spill in front of a large body is copied a
 second time, so how much of the spill the kernel is offered follows

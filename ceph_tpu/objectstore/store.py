@@ -15,7 +15,7 @@ import time
 from typing import Callable, Iterable, Mapping
 
 from ceph_tpu.objectstore.types import CollectionId, Ghobject
-from ceph_tpu.utils import sanitizer, tracer
+from ceph_tpu.utils import copytrack, sanitizer, tracer
 
 NO_SHARD = -1
 
@@ -76,16 +76,20 @@ class Transaction:
         # snapshot MUTABLE buffers (bytearray, numpy views): the txn
         # applies later and must see the bytes as queued. Immutable
         # payloads — bytes, and the read-only memoryviews the zero-copy
-        # receive path delivers — pass through by reference: bytes()
-        # here silently re-copied every full payload, exactly the copy
-        # the rx discipline removed (and invisibly to the copy ledger).
+        # receive path delivers — pass through by reference, and a store
+        # may KEEP what it is given here for as long as the object
+        # lives (MemStore does): whoever hands a read-only view to a
+        # transaction gives up the buffer under it for good.
         # A sanitizer-guarded rx view unwraps first (with its
         # use-after-recycle check) so it keeps the by-reference path
         # instead of being silently bytes()-copied below.
         data = sanitizer.unwrap(data)
         if not isinstance(data, bytes) and \
                 not (isinstance(data, memoryview) and data.readonly):
+            t0 = time.perf_counter()
             data = bytes(data)
+            copytrack.copied("store_write", len(data),
+                             time.perf_counter() - t0)
         self.ops.append((Op.WRITE, cid, oid, offset, data))
         return self
 
@@ -260,7 +264,13 @@ class ObjectStore:
         raise NotImplementedError
 
     def read(self, cid: CollectionId, oid: Ghobject, offset: int = 0,
-             length: int | None = None) -> bytes:
+             length: int | None = None) -> bytes | memoryview:
+        """Object data as something bytes-like and read-only that the
+        store never writes again: `bytes`, or a read-only `memoryview`
+        of a buffer the store keeps (MemStore). It compares, slices,
+        hashes, sends and feeds `np.frombuffer` as it is; a caller that
+        needs `bytes` itself (`.decode`, `json`, `+`) says `bytes(...)`.
+        A later write to the object never shows through it."""
         raise NotImplementedError
 
     def corrupt(self, cid: CollectionId, oid: Ghobject, offset: int = 0,

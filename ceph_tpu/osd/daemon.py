@@ -814,7 +814,7 @@ class OSD(Dispatcher):
                 continue
             cid = pg.backend.coll()
             gh = pg.backend.ghobject(oid)
-            size = len(self.store.read(cid, gh))
+            size = self.store.stat(cid, gh)["size"]
             if size == 0:
                 return {"error": f"{oid!r} is empty on osd.{self.whoami}"}
             off = int(offset) if offset is not None else size // 2

@@ -226,7 +226,7 @@ def snap_state_for_push(store: ObjectStore, cid: CollectionId,
         cgh = clone_gh(head, clone["id"])
         try:
             clones[str(clone["id"])] = {
-                "data": store.read(cid, cgh).decode("latin1"),
+                "data": bytes(store.read(cid, cgh)).decode("latin1"),
                 "attrs": {k: v.decode("latin1")
                           for k, v in store.getattrs(cid, cgh).items()}}
         except StoreError:

@@ -18,6 +18,12 @@ at every point where bytes either move (copied) or merely change hands
   h2d                staged batch transferred into device memory
   d2h                device result transferred back to host memory
   reply_assemble     host result planes copied into per-shard replies
+  store_write        payload a transaction writes: kept by the store as
+                     the buffer it arrived in (referenced), or copied, by
+                     `Transaction.write`'s snapshot of a mutable buffer or
+                     by the store into an object it has made private
+  store_read         object data the store hands out: a read-only window
+                     on the buffer it keeps (referenced) or `bytes`
 
 Each stage tracks copied bytes, referenced bytes, copy wall time, and
 event count. The hot-path cost is one lock + three int adds per event
@@ -41,7 +47,8 @@ from ceph_tpu.utils.perf_counters import (PerfCounters,
 #: the pipeline stages, in data-path order (the attribution waterfall
 #: renders them in this order)
 STAGES = ("frame_tx", "frame_rx", "frame_to_buffer",
-          "buffer_to_staging", "h2d", "d2h", "reply_assemble")
+          "buffer_to_staging", "h2d", "d2h", "reply_assemble",
+          "store_write", "store_read")
 
 _lock = threading.Lock()
 _copied = dict.fromkeys(STAGES, 0)

@@ -87,6 +87,48 @@ def test_ledger_bufferlist_copy_vs_reference():
     assert staging["copied_bytes"] == 150
 
 
+@pytest.mark.parametrize("payload,kept", [
+    ("bytes", True), ("readonly_view", True), ("bytearray", False),
+    ("sliver_of_a_large_body", False)])
+def test_ledger_store_stages_exact_bytes(payload, kept):
+    """`store_write`: a payload the store keeps as it came is
+    referenced, one that is copied on the way in is copied (a mutable
+    buffer at `Transaction.write`'s snapshot, after which the bytes are
+    kept: both; a sliver of a large body by the store). `store_read`:
+    a window on a kept buffer is referenced, `bytes` of an object the
+    store has written into is copied."""
+    from ceph_tpu.objectstore import (CollectionId, Ghobject, MemStore,
+                                      Transaction)
+    assert copytrack.STAGES[-2:] == ("store_write", "store_read")
+    cid, oid = CollectionId.make_pg(1, 0), Ghobject(pool=1, name="o")
+    store = MemStore()
+    store.queue_transaction(Transaction().create_collection(cid))
+    raw = b"s" * 4096
+    data = {"bytes": raw, "bytearray": bytearray(raw),
+            "readonly_view": memoryview(bytearray(raw)).toreadonly(),
+            "sliver_of_a_large_body":
+                memoryview(bytes(64) + raw * 5).toreadonly()[64:64 + 4096],
+            }[payload]
+    store.queue_transaction(Transaction().write(cid, oid, 0, data))
+    snap = copytrack.snapshot()["stages"]
+    assert snap["store_write"]["copied_bytes"] == (0 if kept else 4096)
+    assert snap["store_write"]["referenced_bytes"] == \
+        (0 if payload.startswith("sliver") else 4096)
+    assert store.read(cid, oid, 96, 1000) == raw[:1000]
+    snap = copytrack.snapshot()["stages"]["store_read"]
+    assert (snap["referenced_bytes"], snap["copied_bytes"]) == (1000, 0)
+    # a write into the object makes it the store's own: reads copy
+    store.queue_transaction(Transaction().write(cid, oid, 10, b"in"))
+    assert store.read(cid, oid, 0, 12) == raw[:10] + b"in"
+    snap = copytrack.snapshot()["stages"]
+    assert (snap["store_read"]["referenced_bytes"],
+            snap["store_read"]["copied_bytes"]) == (1000, 12)
+    assert snap["store_write"]["copied_bytes"] == (0 if kept else 4096) + 2
+    dump = copytrack.perf().dump()
+    assert dump["copied_bytes_store_read"] == 12
+    assert dump["referenced_bytes_store_read"] == 1000
+
+
 def test_ledger_amplification_and_totals():
     copytrack.copied("h2d", 300, 0.001)
     copytrack.referenced("buffer_to_staging", 1000)

@@ -97,8 +97,8 @@ def test_client_ops_proceed_during_backfill(tmp_path, monkeypatch):
             stale = []
             for pg in vosd.pgs.values():
                 for oid in pg.list_objects():
-                    data = vosd.store.read(pg.backend.coll(),
-                                           pg.backend.ghobject(oid))
+                    data = bytes(vosd.store.read(pg.backend.coll(),
+                                                 pg.backend.ghobject(oid)))
                     if oid.startswith("o") and not data.startswith(b"v2") \
                             and oid != pending_oid:
                         stale.append(oid)

@@ -449,8 +449,8 @@ def test_osd_restart_recovers_by_log(tmp_path, backend):
                     if pg.state not in ("active", "replica"):
                         continue
                     for oid in pg.list_objects():
-                        data = osd.store.read(pg.backend.coll(),
-                                              pg.backend.ghobject(oid))
+                        data = bytes(osd.store.read(
+                            pg.backend.coll(), pg.backend.ghobject(oid)))
                         if oid.startswith("a") and not \
                                 data.startswith(b"second"):
                             stale.append(oid)
