@@ -52,6 +52,10 @@ class Pool:
     crush_rule: int = 0
     ec_profile: str = ""
     stripe_width: int = 0
+    # pg_pool_t FLAG_EC_FAST_READ (`osd pool set <pool> fast_read 1`): a
+    # client read of an erasure pool asks every live shard at once and
+    # answers from the first k of one version
+    fast_read: bool = False
     # snapshots (pg_pool_t snap_seq/snaps/removed_snaps): snap ids are
     # allocated from snap_seq; pool_snaps names the pool-level ones
     # (str keys: the record round-trips through JSON); removed ids are
@@ -65,6 +69,26 @@ class Pool:
 
     def raw_pg_to_pg(self, ps: int) -> int:
         return stable_mod(ps, self.pg_num, self.pg_mask())
+
+
+def pool_options():
+    """Defaults a new pool takes, declared on the mon and on every OSD
+    (one schema, so `config help` reads the same on both)."""
+    from ceph_tpu.utils.config import Option
+    return [
+        Option("osd_pool_default_ec_fast_read", "bool", False,
+               "whether an erasure pool is created with fast_read on: "
+               "a client read sends sub-reads to every live shard and "
+               "is served from the first k replies of one version, "
+               "decoding whatever those are, so a slow shard costs "
+               "shard reads and not the tail (upstream: the mon's, "
+               "read when the pool is created; `osd pool set <pool> "
+               "fast_read 0|1` afterwards). Departure: an OSD whose "
+               "own value is true also reads fast on a pool whose "
+               "flag is off, `pool.fast_read or this`, so that a "
+               "cluster can turn it on through its OSDs' config "
+               "after the pool exists (hot: the next read sees it)"),
+    ]
 
 
 @dataclasses.dataclass

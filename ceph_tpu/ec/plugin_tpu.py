@@ -152,11 +152,15 @@ class ErasureCodeTpu(ErasureCodeJerasure):
         if r < self.m:
             R = np.concatenate(
                 [R, np.zeros((self.m - r, self.k), dtype=np.uint8)])
+        built = rs_codec.MatrixCodec.misses
         codec = rs_codec.MatrixCodec.get(R)
         device_resident = isinstance(chunks, jax.Array)
         with tracer.span("tpu_decode_dispatch") as sp:
             if sp is not None:
                 sp.set_tag("mode", "device" if device_resident else "host")
+                # this pattern's first decode: its codec was built now
+                sp.set_tag("matrix_miss",
+                           rs_codec.MatrixCodec.misses != built)
                 sp.set_tag("batch", int(chunks.shape[0]))
                 sp.set_tag("bytes", int(chunks.size))
                 sp.tags.update(decode_batch_tags(avail_ids, want_ids))

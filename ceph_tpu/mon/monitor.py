@@ -30,6 +30,7 @@ import json
 import time
 
 from ceph_tpu.crush import CrushMap, Incremental, OSDMap, Pool, Rule, Step
+from ceph_tpu.crush.osdmap import pool_options
 from ceph_tpu.mon.paxos import NotLeader, Paxos
 from ceph_tpu.mon.store import MonStore, MonStoreTxn
 from ceph_tpu.msg.messages import (MLog, Message, MMgrMap, MMonCommand,
@@ -41,6 +42,7 @@ from ceph_tpu.msg.messages import (MLog, Message, MMgrMap, MMonCommand,
 from ceph_tpu.msg.messenger import Connection, Dispatcher, Messenger
 from ceph_tpu.utils import flight
 from ceph_tpu.utils.async_util import reap, reap_all
+from ceph_tpu.utils.config import Config
 from ceph_tpu.utils.dout import dout
 from ceph_tpu.utils.perf_counters import PerfCountersCollection
 
@@ -239,10 +241,33 @@ class OSDMonitor:
         pending.new_pools[pid] = Pool(
             id=pid, name=name, type=pool_type, size=size, min_size=min_size,
             pg_num=pg_num, crush_rule=rule_id,
-            ec_profile=erasure_code_profile, stripe_width=stripe_width)
+            ec_profile=erasure_code_profile, stripe_width=stripe_width,
+            fast_read=pool_type == "erasure" and self.mon.config.get(
+                "osd_pool_default_ec_fast_read"))
         pending.new_crush = crush.to_dict()
         return {"pool": name, "pool_id": pid, "size": size,
                 "min_size": min_size, "crush_rule": rule_id}
+
+    def cmd_pool_set(self, pool_name: str, var: str, val) -> dict:
+        """`osd pool set <pool> <var> <val>` (OSDMonitor
+        prepare_command_pool_set); `fast_read` is the one variable this
+        program has, and an erasure pool's alone, as upstream."""
+        import dataclasses as _dc
+        pid = self.osdmap.pool_names.get(pool_name)
+        if pid is None:
+            raise ValueError(f"pool {pool_name!r} does not exist")
+        if var != "fast_read":
+            raise ValueError(f"osd pool set: unknown variable {var!r}")
+        pending = self.get_pending()
+        base = pending.new_pools.get(pid, self.osdmap.pools[pid])
+        if base.type != "erasure":
+            raise ValueError(f"pool {pool_name!r} is not an erasure pool: "
+                             "fast read is not supported")
+        on = str(val).lower() in ("1", "true", "yes", "on")
+        if not on and str(val).lower() not in ("0", "false", "no", "off"):
+            raise ValueError(f"fast_read takes 0 or 1, not {val!r}")
+        pending.new_pools[pid] = _dc.replace(base, fast_read=on)
+        return {"pool": pool_name, "fast_read": on}
 
     def cmd_pool_snap(self, pool_name: str, action: str,
                       snap_name: str | None = None,
@@ -427,9 +452,13 @@ class Monitor(Dispatcher):
 
     def __init__(self, name: str, monmap: MonMap,
                  store_path: str | None = None,
-                 auth_key: bytes | None = None):
+                 auth_key: bytes | None = None,
+                 config: Config | None = None):
         self.name = name
         self.monmap = monmap
+        # what a new pool defaults to (osd_pool_default_*)
+        self.config = config if config is not None \
+            else Config(pool_options())
         self.rank = monmap.rank_of(name)
         self.store = MonStore(store_path)
         self.messenger = Messenger(f"mon.{name}", auth_key=auth_key)
@@ -1019,6 +1048,11 @@ class Monitor(Dispatcher):
                 erasure_code_profile=cmd.get("erasure_code_profile", ""),
                 crush_failure_domain=int(cmd.get("crush_failure_domain", 1)))
             await om.propose_pending()
+            return out
+        if prefix == "osd pool set":
+            out = om.cmd_pool_set(cmd["pool"], cmd["var"], cmd["val"])
+            await om.propose_pending()
+            out["epoch"] = om.osdmap.epoch
             return out
         if prefix in ("osd pool mksnap", "osd pool rmsnap",
                       "osd pool selfmanaged snap create",

@@ -109,6 +109,9 @@ class MatrixCodec:
 
     _cache: "collections.OrderedDict[bytes, MatrixCodec]" = collections.OrderedDict()
     _CACHE_MAX = 2048
+    #: codecs `get` had to build: a bit-matrix expanded on the caller's
+    #: thread and put on the device (callers read it before and after)
+    misses = 0
 
     def __init__(self, M: np.ndarray):
         M = np.ascontiguousarray(M, dtype=np.uint8)
@@ -146,6 +149,7 @@ class MatrixCodec:
         codec = cls._cache.get(key)
         if codec is None:
             codec = cls._cache[key] = cls(M)
+            cls.misses += 1
             while len(cls._cache) > cls._CACHE_MAX:
                 cls._cache.popitem(last=False)
         else:

@@ -17,12 +17,15 @@ epoch instead of tracking creation deltas.
 from __future__ import annotations
 
 import asyncio
+import collections
+import contextlib
 import json
 import time
 
-from ceph_tpu.crush.osdmap import PG, Incremental, OSDMap
+from ceph_tpu.crush.osdmap import PG, Incremental, OSDMap, pool_options
 from ceph_tpu.mgr.mgr_client import MgrClient
-from ceph_tpu.msg.messages import (Message, MOSDOp, MOSDOpReply,
+from ceph_tpu.msg.messages import (Message, MOSDECSubOpWrite, MOSDOp,
+                                   MOSDOpReply,
                                    MOSDOpThrottle, MOSDPGInfo,
                                    MOSDPGLog, MOSDPGPush, MOSDPGPushReply,
                                    MOSDPGQuery, MOSDRepOp, MOSDRepOpReply,
@@ -206,6 +209,25 @@ class OSD(Dispatcher):
                    "JSON {tenant: {reservation, limit, weight}} "
                    "per-tenant overrides of the osd_mclock_client_* "
                    "defaults"),
+            Option("osd_debug_inject_dispatch_delay_probability", "float",
+                   0.0,
+                   "share of dequeued ops this daemon holds back for "
+                   "osd_debug_inject_dispatch_delay_duration before it "
+                   "runs them (upstream: OSD::dequeue_op sleeps the "
+                   "shard thread, qa's osd-dispatch-delay.yaml sets "
+                   "0.1). One seeded draw (qa/faultinject, site "
+                   "dispatch_delay) for every client op the op queue "
+                   "hands to its PG and for every EC sub-op request "
+                   "(read or write) handed to the backend; never for a "
+                   "reply, a heartbeat or map traffic. What is held "
+                   "keeps what comes behind it for the same PG "
+                   "waiting; other PGs go on (hot)",
+                   minimum=0.0, maximum=1.0),
+            Option("osd_debug_inject_dispatch_delay_duration", "float", 0.0,
+                   "seconds an op picked by osd_debug_inject_dispatch_"
+                   "delay_probability is held; the hold sleeps the op, "
+                   "not a thread, and burns no CPU (hot)", minimum=0.0),
+            *pool_options(),
         ])
         # op tracing rides the same config (hot-togglable: `config set
         # tracer_enabled true` over the admin socket starts collecting)
@@ -270,6 +292,19 @@ class OSD(Dispatcher):
                                   "(repair-bandwidth baseline)")
         self.perf.add("heartbeat_failures",
                       description="peers reported failed to the mon")
+        self.perf.add("ec_subread_late",
+                      description="EC sub-read replies no gather was "
+                                  "waiting for any more: what a fast "
+                                  "read asked of every shard and did "
+                                  "not need, or what came after a "
+                                  "gather's deadline")
+        self.perf.add("ec_subread_late_bytes",
+                      description="chunk bytes those late replies "
+                                  "carried")
+        self.perf.add("dispatch_delays",
+                      description="dequeued ops and sub-op requests "
+                                  "held back by osd_debug_inject_"
+                                  "dispatch_delay_probability")
         # per-stage latency histograms (power-of-two µs buckets; the
         # exporter renders them as cumulative prometheus histograms)
         # per-PG pipelined execution (the PrimaryLogPG concurrency
@@ -332,6 +367,14 @@ class OSD(Dispatcher):
             perf=self.perf)
         self.config.add_observer(("osd_pg_pipeline_depth",),
                                  self._on_pipeline_depth)
+        # osd_debug_inject_dispatch_delay_*: sub-op requests of a PG
+        # that wait behind a held one, in the order they came
+        self._behind_hold: dict[PG, collections.deque] = {}
+        self._on_dispatch_delay("", self.config.get(
+            "osd_debug_inject_dispatch_delay_probability"))
+        self.config.add_observer(
+            ("osd_debug_inject_dispatch_delay_probability",),
+            self._on_dispatch_delay)
         # dmclock arbiter wiring: seed the scheduler from the knobs,
         # then keep it live via the observer (every osd_mclock_* knob
         # is hot, including the enable toggle — queued work migrates)
@@ -681,6 +724,90 @@ class OSD(Dispatcher):
         """osd_pg_pipeline_depth observer: hot-resize the live per-PG
         admission window."""
         self._run_on_loop(self.op_queue.set_pipeline_depth, int(value))
+
+    # -- osd_debug_inject_dispatch_delay_* -----------------------------------
+    # (upstream consults the two options in OSD::dequeue_op, which
+    # client ops and sub-ops both pass; here client ops pass the op
+    # queue and EC sub-ops go from ms_dispatch to the backend, so there
+    # are two places, and one draw in each)
+
+    def _on_dispatch_delay(self, name: str, value) -> None:
+        self._dispatch_delay_p = float(value)
+        self.op_queue.hold = (lambda: self._dispatch_hold("op")) \
+            if value > 0 else None
+
+    def _dispatch_hold(self, kind: str):
+        """One draw for a dequeued client op ("op") or sub-op request
+        ("subop"): the hold as an awaitable, or None."""
+        if not faultinject.hold_dispatch(self._dispatch_delay_p,
+                                         f"osd.{self.whoami} {kind}"):
+            return None
+        return self._held(kind)
+
+    async def _held(self, kind: str) -> None:
+        self.perf.inc("dispatch_delays")
+        with tracer.span_sampled_only("dispatch_hold",
+                                      f"osd.{self.whoami}") as sp:
+            if sp is not None:
+                sp.set_tag("kind", kind)
+            await asyncio.sleep(self.config.get(
+                "osd_debug_inject_dispatch_delay_duration"))
+
+    def _hold_sub_op(self, pg: PGInstance, conn: Connection,
+                     msg: Message) -> bool:
+        """Whether the sub-op request leaves the connection's dispatch
+        loop for its PG's queue of held ones: it does when it is held
+        itself, or when one of its PG that came before it still is,
+        since a PG's sub-writes must be applied in the order they were
+        sent. The connection goes on meanwhile: replies, pings and the
+        other PGs' sub-ops behind this one are not kept waiting."""
+        hold = self._dispatch_hold("subop")
+        waiting = self._behind_hold.get(pg.pgid)
+        if waiting is not None:
+            waiting.append((hold, conn, msg))
+            return True
+        if hold is None:
+            return False
+        self._behind_hold[pg.pgid] = collections.deque(
+            [(hold, conn, msg)])
+        t = asyncio.get_running_loop().create_task(
+            self._drain_behind_hold(pg))
+        self._notify_tasks.add(t)
+        t.add_done_callback(self._notify_tasks.discard)
+        return True
+
+    async def _drain_behind_hold(self, pg: PGInstance) -> None:
+        waiting = self._behind_hold[pg.pgid]
+        try:
+            while waiting:
+                hold, conn, msg = waiting[0]
+                try:
+                    if hold is not None:
+                        await hold
+                    # under the sender's trace context, as the
+                    # connection's dispatch loop would have run it
+                    traced = msg.trace is not None and tracer.active()
+                    with tracer.dispatch_scope(
+                            "ms_dispatch", f"osd.{self.whoami}",
+                            parent=msg.trace) if traced \
+                            else contextlib.nullcontext():
+                        await self._handle_sub_op(pg, conn, msg)
+                except Exception as e:
+                    dout("osd", 0, f"osd.{self.whoami}: held sub-op "
+                                   f"{msg!r} failed: "
+                                   f"{type(e).__name__} {e}")
+                waiting.popleft()
+        finally:
+            del self._behind_hold[pg.pgid]
+            for hold, _conn, _msg in waiting:   # cancelled at shutdown
+                if hold is not None:
+                    hold.close()
+
+    async def _handle_sub_op(self, pg: PGInstance, conn: Connection,
+                             msg: Message) -> None:
+        await pg.backend.handle_sub_op(conn, msg)
+        if isinstance(msg, MOSDECSubOpWrite):
+            self.perf.inc("subop")
 
     def _apply_qos_knobs(self) -> None:
         """Push every osd_mclock_* value into the live scheduler."""
@@ -1398,14 +1525,13 @@ class OSD(Dispatcher):
         """EC sub-op messages are routed to the PG's ECBackend."""
         from ceph_tpu.msg.messages import (MOSDECSubOpRead,
                                            MOSDECSubOpReadReply,
-                                           MOSDECSubOpWrite,
                                            MOSDECSubOpWriteReply)
         if isinstance(msg, (MOSDECSubOpWrite, MOSDECSubOpRead)):
             pg = self._pg_of(msg, create=True)
-            if pg is not None:
-                await pg.backend.handle_sub_op(conn, msg)
-                if isinstance(msg, MOSDECSubOpWrite):
-                    self.perf.inc("subop")
+            if pg is not None and not (
+                    (self._dispatch_delay_p > 0 or self._behind_hold)
+                    and self._hold_sub_op(pg, conn, msg)):
+                await self._handle_sub_op(pg, conn, msg)
             return True
         if isinstance(msg, (MOSDECSubOpWriteReply, MOSDECSubOpReadReply)):
             pg = self._pg_of(msg)

@@ -98,6 +98,7 @@ class ECBackend(PGBackend):
         self._zcrc = self._crc32c(b"\x00" * self.sinfo.chunk_size)
         # read gather plumbing: tid -> future resolving to (payload, data)
         self._read_waiters: dict[int, asyncio.Future] = {}
+        self._read_arrivals = 0
         # per-object write ordering lives in PGBackend._obj_locks now
         # (obj_lock): the PG's modify path holds it across log intent +
         # this backend's RMW/fan-out, and the replicated backend shares
@@ -119,6 +120,19 @@ class ECBackend(PGBackend):
         return {i: o for i, o in enumerate(self.pg.acting)
                 if o != CRUSH_NONE and self.host.osdmap.is_up(o)}
 
+
+    def _reads_fast(self) -> bool:
+        """Whether a client read asks every live shard at once (the
+        pool's `fast_read` flag; or this daemon's own
+        `osd_pool_default_ec_fast_read`, a departure: upstream's default
+        is the mon's alone, read when the pool is created)."""
+        return self.pg.pool.fast_read or self.host.config.get(
+            "osd_pool_default_ec_fast_read")
+
+    def _late_sub_read(self, data) -> None:
+        """A sub-read reply nobody waits for: dropped, and counted."""
+        self.host.perf.inc("ec_subread_late")
+        self.host.perf.inc("ec_subread_late_bytes", len(data))
 
     def _pad(self, data: bytes) -> bytes:
         w = self.sinfo.stripe_width
@@ -684,11 +698,23 @@ class ECBackend(PGBackend):
             chunk_off: int = 0,
             chunk_len: int = -1,
             snap: int | None = None,
+            fast: bool = False,
     ) -> tuple[dict[int, bytes], int, dict]:
         """Collect shard chunk EXTENTS [chunk_off, chunk_off+chunk_len)
         until a version-consistent decodable set exists; returns
         ({shard: extent}, logical size, meta). chunk_len < 0 means to the
         end of the shard; chunk_len == 0 fetches no data (stat).
+
+        Two ways to ask. The default is two rounds: a minimum set first
+        (k shards in all, data positions preferred), the other positions
+        only when that round cannot decode or half the deadline is
+        spent. `fast` (a client read of a pool that reads fast,
+        `_reads_fast`) is one round to every live position at once, and
+        the gather is done at the first k chunks of one version,
+        whichever positions they are, taken in the order the replies
+        came; what is still out then is dropped when it comes and
+        counted (`ec_subread_late`, `meta["late"]`). The version rules
+        below hold for both.
 
         Shards carry the eversion of the write that produced them: mixing
         chunks of two writes would decode garbage (the reference guards
@@ -735,15 +761,18 @@ class ECBackend(PGBackend):
         # two rounds: ask a minimum set first (k shards total, preferring
         # data positions), top up with the remaining positions only when
         # the first round can't decode — the reference reads exactly
-        # minimum_to_decode and falls back to extra shards on miss
+        # minimum_to_decode and falls back to extra shards on miss. A
+        # fast read (the reference's do_redundant_reads) has one round:
+        # its first is everybody, and nothing is left to top up with
         candidates = [(idx, osd)
                       for idx, osd in sorted(self._live_positions().items())
                       if osd != self.host.whoami
                       and osd not in exclude_osds]
-        need_first = max(0, self.k - sum(len(v) for v in
-                                         by_version.values()))
+        need_first = len(candidates) if fast else max(
+            0, self.k - sum(len(v) for v in by_version.values()))
         rounds = [candidates[:need_first], candidates[need_first:]]
         waits: dict[asyncio.Future, int] = {}
+        taken: set = set()  # replies looked at; the rest of `waits` is late
         deadline = asyncio.get_running_loop().time() + READ_TIMEOUT
 
         async def send_round(batch) -> set:
@@ -768,7 +797,7 @@ class ECBackend(PGBackend):
                     fut.cancel()
             return futs
 
-        topped_up = False
+        topped_up = fast
         try:
             pending = await send_round(rounds[0])
             half = deadline - READ_TIMEOUT / 2
@@ -801,7 +830,14 @@ class ECBackend(PGBackend):
                 done, pending = await asyncio.wait(
                     pending, timeout=timeout,
                     return_when=asyncio.FIRST_COMPLETED)
-                for fut in done:
+                # `wait` hands the finished waiters over as a set: a
+                # fast read takes them in the order they came and stops
+                # at the k-th chunk of one version
+                for fut in sorted(done,
+                                  key=lambda f: f.result()[0]["arrival"]):
+                    if fast and best() is not None:
+                        break
+                    taken.add(fut)
                     payload, data = fut.result()
                     if payload.get("found"):
                         add(payload["shard"], data, payload["ec_size"],
@@ -809,6 +845,8 @@ class ECBackend(PGBackend):
                             payload.get("uattrs"))
         finally:
             for fut, tid in waits.items():
+                if fut not in taken and fut.done() and not fut.cancelled():
+                    self._late_sub_read(fut.result()[1])    # came, unused
                 fut.cancel()
                 self._read_waiters.pop(tid, None)
         if best() is None and by_version and allow_rollback:
@@ -851,7 +889,8 @@ class ECBackend(PGBackend):
                                    "rolled_back": rolled_back,
                                    "uattrs": uattrs_by.get(ver, {}),
                                    "asked": len(waits),
-                                   "rounds": 1 + topped_up}
+                                   "late": len(waits) - len(taken),
+                                   "rounds": 1 if fast else 1 + topped_up}
 
     async def _gather_prev_pass(self, oid: str, exclude_osds: frozenset,
                                 chunk_off: int, chunk_len: int,
@@ -915,9 +954,14 @@ class ECBackend(PGBackend):
         else:
             last = -(-(offset + length) // w)
             chunk_off, chunk_len = first * c, (last - first) * c
+        fast = self._reads_fast()
         with tracer.span("ec_read", f"osd.{self.host.whoami}") as sp:
             got, ec_size, meta = await self._gather_chunks(
-                oid, chunk_off=chunk_off, chunk_len=chunk_len, snap=snap)
+                oid, chunk_off=chunk_off, chunk_len=chunk_len, snap=snap,
+                fast=fast)
+            # which k chunks the gather came back with decides the work:
+            # the k data positions interleave, anything else reconstructs
+            # the data positions that are missing, on the plugin's device
             data = await ec_util.decode_concat_async(
                 self.sinfo, self.ec_impl, got, service=self._offload_svc())
             start = offset - first * w
@@ -927,6 +971,10 @@ class ECBackend(PGBackend):
                 sp.set_tag("bytes", max(0, end - start))
                 sp.set_tag("shards_asked", meta["asked"])
                 sp.set_tag("rounds", meta["rounds"])
+                if fast:
+                    sp.set_tag("fast", True)
+                    sp.set_tag("shards_used", sorted(got))
+                    sp.set_tag("late", meta["late"])
             return data[start:max(start, end)]
 
     async def gather_snapset(self, oid: str, authoritative: bool = False):
@@ -1062,8 +1110,15 @@ class ECBackend(PGBackend):
             self.sub_op_ack(p["tid"], p["from"])
             return
         fut = self._read_waiters.get(p["tid"])
-        if fut is not None and not fut.done():
-            fut.set_result((p, msg.data))
+        if fut is None or fut.done():
+            # its gather has its k chunks, or gave up
+            self._late_sub_read(msg.data)
+            return
+        # replies are numbered as they come: a gather is handed its
+        # finished waiters as a set, and a fast read takes the first k
+        self._read_arrivals += 1
+        p["arrival"] = self._read_arrivals
+        fut.set_result((p, msg.data))
 
     # -- recovery (RecoveryOp-lite: reconstruct + push) ----------------------
 

@@ -17,7 +17,10 @@ layer consults:
     (`fault_inject_bitrot`), caught by the per-chunk crc gate;
   * offload/service.py: injected device-dispatch failures
     (`fault_inject_device_fail`), exercising the circuit breaker and
-    the bit-identical host fallback.
+    the bit-identical host fallback;
+  * osd/daemon.py: a dequeued client op or sub-op request held back
+    (`osd_debug_inject_dispatch_delay_*`, the daemon's own options:
+    `hold_dispatch` only draws, armed or not).
 
 Determinism: every probabilistic decision is derived from
 (seed, site, per-site event counter) — NOT from a shared RNG whose
@@ -179,6 +182,18 @@ class FaultInjector:
                 return True
         return False
 
+    def hold_dispatch(self, probability: float, detail: str) -> bool:
+        """Whether the op or sub-op a daemon has just dequeued is held
+        back (upstream's `osd_debug_inject_dispatch_delay_probability`,
+        which the caller passes: the option is the daemon's). Site
+        `dispatch_delay`, one draw a dequeue."""
+        with self._lock:
+            u, n = self._draw("dispatch_delay")
+            if u >= probability:
+                return False
+            self._note("dispatch_delay", n, "hold", detail)
+            return True
+
     def maybe_bitrot(self, size: int) -> int | None:
         """Byte offset to corrupt in a just-applied shard blob extent,
         or None. The offset derives from the same (seed, site, n) space
@@ -243,6 +258,10 @@ def on_message(entity: str, msg) -> tuple[str, float]:
 
 def should_fail_device() -> bool:
     return _armed and _injector.should_fail_device()
+
+
+def hold_dispatch(probability: float, detail: str) -> bool:
+    return _injector.hold_dispatch(probability, detail)
 
 
 def maybe_bitrot(size: int) -> int | None:

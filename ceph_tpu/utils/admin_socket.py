@@ -4,8 +4,8 @@ Re-creation of the reference's AdminSocket (src/common/admin_socket.{h,cc}):
 daemons expose a unix socket accepting newline-terminated JSON requests
 `{"prefix": "<command>", ...args}` and answering with a JSON document.
 Built-in commands: help, version, perf dump, perf schema, config show,
-config diff, config set, config get, dump_recent (log ring). Components
-register additional hooks with `register_command`.
+config diff, config set, config get, config help, dump_recent (log
+ring). Components register additional hooks with `register_command`.
 """
 from __future__ import annotations
 
@@ -133,6 +133,17 @@ class AdminSocket:
                 self.config.set(req["key"], req["value"])
                 return {"success": True}
             self.register_command("config set", _set, "set one option")
+
+            def _help(req):
+                schema = self.config.schema()
+                names = [req["key"]] if req.get("key") else sorted(schema)
+                return {n: {"type": schema[n].type,
+                            "default": schema[n].default,
+                            "description": schema[n].description}
+                        for n in names}
+            self.register_command("config help", _help,
+                                  "what one option (key=) or every "
+                                  "option means, its type and default")
 
     # -- server --------------------------------------------------------------
 

@@ -622,18 +622,25 @@ class Finisher:
 class _KeyWindow:
     """Per-key (per-PG) in-flight execution state of one shard: how many
     items of each class are running, which object streams are occupied,
-    and whether an exclusive (obj=None) item holds the key."""
+    whether an exclusive (obj=None) item holds the key, and how many
+    admitted items are still held back before they start (`hold`)."""
 
-    __slots__ = ("counts", "objs", "exclusive")
+    __slots__ = ("counts", "objs", "exclusive", "held")
 
     def __init__(self):
         self.counts = collections.Counter()     # klass -> in-flight
         self.objs: set = set()                  # objects in execution
         self.exclusive = False                  # obj=None item running
+        self.held = 0                           # admitted, not yet started
 
     @property
     def total(self) -> int:
         return sum(self.counts.values())
+
+    def shut(self, klass: str, depth: int) -> bool:
+        """Nothing more of `klass` may start for this key just now."""
+        return self.exclusive or self.held > 0 \
+            or self.counts[klass] >= depth
 
 
 class ShardedOpQueue:
@@ -696,6 +703,15 @@ class ShardedOpQueue:
     migrate between the class and entity queues preserving arrival
     order, and with the scheduler OFF this code path is bit-identical
     to the legacy WRR queue.
+
+    `hold` (the daemon's `osd_debug_inject_dispatch_delay_*`): asked
+    once for every client item a worker dequeues; where it returns an
+    awaitable the item waits for it before it starts, and until then
+    nothing else of its key is admitted, so what was queued behind it
+    for the same PG stays behind it (upstream sleeps the shard thread
+    in `dequeue_op`, which holds back every PG of the shard; here the
+    other keys go on). Items of the key that were already running
+    finish. None, the default, costs one comparison a dequeue.
     """
 
     #: legacy-path class weights, derived from the declared profile
@@ -753,6 +769,7 @@ class ShardedOpQueue:
         self._last_stall_flight = 0.0
         self.processed = 0
         self.processed_by_class = collections.Counter()
+        self.hold: Callable[[], Awaitable | None] | None = None
 
     def start(self) -> None:
         loop = asyncio.get_running_loop()
@@ -934,7 +951,7 @@ class ShardedOpQueue:
         st = infl.get(key)
         if st is None:
             return True
-        if st.exclusive or st.counts[klass] >= depth:
+        if st.shut(klass, depth):
             return False
         if obj is None:
             return st.total == 0        # barrier: needs the key idle
@@ -969,8 +986,7 @@ class ShardedOpQueue:
                 blocked_keys.add(key)
                 continue
             st = infl.get(key)
-            if st is not None and (st.exclusive
-                                   or st.counts[klass] >= depth):
+            if st is not None and st.shut(klass, depth):
                 blocked_keys.add(key)   # whole window full
             else:
                 blocked_objs.add((key, obj))
@@ -1115,16 +1131,22 @@ class ShardedOpQueue:
                 blocked_keys.add(key)
                 continue
             st = infl.get(key)
-            if st is not None and (st.exclusive
-                                   or st.counts[klass] >= depth):
+            if st is not None and st.shut(klass, depth):
                 blocked_keys.add(key)
             else:
                 blocked_objs.add((key, obj))
         return None
 
     async def _run_one(self, shard: int, klass: str, key, obj,
-                       work) -> None:
+                       work, hold: Awaitable | None = None) -> None:
         try:
+            if hold is not None:
+                st = self._inflight[shard][key]
+                try:
+                    await hold
+                finally:
+                    st.held -= 1
+                    self._wake[shard].set()
             await work()
         except Exception as e:
             dout("osd", 1, f"{self.name}.{shard}: work raised "
@@ -1180,12 +1202,17 @@ class ShardedOpQueue:
             klass, key, obj, work = picked
             if self._hb_ids:
                 self._hb_map.touch(self._hb_ids[shard])
+            hold = None
+            if self.hold is not None and klass == "client":
+                hold = self.hold()
+                if hold is not None:
+                    self._inflight[shard][key].held += 1
             if self.pipeline_depth <= 1:
                 # legacy serial path: bit-identical to the pre-pipeline
                 # queue (one in-flight item per shard, awaited inline)
-                await self._run_one(shard, klass, key, obj, work)
+                await self._run_one(shard, klass, key, obj, work, hold)
             else:
                 t = loop.create_task(
-                    self._run_one(shard, klass, key, obj, work))
+                    self._run_one(shard, klass, key, obj, work, hold))
                 self._exec_tasks[shard].add(t)
                 t.add_done_callback(self._exec_tasks[shard].discard)
