@@ -1,7 +1,7 @@
 """Typed messages — the src/messages/ equivalent.
 
 A Message is (type id, metadata dict, data bytes). On the wire it rides a
-MESSAGE frame as three segments: header (seq/type, JSON), payload
+MESSAGE frame as three segments: header (seq/type/ack, JSON), payload
 (type-specific metadata, JSON), data (raw bytes, untouched — chunk
 payloads never pass through JSON). Subclasses declare `TYPE` and carry
 their fields in `payload`/`data`; `register_message` fills the decode
@@ -54,6 +54,10 @@ class Message:
     #: at most, so the copy is noise while the API stays exact.
     DATA_VIEW = False
 
+    #: rx only: the ack the frame that brought this message carried.
+    #: What a frame carries on its way out is its connection's to say
+    ack = 0
+
     def __init__(self, payload: dict[str, Any] | None = None,
                  data: bytes = b""):
         self.payload = payload or {}
@@ -66,9 +70,14 @@ class Message:
 
     # -- wire form -----------------------------------------------------------
 
-    def encode_segments(self) -> list[bytes]:
-        header = json.dumps({"type": self.TYPE, "seq": self.seq},
-                            separators=(",", ":")).encode()
+    def encode_segments(self, ack: int = 0) -> list[bytes]:
+        """`ack`: the last seq the sending connection has finished
+        dispatching and not yet told its peer (msgr2's ack_seq); 0
+        leaves the key out, and a receiver without it reads 0."""
+        head = {"type": self.TYPE, "seq": self.seq}
+        if ack:
+            head["ack"] = ack
+        header = json.dumps(head, separators=(",", ":")).encode()
         payload = json.dumps(self.payload, separators=(",", ":"),
                              sort_keys=True).encode()
         # tx boundary: a forwarded sanitizer-guarded rx view (e.g. the
@@ -104,6 +113,7 @@ class Message:
         msg = cls.__new__(cls)
         Message.__init__(msg, _json_seg(segments[1]), data)
         msg.seq = header["seq"]
+        msg.ack = header.get("ack", 0)
         if len(segments) == 4:
             # unknown trailing segments are dropped, not errors: a newer
             # peer's extra TLV must never break this one

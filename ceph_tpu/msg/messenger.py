@@ -10,19 +10,23 @@ The reference contract this keeps (src/msg/Messenger.h, ProtocolV2.cc):
     faults are invisible; the initiator reconnects and both sides replay
     messages the other hasn't acked);
   * session semantics: cookie identifies a session across TCP transports;
-    in_seq/out_seq + ACK frames bound replay; receivers drop duplicates
+    in_seq/out_seq + acks bound replay; receivers drop duplicates
     by seq (ProtocolV2 reconnect/replay, out-of-order-safe);
-  * control frames cost nothing of their own while anything else moves
-    (ProtocolV2::write_event): the write loop sends all that is queued
-    for a peer in one writelines per wake-up and appends the ACK the
-    peer is owed (only what a handler has finished), so an ack goes
-    alone only from a connection with nothing to ride on — after
-    ACK_EVERY unacked messages or IDLE_ACK_S of quiet; and a lossless
-    end sends a KEEPALIVE only once it has received nothing for
-    KEEPALIVE_INTERVAL, since any frame shows the peer alive. A dead
-    peer is still faulted KEEPALIVE_TIMEOUT after its last frame
-    (counters: ctrl_frames_tx, ctrl_rode_tx, tx_sends,
-    keepalives_skipped).
+  * an ack is a field, not a frame (ceph_msg_header2.ack_seq): the write
+    loop sends all that is queued for a peer in one writelines per wake-up
+    (ProtocolV2::write_event) and every MESSAGE frame's header carries the
+    ack the peer is owed (only what a handler has finished). An ACK frame
+    is framed only in a send with no MESSAGE frame — after ACK_EVERY
+    unacked messages or IDLE_ACK_S of quiet on a connection that is
+    sending nothing — and a lossy session, which keeps nothing to replay,
+    owes none. Both ends must read the header's `ack`: a peer that ignored
+    it would never trim `_sent` under request/reply traffic, which frames
+    no ACK (upstream: a feature bit; nothing is negotiated here). A
+    lossless end sends a KEEPALIVE only once it has received nothing for
+    KEEPALIVE_INTERVAL, since any frame shows the peer alive; a dead peer
+    is faulted KEEPALIVE_TIMEOUT after its last frame (counters:
+    acks_carried_tx, ack_frames_tx, ctrl_frames_tx, ctrl_rode_tx,
+    tx_sends, keepalives_skipped).
 
 Idiomatic divergences: one asyncio event loop per DAEMON (under the
 sharded reactor runtime, utils/reactor.py, each daemon's messenger
@@ -162,6 +166,11 @@ def msgr_perf():
         pc.add("ctrl_rode_tx",
                description="those of them that left in a send which "
                            "also carried a MESSAGE frame")
+        pc.add("acks_carried_tx",
+               description="acks that left in a MESSAGE frame's header")
+        pc.add("ack_frames_tx",
+               description="ACK frames framed: acks owed by a send that "
+                           "carried no MESSAGE frame")
         pc.add("tx_sends",
                description="calls of the write loops into the "
                            "transport's writelines: one sendmsg each "
@@ -301,8 +310,8 @@ class Connection:
 
     RECONNECT_BACKOFF = 0.2     # doubles per attempt, capped
     RECONNECT_BACKOFF_MAX = 5.0
-    ACK_EVERY = 16              # unacked messages that force an ack out
-    #                             with nothing to ride on (see IDLE_ACK_S)
+    ACK_EVERY = 16              # unacked messages that force an ACK frame
+    #                             out with nothing to send (see IDLE_ACK_S)
     KEEPALIVE_INTERVAL = 1.0    # a lossless end that has received nothing
     #                             for this long probes, and again every
     #                             this often until something arrives
@@ -651,6 +660,10 @@ class Connection:
             if frame.tag == Tag.MESSAGE:
                 perf.inc("frames_rx")
                 msg = Message.decode_segments(frame.segments)
+                # an ack that arrived is an ack, whatever the dup filter
+                # or faultinject do to its carrier (a batch envelope's
+                # rides the envelope's header)
+                self._trim_sent(msg.ack)
                 if isinstance(msg, (_messages.MOSDECSubOpBatch,
                                     _messages.MOSDECSubOpBatchReply)):
                     # batch envelope: unpack BEFORE seq accounting —
@@ -737,7 +750,9 @@ class Connection:
             except Exception as e:
                 dout("ms", 0, f"{self} dispatch of {msg!r} failed: "
                               f"{type(e).__name__} {e}")
-            if gen == self._session_gen:
+            if gen == self._session_gen and not self.policy.lossy:
+                # (a lossy end keeps nothing in _sent for an ack to trim
+                # and so owes none: ProtocolV2::handle_message)
                 self._processed_seq = msg.seq
                 if self._processed_seq - self._last_acked_in >= \
                         self.ACK_EVERY:
@@ -835,12 +850,12 @@ class Connection:
                           onwire: Onwire | None = None) -> None:
         """One send per wake-up (ProtocolV2::write_event): frame the
         item that woke the loop and whatever else is already queued,
-        append the ack the peer is owed, and hand it all to the
-        transport in ONE writelines, so a control frame costs no
-        sendmsg, no wake-up and no segment on the wire of its own when
-        anything else is going the same way. Gathering stops at
-        SPILL_SIZE bytes of payload: a large frame still leaves from
-        where its bytes lie, one at a time."""
+        each MESSAGE frame's header carrying the ack the peer is owed
+        by then, and hand it all to the transport in ONE writelines.
+        An ACK frame is built only where the send has no MESSAGE frame
+        to carry it. Gathering stops at SPILL_SIZE bytes of payload: a
+        large frame still leaves from where its bytes lie, one at a
+        time."""
         perf = self.messenger.perf
         out = self._out
         pending: tuple | None = None
@@ -853,12 +868,16 @@ class Connection:
                 # the dispatch loop's lazy _schedule_ack_flush timer
                 item = await out.get()
             parts: list = []
-            gathered = ctrl = msgs = 0
+            gathered = ctrl = msgs = carried = 0
             while True:
                 kind, arg = item
                 if kind == "msg":
                     arg, pending = await self._coalesce(arg)
-                    frame = Frame(Tag.MESSAGE, arg.encode_segments())
+                    # taken after the await and at every encoding: a
+                    # replay carries the ack of the time it leaves
+                    ack = self._take_ack()
+                    carried += ack > 0
+                    frame = Frame(Tag.MESSAGE, arg.encode_segments(ack))
                     msgs += 1
                     perf.inc("frames_tx")
                     if type(arg).TYPE in _messages.BATCHABLE_TYPES or \
@@ -871,8 +890,8 @@ class Connection:
                     self._frame_into(parts, Frame(_PROBE_TAGS[kind], []),
                                      onwire)
                 # an ("ack", seq) is a wake-up and no more: what the
-                # peer is owed is read below, once, so one that a ride
-                # has overtaken since it was queued sends nothing
+                # peer is owed is read by _take_ack, so one that a
+                # header has overtaken since it was queued sends nothing
                 if gathered >= SPILL_SIZE:
                     break
                 if pending is not None:
@@ -881,16 +900,15 @@ class Connection:
                     break
                 else:
                     item = out.get_nowait()
-            if self._processed_seq > self._last_acked_in:
-                # the ack rides: only what a handler has FINISHED is
-                # advertised, as when it went alone
-                self._last_acked_in = self._processed_seq
-                if self._ack_timer is not None:
-                    self._ack_timer.cancel()
-                    self._ack_timer = None
+            if carried:
+                perf.inc("acks_carried_tx", carried)
+            if not msgs and (ack := self._take_ack()):
+                # no MESSAGE to carry it (ACK_EVERY's wake-up, the idle
+                # flush's, a probe's): upstream's case for the frame
                 ctrl += 1
-                self._frame_into(parts, Frame(
-                    Tag.ACK, [b"[%d]" % self._last_acked_in]), onwire)
+                perf.inc("ack_frames_tx")
+                self._frame_into(parts, Frame(Tag.ACK, [b"[%d]" % ack]),
+                                 onwire)
             if not parts:
                 continue
             if ctrl:
@@ -900,6 +918,18 @@ class Connection:
             perf.inc("tx_sends")
             writer.writelines(parts)
             await writer.drain()
+
+    def _take_ack(self) -> int:
+        """The ack the peer is owed and has not been sent, taken for
+        the frame in hand (0: none). Only what a handler has FINISHED
+        is advertised."""
+        if self._processed_seq <= self._last_acked_in:
+            return 0
+        self._last_acked_in = self._processed_seq
+        if self._ack_timer is not None:
+            self._ack_timer.cancel()
+            self._ack_timer = None
+        return self._last_acked_in
 
     def _frame_into(self, parts: list, frame: Frame,
                     onwire: Onwire | None) -> int:
