@@ -34,7 +34,7 @@ import zlib
 from dataclasses import dataclass, field
 
 from ceph_tpu.native import ec_native
-from ceph_tpu.utils import copytrack
+from ceph_tpu.utils import copytrack, tracer
 
 MAGIC = 0xEC02
 MAX_SEGMENTS = 4
@@ -175,18 +175,19 @@ class Frame:
         consecutive parts under one chained crc; empty parts are left
         out."""
         self._check_count()
-        pre = bytearray(_PRE_FIXED.pack(MAGIC, int(self.tag),
-                                        len(self.segments)))
-        for seg in self.segments:
-            pre += _U32.pack(_seg_len(seg))
-        pre += _U32.pack(crc32c(bytes(pre)))
-        parts: list = [bytes(pre)]
-        for seg in self.segments:
-            crc = 0
-            for p in _live_parts(seg):
-                parts.append(p)
-                crc = crc32c(p, crc)
-            parts.append(_U32.pack(crc))
+        with tracer.section("msgr.codec"):
+            pre = bytearray(_PRE_FIXED.pack(MAGIC, int(self.tag),
+                                            len(self.segments)))
+            for seg in self.segments:
+                pre += _U32.pack(_seg_len(seg))
+            pre += _U32.pack(crc32c(bytes(pre)))
+            parts: list = [bytes(pre)]
+            for seg in self.segments:
+                crc = 0
+                for p in _live_parts(seg):
+                    parts.append(p)
+                    crc = crc32c(p, crc)
+                parts.append(_U32.pack(crc))
         return parts
 
     def payload_len(self) -> int:
@@ -221,8 +222,9 @@ class Frame:
             # the preamble, then 4 bytes of crc a segment, in one
             # buffer of this frame's own: the slices below are all
             # that ever sees it
-            hdr = memoryview(_frame_native.crcs(MAGIC, int(self.tag),
-                                                self.segments))
+            with tracer.section("msgr.codec"):
+                hdr = memoryview(_frame_native.crcs(MAGIC, int(self.tag),
+                                                    self.segments))
             off = len(hdr) - 4 * len(self.segments)
             parts = [hdr[:off]]
             for seg in self.segments:
@@ -245,7 +247,9 @@ class Frame:
             # trip here would re-copy the whole frame
             t0 = time.perf_counter()
             self._check_count()
-            blob = _frame_native.pack(MAGIC, int(self.tag), self.segments)
+            with tracer.section("msgr.codec"):
+                blob = _frame_native.pack(MAGIC, int(self.tag),
+                                          self.segments)
             copytrack.copied("frame_tx", self.payload_len(),
                              time.perf_counter() - t0)
             return blob
@@ -311,7 +315,8 @@ class Frame:
             # without the numpy fallback the sliced decode path needs)
             buf = base if type(base) in (bytes, bytearray) \
                 and len(base) == want else body[:want]
-            bad = _frame_native.verify_body(buf, seg_lens)
+            with tracer.section("msgr.codec"):
+                bad = _frame_native.verify_body(buf, seg_lens)
             if bad >= 0:
                 raise FrameError("segment crc mismatch")
             segments = []
@@ -329,7 +334,9 @@ class Frame:
                 if len(seg) != ln:
                     raise FrameError("truncated segment")
                 (seg_crc,) = _U32.unpack_from(body, off + ln)
-                if crc32c(seg) != seg_crc:
+                with tracer.section("msgr.codec"):
+                    ok = crc32c(seg) == seg_crc
+                if not ok:
                     raise FrameError("segment crc mismatch")
                 segments.append(seg)
                 off += ln + 4

@@ -744,7 +744,10 @@ class Connection:
                             sp.set_tag("bytes", len(msg.data))
                         await self.messenger._dispatch(self, msg)
                 else:
-                    await self.messenger._dispatch(self, msg)
+                    # no trace context (sent from a timer or a daemon's
+                    # own loop): the handler's time, not this loop's
+                    with tracer.section("msgr.handler"):
+                        await self.messenger._dispatch(self, msg)
             except asyncio.CancelledError:
                 raise
             except Exception as e:
@@ -916,7 +919,8 @@ class Connection:
                 if msgs:
                     perf.inc("ctrl_rode_tx", ctrl)
             perf.inc("tx_sends")
-            writer.writelines(parts)
+            with tracer.section("msgr.tx_sock"):    # `sendmsg`, tried inline
+                writer.writelines(parts)
             await writer.drain()
 
     def _take_ack(self) -> int:

@@ -43,6 +43,8 @@ from __future__ import annotations
 
 import asyncio
 
+from ceph_tpu.utils import tracer
+
 #: bytes of spill per connection, and the size above which a read gets
 #: a buffer of its own
 SPILL_SIZE = 65536
@@ -215,7 +217,8 @@ class Endpoint(asyncio.BufferedProtocol):
         return out
 
     async def _read_body(self, n: int) -> bytearray:
-        buf = bytearray(n)
+        with tracer.section("msgr.rx_alloc"):
+            buf = bytearray(n)
         have = self._wpos - self._rpos      # < n: the spill is smaller
         if have:
             buf[:have] = self._spill_mv[self._rpos:self._wpos]

@@ -2,13 +2,15 @@
 
 While armed it times every callback the loop runs (`Handle._run` is the
 seam ready queue, timers and I/O all pass through) and charges it to a
-LABEL: the innermost open tracer span of the task being stepped (kept
-on the task as `loop_label`; the span CM closes the running interval and
-opens the next, so span time is SELF time), else the package of the
-callback's code (`LABEL_OF_*`, the one table of layers). Collector
-pauses go to `gc`, time parked in `select` is `idle`, the loop's own
-machinery to the callback it follows. Every 50 ms a `loop_slice` span
-and a `loop_slice50` annotation in the profiler's trace; a 10 Hz
+PART of a LABEL (`"msgr.rx_sock"`; a label without parts, `"store"`, is
+its own): the innermost open tracer span or `tracer.section` of the task
+being stepped (kept on the task as `loop_label`; the CM closes the
+running interval and opens the next, so span time is SELF time), else
+the file and name of the callback's code (`LABEL_OF_*`, the one table of
+layers). A label's time is the sum of its parts'. Collector pauses go to
+`gc`, time parked in `select` is `idle`, the loop's own machinery to the
+callback it follows. Every 50 ms a `loop_slice` span and a
+`loop_slice50` annotation in the profiler's trace; a 10 Hz
 watchdog catches a callback that holds the loop 0.5 s: a `loop_pause`.
 `tracer.enable()` arms the running loop, or the first a mapped span is
 entered on; `profiler_enabled` arms without tracing. Disarmed, nothing
@@ -34,26 +36,51 @@ from ceph_tpu.utils.perf_counters import TYPE_GAUGE, PerfCountersCollection
 LABELS = ("msgr", "client", "osd", "offload", "store", "harness",
           "background", "gc", "unattributed")
 IDLE = "idle"
+#: the labels that are read by part; `other` is charged, never computed
+PARTS = {"msgr": ("rx_sock", "rx_alloc", "rx_frame", "codec", "tx_frame",
+                  "tx_sock", "dispatch", "handler", "other"),
+         "osd": ("pg", "ec", "subop", "queue", "scrub", "other")}
+#: what the books are keyed by: every part, the labels that have none
+#: (each its own one part), `idle`
+KEYS = tuple(f"{lab}.{p}" for lab in LABELS for p in PARTS.get(lab, ())
+             ) + tuple(lab for lab in LABELS if lab not in PARTS) + (IDLE,)
+#: self time of a span. Sections (`tracer.section`) name their part
+#: themselves: `msgr.rx_alloc`, `msgr.codec`, `msgr.tx_sock`,
+#: `msgr.handler` (a handler of a message that carries no trace context)
 LABEL_OF_SPAN = {
-    "ms_send": "msgr", "ms_dispatch": "msgr", "rados_op": "client",
-    "aio_op": "client", "osd_op": "osd", "pg_op": "osd", "ec_write": "osd",
-    "ec_read": "osd", "ec_encode": "osd",
-    "ec_decode": "osd", "ec_recover": "osd", "offload_batch": "offload",
-    "store_commit": "store", "scrub_round": "osd", "scrub_chunk": "osd"}
+    "ms_dispatch": "msgr.handler", "rados_op": "client", "aio_op": "client",
+    "osd_op": "osd.pg", "pg_op": "osd.pg", "ec_write": "osd.ec",
+    "ec_read": "osd.ec", "ec_encode": "osd.ec", "ec_decode": "osd.ec",
+    "ec_recover": "osd.ec", "offload_batch": "offload",
+    "store_commit": "store", "scrub_round": "osd.scrub",
+    "scrub_chunk": "osd.scrub"}
 #: `ms_dispatch` covers the handler: the receiving daemon's, where known
-LABEL_OF_SERVICE = {"osd": "osd", "client": "client", "mon": "background",
-                    "mgr": "background"}
-#: first match wins: the sockets are the messenger's (asyncio's stream
-#: transport reads them), the op queue the OSD's, the ticker our own
+#: (on an OSD: a shard serving a sub-op, the primary taking its replies)
+LABEL_OF_SERVICE = {"osd": "osd.subop", "client": "client",
+                    "mon": "background", "mgr": "background"}
+#: (file, name of the code or None for any, part), first match wins: the
+#: sockets are the messenger's (asyncio's transport reads and writes
+#: them), the op queue the OSD's, its timers and the ticker our own
 LABEL_OF_PATH = (
-    ("/ceph_tpu/msg/", "msgr"), ("/asyncio/selector_events.py", "msgr"),
-    ("/asyncio/streams.py", "msgr"), ("/ceph_tpu/rados/", "client"),
-    ("/ceph_tpu/osd/", "osd"), ("/ceph_tpu/utils/work_queue.py", "osd"),
-    ("/ceph_tpu/offload/", "offload"), ("/ceph_tpu/objectstore/", "store"),
-    ("/ceph_tpu/mon/", "background"), ("/ceph_tpu/mgr/", "background"),
-    ("/ceph_tpu/utils/loopprof.py", "background"),
-    ("/benchmarks/", "harness"))
-OSD_TIMERS = ("_heartbeat", "_scrub_loop")       # background, in osd/
+    ("/ceph_tpu/msg/messenger.py", "_read_loop", "msgr.rx_frame"),
+    ("/ceph_tpu/msg/messenger.py", "_write_loop", "msgr.tx_frame"),
+    ("/ceph_tpu/msg/messenger.py", "_dispatch_loop", "msgr.dispatch"),
+    ("/asyncio/selector_events.py", "_read_ready", "msgr.rx_sock"),
+    ("/asyncio/selector_events.py", "_write_ready", "msgr.tx_sock"),
+    ("/ceph_tpu/msg/", None, "msgr.other"),
+    ("/asyncio/selector_events.py", None, "msgr.other"),
+    ("/asyncio/streams.py", None, "msgr.other"),
+    ("/ceph_tpu/rados/", None, "client"),
+    ("/ceph_tpu/osd/", "_heartbeat", "background"),
+    ("/ceph_tpu/osd/", "_scrub_loop", "background"),
+    ("/ceph_tpu/osd/", None, "osd.other"),
+    ("/ceph_tpu/utils/work_queue.py", None, "osd.queue"),
+    ("/ceph_tpu/offload/", None, "offload"),
+    ("/ceph_tpu/objectstore/", None, "store"),
+    ("/ceph_tpu/mon/", None, "background"),
+    ("/ceph_tpu/mgr/", None, "background"),
+    ("/ceph_tpu/utils/loopprof.py", None, "background"),
+    ("/benchmarks/", None, "harness"))
 
 SLICE50_NS = 50_000_000         # a `loop_slice` span, and its annotation
 TICK_S = 0.010                  # the lag ticker
@@ -68,10 +95,10 @@ _lock = threading.Lock()
 _states: dict = {}              # loop -> _Acct, while armed
 _tracked_loops = weakref.WeakSet()  # where a `config set` is marshalled to
 _by_tracer = False              # tracer.enable() wants every loop armed
-_books: dict[str, dict] = {}    # shard label -> ns by label, since reset
+_books: dict[str, dict] = {}    # shard label -> ns by part, since reset
 _gc_t0 = 0                      # the running collection's start, and
 _gcs: collections.deque = collections.deque(maxlen=64)  # (end, ns) of late
-_code_labels: dict = {}         # id(code object or type) -> (label, it)
+_code_labels: dict = {}         # id(code object or type) -> (part, it)
 _watchdog = None                # (thread, its stop event) while any loop
 
 
@@ -93,8 +120,7 @@ class _Acct:
         self.seen, self.pause, self.late, self.woke = -1, None, 0, 0
         self.thread_id = threading.get_ident()
         self.cpu_clock = time.pthread_getcpuclockid(self.thread_id)
-        self.acc = _books.setdefault(label,
-                                     dict.fromkeys(LABELS + (IDLE,), 0))
+        self.acc = _books.setdefault(label, dict.fromkeys(KEYS, 0))
         self.acc50 = dict(self.acc)
         self.mark = self.t_cb = self.t50 = self.t1 = now = _now()
         self.lag = [0] * (len(LAG_EDGES_MS) + 1)    # since a reset
@@ -102,16 +128,24 @@ class _Acct:
         self.cpus: collections.deque = collections.deque(
             [(now, time.clock_gettime_ns(self.cpu_clock))], maxlen=16)
 
-    def switch(self, label: str) -> None:
+    def switch(self, part: str) -> None:
         now = _now()
         self.acc[self.cur] += now - self.mark
-        self.mark, self.cur = now, label
+        self.mark, self.cur = now, part
+
+
+def _by_label(acc: dict) -> dict:
+    """The books by label: a label's parts summed, `idle` with them."""
+    by = dict.fromkeys(LABELS + (IDLE,), 0)
+    for part, v in acc.items():
+        by[part.partition(".")[0]] += v
+    return by
 
 
 def _label_of(cb, owner) -> str:
-    """Label of a callback by its code's package; a task (`owner` of its
-    step or wake-up) keeps it as `loop_label`, where an open span
-    overrides it."""
+    """Part of a callback by its code's file and name; a task (`owner` of
+    its step or wake-up) keeps it as `loop_label`, where an open span or
+    section overrides it."""
     task = owner if isinstance(owner, asyncio.Task) else None
     if task is not None:
         key = getattr(task.get_coro(), "cr_code", None) or type(task)
@@ -123,10 +157,10 @@ def _label_of(cb, owner) -> str:
     if hit is None:             # the table is walked once per key
         path = getattr(key, "co_filename", None) or \
             "/" + getattr(key, "__module__", "").replace(".", "/") + "/"
-        label = next((lab for part, lab in LABEL_OF_PATH if part in path),
+        name = getattr(key, "co_name", None)
+        label = next((part for file, code, part in LABEL_OF_PATH
+                      if file in path and code in (None, name)),
                      "unattributed")
-        if label == "osd" and getattr(key, "co_name", "") in OSD_TIMERS:
-            label = "background"
         hit = _code_labels[id(key)] = (label, key)  # `key` lives: id is its
     label = hit[0]
     if task is not None:
@@ -134,9 +168,16 @@ def _label_of(cb, owner) -> str:
     return label
 
 
-def _span_enter(span):
-    """Span CM enter: switch to the span's label (None: it moves none)."""
-    label = LABEL_OF_SPAN.get(span.name)
+def _enter(what):
+    """A span CM's enter, or a section's (`what` is then its part):
+    switch to the part (None: it moves none)."""
+    if type(what) is str:
+        label = what
+    elif what.name == "ms_dispatch":
+        label = LABEL_OF_SERVICE.get(what.service.partition(".")[0],
+                                     LABEL_OF_SPAN["ms_dispatch"])
+    else:
+        label = LABEL_OF_SPAN.get(what.name)
     loop = _events._get_running_loop()
     if label is None or loop is None:
         return None
@@ -145,8 +186,6 @@ def _span_enter(span):
         if not _by_tracer:
             return None
         st = install(loop, owner="tracer")      # deferred arming
-    if span.name == "ms_dispatch":
-        label = LABEL_OF_SERVICE.get(span.service.partition(".")[0], label)
     task = _current_task(loop)
     if task is not None:        # kept on the task for when it resumes
         back = getattr(task, "loop_label", None) or _label_of(None, task)
@@ -157,7 +196,7 @@ def _span_enter(span):
     return st, task, back
 
 
-def _span_exit(token) -> None:
+def _exit(token) -> None:
     st, task, back = token
     if task is not None:
         task.loop_label = back
@@ -232,9 +271,12 @@ def _annotation():
 
 
 def _roll(st: _Acct, now: int) -> None:
-    """50 ms: the slice as a `loop_slice` span and, for the profiler's
-    trace, a `loop_slice50` annotation; once a second the gauges."""
-    us = {k + "_us": (v - st.acc50[k]) / 1e3 for k, v in st.acc.items()}
+    """50 ms: the slice as a `loop_slice` span (microseconds by label,
+    and under `parts` by part of the labels that have them) and, for the
+    profiler's trace, a `loop_slice50` annotation (by label); once a
+    second the gauges."""
+    parts = {k: (v - st.acc50[k]) / 1e3 for k, v in st.acc.items()}
+    us = {k + "_us": v for k, v in _by_label(parts).items()}
     mark = _annotation()
     if mark is not None:
         with mark("loop_slice50", len_us=(now - st.t50) // 1000, pc_ns=now,
@@ -242,7 +284,8 @@ def _roll(st: _Acct, now: int) -> None:
             pass
     tracer.record_span(
         "loop_slice", st.t50 / 1e9, (now - st.t50) / 1e3,
-        dict(us, callbacks=st.n - st.n50, lag_edges_ms=LAG_EDGES_MS,
+        dict(us, parts={k: v for k, v in parts.items() if "." in k},
+             callbacks=st.n - st.n50, lag_edges_ms=LAG_EDGES_MS,
              lag_hist=[a - b for a, b in zip(st.lag, st.lag50)]),
         service=st.label)
     st.t50, st.acc50, st.n50, st.lag50 = now, dict(st.acc), st.n, list(st.lag)
@@ -258,7 +301,8 @@ def _long_callback(st: _Acct, now: int, took: int) -> None:
     `loop_pause` (was it the collector, Python, a block, the machine)."""
     mark = _annotation()
     if mark is not None:
-        with mark(f"loop:{st.cur}", dur_us=took // 1000, pc_ns=now):
+        with mark(f"loop:{st.cur.partition('.')[0]}", dur_us=took // 1000,
+                  pc_ns=now):
             pass
     if took < PAUSE_NS:
         return
@@ -321,7 +365,7 @@ def install(loop: asyncio.AbstractEventLoop | None = None,
             if not _states:
                 _events.Handle._run = _run
                 gc.callbacks.append(_on_gc)
-                tracer.set_account(_span_enter, _span_exit)
+                tracer.set_account(_enter, _exit)
                 stop = threading.Event()
                 _watchdog = (threading.Thread(
                     target=_watch, args=(stop,), daemon=True,
@@ -371,7 +415,7 @@ def tracer_armed(on: bool) -> None:
     for lp in [] if on else list(_states):
         uninstall(lp, owner="tracer")
     if on or not _states:
-        tracer.set_account(*((_span_enter, _span_exit) if on
+        tracer.set_account(*((_enter, _exit) if on
                              else (None, None)))
 
 
@@ -414,16 +458,19 @@ def shard_busy_skew(shards: dict[str, dict] | None = None) -> float:
 
 
 def dump() -> dict:
-    """`profile dump`: us by label, busy fraction, the armed loops' lag."""
+    """`profile dump`: us by label and by part, busy fraction, the armed
+    loops' lag."""
     st = _states.get(_events._get_running_loop())
     if st is not None:
         st.switch(st.cur)       # the running callback, so far
-    labels = {k: round(sum(d[k] for d in _books.values()) / 1e3, 1)
-              for k in LABELS + (IDLE,)}
+    parts = {k: sum(d[k] for d in _books.values()) for k in KEYS}
+    labels = {k: round(v / 1e3, 1) for k, v in _by_label(parts).items()}
     wall = sum(labels.values())
     shards = shard_stats()
     live = list(_states.values())
     return {"enabled": bool(installed_loops()), "labels_us": labels,
+            "parts_us": {k: round(v / 1e3, 1) for k, v in parts.items()
+                         if "." in k},
             "wall_us": round(wall, 1),
             "loop_busy_fraction": round((wall - labels[IDLE]) / wall, 4)
             if wall else 0.0,

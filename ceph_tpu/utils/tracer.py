@@ -59,12 +59,12 @@ FLAG_SAMPLED = 1
 _current: contextvars.ContextVar[tuple[int, int, int] | None] = \
     contextvars.ContextVar("trace_ctx", default=None)
 
-#: loopprof's `_span_enter(span) -> token | None` / `_span_exit(token)`
-#: while an account is armed on any loop, else None: a span CM then
-#: closes the loop's running interval and opens the next, and keeps the
-#: label of the innermost mapped span a task is inside on the task
-#: itself (`task.loop_label`), which is what the account charges the
-#: task's next step to when it resumes
+#: loopprof's `_enter(span or part) -> token | None` / `_exit(token)`
+#: while an account is armed on any loop, else None: a span CM or a
+#: `section` then closes the loop's running interval and opens the next,
+#: and keeps the part of the innermost mapped span or section a task is
+#: inside on the task itself (`task.loop_label`), which is what the
+#: account charges the task's next step to when it resumes
 _acct_enter = _acct_exit = None
 
 
@@ -609,6 +609,35 @@ class _SpanCM:
             self.span.tags.setdefault("error", f"{et.__name__}: {ev}")
         self.span.finish()
         return False
+
+
+class _SectionCM:
+    """A stretch of a callback that the loop account charges to `part`."""
+
+    __slots__ = ("_part", "_acct")
+
+    def __init__(self, part: str):
+        self._part = part
+
+    def __enter__(self) -> None:
+        enter = _acct_enter
+        self._acct = enter(self._part) if enter is not None else None
+
+    def __exit__(self, *exc) -> bool:
+        if self._acct is not None and _acct_exit is not None:
+            _acct_exit(self._acct)
+        return False
+
+
+def section(part: str):
+    """`with tracer.section("msgr.codec"):` charges the stretch to that
+    part of the loop account (`utils/loopprof.py`) and the rest of the
+    callback to its own. No span: nothing reaches the collector. With no
+    account armed the shared no-op is returned, nothing is allocated and
+    no clock is read; so is it a no-op where no loop runs."""
+    if _acct_enter is None:
+        return _NOOP
+    return _SectionCM(part)
 
 
 def _parse_parent(parent) -> tuple[int, int, int] | None:

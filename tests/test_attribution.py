@@ -163,10 +163,14 @@ def _spin(seconds: float) -> None:
         pass
 
 
-def _account(body) -> tuple[dict, float, list[dict]]:
+def _account(body, parts: dict | None = None
+             ) -> tuple[dict, float, list[dict]]:
     """Run `body()` on a fresh loop under full tracing; returns the
     account's microseconds by label, the wall microseconds it was armed
-    for, and the spans it closed."""
+    for, and the spans it closed; `parts` is filled with the
+    microseconds by part."""
+    parts = {} if parts is None else parts
+
     async def main():
         tracer.reset()
         tracer.enable(max_spans=65536)
@@ -178,6 +182,7 @@ def _account(body) -> tuple[dict, float, list[dict]]:
         d = loopprof.dump()
         wall = (time.perf_counter() - t0) * 1e6
         tracer.disable()
+        parts.update(d["parts_us"])
         return d["labels_us"], wall, tracer.collector().spans()
     try:
         return asyncio.run(main())
@@ -283,15 +288,107 @@ def test_account_closes_slices_that_add_up_to_their_length():
         assert tags["callbacks"] > 0
         assert len(tags["lag_hist"]) == len(tags["lag_edges_ms"]) + 1
         assert set(tags) == {k + "_us" for k in loopprof.LABELS + ("idle",)} \
-            | {"callbacks", "lag_hist", "lag_edges_ms"}
+            | {"callbacks", "lag_hist", "lag_edges_ms", "parts"}
         assert s["parent_id"] is None
     assert sum(sum(s["tags"]["lag_hist"]) for s in slices) > 10
+
+
+def _spin_in(part: str, seconds: float) -> None:
+    with tracer.section(part):
+        _spin(seconds)
+
+
+def test_section_books_its_stretch_to_its_part_and_the_rest_to_the_tasks(
+        monkeypatch):
+    """A task whose code the table maps to `msgr.rx_frame` (by file and
+    name) spins 30 ms of its own and 20 ms inside a section: the section
+    is `msgr.codec`'s, across an await too, and the label is the sum."""
+    monkeypatch.setattr(loopprof, "LABEL_OF_PATH", (
+        ("/test_attribution.py", "reader", "msgr.rx_frame"),
+        ("/test_attribution.py", "writer", "msgr.tx_frame"))
+        + loopprof.LABEL_OF_PATH)
+
+    async def reader():
+        _spin(0.030)
+        with tracer.section("msgr.codec"):
+            _spin(0.010)
+            await asyncio.sleep(0.02)           # parked: idle
+            _spin(0.010)
+        _spin_in("msgr.rx_alloc", 0.010)
+
+    async def writer():
+        _spin(0.015)
+
+    async def body():
+        await asyncio.gather(asyncio.create_task(reader()),
+                             asyncio.create_task(writer()))
+
+    parts: dict = {}
+    labels, wall, _spans = _account(body, parts)
+    assert parts["msgr.rx_frame"] == pytest.approx(30_000, abs=5_000)
+    assert parts["msgr.codec"] == pytest.approx(20_000, abs=5_000)
+    assert parts["msgr.rx_alloc"] == pytest.approx(10_000, abs=5_000)
+    assert parts["msgr.tx_frame"] == pytest.approx(15_000, abs=5_000)
+    assert labels["msgr"] == pytest.approx(
+        sum(v for k, v in parts.items() if k.startswith("msgr.")), abs=1)
+    assert labels["msgr"] == pytest.approx(75_000, abs=8_000)
+    assert sum(labels.values()) == pytest.approx(wall, rel=0.01)
+    assert set(parts) == {k for k in loopprof.KEYS if "." in k}
+
+
+def test_a_slices_parts_sum_to_their_labels_and_the_labels_to_its_length():
+    async def body():
+        for _ in range(12):
+            with tracer.span("osd_op"):
+                _spin(0.004)
+                with tracer.span("ec_read"):
+                    _spin(0.003)
+            _spin_in("msgr.codec", 0.003)
+            with tracer.span("ms_dispatch", "osd.3"):
+                _spin(0.002)
+            await asyncio.sleep(0.005)
+
+    _labels, _wall, spans = _account(body)
+    slices = [s for s in spans if s["name"] == "loop_slice"]
+    assert len(slices) >= 3
+    seen = dict.fromkeys(loopprof.PARTS, 0.0)
+    for s in slices:
+        tags = s["tags"]
+        assert sum(tags[k + "_us"] for k in loopprof.LABELS + ("idle",)) \
+            == pytest.approx(s["duration_us"], rel=0.01)
+        assert set(tags["parts"]) == {f"{lab}.{p}" for lab, ps in
+                                      loopprof.PARTS.items() for p in ps}
+        for label in loopprof.PARTS:
+            mine = sum(v for k, v in tags["parts"].items()
+                       if k.startswith(label + "."))
+            assert mine == pytest.approx(tags[label + "_us"], abs=0.01)
+            seen[label] += mine
+    assert seen["msgr"] > 20_000 and seen["osd"] > 60_000
+    total = {k: sum(s["tags"]["parts"][k] for s in slices)
+             for k in slices[0]["tags"]["parts"]}
+    assert total["osd.pg"] > total["osd.ec"] > total["osd.subop"] > 10_000
+    assert total["osd.other"] == total["msgr.other"] == 0
+
+
+def test_section_with_no_loop_running_is_a_no_op():
+    """Armed by the tracer, outside any loop: nothing to charge, nothing
+    installed, the books as they were."""
+    tracer.enable()
+    try:
+        before = loopprof.dump()["parts_us"]
+        with tracer.section("msgr.codec"):
+            _spin(0.002)
+        assert loopprof.installed_loops() == []
+        assert loopprof.dump()["parts_us"] == before
+    finally:
+        tracer.disable()
+        tracer.reset()
 
 
 def test_disarmed_account_leaves_nothing_installed():
     """With the tracer disabled `Handle._run` is asyncio's own,
     `gc.callbacks` is as found, no loopprof thread lives, and
-    `tracer.span()` is the shared no-op."""
+    `tracer.span()` and `tracer.section()` are the shared no-op."""
     import gc
     import threading
     run_before = asyncio.events.Handle._run
@@ -322,6 +419,7 @@ def test_disarmed_account_leaves_nothing_installed():
     assert not any(t.name.startswith("loopprof")
                    for t in threading.enumerate())
     assert tracer.span("osd_op") is tracer.span("pg_op")    # the no-op
+    assert tracer.section("msgr.codec") is tracer.span("osd_op")
 
 
 def test_tracer_enable_without_a_loop_arms_at_the_first_span():
@@ -380,7 +478,7 @@ def test_profile_dump_admin_socket_command(tmp_path):
     asok = AdminSocket(str(tmp_path / "t.asok"))
     out = asok.execute({"prefix": "profile dump"})["result"]
     assert set(out) >= {"enabled", "loop_busy_fraction", "labels_us",
-                        "wall_us", "shards", "lag_hist"}
+                        "parts_us", "wall_us", "shards", "lag_hist"}
     assert asok.execute({"prefix": "profile reset"})[
         "result"]["cleared_wall_us"] >= 0
 
@@ -512,3 +610,60 @@ def test_every_report_merged_logger_is_exportable():
         pc = coll.get(name)
         assert pc is not None, f"extra_logger {name!r} unregistered"
         assert pc.dump(), f"logger {name!r} exports no counters"
+
+
+# ---------------------------------------------------------------------------
+# the parts of `msgr` and `osd` on a live cluster
+# ---------------------------------------------------------------------------
+
+PARTS_ON_A_CLUSTER = [f"{label}.{p}" for label in ("msgr", "osd")
+                      for p in loopprof.PARTS[label] if p != "scrub"]
+
+
+@pytest.fixture(scope="module")
+def cluster_parts() -> dict:
+    """Microseconds by part of one loop that boots an EC pool on three
+    OSDs and serves a few writes and reads of 256 KiB (chunks over the
+    spill's 64 KiB, so bodies are allocated) with the account armed."""
+    from ceph_tpu.tools.cluster_boot import ephemeral_cluster
+
+    async def main():
+        async with ephemeral_cluster(3, prefix="parts-") \
+                as (client, _osds, _mon):
+            tracer.reset()
+            tracer.enable(max_spans=65536)
+            loopprof.reset()
+            try:
+                await client.command({
+                    "prefix": "osd erasure-code-profile set", "name": "prof",
+                    "profile": {"plugin": "jerasure", "k": "2", "m": "1",
+                                "technique": "reed_sol_van"}})
+                await client.pool_create("parts", pg_num=4,
+                                         pool_type="erasure",
+                                         erasure_code_profile="prof")
+                io = client.ioctx("parts")
+                value = bytes(range(256)) * 1024
+                for i in range(6):
+                    await io.write_full(f"obj{i}", value)
+                for i in range(6):
+                    assert await io.read(f"obj{i}") == value
+                await asyncio.sleep(1.1)        # a round of the OSDs' pings
+                return loopprof.dump()["parts_us"]
+            finally:
+                tracer.disable()
+                tracer.reset()
+    return asyncio.run(main())
+
+
+@pytest.mark.parametrize("part", PARTS_ON_A_CLUSTER)
+def test_every_part_is_charged_on_a_live_cluster(cluster_parts, part):
+    """Each row of the table of parts finds its work: by code (the
+    loops, the selector's callbacks, the queue), by span (PG, EC, the
+    OSD's `ms_dispatch`) or by section; and what is left over in `other`
+    is the lesser share of its label."""
+    assert cluster_parts[part] > 0
+    label, _, name = part.partition(".")
+    if name == "other":
+        whole = sum(v for k, v in cluster_parts.items()
+                    if k.startswith(label + "."))
+        assert cluster_parts[part] < whole / 3
