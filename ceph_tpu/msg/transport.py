@@ -13,7 +13,11 @@ spill's size and from nothing else:
     its length, copies across whatever head of it already sits in the
     spill, and from then on `get_buffer` hands the kernel the unfilled
     tail: one `recv_into` takes whatever the socket holds, up to the
-    whole rest of the body. The buffer is never resized, pooled or
+    whole rest of the body. The destination is a `bytearray` made at
+    its length and not zero-filled (`_new_body`): every byte of it is
+    the kernel's to write before anyone reads one, so no byte is
+    written twice, and its pages are entered as the kernel's copy
+    reaches them. The buffer is never resized, pooled or
     reused; it lives as long as a view of it does. A body that carries
     a write is kept for good by the store it is written to (MemStore
     adopts the read-only view it is handed, `objectstore/memstore.py`),
@@ -42,6 +46,7 @@ writer.
 from __future__ import annotations
 
 import asyncio
+import functools
 
 from ceph_tpu.utils import tracer
 
@@ -51,6 +56,30 @@ SPILL_SIZE = 65536
 #: the part of it offered between large bodies: a 512 KiB sub-op whose
 #: head arrives with its preamble has at most 0.8% of itself copied
 NARROW = SPILL_SIZE // 16
+
+
+def _unzeroed():
+    """`make(n)` -> a `bytearray` of length `n` whose bytes are not
+    initialised (the C API's `PyByteArray_FromStringAndSize(NULL, n)`),
+    or `bytearray` itself on an interpreter that has no `ctypes`, no
+    `pythonapi` or no such symbol."""
+    try:
+        import ctypes
+        # PYFUNCTYPE: the GIL stays held, as by the memset this replaces
+        make = functools.partial(ctypes.PYFUNCTYPE(
+            ctypes.py_object, ctypes.c_void_p, ctypes.c_ssize_t)(
+            ("PyByteArray_FromStringAndSize", ctypes.pythonapi)), None)
+        tried = make(8)
+        if type(tried) is not bytearray or len(tried) != 8:
+            return bytearray
+    except (ImportError, AttributeError, OSError, TypeError, ValueError):
+        return bytearray
+    return make
+
+
+#: the destination of a large read, `_new_body(n)`: what it holds is
+#: stale heap until the kernel has written it
+_new_body = _unzeroed()
 
 
 class Endpoint(asyncio.BufferedProtocol):
@@ -217,8 +246,11 @@ class Endpoint(asyncio.BufferedProtocol):
         return out
 
     async def _read_body(self, n: int) -> bytearray:
+        # `buf` is not zero-filled: until `_dest_pos == n` its tail is
+        # stale heap (earlier messages, keys), so neither it nor `_dest`
+        # is ever read, logged or dumped past `_dest_pos`
         with tracer.section("msgr.rx_alloc"):
-            buf = bytearray(n)
+            buf = _new_body(n)
         have = self._wpos - self._rpos      # < n: the spill is smaller
         if have:
             buf[:have] = self._spill_mv[self._rpos:self._wpos]

@@ -16,6 +16,7 @@ from ceph_tpu.msg.frames import (Frame, FrameError, Onwire, Tag,
                                  encode_trace_ctx)
 from ceph_tpu.msg.messages import MOSDECSubOpWrite, MPing
 from ceph_tpu.msg.messenger import Messenger, Policy, msgr_perf
+from ceph_tpu.msg import transport
 from ceph_tpu.msg.transport import NARROW, SPILL_SIZE, Endpoint
 
 from tests.test_msg import Collector
@@ -220,6 +221,150 @@ def test_window_narrows_after_a_body_and_widens_on_small_traffic():
     run(main())
 
 
+# -- a body's memory: a `bytearray` at its length, not zero-filled ----------------
+
+#: how a body gets its memory: the C API's unzeroed `bytearray`, and
+#: `bytearray(n)`, which an interpreter without that symbol falls back to
+HOW = ["unzeroed", "zeroed"]
+BODY_SIZES = [SPILL_SIZE + 1, SPILL_SIZE + 4096, 100_003, 777_777, 512 << 10,
+              (512 << 10) + 2070, 4 << 20, (4 << 20) + 1234]
+
+
+@pytest.fixture
+def how(request, monkeypatch):
+    if request.param == "zeroed":
+        monkeypatch.setattr(transport, "_new_body", bytearray)
+    else:
+        assert transport._new_body is not bytearray, \
+            "this interpreter has the C API: the binding should have held"
+    return request.param
+
+
+@pytest.mark.parametrize("how", HOW, indirect=True)
+@pytest.mark.parametrize("head", [0, 1000])
+@pytest.mark.parametrize("n", BODY_SIZES)
+def test_a_body_is_the_senders_bytes_however_its_memory_came(n, head, how):
+    """Exactly `n` bytes, a plain `bytearray`, equal to what was sent:
+    with `head` bytes of it already in the spill behind the two that
+    were read first, and twice, so that the second body may get the
+    first one's freed memory."""
+    data = [os.urandom(n), os.urandom(n)]
+
+    async def main():
+        ep = await _made()
+        wire = b"ab" + data[0] + b"cd" + data[1]
+        feeder = asyncio.create_task(
+            _feed(ep, wire, [2 + head, n - head, 2 + head, 1 << 30]))
+        out = []
+        for i, lead in enumerate((b"ab", b"cd")):
+            assert await ep.readexactly(2) == lead
+            got = await ep.readexactly(n)
+            assert type(got) is bytearray and len(got) == n
+            assert got == data[i]
+            out.append(len(got))
+            del got
+        await feeder
+        return ep, out
+
+    ep, out = run(main())
+    assert out == [n, n]
+    assert ep._perf.v["rx_direct_bytes"] == 2 * (n - head)
+    assert ep._perf.v["rx_spill_bytes"] == 2 * (2 + head)
+
+
+@pytest.mark.parametrize("how", HOW, indirect=True)
+@pytest.mark.parametrize("n,got", [(SPILL_SIZE + 1, 1), (512 << 10, 300_001),
+                                   (4 << 20, (4 << 20) - 1)])
+def test_a_body_cut_short_hands_back_what_came_and_no_byte_more(n, got, how):
+    """EOF short of `n`: the partial read is the bytes received, never
+    the tail of the buffer, which nobody has written."""
+    data = os.urandom(got)
+
+    async def main():
+        ep = await _made()
+        read = asyncio.create_task(ep.readexactly(n))
+        await _feed(ep, data, [1000, 1 << 30])
+        assert not read.done()
+        ep.eof_received()
+        with pytest.raises(asyncio.IncompleteReadError) as ei:
+            await read
+        return ei.value
+
+    err = run(main())
+    assert err.expected == n
+    assert type(err.partial) is bytes and err.partial == data
+
+
+@pytest.mark.parametrize("how", HOW, indirect=True)
+def test_a_body_carries_no_export_and_the_store_adopts_its_window(how):
+    """Nothing is left on the buffer once it is full: its owner can
+    resize it, and MemStore keeps the read-only window it is handed as
+    it keeps one on a `bytearray(n)`."""
+    from ceph_tpu.objectstore import (CollectionId, Ghobject, MemStore,
+                                      Transaction)
+    from ceph_tpu.utils import copytrack
+
+    n, slack = (512 << 10) + 2070, 2070
+    data = os.urandom(n)
+
+    async def main():
+        ep = await _made()
+        feeder = asyncio.create_task(_feed(ep, data, [1 << 30]))
+        out = await ep.readexactly(n)
+        await feeder
+        return out
+
+    body = run(main())
+    view = memoryview(body).toreadonly()[slack:]
+    store = MemStore()
+    store.mkfs()
+    store.mount()
+    cid, oid = CollectionId.make_pg(1, 0x2A), Ghobject(pool=1, name="o")
+    store.queue_transaction(Transaction().create_collection(cid))
+    before = dict(copytrack.snapshot()["stages"]["store_write"])
+    store.queue_transaction(Transaction().write(cid, oid, 0, view))
+    after = copytrack.snapshot()["stages"]["store_write"]
+    assert store._colls[cid][oid].data is view
+    assert (after["referenced_bytes"] - before["referenced_bytes"],
+            after["copied_bytes"] - before["copied_bytes"]) == (n - slack, 0)
+    assert store.read(cid, oid) == data[slack:]
+    store.umount()
+    with pytest.raises(BufferError):        # the window's, and only its
+        body.extend(b"x")
+    del view, store, after
+    body.extend(b"x")
+    assert body == data + b"x"
+
+
+@pytest.mark.parametrize("n", BODY_SIZES)
+def test_an_unzeroed_body_is_a_plain_bytearray_of_its_length(n):
+    buf = transport._unzeroed()(n)
+    assert type(buf) is bytearray and len(buf) == n
+    buf[:] = bytes(n)               # every byte is there to be written
+    buf.extend(b"x")                # and its owner may resize it
+    assert buf == bytes(n) + b"x"
+
+
+@pytest.mark.parametrize("broken", ["no_ctypes", "no_pythonapi", "no_symbol"])
+def test_without_the_c_api_a_body_is_bytearray_n(broken, monkeypatch):
+    """Found once, at import, by trying it: an interpreter without
+    `ctypes`, without `ctypes.pythonapi` or without the symbol gets
+    the `bytearray(n)` the transport had before, and still imports."""
+    import ctypes.util
+    import sys
+
+    if broken == "no_ctypes":
+        monkeypatch.setitem(sys.modules, "ctypes", None)
+    elif broken == "no_pythonapi":
+        monkeypatch.delattr(ctypes, "pythonapi")
+    else:
+        libm = ctypes.util.find_library("m")
+        if libm is None:
+            pytest.skip("no second library to look the symbol up in")
+        monkeypatch.setattr(ctypes, "pythonapi", ctypes.PyDLL(libm))
+    assert transport._unzeroed() is bytearray
+
+
 # -- real sockets ----------------------------------------------------------------
 
 async def _socket_pair():
@@ -333,7 +478,8 @@ async def _wait_for(col: Collector, n: int) -> None:
         await asyncio.wait_for(col.got.wait(), 30)
 
 
-def test_a_4mib_message_lands_in_its_own_buffer(codec):
+@pytest.mark.parametrize("how", HOW, indirect=True)
+def test_a_4mib_message_lands_in_its_own_buffer(codec, how):
     async def main():
         server = Messenger("osd.1")
         col = Collector()
