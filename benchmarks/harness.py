@@ -17,6 +17,7 @@ import importlib.util
 import json
 import math
 import os
+import shutil
 import statistics
 import time
 
@@ -29,6 +30,8 @@ ROOT = os.path.dirname(HERE)
 CONTROLS = ("flip_read", "bitrot")
 SAMPLED = 8
 OP_KINDS = ("write", "read")
+STORES = ("memstore", "filestore", "bluestore")
+EVENT_KINDS = ("stop_osd", "start_osd", "osd_out", "osd_in")
 
 
 @dataclasses.dataclass
@@ -150,6 +153,8 @@ def _shard_blobs(osds, pool: str, oid: str) -> dict[int, bytes]:
         for pg in osd.pgs.values():
             if pg.pool.name != pool:
                 continue
+            if osd.whoami not in pg.acting:
+                continue        # a PG it has left: marked out, or a spare
             cid, gh = pg.backend.coll(), pg.backend.ghobject(oid)
             if osd.store.exists(cid, gh):
                 blobs[pg.acting.index(osd.whoami)] = bytes(
@@ -209,6 +214,8 @@ class _Run:
         self.setup_s = 0.0
         self.phases: dict[str, float] = {}  # seconds since process start
         self.compiles: list[float] = []     # perf_counter of each event
+        self.events: list[dict] = []        # the schedule's, as each ended
+        self.store_dirs: list[str] = []     # a persistent store's, to remove
 
 
 async def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
@@ -234,6 +241,8 @@ async def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                                control, run, device)
     finally:
         jax.monitoring.unregister_event_duration_listener(on_compile)
+        for path in run.store_dirs:
+            shutil.rmtree(path, ignore_errors=True)
 
 
 async def _run_cell(cell, seed, seconds, trace, out_dir, t_start, control,
@@ -243,7 +252,12 @@ async def _run_cell(cell, seed, seconds, trace, out_dir, t_start, control,
     from ceph_tpu import offload
     from ceph_tpu.tools.cluster_boot import ephemeral_cluster
     from ceph_tpu.utils import tracer
+    from ceph_tpu.utils.config import ConfigError
 
+    factory = store_factory(cell.config["objectstore"], run.store_dirs)
+    events = schedule_of(cell.traffic)
+    n_stop = cell.traffic.get("stop_osds", 0)
+    victims = draw_victims(seed, cell.config["osds"], n_stop, events)
     gen = cell.generator.make(cell.config, cell.traffic, seed)
     pool_cfg = cell.config["pool"]
     k, m, chunk = pool_cfg["k"], pool_cfg["m"], pool_cfg["stripe_unit"]
@@ -257,8 +271,15 @@ async def _run_cell(cell, seed, seconds, trace, out_dir, t_start, control,
         run.phases[name] = time.monotonic() - t_start
     phase("backend_and_payloads")
 
-    async with ephemeral_cluster(cell.config["osds"], prefix="bench-") \
-            as (client, osds, _mon):
+    async with ephemeral_cluster(cell.config["osds"], prefix="bench-",
+                                 store_factory=factory) \
+            as (client, osds, mon):
+        # defaults the mon reads when a pool is created, before it is
+        for key, value in cell.config.get("mon_config", {}).items():
+            try:
+                mon.config.set(key, value)
+            except ConfigError as e:
+                raise SystemExit(f"benchmark: mon_config: {e}")
         profile = {"plugin": pool_cfg["plugin"], "k": str(k), "m": str(m),
                    "technique": pool_cfg["technique"]}
         await client.command({"prefix": "osd erasure-code-profile set",
@@ -347,8 +368,39 @@ async def _run_cell(cell, seed, seconds, trace, out_dir, t_start, control,
         await asyncio.gather(*[loader() for _ in range(gen.clients)])
 
         phase("preload")
-        stopped = await stop_osds(cell.traffic.get("stop_osds", 0), seed,
-                                  osds, client)
+        down = set(await stop_osds(n_stop, seed, osds, client))
+        harness_stopped = set(down)     # at any time; `down` is now
+        timers: list[asyncio.TimerHandle] = []
+        event_tasks: list[asyncio.Task] = []
+
+        async def run_event(e: dict) -> None:
+            """One entry of the schedule, as a task of its own. It is
+            recorded once it has ended; one that raises is not."""
+            i = victims[e["osd"]]
+            try:
+                with tracer.span("bench_event") as sp:
+                    if sp is not None:
+                        sp.set_tag("do", e["do"])
+                        sp.set_tag("osd", i)
+                    if e["do"] == "stop_osd":
+                        down.add(i)
+                        harness_stopped.add(i)
+                        await osds[i].stop()
+                    elif e["do"] == "start_osd":
+                        if i not in down:
+                            raise RuntimeError(f"osd.{i} is running")
+                        await revive_osd(osds, i, mon, cell.config.get(
+                            "osd_config", {}))
+                        down.discard(i)
+                    else:       # the mon's command of that name
+                        await client.command({
+                            "prefix": e["do"].replace("_", " "),
+                            "ids": [i]})
+            except Exception as exc:    # a check row; the run goes on
+                run.failures.append(f"event {e['do']} osd.{i}: {exc!r}")
+                return
+            run.events.append({"do": e["do"], "osd": i,
+                               "t_s": time.perf_counter() - run.t_open})
 
         # the profiler's trace is read for TPU planes only; on another
         # backend (the tests) a traced run still reads spans and counters
@@ -383,6 +435,10 @@ async def _run_cell(cell, seed, seconds, trace, out_dir, t_start, control,
             run.snap_open = _snapshot(svc)
             run.t_open = run.snap_open["t"]
             run.setup_s = time.monotonic() - t_start
+            for e in events:
+                timers.append(loop.call_later(
+                    e["at_s"], lambda e=e: event_tasks.append(
+                        loop.create_task(run_event(e)))))
             loop.call_later(seconds, close_window)
 
         def close_window() -> None:
@@ -408,6 +464,13 @@ async def _run_cell(cell, seed, seconds, trace, out_dir, t_start, control,
                     run.records.append(rec)
 
         await asyncio.gather(*[client_loop() for _ in range(gen.clients)])
+        for timer in timers:        # one due after the close never ran
+            timer.cancel()
+        if event_tasks:
+            _done, late = await asyncio.wait(event_tasks, timeout=60)
+            for task in late:
+                task.cancel()
+            await asyncio.gather(*late, return_exceptions=True)
         await svc.drain()
         if profiling:
             jax.profiler.stop_trace()
@@ -420,15 +483,19 @@ async def _run_cell(cell, seed, seconds, trace, out_dir, t_start, control,
                    and r[1] < t_close]
         good = [r for r in started if r[2] <= t_close and r[3]]
         lat = sorted((r[2] - r[1]) * 1e3 for r in good)
+        stopped = sorted(down)      # not running when the window closed
         checks = await final_checks(
             cell, gen, model, io, osds, pool, seed, control, run,
-            k, m, chunk, stopped, watch, svc, device.platform)
+            k, m, chunk, stopped, watch, svc, device.platform,
+            harness_stopped)
         watching.cancel()
         await asyncio.gather(watching, return_exceptions=True)
         checks.insert(0, ("ops_failed", sum(not r[3] for r in started), 0))
         checks.insert(1, ("read_mismatches", run.read_mismatches, 0))
-
         window_s = t_close - t_open
+        checks.insert(2, ("events_failed", len(events) - sum(
+            e["t_s"] <= window_s for e in run.events), 0))
+
         metrics: dict[str, dict] = {}
         if not trace:
             values = {"ops_s": len(good) / window_s,
@@ -462,7 +529,8 @@ async def _run_cell(cell, seed, seconds, trace, out_dir, t_start, control,
                                       if o.whoami not in stopped),
                       live_user_bytes=sum(
                           len(gen.value_of(n, max(model.candidates(n))))
-                          for n in model.names()))
+                          for n in model.names()),
+                      events=list(run.events))
             for mod in cell.readers:
                 value = mod.read(ctx)
                 if value is not None:
@@ -498,6 +566,7 @@ async def _run_cell(cell, seed, seconds, trace, out_dir, t_start, control,
             "compiles_in_window": len(in_window),
             "compile_events": len(run.compiles),
             "convoy": ser["completions"][:8],
+            "events": run.events,
             "failures": run.failures[:5]}
     out = {"correct": correct, "attempted": len(started),
            "failed": sum(not r[3] for r in started),
@@ -512,7 +581,9 @@ class Ctx:
     """What a per-layer reader may read. `open` and `close` are the
     counter snapshots at the window's edges; `spans` are the program's
     spans that lie inside the window; `trace` is the reduced profile;
-    `ops` and `user_bytes` count the ops that completed in the window."""
+    `ops` and `user_bytes` count the ops that completed in the window;
+    `events` are the traffic file's that ran: `do`, the OSD's id, and
+    `t_s`, the seconds after the window opened at which it had ended."""
     cell: Cell
     window_s: float
     ops: int
@@ -527,6 +598,7 @@ class Ctx:
     peaks: dict | None
     store_bytes: int
     live_user_bytes: int
+    events: list[dict]
 
     def delta(self, group: str, key: str) -> float:
         return self.close[group][key] - self.open[group][key]
@@ -572,14 +644,86 @@ def _encode_direct(profile: dict, devices, k: int, chunk: int,
             np.asarray(code.encode_stripes(jax.device_put(batch, device)))
 
 
+def store_factory(kind: str, made: list[str]):
+    """A configuration's `objectstore` as `ephemeral_cluster` takes it:
+    nothing for `memstore` (its own default), else a store of that name
+    in a directory of its own under the cluster's temporary directory.
+    `made` collects the directories, for the run to remove."""
+    if kind not in STORES:
+        raise SystemExit(f"benchmark: objectstore {kind!r} is not one of "
+                         f"{STORES}")
+    if kind == "memstore":
+        return None
+    if kind == "filestore":
+        from ceph_tpu.objectstore.filestore import FileStore as Store
+    else:
+        from ceph_tpu.objectstore.bluestore import BlueStore as Store
+
+    def factory(tmpdir: str, osd_id: int):
+        made.append(os.path.join(tmpdir, f"osd{osd_id}"))
+        return Store(made[-1])
+    return factory
+
+
+def schedule_of(traffic: dict) -> list[dict]:
+    """A traffic file's `events`, each `{"at_s", "do", "osd"}`: `at_s`
+    seconds after the window opens, `do` one of `EVENT_KINDS`, `osd` an
+    index into the victims the seed draws (`draw_victims`)."""
+    events = traffic.get("events", [])
+    for e in events:
+        if set(e) != {"at_s", "do", "osd"}:
+            raise SystemExit(f"benchmark: event {e!r} has other keys than "
+                             f"at_s, do, osd")
+        if e["do"] not in EVENT_KINDS:
+            raise SystemExit(f"benchmark: event {e!r}: do {e['do']!r} is "
+                             f"not one of {EVENT_KINDS}")
+        if not (isinstance(e["at_s"], (int, float)) and e["at_s"] >= 0):
+            raise SystemExit(f"benchmark: event {e!r}: at_s "
+                             f"{e['at_s']!r} is no time at or after 0")
+        if not isinstance(e["osd"], int) or e["osd"] < 0:
+            raise SystemExit(f"benchmark: event {e!r}: osd {e['osd']!r} "
+                             f"is no index")
+    return events
+
+
+def draw_victims(seed: int, n_osds: int, stop: int,
+                 events: list[dict]) -> list[int]:
+    """The OSD ids a mix's failures fall on. The first `stop` are the
+    ones set-up stops, the draw `stop_osds` has always made; the events
+    index the same list, which goes on through the other OSDs in an
+    order drawn after it, as far as they reach."""
+    need = max([stop] + [e["osd"] + 1 for e in events])
+    if need > n_osds:
+        raise SystemExit(f"benchmark: the mix names {need} OSDs and the "
+                         f"deployment has {n_osds}")
+    if not need:
+        return []
+    rng = np.random.default_rng([seed, 4])
+    victims = sorted(int(x) for x in rng.choice(
+        n_osds, size=stop, replace=False)) if stop else []
+    rest = [int(x) for x in rng.permutation(n_osds) if x not in victims]
+    return victims + rest[:need - stop]
+
+
+async def revive_osd(osds: list, i: int, mon, osd_config: dict) -> None:
+    """A new daemon of the stopped osd.`i` on its store, with the
+    deployment's settings: a thrasher's revive (`OSD.start` does not
+    run twice on one object). It takes the old one's place in `osds`
+    before it starts, so the cluster's teardown reaps it either way."""
+    from ceph_tpu.osd.daemon import OSD
+
+    old = osds[i]
+    osds[i] = OSD(i, list(mon.monmap.mons.values()), store=old.store,
+                  crush_location=old.crush_location)
+    for key, value in osd_config.items():
+        osds[i].config.set(key, value)
+    await osds[i].start()
+
+
 async def stop_osds(n: int, seed: int, osds, client) -> list[int]:
     """Stop `n` OSDs drawn from the seed and wait until every map says
     so (a mix's failure to inject; copied from chip_smoke.py)."""
-    if not n:
-        return []
-    rng = np.random.default_rng([seed, 4])
-    dead = sorted(int(x) for x in rng.choice(len(osds), size=n,
-                                             replace=False))
+    dead = draw_victims(seed, len(osds), n, [])
     for i in dead:
         await osds[i].stop()
     alive = [o for o in osds if o.whoami not in dead]
@@ -595,10 +739,12 @@ async def stop_osds(n: int, seed: int, osds, client) -> list[int]:
 
 async def final_checks(cell, gen, model, io, osds, pool, seed, control, run,
                        k, m, chunk, stopped, watch, svc,
-                       platform) -> list[tuple]:
+                       platform, harness_stopped) -> list[tuple]:
     """After the window: sampled objects read back and their shards at
     rest compared with the reference; the smoke's gates on the offload
-    counters. Returns (name, value, limit) rows; all limits are exact."""
+    counters. Returns (name, value, limit) rows; all limits are exact.
+    `stopped` are the OSDs not running now; `harness_stopped` those the
+    harness stopped at any time, whose mark-downs are its own doing."""
     rng = np.random.default_rng([seed, 5])
     names = model.names()
     sample = [names[i] for i in sorted(rng.choice(
@@ -629,7 +775,8 @@ async def final_checks(cell, gen, model, io, osds, pool, seed, control, run,
             diff = 0
             for shard in range(k + m):
                 if shard not in blobs:
-                    # a shard may be missing only with its OSD stopped
+                    # a shard may be missing only with an OSD stopped
+                    # (its own, or one whose shards a spare now takes)
                     diff += 0 if stopped else want.shape[1]
                     continue
                 have = np.frombuffer(blobs[shard], dtype=np.uint8)
@@ -644,7 +791,7 @@ async def final_checks(cell, gen, model, io, osds, pool, seed, control, run,
     strangers = [d for d in devices
                  if d != "host" and not d.startswith(platform + ":")]
     watch.poll()
-    marked = watch.marked - {f"osd.{i}" for i in stopped}
+    marked = watch.marked - {f"osd.{i}" for i in harness_stopped}
     return [("sample_read_mismatches", sample_mismatches, 0),
             ("shard_bytes_differing", shard_bytes_differing, 0),
             ("fallback_ops", off["fallback_ops"], 0),

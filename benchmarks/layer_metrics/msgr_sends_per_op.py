@@ -14,4 +14,4 @@ def read(ctx):
     got = msgr_ctrl.deltas(ctx)
     if got is None or not ctx.ops:
         return None
-    return got[2] / ctx.ops
+    return got[1] / ctx.ops

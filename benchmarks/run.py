@@ -91,9 +91,15 @@ def main(argv: list[str] | None = None) -> int:
         cell, args.seed, args.seconds, bool(args.trace), out_dir, t_start,
         control))
     print(f"benchmark: {json.dumps(done['info'])}")
+    # each number compared beside its limit: the last lines of standard
+    # error, and the last key of the result's line
     for name, value, limit in done["checks"]:
-        print(f"benchmark: check {name} = {value} (limit {limit})")
-    print(json.dumps(done["result"]), flush=True)
+        print(f"benchmark: check {name} = {value} (limit {limit})",
+              file=sys.stderr)
+    sys.stderr.flush()
+    checks = {name: {"value": value, "limit": limit}
+              for name, value, limit in done["checks"]}
+    print(json.dumps({**done["result"], "checks": checks}), flush=True)
     return 0
 
 

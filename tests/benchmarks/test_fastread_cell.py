@@ -20,12 +20,13 @@ CELL = "rb4m_fastread_seqread"
 NEW = ["fastread_decode_pct", "fastread_mean_r", "fastread_decode_patterns",
        "fastread_matrix_misses", "fastread_late_replies_pct",
        "dispatch_held_pct"]
-RENAMED = ["ec_read_ms.fastread", "ec_decode_ms.fastread",
-           "decode_ops_per_batch.fastread", "decode_handoff_ms.fastread",
-           "decode_device_call_ms.fastread",
-           "decode_link_bytes_per_byte.fastread",
-           "decode_bitmatrix_roofline.fastread"]
-FROM_TRACE = {"device_idle_pct", "decode_bitmatrix_roofline.fastread"}
+#: accepted entries (the read cell's one, the degraded cell's six)
+#: that list this cell since PR 41; before it, readers of this cell's
+#: own imported them as `<name>.fastread`
+FOLDED = ["ec_read_ms", "ec_decode_ms", "decode_ops_per_batch",
+          "decode_handoff_ms", "decode_device_call_ms",
+          "decode_link_bytes_per_byte", "decode_bitmatrix_roofline"]
+FROM_TRACE = {"device_idle_pct", "decode_bitmatrix_roofline"}
 K, M = 8, 3
 
 
@@ -35,22 +36,26 @@ def _reader(name):
 
 # -- BENCHMARK.json and the files it names --------------------------------------------
 
-def test_the_entries_are_appended_and_nothing_before_them_moved():
-    """A prefix check (50 entries, three configurations and four cells
-    stood before this PR), so that the next PR's entries do not fail
-    it."""
+def test_the_entries_stand_in_their_order_after_what_stood_before_them():
+    """PR 35 appended them after the stores' two shares (PR 34); PR 41
+    took out four entries before them and this cell's seven renamed
+    readers after, so the place is found by name. Three configurations
+    and four cells stood before this one."""
     names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[50:63] == NEW + RENAMED
-    assert names[48:50] == ["store_write_direct_pct", "store_read_direct_pct"]
-    for m in BENCH["per_layer"][50:63]:
+    at = names.index(NEW[0])
+    assert names[at - 2:at] == ["store_write_direct_pct",
+                                "store_read_direct_pct"]
+    assert names[at:at + 6] == NEW
+    assert names[at + 6] == "msgr_acks_carried_pct"
+    for m in BENCH["per_layer"][at:at + 6]:
         assert m["workloads"] == [CELL]
         mod = _reader(m["name"])
         assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
             (m["name"], m["unit"], m["layer"], m["moves"])
-    assert all(CELL not in m.get("workloads", [])
-               for m in BENCH["per_layer"][:50])
-    layers = {m["layer"] for m in BENCH["per_layer"][:50]}
-    assert {m["layer"] for m in BENCH["per_layer"][50:63]} <= layers
+    assert [m["name"] for m in BENCH["per_layer"][:at]
+            if CELL in m.get("workloads", [])] == FOLDED
+    layers = {m["layer"] for m in BENCH["per_layer"][:at]}
+    assert {m["layer"] for m in BENCH["per_layer"][at:at + 6]} <= layers
     assert [c["name"] for c in BENCH["configs"]][:4] == [
         "radosbench_ec83_tpu", "radosbench_ec83_tpu_degraded",
         "radosbench_ec83_tpu_scrub", CONFIG]
@@ -71,8 +76,10 @@ def test_configuration_and_traffic_hold_the_deployments_keys():
     for key in ("generator", "object_size", "concurrent_ops", "pool", "osds",
                 "offload_service", "hosts", "objectstore"):
         assert body[key] == sibling[key], key
+    # the pool's default is the mon's, in force when the pool is made
+    assert body["mon_config"] == {"osd_pool_default_ec_fast_read": True}
+    assert "mon_config" not in sibling
     assert body["osd_config"] == {
-        "osd_pool_default_ec_fast_read": True,
         "osd_debug_inject_dispatch_delay_probability": 0.1,
         "osd_debug_inject_dispatch_delay_duration": 0.1,
         "osd_scrub_interval": 86400.0, "osd_heartbeat_grace": 20.0}
@@ -83,8 +90,8 @@ def test_configuration_and_traffic_hold_the_deployments_keys():
         {"fast_read", "decodes_served_by"}
     assert "first k chunks of one version" in body["guarantees"]["fast_read"]
     assert body["guarantees"]["durability"].startswith("none")
-    for key in ("fast_read_default_on_the_osd",
-                "where_the_delay_is_consulted", "values_and_fragment_names",
+    assert "fast_read_default_on_the_osd" not in body["assumed"]
+    for key in ("where_the_delay_is_consulted", "values_and_fragment_names",
                 "thrasher_left_out", "seq_wraps"):
         assert key in body["assumed"], key
     traffic = json.load(open(os.path.join(
@@ -117,17 +124,18 @@ def test_the_reference_imports_nothing_of_the_program():
 def test_the_cell_loads_its_readers_and_no_other_cells():
     cell = harness.load_cell(CELL)
     names = {r.NAME for r in cell.readers}
-    assert set(NEW + RENAMED) <= names
-    # the accepted entries that list their cells do not list this one,
-    # and are not a `model_config` PR's to append to: neither of the
-    # stores' shares is read here (tests/conftest.py says which accepted
-    # test expects one), nor the degraded cell's own seven
+    assert set(NEW + FOLDED) <= names
+    # neither of the stores' shares lists this cell (its reads are the
+    # read cell's, its decodes the degraded cell's: nothing of its own
+    # to say there), nor the degraded cell's share of reads that decode,
+    # which `fastread_decode_pct` is here
     assert not {"store_read_direct_pct", "store_write_direct_pct",
-                "degraded_read_pct", "ec_decode_ms", "ec_read_ms"} & names
+                "degraded_read_pct"} & names
+    assert not any(n.endswith(".fastread") for n in names)
     for w in BENCH["workloads"]:
         if w["name"] != CELL:
             other = {r.NAME for r in harness.load_cell(w["name"]).readers}
-            assert not other & set(NEW + RENAMED)
+            assert not other & set(NEW)
 
 
 # -- the readers on hand-built spans -------------------------------------------------------
@@ -169,14 +177,15 @@ PARENT_READS = [_span("ec_read", 90000.0, bytes=4 << 20, shards_asked=7,
                       rounds=1)] * 5
 
 
-@pytest.mark.parametrize("name", NEW + RENAMED)
+@pytest.mark.parametrize("name", NEW + FOLDED)
 @pytest.mark.parametrize("case", ["nothing", "untagged_spans"])
 def test_reader_finds_nothing_on_a_program_without_the_tags(name, case):
     """A program without `fast_read` opens `ec_read` spans that are not
     tagged `fast`, holds nothing back, decodes nothing in a healthy
-    pool and builds no codec: every new reader returns None there
-    (`ec_read_ms.fastread` is the accepted reader and reads the spans
-    the parent opens too: None only where there are none)."""
+    pool and builds no codec: every reader of this cell's own returns
+    None there, and so do the degraded cell's that it shares
+    (`ec_read_ms` reads the spans the parent opens too: None only
+    where there are none)."""
     ctx = {
         "nothing": _ctx(),
         "untagged_spans": _ctx(
@@ -187,7 +196,7 @@ def test_reader_finds_nothing_on_a_program_without_the_tags(name, case):
             trace=TRACE, peaks=PEAKS),
     }[case]
     got = _reader(name).read(ctx)
-    if name == "ec_read_ms.fastread" and case == "untagged_spans":
+    if name == "ec_read_ms" and case == "untagged_spans":
         assert got == pytest.approx(90.0)
     else:
         assert got is None
@@ -236,21 +245,20 @@ def test_fastread_readers_read_the_spans():
     assert _reader("fastread_matrix_misses").read(warm) == 0.0
 
 
-@pytest.mark.parametrize("name", RENAMED)
-def test_an_accepted_reader_under_this_cells_name(name):
-    """The degraded cell's readers and `ec_read_ms` list their cells,
-    and a `model_config` PR may not append to an accepted entry: the
-    same code reads them here, under `<name>.fastread`."""
-    accepted, mod = _reader(name[:-len(".fastread")]), _reader(name)
-    assert mod.read.__code__.co_filename == accepted.read.__code__.co_filename
-    assert (mod.UNIT, mod.LAYER, mod.MOVES) == \
-        (accepted.UNIT, accepted.LAYER, accepted.MOVES)
+@pytest.mark.parametrize("name", FOLDED)
+def test_an_accepted_entry_lists_this_cell(name):
+    """The degraded cell's readers and `ec_read_ms` list their cells.
+    Until PR 41 this one reported them as `<name>.fastread` through
+    readers of its own that imported the accepted ones; now the
+    accepted entries name the cell, after the cells they named before,
+    and neither those entries nor those files are left."""
     by = {m["name"]: m for m in BENCH["per_layer"]}
-    assert {k: v for k, v in by[name].items()
-            if k not in ("name", "workloads")} == \
-        {k: v for k, v in by[accepted.NAME].items()
-         if k not in ("name", "workloads")}
-    assert CELL not in by[accepted.NAME]["workloads"]
+    assert by[name]["workloads"][-1] == CELL
+    assert "rb4m_degraded_seqread" in by[name]["workloads"]
+    assert name + ".fastread" not in by
+    with pytest.raises(SystemExit):
+        _reader(name + ".fastread")
+    assert name in {r.NAME for r in harness.load_cell(CELL).readers}
 
 
 def test_the_roofline_reckons_each_batch_at_its_true_r():
@@ -259,7 +267,7 @@ def test_the_roofline_reckons_each_batch_at_its_true_r():
     program's device time, and it is under 100."""
     from benchmarks.layer_metrics.apply_bitmatrix_batched_roofline import (
         least_seconds)
-    mod = _reader("decode_bitmatrix_roofline.fastread")
+    mod = _reader("decode_bitmatrix_roofline")
     batches = [_batch("dec", r=r, pattern=f"p{r}") for r in (1, 1, 2, 3)]
     ctx = _ctx(spans={"offload_batch": batches}, trace=TRACE, peaks=PEAKS)
     least = sum(max(least_seconds(4 << 20, K, r, PEAKS).values())
@@ -298,7 +306,7 @@ def test_tiny_served_run_is_correct_and_reconstructs(served):
     assert all(value <= limit for _n, value, limit in done["checks"])
     m = {k: v["value"] for k, v in line["metrics"].items()}
     assert set(m) == {r.NAME for r in cell.readers} - FROM_TRACE
-    assert set(NEW + RENAMED) - FROM_TRACE <= set(m)
+    assert set(NEW + FOLDED) - FROM_TRACE <= set(m)
     assert m["compiles_in_window"] == 0
     # k=2 m=1 here: a read asks both peers and answers from the first
     assert 0 < m["fastread_decode_pct"] < 100
@@ -307,13 +315,12 @@ def test_tiny_served_run_is_correct_and_reconstructs(served):
     assert 1 <= m["fastread_decode_patterns"] <= 3
     assert m["fastread_matrix_misses"] >= 0
     assert 5.0 <= m["dispatch_held_pct"] <= 15.0
-    assert m["decode_ops_per_batch.fastread"] >= 1.0
-    assert m["ec_read_ms.fastread"] > 0 and m["ec_decode_ms.fastread"] > 0
-    assert m["decode_handoff_ms.fastread"] > 0
-    assert m["decode_device_call_ms.fastread"] > 0
-    assert 0 < m["decode_link_bytes_per_byte.fastread"] < 1.5
-    # neither of the stores' shares: their accepted entries list their
-    # cells (what test_store_direct's case of this cell would look for)
+    assert m["decode_ops_per_batch"] >= 1.0
+    assert m["ec_read_ms"] > 0 and m["ec_decode_ms"] > 0
+    assert m["decode_handoff_ms"] > 0
+    assert m["decode_device_call_ms"] > 0
+    assert 0 < m["decode_link_bytes_per_byte"] < 1.5
+    # neither of the stores' shares: their entries do not list this cell
     assert not {"store_read_direct_pct", "store_write_direct_pct"} & set(m)
     reads = [s["tags"] for s in seen["spans"]["ec_read"]]
     assert reads and all(t["fast"] is True and t["shards_asked"] == 2

@@ -5,8 +5,6 @@ parts add up to their labels and whose labels add up to the loop's CPU
 an op."""
 from __future__ import annotations
 
-import hashlib
-import os
 import types
 
 import pytest
@@ -27,11 +25,6 @@ READERS.update({f"msgr_{p}_ms_per_op": ("msg/messenger", f"msgr.{p}")
 READERS.update({f"osd_{p}_ms_per_op": ("osd/pg+osd/ec_backend", f"osd.{p}")
                 for p in OSD})
 READERS[SCRUB] = ("osd/scrub", "osd.scrub")
-#: BENCHMARK.json at PR 37 without its closing "\n  ]\n}\n": all that
-#: stood before these entries, `per_layer`'s first sixty-four included
-PARENT_BYTES = 19482
-PARENT_SHA256 = \
-    "5832117613500903ac4fe9b757fa4c0e4767f9e529979903a84e611654597f08"
 PARTS = [f"msgr.{p}" for p in MSGR] + [f"osd.{p}" for p in OSD + ("scrub",)]
 
 
@@ -53,10 +46,13 @@ def _ctx(ops, *slices):
 
 # -- the entries ---------------------------------------------------------------
 
-def test_the_sixteen_entries_are_appended_and_what_stood_is_the_parents():
-    """Byte for byte: the file up to the end of the sixty-fourth entry
-    is the parent's; a prefix check, so a later PR's entries pass it."""
-    added = BENCH["per_layer"][64:80]
+def test_the_sixteen_entries_stand_in_their_order_after_the_acks_share():
+    """PR 39 appended them after `msgr_acks_carried_pct`; PR 41 took
+    eleven entries out before them, so the place is found by name, and
+    a later PR's entries come after."""
+    names = [m["name"] for m in BENCH["per_layer"]]
+    at = names.index(WHOLE)
+    added = BENCH["per_layer"][at:at + 16]
     assert [m["name"] for m in added] == list(READERS)
     for m in added:
         layer, _part = READERS[m["name"]]
@@ -65,14 +61,10 @@ def test_the_sixteen_entries_are_appended_and_what_stood_is_the_parents():
         if m["name"] == SCRUB:
             want["workloads"] = ["rb4m_scrub_seqread"]
         assert m == want
-    assert BENCH["per_layer"][63]["name"] == "msgr_acks_carried_pct"
-    with open(os.path.join(ROOT, "BENCHMARK.json"), "rb") as f:
-        text = f.read()
-    assert hashlib.sha256(text[:PARENT_BYTES]).hexdigest() == PARENT_SHA256
-    assert text[PARENT_BYTES:].startswith(b',\n    {\n      "name": "%s",'
-                                          % WHOLE.encode())
+    assert names[at - 1] == "msgr_acks_carried_pct"
+    assert not any(n.endswith("_ms_per_op") for n in names[:at])
     assert {layer for layer, _ in READERS.values()} <= \
-        {m["layer"] for m in BENCH["per_layer"][:64]}
+        {m["layer"] for m in BENCH["per_layer"][:at]}
 
 
 @pytest.mark.parametrize("name", READERS)

@@ -1,6 +1,10 @@
 """The readers of the messenger's control-frame and send counters
-(`msgr_ctrl_frames_per_op`, `msgr_ctrl_rode_pct`, `msgr_sends_per_op`),
-on hand-built snapshots and in a tiny traced run of each cell."""
+(`msgr_ctrl_frames_per_op`, `msgr_sends_per_op`), on hand-built
+snapshots and in a tiny traced run of each cell. A third,
+`msgr_ctrl_rode_pct` (the share of control frames that left beside a
+MESSAGE frame), was retired in PR 41: since PR 37 an ack is a field of
+a header and no frame, so nothing rides and the share said nothing;
+the counter `ctrl_rode_tx` stays the program's and feeds no reader."""
 from __future__ import annotations
 
 import types
@@ -11,9 +15,8 @@ from tests.benchmarks.test_benchmarks import BENCH, CELLS, _tiny
 from tests.benchmarks.test_msgr_rx import ROOT
 from benchmarks import harness
 
-NEW = ["msgr_ctrl_frames_per_op", "msgr_ctrl_rode_pct", "msgr_sends_per_op"]
+NEW = ["msgr_ctrl_frames_per_op", "msgr_sends_per_op"]
 SHAPE = {"msgr_ctrl_frames_per_op": ("frames/op", "lower"),
-         "msgr_ctrl_rode_pct": ("%", "higher"),
          "msgr_sends_per_op": ("sends/op", "lower")}
 
 
@@ -31,15 +34,19 @@ def _counters(ctrl, rode, sends, **more):
                 frames_tx=5, tx_direct_bytes=1, **more)
 
 
-def test_the_three_entries_are_appended_and_nothing_before_them_moved():
-    """A prefix check (34 entries stood before this PR), so that the
-    next PR's entries do not fail it."""
+def test_the_two_entries_stand_where_they_stood_and_the_third_is_gone():
+    """A prefix check (34 entries stood before PR 30's three), so that
+    a later PR's entries do not fail it; the retired share has neither
+    an entry nor a reader."""
     names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[34:37] == NEW
+    assert names[34:36] == NEW
     assert names[24:26] == ["msgr_rx_direct_pct", "msgr_recvs_per_mib"]
     assert names[32:34] == ["decode_bitmatrix_roofline",
                             "msgr_tx_direct_pct"]
-    for entry in BENCH["per_layer"][34:37]:
+    assert "msgr_ctrl_rode_pct" not in names
+    with pytest.raises(SystemExit):
+        _reader("msgr_ctrl_rode_pct")
+    for entry in BENCH["per_layer"][34:36]:
         unit, better = SHAPE[entry["name"]]
         assert entry == {"name": entry["name"], "unit": unit,
                          "better": better, "source": "program_counter",
@@ -72,18 +79,23 @@ def test_reader_finds_nothing_where_there_is_nothing_to_read(name, case):
     assert _reader(name).read(ctx) is None
 
 
-def test_a_window_without_a_control_frame_has_no_share_but_has_counts():
+def test_a_window_without_a_control_frame_has_counts_all_the_same():
+    """Nor do the two need the retired share's counter: a program that
+    drops `ctrl_rode_tx` is read as before."""
     ctx = _ctx(_counters(7, 3, 100), _counters(7, 3, 180), ops=8)
-    assert _reader("msgr_ctrl_rode_pct").read(ctx) is None
+    assert _reader("msgr_ctrl_frames_per_op").read(ctx) == 0.0
+    assert _reader("msgr_sends_per_op").read(ctx) == 10.0
+    for snap in (ctx.open["msgr"], ctx.close["msgr"]):
+        del snap["ctrl_rode_tx"]
     assert _reader("msgr_ctrl_frames_per_op").read(ctx) == 0.0
     assert _reader("msgr_sends_per_op").read(ctx) == 10.0
 
 
 @pytest.mark.parametrize("ctrl,rode,sends,ops,want", [
-    (100, 50, 400, 20, (5.0, 50.0, 20.0)),
-    (9, 9, 27, 3, (3.0, 100.0, 9.0)),
-    (8, 0, 8, 16, (0.5, 0.0, 0.5)),
-    (1000, 925, 2700, 100, (10.0, 92.5, 27.0)),
+    (100, 50, 400, 20, (5.0, 20.0)),
+    (9, 9, 27, 3, (3.0, 9.0)),
+    (8, 0, 8, 16, (0.5, 0.5)),
+    (1000, 925, 2700, 100, (10.0, 27.0)),
 ])
 def test_values_are_the_windows_deltas(ctrl, rode, sends, ops, want):
     before = _counters(700, 600, 5000)
@@ -95,16 +107,17 @@ def test_values_are_the_windows_deltas(ctrl, rode, sends, ops, want):
 @pytest.mark.parametrize("cell", CELLS)
 def test_tiny_traced_run_reports_the_control_frames(cell, tmp_path):
     """Every cell's ops are answered over connections that owe acks:
-    the line of a traced run has all three metrics, control frames do
-    ride, and no op costs fewer sends than one."""
+    the line of a traced run has both metrics (a window this short may
+    frame no control frame at all: an ack is a header's field), and no
+    op costs fewer sends than one."""
     done, _cell = _tiny(cell, trace=True, tmp=tmp_path)
     line = done["result"]
     assert line["correct"] is True
     got = {n: line["metrics"][n] for n in NEW}
     assert {n: g["unit"] for n, g in got.items()} == {
         n: SHAPE[n][0] for n in NEW}
-    assert got["msgr_ctrl_frames_per_op"]["value"] > 0
-    assert 0.0 < got["msgr_ctrl_rode_pct"]["value"] <= 100.0
+    assert got["msgr_ctrl_frames_per_op"]["value"] >= 0
+    assert "msgr_ctrl_rode_pct" not in line["metrics"]
     assert got["msgr_sends_per_op"]["value"] >= 1.0
     assert got["msgr_sends_per_op"]["value"] <= \
         line["metrics"]["msgr_frames_per_op"]["value"] \
