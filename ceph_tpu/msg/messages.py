@@ -362,3 +362,10 @@ MOSDScrubReserve = _simple(0x82, "MOSDScrubReserve")  # remote range
                                                     #  "op": "reserve"|
                                                     #  "grant"|"reject"|
                                                     #  "release"}
+MBackfillReserve = _simple(0x83, "MBackfillReserve")  # the same handshake
+                                                    # for a slot of the
+                                                    # target's
+                                                    # osd_max_backfills
+                                                    # (src/messages/
+                                                    #  MBackfillReserve.h);
+                                                    # both: osd/reserver.py

@@ -1120,11 +1120,14 @@ class OffloadService:
             self._flush_bucket(key)
 
     async def drain(self) -> None:
-        """Flush and wait for every in-flight batch (tests/bench)."""
+        """Flush and wait for every batch in flight NOW (tests/bench).
+        What others submit meanwhile is theirs to wait for: a backfill
+        that runs beside the caller feeds the service all the time, and
+        waiting for an idle one would wait for the backfill's end."""
         self._flush_all()
-        while self._tasks:
-            await asyncio.gather(*list(self._tasks),
-                                 return_exceptions=True)
+        mine = set(self._tasks)
+        while mine & self._tasks:
+            await asyncio.gather(*mine, return_exceptions=True)
             # gather over already-finished tasks completes without
             # suspending; the discard callbacks that empty _tasks only
             # run once the loop gets a turn

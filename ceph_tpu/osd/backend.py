@@ -112,7 +112,10 @@ class PGBackend:
             if not fut.done():
                 fut.set_result(None)
 
-    def fail_inflight(self, why: str) -> None:
+    def fail_inflight(self, why: str, reads: bool = False) -> None:
+        """Fail every fan-out that waits for its peers; with `reads`
+        (the daemon is stopping) what waits for a peer's read as well,
+        where the backend reads from peers."""
         for pending, fut in self._inflight.values():
             if not fut.done():
                 fut.set_exception(IntervalChange(why))
@@ -235,6 +238,12 @@ class PGBackend:
     def local_exists(self, oid: str, shard: int = -1) -> bool:
         return self.host.store.exists(self.coll(shard),
                                       self.ghobject(oid, shard))
+
+    def set_aside_misplaced(self, position: int) -> list[str]:
+        """Objects this OSD holds for another position of the acting
+        set than `position`, set aside; a replica holds whole objects,
+        so none (ECBackend keeps a chunk a position)."""
+        return []
 
     # -- interface subclasses implement --------------------------------------
 

@@ -24,7 +24,8 @@ import time
 
 from ceph_tpu.crush.osdmap import PG, Incremental, OSDMap, pool_options
 from ceph_tpu.mgr.mgr_client import MgrClient
-from ceph_tpu.msg.messages import (Message, MOSDECSubOpWrite, MOSDOp,
+from ceph_tpu.msg.messages import (MBackfillReserve, Message,
+                                   MOSDECSubOpWrite, MOSDOp,
                                    MOSDOpReply,
                                    MOSDOpThrottle, MOSDPGInfo,
                                    MOSDPGLog, MOSDPGPush, MOSDPGPushReply,
@@ -38,6 +39,7 @@ from ceph_tpu.objectstore.store import StoreError
 from ceph_tpu.osd import scrub as scrub_mod
 from ceph_tpu.osd.backend import IntervalChange
 from ceph_tpu.osd.pg import PGInstance
+from ceph_tpu.osd.reserver import RemoteReserver
 from ceph_tpu.qa import faultinject
 from ceph_tpu.utils import (copytrack, crash, flight, loopprof, sanitizer,
                             tracer)
@@ -65,8 +67,6 @@ class OSD(Dispatcher):
 
     SCRUB_INTERVAL = 60.0       # osd_scrub_min_interval analog
     DEEP_SCRUB_EVERY = 4        # every Nth scrub round goes deep
-
-    MAX_RECOVERY_IN_FLIGHT = 4  # osd_max_backfills / AsyncReserver slots
 
     PG_PIPELINE_DEPTH = 4       # per-PG execution window (1 = serial)
 
@@ -116,11 +116,17 @@ class OSD(Dispatcher):
                    "reservation pool)", minimum=1),
             Option("osd_op_num_shards", "int", self.NUM_OP_SHARDS,
                    "op queue shards (startup only)", minimum=1),
-            Option("osd_max_recovery_in_flight", "int",
-                   self.MAX_RECOVERY_IN_FLIGHT,
-                   "host-wide recovery reservation slots (hot: resizes "
-                   "the live pool, so recovery pressure can be tuned "
-                   "mid-storm)", minimum=1),
+            Option("osd_max_backfills", "int", 1,
+                   "PGs this daemon recovers or backfills at once as "
+                   "their primary, and as many again that it is the "
+                   "target of (two slot pools, local and remote: a PG "
+                   "pushes only while it holds one of its primary's "
+                   "and one of each target's; hot: resizes both)",
+                   minimum=1),
+            Option("osd_recovery_max_active", "int", 3,
+                   "recovery pushes this daemon has in flight at once, "
+                   "over the PGs that hold their reservations (the "
+                   "reference's default on hdd; hot)", minimum=1),
             Option("osd_pg_pipeline_depth", "int",
                    self.PG_PIPELINE_DEPTH,
                    "max concurrent client ops in the execution slice "
@@ -286,6 +292,19 @@ class OSD(Dispatcher):
         self.perf.add("recovery_bytes_fetched",
                       description="shard bytes fetched by recovery "
                                   "reconstruction gathers")
+        self.perf.add("backfill_reserve_granted",
+                      description="backfill reservations granted to "
+                                  "other primaries (a remote slot each)")
+        self.perf.add("backfill_reserve_rejected",
+                      description="backfill reservations other "
+                                  "primaries asked for and were refused")
+        for role in ("local", "remote"):
+            self.perf.add(f"backfills_{role}", type=TYPE_GAUGE,
+                          description=f"{role} backfill slots held now "
+                                      f"(of osd_max_backfills)")
+            self.perf.add(f"backfills_{role}_peak", type=TYPE_GAUGE,
+                          description=f"the most {role} backfill slots "
+                                      f"held at once")
         self.perf.add("recovery_bytes_full_equiv",
                       description="bytes a full-stripe gather would "
                                   "have fetched for the same repairs "
@@ -490,18 +509,33 @@ class OSD(Dispatcher):
         # (the reference requeues at the front for the same reason)
         self._waiting_for_active: dict[PG, list] = {}
         self._op_seq = 0
+        # peering queries of an epoch this daemon has not reached yet:
+        # (conn, msg), dispatched again when a map arrives
+        self._waiting_for_map: list[tuple] = []
         # strong refs to detached notify tasks (the loop keeps only
         # weak refs; a collected task would drop the notify silently)
         self._notify_tasks: set[asyncio.Task] = set()
-        # host-wide recovery throttle: background pushes across ALL PGs
-        # share these slots so backfill cannot monopolize the daemon
-        # (AsyncReserver, src/common/AsyncReserver.h). Resizable live
-        # via the osd_max_recovery_in_flight config observer so
-        # recovery pressure can be tuned mid-storm.
-        self.recovery_reservations = AdjustableSemaphore(
-            self.config.get("osd_max_recovery_in_flight"))
-        self.config.add_observer(("osd_max_recovery_in_flight",),
-                                 self._on_recovery_slots)
+        # recovery and backfill (doc/dev/osd_internals/
+        # backfill_reservation.rst): a PG pushes only while it holds
+        # one of its primary's `osd_max_backfills` LOCAL slots, waited
+        # for in turn (`pg._drain_recovery`), and one of each target's
+        # REMOTE slots, asked for and granted or refused at once
+        # (`backfill_reserver`); the pushes of the PGs that hold theirs
+        # share `osd_recovery_max_active` slots. All three resize live.
+        self.backfill_local = AdjustableSemaphore(
+            self.config.get("osd_max_backfills"))
+        self.backfill_reserver = RemoteReserver(
+            self, AdjustableSemaphore(self.config.get("osd_max_backfills")),
+            MBackfillReserve, "backfill_remote", of_interval=True,
+            on_decided=self._backfill_decided,
+            on_given_back=lambda _pg, _frm: self.note_backfills())
+        self.recovery_active = AdjustableSemaphore(
+            self.config.get("osd_recovery_max_active"))
+        self._backfills = {"local": 0, "remote": 0}     # slots held now
+        self._backfill_peaks = dict(self._backfills)
+        self.config.add_observer(
+            ("osd_max_backfills", "osd_recovery_max_active"),
+            self._on_recovery_limits)
         # host-wide scrub slots (osd_max_scrubs): a round — primary- or
         # replica-side — holds one for its whole duration, taken only
         # when free (`try_acquire`: nobody parks here). Named, so when
@@ -511,8 +545,13 @@ class OSD(Dispatcher):
             name=f"osd.{self.whoami}:scrub_reservations")
         self.scrub_reservations.lockdep_detail = {
             "entity": f"osd.{self.whoami}"}
-        # remote grants held for other primaries: (pool, ps, tid, from)
-        self._scrub_remote_grants: set[tuple] = set()
+        # the same slots as other primaries' rounds are granted them;
+        # one given back lets this daemon's own scheduler have its turn
+        self.scrub_reserver = RemoteReserver(
+            self, self.scrub_reservations, MOSDScrubReserve,
+            "scrub_reservations",
+            on_given_back=lambda pg, primary: self.scrub_slot_freed(
+                scrub_mod.turn_hold(pg, primary)))
         # the primaries' PGs in the order they are due, served one
         # round at a time by `_scrub_loop`; `_scrub_kick` wakes it for
         # an operator's request or a slot given back
@@ -854,10 +893,32 @@ class OSD(Dispatcher):
         else:
             self._run_on_loop(self._apply_qos_knobs)
 
-    def _on_recovery_slots(self, name: str, value) -> None:
-        """osd_max_recovery_in_flight observer: resize the live slot
-        pool."""
-        self._run_on_loop(self.recovery_reservations.resize, int(value))
+    def _on_recovery_limits(self, name: str, value) -> None:
+        """osd_max_backfills / osd_recovery_max_active observer: resize
+        the live slot pools. What is held stays held; a pool that
+        shrank refills to its new limit as holders let go."""
+        pools = (self.recovery_active,) \
+            if name == "osd_recovery_max_active" \
+            else (self.backfill_local, self.backfill_reserver.slots)
+        for pool in pools:
+            self._run_on_loop(pool.resize, int(value))
+
+    def _backfill_decided(self, granted: bool) -> None:
+        self.perf.inc("backfill_reserve_granted" if granted
+                      else "backfill_reserve_rejected")
+        self.note_backfills()
+
+    def note_backfills(self, local: int = 0) -> None:
+        """The gauges `backfills_local` / `backfills_remote` and their
+        peaks: the PGs whose drains hold a local slot (`local`: one
+        more, or one fewer), the grants other primaries hold here."""
+        self._backfills["local"] += local
+        self._backfills["remote"] = len(self.backfill_reserver.grants)
+        for role, n in self._backfills.items():
+            self.perf.set(f"backfills_{role}", n)
+            if n > self._backfill_peaks[role]:
+                self._backfill_peaks[role] = n
+                self.perf.set(f"backfills_{role}_peak", n)
 
     def _on_scrub_slots(self, name: str, value) -> None:
         """osd_max_scrubs observer: resize the live scrub slot pool."""
@@ -1211,7 +1272,7 @@ class OSD(Dispatcher):
             self._notify_tasks.clear()
             for pg in self.pgs.values():
                 pg._cancel_peering()
-                pg.backend.fail_inflight("osd stopping")
+                pg.backend.fail_inflight("osd stopping", reads=True)
             for waiting in self._waiting_for_active.values():
                 for _, _, _, trk in waiting:
                     trk.finish()
@@ -1277,7 +1338,14 @@ class OSD(Dispatcher):
         for peer in list(self._conns):
             if not self.osdmap.is_up(peer):
                 self._drop_conn(peer)
+        # a requester the map marked down sends no release
+        for reserver in (self.scrub_reserver, self.backfill_reserver):
+            reserver.drop(lambda key: not self.osdmap.is_up(key[3]))
+        self.note_backfills()
         self._advance_pgs()
+        parked, self._waiting_for_map = self._waiting_for_map, []
+        for conn, msg in parked:        # back through the front door
+            await self.ms_dispatch(conn, msg)
 
     def _same_addr(self, addr) -> bool:
         if self.addr is None:
@@ -1467,7 +1535,14 @@ class OSD(Dispatcher):
                                       msg.payload["from"])
             return True
         if isinstance(msg, MOSDPGQuery):
-            pg = self._pg_of(msg)
+            if msg.payload.get("epoch", 0) > self.osdmap.epoch:
+                # the primary peers under a map this daemon has not
+                # seen: answered now, a new member would have no PG to
+                # answer for and the primary would wait out
+                # PEER_TIMEOUT; it is answered when the map is here
+                self._waiting_for_map.append((conn, msg))
+                return True
+            pg = self._pg_of(msg, create=True)
             if pg is not None:
                 await pg.handle_query(conn, msg)
             return True
@@ -1501,10 +1576,16 @@ class OSD(Dispatcher):
             if pg is not None:
                 pg.handle_scrub_map(msg)
             return True
-        if isinstance(msg, MOSDScrubReserve):
+        if isinstance(msg, (MOSDScrubReserve, MBackfillReserve)):
             pg = self._pg_of(msg, create=True)
             if pg is not None:
-                answer = scrub_mod.handle_scrub_reserve(self, pg, msg)
+                if isinstance(msg, MOSDScrubReserve):
+                    answer = scrub_mod.handle_scrub_reserve(self, pg, msg)
+                else:
+                    with tracer.section("osd.recovery"):
+                        answer = self.backfill_reserver.handle(pg, msg)
+                    if answer is not None:
+                        answer = self._backfill_answer(answer)
                 if answer is not None:
                     # the slot is decided; the answer's way out is not
                     # this connection's dispatch loop's to wait for
@@ -1539,6 +1620,14 @@ class OSD(Dispatcher):
                 pg.backend.handle_sub_op_reply(msg)
             return True
         return False
+
+    @staticmethod
+    async def _backfill_answer(answer) -> None:
+        """A backfill reservation's answer on its way out, under a name
+        of its own: the loop account charges a task by its coroutine's
+        code, and this one is `osd.recovery`'s (`loopprof.LABEL_OF_PATH`)
+        where a scrub reservation's stays with the OSD's other work."""
+        await answer
 
     def _pg_of(self, msg: Message, create: bool = False) -> PGInstance | None:
         pool_id, ps = msg.payload["pgid"]

@@ -625,6 +625,30 @@ class ECBackend(PGBackend):
         txn.clone(cid, gh, pgh)
         self.host.store.queue_transaction(txn)
 
+    def set_aside_misplaced(self, position: int) -> list[str]:
+        """This OSD stands at `position` of the acting set now: every
+        head whose chunk was written for ANOTHER position (CRUSH may
+        hand a surviving member the rank of one that left, where two
+        ranks are refilled at once) becomes the object's rollback
+        generation, and is returned as an object this OSD lacks. The
+        store keys a chunk by object and not by position, so left where
+        it was it would pass for this position's, with a log that says
+        it is up to date; as a rollback generation a gather may still
+        decode from it when nothing newer will do (`_gather_prev_pass`),
+        and recovery rebuilds the position's own chunk over it."""
+        cid, moved = self.coll(), []
+        for oid in self.pg.list_objects():
+            gh = self.ghobject(oid)
+            try:
+                held = int(self.host.store.getattr(cid, gh, "shard"))
+            except (StoreError, ValueError):
+                continue
+            if held != position:
+                self._stash_prev(oid)
+                self.local_apply(oid, "delete", b"")
+                moved.append(oid)
+        return moved
+
     def _apply_sub_write(self, oid: str, shard: int, sub: dict,
                          chunk: bytes) -> None:
         kind = sub["op"]
@@ -773,6 +797,7 @@ class ECBackend(PGBackend):
         rounds = [candidates[:need_first], candidates[need_first:]]
         waits: dict[asyncio.Future, int] = {}
         taken: set = set()  # replies looked at; the rest of `waits` is late
+        self._not_while_stopping()
         deadline = asyncio.get_running_loop().time() + READ_TIMEOUT
 
         async def send_round(batch) -> set:
@@ -996,6 +1021,7 @@ class ECBackend(PGBackend):
         for idx, osd in sorted(self._live_positions().items()):
             if osd == self.host.whoami:
                 continue
+            self._not_while_stopping()
             tid = self.new_tid()
             fut = asyncio.get_running_loop().create_future()
             self._read_waiters[tid] = fut
@@ -1103,6 +1129,22 @@ class ECBackend(PGBackend):
                                            p["oid"]).items()}})
             self.sub_read_bytes_served += len(data)
         conn.send_message(MOSDECSubOpReadReply(payload, data))
+
+    def fail_inflight(self, why: str, reads: bool = False) -> None:
+        super().fail_inflight(why)
+        if reads:
+            # a gather in flight (a recovery's, a read's) would wait out
+            # READ_TIMEOUT for peers that stop beside this daemon
+            for fut in self._read_waiters.values():
+                if not fut.done():
+                    fut.set_exception(IntervalChange(why))
+
+    def _not_while_stopping(self) -> None:
+        """No new gather on a daemon that is stopping: a recovery item
+        that outlived `fail_inflight` would start one and wait for
+        peers that stop beside it (`op_queue.stop` waits for the item)."""
+        if self.host._stopping:
+            raise IntervalChange("osd stopping")
 
     def handle_sub_op_reply(self, msg) -> None:
         p = msg.payload
@@ -1330,8 +1372,9 @@ class ECBackend(PGBackend):
                        f"fetched {fetched}B vs {full_equiv}B full-gather")
         return chunk, attrs
 
-    async def _reconstruct(self, oid: str, idx: int,
-                           exclude: frozenset) -> tuple[bytes, dict] | None:
+    async def _reconstruct(self, oid: str, idx: int, exclude: frozenset,
+                           target: int | None = None
+                           ) -> tuple[bytes, dict] | None:
         """Chunk for position `idx` + its attrs, reconstructed from any k
         version-consistent survivors — INCLUDING the target itself when
         its chunk is crc-valid at the needed version (version attrs keep
@@ -1363,9 +1406,15 @@ class ECBackend(PGBackend):
         if idx in got:
             chunk = got[idx]
         else:
+            # which object is rebuilt for whom, in which interval: an
+            # `ec_recover` span that says so can be told from another
+            # of the same object
             chunk = (await ec_util.decode_shards_async(
                 self.sinfo, self.ec_impl, got, [idx],
-                service=self._offload_svc()))[idx]
+                service=self._offload_svc(),
+                tags={"oid": oid, "pgid": str(self.pg.pgid),
+                      "target": target,
+                      "interval": self.pg.last_epoch_started}))[idx]
         attrs = self._chunk_attrs(idx, ec_size, meta["version"],
                                   self._csums(chunk))
         for name, val in meta.get("uattrs", {}).items():
@@ -1471,7 +1520,8 @@ class ECBackend(PGBackend):
             # holding the newest version must still count toward its
             # decodability, or a partial fan-out looks rollback-worthy
             # when it is not (found by the thrashing model checker)
-            rec = await self._reconstruct(oid, idx, exclude=frozenset())
+            rec = await self._reconstruct(oid, idx, exclude=frozenset(),
+                                          target=peer)
         except StoreError as e:
             if e.code != "ENOENT":
                 raise
@@ -1496,7 +1546,8 @@ class ECBackend(PGBackend):
             self.local_apply(oid, "delete", b"")
             return
         try:
-            rec = await self._reconstruct(oid, me, exclude=frozenset())
+            rec = await self._reconstruct(oid, me, exclude=frozenset(),
+                                          target=self.host.whoami)
         except StoreError as e:
             if e.code != "ENOENT":
                 raise

@@ -487,7 +487,8 @@ async def decode_shards_async(sinfo: StripeInfo, ec_impl,
                               to_decode: Mapping[int, bytes],
                               need: Iterable[int],
                               service=None,
-                              fragments: bool = False) -> dict[int, bytes]:
+                              fragments: bool = False,
+                              tags: dict | None = None) -> dict[int, bytes]:
     """decode_shards() with the repair dispatch routed through the
     offload service. Whole-chunk plans on batch-capable plugins ride
     the DecodeJob (n, k, C) shape; single-shard SUB-CHUNK plans (the
@@ -495,7 +496,9 @@ async def decode_shards_async(sinfo: StripeInfo, ec_impl,
     repair_per_chunk bytes per helper chunk — declared by
     `fragments=True`) ride the service's repair job — coalesced per
     erasure pattern and run off the event loop. Mapped plugins and
-    multi-shard sub-chunk plans keep the inline path."""
+    multi-shard sub-chunk plans keep the inline path. `tags` go on the
+    `ec_recover` span, whichever path opens it (recovery says which
+    object it rebuilds, for whom)."""
     need_l = sorted(set(need))
     if (fragments and service is not None and len(need_l) == 1
             and ec_impl.get_sub_chunk_count() > 1
@@ -506,6 +509,7 @@ async def decode_shards_async(sinfo: StripeInfo, ec_impl,
         if n_chunks > 0 and rpc < sinfo.chunk_size:
             with tracer.span("ec_recover") as sp:
                 if sp is not None:
+                    sp.tags.update(tags or {})
                     sp.set_tag("need", need_l)
                     sp.set_tag("helpers", helpers)
                     sp.set_tag("chunks", n_chunks)
@@ -525,14 +529,15 @@ async def decode_shards_async(sinfo: StripeInfo, ec_impl,
             and not ec_impl.get_chunk_mapping()
             and callable(getattr(ec_impl, "decode_stripes", None))):
         return decode_shards(sinfo, ec_impl, to_decode, need_l,
-                             fragments=fragments)
+                             fragments=fragments, tags=tags)
     arrays, helpers, _plan, _sub, _rpc, n_chunks = _decode_shards_frame(
         sinfo, ec_impl, to_decode, need_l)
     if n_chunks == 0:
         return decode_shards(sinfo, ec_impl, to_decode, need_l,
-                             fragments=fragments)
+                             fragments=fragments, tags=tags)
     with tracer.span("ec_recover") as sp:
         if sp is not None:
+            sp.tags.update(tags or {})
             sp.set_tag("need", need_l)
             sp.set_tag("helpers", helpers)
             sp.set_tag("chunks", n_chunks)
@@ -549,7 +554,8 @@ async def decode_shards_async(sinfo: StripeInfo, ec_impl,
 
 def decode_shards(sinfo: StripeInfo, ec_impl, to_decode: Mapping[int, bytes],
                   need: Iterable[int],
-                  fragments: bool = False) -> dict[int, bytes]:
+                  fragments: bool = False,
+                  tags: dict | None = None) -> dict[int, bytes]:
     """Reconstruct whole shards (data or parity) — the per-shard
     ECUtil::decode variant (ECUtil.cc:61-131) used by shard recovery.
 
@@ -566,6 +572,7 @@ def decode_shards(sinfo: StripeInfo, ec_impl, to_decode: Mapping[int, bytes],
 
     with tracer.span("ec_recover") as sp:
         if sp is not None:
+            sp.tags.update(tags or {})
             sp.set_tag("need", need)
             sp.set_tag("helpers", helpers)
             sp.set_tag("chunks", n_chunks)

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import asyncio
 import json
+import time
 from typing import TYPE_CHECKING
 
 from ceph_tpu.crush.crush import CRUSH_NONE
@@ -34,6 +35,7 @@ from ceph_tpu.msg.messages import (Message, MOSDPGInfo, MOSDPGLog,
 from ceph_tpu.objectstore.store import StoreError, Transaction
 from ceph_tpu.objectstore.types import CollectionId, Ghobject
 from ceph_tpu.osd.pglog import ZERO, Eversion, LogEntry, PGLog
+from ceph_tpu.osd.reserver import retry_delay
 from ceph_tpu.qa import interleave
 from ceph_tpu.utils import tracer
 from ceph_tpu.utils.dout import dout
@@ -44,6 +46,9 @@ if TYPE_CHECKING:
 
 PEER_TIMEOUT = 5.0
 PGMETA_OID = "_pgmeta_"
+#: how long a PG whose backfill target had no slot free waits before it
+#: asks again, stretched by up to as much again (`reserver.retry_delay`)
+RECOVERY_RETRY_S = 0.3
 
 
 class PeerSilent(Exception):
@@ -83,6 +88,9 @@ class PGInstance:
         self._deferred_activate: dict[int, dict] = {}
         self._recovery_inflight: dict[str, asyncio.Future] = {}
         self._recovery_task: asyncio.Task | None = None
+        # objects and shard bytes this primary has pushed, ever: a
+        # reserved round reports what it added (`backfill_done`)
+        self.recovery_pushed = [0, 0]
         # scrub: (tid, peer) -> future resolving to the peer's scrub map
         self._scrub_waiters: dict[tuple, asyncio.Future] = {}
         # scrub reservations: (tid, peer) -> future resolving True on
@@ -338,6 +346,11 @@ class PGInstance:
         self.up, self.acting = list(up), list(acting)
         if interval_changed:
             self.backend.fail_inflight("peering interval change")
+            # a backfill slot granted to this PG's primary was that
+            # interval's: it asks again in the new one, if it still is
+            if self.host.backfill_reserver.drop(
+                    lambda key: key[:2] == (self.pgid.pool, self.pgid.ps)):
+                self.host.note_backfills()
         self._cancel_peering()
         if self.host.whoami not in self.acting:
             self.state = "stray"
@@ -425,7 +438,7 @@ class PGInstance:
             self._peer_waiters[peer] = fut
             await self.host.send_osd(peer, MOSDPGQuery(
                 {"pgid": pgid_key, "from": self.host.whoami,
-                 "epoch": epoch}))
+                 "epoch": epoch, "position": self.acting.index(peer)}))
             waits.append((peer, fut))
         silent: list[int] = []
         for peer, fut in waits:
@@ -438,6 +451,9 @@ class PGInstance:
                 self._peer_waiters.pop(peer, None)
         if silent:
             raise PeerSilent(f"acting peers {silent} silent during peering")
+        # what this OSD holds for another position than its own is
+        # missing here, and is pulled below like anything else that is
+        self._note_misplaced(self.acting.index(self.host.whoami))
 
         # find_best_info: max last_update wins (self is a candidate)
         auth_osd, auth_head = self.host.whoami, self.log.head
@@ -483,6 +499,16 @@ class PGInstance:
             raise PeerSilent(
                 f"missing {len(real_missing)} objects with no pull "
                 f"source (sole survivor)")
+        # An erasure pool's primary serves without its own chunk (a
+        # read gathers k others, a write_full lays every chunk anew), so
+        # what it lacks is rebuilt in the background like a behind
+        # peer's, under the same reservations, and at once for an op
+        # that touches the object (`_do_op`): a new OSD that
+        # takes position 0 does not hold its PG's ops back while it
+        # rebuilds every object (the reference keeps the old primary by
+        # pg_temp until the backfill ends). A replicated primary serves
+        # from its own copy and pulls first, as before.
+        own_later = self.pool.type == "erasure"
         for oid, need in list(self.log.missing.items()):
             if tuple(need) == ZERO:
                 # rewind-to-none tombstone: the authoritative history
@@ -490,11 +516,13 @@ class PGInstance:
                 # shards (or their rollback generations) would resurrect
                 # an acked delete (found by the thrashing model checker)
                 self.backend.local_apply(oid, "delete", b"")
+            elif own_later:
+                continue
             else:
                 await self.backend.pull_object(
                     source, oid, need,
                     fallbacks=[p for p in sorted(replies) if p != source])
-        self.log.clear_missing()
+            self.log.mark_recovered(oid)
 
         # Activate: up-to-date replicas immediately; behind replicas get
         # a persisted `recovering` marker and their pushes run in the
@@ -503,7 +531,8 @@ class PGInstance:
         # with AsyncReserver; activation per peer when its data is in)
         log_dict = self.log.to_dict()
         my_objects = None
-        pending: dict[str, set[int]] = {}
+        pending: dict[str, set[int]] = {
+            oid: {self.host.whoami} for oid in self.log.missing}
         deferred: dict[int, dict] = {}
         for peer, rep in replies.items():
             peer_head = tuple(rep["info"]["last_update"])
@@ -516,12 +545,17 @@ class PGInstance:
                 # ship the authoritative object list so the replica can
                 # drop strays (deletes it missed past the log window
                 # would otherwise resurrect if it later became primary)
-                if my_objects is None:
-                    my_objects = self.recovery_objects()
+                if my_objects is None:     # what it holds, and is owed
+                    my_objects = sorted({*self.recovery_objects(),
+                                         *self.log.missing})
                 need_oids = list(my_objects)
                 act_payload["objects"] = my_objects
             else:
-                need_oids = sorted({e.oid for e in entries})
+                # what the log says it missed, and what it says itself
+                # that it lacks (pushes an earlier interval did not
+                # reach, chunks of a position it no longer stands at)
+                need_oids = sorted({e.oid for e in entries}
+                                   | set(rep.get("missing", ())))
             if not need_oids:
                 await self.host.send_osd(peer, MOSDPGInfo(act_payload))
                 continue
@@ -562,43 +596,39 @@ class PGInstance:
     # -- async recovery / backfill (primary side) ----------------------------
 
     async def _drain_recovery(self) -> None:
-        """Push pending objects to behind peers under the host's
-        recovery reservations; activate each peer once its set drains
-        (AsyncReserver semantics, doc/dev/osd_internals/
-        backfill_reservation.rst)."""
+        """Push pending objects to behind peers, held to the reference's
+        reservations (doc/dev/osd_internals/backfill_reservation.rst),
+        log-based recovery and backfill alike: the PG pushes only while
+        it holds one of this daemon's `osd_max_backfills` local slots
+        and one of each target's remote ones (`_reserve_recovery`), up
+        to `osd_recovery_max_active` objects in flight on this daemon
+        (`_push_pending`), and lets all of them go when nothing is
+        pending, when a push failed for good, or when the interval
+        changes under it (`_cancel_peering` cancels this task). Each
+        peer is activated once its set has drained."""
         try:
             while self._pending_recovery:
-                oid = next(iter(self._pending_recovery))
-                # reservation first (host-wide slot), THEN the op queue's
-                # recovery class: the shard worker must never block on a
-                # slot held by another PG's backfill
-                await self.host.recovery_reservations.acquire()
-                done = asyncio.get_running_loop().create_future()
-
-                async def work(oid=oid, done=done):
-                    try:
-                        await self.recover_object_now(oid)
-                    finally:
-                        self.host.recovery_reservations.release()
-                        if not done.done():
-                            done.set_result(None)
-                # obj=oid: the recovery item admits through the PG's
-                # pipelined window alongside client ops to OTHER
-                # objects, but serializes FIFO against any client op
-                # touching the object being rebuilt
-                # nbytes: a push moves whole shard chunks, so bill the
-                # recovery entity one full per-IO byte budget (~2 cost
-                # units) rather than metering the exact object size —
-                # the tag clocks need relative pressure, not a ledger
-                self.host.op_queue.enqueue(
-                    (self.pgid.pool, self.pgid.ps), work,
-                    klass="recovery", obj=oid,
-                    nbytes=self.host.op_queue.sched.cost_per_io_bytes)
-                await done
-                if oid in self._pending_recovery:
-                    # push failed and was re-queued: back off instead of
-                    # hammering an unreachable peer
-                    await asyncio.sleep(0.3)
+                tid, targets = await self._reserve_recovery()
+                t0, before = time.perf_counter(), list(self.recovery_pushed)
+                state = "aborted"
+                try:
+                    await self._push_pending()
+                    state = "done"
+                except asyncio.CancelledError:
+                    state = "interval_change"
+                    raise
+                finally:
+                    with tracer.span("backfill_done",
+                                     f"osd.{self.host.whoami}") as sp:
+                        if sp is not None:
+                            sp.tags.update(
+                                pgid=str(self.pgid), target=targets,
+                                objects=self.recovery_pushed[0] - before[0],
+                                bytes=self.recovery_pushed[1] - before[1],
+                                held_us=round(
+                                    (time.perf_counter() - t0) * 1e6, 1),
+                                state=state)
+                    await self._release_recovery(tid, targets)
             await self._activate_recovered()
         except asyncio.CancelledError:
             raise
@@ -606,6 +636,130 @@ class PGInstance:
             dout("osd", 1, f"pg {self.pgid} background recovery failed: "
                            f"{type(e).__name__} {e} (interval change "
                            f"will retry)")
+
+    async def _reserve_recovery(self) -> tuple[int, list[int]]:
+        """Hold a local slot and a remote one on every peer that is
+        owed a push; returns the round's tid and those peers (what
+        this primary lacks itself needs the local slot alone). The local
+        slot is waited for in turn; a target is asked and answers at
+        once (`osd/reserver.py`), the lowest id first and one at a time.
+        One that refuses, or does not answer within PEER_TIMEOUT, costs
+        the round everything it holds, the local slot too, so that
+        another PG of this daemon may try its own targets meanwhile;
+        the PG asks again RECOVERY_RETRY_S or up to twice that later. One
+        `backfill_reserve` span from the first request to the grant or
+        the giving up: `local_us` waiting for this daemon's slot,
+        `remote_us` everything after it (answers, and the waits
+        between attempts)."""
+        host = self.host
+        waited = {"local": 0.0, "remote": 0.0}
+        rejects, state, targets = 0, "aborted", []
+        with tracer.span("backfill_reserve", f"osd.{host.whoami}") as sp:
+            try:
+                while True:
+                    # anew each time: a client's write may have
+                    # recovered a peer's last object meanwhile
+                    targets = sorted({p for peers in
+                                      self._pending_recovery.values()
+                                      for p in peers} - {host.whoami})
+                    t0 = time.perf_counter()
+                    await host.backfill_local.acquire()
+                    host.note_backfills(+1)
+                    t1 = time.perf_counter()
+                    waited["local"] += t1 - t0
+                    tid, asked = self.backend.new_tid(), []
+                    try:
+                        for osd in targets:
+                            why = await host.backfill_reserver.ask(
+                                self, tid, osd, PEER_TIMEOUT, asked)
+                            if why is not None:
+                                break
+                        else:
+                            state = "granted"
+                            return tid, asked
+                    finally:        # refused, or cancelled while asking
+                        if state != "granted":
+                            await self._release_recovery(tid, asked)
+                    rejects += 1
+                    dout("osd", 4, f"pg {self.pgid} backfill reservation "
+                                   f"on osd.{osd} failed ({why}): asking "
+                                   f"again")
+                    await asyncio.sleep(retry_delay(
+                        RECOVERY_RETRY_S,
+                        self.pgid.pool * 65599 + self.pgid.ps, rejects))
+                    waited["remote"] += time.perf_counter() - t1
+            except asyncio.CancelledError:
+                state = "interval_change"
+                raise
+            finally:
+                if state == "granted":
+                    waited["remote"] += time.perf_counter() - t1
+                if sp is not None:
+                    kind = "backfill" if any(
+                        self._deferred_activate.get(p, {}).get("backfill")
+                        for p in targets) else "log"
+                    sp.tags.update(
+                        pgid=str(self.pgid), target=targets, kind=kind,
+                        local_us=round(waited["local"] * 1e6, 1),
+                        remote_us=round(waited["remote"] * 1e6, 1),
+                        rejects=rejects, state=state)
+
+    async def _release_recovery(self, tid: int, granted: list[int]) -> None:
+        """Give back this daemon's local slot and the targets' grants of
+        round `tid`. A daemon that is stopping tells nobody: its peers
+        drop what they hold for it when the map says it is down."""
+        host = self.host
+        host.backfill_local.release()
+        host.note_backfills(-1)
+        if not host._stopping:
+            await host.backfill_reserver.release(
+                self, tid, [o for o in granted if host.osdmap.is_up(o)])
+
+    async def _push_pending(self) -> None:
+        """Recover what is pending, each object as an item of the op
+        queue's `recovery` class that holds one of this daemon's
+        `osd_recovery_max_active` slots while it runs. An object whose
+        push failed stays pending and is tried again after a pause; one
+        that a client's write recovered meanwhile is gone from the set
+        when its turn comes."""
+        host = self.host
+        flying: dict[str, asyncio.Future] = {}
+        while self._pending_recovery or flying:
+            oid = next((o for o in self._pending_recovery
+                        if o not in flying), None)
+            if oid is None:
+                await asyncio.wait(flying.values(),
+                                   return_when=asyncio.FIRST_COMPLETED)
+                failed = [o for o, f in flying.items()
+                          if f.done() and o in self._pending_recovery]
+                flying = {o: f for o, f in flying.items() if not f.done()}
+                if failed:
+                    # back off instead of hammering an unreachable peer
+                    await asyncio.sleep(0.3)
+                continue
+            await host.recovery_active.acquire()
+            done = asyncio.get_running_loop().create_future()
+
+            async def work(oid=oid, done=done):
+                try:
+                    await self.recover_object_now(oid)
+                finally:
+                    host.recovery_active.release()
+                    if not done.done():
+                        done.set_result(None)
+            # obj=oid: the recovery item admits through the PG's
+            # pipelined window alongside client ops to OTHER
+            # objects, but serializes FIFO against any client op
+            # touching the object being rebuilt
+            # nbytes: a push moves whole shard chunks, so bill the
+            # recovery entity one full per-IO byte budget (~2 cost
+            # units) rather than metering the exact object size —
+            # the tag clocks need relative pressure, not a ledger
+            host.op_queue.enqueue(
+                (self.pgid.pool, self.pgid.ps), work,
+                klass="recovery", obj=oid,
+                nbytes=host.op_queue.sched.cost_per_io_bytes)
+            flying[oid] = done
 
     async def recover_object_now(self, oid: str) -> None:
         """Recover one object to every behind peer NOW — also called by
@@ -617,7 +771,8 @@ class PGInstance:
         if inflight is not None:
             await asyncio.shield(inflight)
             return
-        peers = self._pending_recovery.pop(oid, None)
+        peers = None if self.host._stopping \
+            else self._pending_recovery.pop(oid, None)
         if not peers:
             return
         fut = asyncio.get_running_loop().create_future()
@@ -626,8 +781,14 @@ class PGInstance:
         try:
             for peer in sorted(peers):
                 try:
-                    await self.backend.push_object(peer, oid)
+                    if peer == self.host.whoami:    # this primary's own
+                        await self.backend.pull_object(
+                            peer, oid, self.log.missing.get(oid))
+                        self.log.mark_recovered(oid)
+                    else:
+                        await self.backend.push_object(peer, oid)
                     self.host.perf.inc("recovery_push")
+                    self.recovery_pushed[0] += 1
                 except Exception as e:
                     dout("osd", 3, f"recovery push of {oid} to osd.{peer} "
                                    f"failed: {type(e).__name__} {e}")
@@ -645,6 +806,7 @@ class PGInstance:
                 fut.set_result(None)
 
     async def _activate_recovered(self) -> None:
+        self.persist_meta()     # this primary's own missing set is empty
         deferred, self._deferred_activate = self._deferred_activate, {}
         log_dict = self.log.to_dict()
         for peer, shape in deferred.items():
@@ -736,6 +898,7 @@ class PGInstance:
             # recovery-bandwidth observability: the failure-storm bench
             # derives recovery MB/s from this counter's delta
             self.host.perf.inc("recovery_bytes_pushed", len(data))
+            self.recovery_pushed[1] += len(data)
         await self.host.send_osd(peer, MOSDPGPush(payload, data))
 
     # -- peering message handlers (both roles) -------------------------------
@@ -744,12 +907,29 @@ class PGInstance:
         """A primary wants our info + log (GetInfo+GetLog combined);
         `want: objects` additionally returns the collection listing (the
         backfill scan)."""
+        if msg.payload.get("position") is not None:
+            self._note_misplaced(msg.payload["position"])
         payload = {"pgid": [self.pgid.pool, self.pgid.ps],
                    "from": self.host.whoami, "info": self.info(),
-                   "entries": [e.to_dict() for e in self.log.entries]}
+                   "entries": [e.to_dict() for e in self.log.entries],
+                   "missing": sorted(self.log.missing)}
         if msg.payload.get("want") == "objects":
             payload["objects"] = self.recovery_objects()
         conn.send_message(MOSDPGLog(payload))
+
+    def _note_misplaced(self, position: int) -> None:
+        """This OSD stands at `position` of the acting set: chunks it
+        holds for another are set aside and recorded as missing, so
+        that a primary pulls them before it serves and a replica says
+        so when it is asked (`ECBackend.set_aside_misplaced`)."""
+        moved = self.backend.set_aside_misplaced(position)
+        if moved:
+            for oid in moved:
+                self.log.missing[oid] = self.log.head
+            self.persist_meta()
+            dout("osd", 2, f"osd.{self.host.whoami} pg {self.pgid}: "
+                           f"{len(moved)} chunks of another position "
+                           f"than {position} set aside")
 
     def handle_log(self, msg: MOSDPGLog) -> None:
         peer = msg.payload["from"]
@@ -1016,6 +1196,11 @@ class PGInstance:
         mark_op_event("started")
         oid = op["oid"]
         kind = op["op"]
+        if oid in self.log.missing:
+            # this primary lacks its own chunk of the object: rebuild it
+            # now, before any op reads attrs or state beside the data
+            # (the reference's wait_for_unreadable_object)
+            await self.recover_object_now(oid)
         if self.pool.type == "erasure" and kind in self.EC_UNSUPPORTED:
             return -95, {"error": f"EOPNOTSUPP: {kind} on an ec pool"}, b""
 

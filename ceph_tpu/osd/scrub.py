@@ -86,10 +86,10 @@ from typing import TYPE_CHECKING
 
 import numpy as np
 
-from ceph_tpu.msg.messages import (MOSDRepScrub, MOSDRepScrubMap,
-                                   MOSDScrubReserve)
+from ceph_tpu.msg.messages import MOSDRepScrub, MOSDRepScrubMap
 from ceph_tpu.objectstore.store import StoreError
-from ceph_tpu.utils import flight, sanitizer, tracer
+from ceph_tpu.osd.reserver import retry_delay as _stretched
+from ceph_tpu.utils import flight, tracer
 from ceph_tpu.utils.dout import dout
 from ceph_tpu.utils.perf_counters import (TYPE_HISTOGRAM,
                                           PerfCountersCollection)
@@ -468,11 +468,6 @@ def _note_repaired(pg: "PGInstance", oid: str, osd: int, ok: bool,
         entry["repaired"] = True
 
 
-def _drawn(who: int, attempt: int) -> float:
-    """A share in [0, 1) that only `who` and `attempt` decide."""
-    return (who * 2654435761 + attempt * 40503) % 1021 / 1021.0
-
-
 def turn_hold(pg: "PGInstance", primary: int) -> float:
     """How long this daemon holds its own next round back when a round
     of `primary` on `pg` has given its slot back: SCRUB_TURN_S for
@@ -493,10 +488,9 @@ def turn_hold(pg: "PGInstance", primary: int) -> float:
 
 
 def retry_delay(who: int, attempt: int) -> float:
-    """SCRUB_RETRY_S stretched by up to as much again. The stretch is
-    drawn from `who` waits (a PG) and from the attempt and from nothing
-    else, so that a schedule explorer's replays see the same delays."""
-    return SCRUB_RETRY_S * (1.0 + _drawn(who, attempt))
+    """SCRUB_RETRY_S stretched by up to as much again
+    (`reserver.retry_delay`, which backfill shares)."""
+    return _stretched(SCRUB_RETRY_S, who, attempt)
 
 
 class ScrubQueue:
@@ -595,7 +589,8 @@ async def _reserve_acting_set(pg: "PGInstance",
     """Claim one `osd_max_scrubs` slot on every up member of the acting
     set, this daemon among them, before the round may gate client
     writes (the reference's scrub reserver: OSD::sched_scrub +
-    MOSDScrubReserve). The member with the lowest id is asked first
+    MOSDScrubReserve; the wire is `osd/reserver.py`'s, which backfill
+    shares). The member with the lowest id is asked first
     and alone, the others at once when it has granted; this daemon's
     own slot is taken, not asked for, at its place in that order. And
     nobody waits for a slot: a daemon whose slots are taken says so at
@@ -605,13 +600,10 @@ async def _reserve_acting_set(pg: "PGInstance",
     every PG spans every OSD, as on the benchmark's pool, the losers
     are turned away by the first daemon they ask and the winner is
     never crossed. The one wait left is for a peer's answer, bounded by
-    `osd_scrub_reserve_timeout`; while it lasts it is registered with
-    lockdep under the PEER's slot name, the inter-OSD edge that the
-    in-process watchdog and the mgr's cross-daemon wait-for graph
-    report for a peer that has gone quiet."""
+    `osd_scrub_reserve_timeout`."""
     host = pg.host
-    sem = getattr(host, "scrub_reservations", None)
-    if sem is None:
+    reserver = getattr(host, "scrub_reserver", None)
+    if reserver is None:
         return True, []
     timeout = float(_cfg(pg, "osd_scrub_reserve_timeout", 10.0))
     me = host.whoami
@@ -622,32 +614,9 @@ async def _reserve_acting_set(pg: "PGInstance",
         """None: `osd` holds a slot for this round. Else why not."""
         if osd == me:
             nonlocal mine
-            mine = sem.try_acquire()
+            mine = reserver.slots.try_acquire()
             return None if mine else "rejected"
-        fut = asyncio.get_running_loop().create_future()
-        pg._reserve_waiters[(tid, osd)] = fut
-        token = sanitizer.lockdep_wait_start(
-            f"osd.{osd}:scrub_reservations", kind="remote_reserve",
-            entity=f"osd.{me}", peer=osd, tid=tid, pgid=str(pg.pgid))
-        try:
-            await host.send_osd(osd, MOSDScrubReserve(
-                {"pgid": [pg.pgid.pool, pg.pgid.ps], "tid": tid,
-                 "from": me, "op": "reserve"}))
-            # from here the peer may grant, heard or not: it gets a
-            # release whatever happens (one it holds nothing for is
-            # ignored there)
-            asked.append(osd)
-            if await asyncio.wait_for(fut, timeout):
-                return None
-            asked.remove(osd)           # it said no: it holds nothing
-            return "rejected"
-        except asyncio.TimeoutError:
-            return "timeout"
-        except Exception as e:
-            return f"{type(e).__name__}: {e}"
-        finally:
-            sanitizer.lockdep_wait_end(token)
-            pg._reserve_waiters.pop((tid, osd), None)
+        return await reserver.ask(pg, tid, osd, timeout, asked)
 
     members = sorted({me, *(o for o in pg.acting_peers()
                             if host.osdmap.is_up(o))})
@@ -684,90 +653,26 @@ async def _release_acting_set(pg: "PGInstance", tid: int, mine: bool,
                               granted: list[int]) -> None:
     """Return the local slot (if `mine`) and every remote grant of this
     round."""
-    host = pg.host
     if mine:
-        host.scrub_reservations.release()
-    interrupted: asyncio.CancelledError | None = None
-    for peer in granted:
-        try:
-            await host.send_osd(peer, MOSDScrubReserve(
-                {"pgid": [pg.pgid.pool, pg.pgid.ps], "tid": tid,
-                 "from": host.whoami, "op": "release"}))
-        # deferred re-raise below: every granted peer must get its
-        # release even when this round is being cancelled, or the
-        # peer's slot stays taken for good
-        # radoslint: disable-next=cancellation-swallow
-        except asyncio.CancelledError as e:
-            interrupted = e
-        except Exception as e:
-            dout("scrub", 2,
-                 f"scrub reserve release to osd.{peer} failed: {e}")
-    if interrupted is not None:
-        raise interrupted
+        pg.host.scrub_reserver.slots.release()
+    await pg.host.scrub_reserver.release(pg, tid, granted)
 
 
 def handle_scrub_reserve(host, pg: "PGInstance", msg):
-    """Both halves of the reservation wire protocol, decided where the
-    message is dispatched, so in the order the messages came: a release
-    that follows its reserve on the wire finds the grant.
-
-    Replica (`op=reserve`): take a local slot on the requesting
-    primary's behalf if one is free; the answer, grant or reject, is
-    owed at once and is returned as a coroutine for the caller to run.
-
-    Primary (`op=grant|reject`): resolve the round's waiter. An answer
-    with no waiter comes from a peer the round stopped waiting for,
-    which has been sent its release already.
-
-    Anyone (`op=release`): free a slot previously granted to this
-    requester (`give_back`): what a round that ended early sends; one
-    that ran to its end lets its last request say so."""
-    p = msg.payload
-    op, tid, frm = p.get("op"), p.get("tid"), p.get("from")
-    key = (pg.pgid.pool, pg.pgid.ps, tid, frm)
-    sem = getattr(host, "scrub_reservations", None)
-    if op == "reserve":
-        granted = sem is None or sem.try_acquire()
-        if granted and sem is not None:
-            host._scrub_remote_grants.add(key)
-        return _answer_reserve(host, pg, key, granted)
-    if op in ("grant", "reject"):
-        fut = pg._reserve_waiters.get((tid, frm))
-        if fut is not None and not fut.done():
-            fut.set_result(op == "grant")
-    elif op == "release":
-        give_back(pg, tid, frm)
-    return None
+    """An MOSDScrubReserve, decided where it is dispatched
+    (`RemoteReserver.handle`): a `reserve` returns the coroutine that
+    answers it, for the caller to run; `grant`, `reject` and `release`
+    return None."""
+    return host.scrub_reserver.handle(pg, msg)
 
 
 def give_back(pg: "PGInstance", tid: int, primary: int) -> None:
     """Free the slot this daemon holds for round `tid` of `primary` on
-    `pg`, if it holds one, and let its own scheduler have its turn."""
-    host = pg.host
-    key = (pg.pgid.pool, pg.pgid.ps, tid, primary)
-    if key in host._scrub_remote_grants:
-        host._scrub_remote_grants.discard(key)
-        host.scrub_reservations.release()
-        host.scrub_slot_freed(turn_hold(pg, primary))
-
-
-async def _answer_reserve(host, pg: "PGInstance", key: tuple,
-                          granted: bool) -> None:
-    _pool, _ps, tid, frm = key
-    try:
-        await host.send_osd(frm, MOSDScrubReserve(
-            {"pgid": [pg.pgid.pool, pg.pgid.ps], "tid": tid,
-             "from": host.whoami, "op": "grant" if granted else "reject"}))
-    except BaseException as e:
-        # the grant never reached the requester (this task reaped at
-        # daemon stop, or the send failed), so nobody will ever release
-        # it: hand the slot back
-        if key in host._scrub_remote_grants:
-            host._scrub_remote_grants.discard(key)
-            host.scrub_reservations.release()
-        if not isinstance(e, Exception):
-            raise
-        dout("scrub", 2, f"scrub reserve reply to osd.{frm} failed: {e}")
+    `pg`, if it holds one, and let its own scheduler have its turn
+    (`OSD.scrub_reserver`'s `on_given_back`): what a round that ran to
+    its end lets its last request say; one that ended early sends a
+    `release`."""
+    pg.host.scrub_reserver.give_back(pg, tid, primary)
 
 
 async def scrub_pg(pg: "PGInstance", deep: bool) -> dict:
@@ -907,7 +812,7 @@ async def _scrub_locked(pg: "PGInstance", deep: bool,
         t_res = time.perf_counter()
         ok, reserved_peers = await _reserve_acting_set(pg, reserve_tid)
         progress.legs["reserve"] = time.perf_counter() - t_res
-        reserved = ok and getattr(host, "scrub_reservations",
+        reserved = ok and getattr(host, "scrub_reserver",
                                   None) is not None
         if not ok:
             progress.finish("reserve_failed")

@@ -39,7 +39,8 @@ IDLE = "idle"
 #: the labels that are read by part; `other` is charged, never computed
 PARTS = {"msgr": ("rx_sock", "rx_alloc", "rx_frame", "codec", "tx_frame",
                   "tx_sock", "dispatch", "handler", "other"),
-         "osd": ("pg", "ec", "subop", "queue", "scrub", "other")}
+         "osd": ("pg", "ec", "subop", "queue", "scrub", "recovery",
+                 "other")}
 #: what the books are keyed by: every part, the labels that have none
 #: (each its own one part), `idle`
 KEYS = tuple(f"{lab}.{p}" for lab in LABELS for p in PARTS.get(lab, ())
@@ -51,9 +52,9 @@ LABEL_OF_SPAN = {
     "ms_dispatch": "msgr.handler", "rados_op": "client", "aio_op": "client",
     "osd_op": "osd.pg", "pg_op": "osd.pg", "ec_write": "osd.ec",
     "ec_read": "osd.ec", "ec_encode": "osd.ec", "ec_decode": "osd.ec",
-    "ec_recover": "osd.ec", "offload_batch": "offload",
-    "store_commit": "store", "scrub_round": "osd.scrub",
-    "scrub_chunk": "osd.scrub"}
+    "ec_recover": "osd.recovery", "backfill_reserve": "osd.recovery",
+    "offload_batch": "offload", "store_commit": "store",
+    "scrub_round": "osd.scrub", "scrub_chunk": "osd.scrub"}
 #: `ms_dispatch` covers the handler: the receiving daemon's, where known
 #: (on an OSD: a shard serving a sub-op, the primary taking its replies)
 LABEL_OF_SERVICE = {"osd": "osd.subop", "client": "client",
@@ -73,6 +74,7 @@ LABEL_OF_PATH = (
     ("/ceph_tpu/rados/", None, "client"),
     ("/ceph_tpu/osd/", "_heartbeat", "background"),
     ("/ceph_tpu/osd/", "_scrub_loop", "background"),
+    ("/ceph_tpu/osd/", "_backfill_answer", "osd.recovery"),
     ("/ceph_tpu/osd/", None, "osd.other"),
     ("/ceph_tpu/utils/work_queue.py", None, "osd.queue"),
     ("/ceph_tpu/offload/", None, "offload"),

@@ -7,7 +7,8 @@ blocking counted-resource budget used on every IO path) and
 checks in with a grace deadline; `is_healthy` flags stuck threads and a
 suicide grace escalates to process abort). `AdjustableSemaphore` is the
 AsyncReserver analog's slot pool, resizable live so reservation-backed
-knobs (osd_max_recovery_in_flight) can be retuned mid-storm.
+knobs (osd_max_backfills, osd_recovery_max_active, osd_max_scrubs)
+can be retuned mid-storm.
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """Async backfill (r4 verdict item #7): the PG serves client I/O while
 a revived OSD backfills in the background; writes to not-yet-recovered
-objects recover-on-write; recovery pushes share host-wide reservation
-slots; stray replica objects are removed.
+objects recover-on-write; recovery pushes share the host-wide
+`osd_recovery_max_active` slots; stray replica objects are removed.
 
 Reference: doc/dev/osd_internals/backfill_reservation.rst,
 src/common/AsyncReserver.h, PrimaryLogPG wait_for_degraded_object."""
@@ -16,13 +16,12 @@ def test_client_ops_proceed_during_backfill(tmp_path, monkeypatch):
     """With a throttled, slowed recovery drain, client reads AND writes
     complete while the revived peer's backfill is still pending; a write
     to a pending object recovers it immediately (recover-on-write)."""
-    from ceph_tpu.osd.daemon import OSD
-    monkeypatch.setattr(OSD, "MAX_RECOVERY_IN_FLIGHT", 1)
-
     async def body():
         c = ClusterHarness(tmp_path)
         try:
             await c.start()
+            for osd in c.osds.values():     # one push at a time
+                osd.config.set("osd_recovery_max_active", 1)
             cl = await c.client()
             await cl.pool_create("rbd", pg_num=1, size=3)
             io = cl.ioctx("rbd")

@@ -7,7 +7,7 @@ sequences; EC reads served bit-identically with 1..m OSDs down on both
 the host (jerasure) and offload (tpu) plugin paths; injected shard
 bit-rot caught by the per-chunk crc gate; injected offload device
 failures absorbed by the breaker's bit-identical host fallback;
-`osd_max_recovery_in_flight` resizable mid-flight; crash records
+`osd_max_backfills` / `osd_recovery_max_active` resizable mid-flight; crash records
 surfaced as RECENT_CRASH with `crash ls`/`crash archive`; and CLAY
 single-shard recovery fetching measurably fewer bytes than the
 full-stripe gather.
@@ -206,26 +206,32 @@ def test_adjustable_semaphore_shrink_blocks_while_overheld():
     asyncio.run(asyncio.wait_for(body(), 30))
 
 
-def test_recovery_slots_resize_live(tmp_path):
+@pytest.mark.parametrize("option, pool_of", [
+    ("osd_recovery_max_active", lambda osd: osd.recovery_active),
+    ("osd_max_backfills", lambda osd: osd.backfill_local),
+    ("osd_max_backfills", lambda osd: osd.backfill_reserver.slots)],
+    ids=["recovery_max_active", "max_backfills_local",
+         "max_backfills_remote"])
+def test_recovery_slots_resize_live(tmp_path, option, pool_of):
     async def body():
         c = ClusterHarness(tmp_path, n_osds=1)
         try:
             await c.start()
             osd = c.osds[0]
-            sem = osd.recovery_reservations
+            sem = pool_of(osd)
             assert isinstance(sem, AdjustableSemaphore)
             base = sem.limit
-            assert base == osd.config.get("osd_max_recovery_in_flight")
+            assert base == osd.config.get(option)
             for _ in range(base):
                 await sem.acquire()
             # grow: an extra slot appears without releasing anything
-            osd.config.set("osd_max_recovery_in_flight", base + 4)
+            osd.config.set(option, base + 4)
             await asyncio.sleep(0)      # let a threadsafe hop land
             await asyncio.wait_for(sem.acquire(), 2)
             assert sem.limit == base + 4
             # shrink below what is held (base+1 in flight): the pool
             # stays locked and refills only as holders release
-            osd.config.set("osd_max_recovery_in_flight", 1)
+            osd.config.set(option, 1)
             await asyncio.sleep(0)
             assert sem.limit == 1 and sem.locked()
             for _ in range(base + 1):

@@ -415,7 +415,7 @@ def test_crossed_scrub_reservations_are_rejected_not_parked(tmp_path):
                 while sem.locked():     # the winner's release, crossing
                     assert time.monotonic() - t0 < 5.0
                     await asyncio.sleep(0.01)
-                assert c.osds[who]._scrub_remote_grants == set()
+                assert c.osds[who].scrub_reserver.grants == set()
             # the one that lost comes back and runs to the end
             res = await pg1.scrub()
             assert "reserve_failed" not in res and res["errors"] == 0

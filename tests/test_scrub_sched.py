@@ -182,7 +182,7 @@ async def _watch(c, seconds, until=None, peak=None):
     while time.monotonic() - t0 < seconds:
         for i, osd in c.osds.items():
             peak[i] = max(peak[i], _slots_held(osd))
-            assert len(osd._scrub_remote_grants) <= osd.scrub_reservations.limit
+            assert len(osd.scrub_reserver.grants) <= osd.scrub_reservations.limit
         if until is not None and until():
             break
         await asyncio.sleep(0.005)
@@ -276,7 +276,7 @@ def test_a_taken_slot_rejects_at_once_and_nothing_stays_held(tmp_path):
             while _slots_held(c.osds[1]):           # osd.1's: a message
                 assert time.monotonic() < deadline
                 await asyncio.sleep(0.005)
-            assert c.osds[1]._scrub_remote_grants == set()
+            assert c.osds[1].scrub_reserver.grants == set()
             assert _slots_held(c.osds[2]) == 1      # the one taken here
             # no write gate was ever closed
             await io.write_full("obj", os.urandom(8192))
@@ -324,7 +324,7 @@ def test_a_round_that_ends_sends_no_release(tmp_path, chunk_max):
                 p["range"][1] is None for p in naming)
             for osd in c.osds.values():
                 assert _slots_held(osd) == 0
-                assert osd._scrub_remote_grants == set()
+                assert osd.scrub_reserver.grants == set()
             step = scrub_mod.SCRUB_TURN_S
             for i, osd in c.osds.items():
                 behind = (i - me - 1) % 3
@@ -435,7 +435,7 @@ def test_a_peer_that_never_answers_costs_one_bounded_wait(
             while any(_slots_held(o) for o in c.osds.values()):
                 assert time.monotonic() < deadline
                 await asyncio.sleep(0.005)
-            assert all(o._scrub_remote_grants == set()
+            assert all(o.scrub_reserver.grants == set()
                        for o in c.osds.values())
             assert not pg._reserve_waiters
             quiet["on"] = False
