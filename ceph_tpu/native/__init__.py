@@ -66,6 +66,10 @@ def load() -> ctypes.CDLL:
             lib.crc32c_blocks.argtypes = [u8p, ctypes.c_size_t,
                                           ctypes.c_size_t, ctypes.c_uint32,
                                           u32p]
+            lib.planes_from_stripes.restype = None
+            lib.planes_from_stripes.argtypes = [
+                u8p, ctypes.c_size_t, ctypes.c_size_t, ctypes.c_size_t,
+                ctypes.c_size_t, ctypes.c_size_t, u8p]
             lib.ec_native_crc32c_sw.restype = ctypes.c_uint32
             lib.ec_native_crc32c_sw.argtypes = lib.crc32c.argtypes
             lib.ec_native_crc32c_impl.restype = ctypes.c_char_p

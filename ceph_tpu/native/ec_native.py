@@ -130,6 +130,26 @@ def crc32c_blocks(data: np.ndarray, block_size: int,
     return out
 
 
+def planes_from_stripes(stripes: np.ndarray, first: int, count: int,
+                        out: np.ndarray) -> None:
+    """Planes `first` .. `first + count` of (S, n, C) uint8 `stripes`
+    (plane i is `stripes[:, i, :]`: shard i's chunk of every stripe) made
+    contiguous, one after another, in `out` (count * S * C bytes): the
+    per-shard buffers of an erasure-coded write, all in one call that
+    runs without the GIL."""
+    if stripes.dtype != np.uint8 or stripes.ndim != 3 \
+            or not stripes.flags.c_contiguous:
+        raise ValueError("stripes: a C-contiguous (S, n, C) uint8 array")
+    S, n, C = stripes.shape
+    if not 0 <= first <= first + count <= n:
+        raise ValueError(f"planes {first}..{first + count} of {n}")
+    if out.dtype != np.uint8 or not out.flags.c_contiguous \
+            or not out.flags.writeable or out.size != count * S * C:
+        raise ValueError(f"out: {count * S * C} contiguous writable bytes")
+    native.load().planes_from_stripes(_ptr(stripes), S, n, C, first, count,
+                                      _ptr(out))
+
+
 def available() -> bool:
     try:
         native.load()

@@ -82,8 +82,8 @@ from ceph_tpu.utils.throttle import Throttle
 
 #: the hops of one staged dispatch that `_device_call` stamps on the
 #: `offload_batch` span, in order; `scatter_us` closes the span after them
-_HOPS = ("pool_wait_us", "h2d_submit_us", "launch_us", "result_wait_us",
-         "resume_us")
+_HOPS = ("pool_wait_us", "stack_us", "h2d_submit_us", "launch_us",
+         "result_wait_us", "finish_us", "resume_us")
 
 # -- module-wide defaults (mirrored by the ec_offload_* config options) ------
 
@@ -206,12 +206,20 @@ class _Job:
     `data` is one array, or a LIST of row-compatible arrays (a scatter
     job — e.g. per-shard csum fragments): the fragments stack straight
     into the staging pages at batch build, never through an
-    intermediate join on the submit path."""
+    intermediate join on the submit path. `finish`, where a job has
+    one, is its finisher: called in the staging pool on the job's own
+    rows of the batch's result, it returns what the job's future
+    resolves with (or raises, and the future takes the exception). It
+    reads the job's `data` there, after the loop has moved on: a rider
+    leaves what it submitted as it is until its future resolves."""
 
-    __slots__ = ("data", "rows", "nbytes", "fut", "span", "t_submit")
+    __slots__ = ("data", "rows", "nbytes", "fut", "span", "t_submit",
+                 "finish")
 
-    def __init__(self, data, fut: asyncio.Future):
+    def __init__(self, data, fut: asyncio.Future,
+                 finish: Callable | None = None):
         self.data = data
+        self.finish = finish
         if isinstance(data, list):
             self.rows = sum(f.shape[0] for f in data)
             self.nbytes = int(sum(f.nbytes for f in data))
@@ -221,6 +229,73 @@ class _Job:
         self.fut = fut
         self.span = tracer.start_span("offload_queue_wait")
         self.t_submit = time.perf_counter()
+
+
+class _Failed:
+    """A finisher's exception on its way from the staging pool to its
+    rider's future."""
+
+    __slots__ = ("exc",)
+
+    def __init__(self, exc: Exception):
+        self.exc = exc
+
+
+class _Batch:
+    """The host side of one flushed bucket. Made on the loop
+    (`OffloadService._stage`: the staging page is the loop's to take
+    and to give back), served in the staging pool, where the batch goes
+    anyway for its kernel: `serve` makes both of the batch's host copies
+    there, the stacking copy into `stacked` before the kernel and each
+    rider's finisher after it, and touches no span: it leaves its four
+    timestamps in `t` (start, stacked, kernel returned, finished) and
+    the riders' results in `results` for the loop to read. The stacking
+    copy is `np.copyto` on uint8, which lets go of the GIL, so the loop
+    runs beside it; a finisher is to do the same."""
+
+    __slots__ = ("jobs", "frags", "stacked", "staging", "t", "results")
+
+    def __init__(self, jobs: list[_Job], frags: list[np.ndarray] | None,
+                 stacked: np.ndarray, staging: np.ndarray | None):
+        self.jobs = jobs
+        #: what is still to be copied into `stacked` (None: nothing, a
+        #: lone job handed through by reference, or copied already)
+        self.frags = frags
+        self.stacked = stacked
+        self.staging = staging
+        self.t: tuple[float, ...] = ()
+        self.results: list | None = None
+
+    def serve(self, call: Callable) -> np.ndarray:
+        t0 = time.perf_counter()
+        if self.frags is not None:
+            row = nbytes = 0
+            for f in self.frags:
+                np.copyto(self.stacked[row:row + f.shape[0]], f)
+                row += f.shape[0]
+                nbytes += int(f.nbytes)
+            # a batch that fails over to another chip is served again,
+            # from the page as it is
+            self.frags = None
+            t1 = time.perf_counter()
+            copytrack.copied("buffer_to_staging", nbytes, t1 - t0)
+        else:
+            t1 = t0
+        out = call(self.stacked)
+        t2 = time.perf_counter()
+        results, row = [], 0
+        for j in self.jobs:
+            part = out[row:row + j.rows]
+            row += j.rows
+            if j.finish is not None:
+                try:
+                    part = j.finish(part)
+                except Exception as e:      # that rider's alone
+                    part = _Failed(e)
+            results.append(part)
+        self.results = results
+        self.t = (t0, t1, t2, time.perf_counter())
+        return out
 
 
 class _Bucket:
@@ -720,9 +795,12 @@ class OffloadService:
 
     # -- public job API ------------------------------------------------------
 
-    async def encode(self, ec_impl, stripes: np.ndarray) -> np.ndarray:
+    async def encode(self, ec_impl, stripes: np.ndarray,
+                     finish: Callable | None = None):
         """(S, k, C) data stripes -> (S, m, C) parity via the plugin's
-        batched device API, coalesced with concurrent callers."""
+        batched device API, coalesced with concurrent callers. With
+        `finish` (parity -> result) the caller gets that result
+        instead, made in the staging pool beside the loop."""
         key = ("enc", ec_impl.coding_matrix.tobytes(), stripes.shape[2])
 
         def dispatch(batch: np.ndarray) -> np.ndarray:
@@ -735,7 +813,8 @@ class OffloadService:
             return self._mesh_apply(key[:2], ec_impl.coding_matrix, batch)
 
         return await self._submit(key, stripes, dispatch, fallback,
-                                  shard_dispatch=shard_dispatch)
+                                  shard_dispatch=shard_dispatch,
+                                  finish=finish)
 
     async def decode(self, ec_impl, avail_ids: tuple[int, ...],
                      want_ids: tuple[int, ...],
@@ -929,16 +1008,18 @@ class OffloadService:
                       dispatch: Callable, fallback: Callable,
                       uses_device: bool = True,
                       shard_dispatch: Callable | None = None,
-                      pad_rows: Callable | None = None) -> np.ndarray:
+                      pad_rows: Callable | None = None,
+                      finish: Callable | None = None):
         if not self.enabled:
-            return self._inline(data, dispatch, fallback, uses_device)
+            out = self._inline(data, dispatch, fallback, uses_device)
+            return out if finish is None else finish(out)
         nbytes = int(sum(f.nbytes for f in data)) \
             if isinstance(data, list) else int(data.nbytes)
         await self._acquire(nbytes)
         self.perf.inc("jobs")
         self.stats["jobs"] += 1
         fut: asyncio.Future = self._loop.create_future()
-        job = _Job(data, fut)
+        job = _Job(data, fut, finish)
         bucket = self._buckets.get(key)
         if bucket is None:
             bucket = self._buckets[key] = _Bucket(key, dispatch, fallback,
@@ -1133,19 +1214,20 @@ class OffloadService:
             # run once the loop gets a turn
             await asyncio.sleep(0)
 
-    def _stack(self, slot: _DeviceSlot, jobs: list[_Job],
-               pad_rows: Callable | None = None):
-        """Jobs -> one contiguous batch. A lone single-array job is
-        handed through by reference (zero-copy: the memoryview-through
-        path from bufferlist to staging); everything else — coalesced
-        jobs AND scatter jobs' fragments — stacks in one pass straight
-        into the slot's REUSED staging array (warm pages, no
-        intermediate bufferlist join anywhere on the path; the old
-        b"".join the callers did before submitting showed up as an
-        unmetered extra copy of every csum'd byte). With `pad_rows` the
-        batch is staged at `pad_rows(rows)` rows: those past the jobs'
-        keep what the page held, and nobody reads their results.
-        Returns (stacked, staging_buf_or_None, stack_seconds)."""
+    def _stage(self, slot: _DeviceSlot, jobs: list[_Job],
+               pad_rows: Callable | None = None) -> _Batch:
+        """Jobs -> the batch they make, its page taken and nothing
+        copied yet. A lone single-array job is handed through by
+        reference (zero-copy: the memoryview-through path from
+        bufferlist to staging); everything else — coalesced jobs AND
+        scatter jobs' fragments — is to stack in one pass straight into
+        the slot's REUSED staging array (warm pages, no intermediate
+        bufferlist join anywhere on the path; the old b"".join the
+        callers did before submitting showed up as an unmetered extra
+        copy of every csum'd byte), which `_Batch.serve` does in the
+        staging pool. With `pad_rows` the batch is staged at
+        `pad_rows(rows)` rows: those past the jobs' keep what the page
+        held, and nobody reads their results."""
         frags: list[np.ndarray] = []
         for j in jobs:
             if isinstance(j.data, list):
@@ -1156,20 +1238,12 @@ class OffloadService:
         staged = rows if pad_rows is None else pad_rows(rows)
         if len(frags) == 1 and staged == rows:
             copytrack.referenced("buffer_to_staging", jobs[0].nbytes)
-            return frags[0], None, 0.0
-        nbytes = sum(int(f.nbytes) for f in frags)
+            return _Batch(jobs, None, frags[0], None)
         row_bytes = frags[0].itemsize * int(np.prod(frags[0].shape[1:]))
-        t0 = time.perf_counter()
         buf = slot.get_staging(staged * row_bytes)
         view = buf[:staged * row_bytes].reshape(
             (staged,) + frags[0].shape[1:])
-        row = 0
-        for f in frags:
-            np.copyto(view[row:row + f.shape[0]], f)
-            row += f.shape[0]
-        dt = time.perf_counter() - t0
-        copytrack.copied("buffer_to_staging", nbytes, dt)
-        return view, buf, dt
+        return _Batch(jobs, frags, view, buf)
 
     async def _run_batch(self, bucket: _Bucket) -> None:
         jobs = bucket.jobs
@@ -1187,7 +1261,7 @@ class OffloadService:
                     j.fut.set_exception(e)
             return
         slot.inflight += 1
-        staging = None
+        batch = None
         try:
             # the semaphore wait is INSIDE the try: a cancel delivered
             # while queued behind full staging slots must still cancel
@@ -1203,8 +1277,7 @@ class OffloadService:
                         if j.span is not None:
                             j.span.set_tag("batch_ops", len(jobs))
                             j.span.finish()
-                    stacked, staging, stack_s = self._stack(
-                        slot, jobs, bucket.pad_rows)
+                    batch = self._stage(slot, jobs, bucket.pad_rows)
                     nbytes = sum(j.nbytes for j in jobs)
                     with tracer.span("offload_batch") as sp:
                         if sp is not None:
@@ -1216,14 +1289,11 @@ class OffloadService:
                                 if j.span is not None and \
                                         j.span.trace_id != sp.trace_id:
                                     sp.add_link(j.span.context())
-                            # the two hops before the span opens: the
-                            # slot semaphore (inside the riders'
-                            # offload_queue_wait, after the linger) and
-                            # the stacking copy
+                            # the hop before the span opens: the slot
+                            # semaphore (inside the riders'
+                            # offload_queue_wait, after the linger)
                             sp.set_tag("sem_wait_us",
                                        round((now - t_sem) * 1e6, 1))
-                            sp.set_tag("stack_us",
-                                       round(stack_s * 1e6, 1))
                             # enc / dec / crc / rep; a decode batch
                             # also carries its r and its pattern
                             sp.set_tag("kind", bucket.key[0])
@@ -1235,26 +1305,35 @@ class OffloadService:
                                                          for j in jobs))
                                 sp.set_tag("block_size", bucket.key[2])
                                 sp.set_tag("padded_blocks",
-                                           int(stacked.shape[0]))
+                                           int(batch.stacked.shape[0]))
                         out, on_device = await self._dispatch(
-                            bucket, slot, stacked, len(jobs), sp,
-                            token)
+                            bucket, slot, batch, len(jobs), sp, token)
                         if sp is not None:
                             sp.set_tag("ops", len(jobs))
                             sp.set_tag("bytes", nbytes)
                             sp.set_tag("device", on_device)
                             sp.set_tag("copy_bytes",
-                                       nbytes if staging is not None
+                                       nbytes if batch.staging is not None
                                        else 0)
-                        row = 0
-                        for j in jobs:
-                            if not j.fut.done():
-                                j.fut.set_result(out[row:row + j.rows])
-                            row += j.rows
+                            if "resume_us" not in sp.tags:
+                                # a host, mesh or fallback batch has no
+                                # device hops; its copies it has
+                                t0, t1, t2, t3 = batch.t
+                                sp.set_tag("stack_us",
+                                           round((t1 - t0) * 1e6, 1))
+                                sp.set_tag("finish_us",
+                                           round((t3 - t2) * 1e6, 1))
+                        for j, res in zip(jobs, batch.results):
+                            if j.fut.done():
+                                continue
+                            if isinstance(res, _Failed):
+                                j.fut.set_exception(res.exc)
+                            else:
+                                j.fut.set_result(res)
                         if sp is not None and "resume_us" in sp.tags:
-                            # the last of the six hops inside the span
-                            # (`_device_call` stamps the other five):
-                            # the riders' futures resolved
+                            # the last of the eight hops inside the
+                            # span (`_device_call` stamps the other
+                            # seven): the riders' futures resolved
                             sp.set_tag("scatter_us", round(
                                 (time.perf_counter() - sp.t0) * 1e6
                                 - sum(sp.tags[h] for h in _HOPS), 1))
@@ -1269,9 +1348,13 @@ class OffloadService:
                         self.stats["crc_batches"] += 1
                         self.stats["crc_bytes"] += nbytes
                 except asyncio.CancelledError:
+                    if batch is not None:
+                        # the staging pool may still be writing the
+                        # page: it is not the next batch's to take
+                        batch.staging = None
                     raise
                 except Exception as e:
-                    # pre-dispatch failure (stacking): release OUR probe
+                    # pre-dispatch failure (staging): release OUR probe
                     # claim — the breaker callbacks that normally clear
                     # it never ran
                     slot.release_probe(token)
@@ -1279,8 +1362,8 @@ class OffloadService:
                         if not j.fut.done():
                             j.fut.set_exception(e)
                 finally:
-                    if staging is not None:
-                        slot.put_staging(staging)
+                    if batch is not None and batch.staging is not None:
+                        slot.put_staging(batch.staging)
                     self.perf.dec("inflight_batches")
         except asyncio.CancelledError:
             # cancelled before/while dispatching: un-claim OUR probe so
@@ -1294,17 +1377,18 @@ class OffloadService:
             slot.inflight -= 1
 
     async def _in_staging_pool(self, fn: Callable,
-                               stacked: np.ndarray) -> np.ndarray:
-        """Run one batch kernel in the staging pool UNDER the caller's
+                               batch: _Batch) -> np.ndarray:
+        """Serve one batch in the staging pool, its kernel `fn` between
+        its two host copies (`_Batch.serve`), UNDER the caller's
         contextvar context: run_in_executor does not propagate it, which
         would orphan the plugin's tpu_*_dispatch spans into fresh root
         traces instead of nesting under offload_batch."""
         ctx = contextvars.copy_context()
         return await self._loop.run_in_executor(
-            _executor(), lambda: ctx.run(fn, stacked))
+            _executor(), lambda: ctx.run(batch.serve, fn))
 
     async def _device_call(self, slot: _DeviceSlot, fn: Callable,
-                           stacked: np.ndarray, sp=None) -> np.ndarray:
+                           batch: _Batch, sp=None) -> np.ndarray:
         """One staged dispatch onto `slot`'s device: H2D onto that chip
         (from the reused staging buffer — the steady-state link rate),
         the bucket kernel on the committed device array, D2H of the
@@ -1312,31 +1396,32 @@ class OffloadService:
         longer see (it receives a device-resident array). The batch
         span gets the hand-offs (`_HOPS`), from timestamps taken where
         the work happens and without serializing anything:
-        `pool_wait_us` (the span opens -> `run` starts on the
-        staging-pool thread), `h2d_submit_us` (device_put returns),
-        `launch_us` (`fn(dev)` returns), `result_wait_us` (np.asarray
-        returns: kernel and D2H), `resume_us` (`run` returns -> this
-        coroutine runs again on the loop)."""
+        `pool_wait_us` (the span opens -> `serve` starts on the
+        staging-pool thread), `stack_us` (the riders' stripes are in
+        the page), `h2d_submit_us` (device_put returns), `launch_us`
+        (`fn(dev)` returns), `result_wait_us` (np.asarray returns:
+        kernel and D2H), `finish_us` (the riders' finishers are done),
+        `resume_us` (`serve` returns -> this coroutine runs again on
+        the loop)."""
         import jax
-        nbytes = int(stacked.nbytes)
-        t = [sp.t0 if sp is not None else 0.0]
+        t: list[float] = []
 
-        def run(batch: np.ndarray) -> np.ndarray:
-            t.append(time.perf_counter())
-            dev = jax.device_put(batch, slot.jdev)
+        def run(stacked: np.ndarray) -> np.ndarray:
+            dev = jax.device_put(stacked, slot.jdev)
             t.append(time.perf_counter())
             res = fn(dev)
             t.append(time.perf_counter())
             out = np.asarray(res)
-            t.append(time.perf_counter())
-            copytrack.copied("h2d", nbytes)
+            copytrack.copied("h2d", int(stacked.nbytes))
             copytrack.copied("d2h", int(out.nbytes))
             return out
 
-        out = await self._in_staging_pool(run, stacked)
+        out = await self._in_staging_pool(run, batch)
         if sp is not None:
-            t.append(time.perf_counter())
-            for name, a, b in zip(_HOPS, t, t[1:]):
+            start, stacked, fetched, finished = batch.t
+            stamps = (sp.t0, start, stacked, *t, fetched, finished,
+                      time.perf_counter())
+            for name, a, b in zip(_HOPS, stamps, stamps[1:]):
                 sp.set_tag(name, round((b - a) * 1e6, 1))
         return out
 
@@ -1373,7 +1458,7 @@ class OffloadService:
             return False
 
     async def _dispatch(self, bucket: _Bucket, slot: _DeviceSlot,
-                        stacked: np.ndarray, n_ops: int,
+                        batch: _Batch, n_ops: int,
                         sp=None, token: object = None
                         ) -> tuple[np.ndarray, str]:
         """One staged dispatch with per-device failover and host-codec
@@ -1382,10 +1467,10 @@ class OffloadService:
             # schedule explorer: let a racing batch reach the breaker/
             # staging state between routing and dispatch
             await interleave.yield_point("offload_dispatch")
-        nbytes = int(stacked.nbytes)
+        nbytes = int(batch.stacked.nbytes)
         if not bucket.uses_device:
             t0 = time.perf_counter()
-            out = await self._in_staging_pool(bucket.dispatch, stacked)
+            out = await self._in_staging_pool(bucket.dispatch, batch)
             self._note_device("host", n_ops, nbytes,
                               time.perf_counter() - t0)
             return out, "host"
@@ -1403,8 +1488,8 @@ class OffloadService:
             topo = self._topo
             try:
                 t0 = time.perf_counter()
-                out = await self._in_staging_pool(
-                    lambda b: bucket.shard_dispatch(b), stacked)
+                out = await self._in_staging_pool(bucket.shard_dispatch,
+                                                  batch)
                 busy = time.perf_counter() - t0
                 with topo.lock:
                     topo.note("mesh_degraded", write=True)
@@ -1449,7 +1534,7 @@ class OffloadService:
                 try:
                     t0 = time.perf_counter()
                     out = await self._device_call(slot, bucket.dispatch,
-                                                  stacked, sp)
+                                                  batch, sp)
                     self._slot_success(slot)
                     busy_s = time.perf_counter() - t0
                     self._note_device(slot.label, n_ops, nbytes, busy_s)
@@ -1487,7 +1572,7 @@ class OffloadService:
             self.perf.inc("fallback_ops", n_ops)
             self.stats["fallback_ops"] += n_ops
             t0 = time.perf_counter()
-            out = await self._in_staging_pool(bucket.fallback, stacked)
+            out = await self._in_staging_pool(bucket.fallback, batch)
             self._note_device("host", n_ops, nbytes,
                               time.perf_counter() - t0, fallback=True)
             return out, "host"

@@ -155,17 +155,32 @@ class ECBackend(PGBackend):
             return get_service_or_none()
         return None
 
-    async def _encode(self, data: bytes) -> dict[int, bytes]:
-        """One batched encode dispatch through the process-wide offload
+    async def _encode_csums(
+            self, data: bytes) -> tuple[dict[int, bytes],
+                                        dict[int, list[int]]]:
+        """A write's shards and their per-chunk crc32c lists: one
+        batched encode dispatch through the process-wide offload
         service — concurrent PGs' stripes coalesce into one device
         batch — sampled into the daemon's `ec_encode_us` histogram
-        (ec_util opens the per-dispatch span with bytes/k/m tags)."""
+        (ec_util opens the per-dispatch span with bytes/k/m tags).
+        Where the crc is the host's beside a device-batched plugin (the
+        service with `ec_offload_crc_device` off) it rides the encode:
+        the rider's finisher takes it on the staging-pool thread over
+        the planes it has just written, one native call and no CrcJob,
+        whose staging copied every shard byte once more. Where it is
+        the device's, or there is no service, it is a step of its own
+        (`_csums_shards`)."""
+        svc = self._offload_svc()
+        rides = svc is not None and not svc.crc_device
         t0 = time.perf_counter()
-        shards = await ec_util.encode_async(self.sinfo, self.ec_impl, data,
-                                            service=self._offload_svc())
+        shards, csums = await ec_util.encode_csums_async(
+            self.sinfo, self.ec_impl, data,
+            self.sinfo.chunk_size if rides else 0, service=svc)
         self.host.perf.hist_add("ec_encode_us",
                                 (time.perf_counter() - t0) * 1e6)
-        return shards
+        if csums is None:
+            csums = await self._csums_shards(shards)
+        return shards, csums
 
     def _csums(self, shard_buf: bytes) -> list[int]:
         """Per-chunk crc32c list of a shard buffer (Checksummer analog).
@@ -183,22 +198,18 @@ class ECBackend(PGBackend):
     async def _csums_shards(
             self, shards: dict[int, bytes]) -> dict[int, list[int]]:
         """Per-chunk crc32c lists for ALL shards of one write in a
-        single CrcJob through the offload service: the n per-shard
-        checksum calls become one batch that also coalesces with
-        concurrent writers and runs off the event loop (the BlueStore
-        Checksummer's batch shape, src/common/Checksummer.h:195-234)."""
+        single CrcJob through the offload service, where the crc is the
+        device's (`_encode_csums`): the n per-shard checksum calls
+        become one batch that also coalesces with concurrent writers
+        and runs off the event loop (the BlueStore Checksummer's batch
+        shape, src/common/Checksummer.h:195-234). Without a service (a
+        jerasure pool gains nothing from the linger wait its writes
+        would pay) the native kernel, shard by shard."""
         c = self.sinfo.chunk_size
-        # only the device-plugin pools ride the queue (a jerasure pool
-        # gains nothing from the linger wait its writes would pay), and
-        # only when the crc work is big enough to beat the queue round
-        # trip — the native kernel does a tiny op's csums in ~30 µs,
-        # cheaper than any linger
         svc = self._offload_svc()
         lens = {len(b) for b in shards.values()}
-        total_blocks = sum(len(b) for b in shards.values()) // c
         if (svc is None or self._checksummer is None or not shards
-                or lens == {0} or any(ln % c for ln in lens)
-                or (total_blocks < 256 and not svc.crc_device)):
+                or lens == {0} or any(ln % c for ln in lens)):
             return {i: self._csums(b) for i, b in shards.items()}
         order = sorted(shards)
         # ONE scatter CrcJob over the per-shard buffers: the fragments
@@ -303,9 +314,7 @@ class ECBackend(PGBackend):
 
         if op in ("write_full", "push"):
             padded = self._pad(data)
-            shards = await self._encode(padded) \
-                if padded else {i: b"" for i in range(self.n)}
-            csums = await self._csums_shards(shards)
+            shards, csums = await self._encode_csums(padded)
             # WRITEFULL replaces data, not xattrs: the full-state shard
             # rewrite must carry the user attrs forward (the primary's
             # copy is authoritative — xattrs replicate to every shard)
@@ -479,8 +488,7 @@ class ECBackend(PGBackend):
         # the bufferlist region goes to the codec as-is (np.frombuffer
         # views a bytearray zero-copy); the old bytes(region) paid a
         # full extra copy per RMW merge
-        shards = await self._encode(region)
-        csums = await self._csums_shards(shards)
+        shards, csums = await self._encode_csums(region)
         new_n = -(-new_size // w)
         payloads = {}
         for i in live:

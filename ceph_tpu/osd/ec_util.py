@@ -145,10 +145,23 @@ def _encode_frame(sinfo: StripeInfo, ec_impl, data, want):
     return stripes, want, k, n_chunks, mapping, batched
 
 
+def _csums_of(buf, block: int) -> list[int]:
+    from ceph_tpu.native import ec_native
+    return ec_native.crc32c_blocks(np.frombuffer(buf, dtype=np.uint8),
+                                   block).tolist()
+
+
 def _encode_assemble(stripes: np.ndarray, parity: np.ndarray, k: int,
-                     want, sp=None) -> dict[int, memoryview]:
+                     want, csum_block: int = 0
+                     ) -> tuple[dict[int, memoryview], dict | None, dict]:
     """Shard planes -> per-shard reply buffers, AT MOST one copy per
-    byte — and zero for contiguous planes.
+    byte — and zero for contiguous planes; with `csum_block`, also each
+    buffer's crc32c by blocks of that many bytes, taken where the planes
+    were just written (one native call over all that were copied).
+    Returns the buffers, the crcs (or None) and its own account of the
+    work (`copy_bytes`, `copy_us`, `csum_us`: tags of the caller's
+    `ec_encode`), and touches no span itself: through `encode_async` it
+    runs in the offload service's staging pool, beside the loop.
 
     A shard's chunks-per-stripe plane `stripes[:, i, :]` (or
     `parity[:, i-k, :]`) is C-contiguous whenever the write is a single
@@ -157,34 +170,66 @@ def _encode_assemble(stripes: np.ndarray, parity: np.ndarray, k: int,
     reply buffer and a memoryview over it goes downstream as-is
     (message frames, object-store writes and crc all take buffer
     objects), metered referenced. Strided planes (multi-stripe, k or
-    m >= 2) still pay the single extraction copy into a fresh
-    bytearray — the remaining reply_assemble ledger entry."""
+    m >= 2) still pay the single extraction copy — the remaining
+    reply_assemble ledger entry. They are copied run by run of
+    neighbouring shards (the data shards in one native call, the parity
+    shards in another: `ec_native.planes_from_stripes`) into one
+    allocation, which their memoryviews share and keep until the last
+    is gone: the thread that copies is without the GIL for a run's
+    length and takes it back once a run, not once a plane. So a buffer
+    that outlives its write (a sub-op queued to a slow replica) holds
+    the write's other planes with it, S * C times the shards copied
+    and not S * C. The allocation is uninitialised (the copy writes every byte
+    of it, so no stale byte leaves): a `bytearray(n)` is zero-filled
+    under the GIL, for a copy's length."""
     t0 = time.perf_counter()
     S, _, C = stripes.shape
     out: dict[int, memoryview] = {}
-    copied = 0
+    runs: list[list] = []       # [source, first plane, planes] to copy
+    copied: list[int] = []      # their shards, in order
     referenced = 0
     for i in sorted(want):
-        src = stripes[:, i, :] if i < k else parity[:, i - k, :]
-        if src.flags.c_contiguous:
+        src, j = (stripes, i) if i < k else (parity, i - k)
+        if src[:, j, :].flags.c_contiguous:
             # no materialization: the plane is a window over the encode
             # input (data shards) or the device result (parity)
-            out[i] = memoryview(src.reshape(S * C))
+            out[i] = memoryview(src[:, j, :].reshape(S * C))
             referenced += S * C
             continue
-        buf = bytearray(S * C)
-        np.copyto(np.frombuffer(buf, dtype=np.uint8).reshape(S, C), src)
-        out[i] = memoryview(buf)
-        copied += S * C
-    dt = time.perf_counter() - t0
+        if runs and runs[-1][0] is src and sum(runs[-1][1:]) == j:
+            runs[-1][2] += 1
+        else:
+            runs.append([src, j, 1])
+        copied.append(i)
+        out[i] = None           # its place in the order; filled below
+    if copied:
+        from ceph_tpu.native import ec_native
+        planes = np.empty(len(copied) * S * C, dtype=np.uint8)
+        at = 0
+        for src, first, count in runs:
+            ec_native.planes_from_stripes(src, first, count,
+                                          planes[at:at + count * S * C])
+            at += count * S * C
+        for n, i in enumerate(copied):
+            out[i] = memoryview(planes[n * S * C:(n + 1) * S * C])
+    t1 = time.perf_counter()
     if referenced:
         copytrack.referenced("reply_assemble", referenced)
     if copied:
-        copytrack.copied("reply_assemble", copied, dt)
-    if sp is not None:
-        sp.set_tag("copy_bytes", copied)
-        sp.set_tag("copy_us", round(dt * 1e6, 1))
-    return out
+        copytrack.copied("reply_assemble", len(copied) * S * C, t1 - t0)
+    tags = {"copy_bytes": len(copied) * S * C,
+            "copy_us": round((t1 - t0) * 1e6, 1)}
+    csums = None
+    if csum_block:
+        csums = {i: _csums_of(out[i], csum_block)
+                 for i in out if i not in copied}
+        if copied:
+            crcs = _csums_of(planes, csum_block)
+            per = len(crcs) // len(copied)
+            for n, i in enumerate(copied):
+                csums[i] = crcs[n * per:(n + 1) * per]
+        tags["csum_us"] = round((time.perf_counter() - t1) * 1e6, 1)
+    return out, csums, tags
 
 
 def _encode_scalar(sinfo: StripeInfo, ec_impl, stripes, want, k, n_chunks,
@@ -205,8 +250,10 @@ def _encode_scalar(sinfo: StripeInfo, ec_impl, stripes, want, k, n_chunks,
 
 
 def _encode_framed(sinfo: StripeInfo, ec_impl, stripes, want, k, n_chunks,
-                   mapping, batched) -> dict[int, bytes]:
-    """Inline dispatch of an already-validated frame."""
+                   mapping, batched, csum_block: int = 0
+                   ) -> tuple[dict[int, bytes], dict | None]:
+    """Inline dispatch of an already-validated frame: the shards, and
+    with `csum_block` their crcs."""
     with tracer.span("ec_encode") as sp:
         if sp is not None:
             sp.set_tag("bytes", int(stripes.size))
@@ -216,9 +263,15 @@ def _encode_framed(sinfo: StripeInfo, ec_impl, stripes, want, k, n_chunks,
             sp.set_tag("batched", batched)
         if batched:
             parity = np.asarray(ec_impl.encode_stripes(stripes))
-            return _encode_assemble(stripes, parity, k, want, sp=sp)
-        return _encode_scalar(sinfo, ec_impl, stripes, want, k, n_chunks,
-                              mapping)
+            out, csums, tags = _encode_assemble(stripes, parity, k, want,
+                                                csum_block)
+            if sp is not None:
+                sp.tags.update(tags)
+            return out, csums
+        out = _encode_scalar(sinfo, ec_impl, stripes, want, k, n_chunks,
+                             mapping)
+        return out, {i: _csums_of(b, csum_block) for i, b in out.items()} \
+            if csum_block else None
 
 
 def encode(sinfo: StripeInfo, ec_impl, data: bytes | np.ndarray,
@@ -235,7 +288,7 @@ def encode(sinfo: StripeInfo, ec_impl, data: bytes | np.ndarray,
     if stripes is None:
         return {i: b"" for i in sorted(want)}
     return _encode_framed(sinfo, ec_impl, stripes, want, k, n_chunks,
-                          mapping, batched)
+                          mapping, batched)[0]
 
 
 async def encode_async(sinfo: StripeInfo, ec_impl,
@@ -247,13 +300,29 @@ async def encode_async(sinfo: StripeInfo, ec_impl,
     callers' stripes (one staged device batch across PGs/daemons)
     instead of dispatching inline. Without a service — or on a plugin
     with no batched API — this is exactly encode()."""
+    return (await encode_csums_async(sinfo, ec_impl, data, 0, want,
+                                     service))[0]
+
+
+async def encode_csums_async(sinfo: StripeInfo, ec_impl,
+                             data: bytes | np.ndarray, csum_block: int,
+                             want: Iterable[int] | None = None,
+                             service=None
+                             ) -> tuple[dict[int, bytes], dict | None]:
+    """encode_async(), and with a `csum_block` each shard's crc32c by
+    blocks of that many bytes as well (shard id -> list; None without):
+    taken by the same finisher on the service's staging-pool thread,
+    over planes it has just written, so a write's checksums cost the
+    loop no second job and the pool thread no staging copy of every
+    shard byte."""
     stripes, want, k, n_chunks, mapping, batched = _encode_frame(
         sinfo, ec_impl, data, want)
     if stripes is None:
-        return {i: b"" for i in sorted(want)}
+        return ({i: b"" for i in sorted(want)},
+                {i: [] for i in sorted(want)} if csum_block else None)
     if not (batched and service is not None):
         return _encode_framed(sinfo, ec_impl, stripes, want, k, n_chunks,
-                              mapping, batched)
+                              mapping, batched, csum_block)
     with tracer.span("ec_encode") as sp:
         if sp is not None:
             sp.set_tag("bytes", int(stripes.size))
@@ -262,16 +331,24 @@ async def encode_async(sinfo: StripeInfo, ec_impl,
             sp.set_tag("stripes", stripes.shape[0])
             sp.set_tag("batched", True)
             sp.set_tag("offload", True)
-        parity = np.asarray(await service.encode(ec_impl, stripes))
-        t0 = time.perf_counter()
-        out = _encode_assemble(stripes, parity, k, want, sp=sp)
+
+        def finish(parity: np.ndarray):
+            # the rider's finisher: in the service's staging pool, on
+            # this rider's rows of its batch's result
+            t0 = time.perf_counter()
+            out, csums, tags = _encode_assemble(stripes, parity, k, want,
+                                                csum_block)
+            # inside the batch's finish_us: with offload_queue_wait and
+            # offload_batch it makes up this span but for the rider's
+            # wait for its turn on the loop
+            tags["assemble_us"] = round((time.perf_counter() - t0) * 1e6, 1)
+            return out, csums, tags
+
+        out, csums, tags = await service.encode(ec_impl, stripes,
+                                                finish=finish)
         if sp is not None:
-            # what follows the rider's future on the loop: with
-            # offload_queue_wait, the batch's stack_us and offload_batch
-            # it makes up this span but for the rider's wait for its turn
-            sp.set_tag("assemble_us",
-                       round((time.perf_counter() - t0) * 1e6, 1))
-        return out
+            sp.tags.update(tags)
+        return out, csums
 
 
 def _reconstruct_stack(ec_impl, stacked: Mapping[int, np.ndarray],

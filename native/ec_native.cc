@@ -448,4 +448,17 @@ int frame_verify_body(const uint8_t* body, const uint64_t* seg_lens,
   return -1;
 }
 
+// De-interleave: planes [first, first + count) of `src`, S stripes of n
+// chunks of C bytes each, into `dst`, count planes of S * C bytes one
+// after another: a shard's chunks of every stripe made contiguous, for
+// any run of shards in one call (and so in one stretch without the GIL).
+void planes_from_stripes(const uint8_t* src, size_t S, size_t n, size_t C,
+                         size_t first, size_t count, uint8_t* dst) {
+  for (size_t s = 0; s < S; s++) {
+    const uint8_t* row = src + (s * n + first) * C;
+    for (size_t p = 0; p < count; p++)
+      memcpy(dst + (p * S + s) * C, row + p * C, C);
+  }
+}
+
 }  // extern "C"

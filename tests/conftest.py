@@ -178,7 +178,7 @@ def _offload_defaults_restored():
     (`rb4m_scrub_seqread`: `ec_offload_crc_device`; the accepted tests
     under tests/benchmarks/ serve every cell of BENCHMARK.json) must not
     hand it to the next file on its worker, where `test_chip_smoke`
-    counts the writes' CrcJobs on the host lane."""
+    serves at the defaults."""
     from ceph_tpu.offload import service
     kept = dict(service._DEFAULTS)
     yield

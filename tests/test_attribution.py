@@ -524,9 +524,11 @@ def test_device_batches_and_fallback_attribution():
 
 
 def test_offload_batch_hops_sum_to_the_span():
-    """The six hops `_device_call` and `_run_batch` stamp on the batch
+    """The eight hops `_device_call` and `_run_batch` stamp on the batch
     span from where the work happens, without serializing anything,
-    account for its duration; the two before it are tags as well."""
+    account for its duration, the batch's two host copies in the
+    staging pool (`stack_us`, `finish_us`) among them; the semaphore
+    wait before it is a tag as well."""
     from ceph_tpu.offload.service import _HOPS
     from ceph_tpu.osd import ec_util
 
@@ -557,11 +559,17 @@ def test_offload_batch_hops_sum_to_the_span():
         inside = sum(tags[h] for h in _HOPS + ("scatter_us",))
         assert inside == pytest.approx(s["duration_us"], rel=0.02)
         assert all(tags[h] >= 0 for h in _HOPS + ("scatter_us",))
-        assert tags["sem_wait_us"] >= 0 and tags["stack_us"] >= 0
+        assert tags["sem_wait_us"] >= 0
+        assert {"stack_us", "finish_us"} < set(_HOPS)
         assert tags["device"].startswith("cpu:")
     enc = [s for s in spans if s["name"] == "ec_encode"]
     assert len(enc) == 1 and enc[0]["tags"]["assemble_us"] >= 0
     assert enc[0]["tags"]["assemble_us"] < enc[0]["duration_us"]
+    # four stripes of 4 + 2 chunks: the rider's six planes are strided
+    # and copied by its finisher, inside the batch's finish_us
+    assert enc[0]["tags"]["copy_bytes"] == 6 * 4 * 4096
+    assert 0 < enc[0]["tags"]["copy_us"] <= enc[0]["tags"]["assemble_us"] \
+        <= batches[-1]["tags"]["finish_us"]
 
 
 # ---------------------------------------------------------------------------

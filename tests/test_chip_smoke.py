@@ -56,9 +56,7 @@ def test_check_served_fails_on_host_fallback():
     ok = {"offload": {"batches": 4, "fallback_ops": 0, "breaker_trips": 0,
                       "device_failovers": 0, "degraded": False,
                       "kernel_gb_s": {"enc": 1.0, "dec": 1.0}},
-          "devices": {"tpu:0": {"ops": 6, "bytes": 100},
-                      "host": {"ops": 4, "bytes": 50}},
-          "crc_jobs": {"ops": 4, "bytes": 50},
+          "devices": {"tpu:0": {"ops": 6, "bytes": 100}},
           "native_frames": True}
     chip_smoke.check_served(ok, "tpu", min_device_bytes=100)
     bad = dict(ok, offload=dict(ok["offload"], fallback_ops=2,
@@ -67,7 +65,7 @@ def test_check_served_fails_on_host_fallback():
         chip_smoke.check_served(bad, "tpu", min_device_bytes=100)
     with pytest.raises(AssertionError, match="expected"):
         chip_smoke.check_served(ok, "tpu", min_device_bytes=101)
-    # anything but the writes' CrcJobs on the host lane
+    # anything on the host lane: a write's checksums ride its encode
     bad = dict(ok, devices=dict(ok["devices"], host={"ops": 5, "bytes": 60}))
     with pytest.raises(AssertionError, match="host lane holds"):
         chip_smoke.check_served(bad, "tpu", min_device_bytes=100)

@@ -338,11 +338,11 @@ def test_device_stats_and_exporter_rows_for_every_mesh_device(monkeypatch):
         svc.device_spill_threshold = 1
         svc.max_batch_bytes = 4096           # every submit flushes
 
-        from ceph_tpu.offload.service import _host_apply
+        orig = svc._device_call
 
-        async def slow(slot, fn, stacked, sp=None):
+        async def slow(*args):
             await asyncio.sleep(0.05)        # keep slots busy to rotate
-            return _host_apply(impl.coding_matrix, stacked)
+            return await orig(*args)
         monkeypatch.setattr(svc, "_device_call", slow)
         try:
             # 16 distinct bucket keys (one per chunk size) in flight at
