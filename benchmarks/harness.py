@@ -27,7 +27,7 @@ from benchmarks import reference
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
-CONTROLS = ("flip_read", "bitrot")
+CONTROLS = ("flip_read", "bitrot", "torn_store")
 SAMPLED = 8
 OP_KINDS = ("write", "read")
 STORES = ("memstore", "filestore", "bluestore")
@@ -145,21 +145,54 @@ class _FlightWatch:
             await asyncio.sleep(0.5)
 
 
-def _shard_blobs(osds, pool: str, oid: str) -> dict[int, bytes]:
-    """shard index -> the blob that shard's OSD holds for `oid` (copied
-    from chip_smoke.py)."""
-    blobs: dict[int, bytes] = {}
+def _shard_places(osds, pool: str, oid: str):
+    """(osd, shard index, collection, object) for each place where one
+    of `osds` would hold a shard of `oid`."""
     for osd in osds:
         for pg in osd.pgs.values():
             if pg.pool.name != pool:
                 continue
             if osd.whoami not in pg.acting:
                 continue        # a PG it has left: marked out, or a spare
-            cid, gh = pg.backend.coll(), pg.backend.ghobject(oid)
-            if osd.store.exists(cid, gh):
-                blobs[pg.acting.index(osd.whoami)] = bytes(
-                    osd.store.read(cid, gh))
-    return blobs
+            yield (osd, pg.acting.index(osd.whoami), pg.backend.coll(),
+                   pg.backend.ghobject(oid))
+
+
+def _shard_blobs(osds, pool: str, oid: str) -> dict[int, bytes]:
+    """shard index -> the blob that shard's OSD holds for `oid` (copied
+    from chip_smoke.py)."""
+    return {shard: bytes(osd.store.read(cid, gh))
+            for osd, shard, cid, gh in _shard_places(osds, pool, oid)
+            if osd.store.exists(cid, gh)}
+
+
+def _shards_differ(blobs: dict[int, bytes], values: list[bytes], k: int,
+                   m: int, chunk: int, absent_counts: bool) -> int:
+    """Bytes by which the shards in `blobs` differ from the reference's
+    for the candidate value that fits them best. A shard that `blobs`
+    lacks counts its whole length where `absent_counts`."""
+    best = None
+    for value in values:
+        want = reference.expected_shards(value, k, m, chunk)
+        diff = 0
+        for shard in range(k + m):
+            if shard not in blobs:
+                diff += want.shape[1] if absent_counts else 0
+                continue
+            have = np.frombuffer(blobs[shard], dtype=np.uint8)
+            diff += want.shape[1] if have.size != want.shape[1] else \
+                int(np.count_nonzero(have != want[shard]))
+        best = diff if best is None else min(best, diff)
+    return best
+
+
+def _sample(model, seed: int) -> list[str]:
+    """The objects compared once the window has closed, drawn from the
+    seed."""
+    rng = np.random.default_rng([seed, 5])
+    names = model.names()
+    return [names[i] for i in sorted(rng.choice(
+        len(names), size=min(SAMPLED, len(names)), replace=False))]
 
 
 def _flip_a_bit(data: bytes) -> bytes:
@@ -216,6 +249,7 @@ class _Run:
         self.compiles: list[float] = []     # perf_counter of each event
         self.events: list[dict] = []        # the schedule's, as each ended
         self.store_dirs: list[str] = []     # a persistent store's, to remove
+        self.store_dir_bytes: int | None = None   # what they held at the end
 
 
 async def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
@@ -228,6 +262,9 @@ async def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     for c in control:
         if c not in CONTROLS:
             raise SystemExit(f"benchmark: unknown control {c!r}")
+    if "torn_store" in control and cell.config["objectstore"] == "memstore":
+        raise SystemExit("benchmark: control torn_store wants a persistent "
+                         "store and the configuration's is memstore")
     device = jax.devices()[0]
     os.makedirs(out_dir, exist_ok=True)
     run = _Run()
@@ -542,6 +579,11 @@ async def _run_cell(cell, seed, seconds, trace, out_dir, t_start, control,
                        "seconds": seconds, "trace": int(trace),
                        "window_s": window_s, "setup_s": run.setup_s, **ser},
                       f)
+        if factory is not None:
+            # last, since it stops the daemons: nothing reads them after
+            checks.append(await lost_on_remount(
+                gen, model, osds, pool, seed, control, run, k, m, chunk,
+                stopped))
 
     correct = all(value <= limit for _n, value, limit in checks)
     stats = device.memory_stats() or {}
@@ -567,6 +609,7 @@ async def _run_cell(cell, seed, seconds, trace, out_dir, t_start, control,
             "compile_events": len(run.compiles),
             "convoy": ser["completions"][:8],
             "events": run.events,
+            "store_dir_bytes": run.store_dir_bytes,
             "failures": run.failures[:5]}
     out = {"correct": correct, "attempted": len(started),
            "failed": sum(not r[3] for r in started),
@@ -737,6 +780,72 @@ async def stop_osds(n: int, seed: int, osds, client) -> list[int]:
     return dead
 
 
+def _dir_bytes(path: str) -> int:
+    return sum(os.path.getsize(os.path.join(d, f))
+               for d, _dirs, files in os.walk(path) for f in files)
+
+
+def _tear(path: str) -> None:
+    """Control `torn_store`: every file under a store's directory loses
+    its second half (the block file, the KV's runs and log, a journal,
+    a blob), as if what was acknowledged had never reached them."""
+    for d, _dirs, files in os.walk(path):
+        for f in files:
+            full = os.path.join(d, f)
+            os.truncate(full, os.path.getsize(full) // 2)
+
+
+async def lost_on_remount(gen, model, osds, pool, seed, control, run,
+                          k, m, chunk, stopped) -> tuple:
+    """A persistent store's guarantee, as far as a run can show it: an
+    acknowledged write is on the store. The running daemons are stopped
+    as a kill would stop them (no `umount`; each keeps its store object,
+    so nothing in user space is closed and flushed on the way), a new
+    store of the same class is mounted on each directory, and the
+    sampled objects' shards are read from it and compared with the
+    reference's, as the live ones were. Returns the check row: the
+    bytes that differ or cannot be read, limit 0."""
+    from ceph_tpu.utils.async_util import bounded_stop
+
+    live = [o for o in osds if o.whoami not in stopped]
+    sample = _sample(model, seed)
+    places = {name: [(osd, shard, cid, gh)
+                     for osd, shard, cid, gh in _shard_places(live, pool, name)
+                     if osd.store.exists(cid, gh)]
+              for name in sample}
+    for osd in live:
+        osd.store.umount = lambda: None     # a kill unmounts nothing
+        await bounded_stop(osd.stop(), 60.0)
+    run.store_dir_bytes = sum(_dir_bytes(osd.store.path) for osd in live)
+    if "torn_store" in control:
+        holder = places[sample[0]][0][0]
+        _tear(holder.store.path)
+    fresh: dict[int, object] = {}
+    for osd in live:
+        try:
+            fresh[osd.whoami] = type(osd.store)(osd.store.path)
+            fresh[osd.whoami].mount()
+        except Exception as e:      # a store that does not mount holds nothing
+            run.failures.append(f"remount osd.{osd.whoami}: {e!r}")
+            fresh[osd.whoami] = None
+    lost = 0
+    for name in sample:
+        blobs: dict[int, bytes] = {}
+        for osd, shard, cid, gh in places[name]:
+            blobs[shard] = b""      # unreadable: every byte of it is lost
+            if fresh[osd.whoami] is None:
+                continue
+            try:
+                blobs[shard] = bytes(fresh[osd.whoami].read(cid, gh))
+            except Exception as e:
+                run.failures.append(f"remount osd.{osd.whoami} shard "
+                                    f"{shard} of {name}: {e!r}")
+        values = [gen.value_of(name, v) for v in model.candidates(name)]
+        lost += _shards_differ(blobs, values, k, m, chunk,
+                               absent_counts=False)
+    return ("shard_bytes_lost_on_remount", lost, 0)
+
+
 async def final_checks(cell, gen, model, io, osds, pool, seed, control, run,
                        k, m, chunk, stopped, watch, svc,
                        platform, harness_stopped) -> list[tuple]:
@@ -745,10 +854,7 @@ async def final_checks(cell, gen, model, io, osds, pool, seed, control, run,
     counters. Returns (name, value, limit) rows; all limits are exact.
     `stopped` are the OSDs not running now; `harness_stopped` those the
     harness stopped at any time, whose mark-downs are its own doing."""
-    rng = np.random.default_rng([seed, 5])
-    names = model.names()
-    sample = [names[i] for i in sorted(rng.choice(
-        len(names), size=min(SAMPLED, len(names)), replace=False))]
+    sample = _sample(model, seed)
     if "bitrot" in control:
         # the program's own fault path: one byte of one shard at rest
         for osd in osds:
@@ -769,21 +875,10 @@ async def final_checks(cell, gen, model, io, osds, pool, seed, control, run,
             sample_mismatches += 1
         blobs = _shard_blobs([o for o in osds if o.whoami not in stopped],
                              pool, name)
-        best = None
-        for value in values:
-            want = reference.expected_shards(value, k, m, chunk)
-            diff = 0
-            for shard in range(k + m):
-                if shard not in blobs:
-                    # a shard may be missing only with an OSD stopped
-                    # (its own, or one whose shards a spare now takes)
-                    diff += 0 if stopped else want.shape[1]
-                    continue
-                have = np.frombuffer(blobs[shard], dtype=np.uint8)
-                diff += want.shape[1] if have.size != want.shape[1] else \
-                    int(np.count_nonzero(have != want[shard]))
-            best = diff if best is None else min(best, diff)
-        shard_bytes_differing += best
+        # a shard may be missing only with an OSD stopped (its own, or
+        # one whose shards a spare now takes)
+        shard_bytes_differing += _shards_differ(blobs, values, k, m, chunk,
+                                                absent_counts=not stopped)
     off = svc.stats
     devices = svc.device_snapshot()
     on_device = sum(s["bytes"] for d, s in devices.items()

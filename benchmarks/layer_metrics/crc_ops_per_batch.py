@@ -1,7 +1,9 @@
 """Crc jobs per device batch: the offload service's `crc_jobs` over
 `crc_batches` (device lanes only), deltas over the window. A job is one
-scan chunk of one OSD (a write's checksums too, in a cell that writes);
-the members of a PG scan at once, so their jobs can share a batch."""
+scan chunk of one OSD; the members of a PG scan at once, so their jobs
+can share a batch. No write's checksums are in it since PR 43 (an
+encode's finisher makes them): only `ec_offload_crc_device` and scrub
+make a crc job."""
 NAME = "crc_ops_per_batch"
 UNIT = "jobs/batch"
 LAYER = "offload/service"

@@ -1,5 +1,9 @@
 """Median `ec_encode` span: from the primary handing stripes to the
-codec until parity is back (batcher wait, H2D, kernel, D2H)."""
+codec until the shards are back (batcher wait, stacking copy, H2D,
+kernel, D2H, the shard planes' assembly). Since PR 43 it also holds
+each shard's crc32c by block, made in the batch's finisher on the
+staging-pool thread: the crc job that used to follow the span is gone,
+so the span is longer where the op is not."""
 import statistics
 
 NAME = "ec_encode_ms"

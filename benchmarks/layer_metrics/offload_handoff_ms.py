@@ -1,7 +1,10 @@
 """Median per `offload_batch` of the three waits between its four hops:
 `sem_wait_us` (the slot semaphore), `pool_wait_us` (until the
 staging-pool thread starts) and `resume_us` (until the coroutine runs
-again on the loop). Nothing computes in them."""
+again on the loop). Nothing computes in them. Since PR 43 the pool's
+thread also makes a batch's stacking copy and its riders' finishers,
+so `pool_wait_us` grows with a busier lane: a rise here beside a fall
+of `loop_offload_pct` is work that left the loop, not a slower hop."""
 import statistics
 
 NAME = "offload_handoff_ms"

@@ -39,20 +39,25 @@ def _counters(carried, framed, **more):
                 ctrl_frames_tx=framed + 3, frames_tx=5, **more)
 
 
-def test_the_entry_stands_after_the_fastread_cells_six_and_is_the_parents():
+def entries_stand(bench):
     """PR 37 appended it after the entries of the cell that reads fast;
     PR 41 took that cell's seven renamed readers out from between, and
     four entries before them, so the place is found by name; a later
     PR's entries come after."""
-    names = [m["name"] for m in BENCH["per_layer"]]
+    entries = bench["per_layer"]
+    names = [m["name"] for m in entries]
     at = names.index(NAME)
-    assert BENCH["per_layer"][at] == ENTRY
+    assert entries[at] == ENTRY
     assert names[at - 6:at] == fastread_cell.NEW
     assert names[at + 1] == "loop_cpu_ms_per_op"
     mod = _reader(NAME)
     assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == (
         NAME, "%", "msg/messenger", "ops_s")
-    assert "msg/messenger" in {m["layer"] for m in BENCH["per_layer"][:at]}
+    assert "msg/messenger" in {m["layer"] for m in entries[:at]}
+
+
+def test_the_entry_stands_after_the_fastread_cells_six_and_is_the_parents():
+    entries_stand(BENCH)
 
 
 @pytest.mark.parametrize("case", ["no_counters", "one_counter_missing",

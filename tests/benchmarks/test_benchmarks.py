@@ -40,38 +40,48 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 # -- BENCHMARK.json and the files it names ---------------------------------------
 
-def test_benchmark_json_keeps_the_contract():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+def contract_holds(bench, root=ROOT):
+    """What BENCHMARK.json has to keep, of any copy of it: the file as
+    it stands, and one with a later PR's entries appended
+    (`test_appended_entries.py`)."""
+    cells = [w["name"] for w in bench["workloads"]]
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    assert BENCH["paths"] == ["benchmarks", "tests/benchmarks"]
-    assert 1 <= BENCH["run_seconds"] <= 51
-    names = [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]]
+    assert bench["paths"] == ["benchmarks", "tests/benchmarks"]
+    assert 1 <= bench["run_seconds"] <= 51
+    end_to_end = [m["name"] for m in bench["end_to_end"]]
+    names = end_to_end + [m["name"] for m in bench["per_layer"]]
     assert len(names) == len(set(names))
-    assert [m["name"] for m in BENCH["end_to_end"]] == \
-        ["ops_s", "op_p50_ms", "op_p95_ms", "setup_s"]
-    for m in BENCH["end_to_end"]:
+    assert end_to_end == ["ops_s", "op_p50_ms", "op_p95_ms", "setup_s"]
+    for m in bench["end_to_end"]:
         assert set(m) <= {"name", "unit", "better", "bound", "source",
                           "workloads"}
         assert 0.01 <= m["bound"] <= 0.25
         assert m["source"] in ("host_clock", "device_trace")
-    layers = {m["name"]: m for m in BENCH["per_layer"]}
-    for m in layers.values():
+    for m in bench["per_layer"]:
         assert set(m) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-        assert m["moves"] in names[:4]
-        assert set(m.get("workloads", CELLS)) <= set(CELLS)
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert m["moves"] in end_to_end
+        assert set(m.get("workloads", cells)) <= set(cells)
+    for m in bench["end_to_end"] + bench["per_layer"]:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
         assert m["better"] in ("lower", "higher")
-    for c in BENCH["configs"]:
+    assert len(cells) == len(set(cells))
+    configs = [c["name"] for c in bench["configs"]]
+    assert len(configs) == len(set(configs))
+    for c in bench["configs"]:
         assert c["file"].startswith("benchmarks/")
-        body = json.load(open(os.path.join(ROOT, c["file"])))
+        body = json.load(open(os.path.join(root, c["file"])))
         assert sorted(body["reduced"]) == sorted(c["reduced"])
         assert all(key in body for key in c["reduced"])
-        assert any(w["config"] == c["name"] for w in BENCH["workloads"])
-    for w in BENCH["workloads"]:
+        assert any(w["config"] == c["name"] for w in bench["workloads"])
+    for w in bench["workloads"]:
         assert NAME.match(w["name"]) and len(w["why"]) <= 200
-        assert w["chips"] == 1
+        assert w["chips"] == 1 and w["config"] in configs
+
+
+def test_benchmark_json_keeps_the_contract():
+    contract_holds(BENCH)
 
 
 @pytest.mark.parametrize("cell", CELLS + SHELVED)
@@ -86,34 +96,76 @@ def test_cell_resolves_to_its_files(cell, with_ycsb):
     assert always <= {r.NAME for r in c.readers}
 
 
-def test_later_pr_adds_a_cell_with_new_files_only(tmp_path):
-    """benchmarks/README.md's worked example: `rb4m_degraded_read` is a
-    traffic file and one `workloads` entry; nothing that exists is
-    edited. A new generator and a new reader resolve the same way."""
-    shutil.copytree(os.path.join(ROOT, "benchmarks"),
-                    tmp_path / "benchmarks",
+def appended_copy(tmp):
+    """A copy of the benchmark under `tmp` with a later PR's entries
+    **appended at the end** and its files beside the accepted ones,
+    nothing that exists edited: a configuration on a persistent store
+    with a `mon_config`, a cell of it whose traffic has `events`, and
+    two per-layer entries (one that lists the new cell, one in every
+    cell). Returns `(root, bench)`."""
+    shutil.copytree(os.path.join(ROOT, "benchmarks"), tmp / "benchmarks",
                     ignore=shutil.ignore_patterns("__pycache__"))
     bench = json.loads(json.dumps(BENCH))
+    config = json.load(open(os.path.join(
+        ROOT, "benchmarks", "configs", "radosbench_ec83_tpu.json")))
+    config.update(name="radosbench_ec83_tpu_bluestore",
+                  objectstore="bluestore",
+                  mon_config={"osd_pool_default_ec_fast_read": False},
+                  reduced={k: v for k, v in config["reduced"].items()
+                           if k != "objectstore"})
+    config["guarantees"]["durability"] = \
+        "an acknowledged write is read back from a fresh mount of the store"
+    (tmp / "benchmarks/configs/radosbench_ec83_tpu_bluestore.json"
+     ).write_text(json.dumps(config))
+    bench["configs"].append({
+        "name": config["name"], "source": config["source"] + "; BlueStore",
+        "file": "benchmarks/configs/radosbench_ec83_tpu_bluestore.json",
+        "reduced": sorted(config["reduced"]), "why": "see README"})
     bench["workloads"].append({
-        "name": "rb4m_degraded_read", "config": "radosbench_ec83_tpu",
-        "traffic": "rb4m_degraded_read", "chips": 1, "why": "see README"})
-    bench["per_layer"].append({
-        "name": "decode_ops", "unit": "count", "better": "higher",
-        "source": "program_counter", "layer": "offload/service",
-        "moves": "ops_s", "workloads": ["rb4m_degraded_read"]})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    (tmp_path / "benchmarks/traffic/rb4m_degraded_read.json").write_text(
-        json.dumps({"op": "seq", "clients": 16, "preload_objects": 128,
-                    "warmup_ops": 64, "payload_pool": 64, "stop_osds": 3}))
-    (tmp_path / "benchmarks/layer_metrics/decode_ops.py").write_text(
-        'NAME = "decode_ops"\nUNIT = "count"\nLAYER = "offload/service"\n'
-        'MOVES = "ops_s"\n\n\ndef read(ctx):\n    return None\n')
-    cell = harness.load_cell("rb4m_degraded_read", root=str(tmp_path))
-    assert cell.traffic["stop_osds"] == 3
-    assert "decode_ops" in {r.NAME for r in cell.readers}
-    assert "ec_encode_ms" not in {r.NAME for r in cell.readers}
+        "name": "rb4m_restart_write", "config": config["name"],
+        "traffic": "rb4m_restart_write", "chips": 1, "why": "see README"})
+    (tmp / "benchmarks/traffic/rb4m_restart_write.json").write_text(
+        json.dumps({"op": "write", "clients": 16, "preload_objects": 128,
+                    "warmup_ops": 64, "payload_pool": 64, "events": [
+                        {"at_s": 4, "do": "stop_osd", "osd": 0},
+                        {"at_s": 24, "do": "start_osd", "osd": 0}]}))
+    for name, only in (("restart_ops", ["rb4m_restart_write"]),
+                       ("store_fsyncs_per_op", None)):
+        entry = {"name": name, "unit": "count", "better": "lower",
+                 "source": "program_counter", "layer": "objectstore",
+                 "moves": "ops_s"}
+        if only:
+            entry["workloads"] = only
+        bench["per_layer"].append(entry)
+        (tmp / f"benchmarks/layer_metrics/{name}.py").write_text(
+            f'NAME = "{name}"\nUNIT = "count"\nLAYER = "objectstore"\n'
+            'MOVES = "ops_s"\n\n\ndef read(ctx):\n    return None\n')
+    (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
+    return str(tmp), bench
+
+
+def test_later_pr_adds_a_cell_with_new_files_only(tmp_path):
+    """benchmarks/README.md's worked example: a configuration, a cell
+    and two readers are files of their own and entries appended at the
+    end; nothing that exists is edited, and every accepted cell loads
+    what it loaded but for the reader that lists every cell."""
+    root, bench = appended_copy(tmp_path)
+    cell = harness.load_cell("rb4m_restart_write", root=root)
+    assert cell.config["objectstore"] == "bluestore"
+    assert cell.config["mon_config"] == {
+        "osd_pool_default_ec_fast_read": False}
+    assert [e["do"] for e in harness.schedule_of(cell.traffic)] == [
+        "stop_osd", "start_osd"]
+    assert callable(harness.store_factory(cell.config["objectstore"], []))
+    names = {r.NAME for r in cell.readers}
+    assert {"restart_ops", "store_fsyncs_per_op"} <= names
+    assert "ec_encode_ms" not in names
+    for name in CELLS:
+        before = [r.NAME for r in harness.load_cell(name).readers]
+        after = [r.NAME for r in harness.load_cell(name, root=root).readers]
+        assert after == before + ["store_fsyncs_per_op"]
     with pytest.raises(SystemExit):
-        harness.load_cell("no_such_cell", root=str(tmp_path))
+        harness.load_cell("no_such_cell", root=root)
 
 
 # -- generators ----------------------------------------------------------------------
@@ -260,19 +312,54 @@ def test_latency_arithmetic():
 
 # -- a whole run, tiny ---------------------------------------------------------------------
 
-def _tiny(cell_name, trace=False, control=(), seconds=0.6, tmp=".",
-          root=ROOT):
-    cell = harness.load_cell(cell_name, root=root)
-    cell.config = dict(cell.config, osds=3, object_size=65536,
+def shrink(cell, seconds, run_seconds=BENCH["run_seconds"]):
+    """`cell` cut, in place, to the smallest deployment its own files
+    leave it: 2+1 on three OSDs, 64 KiB objects, four clients; where
+    the mix stops OSDs under writes, as many parities more (a pool
+    takes no write below `min_size`, k + 1), and an OSD to spare for
+    each that an event marks out. A schedule keeps its place in the
+    window (`at_s` by `seconds / run_seconds`); with one, a device
+    batch is bounded by its bytes at the clients' four objects, as the
+    chip's is at two, so that work beside the clients (recovery's
+    decodes) meets no shape set-up did not warm."""
+    events = cell.traffic.get("events", [])
+    down = len(set(range(cell.traffic.get("stop_osds", 0)))
+               | {e["osd"] for e in events if e["do"] == "stop_osd"})
+    spares = len({e["osd"] for e in events if e["do"] == "osd_out"})
+    m = 1 + (0 if cell.traffic.get("op") == "seq" else down)
+    cell.config = dict(cell.config, osds=2 + m + spares, object_size=65536,
                        recordcount=40,
-                       pool=dict(cell.config["pool"], k=2, m=1, pg_num=8))
+                       pool=dict(cell.config["pool"], k=2, m=m, pg_num=8))
     cell.traffic = dict(cell.traffic, clients=4, warmup_ops=8,
                         payload_pool=4)
     if cell.traffic.get("preload_objects"):
         cell.traffic["preload_objects"] = 8
-    return asyncio.run(harness.run_cell(
-        cell, 2 ** 31 + 5, seconds, trace, str(tmp), time.monotonic(),
-        control)), cell
+    if events:
+        cell.traffic["events"] = [
+            dict(e, at_s=e["at_s"] * seconds / run_seconds) for e in events]
+        cell.config["osd_config"] = dict(
+            cell.config["osd_config"], ec_offload_max_batch_bytes=4 * 65536)
+    return cell
+
+
+def run_tiny(cell, trace=False, control=(), seconds=0.6, tmp="."):
+    """One run of a cell that `shrink` has cut; the offload service's
+    defaults, which a cell's `osd_config` may turn, are put back."""
+    from ceph_tpu.offload import service
+
+    kept = dict(service._DEFAULTS)
+    try:
+        return asyncio.run(harness.run_cell(
+            cell, 2 ** 31 + 5, seconds, trace, str(tmp), time.monotonic(),
+            control))
+    finally:
+        service._DEFAULTS.update(kept)
+
+
+def _tiny(cell_name, trace=False, control=(), seconds=0.6, tmp=".",
+          root=ROOT):
+    cell = shrink(harness.load_cell(cell_name, root=root), seconds)
+    return run_tiny(cell, trace, control, seconds, tmp), cell
 
 
 def test_tiny_run_prints_the_contracts_keys(tmp_path, with_ycsb):

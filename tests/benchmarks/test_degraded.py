@@ -135,6 +135,30 @@ def test_a_decode_runs_the_encode_program_of_its_shape(code):
 
 # -- the configuration and its traffic ----------------------------------------
 
+def entries_stand(bench, root=ROOT):
+    """The configuration, the cell and the seven entries, each found by
+    its name: they name this cell; since PR 41 the six that read a
+    decode name the cell that reads fast too, and two accepted entries
+    before them (the loop's share in the batcher, the median `ec_read`)
+    name this one, which has had that work all along. A later cell may
+    join any of the lists."""
+    entry = {c["name"]: c for c in bench["configs"]}[CONFIG]
+    assert len(entry["source"]) <= 200
+    cell = {w["name"]: w for w in bench["workloads"]}[CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        (CONFIG, CELL, 1)
+    entries = bench["per_layer"]
+    names = [m["name"] for m in entries]
+    at = names.index(NEW[0])
+    assert names[at:at + 7] == NEW
+    assert all(CELL in m["workloads"] for m in entries[at:at + 7])
+    assert [m["name"] for m in entries[:at]
+            if CELL in m.get("workloads", [])] == [
+        "loop_offload_pct", "ec_read_ms"]
+    loaded = {r.NAME for r in harness.load_cell(CELL, root=root).readers}
+    assert set(NEW) | {"loop_offload_pct", "ec_read_ms"} <= loaded
+
+
 def test_configuration_and_traffic_describe_one_failure():
     entry = {c["name"]: c for c in BENCH["configs"]}[CONFIG]
     config = json.load(open(os.path.join(ROOT, entry["file"])))
@@ -158,18 +182,12 @@ def test_configuration_and_traffic_describe_one_failure():
 
 
 def test_the_seven_entries_are_appended_for_this_cell_first():
-    """They name this cell first; since PR 41 the six that read a
-    decode name the cell that reads fast too, and two accepted entries
-    before them (the loop's share in the batcher, the median `ec_read`)
-    name this one, which has had that work all along."""
-    names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[26:33] == NEW
-    for m in BENCH["per_layer"][26:33]:
-        assert m["workloads"] in ([CELL], [CELL, "rb4m_fastread_seqread"])
-    assert BENCH["per_layer"][26]["workloads"] == [CELL]
-    assert [m["name"] for m in BENCH["per_layer"][:26]
-            if CELL in m.get("workloads", [])] == [
-        "loop_offload_pct", "ec_read_ms"]
+    entries_stand(BENCH)
+    by = {m["name"]: m for m in BENCH["per_layer"]}
+    # the share of reads that reconstruct is this cell's alone: where
+    # reads are fast, `fastread_decode_pct` is that share
+    assert "rb4m_fastread_seqread" not in by["degraded_read_pct"][
+        "workloads"]
 
 
 def test_mon_keeps_a_down_osd_in_for_upstreams_ten_minutes():

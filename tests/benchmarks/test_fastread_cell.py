@@ -36,32 +36,51 @@ def _reader(name):
 
 # -- BENCHMARK.json and the files it names --------------------------------------------
 
-def test_the_entries_stand_in_their_order_after_what_stood_before_them():
+def entries_stand(bench, root=ROOT):
     """PR 35 appended them after the stores' two shares (PR 34); PR 41
     took out four entries before them and this cell's seven renamed
     readers after, so the place is found by name. Three configurations
-    and four cells stood before this one."""
-    names = [m["name"] for m in BENCH["per_layer"]]
+    and four cells stood before this one; a later PR's come after, and
+    a later cell may join a list."""
+    entries = bench["per_layer"]
+    names = [m["name"] for m in entries]
     at = names.index(NEW[0])
     assert names[at - 2:at] == ["store_write_direct_pct",
                                 "store_read_direct_pct"]
     assert names[at:at + 6] == NEW
     assert names[at + 6] == "msgr_acks_carried_pct"
-    for m in BENCH["per_layer"][at:at + 6]:
-        assert m["workloads"] == [CELL]
+    for m in entries[at:at + 6]:
+        assert CELL in m["workloads"]
         mod = _reader(m["name"])
         assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
             (m["name"], m["unit"], m["layer"], m["moves"])
-    assert [m["name"] for m in BENCH["per_layer"][:at]
+    assert [m["name"] for m in entries[:at]
             if CELL in m.get("workloads", [])] == FOLDED
-    layers = {m["layer"] for m in BENCH["per_layer"][:at]}
-    assert {m["layer"] for m in BENCH["per_layer"][at:at + 6]} <= layers
-    assert [c["name"] for c in BENCH["configs"]][:4] == [
+    layers = {m["layer"] for m in entries[:at]}
+    assert {m["layer"] for m in entries[at:at + 6]} <= layers
+    assert [c["name"] for c in bench["configs"]][:4] == [
         "radosbench_ec83_tpu", "radosbench_ec83_tpu_degraded",
         "radosbench_ec83_tpu_scrub", CONFIG]
-    assert [w["name"] for w in BENCH["workloads"]][:5] == [
+    assert [w["name"] for w in bench["workloads"]][:5] == [
         "rb4m_write", "rb4m_seqread", "rb4m_degraded_seqread",
         "rb4m_scrub_seqread", CELL]
+    entry = {c["name"]: c for c in bench["configs"]}[CONFIG]
+    assert len(entry["source"]) <= 200 and len(entry["why"]) <= 200
+    cell = {w["name"]: w for w in bench["workloads"]}[CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        (CONFIG, CELL, 1)
+    # the cell loads its readers, and no other cell loads them
+    loaded = {r.NAME for r in harness.load_cell(CELL, root=root).readers}
+    assert set(NEW + FOLDED) <= loaded
+    for w in bench["workloads"]:
+        if w["name"] != CELL:
+            other = {r.NAME for r in harness.load_cell(
+                w["name"], root=root).readers}
+            assert not other & set(NEW)
+
+
+def test_the_entries_stand_in_their_order_after_what_stood_before_them():
+    entries_stand(BENCH)
 
 
 def test_configuration_and_traffic_hold_the_deployments_keys():
@@ -250,10 +269,11 @@ def test_an_accepted_entry_lists_this_cell(name):
     """The degraded cell's readers and `ec_read_ms` list their cells.
     Until PR 41 this one reported them as `<name>.fastread` through
     readers of its own that imported the accepted ones; now the
-    accepted entries name the cell, after the cells they named before,
-    and neither those entries nor those files are left."""
+    accepted entries name the cell, after the cells they named before
+    (a later cell may come after it), and neither those entries nor
+    those files are left."""
     by = {m["name"]: m for m in BENCH["per_layer"]}
-    assert by[name]["workloads"][-1] == CELL
+    assert CELL in by[name]["workloads"]
     assert "rb4m_degraded_seqread" in by[name]["workloads"]
     assert name + ".fastread" not in by
     with pytest.raises(SystemExit):

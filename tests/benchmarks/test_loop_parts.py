@@ -46,25 +46,31 @@ def _ctx(ops, *slices):
 
 # -- the entries ---------------------------------------------------------------
 
-def test_the_sixteen_entries_stand_in_their_order_after_the_acks_share():
+def entries_stand(bench):
     """PR 39 appended them after `msgr_acks_carried_pct`; PR 41 took
     eleven entries out before them, so the place is found by name, and
     a later PR's entries come after."""
-    names = [m["name"] for m in BENCH["per_layer"]]
+    entries = bench["per_layer"]
+    names = [m["name"] for m in entries]
     at = names.index(WHOLE)
-    added = BENCH["per_layer"][at:at + 16]
+    added = entries[at:at + 16]
     assert [m["name"] for m in added] == list(READERS)
     for m in added:
         layer, _part = READERS[m["name"]]
         want = {"name": m["name"], "unit": "ms/op", "better": "lower",
                 "source": "program_span", "layer": layer, "moves": "ops_s"}
         if m["name"] == SCRUB:
-            want["workloads"] = ["rb4m_scrub_seqread"]
+            assert "rb4m_scrub_seqread" in m["workloads"]
+            want["workloads"] = m["workloads"]
         assert m == want
     assert names[at - 1] == "msgr_acks_carried_pct"
     assert not any(n.endswith("_ms_per_op") for n in names[:at])
     assert {layer for layer, _ in READERS.values()} <= \
-        {m["layer"] for m in BENCH["per_layer"][:at]}
+        {m["layer"] for m in entries[:at]}
+
+
+def test_the_sixteen_entries_stand_in_their_order_after_the_acks_share():
+    entries_stand(BENCH)
 
 
 @pytest.mark.parametrize("name", READERS)
@@ -123,8 +129,6 @@ def test_a_part_the_account_does_not_know_reads_nothing():
 def traced(request, tmp_path_factory):
     """One traced run of the cell through `_tiny`, with what the readers
     were given (`harness.Ctx`) caught on the way."""
-    from ceph_tpu.offload import service
-
     seen: dict = {}
     real = harness.Ctx
 
@@ -132,13 +136,11 @@ def traced(request, tmp_path_factory):
         seen["ctx"] = real(**kw)
         return seen["ctx"]
     harness.Ctx = ctx
-    kept = dict(service._DEFAULTS)      # the scrub cell turns a knob
     try:
         done, cell = _tiny(request.param, trace=True, seconds=1.5,
                            tmp=tmp_path_factory.mktemp("traced"))
     finally:
         harness.Ctx = real
-        service._DEFAULTS.update(kept)
     return request.param, done, cell, seen["ctx"]
 
 
@@ -172,10 +174,12 @@ def test_tiny_traced_runs_parts_add_up(traced):
         (sum(by.values()) - by["idle"]) / ctx.ops / 1000.0)
     assert sum(m[f"msgr_{p}_ms_per_op"] for p in MSGR) == pytest.approx(
         by["msgr"] / ctx.ops / 1000.0, rel=0.01)
-    osd = sum(m[f"osd_{p}_ms_per_op"] for p in OSD) + m.get(SCRUB, 0.0)
-    scrub = loop_parts.ms_per_op(ctx, "osd.scrub")
-    if name != "rb4m_scrub_seqread":        # no reader there: the span's
-        osd += scrub
+    osd = sum(m[f"osd_{p}_ms_per_op"] for p in OSD)
+    # the two parts that only their own cell has a reader for: what the
+    # reader gives there, the span's elsewhere (0 where no such work)
+    for part, reader in (("osd.scrub", SCRUB),
+                         ("osd.recovery", "osd_recovery_ms_per_op")):
+        osd += m[reader] if reader in m else loop_parts.ms_per_op(ctx, part)
     assert osd == pytest.approx(by["osd"] / ctx.ops / 1000.0, rel=0.01)
     # what the accepted shares read is what the parts sum to
     assert m["loop_msgr_pct"] == pytest.approx(

@@ -34,19 +34,21 @@ def _counters(ctrl, rode, sends, **more):
                 frames_tx=5, tx_direct_bytes=1, **more)
 
 
-def test_the_two_entries_stand_where_they_stood_and_the_third_is_gone():
-    """A prefix check (34 entries stood before PR 30's three), so that
-    a later PR's entries do not fail it; the retired share has neither
-    an entry nor a reader."""
-    names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[34:36] == NEW
-    assert names[24:26] == ["msgr_rx_direct_pct", "msgr_recvs_per_mib"]
-    assert names[32:34] == ["decode_bitmatrix_roofline",
-                            "msgr_tx_direct_pct"]
+def entries_stand(bench):
+    """PR 30's two stand together after the send path's share (PR 28),
+    found by name, so that a later PR's entries do not fail it; the
+    retired share has neither an entry nor a reader."""
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(NEW[0])
+    assert names[at:at + 2] == NEW
+    assert names[at - 2:at] == ["decode_bitmatrix_roofline",
+                                "msgr_tx_direct_pct"]
+    assert names.index("msgr_recvs_per_mib") == \
+        names.index("msgr_rx_direct_pct") + 1 < at
     assert "msgr_ctrl_rode_pct" not in names
     with pytest.raises(SystemExit):
         _reader("msgr_ctrl_rode_pct")
-    for entry in BENCH["per_layer"][34:36]:
+    for entry in bench["per_layer"][at:at + 2]:
         unit, better = SHAPE[entry["name"]]
         assert entry == {"name": entry["name"], "unit": unit,
                          "better": better, "source": "program_counter",
@@ -54,6 +56,10 @@ def test_the_two_entries_stand_where_they_stood_and_the_third_is_gone():
         mod = _reader(entry["name"])
         assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == (
             entry["name"], unit, "msg/messenger", "ops_s")
+
+
+def test_the_two_entries_stand_where_they_stood_and_the_third_is_gone():
+    entries_stand(BENCH)
 
 
 @pytest.mark.parametrize("name", NEW)

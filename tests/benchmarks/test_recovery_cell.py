@@ -1,14 +1,12 @@
 """The recovery cell (`rb4m_recovery_write`): its entries and files, its
 nine readers on hand-made spans, the plain reference for recovery, and
-the cell's own files run tiny on the CPU backend at a shape that leaves
-an OSD to spare for each mark-out (`conftest.py` says why `_tiny`'s
-three OSDs cannot)."""
+the cell's own files run tiny on the CPU backend at the shape
+`test_benchmarks.shrink` learns from them: an OSD to spare for each
+mark-out, a parity more for the OSD set-up stops."""
 from __future__ import annotations
 
-import asyncio
 import json
 import os
-import time
 import types
 
 import numpy as np
@@ -16,7 +14,7 @@ import pytest
 
 from benchmarks import harness, reference, reference_recovery
 from benchmarks.layer_metrics import loop_share, recovery_spans
-from tests.benchmarks.test_benchmarks import BENCH, ROOT
+from tests.benchmarks.test_benchmarks import BENCH, ROOT, run_tiny, shrink
 
 CELL = "rb4m_recovery_write"
 CONFIG = "radosbench_ec83_tpu_recovery"
@@ -31,6 +29,12 @@ NEW = {"recovery_active_pct": ("%", "osd/pg+osd/ec_backend", "ops_s"),
        "client_rate_in_recovery_pct": ("%", "osd/pg+osd/ec_backend",
                                        "ops_s"),
        "osd_recovery_ms_per_op": ("ms/op", "osd/pg+osd/ec_backend", "ops_s")}
+#: accepted entries that list this cell since PR 44: the write and the
+#: decode readers that read right on a window which both encodes and
+#: decodes (benchmarks/README.md says which stay out, and why)
+JOINED = ["ec_encode_ms", "store_commit_ms", "loop_offload_pct",
+          "decode_ops_per_batch", "decode_handoff_ms",
+          "decode_device_call_ms", "store_write_direct_pct"]
 
 
 def _reader(name):
@@ -39,37 +43,59 @@ def _reader(name):
 
 # -- the entries and the files ---------------------------------------------------
 
-def test_the_cell_is_one_config_one_workload_and_nine_readers_at_the_end():
-    assert BENCH["configs"][-1]["name"] == CONFIG
-    assert BENCH["workloads"][-1] == {
-        "name": CELL, "config": CONFIG, "traffic": CELL, "chips": 1,
-        "why": BENCH["workloads"][-1]["why"]}
-    assert len(BENCH["workloads"][-1]["why"]) <= 200
-    assert len(BENCH["configs"][-1]["source"]) <= 200
-    assert BENCH["configs"][-1]["source"] != BENCH["configs"][1]["source"]
-    added = BENCH["per_layer"][-9:]
-    assert [m["name"] for m in added] == list(NEW)
-    for m in added:
-        unit, layer, moves = NEW[m["name"]]
-        assert m == {"name": m["name"], "unit": unit, "better": m["better"],
-                     "source": "program_span", "layer": layer,
-                     "moves": moves, "workloads": [CELL]}
-    # no accepted entry gained the cell: those lists are not this PR's
-    assert all(CELL not in m.get("workloads", ())
-               for m in BENCH["per_layer"][:-9])
-
-
-def test_cell_resolves_with_its_nine_readers_and_without_the_write_cells():
-    cell = harness.load_cell(CELL)
-    names = {r.NAME for r in cell.readers}
-    assert set(NEW) <= names
-    assert "ec_encode_ms" not in names and "ec_decode_ms" not in names
+def entries_stand(bench, root=ROOT):
+    """The cell's entries, each found by its name: one configuration,
+    one workload, nine readers of its own in their order, and the
+    accepted entries that list the cell, written out."""
+    config = {c["name"]: c for c in bench["configs"]}[CONFIG]
+    cell = {w["name"]: w for w in bench["workloads"]}[CELL]
+    assert cell == {"name": CELL, "config": CONFIG, "traffic": CELL,
+                    "chips": 1, "why": cell["why"]}
+    assert len(cell["why"]) <= 200 and len(config["source"]) <= 200
+    assert config["source"] != {c["name"]: c for c in bench["configs"]}[
+        "radosbench_ec83_tpu_degraded"]["source"]
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(next(iter(NEW)))
+    assert names[at:at + 9] == list(NEW)
+    by = {m["name"]: m for m in bench["per_layer"]}
+    for name, (unit, layer, moves) in NEW.items():
+        assert by[name] == {
+            "name": name, "unit": unit, "better": by[name]["better"],
+            "source": "program_span", "layer": layer, "moves": moves,
+            "workloads": by[name]["workloads"]}
+        assert CELL in by[name]["workloads"]
+    assert [n for n in names if n not in NEW
+            and CELL in by[n].get("workloads", ())] == JOINED
+    # what every cell reports, and none of what mixes the two kinds of
+    # batch or finds nothing to read here
+    cell = harness.load_cell(CELL, root=root)
+    loaded = {r.NAME for r in cell.readers}
+    assert set(NEW) | set(JOINED) <= loaded
+    assert not {"offload_ops_per_batch", "offload_handoff_ms",
+                "offload_device_call_ms", "link_bytes_per_byte",
+                "ec_decode_ms", "apply_bitmatrix_batched_roofline",
+                "decode_bitmatrix_roofline"} & loaded
     assert {"loop_busy_pct", "device_idle_pct", "compiles_in_window",
-            "osd_pg_ms_per_op", "msgr_frames_per_op"} <= names
+            "osd_pg_ms_per_op", "msgr_frames_per_op"} <= loaded
+
+
+def test_the_cell_is_one_config_one_workload_and_nine_readers_by_name():
+    entries_stand(BENCH)
     for name, (unit, layer, moves) in NEW.items():
         mod = _reader(name)
         assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
             (name, unit, layer, moves)
+
+
+@pytest.mark.parametrize("name", JOINED)
+def test_an_accepted_entry_lists_this_cell(name):
+    """The write cell's and the degraded cell's readers list their
+    cells; this one joined them in PR 44, after the cells they named."""
+    by = {m["name"]: m for m in BENCH["per_layer"]}
+    assert CELL in by[name]["workloads"]
+    assert {"rb4m_write", "rb4m_degraded_seqread"} & set(
+        by[name]["workloads"])
+    assert name in {r.NAME for r in harness.load_cell(CELL).readers}
 
 
 def test_the_files_are_the_siblings_with_two_more_osds_and_two_events():
@@ -293,26 +319,22 @@ def test_reservation_checker_counts_each_role_on_each_osd():
 # -- the cell's own files, tiny ---------------------------------------------------------
 
 def _tiny_recovery(trace, tmp, seconds=5.0, control=(), m=2):
-    """The cell's files at six OSDs and 2+2 (four positions, two OSDs to
-    spare, `min_size` 3 of the 3 that one stopped OSD leaves), the
-    events an eighth of the way in that the cell's are, a heartbeat
-    grace a run on the CPU can wait out, and a device batch bounded by
-    its bytes at four objects as the chip's is at two (so recovery's
-    decodes, which are not the clients', meet no shape set-up did not
-    warm). `m` 3 on seven OSDs leaves an object readable with two
+    """The cell's files as `shrink` cuts them (six OSDs and 2+2: four
+    positions, two OSDs to spare, `min_size` 3 of the 3 that one
+    stopped OSD leaves; the events as far into the window as
+    the cell's are; a device batch bounded at four objects), with more
+    objects to rebuild and a heartbeat grace a run on the CPU can wait
+    out. `m` 3 on seven OSDs leaves an object readable with two
     positions not yet rebuilt and a third rotted, as 8+3 does."""
-    from ceph_tpu.offload import service
-
-    cell = harness.load_cell(CELL)
+    cell = shrink(harness.load_cell(CELL), seconds)
+    assert (cell.config["osds"], cell.config["pool"]["m"]) == (6, 2)
+    assert [e["at_s"] for e in cell.traffic["events"]] == [
+        pytest.approx(2 * seconds / 40), pytest.approx(16 * seconds / 40)]
     cell.config = dict(
-        cell.config, osds=4 + m, object_size=65536,
-        pool=dict(cell.config["pool"], k=2, m=m, pg_num=8),
-        osd_config=dict(cell.config["osd_config"], osd_heartbeat_grace=6.0,
-                        ec_offload_max_batch_bytes=4 * 65536))
-    cell.traffic = dict(
-        cell.traffic, clients=4, warmup_ops=8, payload_pool=4,
-        preload_objects=24,
-        events=[dict(e, at_s=e["at_s"] / 8) for e in cell.traffic["events"]])
+        cell.config, osds=4 + m,
+        pool=dict(cell.config["pool"], m=m),
+        osd_config=dict(cell.config["osd_config"], osd_heartbeat_grace=6.0))
+    cell.traffic = dict(cell.traffic, preload_objects=24)
     seen: dict = {}
     real = harness.Ctx
 
@@ -320,14 +342,10 @@ def _tiny_recovery(trace, tmp, seconds=5.0, control=(), m=2):
         seen["ctx"] = real(**kw)
         return seen["ctx"]
     harness.Ctx = ctx
-    kept = dict(service._DEFAULTS)      # the tiny shape turns a knob
     try:
-        done = asyncio.run(harness.run_cell(
-            cell, 2 ** 31 + 5, seconds, trace, str(tmp), time.monotonic(),
-            control))
+        done = run_tiny(cell, trace, control, seconds, tmp)
     finally:
         harness.Ctx = real
-        service._DEFAULTS.update(kept)
     return done, cell, seen.get("ctx")
 
 
@@ -365,8 +383,17 @@ def test_tiny_traced_run_reports_the_nine_and_the_accepted_families(traced):
     assert m["backfill_pgs_done"] >= 8      # a PG once an interval
     assert m["backfill_reserve_wait_ms"] >= 0
     assert m["osd_recovery_ms_per_op"] > 0
-    # what every cell reports, here too (conftest.py skips `_tiny`'s)
-    assert "ec_encode_ms" not in m and "store_write_direct_pct" not in m
+    # the accepted write and decode readers that list the cell since
+    # PR 44, and none of those that would mix the two kinds of batch
+    assert set(JOINED) <= set(m)
+    assert m["ec_encode_ms"] > 0 and m["store_commit_ms"] > 0
+    assert 0.0 < m["store_write_direct_pct"] <= 100.0
+    assert m["decode_ops_per_batch"] >= 1.0
+    assert m["decode_handoff_ms"] >= 0 and m["decode_device_call_ms"] > 0
+    assert not {"offload_ops_per_batch", "offload_handoff_ms",
+                "offload_device_call_ms", "link_bytes_per_byte",
+                "ec_decode_ms"} & set(m)
+    # what every cell reports, here too
     assert 50.0 < m["msgr_acks_carried_pct"] <= 100.0
     assert 1.0 <= m["msgr_sends_per_op"] <= \
         m["msgr_frames_per_op"] + m["msgr_ctrl_frames_per_op"]
