@@ -18,6 +18,12 @@ log-structured merge engine:
     mid-flush/mid-compaction falls back to the previous run set plus
     WAL replay.
 
+Threads: one writer, any readers. `submit_transaction`, and with it a
+memtable flush or a compaction that falls due, runs on whatever thread
+calls it (BlueStore's commit thread); `get` and `iterate` may run on
+another meanwhile. `_lock` is held only while tables change hands or
+are iterated, never across file I/O.
+
 Idiomatic divergences: runs are loaded into memory at open (block
 cache = whole-file residency — state here is control-plane-sized);
 values are latin1-mapped JSON rather than varint-framed blocks.
@@ -27,11 +33,13 @@ from __future__ import annotations
 import json
 import os
 import struct
+import threading
 
 from ceph_tpu.kv.keyvaluedb import KeyValueDB, KVTransaction
 from ceph_tpu.utils.crash import SimulatedCrash  # noqa: F401 (re-export)
 
 _TOMB = None          # tombstone marker inside tables
+_MISSING = object()   # no entry in a table, where None is a tombstone
 
 
 def _crc32c(data: bytes) -> int:
@@ -64,6 +72,13 @@ class LSMStore(KeyValueDB):
         self._wal = None
         self._next_file = 1
         self.fail_after_wal = False     # SimulatedCrash hook
+        self._lock = threading.Lock()
+        #: since the store was made: `fsync`s (log, runs, manifest,
+        #: directory), bytes written to the log and to runs, flushes of
+        #: the memtable, compactions
+        self.stats = dict.fromkeys(
+            ("fsyncs", "bytes_written", "memtable_flushes", "compactions"),
+            0)
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -123,17 +138,20 @@ class LSMStore(KeyValueDB):
              for o in txn.ops]).encode()
         self._wal.write(struct.pack("<II", len(rec), _crc32c(rec)) + rec)
         self._wal.flush()
+        self.stats["bytes_written"] += 8 + len(rec)
         if sync:
             os.fsync(self._wal.fileno())
+            self.stats["fsyncs"] += 1
         if self.fail_after_wal:
             raise SimulatedCrash("crash between WAL append and apply")
-        for op in txn.ops:
-            if op[0] == "set":
-                self._mem_set(f"{op[1]}\x00{op[2]}", op[3])
-            elif op[0] == "rm":
-                self._mem_set(f"{op[1]}\x00{op[2]}", _TOMB)
-            elif op[0] == "rmprefix":
-                self._rm_prefix_mem(op[1])
+        with self._lock:
+            for op in txn.ops:
+                if op[0] == "set":
+                    self._mem_set(f"{op[1]}\x00{op[2]}", op[3])
+                elif op[0] == "rm":
+                    self._mem_set(f"{op[1]}\x00{op[2]}", _TOMB)
+                elif op[0] == "rmprefix":
+                    self._rm_prefix_mem(op[1])
         if self._mem_bytes >= self.FLUSH_BYTES:
             self._flush()
 
@@ -179,6 +197,8 @@ class LSMStore(KeyValueDB):
             f.write(struct.pack("<I", _crc32c(body)) + body)
             f.flush()
             os.fsync(f.fileno())
+        self.stats["bytes_written"] += 4 + len(body)
+        self.stats["fsyncs"] += 1
         os.replace(tmp, self._run_path(name))
         return name
 
@@ -191,16 +211,21 @@ class LSMStore(KeyValueDB):
             os.fsync(f.fileno())
         os.replace(tmp, os.path.join(self.path, "MANIFEST"))
         _fsync_dir(self.path)
+        self.stats["fsyncs"] += 2
 
     def _flush(self) -> None:
         if not self._memtable:
             return
+        # the writer alone changes the memtable, and this is the writer
         name = self._write_run(self._memtable)
         self._run_files.insert(0, name)
-        self._runs.insert(0, dict(self._memtable))
+        with self._lock:
+            self._runs.insert(0, dict(self._memtable))
         self._commit_manifest()
-        self._memtable.clear()
+        with self._lock:            # the run above holds every key of it
+            self._memtable.clear()
         self._mem_bytes = 0
+        self.stats["memtable_flushes"] += 1
         # WAL content is now durable in the run: start a fresh log
         self._wal.close()
         os.truncate(self._wal_path(), 0)
@@ -218,8 +243,10 @@ class LSMStore(KeyValueDB):
         name = self._write_run(merged)
         old_files = self._run_files
         self._run_files = [name]
-        self._runs = [merged]
+        with self._lock:
+            self._runs = [merged]
         self._commit_manifest()
+        self.stats["compactions"] += 1
         for fn in old_files:
             try:
                 os.unlink(self._run_path(fn))
@@ -235,24 +262,29 @@ class LSMStore(KeyValueDB):
     # -- reads ---------------------------------------------------------------
 
     def get(self, prefix: str, key: str) -> bytes | None:
+        # no lock: one lookup a table, and a flush puts the memtable's
+        # keys into a run before it clears them
         fq = f"{prefix}\x00{key}"
-        if fq in self._memtable:
-            return self._memtable[fq]
+        value = self._memtable.get(fq, _MISSING)
+        if value is not _MISSING:
+            return value
         for run in self._runs:
-            if fq in run:
-                return run[fq]
+            value = run.get(fq, _MISSING)
+            if value is not _MISSING:
+                return value
         return None
 
     def iterate(self, prefix: str, start: str = ""):
         p = prefix + "\x00"
         view: dict[str, bytes | None] = {}
-        for run in reversed(self._runs):
-            for k, v in run.items():
+        with self._lock:
+            for run in reversed(self._runs):
+                for k, v in run.items():
+                    if k.startswith(p):
+                        view[k] = v
+            for k, v in self._memtable.items():
                 if k.startswith(p):
                     view[k] = v
-        for k, v in self._memtable.items():
-            if k.startswith(p):
-                view[k] = v
         for k in sorted(view):
             key = k[len(p):]
             if view[k] is not None and key >= start:

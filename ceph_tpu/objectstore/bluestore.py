@@ -17,13 +17,27 @@ Re-creation of the reference BlueStore's architecture
     KV value and never touch the block file (the deferred-write WAL
     role, BlueStore.cc :14191 _kv_sync_thread) — one fsync'd KV batch
     is the whole commit;
-  * large writes go data-first: extents are written + fsync'd to the
-    block file BEFORE the KV batch commits, so a crash in between
+  * large writes go data-first: extents are written to the block file
+    and synced BEFORE the KV batch commits, so a crash in between
     leaves the old onode pointing at the old extents (BlueStore's txc
     ordering); freed extents return to the allocator only after the
     batch is durable;
-  * transactions map 1:1 onto an atomic KV batch (the RocksDB
-    WriteBatch role): apply is all-or-nothing at the KV WAL.
+  * a transaction is one atomic slice of a KV batch (the RocksDB
+    WriteBatch role): apply is all-or-nothing at the KV WAL;
+  * the commit is a pipeline (the txc state machine, _txc_state_proc
+    :13556, and _kv_sync_thread :14191): `queue_transaction` PREPARES
+    on the caller's thread (ops applied to staged onodes, units
+    allocated, extents written, csums made, the KV batch built),
+    queues the context and returns. One commit thread a mounted store
+    takes every context queued, syncs the block file once, submits ONE
+    synced KV batch for all of them and hands each context's
+    `on_commit` back to the loop that queued it, in queue order. The
+    group is whatever queued while the last sync ran: no timer, no
+    knob. Reads see a queued transaction at once (`on_applied` is
+    immediate, as upstream's is on BlueStore). A caller with no
+    running loop (the tools, a plain test) waits for its context and
+    gets the callbacks, or the commit's exception, before the call
+    returns.
 
 Idiomatic divergences: writes rewrite the object's extent set rather
 than splicing sub-extents (the RMW/compression/blob-reuse machinery is
@@ -32,8 +46,13 @@ in the onode record.
 """
 from __future__ import annotations
 
+import asyncio
+import collections
 import json
 import os
+import threading
+import time
+import weakref
 
 from ceph_tpu.kv.keyvaluedb import KeyValueDB, KVTransaction
 from ceph_tpu.kv.lsm import LSMStore
@@ -41,7 +60,9 @@ from ceph_tpu.objectstore.store import (ObjectStore, Op, StoreError,
                                         Transaction)
 from ceph_tpu.objectstore.types import (CollectionId, Ghobject, cid_from,
                                         cid_key, oid_from, oid_key)
+from ceph_tpu.utils import tracer
 from ceph_tpu.utils.crash import SimulatedCrash  # noqa: F401 (re-export)
+from ceph_tpu.utils.dout import dout
 
 AU = 4096                    # allocation unit (min_alloc_size)
 INLINE_MAX = 64 * 1024       # deferred/inline object size ceiling
@@ -51,6 +72,13 @@ P_SUPER = "S"
 P_COLL = "C"
 P_ONODE = "O"
 P_OMAP = "M"
+
+_CLEAR = "\x00CLEAR\x00"     # in an omap overlay: the keys under it are gone
+#: a context's states (the txc state machine) are `prepare`, on the
+#: caller's thread; `queued`, `block_synced`, `kv_submitted`, on the
+#: commit thread; `done`, where the callbacks ran; or `failed`. A caller
+#: with no loop waits for one of these:
+_SETTLED = ("kv_submitted", "done", "failed")
 
 
 def _crc32c(data: bytes) -> int:
@@ -80,11 +108,15 @@ def _onode_key(cid: CollectionId, oid: Ghobject) -> str:
 
 class BitmapAllocator:
     """AU-granular bitmap over the block file (BitmapAllocator +
-    FreelistManager: the bitmap itself rides the commit batch)."""
+    FreelistManager: the bitmap itself rides the commit batch). It
+    counts its free units: a device that is full, as one that only ever
+    takes new objects is after every allocation, grows at once and is
+    not walked first."""
 
     def __init__(self, n_units: int = 0):
         self.bits = bytearray(n_units)        # 0 free, 1 used
         self._cursor = 0
+        self._free = n_units
 
     def to_bytes(self) -> bytes:
         return bytes(self.bits)
@@ -93,49 +125,102 @@ class BitmapAllocator:
     def from_bytes(cls, blob: bytes) -> "BitmapAllocator":
         a = cls()
         a.bits = bytearray(blob)
+        a._free = a.bits.count(0)
         return a
 
     def grow(self, n_units: int) -> None:
         if n_units > len(self.bits):
-            self.bits.extend(b"\x00" * (n_units - len(self.bits)))
+            self._free += n_units - len(self.bits)
+            self.bits.extend(bytes(n_units - len(self.bits)))
+
+    def _take(self, unit: int, count: int) -> None:
+        self.bits[unit:unit + count] = b"\x01" * count
+        self._free -= count
 
     def allocate(self, n_units: int) -> list[tuple[int, int]]:
-        """Allocate `n_units`, possibly fragmented: [(unit, count)...].
-        Grows the device when free space runs out."""
+        """Allocate `n_units`, possibly fragmented: [(unit, count)...],
+        from the cursor on and once round. Grows the device when free
+        space runs out."""
         out: list[tuple[int, int]] = []
         need = n_units
-        scanned = 0
-        i = self._cursor
-        total = len(self.bits)
-        while need and scanned < total:
-            if i >= total:
-                i = 0
-            if not self.bits[i]:
-                j = i
-                while j < total and not self.bits[j] and (j - i) < need:
-                    j += 1
-                for k in range(i, j):
-                    self.bits[k] = 1
-                out.append((i, j - i))
-                need -= j - i
-                scanned += j - i
-                i = j
-            else:
-                i += 1
-                scanned += 1
-        if need:
-            base = len(self.bits)
-            self.grow(base + need)
-            for k in range(base, base + need):
-                self.bits[k] = 1
-            out.append((base, need))
+        bits = self.bits
+        i, wrapped = self._cursor, False
+        while need and self._free:
+            i = bits.find(0, i)             # the next free unit
+            if i < 0:
+                if wrapped:
+                    break
+                i, wrapped = 0, True
+                continue
+            j = bits.find(1, i, i + need)   # the run's end, or enough
+            if j < 0:
+                j = min(len(bits), i + need)
+            self._take(i, j - i)
+            out.append((i, j - i))
+            need -= j - i
+            i = j
         self._cursor = i
+        if need:
+            base = len(bits)
+            self.grow(base + need)
+            self._take(base, need)
+            out.append((base, need))
+            self._cursor = base + need
         return out
 
     def free(self, extents: list[tuple[int, int]]) -> None:
         for unit, count in extents:
-            for k in range(unit, unit + count):
-                self.bits[k] = 0
+            self._free += self.bits.count(1, unit, unit + count)
+            self.bits[unit:unit + count] = bytes(count)
+
+
+class _CommitQueue:
+    """What a store and its commit thread share. It refers to no store:
+    the thread holds its store weakly, so a store dropped without
+    `umount` (a killed daemon, a test's) is collected and its finalizer
+    stops the thread."""
+
+    def __init__(self):
+        self.cond = threading.Condition()
+        self.queued: list[_TxnCtx] = []     # prepared, in queue order
+        self.busy = False                   # a group is being committed
+        self.stop = False
+        self.failed: BaseException | None = None    # a group's; for good
+
+
+def _stop_queue(q: _CommitQueue) -> None:
+    with q.cond:
+        q.stop = True
+        q.cond.notify_all()
+
+
+def _kv_sync_thread(ref, q: _CommitQueue) -> None:
+    """The commit thread's body (BlueStore::_kv_sync_thread): take ALL
+    that queued, commit it as one group, again. It ends when told to
+    and the queue is empty, or when its store is gone."""
+    while True:
+        with q.cond:
+            while not q.queued and not q.stop:
+                q.cond.wait()
+            if not q.queued:
+                return
+            group, q.queued = q.queued, []
+            q.busy = True
+        store = ref()
+        try:
+            if store is None:
+                return
+            store._commit_group(group)
+        except Exception as e:
+            # a fault of the pipeline itself, past what `_commit_group`
+            # takes for a failed sync: the same end, a dead store whose
+            # waiters are told, never a thread that is silently gone
+            store._fail_group(group, e)
+        finally:
+            del store, group
+            with q.cond:
+                q.busy = False
+                q.cond.notify_all()
 
 
 class BlueStore(ObjectStore):
@@ -144,7 +229,7 @@ class BlueStore(ObjectStore):
         self.path = path
         self.kv = kv if kv is not None else LSMStore(
             os.path.join(path, "db"))
-        self._block = None
+        self._fd: int | None = None         # the block file
         self.alloc = BitmapAllocator()
         # per-AU block checksums through the shared Checksummer engine
         # (bluestore_blob_t csum_data at csum_block_size granularity:
@@ -153,9 +238,32 @@ class BlueStore(ObjectStore):
         # for the EC shard csums)
         from ceph_tpu.utils.checksummer import Checksummer
         self.csum = Checksummer("crc32c", AU)
-        # test hook: crash after block-file data writes, before the KV
+        # test hook: crash after the block file's sync, before the KV
         # batch commit (the txc window the ordering protects)
         self.fail_before_kv = False
+        # the commit pipeline. `_lock` guards what both threads touch:
+        # the allocator and the overlay of uncommitted state
+        self._q = _CommitQueue()
+        self._thread: threading.Thread | None = None
+        self._lock = threading.Lock()
+        self._seq = 0               # contexts queued
+        self._groups = 0            # groups taken by the thread
+        self._groups_done = 0       # groups whose syncs have returned
+        self._done: collections.deque = collections.deque()
+        self._tail: _TxnCtx | None = None   # the last context queued
+        self._fatal_told = False
+        # uncommitted state the reads overlay on the KV, each entry
+        # beside the sequence number of the last context that set it:
+        # onode key -> (onode | None), collection key -> exists,
+        # onode key -> omap overlay (`_TxnCtx.omap_over`'s shape)
+        self._pend_onodes: dict[str, tuple[dict | None, int]] = {}
+        self._pend_colls: dict[str, tuple[bool, int]] = {}
+        self._pend_omap: dict[str, tuple[dict, int]] = {}
+        # the allocator as the KV holds it: the commit thread's alone
+        self._durable_bits = bytearray()
+        self._stats = dict.fromkeys(
+            ("txcs", "kv_syncs", "block_syncs", "block_bytes_written",
+             "acks_before_sync"), 0)
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -167,30 +275,87 @@ class BlueStore(ObjectStore):
                 pass
 
     def mount(self) -> None:
+        if self._fd is not None:
+            return
         self.mkfs()
         self.kv.open()
-        self._block = open(os.path.join(self.path, "block"), "r+b")
+        self._fd = os.open(os.path.join(self.path, "block"), os.O_RDWR)
         blob = self.kv.get(P_SUPER, "freelist")
         self.alloc = BitmapAllocator.from_bytes(blob) if blob \
             else BitmapAllocator()
+        self._durable_bits = bytearray(self.alloc.bits)
+        self._q = q = _CommitQueue()
+        self._fatal_told = False
+        self._thread = threading.Thread(
+            target=_kv_sync_thread, args=(weakref.ref(self), q),
+            name="bstore-kv-sync", daemon=True)
+        self._thread.start()
+        weakref.finalize(self, _stop_queue, q)
+
+    def flush(self) -> None:
+        """Wait until everything queued is committed and its callbacks
+        have run (ObjectStore::flush). Nothing is closed; a store that
+        was flushed and gets nothing more writes nothing more."""
+        q = self._q
+        with q.cond:
+            while q.queued or q.busy:
+                q.cond.wait()
+        self._deliver()
 
     def umount(self) -> None:
-        if self._block is not None:
-            self._block.close()
-            self._block = None
+        if self._fd is None:
+            return
+        self.flush()                # drain first, then close
+        _stop_queue(self._q)
+        self._thread.join()
+        self._thread = None
+        os.close(self._fd)
+        self._fd = None
         self.kv.close()
+
+    def stats(self) -> dict:
+        """The pipeline's counters since the store was made: contexts
+        committed, groups, syncs of the block file and of the KV,
+        bytes written to each, the KV's flushes and compactions, and
+        `acks_before_sync`: callbacks delivered before the group that
+        covers them had finished, which must read 0."""
+        kv = self._kv_stats()
+        return {**self._stats,
+                "kv_fsyncs": kv.get("fsyncs", 0),
+                "kv_bytes_written": kv.get("bytes_written", 0),
+                "memtable_flushes": kv.get("memtable_flushes", 0),
+                "compactions": kv.get("compactions", 0)}
+
+    def _kv_stats(self) -> dict:
+        """The KV's own counters; an engine that keeps none (MemDB)
+        reads as zeros."""
+        return getattr(self.kv, "stats", {})
 
     # -- onode helpers -------------------------------------------------------
 
     def _onode(self, cid: CollectionId, oid: Ghobject) -> dict | None:
-        blob = self.kv.get(P_ONODE, _onode_key(cid, oid))
+        """The onode as queued: an uncommitted context's, else the
+        KV's. Not the caller's to change (`_staged` copies)."""
+        key = _onode_key(cid, oid)
+        pend = self._pend_onodes.get(key)
+        if pend is not None:
+            return pend[0]
+        blob = self.kv.get(P_ONODE, key)
         return None if blob is None else json.loads(blob)
+
+    def _coll_exists(self, cid: CollectionId,
+                     ctx: "_TxnCtx | None" = None) -> bool:
+        key = _cid_key(cid)
+        if ctx is not None and key in ctx.colls:
+            return ctx.colls[key]
+        pend = self._pend_colls.get(key)
+        if pend is not None:
+            return pend[0]
+        return self.kv.get(P_COLL, key) is not None
 
     def _require_coll(self, cid: CollectionId,
                       ctx: "_TxnCtx | None" = None) -> None:
-        if ctx is not None and _cid_key(cid) in ctx.new_colls:
-            return
-        if self.kv.get(P_COLL, _cid_key(cid)) is None:
+        if not self._coll_exists(cid, ctx):
             raise StoreError("ENOENT", f"no collection {cid}")
 
     def _require_onode(self, cid: CollectionId, oid: Ghobject) -> dict:
@@ -206,8 +371,7 @@ class BlueStore(ObjectStore):
             return on["inline"].encode("latin1")
         out = bytearray()
         for unit, count, crc in on["extents"]:
-            self._block.seek(unit * AU)
-            chunk = self._block.read(count * AU)
+            chunk = os.pread(self._fd, count * AU, unit * AU)
             if len(chunk) != count * AU:
                 # truncated block file (crash mid-write): same EIO
                 # contract as a csum mismatch, so read-repair callers
@@ -248,81 +412,316 @@ class BlueStore(ObjectStore):
         units = len(padded) // AU
         extents = []
         off = 0
-        for unit, count in self.alloc.allocate(units):
+        with self._lock:
+            # a group that failed has given its units back by now, and
+            # the KV's log may still name them: nothing is written over
+            # them (`_fail_group` sets `failed` before it frees)
+            self._refuse_if_failed()
+            got = self.alloc.allocate(units)
+        for unit, count in got:
             ctx.allocated.append((unit, count))
             chunk = padded[off:off + count * AU]
-            self._block.seek(unit * AU)
-            self._block.write(chunk)
+            # positional and unbuffered: the commit thread syncs this
+            # descriptor while the caller's thread writes through it
+            os.pwrite(self._fd, chunk, unit * AU)
             extents.append([unit, count,
                             [int(x) for x in self.csum.calculate(chunk)]])
             off += count * AU
         on["extents"] = extents
-        ctx.block_dirty = True
+        ctx.block_bytes += len(padded)
 
-    # -- transaction apply ---------------------------------------------------
+    # -- the commit pipeline -------------------------------------------------
 
     def queue_transaction(self, txn: Transaction) -> None:
+        """PREPARE on the caller's thread, queue the context, return:
+        the transaction is readable now (`on_applied` fires before the
+        return) and durable when `on_commit` fires, which the commit
+        thread hands back to the caller's loop. The span and the
+        histogram around this call (`store_commit`) are what the
+        caller's thread pays, not the commit. With no running loop the
+        call waits for the commit, and raises what it raised."""
+        q = self._q
+        self._refuse_if_failed()
         ctx = _TxnCtx(self.kv.transaction())
-        # staged onode cache so multiple ops on one object in one txn
-        # compose before the single KV batch write
         try:
             for op in txn.ops:
                 self._apply_op(op, ctx)
         except BaseException:
-            # all-or-nothing: nothing was committed, so units allocated
+            # all-or-nothing: nothing was queued, so units allocated
             # by earlier ops of this txn must return to the allocator
-            self.alloc.free(ctx.allocated)
+            with self._lock:
+                self.alloc.free(ctx.allocated)
             raise
         for key, on in ctx.onodes.items():
             if on is None:
                 ctx.batch.rmkey(P_ONODE, key)
             else:
                 ctx.batch.set(P_ONODE, key, json.dumps(on).encode())
-        if ctx.block_dirty:
-            # data before metadata: the txc ordering (BlueStore.cc
-            # _txc_state_proc) — a crash here leaves old onodes valid
-            self._block.flush()
-            os.fsync(self._block.fileno())
-        if self.fail_before_kv:
-            self.alloc.free(ctx.allocated)
-            raise SimulatedCrash("crash between data write and KV commit")
-        # frees apply BEFORE the batch builds: every block write of this
-        # txn has already landed (on fresh units only), so the persisted
-        # bitmap can return the old extents atomically with the metadata
-        # that stopped referencing them (the FreelistManager role)
-        self.alloc.free(ctx.free_after)
-        if ctx.allocated or ctx.free_after:
-            ctx.batch.set(P_SUPER, "freelist", self.alloc.to_bytes())
+        ctx.n_ops = len(txn.ops)
+        ctx.on_applied, ctx.on_commit = txn.on_applied, txn.on_commit
         try:
-            self.kv.submit_transaction(ctx.batch, sync=True)
-        except BaseException:
-            # restore the in-memory allocator to the durable state
-            self.alloc.free(ctx.allocated)
-            for unit, count in ctx.free_after:
-                for k in range(unit, unit + count):
-                    self.alloc.bits[k] = 1
-            raise
-        for fn in txn.on_applied:
+            ctx.loop = asyncio.get_running_loop()
+        except RuntimeError:
+            ctx.loop = None
+        with self._lock:
+            self._seq = seq = self._seq + 1
+            ctx.seq = seq
+            self._publish(ctx, seq)
+        ctx.state = "queued"
+        ctx.t_queued = time.perf_counter()
+        with q.cond:
+            q.queued.append(ctx)
+            q.cond.notify_all()
+        if ctx.loop is not None:
+            for fn in ctx.on_applied:
+                fn()
+            return
+        with q.cond:
+            while ctx.state not in _SETTLED:
+                q.cond.wait()
+        self._deliver()
+        if ctx.error is not None:
+            raise ctx.error
+
+    @property
+    def failed(self) -> BaseException | None:
+        return self._q.failed
+
+    def _refuse_if_failed(self) -> None:
+        if self._q.failed is not None:
+            raise StoreError("EIO", f"store failed at a commit: "
+                                    f"{self._q.failed!r}")
+
+    def flush_commit(self, fn) -> None:
+        """`fn()` once everything queued so far is durable: now, where
+        nothing is in flight, else among the last queued context's
+        `on_commit`s (CollectionHandle::flush_commit; one thread
+        commits the whole store in queue order, so the last covers
+        the rest)."""
+        last = self._tail
+        if last is None:
             fn()
-        for fn in txn.on_commit:
-            fn()
+        else:   # its own list: the transaction's is the caller's
+            last.on_commit = [*last.on_commit, fn]
+
+    def _publish(self, ctx: "_TxnCtx", seq: int) -> None:
+        """Lay a prepared context over the KV for the reads. Under
+        `_lock`."""
+        self._tail = ctx
+        for key, on in ctx.onodes.items():
+            self._pend_onodes[key] = (on, seq)
+        for key, exists in ctx.colls.items():
+            self._pend_colls[key] = (exists, seq)
+        for key, over in ctx.omap_over.items():
+            old = self._pend_omap.get(key)
+            if old is not None and _CLEAR not in over:
+                over = {**old[0], **over}
+            self._pend_omap[key] = (over, seq)
+
+    def _retire(self, group: "list[_TxnCtx]") -> None:
+        """The KV holds the group now: its frees reach the allocator,
+        and what it laid over the KV goes unless a later context has
+        laid its own there since. Under `_lock`."""
+        hi = group[-1].seq
+        for ctx in group:
+            self.alloc.free(ctx.free_after)
+            for pend, keys in ((self._pend_onodes, ctx.onodes),
+                               (self._pend_colls, ctx.colls),
+                               (self._pend_omap, ctx.omap_over)):
+                for key in keys:
+                    if key in pend and pend[key][1] <= hi:
+                        del pend[key]
+
+    def _commit_group(self, group: "list[_TxnCtx]") -> None:
+        """On the commit thread: one sync of the block file if any
+        context wrote extents, one synced KV batch for all of them (the
+        freelist key once), the frees, then the callbacks handed back
+        in queue order."""
+        if self._q.failed is not None:
+            # prepared while the group before it failed: nothing
+            # commits behind a hole
+            self._fail_group(group, self._q.failed)
+            return
+        t0 = time.perf_counter()
+        self._groups = seqno = self._groups + 1
+        for ctx in group:
+            ctx.group, ctx.t_taken = seqno, t0
+        kv0 = dict(self._kv_stats())
+        block_bytes = sum(ctx.block_bytes for ctx in group)
+        freelist_bytes = 0
+        try:
+            if block_bytes:
+                # data before metadata: the txc ordering (BlueStore.cc
+                # _txc_state_proc) — a crash here leaves old onodes valid
+                os.fdatasync(self._fd)
+            t1 = time.perf_counter()
+            for ctx in group:
+                ctx.state = "block_synced"
+            if self.fail_before_kv:
+                raise SimulatedCrash(
+                    "crash between data write and KV commit")
+            batch = self.kv.transaction()
+            for ctx in group:
+                batch.ops.extend(ctx.batch.ops)
+            # the persisted bitmap takes the group's allocations and
+            # returns its frees atomically with the metadata that
+            # started and stopped referencing them (the FreelistManager
+            # role); units of contexts not in the group are not in it
+            bits = self._durable_bits
+            if any(ctx.allocated or ctx.free_after for ctx in group):
+                bits = bytearray(bits)
+                for ctx in group:
+                    for unit, count in ctx.allocated:
+                        if unit + count > len(bits):
+                            bits.extend(bytes(unit + count - len(bits)))
+                        bits[unit:unit + count] = b"\x01" * count
+                for ctx in group:
+                    for unit, count in ctx.free_after:
+                        bits[unit:unit + count] = bytes(count)
+                batch.set(P_SUPER, "freelist", bytes(bits))
+                freelist_bytes = len(bits)
+            if batch.ops:
+                self.kv.submit_transaction(batch, sync=True)
+            t2 = time.perf_counter()
+        except Exception as e:
+            self._fail_group(group, e)
+            return
+        self._durable_bits = bits
+        with self._lock:
+            self._retire(group)
+        kv1 = self._kv_stats()
+        st = self._stats
+        st["txcs"] += len(group)
+        st["kv_syncs"] += bool(batch.ops)
+        st["block_syncs"] += bool(block_bytes)
+        st["block_bytes_written"] += block_bytes
+        perf = self.commit_perf
+        if perf is not None:
+            perf.hist_add("store_kv_sync_us", (t2 - t0) * 1e6)
+        if tracer.active():
+            tracer.record_span(
+                "bstore_kv_sync", t0, (t2 - t0) * 1e6,
+                {"group": seqno, "txcs": len(group),
+                 "block_synced": int(bool(block_bytes)),
+                 "block_sync_us": (t1 - t0) * 1e6,
+                 "kv_submit_us": (t2 - t1) * 1e6,
+                 "kv_fsyncs": kv1.get("fsyncs", 0) - kv0.get("fsyncs", 0),
+                 "block_bytes": block_bytes,
+                 "kv_bytes": kv1.get("bytes_written", 0)
+                 - kv0.get("bytes_written", 0),
+                 "freelist_bytes": freelist_bytes},
+                getattr(self, "name", type(self).__name__))
+        for ctx in group:
+            ctx.block_sync_us = (t1 - t0) * 1e6
+            ctx.kv_submit_us = (t2 - t1) * 1e6
+            ctx.t_synced = t2
+            ctx.state = "kv_submitted"
+        self._groups_done = seqno
+        self._hand_back(group)
+
+    def _fail_group(self, group: "list[_TxnCtx]", e: BaseException) -> None:
+        """A sync or the KV failed (ENOSPC, EIO, a test's crash hook):
+        no context of the group, and none queued behind it, commits or
+        calls back; their units return to the allocator, the frees they
+        staged never happen, and the store takes no more transactions
+        (upstream aborts the OSD; here its sub-op waits time out and
+        the clients resend)."""
+        q = self._q
+        with q.cond:
+            q.failed = e
+            group = group + q.queued
+            q.queued = []
+        with self._lock:
+            for ctx in group:
+                self.alloc.free(ctx.allocated)
+        for ctx in group:
+            ctx.error, ctx.state = e, "failed"
+        if self._fatal_told:
+            return
+        self._fatal_told = True
+        dout("bluestore", 0, f"{self.path}: commit failed, store is dead: "
+                             f"{type(e).__name__} {e}")
+        loop = next((c.loop for c in group if c.loop is not None), None)
+        if loop is not None and self.on_fatal is not None:
+            try:
+                loop.call_soon_threadsafe(self.on_fatal, e)
+            except RuntimeError:
+                pass        # the loop is closed: nobody is left to tell
+
+    def _hand_back(self, group: "list[_TxnCtx]") -> None:
+        """Committed contexts go to `_done` in queue order; each loop
+        that queued some is told once to deliver."""
+        self._done.extend(group)
+        loops = []
+        for ctx in group:
+            if ctx.loop is not None and ctx.loop not in loops:
+                loops.append(ctx.loop)
+        for loop in loops:
+            try:
+                loop.call_soon_threadsafe(self._deliver)
+            except RuntimeError:
+                pass        # the loop is closed: a flush delivers, or none
+
+    def _deliver(self) -> None:
+        """Run the callbacks of what the thread handed back, in queue
+        order: on the loop that queued them, or on the thread that
+        waits in `queue_transaction` or `flush`."""
+        traced = tracer.active()
+        while True:
+            try:
+                ctx = self._done.popleft()
+            except IndexError:
+                return
+            now = time.perf_counter()
+            ran_ahead = ctx.group > self._groups_done
+            self._stats["acks_before_sync"] += ran_ahead
+            if traced:
+                tracer.record_span(
+                    "bstore_txc", ctx.t0, (now - ctx.t0) * 1e6,
+                    {"prepare_us": (ctx.t_queued - ctx.t0) * 1e6,
+                     "queued_us": (ctx.t_taken - ctx.t_queued) * 1e6,
+                     "block_sync_us": ctx.block_sync_us,
+                     "kv_submit_us": ctx.kv_submit_us,
+                     "deliver_us": (now - ctx.t_synced) * 1e6,
+                     "ops": ctx.n_ops, "bytes": ctx.block_bytes,
+                     "group": ctx.group, "ran_ahead": bool(ran_ahead)},
+                    getattr(self, "name", type(self).__name__))
+            ctx.state = "done"
+            if self._tail is ctx:
+                self._tail = None
+            fns = ctx.on_commit if ctx.loop is not None \
+                else [*ctx.on_applied, *ctx.on_commit]
+            for fn in fns:
+                try:
+                    fn()
+                except Exception as e:
+                    dout("bluestore", 0, f"{self.path}: a commit callback "
+                                         f"raised {type(e).__name__} {e}")
 
     def _staged(self, ctx: "_TxnCtx", cid: CollectionId,
                 oid: Ghobject) -> dict | None:
+        """The onode for this context to change: its own, else a copy
+        of what is queued or committed."""
         key = _onode_key(cid, oid)
         if key in ctx.onodes:
             return ctx.onodes[key]
-        return self._onode(cid, oid)
+        pend = self._pend_onodes.get(key)
+        if pend is None:
+            return self._onode(cid, oid)        # parsed anew: ours
+        on = pend[0]
+        # a queued context's: its extents list is replaced, never
+        # changed in place, so one level of copy is enough
+        return None if on is None else \
+            {**on, "attrs": dict(on.get("attrs", {}))}
 
     def _apply_op(self, op: tuple, ctx: "_TxnCtx") -> None:
         kind = op[0]
         if kind == Op.MKCOLL:
             cid = op[1]
-            if self.kv.get(P_COLL, _cid_key(cid)) is not None \
-                    or _cid_key(cid) in ctx.new_colls:
+            if self._coll_exists(cid, ctx):
                 raise StoreError("EEXIST", f"collection {cid} exists")
             ctx.batch.set(P_COLL, _cid_key(cid), b"1")
-            ctx.new_colls.add(_cid_key(cid))
+            ctx.colls[_cid_key(cid)] = True
             return
         if kind == Op.RMCOLL:
             cid = op[1]
@@ -341,6 +740,7 @@ class BlueStore(ObjectStore):
                 raise StoreError("ENOTEMPTY",
                                  f"collection {cid} not empty")
             ctx.batch.rmkey(P_COLL, _cid_key(cid))
+            ctx.colls[_cid_key(cid)] = False
             return
         cid, oid = op[1], op[2]
         key = _onode_key(cid, oid)
@@ -393,7 +793,7 @@ class BlueStore(ObjectStore):
                 ctx.free_after.extend((u, c) for u, c, _ in on["extents"])
             ctx.onodes[key] = None
             ctx.batch.rmkeys_by_prefix(P_OMAP + "\x01" + key)
-            ctx.omap_over[key] = {"\x00CLEAR\x00": None}
+            ctx.omap_over[key] = {_CLEAR: None}
             return
         if kind == Op.SETATTRS:
             self._require_coll(cid, ctx)
@@ -428,7 +828,7 @@ class BlueStore(ObjectStore):
             okeys = dict(self._omap_staged(ctx, cid, src))
             pre_dst = P_OMAP + "\x01" + _onode_key(cid, dst)
             ctx.batch.rmkeys_by_prefix(pre_dst)
-            over = {"\x00CLEAR\x00": None}
+            over = {_CLEAR: None}
             for k, v in okeys.items():
                 ctx.batch.set(pre_dst, k, v)
                 over[k] = v
@@ -466,11 +866,11 @@ class BlueStore(ObjectStore):
             ctx.batch.rmkeys_by_prefix(
                 P_OMAP + "\x01" + _onode_key(old_cid, old_oid))
             ctx.omap_over[_onode_key(old_cid, old_oid)] = \
-                {"\x00CLEAR\x00": None}
+                {_CLEAR: None}
             ctx.onodes[_onode_key(new_cid, new_oid)] = on
             pre = P_OMAP + "\x01" + _onode_key(new_cid, new_oid)
             ctx.batch.rmkeys_by_prefix(pre)    # replace, never merge
-            over = {"\x00CLEAR\x00": None}
+            over = {_CLEAR: None}
             for k, v in okeys.items():
                 ctx.batch.set(pre, k, v)
                 over[k] = v
@@ -500,7 +900,7 @@ class BlueStore(ObjectStore):
             return
         if kind == Op.OMAP_CLEAR:
             ctx.batch.rmkeys_by_prefix(P_OMAP + "\x01" + key)
-            ctx.omap_over[key] = {"\x00CLEAR\x00": None}
+            ctx.omap_over[key] = {_CLEAR: None}
             return
         raise StoreError("EINVAL", f"unknown op {kind}")
 
@@ -517,40 +917,51 @@ class BlueStore(ObjectStore):
     def _omap_staged(self, ctx: "_TxnCtx", cid: CollectionId,
                      oid: Ghobject) -> dict[str, bytes]:
         key = _onode_key(cid, oid)
-        committed = self._onode(cid, oid) is not None
         staged_off = key in ctx.onodes and ctx.onodes[key] is None
-        base = self.omap_get(cid, oid) \
-            if committed and not staged_off else {}
-        over = ctx.omap_over.get(key, {})
-        if "\x00CLEAR\x00" in over:
-            base = {}
-        for k, v in over.items():
-            if k == "\x00CLEAR\x00":
-                continue
-            if v is None:
-                base.pop(k, None)
-            else:
-                base[k] = v
-        return base
+        base = self._omap_view(key) \
+            if self._onode(cid, oid) is not None and not staged_off else {}
+        return _overlaid(base, ctx.omap_over.get(key, {}))
+
+    def _omap_view(self, key: str) -> dict[str, bytes]:
+        """An onode's omap as queued: the KV's with the uncommitted
+        contexts' overlay on it. The overlay is read FIRST, here and in
+        every read: the commit thread puts a group into the KV and only
+        then takes its overlay away, so an overlay found may lie over
+        either KV (laying it twice changes nothing) and one not found
+        has left a KV that holds it."""
+        pend = self._pend_omap.get(key)
+        base = dict(self.kv.iterate(P_OMAP + "\x01" + key))
+        return base if pend is None else _overlaid(base, pend[0])
 
     # -- reads ---------------------------------------------------------------
 
     def list_collections(self) -> list[CollectionId]:
-        return sorted((_cid_from(k) for k, _ in self.kv.iterate(P_COLL)))
+        with self._lock:
+            pend = {k: v[0] for k, v in self._pend_colls.items()}
+        have = {k for k, _ in self.kv.iterate(P_COLL)}
+        have = (have | {k for k, e in pend.items() if e}) \
+            - {k for k, e in pend.items() if not e}
+        return sorted(_cid_from(k) for k in have)
 
     def collection_exists(self, cid: CollectionId) -> bool:
-        return self.kv.get(P_COLL, _cid_key(cid)) is not None
+        return self._coll_exists(cid)
 
     def collection_list(self, cid: CollectionId,
                         start: Ghobject | None = None,
                         max_count: int = 2 ** 31) -> list[Ghobject]:
         prefix = _cid_key(cid) + "\x01"
-        out = []
+        with self._lock:
+            pend = [(k, v[0] is not None)
+                    for k, v in self._pend_onodes.items()
+                    if k.startswith(prefix)]
+        have = set()
         for k, _ in self.kv.iterate(P_ONODE, start=prefix):
             if not k.startswith(prefix):
                 break                    # keys are ordered: prefix done
-            out.append(_oid_from(k[len(prefix):]))
-        out.sort()
+            have.add(k)
+        for k, exists in pend:
+            (have.add if exists else have.discard)(k)
+        out = sorted(_oid_from(k[len(prefix):]) for k in have)
         if start is not None:
             out = [o for o in out if o > start]
         return out[:max_count]
@@ -586,8 +997,7 @@ class BlueStore(ObjectStore):
     def omap_get(self, cid: CollectionId,
                  oid: Ghobject) -> dict[str, bytes]:
         self._require_onode(cid, oid)
-        pre = P_OMAP + "\x01" + _onode_key(cid, oid)
-        return dict(self.kv.iterate(pre))
+        return self._omap_view(_onode_key(cid, oid))
 
     def omap_get_values(self, cid: CollectionId, oid: Ghobject,
                         keys) -> dict[str, bytes]:
@@ -595,15 +1005,43 @@ class BlueStore(ObjectStore):
         return {k: omap[k] for k in keys if k in omap}
 
 
+def _overlaid(base: dict[str, bytes], over: dict) -> dict[str, bytes]:
+    """`base` with an omap overlay on it: `_CLEAR` empties it, a key set
+    to None goes, any other is set."""
+    if _CLEAR in over:
+        base = {}
+    for k, v in over.items():
+        if k == _CLEAR:
+            continue
+        if v is None:
+            base.pop(k, None)
+        else:
+            base[k] = v
+    return base
+
+
 class _TxnCtx:
-    """Per-transaction staging: onode edits + omap overlay + deferred
-    frees, folded into one KV batch at the end."""
+    """A transaction context (upstream's TransContext): what `prepare`
+    staged, onode edits + omap overlay + deferred frees + the slice of
+    the group's KV batch, and where the context stands in the
+    pipeline (`_SETTLED`'s comment) with the clock at each step."""
 
     def __init__(self, batch: KVTransaction):
         self.batch = batch
         self.onodes: dict[str, dict | None] = {}
-        self.new_colls: set[str] = set()
+        self.colls: dict[str, bool] = {}    # made (True), removed (False)
         self.omap_over: dict[str, dict] = {}
         self.free_after: list[tuple[int, int]] = []
         self.allocated: list[tuple[int, int]] = []
-        self.block_dirty = False
+        self.block_bytes = 0                # written into the block file
+        self.n_ops = 0
+        self.state = "prepare"
+        self.error: BaseException | None = None
+        self.seq = 0                        # in the store's queue order
+        self.group = 0                      # the group that covers it
+        self.loop = None                    # the caller's, to call back on
+        self.on_applied: list = []
+        self.on_commit: list = []
+        self.t0 = time.perf_counter()       # prepare began
+        self.t_queued = self.t_taken = self.t_synced = 0.0
+        self.block_sync_us = self.kv_submit_us = 0.0

@@ -3,9 +3,25 @@
 Re-creation of the reference's ObjectStore contract (src/os/ObjectStore.h,
 src/os/Transaction.h): collections of objects with byte extents, xattrs,
 and omap; mutations travel as atomic `Transaction` op batches through
-`queue_transaction`, with on_applied (readable) and on_commit (durable)
-callbacks. Backends: MemStore here; a file-backed store can implement the
-same API later.
+`queue_transaction`.
+
+The contract of `queue_transaction(txn)`, for every backend:
+
+  * its RETURN means queued, and no more: the transaction will be
+    applied, after every transaction queued before it;
+  * `on_applied` means readable: every read of the store returns the
+    transaction's state from then on;
+  * `on_commit` means durable: the transaction survives a kill of the
+    process and a fresh mount. Whatever is acknowledged to anyone else
+    (a sub-op's reply, a client's, a recovery push's) leaves from here.
+    Transactions commit in the order queued, so the commit of one
+    covers every one queued before it (`flush_commit`).
+
+MemStore and FileStore apply and commit inside the call, so both
+callbacks have fired when it returns. BlueStore prepares inside the
+call, fires `on_applied` before it returns, and commits on a thread of
+its own (`bluestore.py`): `on_commit` arrives later, on the caller's
+loop.
 """
 from __future__ import annotations
 
@@ -173,12 +189,16 @@ class Transaction:
 
 
 def _observed_txn(fn):
-    """Wrap a backend's queue_transaction with commit observability: a
+    """Wrap a backend's queue_transaction with observability: a
     `store_commit` trace span (the objectstore stage of an op's trace)
     and, when the hosting daemon attached a histogram sink
     (`store.commit_perf`), a `store_commit_us` latency sample. Both
-    gates are plain attribute/flag reads — the undecorated fast path
-    runs when neither is on."""
+    measure the CALL: what the caller's thread (an OSD's event loop)
+    pays for the transaction. On a store that commits inside the call
+    that is the commit; on one that commits later (BlueStore) it is
+    `prepare` alone, and the commit has spans of its own (`bstore_txc`,
+    `bstore_kv_sync`). Both gates are plain attribute/flag reads — the
+    undecorated fast path runs when neither is on."""
     @functools.wraps(fn)
     def queue_transaction(self, txn):
         perf = self.commit_perf
@@ -215,6 +235,13 @@ class ObjectStore:
         if impl is not None and not getattr(impl, "_observed", False):
             cls.queue_transaction = _observed_txn(impl)
 
+    #: called once, on a caller's loop, with the exception that left the
+    #: store unable to commit; `failed` is that exception from then on
+    #: (a store that commits inside `queue_transaction` raises there
+    #: instead and has neither)
+    on_fatal = None
+    failed: BaseException | None = None
+
     #: nominal device size for utilization reporting (statfs); daemons
     #: report used/capacity to the mgr, which drives OSD_NEARFULL/FULL
     capacity_bytes = 1 << 30
@@ -231,6 +258,12 @@ class ObjectStore:
     def used_bytes(self) -> int:
         return 0
 
+    def stats(self) -> dict:
+        """Counters of the store's commit path, for the admin socket's
+        `store stats`; a store that commits inside the call keeps
+        none."""
+        return {}
+
     # lifecycle
     def mkfs(self) -> None:
         raise NotImplementedError
@@ -244,6 +277,17 @@ class ObjectStore:
     # transactions
     def queue_transaction(self, txn: Transaction) -> None:
         raise NotImplementedError
+
+    def flush_commit(self, fn: Callable[[], None]) -> None:
+        """Call `fn` once every transaction queued so far is durable
+        (CollectionHandle::flush_commit): at once on a store that
+        commits inside `queue_transaction`."""
+        fn()
+
+    def flush(self) -> None:
+        """Return once every transaction queued so far is durable and
+        its callbacks have run (ObjectStore::flush). Nothing is
+        closed."""
 
     # collections
     def list_collections(self) -> list[CollectionId]:

@@ -399,7 +399,9 @@ class ReplicatedBackend(PGBackend):
         peers = {o for o in pg.acting
                  if o not in (CRUSH_NONE, self.host.whoami)}
         tid = self.new_tid()
-        fut = self._start_waiting(tid, peers)
+        me = self.host.whoami
+        # the primary's own copy is one of the commits the op waits for
+        fut = self._start_waiting(tid, peers | {me})
         # local first (the primary is always a replica of itself). The
         # caller logged the entry synchronously before this call, so a
         # retry after ANY mid-fan-out failure dup-detects instead of
@@ -410,6 +412,7 @@ class ReplicatedBackend(PGBackend):
         # transaction as the data for the same reason; here entry
         # append + local apply run in one event-loop slice.
         self.local_apply(oid, op, data, off=off)
+        self.host.store.flush_commit(lambda: self.sub_op_ack(tid, me))
         msg_payload = {
             "pgid": [pg.pgid.pool, pg.pgid.ps],
             "tid": tid,

@@ -358,9 +358,15 @@ class OSD(Dispatcher):
                       description="EC encode dispatch latency (µs)")
         self.perf.add("store_commit_us", type=TYPE_HISTOGRAM,
                       description="objectstore queue_transaction "
-                                  "latency (µs)")
+                                  "latency (µs): what the caller's "
+                                  "thread pays")
+        self.perf.add("store_kv_sync_us", type=TYPE_HISTOGRAM,
+                      description="one group commit on a store's commit "
+                                  "thread: block sync + KV submit (µs)")
         # the store feeds its commit latency into this daemon's histogram
         self.store.commit_perf = self.perf
+        # and takes the daemon down with it when it can commit no more
+        self.store.on_fatal = self._store_failed
         # op execution substrate: sharded queue (per-PG order, cross-PG
         # concurrency) + finisher for completions + per-op tracking
         self.hb_map = HeartbeatMap()
@@ -461,6 +467,13 @@ class OSD(Dispatcher):
             self.asok.register_command(
                 "status", lambda req: self._daemon_status(),
                 "daemon status")
+            self.asok.register_command(
+                "store stats",
+                lambda req: {"store": type(self.store).__name__,
+                             **self.store.stats()},
+                "the objectstore's commit pipeline: contexts, group "
+                "commits, syncs and bytes written (BlueStore), "
+                "acks_before_sync which must read 0")
             self.asok.register_command(
                 "ec offload status",
                 lambda req: self._offload_admin("status"),
@@ -992,6 +1005,20 @@ class OSD(Dispatcher):
                      backtrace="(injected)")
         await self.stop()
 
+    def _store_failed(self, e: BaseException) -> None:
+        """The store failed at a commit and takes no more transactions
+        (`ObjectStore.on_fatal`; upstream aborts the OSD): nothing it
+        had queued was acknowledged or will be, so the daemon dies as a
+        crash would have it die, its peers' sub-op waits time out and
+        the clients resend to whoever serves next."""
+        if self._stopping or (self._crash_task is not None
+                              and not self._crash_task.done()):
+            return
+        dout("osd", 0, f"osd.{self.whoami} objectstore failed, going "
+                       f"down: {e!r}")
+        self._crash_task = asyncio.get_running_loop().create_task(
+            self.fault_crash(f"objectstore failed: {e!r}"))
+
     async def _inject_bitrot(self, oid: str,
                              offset=None) -> dict:
         """Flip one byte of the local shard blob of `oid` (any PG),
@@ -1290,6 +1317,12 @@ class OSD(Dispatcher):
             # to fire after umount (applied data without its log entry)
             for pg in self.pgs.values():
                 pg.flush_persist()
+            # what this daemon queued is committed before it is gone,
+            # the flush's meta transactions included: a store that was
+            # flushed and gets nothing more writes nothing more, so a
+            # second mount of its directory (a revive, the benchmark's
+            # remount check) races nothing
+            self.store.flush()
             self.store.umount()
         finally:
             self._stop_event.set()
@@ -1867,6 +1900,12 @@ class OSD(Dispatcher):
                 {"tid": tid, "rc": -11, "epoch": self.osdmap.epoch,
                  "error": f"interval change: {e}"}))
         except Exception as e:
+            if self.store.failed is not None:
+                # the op met a dead store, not a bad object: a daemon
+                # that is going down answers nothing, and the client's
+                # resend finds whoever serves next
+                self._store_failed(self.store.failed)
+                return
             conn.send_message(MOSDOpReply(
                 {"tid": tid, "rc": -5, "epoch": self.osdmap.epoch,
                  "error": f"{type(e).__name__}: {e}"}))

@@ -585,14 +585,19 @@ class ECBackend(PGBackend):
     async def _fan_out(self, oid: str, payloads: dict, entry: LogEntry,
                        live: dict) -> None:
         tid = self.new_tid()
-        peers = {o for o in live.values() if o != self.host.whoami}
-        fut = self._start_waiting(tid, peers)
+        me = self.host.whoami
+        # the primary's own shard is one of the commits the op waits
+        # for: it is acknowledged, like a peer's, once its transaction
+        # is durable, and that covers the log intent queued before it
+        fut = self._start_waiting(tid, set(live.values()))
         failed = []
         entry_dict = entry.to_dict()    # once, not per peer
         for idx, osd in live.items():
             sub, chunk = payloads[idx]
-            if osd == self.host.whoami:
+            if osd == me:
                 self._apply_sub_write(oid, idx, sub, chunk)
+                self.host.store.flush_commit(
+                    lambda: self.sub_op_ack(tid, me))
                 continue
             try:
                 await self.host.send_osd(osd, MOSDECSubOpWrite(

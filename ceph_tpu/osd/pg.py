@@ -173,8 +173,16 @@ class PGInstance:
     def _meta_gh(self) -> Ghobject:
         return Ghobject(pool=self.pgid.pool, name=PGMETA_OID)
 
-    def persist_meta(self) -> None:
-        """Durable PG meta: a small static attr (head/tail/missing/seq)
+    def persist_meta(self, on_commit=None) -> None:
+        """Queue the durable PG meta; `on_commit` runs once it IS
+        durable (inside this call on a store that commits there, later
+        on the loop on BlueStore: `objectstore/store.py`). Whoever
+        acknowledges a log entry to a peer does it from `on_commit`.
+        Callers that send nothing go on at once: the store commits in
+        the order queued, so whatever they queue next, and acknowledge
+        from its commit, is durable after this.
+
+        A small static attr (head/tail/missing/seq)
         plus ONE omap key per log entry, written incrementally — only
         entries that changed since the last persist are (re)written.
         Re-serializing the whole 1000-entry window per op dominated the
@@ -218,11 +226,17 @@ class PGInstance:
                     for k, v in dirty.items() if v is not None}
             if sets:
                 txn.omap_setkeys(cid, gh, sets)
+        if on_commit is not None:
+            txn.register_on_commit(on_commit)
         try:
             self.host.store.queue_transaction(txn)
         except Exception:
-            # the delta never reached disk: hand it back or those
-            # entries vanish from the persisted omap forever
+            # the delta was never queued (a failed `prepare`): hand it
+            # back or those entries vanish from the persisted omap
+            # forever. A commit that fails later cannot be handed back:
+            # the store is dead from then on (`BlueStore._fail_group`),
+            # it refuses this OSD's next transaction, and nothing that
+            # waited for `on_commit` is ever acknowledged
             self.log.restore_dirty(full, dirty)
             raise
 
@@ -233,15 +247,20 @@ class PGInstance:
         flush per slice persists them all (the in-memory log is updated
         synchronously; only the disk write coalesces — the same
         window a journaling store batches into one commit). The PRIMARY
-        path keeps its synchronous persist: the dup-replay invariant
-        needs the intent durable within the ordered slice.
+        path queues its persist inside the ordered slice: the
+        dup-replay invariant needs the intent durable before the op is
+        answered, and it is, because the primary's own shard is queued
+        after it on the same store and the op waits for that shard's
+        commit (`ECBackend._fan_out`, `ReplicatedBackend.
+        execute_write`; tests/test_osd_commit_acks.py holds the order).
 
-        `ack` is a deferred (conn, reply) pair sent only AFTER the
-        persist succeeds: a sub-op is never acknowledged while its log
-        entry is not durable — a persist failure drops the acks, the
-        primary's sub-op wait times out, and the client resends
-        (exactly the pre-coalescing failure behavior). Flushed
-        explicitly by flush_persist() at daemon stop."""
+        `ack` is a deferred (conn, reply) pair sent only from the
+        persist's `on_commit`: a sub-op is never acknowledged while its
+        log entry, or the shard transaction queued before it, is not
+        durable — a persist that fails drops the acks, the primary's
+        sub-op wait times out, and the client resends (exactly the
+        pre-coalescing failure behavior). Flushed explicitly by
+        flush_persist() at daemon stop."""
         if ack is not None:
             self._persist_acks.append(ack)
         if self._persist_scheduled:
@@ -252,8 +271,16 @@ class PGInstance:
     def _persist_flush(self) -> None:
         self._persist_scheduled = False
         acks, self._persist_acks = self._persist_acks, []
+
+        def send_acks() -> None:
+            with tracer.section("osd.other"):    # not the store's
+                for conn, reply in acks:
+                    try:
+                        conn.send_message(reply)
+                    except Exception:
+                        pass    # dead peer conn: its timeout handles it
         try:
-            self.persist_meta()
+            self.persist_meta(on_commit=send_acks if acks else None)
         except Exception as e:
             # the delta was handed back by persist_meta's failure path;
             # the UNSENT acks make the primary time the sub-ops out, so
@@ -261,12 +288,6 @@ class PGInstance:
             dout("osd", 1, f"pg {self.pgid} coalesced meta persist "
                            f"failed: {type(e).__name__} {e} (delta "
                            f"restored; sub-op acks withheld)")
-            return
-        for conn, reply in acks:
-            try:
-                conn.send_message(reply)
-            except Exception:
-                pass            # dead peer conn: its timeout handles it
 
     def flush_persist(self) -> None:
         """Synchronously flush the coalesced persist (daemon stop:
@@ -975,14 +996,23 @@ class PGInstance:
             # only the HEAD push resolves the missing record: clone/
             # snapdir pushes are auxiliary state for the same object
             self.log.mark_recovered(p["oid"])
+        # what the push applied is durable before anyone is told of it:
+        # the pusher counts the object recovered on this OSD from the
+        # reply, the puller goes on to serve from it
         if p.get("reply_to") == "pull":
-            fut = self._push_waiters.get(f"pull:{p['oid']}")
-            if fut is not None and not fut.done():
-                fut.set_result(None)
+            key = f"pull:{p['oid']}"
+
+            def pulled() -> None:
+                fut = self._push_waiters.get(key)
+                if fut is not None and not fut.done():
+                    fut.set_result(None)
+            self.host.store.flush_commit(pulled)
         else:
-            conn.send_message(MOSDPGPushReply(
+            reply = MOSDPGPushReply(
                 {"pgid": p["pgid"], "oid": p["oid"],
-                 "from": self.host.whoami}))
+                 "from": self.host.whoami})
+            self.host.store.flush_commit(
+                lambda: conn.send_message(reply))
 
     # -- snaptrim (primary background task) ----------------------------------
 

@@ -9,7 +9,7 @@ throughput and p50/p99 op latency.
 Usage (standalone, boots its own vstart-style cluster):
     python -m ceph_tpu.tools.rados_bench [--seconds 5] [--concurrency 8]
         [--object-size 262144] [--pool-type replicated|erasure]
-        [--k 2] [--m 1] [--osds 3] [--backend memstore|filestore]
+        [--k 2] [--m 1] [--osds 3] [--backend memstore|filestore|bluestore]
 Prints one JSON object with write + read phases.
 
 The in-process programmatic entry (`run_bench`) is what `rados bench`
@@ -92,6 +92,9 @@ async def _main(args) -> dict:
         if args.backend == "filestore":
             from ceph_tpu.objectstore import FileStore
             return FileStore(f"{tmp}/osd{i}")
+        if args.backend == "bluestore":
+            from ceph_tpu.objectstore import BlueStore
+            return BlueStore(f"{tmp}/osd{i}")
         return None
 
     async with ephemeral_cluster(args.osds, prefix="rados-bench-",
@@ -128,7 +131,7 @@ def main() -> None:
     ap.add_argument("--m", type=int, default=1)
     ap.add_argument("--osds", type=int, default=3)
     ap.add_argument("--backend", default="memstore",
-                    choices=["memstore", "filestore"])
+                    choices=["memstore", "filestore", "bluestore"])
     args = ap.parse_args()
     out = asyncio.run(_main(args))
     print(json.dumps(out))

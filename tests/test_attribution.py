@@ -194,27 +194,42 @@ def _account(body, parts: dict | None = None
 def test_account_charges_a_synthetic_loop_to_its_labels():
     """Self time by label: `ms_dispatch` stops being charged the moment
     `osd_op` opens inside it, a bare callback falls to its code's
-    package, and the labels sum, with `idle`, to the wall clock."""
+    package, and the labels sum, with `idle`, to the wall clock. Each
+    stretch is held against what the body itself measured of it: under
+    six workers a 50 ms spin or sleep takes what the machine gives it,
+    and the account charges wall time."""
+    took: dict[str, float] = {}
+
+    def timed(label, fn, *args):
+        t0 = time.perf_counter()
+        fn(*args)
+        took[label] = (time.perf_counter() - t0) * 1e6
+
     async def body():
         loop = asyncio.get_running_loop()
         with tracer.span("ms_dispatch"):
-            _spin(0.050)
+            timed("msgr", _spin, 0.050)
             with tracer.span("osd_op"):
-                _spin(0.030)
+                timed("osd", _spin, 0.030)
+                t0 = time.perf_counter()
                 await asyncio.sleep(0.02)       # parked: idle
+                took["idle"] = (time.perf_counter() - t0) * 1e6
         done = loop.create_future()
 
         def bare():
-            time.sleep(0.040)
+            timed("unattributed", time.sleep, 0.040)
             done.set_result(None)
         loop.call_soon(bare)
         await done
 
     labels, wall, _spans = _account(body)
-    assert labels["msgr"] == pytest.approx(50_000, abs=5_000)
-    assert labels["osd"] == pytest.approx(30_000, abs=5_000)
-    assert labels["unattributed"] == pytest.approx(40_000, abs=5_000)
-    assert labels["idle"] == pytest.approx(20_000, abs=5_000)
+    nominal = {"msgr": 50_000, "osd": 30_000, "unattributed": 40_000,
+               "idle": 20_000}
+    for label, least in nominal.items():
+        assert took[label] >= least * 0.99, label
+        # the stretch itself, and the little the loop does around it
+        assert labels[label] == pytest.approx(
+            took[label], rel=0.05, abs=3_000), label
     assert sum(labels.values()) == pytest.approx(wall, rel=0.01)
     assert set(labels) == set(loopprof.LABELS) | {"idle"}
 
