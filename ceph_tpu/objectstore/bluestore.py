@@ -6,7 +6,7 @@ Re-creation of the reference BlueStore's architecture
   * one flat block file is the "raw device"; a bitmap allocator hands
     out 4 KiB allocation units (src/os/bluestore/BitmapAllocator) and
     its state persists through the same KV batch as the metadata it
-    serves (FreelistManager);
+    serves (FreelistManager): the whole bitmap as one value, deflated;
   * per-object metadata is an onode in the KV store (onode -> extent
     map -> blobs, BlueStore.cc _do_write/_do_alloc_write :16792,:16184):
     logical extents name (physical offset, length, crc32c), and every
@@ -21,23 +21,29 @@ Re-creation of the reference BlueStore's architecture
     and synced BEFORE the KV batch commits, so a crash in between
     leaves the old onode pointing at the old extents (BlueStore's txc
     ordering); freed extents return to the allocator only after the
-    batch is durable;
+    batch is durable. The caller's thread only STAGES an extent (units
+    allocated, csums made, a read-only view of the bytes kept on the
+    context, as _do_alloc_write queues bdev->aio_write on the txc): the
+    commit thread `pwrite`s it, ahead of its group's sync. A write that
+    replaces its object whole is staged as the transaction's own buffer,
+    uncopied; until the KV holds the group, reads are served from the
+    staged views;
   * a transaction is one atomic slice of a KV batch (the RocksDB
     WriteBatch role): apply is all-or-nothing at the KV WAL;
   * the commit is a pipeline (the txc state machine, _txc_state_proc
     :13556, and _kv_sync_thread :14191): `queue_transaction` PREPARES
     on the caller's thread (ops applied to staged onodes, units
-    allocated, extents written, csums made, the KV batch built),
+    allocated, extents staged, csums made, the KV batch built),
     queues the context and returns. One commit thread a mounted store
-    takes every context queued, syncs the block file once, submits ONE
-    synced KV batch for all of them and hands each context's
-    `on_commit` back to the loop that queued it, in queue order. The
-    group is whatever queued while the last sync ran: no timer, no
-    knob. Reads see a queued transaction at once (`on_applied` is
-    immediate, as upstream's is on BlueStore). A caller with no
-    running loop (the tools, a plain test) waits for its context and
-    gets the callbacks, or the commit's exception, before the call
-    returns.
+    takes every context queued, writes their staged extents, syncs the
+    block file once, submits ONE synced KV batch for all of them and
+    hands each context's `on_commit` back to the loop that queued it,
+    in queue order. The group is whatever queued while the last sync
+    ran: no timer, no knob. Reads see a queued transaction at once
+    (`on_applied` is immediate, as upstream's is on BlueStore). A
+    caller with no running loop (the tools, a plain test) waits for its
+    context and gets the callbacks, or the commit's exception, before
+    the call returns.
 
 Idiomatic divergences: writes rewrite the object's extent set rather
 than splicing sub-extents (the RMW/compression/blob-reuse machinery is
@@ -53,6 +59,7 @@ import os
 import threading
 import time
 import weakref
+import zlib
 
 from ceph_tpu.kv.keyvaluedb import KeyValueDB, KVTransaction
 from ceph_tpu.kv.lsm import LSMStore
@@ -84,6 +91,15 @@ _SETTLED = ("kv_submitted", "done", "failed")
 def _crc32c(data: bytes) -> int:
     from ceph_tpu.native import ec_native
     return ec_native.crc32c(data)
+
+
+def _pwrite_all(fd: int, data, offset: int) -> None:
+    """`os.pwrite` until all of `data` is written: positional and
+    unbuffered, and a write may come back short."""
+    view = memoryview(data)
+    while view:
+        n = os.pwrite(fd, view, offset)
+        view, offset = view[n:], offset + n
 
 
 def _cid_key(cid: CollectionId) -> str:
@@ -259,10 +275,14 @@ class BlueStore(ObjectStore):
         self._pend_onodes: dict[str, tuple[dict | None, int]] = {}
         self._pend_colls: dict[str, tuple[bool, int]] = {}
         self._pend_omap: dict[str, tuple[dict, int]] = {}
+        # extents staged and not yet in a group the KV holds, by first
+        # unit: what a read takes in place of the block file's bytes
+        self._pend_extents: dict[int, memoryview] = {}
         # the allocator as the KV holds it: the commit thread's alone
         self._durable_bits = bytearray()
         self._stats = dict.fromkeys(
-            ("txcs", "kv_syncs", "block_syncs", "block_bytes_written",
+            ("txcs", "kv_syncs", "block_syncs", "block_writes",
+             "block_bytes_written", "block_bytes_by_ref",
              "acks_before_sync"), 0)
 
     # -- lifecycle -----------------------------------------------------------
@@ -281,6 +301,10 @@ class BlueStore(ObjectStore):
         self.kv.open()
         self._fd = os.open(os.path.join(self.path, "block"), os.O_RDWR)
         blob = self.kv.get(P_SUPER, "freelist")
+        if blob and blob[:1] == b"\x78":
+            # deflated (a zlib stream's first byte; a bitmap written
+            # before this was raw, and begins with a unit's 0 or 1)
+            blob = zlib.decompress(blob)
         self.alloc = BitmapAllocator.from_bytes(blob) if blob \
             else BitmapAllocator()
         self._durable_bits = bytearray(self.alloc.bits)
@@ -316,9 +340,10 @@ class BlueStore(ObjectStore):
     def stats(self) -> dict:
         """The pipeline's counters since the store was made: contexts
         committed, groups, syncs of the block file and of the KV,
-        bytes written to each, the KV's flushes and compactions, and
-        `acks_before_sync`: callbacks delivered before the group that
-        covers them had finished, which must read 0."""
+        extents written and bytes written to each (`block_bytes_by_ref`
+        of them from a transaction's own buffer), the KV's flushes and
+        compactions, and `acks_before_sync`: callbacks delivered before
+        the group that covers them had finished, which must read 0."""
         kv = self._kv_stats()
         return {**self._stats,
                 "kv_fsyncs": kv.get("fsyncs", 0),
@@ -370,8 +395,12 @@ class BlueStore(ObjectStore):
         if "inline" in on:
             return on["inline"].encode("latin1")
         out = bytearray()
-        for unit, count, crc in on["extents"]:
-            chunk = os.pread(self._fd, count * AU, unit * AU)
+        with self._lock:
+            staged = [self._pend_extents.get(unit)
+                      for unit, _count, _crc in on["extents"]]
+        for (unit, count, crc), chunk in zip(on["extents"], staged):
+            if chunk is None:       # retired: the block file has it
+                chunk = os.pread(self._fd, count * AU, unit * AU)
             if len(chunk) != count * AU:
                 # truncated block file (crash mid-write): same EIO
                 # contract as a csum mismatch, so read-repair callers
@@ -395,40 +424,57 @@ class BlueStore(ObjectStore):
             out.extend(chunk)
         return bytes(out[:on["size"]])
 
-    def _stage_data(self, on: dict, data: bytes,
-                    ctx: "_TxnCtx") -> None:
-        """Replace the onode's data: inline when small, block extents
-        when large. Old extents are freed AFTER the batch commits."""
+    def _stage_data(self, on: dict, data, ctx: "_TxnCtx",
+                    by_ref: bool = False) -> None:
+        """Replace the onode's data with `data`, bytes-like and nobody's
+        to change any more (`by_ref`: the transaction's own buffer):
+        inline when small, block extents when large. The extents are
+        allocated, checksummed and STAGED here, as views of `data` on
+        the context; the commit thread writes them. Old extents are
+        freed AFTER the batch commits."""
         if "extents" in on:
             ctx.free_after.extend((u, c) for u, c, _ in on["extents"])
         on.pop("inline", None)
         on.pop("extents", None)
         on["size"] = len(data)
         if len(data) <= INLINE_MAX:
-            on["inline"] = data.decode("latin1")
+            on["inline"] = str(data, "latin1")
             return
         pad = (-len(data)) % AU
-        padded = data + b"\x00" * pad
-        units = len(padded) // AU
-        extents = []
+        if pad:
+            data, by_ref = b"".join((data, bytes(pad))), False
+        view = memoryview(data).toreadonly()
+        staged = []
         off = 0
         with self._lock:
             # a group that failed has given its units back by now, and
-            # the KV's log may still name them: nothing is written over
+            # the KV's log may still name them: nothing is staged over
             # them (`_fail_group` sets `failed` before it frees)
             self._refuse_if_failed()
-            got = self.alloc.allocate(units)
-        for unit, count in got:
-            ctx.allocated.append((unit, count))
-            chunk = padded[off:off + count * AU]
-            # positional and unbuffered: the commit thread syncs this
-            # descriptor while the caller's thread writes through it
-            os.pwrite(self._fd, chunk, unit * AU)
-            extents.append([unit, count,
-                            [int(x) for x in self.csum.calculate(chunk)]])
-            off += count * AU
-        on["extents"] = extents
-        ctx.block_bytes += len(padded)
+            got = self.alloc.allocate(len(view) // AU)
+            ctx.allocated.extend(got)
+            for unit, count in got:
+                chunk = view[off:off + count * AU]
+                self._pend_extents[unit] = chunk
+                staged.append((unit, count, chunk))
+                off += count * AU
+        ctx.block_writes.extend(staged)
+        on["extents"] = [[unit, count, self.csum.calculate(chunk).tolist()]
+                         for unit, count, chunk in staged]
+        ctx.block_bytes += len(view)
+        if by_ref:
+            ctx.by_ref_bytes += len(view)
+
+    def _unstage(self, ctxs: "list[_TxnCtx]", free: bool) -> None:
+        """These contexts' staged extents leave the reads' map: the
+        block file has them (`_retire`), or nothing ever will, and then
+        their units go back as well (`free`). Under `_lock`."""
+        for ctx in ctxs:
+            for unit, _count, _chunk in ctx.block_writes:
+                self._pend_extents.pop(unit, None)
+            ctx.block_writes = []
+            if free:
+                self.alloc.free(ctx.allocated)
 
     # -- the commit pipeline -------------------------------------------------
 
@@ -450,7 +496,7 @@ class BlueStore(ObjectStore):
             # all-or-nothing: nothing was queued, so units allocated
             # by earlier ops of this txn must return to the allocator
             with self._lock:
-                self.alloc.free(ctx.allocated)
+                self._unstage([ctx], free=True)
             raise
         for key, on in ctx.onodes.items():
             if on is None:
@@ -520,9 +566,11 @@ class BlueStore(ObjectStore):
 
     def _retire(self, group: "list[_TxnCtx]") -> None:
         """The KV holds the group now: its frees reach the allocator,
-        and what it laid over the KV goes unless a later context has
-        laid its own there since. Under `_lock`."""
+        its extents are read from the block file, and what it laid over
+        the KV goes unless a later context has laid its own there
+        since. Under `_lock`."""
         hi = group[-1].seq
+        self._unstage(group, free=False)
         for ctx in group:
             self.alloc.free(ctx.free_after)
             for pend, keys in ((self._pend_onodes, ctx.onodes),
@@ -533,10 +581,11 @@ class BlueStore(ObjectStore):
                         del pend[key]
 
     def _commit_group(self, group: "list[_TxnCtx]") -> None:
-        """On the commit thread: one sync of the block file if any
-        context wrote extents, one synced KV batch for all of them (the
-        freelist key once), the frees, then the callbacks handed back
-        in queue order."""
+        """On the commit thread: the contexts' staged extents written
+        in queue order, one sync of the block file if there were any,
+        one synced KV batch for all the contexts (the freelist key
+        once), the frees, then the callbacks handed back in queue
+        order."""
         if self._q.failed is not None:
             # prepared while the group before it failed: nothing
             # commits behind a hole
@@ -548,11 +597,18 @@ class BlueStore(ObjectStore):
             ctx.group, ctx.t_taken = seqno, t0
         kv0 = dict(self._kv_stats())
         block_bytes = sum(ctx.block_bytes for ctx in group)
+        by_ref_bytes = sum(ctx.by_ref_bytes for ctx in group)
+        block_writes = sum(len(ctx.block_writes) for ctx in group)
         freelist_bytes = 0
         try:
+            # data before metadata: the txc ordering (BlueStore.cc
+            # _txc_state_proc) — a crash in here leaves old onodes
+            # valid, the new units named by nothing durable
+            for ctx in group:
+                for unit, _count, chunk in ctx.block_writes:
+                    _pwrite_all(self._fd, chunk, unit * AU)
+            tw = time.perf_counter()
             if block_bytes:
-                # data before metadata: the txc ordering (BlueStore.cc
-                # _txc_state_proc) — a crash here leaves old onodes valid
                 os.fdatasync(self._fd)
             t1 = time.perf_counter()
             for ctx in group:
@@ -578,8 +634,14 @@ class BlueStore(ObjectStore):
                 for ctx in group:
                     for unit, count in ctx.free_after:
                         bits[unit:unit + count] = bytes(count)
-                batch.set(P_SUPER, "freelist", bytes(bits))
-                freelist_bytes = len(bits)
+                # deflated: a byte a unit is nearly all ones on a device
+                # that fills, and raw it was most of every group's log
+                # record, a megabyte of JSON escapes by the end of a
+                # window, encoded in ONE call that holds the GIL against
+                # the caller's loop; `zlib` works without the GIL
+                value = zlib.compress(bits, 1)
+                batch.set(P_SUPER, "freelist", value)
+                freelist_bytes = len(value)
             if batch.ops:
                 self.kv.submit_transaction(batch, sync=True)
             t2 = time.perf_counter()
@@ -594,7 +656,9 @@ class BlueStore(ObjectStore):
         st["txcs"] += len(group)
         st["kv_syncs"] += bool(batch.ops)
         st["block_syncs"] += bool(block_bytes)
+        st["block_writes"] += block_writes
         st["block_bytes_written"] += block_bytes
+        st["block_bytes_by_ref"] += by_ref_bytes
         perf = self.commit_perf
         if perf is not None:
             perf.hist_add("store_kv_sync_us", (t2 - t0) * 1e6)
@@ -603,6 +667,8 @@ class BlueStore(ObjectStore):
                 "bstore_kv_sync", t0, (t2 - t0) * 1e6,
                 {"group": seqno, "txcs": len(group),
                  "block_synced": int(bool(block_bytes)),
+                 "block_writes": block_writes,
+                 "block_write_us": (tw - t0) * 1e6,
                  "block_sync_us": (t1 - t0) * 1e6,
                  "kv_submit_us": (t2 - t1) * 1e6,
                  "kv_fsyncs": kv1.get("fsyncs", 0) - kv0.get("fsyncs", 0),
@@ -612,6 +678,7 @@ class BlueStore(ObjectStore):
                  "freelist_bytes": freelist_bytes},
                 getattr(self, "name", type(self).__name__))
         for ctx in group:
+            ctx.block_write_us = (tw - t0) * 1e6
             ctx.block_sync_us = (t1 - t0) * 1e6
             ctx.kv_submit_us = (t2 - t1) * 1e6
             ctx.t_synced = t2
@@ -620,10 +687,11 @@ class BlueStore(ObjectStore):
         self._hand_back(group)
 
     def _fail_group(self, group: "list[_TxnCtx]", e: BaseException) -> None:
-        """A sync or the KV failed (ENOSPC, EIO, a test's crash hook):
-        no context of the group, and none queued behind it, commits or
-        calls back; their units return to the allocator, the frees they
-        staged never happen, and the store takes no more transactions
+        """A block write, a sync or the KV failed (ENOSPC, EIO, a test's
+        crash hook): no context of the group, and none queued behind
+        it, commits or calls back; their units return to the allocator
+        and their staged extents are let go, the frees they staged
+        never happen, and the store takes no more transactions
         (upstream aborts the OSD; here its sub-op waits time out and
         the clients resend)."""
         q = self._q
@@ -632,8 +700,7 @@ class BlueStore(ObjectStore):
             group = group + q.queued
             q.queued = []
         with self._lock:
-            for ctx in group:
-                self.alloc.free(ctx.allocated)
+            self._unstage(group, free=True)
         for ctx in group:
             ctx.error, ctx.state = e, "failed"
         if self._fatal_told:
@@ -683,7 +750,9 @@ class BlueStore(ObjectStore):
                      "block_sync_us": ctx.block_sync_us,
                      "kv_submit_us": ctx.kv_submit_us,
                      "deliver_us": (now - ctx.t_synced) * 1e6,
+                     "block_write_us": ctx.block_write_us,
                      "ops": ctx.n_ops, "bytes": ctx.block_bytes,
+                     "by_ref_bytes": ctx.by_ref_bytes,
                      "group": ctx.group, "ran_ahead": bool(ran_ahead)},
                     getattr(self, "name", type(self).__name__))
             ctx.state = "done"
@@ -755,11 +824,16 @@ class BlueStore(ObjectStore):
             offset, data = op[3], op[4]
             on = self._staged(ctx, cid, oid) or \
                 {"size": 0, "inline": "", "attrs": {}}
-            cur = bytearray(self._read_staged(on))
-            if len(cur) < offset:
-                cur.extend(b"\x00" * (offset - len(cur)))
-            cur[offset:offset + len(data)] = data
-            self._stage_data(on, bytes(cur), ctx)
+            if offset == 0 and on["size"] <= len(data):
+                # the object replaced whole (every push and write_full):
+                # `Transaction.write` made the buffer the store's
+                self._stage_data(on, data, ctx, by_ref=True)
+            else:
+                cur = bytearray(self._read_staged(on))
+                if len(cur) < offset:
+                    cur.extend(b"\x00" * (offset - len(cur)))
+                cur[offset:offset + len(data)] = data
+                self._stage_data(on, cur, ctx)
             ctx.onodes[key] = on
             return
         if kind == Op.ZERO:
@@ -771,7 +845,7 @@ class BlueStore(ObjectStore):
             if len(cur) < offset + length:
                 cur.extend(b"\x00" * (offset + length - len(cur)))
             cur[offset:offset + length] = b"\x00" * length
-            self._stage_data(on, bytes(cur), ctx)
+            self._stage_data(on, cur, ctx)
             ctx.onodes[key] = on
             return
         if kind == Op.TRUNCATE:
@@ -784,7 +858,7 @@ class BlueStore(ObjectStore):
                 cur.extend(b"\x00" * (size - len(cur)))
             else:
                 del cur[size:]
-            self._stage_data(on, bytes(cur), ctx)
+            self._stage_data(on, cur, ctx)
             ctx.onodes[key] = on
             return
         if kind == Op.REMOVE:
@@ -847,7 +921,7 @@ class BlueStore(ObjectStore):
             if len(cur) < dst_off:
                 cur.extend(b"\x00" * (dst_off - len(cur)))
             cur[dst_off:dst_off + len(sdata)] = sdata
-            self._stage_data(don, bytes(cur), ctx)
+            self._stage_data(don, cur, ctx)
             ctx.onodes[_onode_key(cid, dst)] = don
             return
         if kind == Op.COLL_MOVE_RENAME:
@@ -1022,9 +1096,10 @@ def _overlaid(base: dict[str, bytes], over: dict) -> dict[str, bytes]:
 
 class _TxnCtx:
     """A transaction context (upstream's TransContext): what `prepare`
-    staged, onode edits + omap overlay + deferred frees + the slice of
-    the group's KV batch, and where the context stands in the
-    pipeline (`_SETTLED`'s comment) with the clock at each step."""
+    staged, onode edits + omap overlay + extents to write + deferred
+    frees + the slice of the group's KV batch, and where the context
+    stands in the pipeline (`_SETTLED`'s comment) with the clock at
+    each step."""
 
     def __init__(self, batch: KVTransaction):
         self.batch = batch
@@ -1033,7 +1108,10 @@ class _TxnCtx:
         self.omap_over: dict[str, dict] = {}
         self.free_after: list[tuple[int, int]] = []
         self.allocated: list[tuple[int, int]] = []
-        self.block_bytes = 0                # written into the block file
+        #: (unit, count, read-only view): for the commit thread to write
+        self.block_writes: list[tuple[int, int, memoryview]] = []
+        self.block_bytes = 0                # staged for the block file
+        self.by_ref_bytes = 0               # of them, the txn's own buffer
         self.n_ops = 0
         self.state = "prepare"
         self.error: BaseException | None = None
@@ -1044,4 +1122,4 @@ class _TxnCtx:
         self.on_commit: list = []
         self.t0 = time.perf_counter()       # prepare began
         self.t_queued = self.t_taken = self.t_synced = 0.0
-        self.block_sync_us = self.kv_submit_us = 0.0
+        self.block_write_us = self.block_sync_us = self.kv_submit_us = 0.0

@@ -21,7 +21,14 @@ MemStore and FileStore apply and commit inside the call, so both
 callbacks have fired when it returns. BlueStore prepares inside the
 call, fires `on_applied` before it returns, and commits on a thread of
 its own (`bluestore.py`): `on_commit` arrives later, on the caller's
-loop.
+loop. Its prepare writes no object data: an extent is staged on the
+caller's thread (units, csums, a read-only view of the bytes) and
+written into the block file by the commit thread, before the sync that
+precedes the metadata which names it; a write that replaces its object
+whole is written from the buffer `Transaction.write` was given. Until
+the metadata is durable the reads are served from the staged views; a
+store that failed at a commit lets them go, and answers a read of what
+it never wrote with EIO.
 """
 from __future__ import annotations
 
@@ -94,8 +101,9 @@ class Transaction:
         # payloads — bytes, and the read-only memoryviews the zero-copy
         # receive path delivers — pass through by reference, and a store
         # may KEEP what it is given here for as long as the object
-        # lives (MemStore does): whoever hands a read-only view to a
-        # transaction gives up the buffer under it for good.
+        # lives (MemStore does; BlueStore until its commit thread has
+        # written it to the block file): whoever hands a read-only view
+        # to a transaction gives up the buffer under it for good.
         # A sanitizer-guarded rx view unwraps first (with its
         # use-after-recycle check) so it keeps the by-reference path
         # instead of being silently bytes()-copied below.
@@ -196,8 +204,8 @@ def _observed_txn(fn):
     measure the CALL: what the caller's thread (an OSD's event loop)
     pays for the transaction. On a store that commits inside the call
     that is the commit; on one that commits later (BlueStore) it is
-    `prepare` alone, and the commit has spans of its own (`bstore_txc`,
-    `bstore_kv_sync`). Both gates are plain attribute/flag reads — the
+    `prepare` alone (no block write is in it), and the commit has spans
+    of its own (`bstore_txc`, `bstore_kv_sync`). Both gates are plain attribute/flag reads — the
     undecorated fast path runs when neither is on."""
     @functools.wraps(fn)
     def queue_transaction(self, txn):

@@ -7,6 +7,7 @@ transaction whole and a prefix of each collection's."""
 from __future__ import annotations
 
 import asyncio
+import errno
 import gc
 import os
 import shutil
@@ -27,9 +28,10 @@ from tests.test_cluster import run
 
 SEEDS = range(20)
 KILLS = ("clean_umount", "dropped_while_queued", "fail_before_kv",
-         "fail_after_wal", "unsynced_tails_cut")
+         "fail_after_wal", "unsynced_tails_cut", "fail_at_block_sync")
 CID = CollectionId.make_pg(7, 0)
 BIG = INLINE_MAX + 3 * AU
+SHARD = 512 * 1024      # an EC shard of the benchmark's 4 MiB objects
 
 
 def _cid(c: int) -> CollectionId:
@@ -81,11 +83,12 @@ def _model_of(store: BlueStore) -> dict:
 
 
 class Syncs:
-    """`os.fsync` and `os.fdatasync` recorded, ("fdatasync" | "fsync",
-    begin | end, thread, file name), and a store's commit thread held
-    in them while `gate` is clear. Only such a thread: a sync that
-    anything else makes on the event loop (a mon's store) would hold
-    the test with it."""
+    """`os.fsync`, `os.fdatasync` and `os.pwrite` recorded, ("fdatasync"
+    | "fsync" | "pwrite", begin | end, thread, file name), and a store's
+    commit thread held in the syncs while `gate` is clear. Only such a
+    thread: a sync that anything else makes on the event loop (a mon's
+    store) would hold the test with it. `fail[name]` is raised, once,
+    by the next `name` on a block file."""
 
     def __init__(self, monkeypatch):
         self.log: list[tuple] = []
@@ -94,22 +97,29 @@ class Syncs:
         self.entered = threading.Event()
         self.sizes: dict[str, int] = {}     # file -> size at its last sync
         self.only: threading.Thread | None = None   # the one thread held
-        for name in ("fsync", "fdatasync"):
+        self.fail: dict[str, OSError] = {}
+        for name in ("fsync", "fdatasync", "pwrite"):
             monkeypatch.setattr(os, name, self._wrap(name, getattr(os, name)))
 
     def _wrap(self, name, real):
-        def sync(fd):
+        is_sync = name != "pwrite"
+
+        def call(fd, *args):
             path = os.readlink(f"/proc/self/fd/{fd}")
             self.log.append((name, "begin", threading.get_ident(), path))
             me = threading.current_thread()
-            if me.name == "bstore-kv-sync" and self.only in (None, me):
+            if is_sync and me.name == "bstore-kv-sync" \
+                    and self.only in (None, me):
                 self.entered.set()
                 self.gate.wait()
-            real(fd)
-            if os.path.isfile(path):
+            if os.path.basename(path) == "block" and name in self.fail:
+                raise self.fail.pop(name)
+            out = real(fd, *args)
+            if is_sync and os.path.isfile(path):
                 self.sizes[path] = os.path.getsize(path)
             self.log.append((name, "end", threading.get_ident(), path))
-        return sync
+            return out
+        return call
 
     def hold(self) -> None:
         self.entered.clear()
@@ -117,6 +127,11 @@ class Syncs:
 
     def release(self) -> None:
         self.gate.set()
+
+    def of_block(self, name: str, edge: str = "end") -> list[int]:
+        """Where in the log the block file's `name`s are."""
+        return [n for n, (what, e, _t, p) in enumerate(self.log)
+                if (what, e, os.path.basename(p)) == (name, edge, "block")]
 
 
 @pytest.fixture
@@ -176,6 +191,12 @@ def test_seeded_sequences_hold_the_contract(tmp_path, syncs, seed, kill):
             if i == at and kill == "fail_after_wal":
                 await _settled(store)
                 store.kv.fail_after_wal = True
+            if i == at and kill == "fail_at_block_sync":
+                # between a group's block writes and their sync: the
+                # next group that writes an extent has written it and
+                # dies in the `fdatasync`
+                await _settled(store)
+                syncs.fail["fdatasync"] = OSError(errno.EIO, "injected")
             t = _transaction(txn)
             t.register_on_commit(lambda i=i: committed.add(i))
             try:
@@ -185,8 +206,15 @@ def test_seeded_sequences_hold_the_contract(tmp_path, syncs, seed, kill):
                 assert e.code == "EIO" and kill.startswith("fail_")
                 assert i > at
                 break
-            # queued is readable, whatever has committed
-            assert _model_of(store) == want[i], f"live state after {i}"
+            # queued is readable, whatever has committed; a store that
+            # died under the read has let its staged extents go, and
+            # what it never wrote is EIO
+            try:
+                assert _model_of(store) == want[i], f"live state after {i}"
+            except StoreError as e:
+                assert e.code == "EIO" and store.failed is not None
+                assert i >= at and kill.startswith("fail_")
+                break
             if i % 3 == 0:
                 await asyncio.sleep(0.001)      # let groups form and land
         if kill == "clean_umount":
@@ -222,16 +250,20 @@ def test_seeded_sequences_hold_the_contract(tmp_path, syncs, seed, kill):
         assert found == want[-1]
     if kill == "dropped_while_queued":
         assert committed < set(range(len(txns)))
-    if kill.startswith("fail_"):
+    if kill == "fail_at_block_sync" and "fdatasync" in syncs.fail:
+        # no transaction from `at` on wrote an extent: nothing died
+        assert committed == set(range(len(txns))) and found == want[-1]
+    elif kill.startswith("fail_"):
         # the group that failed, and all behind it, never called back
         # (a transaction that changes nothing syncs nothing and may
         # pass the KV's hook)
         dead = min(set(range(len(txns))) - committed)
         assert dead >= at and committed == set(range(dead))
-        # before the KV: data landed, metadata did not. After the log's
-        # sync: the group is replayed whole at the mount
-        assert found == want[dead - 1] if kill == "fail_before_kv" \
-            else found in want[dead:]
+        # before the KV, or before the block file's sync: data landed,
+        # metadata did not. After the log's sync: the group is replayed
+        # whole at the mount
+        assert found in want[dead:] if kill == "fail_after_wal" \
+            else found == want[dead - 1]
 
 
 @pytest.mark.parametrize("seed", [101, 102, 103])
@@ -427,7 +459,8 @@ def test_a_read_between_queue_and_commit_returns_the_queued(tmp_path, syncs):
     run(main())
 
 
-@pytest.mark.parametrize("hook", ["fail_before_kv", "fail_after_wal"])
+@pytest.mark.parametrize("hook", ["fail_before_kv", "fail_after_wal",
+                                  "pwrite_enospc"])
 def test_a_failed_group_fires_nothing_and_restores_the_allocator(
         tmp_path, syncs, hook):
     fired = []
@@ -438,8 +471,17 @@ def test_a_failed_group_fires_nothing_and_restores_the_allocator(
         store.queue_transaction(_write("keep"))
         await _settled(store)
         used = sum(store.alloc.bits)
-        setattr(store if hook == "fail_before_kv" else store.kv, hook, True)
         syncs.hold()
+        if hook == "pwrite_enospc":
+            # the device is full at the next block write: the three
+            # queue while the thread stands in an inline object's sync,
+            # since no write waits at the fixture's gate
+            syncs.fail["pwrite"] = OSError(errno.ENOSPC, "injected")
+            store.queue_transaction(_write("held", 1))
+            await asyncio.to_thread(syncs.entered.wait, 10)
+        else:
+            setattr(store if hook == "fail_before_kv" else store.kv,
+                    hook, True)
         for i in range(3):
             store.queue_transaction(_write(
                 f"lost{i}", on_commit=lambda: fired.append(1)))
@@ -449,6 +491,9 @@ def test_a_failed_group_fires_nothing_and_restores_the_allocator(
         await _settled(store)
         assert fired == []
         assert sum(store.alloc.bits) == used    # theirs back, `keep`'s kept
+        assert not store._pend_extents and not syncs.fail
+        assert isinstance(store.failed, OSError if hook == "pwrite_enospc"
+                          else SimulatedCrash)
         with pytest.raises(StoreError) as ei:
             store.queue_transaction(_write("more"))
         assert ei.value.code == "EIO"
@@ -458,6 +503,200 @@ def test_a_failed_group_fires_nothing_and_restores_the_allocator(
         store.umount()
 
     run(main())
+
+
+# -- a staged extent is written by the commit thread -------------------------
+
+@pytest.mark.parametrize("size", [INLINE_MAX, SHARD],
+                         ids=["inline", "shard"])
+@pytest.mark.parametrize("with_loop", [True, False], ids=["loop", "plain"])
+def test_block_writes_are_the_commit_threads_and_precede_its_syncs(
+        tmp_path, syncs, size, with_loop):
+    """No `pwrite` of the block file on the thread that queues, with a
+    loop or without; in a group every `pwrite` ends before the
+    `fdatasync` begins, and that ends before the KV log's `fsync`
+    begins. An object that fits its onode writes no block at all."""
+    def drive(store):
+        store.queue_transaction(Transaction().create_collection(CID))
+        for i in range(3):
+            store.queue_transaction(_write(f"o{i}", size))
+        return threading.get_ident()
+
+    async def main():
+        store = _store(tmp_path)
+        caller = drive(store)
+        await _settled(store)
+        return store, caller
+
+    if with_loop:
+        store, caller = run(main())
+    else:
+        store = _store(tmp_path)
+        caller = drive(store)
+    assert store.stats()["block_writes"] == (3 if size > INLINE_MAX else 0)
+    assert store.stats()["block_bytes_written"] == \
+        (3 * size if size > INLINE_MAX else 0)
+    store.umount()
+    writes = [e for e in syncs.log if e[0] == "pwrite"]
+    assert all(os.path.basename(p) == "block" for _n, _e, _t, p in writes)
+    assert len(writes) == (6 if size > INLINE_MAX else 0)   # begin and end
+    assert caller not in {t for _n, _e, t, _p in writes}
+    assert {t for _n, _e, t, _p in writes} \
+        <= {t for n, _e, t, p in syncs.log
+            if n == "fdatasync" and os.path.basename(p) == "block"}
+    # each group: its writes, then the block sync, then the log's; no
+    # write of the next group slips between the two syncs
+    wrote = syncs.of_block("pwrite")
+    began = syncs.of_block("pwrite", "begin")
+    synced = syncs.of_block("fdatasync", "begin")
+    assert not wrote or wrote[-1] < synced[-1]
+    for before, s in zip([-1, *synced], synced):
+        assert [n for n in wrote if before < n < s], \
+            "a block sync that no write preceded"
+        done, log_sync = (
+            next(n for n, (what, e, _t, p) in enumerate(syncs.log)
+                 if n > s and (what, e, os.path.basename(p)) == want)
+            for want in (("fdatasync", "end", "block"),
+                         ("fsync", "begin", "wal.log")))
+        assert done < log_sync
+        assert not [n for n in began if s < n < log_sync]
+
+
+@pytest.mark.parametrize("size", [INLINE_MAX, SHARD],
+                         ids=["inline", "shard"])
+def test_what_queues_behind_an_unwritten_object_sees_its_bytes(
+        tmp_path, syncs, size):
+    """A read, a partial overwrite and a clone queued behind a write
+    whose extents are still only staged return its bytes; the block
+    file has none of them yet."""
+    async def main():
+        store = _store(tmp_path)
+        store.queue_transaction(Transaction().create_collection(CID))
+        store.queue_transaction(_write("warm"))
+        await _settled(store)
+        syncs.hold()
+        store.queue_transaction(_write("held", 1))
+        await asyncio.to_thread(syncs.entered.wait, 10)
+        # the thread stands in a sync: what follows stays staged
+        data, patch = os.urandom(size), os.urandom(5000)
+        store.queue_transaction(Transaction().write(CID, _gh("a"), 0, data))
+        n_writes = len(syncs.of_block("pwrite", "begin"))
+        assert store.read(CID, _gh("a")) == data
+        assert store.read(CID, _gh("a"), 4090, 12) == data[4090:4102]
+        store.queue_transaction(
+            Transaction().write(CID, _gh("a"), 4000, patch)
+            .clone(CID, _gh("a"), _gh("b")))
+        patched = data[:4000] + patch + data[9000:]
+        assert store.read(CID, _gh("a")) == patched
+        assert store.read(CID, _gh("b")) == patched
+        assert store.corrupt(CID, _gh("b"), 7)
+        rotten = bytearray(patched)
+        rotten[7] ^= 1
+        assert store.read(CID, _gh("b")) == bytes(rotten)
+        assert len(syncs.of_block("pwrite", "begin")) == n_writes
+        assert bool(store._pend_extents) == (size > INLINE_MAX)
+        syncs.release()
+        await _settled(store)
+        assert not store._pend_extents
+        assert store.read(CID, _gh("a")) == patched     # the block file's
+        assert store.read(CID, _gh("b")) == bytes(rotten)
+        store.umount()
+        return patched, bytes(rotten)
+
+    patched, rotten = run(main())
+    fresh = _fresh_model(str(tmp_path / "bs"))[CID.pg_seed]
+    assert fresh["a"]["data"] == patched and fresh["b"]["data"] == rotten
+
+
+@pytest.mark.parametrize("shape", ["whole_bytes", "whole_view",
+                                   "whole_over_shorter", "unaligned",
+                                   "partial_offset", "partial_shorter",
+                                   "inline"])
+def test_a_whole_object_write_is_staged_as_the_transactions_buffer(
+        tmp_path, syncs, shape):
+    """`Op.WRITE` at offset 0 over nothing longer (every push and
+    write_full) stages the buffer `Transaction.write` was given, uncopied,
+    where its length is whole units; any other write stages a private
+    buffer. `bstore_txc` says which, and `stats()`."""
+    data = os.urandom(SHARD + 100 if shape == "unaligned" else
+                      INLINE_MAX if shape == "inline" else SHARD)
+    given = memoryview(data).toreadonly() if shape == "whole_view" else data
+    by_ref = shape.startswith("whole")
+
+    async def main():
+        store = _store(tmp_path)
+        store.queue_transaction(Transaction().create_collection(CID))
+        first = {"whole_over_shorter": SHARD - AU, "partial_offset": SHARD,
+                 "partial_shorter": SHARD + AU}.get(shape)
+        if first:
+            store.queue_transaction(_write("a", first))
+        await _settled(store)
+        base = store.stats()
+        tracer.enable()
+        try:
+            cursor = tracer.collector().last_seq()
+            syncs.hold()
+            t = Transaction().touch(CID, _gh("a")).write(
+                CID, _gh("a"), AU if shape == "partial_offset" else 0, given)
+            store.queue_transaction(t)
+            staged = list(store._pend_extents.values())
+            syncs.release()
+            await _settled(store)
+            txc, = [s for s in tracer.collector().spans()
+                    if s["seq"] > cursor and s["name"] == "bstore_txc"]
+        finally:
+            tracer.disable()
+        after = store.stats()
+        want = store.read(CID, _gh("a"))
+        store.umount()
+        return staged, txc["tags"], base, after, want
+
+    staged, tags, base, after, got = run(main())
+    assert got[AU if shape == "partial_offset" else 0:][:len(data)] == data
+    if shape == "inline":
+        assert staged == [] and tags["bytes"] == tags["by_ref_bytes"] == 0
+        return
+    assert staged and (all(v.obj is data for v in staged)) == by_ref
+    assert all(v.readonly for v in staged)
+    assert tags["bytes"] == sum(len(v) for v in staged) >= len(data)
+    assert tags["by_ref_bytes"] == (tags["bytes"] if by_ref else 0)
+    assert after["block_bytes_by_ref"] - base["block_bytes_by_ref"] \
+        == tags["by_ref_bytes"]
+    assert after["block_bytes_written"] - base["block_bytes_written"] \
+        == tags["bytes"]
+
+
+@pytest.mark.parametrize("legacy", [False, True], ids=["deflated", "raw"])
+def test_the_freelist_is_one_deflated_value_and_a_raw_one_still_mounts(
+        tmp_path, legacy):
+    """Every group that allocates or frees logs the whole bitmap as one
+    KV value, deflated (raw, a byte a unit, it was most of a group's
+    log record and its JSON held the GIL against the loop); a store
+    whose last group was written raw, by the program before this,
+    mounts to the same allocator."""
+    import zlib
+
+    store = _store(tmp_path)
+    store.queue_transaction(Transaction().create_collection(CID))
+    for i in range(4):
+        store.queue_transaction(_write(f"o{i}", SHARD))
+    store.queue_transaction(Transaction().remove(CID, _gh("o1")))
+    bits = bytes(store.alloc.bits)
+    assert bits.count(1) == 3 * SHARD // AU and bits.count(0) == SHARD // AU
+    value = store.kv.get(bluestore.P_SUPER, "freelist")
+    assert value[:1] == b"\x78" and zlib.decompress(value) == bits
+    assert len(value) < len(bits) // 10
+    if legacy:
+        raw = store.kv.transaction()
+        raw.set(bluestore.P_SUPER, "freelist", bits)
+        store.kv.submit_transaction(raw, sync=True)
+    store.umount()
+    again = _store(tmp_path)
+    assert bytes(again.alloc.bits) == bits and again.alloc._free == SHARD // AU
+    again.queue_transaction(_write("o9", SHARD))    # into the hole
+    assert bytes(again.alloc.bits) == b"\x01" * len(bits)
+    assert again.kv.get(bluestore.P_SUPER, "freelist")[:1] == b"\x78"
+    again.umount()
 
 
 def test_umount_drains_the_queue(tmp_path, syncs):
@@ -606,22 +845,40 @@ def test_the_pipeline_is_traced(tmp_path):
     txcs = [s for s in spans if s["name"] == "bstore_txc"]
     groups = [s for s in spans if s["name"] == "bstore_kv_sync"]
     assert len(txcs) == 2 and 1 <= len(groups) <= 2
+    by_group = {g["tags"]["group"]: g["tags"] for g in groups}
     for s in txcs:
         assert set(s["tags"]) >= {"prepare_us", "queued_us", "block_sync_us",
                                   "kv_submit_us", "deliver_us", "ops",
-                                  "bytes", "group", "ran_ahead"}
+                                  "bytes", "group", "ran_ahead",
+                                  "block_write_us", "by_ref_bytes"}
         assert s["tags"]["ran_ahead"] is False
+        # the writes are a part of the block-sync leg, its group's, and
+        # no fifth leg: the five still sum to the span
+        assert 0 <= s["tags"]["block_write_us"] <= s["tags"]["block_sync_us"]
+        assert s["tags"]["block_write_us"] \
+            == by_group[s["tags"]["group"]]["block_write_us"]
         legs = sum(s["tags"][k] for k in ("prepare_us", "queued_us",
                                           "block_sync_us", "kv_submit_us",
                                           "deliver_us"))
         assert legs == pytest.approx(s["duration_us"], rel=0.05, abs=50)
     assert sorted(s["tags"]["bytes"] for s in txcs) == [0, BIG + AU - BIG % AU
                                                         if BIG % AU else BIG]
+    # `a` replaced its object whole from the transaction's own buffer
+    assert [s["tags"]["by_ref_bytes"] for s in txcs] \
+        == [s["tags"]["bytes"] for s in txcs]
     for g in groups:
         assert set(g["tags"]) >= {"txcs", "block_synced", "kv_fsyncs",
                                   "block_bytes", "kv_bytes",
-                                  "freelist_bytes", "group"}
+                                  "freelist_bytes", "group",
+                                  "block_writes", "block_write_us",
+                                  "block_sync_us", "kv_submit_us"}
         assert g["tags"]["kv_fsyncs"] >= 1 and g["tags"]["kv_bytes"] > 0
+        assert g["tags"]["block_write_us"] <= g["tags"]["block_sync_us"]
+        assert g["tags"]["block_sync_us"] + g["tags"]["kv_submit_us"] \
+            == pytest.approx(g["duration_us"], abs=1)
+        assert bool(g["tags"]["block_writes"]) \
+            == bool(g["tags"]["block_bytes"])
+    assert sum(g["tags"]["block_writes"] for g in groups) == 1
     assert sum(g["tags"]["txcs"] for g in groups) == 2
     assert {s["tags"]["group"] for s in txcs} \
         == {g["tags"]["group"] for g in groups}

@@ -239,6 +239,39 @@ def test_killed_with_the_intent_durable_and_no_shard_the_resend_reexecutes(
     run(body())
 
 
+@pytest.mark.parametrize("pool", sorted(POOLS))
+def test_a_clients_whole_object_writes_reach_the_block_file_by_reference(
+        tmp_path, pool):
+    """`write_full` of new objects whose shards (1 MiB over k = 2) and
+    replicas are whole units: every block byte the stores write is
+    written from the buffer the transaction was given, by the commit
+    threads; none is copied on the way. The benchmark's BlueStore cell
+    is this traffic, and `PERF.md` takes its share from `bstore_txc`."""
+    async def body():
+        c, _cl, io = await _cluster(tmp_path, "bluestore", pool)
+        try:
+            await io.write_full("warm", b"w" * 70_000)
+            before = {i: o.store.stats() for i, o in c.osds.items()}
+            values = {f"o{n}": bytes([n]) * (1 << 20) for n in range(4)}
+            await asyncio.gather(*(io.write_full(k, v)
+                                   for k, v in values.items()))
+            for k, v in values.items():
+                assert await io.read(k) == v
+            wrote = by_ref = 0
+            for i, o in c.osds.items():
+                st = o.store.stats()
+                wrote += st["block_bytes_written"] \
+                    - before[i]["block_bytes_written"]
+                by_ref += st["block_bytes_by_ref"] \
+                    - before[i]["block_bytes_by_ref"]
+                assert st["block_writes"] > before[i]["block_writes"]
+            assert wrote == 4 * (3 << 19 if pool == "erasure" else 3 << 20)
+            assert by_ref == wrote
+        finally:
+            await c.stop()
+    run(body())
+
+
 def test_the_admin_socket_serves_the_stores_counters(tmp_path):
     from ceph_tpu.osd.daemon import OSD
     from ceph_tpu.utils.admin_socket import admin_command
