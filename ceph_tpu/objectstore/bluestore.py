@@ -33,8 +33,9 @@ Re-creation of the reference BlueStore's architecture
   * the commit is a pipeline (the txc state machine, _txc_state_proc
     :13556, and _kv_sync_thread :14191): `queue_transaction` PREPARES
     on the caller's thread (ops applied to staged onodes, units
-    allocated, extents staged, csums made, the KV batch built),
-    queues the context and returns. One commit thread a mounted store
+    allocated, extents staged, csums made or taken from the write that
+    brought them, the KV batch built), queues the context and returns.
+    One commit thread a mounted store
     takes every context queued, writes their staged extents, syncs the
     block file once, submits ONE synced KV batch for all of them and
     hands each context's `on_commit` back to the loop that queued it,
@@ -61,6 +62,8 @@ import time
 import weakref
 import zlib
 
+import numpy as np
+
 from ceph_tpu.kv.keyvaluedb import KeyValueDB, KVTransaction
 from ceph_tpu.kv.lsm import LSMStore
 from ceph_tpu.objectstore.store import (ObjectStore, Op, StoreError,
@@ -73,6 +76,13 @@ from ceph_tpu.utils.dout import dout
 
 AU = 4096                    # allocation unit (min_alloc_size)
 INLINE_MAX = 64 * 1024       # deferred/inline object size ceiling
+#: identities (a collection's, an object's in its collection) whose key
+#: a store remembers. One store of the benchmark's deployment touches
+#: about a hundred in a window: 16 ops in flight x a new object and its
+#: rollback generation, its 32 PG-meta objects and 33 collections. Ten
+#: times that, so that the long-lived ones are encoded again (the memo
+#: is emptied when full) once in some 450 new objects
+KEY_MEMO = 1024
 
 # KV prefixes (the reference's column families, BlueStore.cc PREFIX_*)
 P_SUPER = "S"
@@ -101,6 +111,9 @@ def _pwrite_all(fd: int, data, offset: int) -> None:
         n = os.pwrite(fd, view, offset)
         view, offset = view[n:], offset + n
 
+
+# The key FORMAT: pure functions of frozen ids. A mounted store asks its
+# memo (`BlueStore._cid_key`, `_onode_key`), which asks these once an id.
 
 def _cid_key(cid: CollectionId) -> str:
     return json.dumps(cid_key(cid))
@@ -241,6 +254,8 @@ def _kv_sync_thread(ref, q: _CommitQueue) -> None:
 
 class BlueStore(ObjectStore):
 
+    csum_block = AU
+
     def __init__(self, path: str, kv: KeyValueDB | None = None):
         self.path = path
         self.kv = kv if kv is not None else LSMStore(
@@ -283,7 +298,12 @@ class BlueStore(ObjectStore):
         self._stats = dict.fromkeys(
             ("txcs", "kv_syncs", "block_syncs", "block_writes",
              "block_bytes_written", "block_bytes_by_ref",
-             "acks_before_sync"), 0)
+             "csum_bytes_reused", "acks_before_sync"), 0)
+        # id -> key, a collection's under the id and an onode's under
+        # (cid, oid), and the encodings made since the last context
+        # was queued
+        self._keys: dict = {}
+        self._key_encodes = 0
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -341,7 +361,8 @@ class BlueStore(ObjectStore):
         """The pipeline's counters since the store was made: contexts
         committed, groups, syncs of the block file and of the KV,
         extents written and bytes written to each (`block_bytes_by_ref`
-        of them from a transaction's own buffer), the KV's flushes and
+        of them from a transaction's own buffer, `csum_bytes_reused`
+        checksummed by whoever wrote them), the KV's flushes and
         compactions, and `acks_before_sync`: callbacks delivered before
         the group that covers them had finished, which must read 0."""
         kv = self._kv_stats()
@@ -356,12 +377,34 @@ class BlueStore(ObjectStore):
         reads as zeros."""
         return getattr(self.kv, "stats", {})
 
-    # -- onode helpers -------------------------------------------------------
+    # -- keys and onodes ----------------------------------------------------
+
+    def _cid_key(self, cid: CollectionId) -> str:
+        key = self._keys.get(cid)
+        return self._remember(cid, _cid_key(cid)) if key is None else key
+
+    def _onode_key(self, cid: CollectionId, oid: Ghobject) -> str:
+        key = self._keys.get((cid, oid))
+        if key is None:
+            key = self._remember(
+                (cid, oid), self._cid_key(cid) + "\x01" + _oid_key(oid))
+        return key
+
+    def _remember(self, ident, key: str) -> str:
+        """An id's key, encoded just now, for every later op and probe
+        that names the id (ids are frozen and hash by value)."""
+        if len(self._keys) >= KEY_MEMO:
+            self._keys.clear()
+        self._keys[ident] = key
+        self._key_encodes += 1
+        return key
 
     def _onode(self, cid: CollectionId, oid: Ghobject) -> dict | None:
+        return self._onode_at(self._onode_key(cid, oid))
+
+    def _onode_at(self, key: str) -> dict | None:
         """The onode as queued: an uncommitted context's, else the
         KV's. Not the caller's to change (`_staged` copies)."""
-        key = _onode_key(cid, oid)
         pend = self._pend_onodes.get(key)
         if pend is not None:
             return pend[0]
@@ -370,7 +413,7 @@ class BlueStore(ObjectStore):
 
     def _coll_exists(self, cid: CollectionId,
                      ctx: "_TxnCtx | None" = None) -> bool:
-        key = _cid_key(cid)
+        key = self._cid_key(cid)
         if ctx is not None and key in ctx.colls:
             return ctx.colls[key]
         pend = self._pend_colls.get(key)
@@ -378,10 +421,15 @@ class BlueStore(ObjectStore):
             return pend[0]
         return self.kv.get(P_COLL, key) is not None
 
-    def _require_coll(self, cid: CollectionId,
-                      ctx: "_TxnCtx | None" = None) -> None:
+    def _require_coll(self, cid: CollectionId, ctx: "_TxnCtx") -> None:
+        """Once a context: its later ops on the collection go on what
+        the first found (`RMCOLL` takes the finding back)."""
+        key = self._cid_key(cid)
+        if key in ctx.colls_found:
+            return
         if not self._coll_exists(cid, ctx):
             raise StoreError("ENOENT", f"no collection {cid}")
+        ctx.colls_found.add(key)
 
     def _require_onode(self, cid: CollectionId, oid: Ghobject) -> dict:
         on = self._onode(cid, oid)
@@ -410,7 +458,6 @@ class BlueStore(ObjectStore):
                     "EIO", f"short read at unit {unit}: "
                            f"{len(chunk)} of {count * AU} bytes")
             if isinstance(crc, list):
-                import numpy as np
                 bad = self.csum.verify(chunk,
                                        np.asarray(crc, dtype=np.uint32))
                 if bad >= 0:
@@ -425,13 +472,17 @@ class BlueStore(ObjectStore):
         return bytes(out[:on["size"]])
 
     def _stage_data(self, on: dict, data, ctx: "_TxnCtx",
-                    by_ref: bool = False) -> None:
+                    by_ref: bool = False, csums=None) -> None:
         """Replace the onode's data with `data`, bytes-like and nobody's
         to change any more (`by_ref`: the transaction's own buffer):
         inline when small, block extents when large. The extents are
         allocated, checksummed and STAGED here, as views of `data` on
         the context; the commit thread writes them. Old extents are
-        freed AFTER the batch commits."""
+        freed AFTER the batch commits. `csums` is what the write said
+        of its blocks (`Transaction.write`): taken for the extents'
+        where it is of these very blocks, the buffer staged whole and
+        unpadded at one value an allocation unit, and computed here in
+        every other case. A read verifies either the same."""
         if "extents" in on:
             ctx.free_after.extend((u, c) for u, c, _ in on["extents"])
         on.pop("inline", None)
@@ -444,6 +495,7 @@ class BlueStore(ObjectStore):
         if pad:
             data, by_ref = b"".join((data, bytes(pad))), False
         view = memoryview(data).toreadonly()
+        given = _unit_csums(csums, len(view) // AU) if by_ref else None
         staged = []
         off = 0
         with self._lock:
@@ -459,8 +511,16 @@ class BlueStore(ObjectStore):
                 staged.append((unit, count, chunk))
                 off += count * AU
         ctx.block_writes.extend(staged)
-        on["extents"] = [[unit, count, self.csum.calculate(chunk).tolist()]
-                         for unit, count, chunk in staged]
+        if given is None:
+            on["extents"] = [
+                [unit, count, self.csum.calculate(chunk).tolist()]
+                for unit, count, chunk in staged]
+        else:       # a run's values are those of its place in the buffer
+            on["extents"], at = [], 0
+            for unit, count, _chunk in staged:
+                on["extents"].append([unit, count, given[at:at + count]])
+                at += count
+            ctx.csum_reused_bytes += len(view)
         ctx.block_bytes += len(view)
         if by_ref:
             ctx.by_ref_bytes += len(view)
@@ -513,6 +573,7 @@ class BlueStore(ObjectStore):
             self._seq = seq = self._seq + 1
             ctx.seq = seq
             self._publish(ctx, seq)
+        ctx.key_encodes, self._key_encodes = self._key_encodes, 0
         ctx.state = "queued"
         ctx.t_queued = time.perf_counter()
         with q.cond:
@@ -598,6 +659,7 @@ class BlueStore(ObjectStore):
         kv0 = dict(self._kv_stats())
         block_bytes = sum(ctx.block_bytes for ctx in group)
         by_ref_bytes = sum(ctx.by_ref_bytes for ctx in group)
+        csum_reused = sum(ctx.csum_reused_bytes for ctx in group)
         block_writes = sum(len(ctx.block_writes) for ctx in group)
         freelist_bytes = 0
         try:
@@ -659,6 +721,7 @@ class BlueStore(ObjectStore):
         st["block_writes"] += block_writes
         st["block_bytes_written"] += block_bytes
         st["block_bytes_by_ref"] += by_ref_bytes
+        st["csum_bytes_reused"] += csum_reused
         perf = self.commit_perf
         if perf is not None:
             perf.hist_add("store_kv_sync_us", (t2 - t0) * 1e6)
@@ -753,6 +816,8 @@ class BlueStore(ObjectStore):
                      "block_write_us": ctx.block_write_us,
                      "ops": ctx.n_ops, "bytes": ctx.block_bytes,
                      "by_ref_bytes": ctx.by_ref_bytes,
+                     "csum_reused_bytes": ctx.csum_reused_bytes,
+                     "key_encodes": ctx.key_encodes,
                      "group": ctx.group, "ran_ahead": bool(ran_ahead)},
                     getattr(self, "name", type(self).__name__))
             ctx.state = "done"
@@ -767,16 +832,14 @@ class BlueStore(ObjectStore):
                     dout("bluestore", 0, f"{self.path}: a commit callback "
                                          f"raised {type(e).__name__} {e}")
 
-    def _staged(self, ctx: "_TxnCtx", cid: CollectionId,
-                oid: Ghobject) -> dict | None:
+    def _staged(self, ctx: "_TxnCtx", key: str) -> dict | None:
         """The onode for this context to change: its own, else a copy
         of what is queued or committed."""
-        key = _onode_key(cid, oid)
         if key in ctx.onodes:
             return ctx.onodes[key]
         pend = self._pend_onodes.get(key)
         if pend is None:
-            return self._onode(cid, oid)        # parsed anew: ours
+            return self._onode_at(key)          # parsed anew: ours
         on = pend[0]
         # a queued context's: its extents list is replaced, never
         # changed in place, so one level of copy is enough
@@ -789,14 +852,16 @@ class BlueStore(ObjectStore):
             cid = op[1]
             if self._coll_exists(cid, ctx):
                 raise StoreError("EEXIST", f"collection {cid} exists")
-            ctx.batch.set(P_COLL, _cid_key(cid), b"1")
-            ctx.colls[_cid_key(cid)] = True
+            ckey = self._cid_key(cid)
+            ctx.batch.set(P_COLL, ckey, b"1")
+            ctx.colls[ckey] = True
             return
         if kind == Op.RMCOLL:
             cid = op[1]
             self._require_coll(cid, ctx)
-            prefix = _cid_key(cid) + "\x01"
-            live = {_onode_key(cid, gh)
+            ckey = self._cid_key(cid)
+            prefix = ckey + "\x01"
+            live = {self._onode_key(cid, gh)
                     for gh in self.collection_list(cid)}
             for k, on in ctx.onodes.items():
                 if not k.startswith(prefix):
@@ -808,26 +873,31 @@ class BlueStore(ObjectStore):
             if live:
                 raise StoreError("ENOTEMPTY",
                                  f"collection {cid} not empty")
-            ctx.batch.rmkey(P_COLL, _cid_key(cid))
-            ctx.colls[_cid_key(cid)] = False
+            ctx.batch.rmkey(P_COLL, ckey)
+            ctx.colls[ckey] = False
+            ctx.colls_found.discard(ckey)
             return
+        # an op on an object: its key is resolved here, once, and every
+        # step below works on the key
         cid, oid = op[1], op[2]
-        key = _onode_key(cid, oid)
+        key = self._onode_key(cid, oid)
 
         if kind == Op.TOUCH:
             self._require_coll(cid, ctx)
-            if self._staged(ctx, cid, oid) is None:
+            if self._staged(ctx, key) is None:
                 ctx.onodes[key] = {"size": 0, "inline": "", "attrs": {}}
             return
         if kind == Op.WRITE:
             self._require_coll(cid, ctx)
-            offset, data = op[3], op[4]
-            on = self._staged(ctx, cid, oid) or \
+            offset, data, csums = op[3], op[4], op[5]
+            on = self._staged(ctx, key) or \
                 {"size": 0, "inline": "", "attrs": {}}
             if offset == 0 and on["size"] <= len(data):
                 # the object replaced whole (every push and write_full):
-                # `Transaction.write` made the buffer the store's
-                self._stage_data(on, data, ctx, by_ref=True)
+                # `Transaction.write` made the buffer the store's, and
+                # its blocks' csums, where it brought some, are of what
+                # is staged
+                self._stage_data(on, data, ctx, by_ref=True, csums=csums)
             else:
                 cur = bytearray(self._read_staged(on))
                 if len(cur) < offset:
@@ -839,7 +909,7 @@ class BlueStore(ObjectStore):
         if kind == Op.ZERO:
             self._require_coll(cid, ctx)
             offset, length = op[3], op[4]
-            on = self._staged(ctx, cid, oid) or \
+            on = self._staged(ctx, key) or \
                 {"size": 0, "inline": "", "attrs": {}}
             cur = bytearray(self._read_staged(on))
             if len(cur) < offset + length:
@@ -851,7 +921,7 @@ class BlueStore(ObjectStore):
         if kind == Op.TRUNCATE:
             self._require_coll(cid, ctx)
             size = op[3]
-            on = self._staged(ctx, cid, oid) or \
+            on = self._staged(ctx, key) or \
                 {"size": 0, "inline": "", "attrs": {}}
             cur = bytearray(self._read_staged(on))
             if len(cur) < size:
@@ -862,7 +932,7 @@ class BlueStore(ObjectStore):
             ctx.onodes[key] = on
             return
         if kind == Op.REMOVE:
-            on = self._require_staged(ctx, cid, oid)
+            on = self._require_staged(ctx, key, cid, oid)
             if "extents" in on:
                 ctx.free_after.extend((u, c) for u, c, _ in on["extents"])
             ctx.onodes[key] = None
@@ -871,88 +941,86 @@ class BlueStore(ObjectStore):
             return
         if kind == Op.SETATTRS:
             self._require_coll(cid, ctx)
-            on = self._staged(ctx, cid, oid) or \
+            on = self._staged(ctx, key) or \
                 {"size": 0, "inline": "", "attrs": {}}
             on.setdefault("attrs", {}).update(
                 {k: v.decode("latin1") for k, v in op[3].items()})
             ctx.onodes[key] = on
             return
         if kind == Op.RMATTR:
-            on = self._require_staged(ctx, cid, oid)
+            on = self._require_staged(ctx, key, cid, oid)
             on.get("attrs", {}).pop(op[3], None)
             ctx.onodes[key] = on
             return
         if kind == Op.CLONE:
-            src, dst = op[2], op[3]
-            son = self._staged(ctx, cid, src)
+            src, dkey = op[2], self._onode_key(cid, op[3])
+            son = self._staged(ctx, key)
             if son is None:
                 raise StoreError("ENOENT", f"no object {src}")
             data = self._read_staged(son)
             don = {"size": 0, "inline": "", "attrs":
                    dict(son.get("attrs", {}))}
-            old = self._staged(ctx, cid, dst)
+            old = self._staged(ctx, dkey)
             if old is not None and "extents" in old:
                 ctx.free_after.extend((u, c)
                                       for u, c, _ in old["extents"])
             self._stage_data(don, data, ctx)
-            ctx.onodes[_onode_key(cid, dst)] = don
+            ctx.onodes[dkey] = don
             # omap clones with the object (MemStore does the same);
             # the CLEAR sentinel hides dst's committed keys from later
             # same-txn readers (replace, never merge)
-            okeys = dict(self._omap_staged(ctx, cid, src))
-            pre_dst = P_OMAP + "\x01" + _onode_key(cid, dst)
+            okeys = dict(self._omap_staged(ctx, key))
+            pre_dst = P_OMAP + "\x01" + dkey
             ctx.batch.rmkeys_by_prefix(pre_dst)
             over = {_CLEAR: None}
             for k, v in okeys.items():
                 ctx.batch.set(pre_dst, k, v)
                 over[k] = v
-            ctx.omap_over[_onode_key(cid, dst)] = over
+            ctx.omap_over[dkey] = over
             return
         if kind == Op.CLONE_RANGE:
-            src, dst, src_off, length, dst_off = (op[2], op[3], op[4],
-                                                  op[5], op[6])
-            son = self._staged(ctx, cid, src)
+            src, src_off, length, dst_off = op[2], op[4], op[5], op[6]
+            dkey = self._onode_key(cid, op[3])
+            son = self._staged(ctx, key)
             if son is None:
                 raise StoreError("ENOENT", f"no object {src}")
             sdata = self._read_staged(son)[src_off:src_off + length]
-            don = self._staged(ctx, cid, dst) or \
+            don = self._staged(ctx, dkey) or \
                 {"size": 0, "inline": "", "attrs": {}}
             cur = bytearray(self._read_staged(don))
             if len(cur) < dst_off:
                 cur.extend(b"\x00" * (dst_off - len(cur)))
             cur[dst_off:dst_off + len(sdata)] = sdata
             self._stage_data(don, cur, ctx)
-            ctx.onodes[_onode_key(cid, dst)] = don
+            ctx.onodes[dkey] = don
             return
         if kind == Op.COLL_MOVE_RENAME:
-            old_cid, old_oid, new_cid, new_oid = op[1], op[2], op[3], op[4]
-            on = self._staged(ctx, old_cid, old_oid)
+            new_cid, new_key = op[3], self._onode_key(op[3], op[4])
+            on = self._staged(ctx, key)
             if on is None:
-                raise StoreError("ENOENT", f"no object {old_oid}")
+                raise StoreError("ENOENT", f"no object {oid}")
             self._require_coll(new_cid, ctx)
-            okeys = dict(self._omap_staged(ctx, old_cid, old_oid))
-            dst_old = self._staged(ctx, new_cid, new_oid)
+            okeys = dict(self._omap_staged(ctx, key))
+            dst_old = self._staged(ctx, new_key)
             if dst_old is not None and "extents" in dst_old:
                 # replaced destination: its space must return
                 ctx.free_after.extend((u, c)
                                       for u, c, _ in dst_old["extents"])
-            ctx.onodes[_onode_key(old_cid, old_oid)] = None
-            ctx.batch.rmkeys_by_prefix(
-                P_OMAP + "\x01" + _onode_key(old_cid, old_oid))
-            ctx.omap_over[_onode_key(old_cid, old_oid)] = \
-                {_CLEAR: None}
-            ctx.onodes[_onode_key(new_cid, new_oid)] = on
-            pre = P_OMAP + "\x01" + _onode_key(new_cid, new_oid)
+            ctx.onodes[key] = None
+            ctx.batch.rmkeys_by_prefix(P_OMAP + "\x01" + key)
+            ctx.omap_over[key] = {_CLEAR: None}
+            ctx.onodes[new_key] = on
+            pre = P_OMAP + "\x01" + new_key
             ctx.batch.rmkeys_by_prefix(pre)    # replace, never merge
             over = {_CLEAR: None}
             for k, v in okeys.items():
                 ctx.batch.set(pre, k, v)
                 over[k] = v
-            ctx.omap_over[_onode_key(new_cid, new_oid)] = over
+            ctx.omap_over[new_key] = over
             return
         if kind == Op.OMAP_SETKEYS:
             self._require_coll(cid, ctx)
-            on = self._staged(ctx, cid, oid) or \
+            on = self._staged(ctx, key) or \
                 {"size": 0, "inline": "", "attrs": {}}
             ctx.onodes[key] = on
             pre = P_OMAP + "\x01" + key
@@ -963,7 +1031,7 @@ class BlueStore(ObjectStore):
             return
         if kind == Op.OMAP_RMKEYS:
             self._require_coll(cid, ctx)
-            on = self._staged(ctx, cid, oid) or \
+            on = self._staged(ctx, key) or \
                 {"size": 0, "inline": "", "attrs": {}}
             ctx.onodes[key] = on
             pre = P_OMAP + "\x01" + key
@@ -978,9 +1046,9 @@ class BlueStore(ObjectStore):
             return
         raise StoreError("EINVAL", f"unknown op {kind}")
 
-    def _require_staged(self, ctx: "_TxnCtx", cid: CollectionId,
-                        oid: Ghobject) -> dict:
-        on = self._staged(ctx, cid, oid)
+    def _require_staged(self, ctx: "_TxnCtx", key: str,
+                        cid: CollectionId, oid: Ghobject) -> dict:
+        on = self._staged(ctx, key)
         if on is None:
             raise StoreError("ENOENT", f"no object {oid} in {cid}")
         return on
@@ -988,12 +1056,10 @@ class BlueStore(ObjectStore):
     def _read_staged(self, on: dict) -> bytes:
         return self._read_extents(on)
 
-    def _omap_staged(self, ctx: "_TxnCtx", cid: CollectionId,
-                     oid: Ghobject) -> dict[str, bytes]:
-        key = _onode_key(cid, oid)
+    def _omap_staged(self, ctx: "_TxnCtx", key: str) -> dict[str, bytes]:
         staged_off = key in ctx.onodes and ctx.onodes[key] is None
         base = self._omap_view(key) \
-            if self._onode(cid, oid) is not None and not staged_off else {}
+            if self._onode_at(key) is not None and not staged_off else {}
         return _overlaid(base, ctx.omap_over.get(key, {}))
 
     def _omap_view(self, key: str) -> dict[str, bytes]:
@@ -1023,7 +1089,7 @@ class BlueStore(ObjectStore):
     def collection_list(self, cid: CollectionId,
                         start: Ghobject | None = None,
                         max_count: int = 2 ** 31) -> list[Ghobject]:
-        prefix = _cid_key(cid) + "\x01"
+        prefix = self._cid_key(cid) + "\x01"
         with self._lock:
             pend = [(k, v[0] is not None)
                     for k, v in self._pend_onodes.items()
@@ -1071,12 +1137,28 @@ class BlueStore(ObjectStore):
     def omap_get(self, cid: CollectionId,
                  oid: Ghobject) -> dict[str, bytes]:
         self._require_onode(cid, oid)
-        return self._omap_view(_onode_key(cid, oid))
+        return self._omap_view(self._onode_key(cid, oid))
 
     def omap_get_values(self, cid: CollectionId, oid: Ghobject,
                         keys) -> dict[str, bytes]:
         omap = self.omap_get(cid, oid)
         return {k: omap[k] for k in keys if k in omap}
+
+
+def _unit_csums(csums, n_units: int) -> list[int] | None:
+    """What a write said of its blocks (`Transaction.write`'s `csums`)
+    as the csums of `n_units` staged allocation units, or None where it
+    does not say that: no word, another block size, another count, or
+    values that are no 32-bit numbers."""
+    if csums is None:
+        return None
+    try:
+        block, values = csums
+        if block != AU or len(values) != n_units:
+            return None
+        return np.asarray(values, dtype=np.uint32).tolist()
+    except (TypeError, ValueError, OverflowError):
+        return None
 
 
 def _overlaid(base: dict[str, bytes], over: dict) -> dict[str, bytes]:
@@ -1105,6 +1187,7 @@ class _TxnCtx:
         self.batch = batch
         self.onodes: dict[str, dict | None] = {}
         self.colls: dict[str, bool] = {}    # made (True), removed (False)
+        self.colls_found: set[str] = set()  # met by an op, and there
         self.omap_over: dict[str, dict] = {}
         self.free_after: list[tuple[int, int]] = []
         self.allocated: list[tuple[int, int]] = []
@@ -1112,6 +1195,8 @@ class _TxnCtx:
         self.block_writes: list[tuple[int, int, memoryview]] = []
         self.block_bytes = 0                # staged for the block file
         self.by_ref_bytes = 0               # of them, the txn's own buffer
+        self.csum_reused_bytes = 0          # of them, csums came with it
+        self.key_encodes = 0                # the store's, up to this one
         self.n_ops = 0
         self.state = "prepare"
         self.error: BaseException | None = None

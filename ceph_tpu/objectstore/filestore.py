@@ -367,7 +367,7 @@ class FileStore(MemStore):
         for op in txn.ops:
             kind = op[0]
             if kind == Op.WRITE:
-                _, cid, oid, offset, data = op
+                _, cid, oid, offset, data, _csums = op
                 buf = content(cid, oid)
                 end = offset + len(data)
                 if len(buf) < end:

@@ -276,7 +276,7 @@ class MemStore(ObjectStore):
         elif kind == Op.TOUCH:
             self._obj_create(op[1], op[2])
         elif kind == Op.WRITE:
-            _, cid, oid, offset, data = op
+            _, cid, oid, offset, data, _csums = op
             t0 = time.perf_counter()
             if self._obj_create(cid, oid).write(offset, data):
                 copytrack.referenced("store_write", len(data))

@@ -22,7 +22,8 @@ callbacks have fired when it returns. BlueStore prepares inside the
 call, fires `on_applied` before it returns, and commits on a thread of
 its own (`bluestore.py`): `on_commit` arrives later, on the caller's
 loop. Its prepare writes no object data: an extent is staged on the
-caller's thread (units, csums, a read-only view of the bytes) and
+caller's thread (units, csums, which are the write's own where it
+brought some that fit, a read-only view of the bytes) and
 written into the block file by the commit thread, before the sync that
 precedes the metadata which names it; a write that replaces its object
 whole is written from the buffer `Transaction.write` was given. Until
@@ -95,7 +96,13 @@ class Transaction:
         return self
 
     def write(self, cid: CollectionId, oid: Ghobject, offset: int,
-              data: bytes) -> "Transaction":
+              data: bytes, csums=None) -> "Transaction":
+        # `csums`, where the writer has them: (block size, the crc32c
+        # of each block of `data` in order), for a store that checksums
+        # at that size (`ObjectStore.csum_block`) to keep in place of
+        # its own. A store takes them only where they are of the very
+        # blocks it stores, verifies every read against them as against
+        # its own, and any other store ignores them.
         # snapshot MUTABLE buffers (bytearray, numpy views): the txn
         # applies later and must see the bytes as queued. Immutable
         # payloads — bytes, and the read-only memoryviews the zero-copy
@@ -114,7 +121,7 @@ class Transaction:
             data = bytes(data)
             copytrack.copied("store_write", len(data),
                              time.perf_counter() - t0)
-        self.ops.append((Op.WRITE, cid, oid, offset, data))
+        self.ops.append((Op.WRITE, cid, oid, offset, data, csums))
         return self
 
     def zero(self, cid: CollectionId, oid: Ghobject, offset: int,
@@ -249,6 +256,11 @@ class ObjectStore:
     #: instead and has neither)
     on_fatal = None
     failed: BaseException | None = None
+
+    #: the block size at which the store checksums object data, for a
+    #: writer that has its blocks' crc32c already (`Transaction.write`);
+    #: 0 where the store keeps none, and such a writer parses nothing
+    csum_block = 0
 
     #: nominal device size for utilization reporting (statfs); daemons
     #: report used/capacity to the mgr, which drives OSD_NEARFULL/FULL

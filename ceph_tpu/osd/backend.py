@@ -129,6 +129,11 @@ class PGBackend:
             txn = Transaction().create_collection(cid)
             self.host.store.queue_transaction(txn)
 
+    def _block_csums(self, attrs: dict[str, bytes] | None):
+        """What a pushed object's attrs say of its blocks' checksums,
+        for `Transaction.write`: nothing, here."""
+        return None
+
     def local_apply(self, oid: str, op: str, data: bytes,
                     attrs: dict[str, bytes] | None = None,
                     shard: int = -1, off: int = 0,
@@ -157,7 +162,7 @@ class PGBackend:
             if self.host.store.exists(cid, gh):
                 txn.remove(cid, gh)
             txn.touch(cid, gh)
-            txn.write(cid, gh, 0, data)
+            txn.write(cid, gh, 0, data, self._block_csums(attrs))
             if attrs:
                 txn.setattrs(cid, gh, attrs)
             if omap:

@@ -226,6 +226,16 @@ class ECBackend(PGBackend):
             row += n
         return out
 
+    def _block_csums(self, attrs: dict[str, bytes] | None):
+        """A shard's `csum` attr is the crc32c of each chunk of it: what
+        a store that checksums its blocks at the chunk size would
+        compute over the same bytes again. Parsed for such a store
+        alone."""
+        if not attrs or "csum" not in attrs \
+                or self.host.store.csum_block != self.sinfo.chunk_size:
+            return None
+        return self.sinfo.chunk_size, json.loads(attrs["csum"])
+
     def _chunk_attrs(self, shard: int, size: int, version,
                      csums: list[int]) -> dict:
         return {"shard": str(shard).encode(),

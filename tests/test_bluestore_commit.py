@@ -885,3 +885,426 @@ def test_the_pipeline_is_traced(tmp_path):
     # the prepare is what `store_commit` measures: no sync inside it
     commits = [s for s in spans if s["name"] == "store_commit"]
     assert len(commits) == 2
+
+
+# -- prepare computes nothing twice: keys once, csums as they came ------------
+
+META = CollectionId.make_meta()
+NOSNAP, NOGEN = 2 ** 64 - 2, 2 ** 64 - 1
+#: the key FORMAT is the store's on-disk format: these are the bytes the
+#: program before the memo wrote, and a store it wrote must still mount
+GOLDEN = [
+    ("pg_collection", CollectionId.make_pg(7, 0x1f, 3), None,
+     "[7, 31, 3, false]"),
+    ("meta_collection", META, None, "[-1, 0, -1, true]"),
+    ("head", CID, Ghobject(pool=7, name="a"),
+     '[7, 0, -1, false]\x01[7, "", "a", %d, %d, -1]' % (NOSNAP, NOGEN)),
+    ("clone", CID, Ghobject(pool=7, nspace="ns", name="a", snap=4),
+     '[7, 0, -1, false]\x01[7, "ns", "a", 4, %d, -1]' % NOGEN),
+    ("generation", CID, Ghobject(pool=7, name="a").with_gen(12),
+     '[7, 0, -1, false]\x01[7, "", "a", %d, 12, -1]' % NOSNAP),
+    ("shard_object", CollectionId.make_pg(7, 5, 10),
+     Ghobject(pool=7, name='b"é', shard=10),
+     '[7, 5, 10, false]\x01[7, "", "b\\"\\u00e9", %d, %d, 10]'
+     % (NOSNAP, NOGEN)),
+]
+
+
+@pytest.mark.parametrize("case,cid,oid,want", GOLDEN,
+                         ids=[g[0] for g in GOLDEN])
+def test_golden_key_bytes(tmp_path, case, cid, oid, want):
+    store = _store(tmp_path)
+    for _cold_then_warm in range(2):
+        if oid is None:
+            assert bluestore._cid_key(cid) == store._cid_key(cid) == want
+            assert bluestore._cid_from(want) == cid
+        else:
+            assert bluestore._onode_key(cid, oid) \
+                == store._onode_key(cid, oid) == want
+            assert bluestore._oid_from(want.split("\x01")[1]) == oid
+    # an equal id that is another object finds the same entry
+    assert store._cid_key(CollectionId(**vars(cid))) == want.split("\x01")[0]
+    assert store._key_encodes == (1 if oid is None else 2)
+    if oid is not None:
+        store.queue_transaction(Transaction().create_collection(cid)
+                                .touch(cid, oid))
+        assert store.kv.get(bluestore.P_ONODE, want) is not None
+        assert store.kv.get(bluestore.P_COLL, want.split("\x01")[0]) == b"1"
+    store.umount()
+
+
+def _ids(n: int, salt: str = "") -> list[tuple[CollectionId, Ghobject]]:
+    return [(_cid(i % 3), Ghobject(pool=7, name=f"{salt}{i:04d}",
+                                   shard=i % 5 - 1)) for i in range(n)]
+
+
+@pytest.mark.parametrize("order", ["cold_write_warm_read",
+                                   "warm_write_cold_read"])
+def test_a_store_reads_the_same_with_the_memo_cold_or_warm(tmp_path, order):
+    """The memo changes when a key is encoded, never what it is: what
+    one store wrote with none of its ids remembered another lists and
+    reads with all of them remembered, and the other way round."""
+    ids = _ids(40)
+    blobs = {i: os.urandom(BIG if n % 4 == 0 else 300)
+             for n, i in enumerate(ids)}
+
+    def write(store):
+        for c in range(3):
+            store.queue_transaction(Transaction().create_collection(_cid(c)))
+        for (cid, oid), data in blobs.items():
+            store.queue_transaction(
+                Transaction().touch(cid, oid).write(cid, oid, 0, data)
+                .setattrs(cid, oid, {"n": oid.name.encode()}))
+
+    def check(store):
+        for c in range(3):
+            listed = store.collection_list(_cid(c))
+            assert listed == sorted(o for cc, o in ids if cc == _cid(c))
+        for (cid, oid), data in blobs.items():
+            assert store.read(cid, oid) == data
+            assert store.getattr(cid, oid, "n") == oid.name.encode()
+
+    first = _store(tmp_path)
+    if order == "warm_write_cold_read":
+        for cid, oid in ids:            # every id met before it is written
+            assert not first.exists(cid, oid)
+        assert len(first._keys) == len(ids) + 3
+    else:
+        assert not first._keys
+    write(first)
+    check(first)
+    first.umount()
+    second = _store(tmp_path)
+    assert not second._keys
+    if order == "cold_write_warm_read":
+        check(second)                   # warms it
+        assert len(second._keys) == len(ids) + 3
+    check(second)
+    second.umount()
+
+
+class _KeyEncodings:
+    """`json.dumps` counted where it encodes a KEY on the caller's
+    thread: `cid_key`'s list, four with a bool last, or `oid_key`'s, six
+    with two strings after the pool, and nothing else (an onode is a
+    dict, the KV's log records are the commit thread's)."""
+
+    def __init__(self, monkeypatch):
+        self.n = 0
+        real, me = bluestore.json.dumps, threading.get_ident()
+
+        def dumps(obj, *args, **kwargs):
+            if isinstance(obj, list) and threading.get_ident() == me and (
+                    (len(obj) == 4 and isinstance(obj[3], bool)) or
+                    (len(obj) == 6 and isinstance(obj[1], str)
+                     and isinstance(obj[2], str))):
+                self.n += 1
+            return real(obj, *args, **kwargs)
+        monkeypatch.setattr(bluestore.json, "dumps", dumps)
+
+    def taken(self) -> int:
+        n, self.n = self.n, 0
+        return n
+
+
+def _shard_pair(store, cid, name: str, data, csums=None):
+    """A shard's data transaction and its PG-log transaction with the
+    probes in front of them, as `ECBackend._apply_sub_write` ->
+    `_stash_prev`, `local_apply("push")` and `PG.persist_meta` build
+    them: every id made anew by each of the three, as `coll()` and
+    `ghobject()` make them. -> the two transactions."""
+    def gh(n):
+        return Ghobject(pool=cid.pool, name=n, shard=cid.shard)
+
+    def coll():
+        return CollectionId(**vars(cid))
+    if store.exists(coll(), gh(name)):          # `_stash_prev`
+        prev = Transaction()
+        if store.exists(coll(), gh(name + ".prev")):
+            prev.remove(coll(), gh(name + ".prev"))
+        store.queue_transaction(
+            prev.clone(coll(), gh(name), gh(name + ".prev")))
+    c, g = coll(), gh(name)                     # `local_apply`, "push"
+    data_txn = Transaction()
+    if store.exists(c, g):
+        data_txn.remove(c, g)
+    data_txn.touch(c, g).write(c, g, 0, data, csums).setattrs(
+        c, g, {"shard": b"3", "ec_size": b"4194304", "csum": b"[1, 2]",
+               "version": b"[3, 9]"})
+    c, m = coll(), gh("_pgmeta_")               # `persist_meta`
+    meta_txn = Transaction()
+    if not store.exists(c, m):
+        meta_txn.touch(c, m)
+    meta_txn.setattr(c, m, "pgmeta", b"{}").omap_setkeys(
+        c, m, {f"log.{name}": b"{}"})
+    return data_txn, meta_txn
+
+
+def test_a_shards_transactions_encode_a_key_once(tmp_path, monkeypatch):
+    """Warm, a shard's data transaction with its probes makes at most 3
+    key encodings (the new object's one; the program before made 33 for
+    the pair) and its meta transaction at most 1, and the `bstore_txc`
+    span says how many its store made up to it."""
+    cid = CollectionId.make_pg(7, 5, 3)
+
+    async def main():
+        store = _store(tmp_path)
+        store.queue_transaction(Transaction().create_collection(cid))
+        for t in _shard_pair(store, cid, "warm", os.urandom(SHARD)):
+            store.queue_transaction(t)
+        await _settled(store)
+        count = _KeyEncodings(monkeypatch)
+        tracer.enable()
+        try:
+            cursor = tracer.collector().last_seq()
+            made = []
+            for name in ("o1", "o2", "o1"):     # the last: an overwrite
+                count.taken()
+                data_txn, meta_txn = _shard_pair(store, cid, name,
+                                                 os.urandom(SHARD))
+                store.queue_transaction(data_txn)
+                made.append(count.taken())
+                store.queue_transaction(meta_txn)
+                made.append(count.taken())
+            await _settled(store)
+            tags = [s["tags"]["key_encodes"]
+                    for s in tracer.collector().spans()
+                    if s["seq"] > cursor and s["name"] == "bstore_txc"]
+        finally:
+            tracer.disable()
+        store.umount()
+        return made, tags
+
+    made, tags = run(main())
+    # data, meta a pair; the overwrite's probes meet the rollback
+    # generation's id for the first time, in a clone of its own
+    assert made == [1, 0, 1, 0, 1, 0]
+    assert all(d <= 3 and m <= 1 for d, m in zip(made[::2], made[1::2]))
+    assert tags == [1, 0, 1, 0, 1, 0, 0]
+    assert sum(tags) == sum(made)
+
+
+def test_the_memo_stays_at_its_bound(tmp_path):
+    store = _store(tmp_path)
+    store.queue_transaction(Transaction().create_collection(_cid(0)))
+    ids = _ids(bluestore.KEY_MEMO + 700, "m")
+    for n, (cid, oid) in enumerate(ids):
+        assert not store.exists(cid, oid)
+        assert len(store._keys) <= bluestore.KEY_MEMO
+        if n == 5:
+            assert len(store._keys) == 9    # three collections, six ids
+    # the ids', two collections' (the first's went to the context that
+    # made it), and all three's again after the memo was emptied once
+    assert store._key_encodes == len(ids) + 2 + 3
+    cid, oid = ids[3]
+    assert store._onode_key(cid, oid) == bluestore._onode_key(cid, oid)
+    gh = Ghobject(pool=7, name="kept")
+    store.queue_transaction(Transaction().write(_cid(0), gh, 0, b"x"))
+    assert store.read(_cid(0), gh) == b"x"
+    store.umount()
+
+
+def test_a_context_checks_its_collection_once_and_forgets_with_rmcoll(
+        tmp_path, monkeypatch):
+    """Later ops of a transaction go on what its first op found of the
+    collection; removing it in the same transaction takes that back."""
+    store = _store(tmp_path)
+    store.queue_transaction(Transaction().create_collection(CID))
+    gets = []
+    real = store.kv.get
+    monkeypatch.setattr(store.kv, "get", lambda prefix, key: (
+        gets.append(prefix), real(prefix, key))[1])
+    gh = _gh("a")
+    store.queue_transaction(
+        Transaction().touch(CID, gh).write(CID, gh, 0, b"abc")
+        .setattrs(CID, gh, {"k": b"v"}).omap_setkeys(CID, gh, {"o": b"1"})
+        .truncate(CID, gh, 2))
+    assert gets.count(bluestore.P_COLL) == 1
+    assert store.read(CID, gh) == b"ab"
+    with pytest.raises(StoreError) as e:
+        store.queue_transaction(
+            Transaction().remove(CID, gh).remove_collection(CID)
+            .touch(CID, gh))
+    assert e.value.code == "ENOENT"
+    assert store.collection_exists(CID) and store.read(CID, gh) == b"ab"
+    # made and used in one transaction: found in the context itself
+    store.queue_transaction(Transaction().create_collection(_cid(9))
+                            .touch(_cid(9), gh))
+    assert store.exists(_cid(9), gh)
+    store.umount()
+
+
+def _crcs(data) -> list[int]:
+    """What the encode's finisher makes of a shard beside the encode
+    (`ECBackend._csums`): crc32c of each 4 KiB chunk."""
+    import numpy as np
+    from ceph_tpu.native import ec_native
+    return [int(x) for x in ec_native.crc32c_blocks(
+        np.frombuffer(data, dtype=np.uint8), AU)]
+
+
+#: case -> (reused, how the write is made). `given` is what the writer
+#: says of its blocks; the store takes it only where it is of the very
+#: units it stages
+HINT_CASES = {
+    "honoured": True, "honoured_as_an_array": True,
+    "honoured_over_a_shorter_object": True, "fragmented": True,
+    "no_hint": False, "wrong_block_size": False, "wrong_count": False,
+    "padded_length": False, "partial_overwrite": False,
+    "partial_offset": False, "not_numbers": False, "negative": False,
+    "inline": False,
+}
+
+
+@pytest.mark.parametrize("case", HINT_CASES)
+def test_a_write_may_carry_its_blocks_checksums(tmp_path, monkeypatch, case):
+    """`Transaction.write(..., csums=(block, values))`: kept as the
+    extents' csums, sliced by allocated run, where block is the
+    allocation unit, there is one value a unit of the buffer as staged
+    and the buffer is staged whole; computed in every other case. What
+    the onode holds is `Checksummer.calculate`'s either way."""
+    import numpy as np
+    reused = HINT_CASES[case]
+    size = {"padded_length": SHARD + 100, "inline": INLINE_MAX}.get(
+        case, SHARD)
+    data = os.urandom(size)
+    padded = data + bytes(-size % AU)
+    good = _crcs(padded)
+    given = {
+        "honoured_as_an_array": (AU, np.asarray(good, dtype=np.uint32)),
+        "no_hint": None,
+        "wrong_block_size": (2 * AU, good[::2]),
+        "wrong_count": (AU, good[:-1]),
+        "not_numbers": (AU, [str(c) for c in good[:-1]] + ["x"]),
+        "negative": (AU, [-1] + good[1:]),
+    }.get(case, (AU, good))
+    offset = AU if case == "partial_offset" else 0
+    first = {"honoured_over_a_shorter_object": SHARD - AU,
+             "partial_overwrite": SHARD + AU, "partial_offset": SHARD}.get(case)
+    store = _store(tmp_path)
+    store.queue_transaction(Transaction().create_collection(CID))
+    if first:
+        store.queue_transaction(_write("a", first))
+    if case == "fragmented":
+        # free runs of 5, 50 and 9 units, then the end of the device
+        store.alloc = bluestore.BitmapAllocator.from_bytes(
+            bytes([1] + [0] * 5 + [1] * 3 + [0] * 50 + [1] + [0] * 9 + [1]))
+    computed = []
+    real = store.csum.calculate
+    monkeypatch.setattr(store.csum, "calculate", lambda chunk: (
+        computed.append(len(chunk)), real(chunk))[1])
+    base = store.stats()
+    store.queue_transaction(
+        Transaction().touch(CID, _gh("a")).write(CID, _gh("a"), offset, data,
+                                                 given))
+    on = store._onode(CID, _gh("a"))
+    after = store.stats()
+    monkeypatch.undo()
+    want = store.read(CID, _gh("a"))
+    assert want[offset:offset + size] == data
+    if case == "inline":
+        assert "extents" not in on and not computed
+        assert after["csum_bytes_reused"] == base["csum_bytes_reused"]
+        store.umount()
+        return
+    whole = want + bytes(-len(want) % AU)
+    at = 0
+    for unit, count, crcs in on["extents"]:
+        assert crcs == store.csum.calculate(
+            whole[at * AU:(at + count) * AU]).tolist()
+        assert all(type(c) is int for c in crcs)
+        at += count
+    assert at * AU == len(whole)
+    if case == "fragmented":
+        assert [c for _u, c, _ in on["extents"]] == [5, 50, 9, 64]
+    assert bool(computed) != reused
+    assert after["csum_bytes_reused"] - base["csum_bytes_reused"] \
+        == (len(whole) if reused else 0)
+    assert after["block_bytes_written"] - base["block_bytes_written"] \
+        == len(whole)
+    store.umount()
+    fresh = BlueStore(str(tmp_path / "bs"))
+    fresh.mount()
+    assert fresh.read(CID, _gh("a")) == want    # through the csum check
+    fresh.umount()
+
+
+@pytest.mark.parametrize("where", ["first_block", "last_block",
+                                   "second_run"])
+def test_a_wrong_checksum_handed_in_reads_back_as_eio(tmp_path, syncs,
+                                                      where):
+    """The store keeps what it was told and verifies every read against
+    it, as against its own: a wrong value is an EIO from the staged view
+    and from a fresh mount, never bytes returned."""
+    data = os.urandom(SHARD)
+    crcs = _crcs(data)
+    bad = {"first_block": 0, "last_block": len(crcs) - 1,
+           "second_run": 7}[where]
+    crcs[bad] ^= 0x10
+
+    async def main():
+        store = _store(tmp_path)
+        store.queue_transaction(Transaction().create_collection(CID))
+        store.queue_transaction(_write("warm"))
+        await _settled(store)
+        if where == "second_run":
+            store.alloc = bluestore.BitmapAllocator.from_bytes(
+                bytes([1] * 40 + [0] * 5 + [1]))
+        syncs.hold()
+        store.queue_transaction(_write("held", 1))
+        await asyncio.to_thread(syncs.entered.wait, 10)
+        store.queue_transaction(
+            Transaction().write(CID, _gh("a"), 0, data, (AU, crcs))
+            .write(CID, _gh("b"), 0, data, (AU, _crcs(data))))
+        assert store._pend_extents
+        with pytest.raises(StoreError) as e:
+            store.read(CID, _gh("a"))
+        assert store.read(CID, _gh("b")) == data
+        syncs.release()
+        await _settled(store)
+        assert not store._pend_extents
+        with pytest.raises(StoreError) as e2:
+            store.read(CID, _gh("a"))           # the block file's
+        store.umount()
+        return e.value, e2.value
+
+    staged, written = run(main())
+    assert staged.code == written.code == "EIO"
+    assert "csum mismatch" in str(staged) and "csum mismatch" in str(written)
+    assert f"(+{(bad - 5 if where == 'second_run' else bad) * AU} bytes)" \
+        in str(staged)
+    fresh = BlueStore(str(tmp_path / "bs"))
+    fresh.mount()
+    with pytest.raises(StoreError) as e3:
+        fresh.read(CID, _gh("a"))
+    assert e3.value.code == "EIO"
+    assert fresh.read(CID, _gh("b")) == data
+    assert fresh.getattrs(CID, _gh("a")) == {}  # the onode itself is whole
+    fresh.umount()
+
+
+@pytest.mark.parametrize("backend", ["memstore", "filestore"])
+def test_a_store_that_keeps_no_checksums_ignores_them(tmp_path, backend):
+    """`csum_block` 0 tells a writer to parse nothing; a write that
+    carries checksums all the same stores the same bytes."""
+    from ceph_tpu.objectstore import FileStore, MemStore
+    store = MemStore() if backend == "memstore" \
+        else FileStore(str(tmp_path / "fs"))
+    assert store.csum_block == 0 and BlueStore.csum_block == AU
+    store.mkfs()
+    store.mount()
+    store.queue_transaction(Transaction().create_collection(CID))
+    data = os.urandom(SHARD)
+    wrong = [c ^ 1 for c in _crcs(data)]
+    store.queue_transaction(
+        Transaction().write(CID, _gh("a"), 0, data, (AU, wrong))
+        .write(CID, _gh("b"), 0, data)
+        .write(CID, _gh("c"), 100, data[:5000], (AU, wrong[:1])))
+    assert store.read(CID, _gh("a")) == store.read(CID, _gh("b")) == data
+    assert store.read(CID, _gh("c")) == bytes(100) + data[:5000]
+    store.umount()
+    if backend == "filestore":
+        again = FileStore(str(tmp_path / "fs"))
+        again.mount()
+        assert again.read(CID, _gh("a")) == data
+        again.umount()

@@ -299,3 +299,84 @@ def test_the_admin_socket_serves_the_stores_counters(tmp_path):
         finally:
             await c.stop()
     run(body())
+
+
+@pytest.mark.parametrize("how", ["write_full", "recovery_push"])
+def test_a_shards_extents_keep_the_checksums_it_arrived_with(
+        tmp_path, fast_timers, how):
+    """An EC `write_full`'s sub-write and a recovery push onto BlueStore
+    carry the shard's `csum` attr (crc32c of each 4 KiB chunk, taken at
+    the encode) into `Transaction.write`: the onode's extents hold those
+    very numbers, the store computed none of its own for them, every
+    read verifies against them, and a deep scrub of the PG finds
+    nothing."""
+    import json
+
+    async def body():
+        c = ClusterHarness(tmp_path, n_osds=4, store_factory=lambda i:
+                           BlueStore(str(tmp_path / f"osd{i}")))
+        await c.start()
+        try:
+            cl = await c.client()
+            await cl.command({"prefix": "osd erasure-code-profile set",
+                              "name": "p22",
+                              "profile": {"plugin": "jerasure", "k": "2",
+                                          "m": "2"}})
+            await cl.pool_create("p", pg_num=2, pool_type="erasure",
+                                 erasure_code_profile="p22")
+            io = cl.ioctx("p")
+            await io.write_full("warm", b"w" * 70_000)
+            if how == "recovery_push":
+                await c.kill_osd(3)
+                await c.wait_osd_down(3)
+            values = {f"o{n}": bytes([n + 1]) * (1 << 20) for n in range(4)}
+            before = {i: o.store.stats() for i, o in c.osds.items()}
+            await asyncio.gather(*(io.write_full(k, v)
+                                   for k, v in values.items()))
+            if how == "recovery_push":
+                # a new store object on the old directory: every block
+                # it writes from here on is a push's
+                back = await c.start_osd(
+                    3, store=BlueStore(str(tmp_path / "osd3")))
+                before[3] = back.store.stats()
+                deadline = asyncio.get_running_loop().time() + 30
+                # four OSDs for k + m = 4: it holds a shard of them all
+                while not set(values) <= {
+                        oid for pg in back.pgs.values()
+                        for oid in pg.list_objects()}:
+                    assert asyncio.get_running_loop().time() < deadline, \
+                        "recovery incomplete"
+                    await asyncio.sleep(0.2)
+            seen = 0
+            for i, osd in c.osds.items():
+                if how == "recovery_push" and i != 3:
+                    continue
+                for pg in osd.pgs.values():
+                    if osd.whoami not in pg.acting:
+                        continue
+                    for oid in set(values) & set(pg.list_objects()):
+                        cid, gh = pg.backend.coll(), pg.backend.ghobject(oid)
+                        on = osd.store._onode(cid, gh)
+                        kept = [crc for _u, _c, crcs in on["extents"]
+                                for crc in crcs]
+                        assert kept == json.loads(
+                            osd.store.getattr(cid, gh, "csum"))
+                        assert len(kept) == (1 << 19) // 4096
+                        assert len(osd.store.read(cid, gh)) == 1 << 19
+                        seen += 1
+                st = osd.store.stats()
+                wrote = st["block_bytes_written"] \
+                    - before[i]["block_bytes_written"]
+                assert wrote > 0 and wrote == st["csum_bytes_reused"] \
+                    - before[i]["csum_bytes_reused"]
+            assert seen == (4 if how == "recovery_push" else 16)
+            for k, v in values.items():
+                assert await io.read(k) == v
+            for osd in c.osds.values():
+                for pg in osd.pgs.values():
+                    if pg.primary == osd.whoami:
+                        res = await pg.scrub(deep=True)
+                        assert res["errors"] == 0, res
+        finally:
+            await c.stop()
+    run(body())
