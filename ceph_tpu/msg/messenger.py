@@ -29,12 +29,12 @@ The reference contract this keeps (src/msg/Messenger.h, ProtocolV2.cc):
     tx_sends, keepalives_skipped).
 
 Idiomatic divergences: one asyncio event loop per DAEMON (under the
-sharded reactor runtime, utils/reactor.py, each daemon's messenger
-binds, accepts, and dispatches wholly on its owning shard's loop —
-connections between daemons on different shards are ordinary localhost
-socket hops, same-shard stays in-loop; a Messenger and its Connections
+process-backed reactor runtime, utils/reactor.py, each daemon's
+messenger binds, accepts, and dispatches wholly on its worker's loop —
+connections between daemons in different workers are ordinary localhost
+socket hops, same-loop stays in-loop; a Messenger and its Connections
 are loop-bound objects in the loop-affinity sense and must never be
-driven from another shard without a threadsafe handoff);
+driven from another thread without a threadsafe handoff);
 coroutine-per-connection instead of a hand-rolled state machine; the
 banner/HELLO exchange carries JSON instead of dencoded structs.
 Transport: every Connection, initiated or accepted, rides one

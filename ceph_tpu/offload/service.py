@@ -11,14 +11,9 @@ real systems (arXiv:1709.05365); the cure is the admission-queue /
 continuous-batching discipline of an inference server (arXiv:2108.02692
 uses the same staging shape for XOR-network kernels).
 
-This module is that admission queue, one front end per event loop —
-one per vstart-style cluster in the single-loop world, one per reactor
-SHARD under the sharded runtime (utils/reactor.py), where the device
-topology, per-chip circuit breakers, and serving mesh are a single
-pool-shared object so every shard sees one rotation decision per chip
-while admission/batching/staging stay loop-local (cross-shard callers
-hand jobs over through `submit_threadsafe`'s call_soon_threadsafe
-handoff):
+This module is that admission queue, one service per event loop (one
+per vstart-style cluster; one per worker process under the
+process-backed reactor, each over its own partition of the chips):
 
   * submit(): callers hand over an `EncodeJob`/`DecodeJob`/`CrcJob`
     (numpy batch + codec identity) and await a future. Admission is
@@ -103,11 +98,9 @@ _DEFAULTS: dict[str, Any] = {
 
 #: one service per event loop: a loop is one cluster's world (tests and
 #: benches run many clusters through sequential asyncio.run calls, and a
-#: service holds loop-bound primitives). Under the sharded reactor each
-#: shard's loop gets its own service FRONT END (admission queue,
-#: buckets, staging pools — all loop-bound), while the device topology
-#: (breaker state per chip, serving mesh) is ONE shared object hung off
-#: the reactor pool, so four shards see one rotation decision per chip.
+#: service holds loop-bound primitives). The lock is for the config
+#: observer, which walks the table from an admin socket's thread while a
+#: loop's first get_service() inserts.
 _instances_lock = threading.Lock()
 _instances: dict[Any, "OffloadService"] = {}
 
@@ -153,8 +146,9 @@ _perf_lock = threading.Lock()
 def _perf():
     coll = PerfCountersCollection.instance()
     with _perf_lock:
-        # shard loops race the first-use registration; the lock also
-        # keeps a second caller from seeing a half-added counter set
+        # an admin socket's thread (`ec offload status`) can race a
+        # loop's first-use registration; the lock keeps the second
+        # caller from seeing a half-added counter set
         pc = coll.get("offload")
         if pc is not None:
             return pc
@@ -326,11 +320,10 @@ class _Bucket:
 
 
 class _DeviceState:
-    """Process-shared identity + circuit-breaker state for one
-    accelerator. Under a reactor pool every shard's service holds a
-    slot onto the SAME state, so breaker evidence (which arrives
-    concurrently from every shard loop) feeds one rotation decision
-    per chip; transitions take `lock`."""
+    """Identity + circuit-breaker state for one accelerator. Breaker
+    evidence is written on the service's loop and read from an admin
+    socket's thread (`ec offload status`, the MgrClient's device
+    report); transitions take `lock`."""
 
     __slots__ = ("label", "jdev", "lock", "degraded", "degraded_since",
                  "consec_failures", "probe_owner", "last_error")
@@ -338,9 +331,8 @@ class _DeviceState:
     def __init__(self, label: str, jdev):
         self.label = label
         self.jdev = jdev                 # jax device, or None = host lane
-        # lockset-recorded (sanitizer TSan-lite): breaker evidence
-        # arrives from every shard thread, and the recorder proves the
-        # "transitions take lock" contract at runtime
+        # lockset-recorded (sanitizer TSan-lite): the recorder proves
+        # the "transitions take lock" contract at runtime
         self.lock = sanitizer.make_lock(f"devstate:{label}")
         self.degraded = False
         self.degraded_since = 0.0
@@ -353,10 +345,11 @@ class _DeviceState:
 
 
 class _Topology:
-    """The cross-shard half of the service: device states, the serving
-    mesh, and the mesh breaker. One per reactor pool (shared by every
-    shard's service) or one per unpooled service (the pre-shard
-    behavior, unchanged)."""
+    """The device half of a service: device states, the serving mesh,
+    and the mesh breaker. The loop routes on it, the `ec-offload`
+    executor's threads fetch mesh kernels from it, and an admin
+    socket's thread resets it (`config set ec_offload_device_count`)
+    and reads it (`ec offload status`): every access takes `lock`."""
 
     def __init__(self):
         self.lock = sanitizer.make_lock("offload_topology")
@@ -368,9 +361,10 @@ class _Topology:
         self.mesh_probe_inflight = False
 
     def note(self, field: str, write: bool) -> None:
-        """Lockset-recorder tap: every shard thread touches this
-        topology, so each field access feeds the sanitizer's TSan-lite
-        conflict analysis (no-op unless recording is armed)."""
+        """Lockset-recorder tap: the loop, the executor's threads and
+        an admin socket's thread touch this topology, so each field
+        access feeds the sanitizer's TSan-lite conflict analysis (no-op
+        unless recording is armed)."""
         sanitizer.note_shared_access(self, field, write)
 
     def reset(self) -> None:
@@ -383,13 +377,12 @@ class _Topology:
             self.mesh_probe_inflight = False
 
     def device_states(self, device_count: int) -> list[_DeviceState]:
-        """Build (once) the shared device list; later callers — other
-        shards' services — reuse it. The expensive half (jax import,
-        device enumeration, mesh build) runs OUTSIDE the lock: shard
-        event loops take this lock synchronously in _mesh_allowed, and
-        holding it across a multi-second backend init would freeze
-        every shard (a racing duplicate build is discarded, which is
-        benign)."""
+        """Build (once) the device list; later callers reuse it. The
+        expensive half (jax import, device enumeration, mesh build)
+        runs OUTSIDE the lock: an admin socket's thread takes this lock
+        in `reset`, and holding it across a multi-second backend init
+        would hold that thread too (a build that raced a reset is
+        discarded or published once, which is benign)."""
         with self.lock:
             self.note("states", write=False)
             if self.states is not None:
@@ -441,9 +434,10 @@ class _Topology:
 
     def mesh_fn(self, cache_key: tuple, M: np.ndarray) -> Callable:
         """The cached stripe-sharded kernel for matrix `M` — one
-        compile per pool, shared by every shard. The XLA compile runs
-        outside the lock (same reasoning as device_states; a racing
-        double-compile loses to setdefault)."""
+        compile per service. Called on the executor's threads; the XLA
+        compile runs outside the lock, which the loop takes
+        synchronously in _mesh_allowed (a racing double-compile loses
+        to setdefault)."""
         with self.lock:
             self.note("mesh_fns", write=False)
             fn = self.mesh_fns.get(cache_key)
@@ -458,11 +452,11 @@ class _Topology:
 
 
 class _DeviceSlot:
-    """One shard's dispatch handle onto a device: the per-shard
-    pipeline semaphore and reusable staging buffers (loop-bound, never
-    shared) plus a reference to the cross-shard `_DeviceState` breaker.
-    Breaker fields proxy through so routing/dispatch code (and tests)
-    keep the flat slot API."""
+    """The service's dispatch handle onto a device: the pipeline
+    semaphore and reusable staging buffers (loop-bound) plus a
+    reference to the `_DeviceState` breaker, which outlives a rebuilt
+    slot list. Breaker fields proxy through so routing/dispatch code
+    (and tests) keep the flat slot API."""
 
     __slots__ = ("state", "sem", "depth", "inflight", "staging")
 
@@ -473,9 +467,9 @@ class _DeviceSlot:
         self.inflight = 0                # batches routed here, not done
         # pinned-in-spirit staging: reused flat uint8 arrays (the warm
         # pages the link bench's reused-buffer rate measures); at most
-        # `depth` buffers — the double-buffer pair at depth 2. Per
-        # SHARD: staging arrays are written on this shard's dispatch
-        # path only, so they never need a lock.
+        # `depth` buffers — the double-buffer pair at depth 2. Staging
+        # arrays are written on this service's dispatch path only, so
+        # they never need a lock.
         self.staging: list[np.ndarray] = []
 
     @property
@@ -619,44 +613,15 @@ class OffloadService:
         # dispatch topology (built lazily on first use: importing jax /
         # enumerating devices must not tax service construction on
         # paths that never touch a device). The device/breaker/mesh
-        # half lives in `_topo` — ONE shared object across every shard
-        # of a reactor pool, private for unpooled loops — while the
-        # slots (pipeline semaphores + staging pools) stay per shard.
-        # Resolved per ACCESS (the _topo property): services are cached
-        # per loop across ShardPool lifetimes, and a service created
-        # before its loop joined a pool must re-bind to the pool-shared
-        # topology or shard 0 would run a private breaker world.
-        self._topo_pool = None
-        self._topo_obj: _Topology | None = None
+        # half lives in `_topo`, the slots (pipeline semaphores +
+        # staging pools) in `_slots`.
+        self._topo = _Topology()
         self._slots: list[_DeviceSlot] | None = None
         self._host_slot = _DeviceSlot(_DeviceState("host", None),
                                       self.pipeline_depth)
         self._last_error = ""
         # per-kernel-kind achieved-GB/s EWMA backing the kernel_*_gbps gauges
         self._kernel_gbps: dict[str, float] = {}
-
-    @property
-    def _topo(self) -> _Topology:
-        try:
-            from ceph_tpu.utils import reactor
-            pool = reactor.pool_for(self._loop)
-        except Exception:
-            pool = None
-        if pool is not None and \
-                getattr(pool, "backend", "thread") != "thread":
-            # process-backed shards share no memory: shared() is
-            # structurally absent there, and each worker process keeps
-            # its OWN topology over its partition of the chips (the
-            # parent's control loop likewise stays private)
-            pool = None
-        if self._topo_obj is None or pool is not self._topo_pool:
-            self._topo_pool = pool
-            self._topo_obj = pool.shared("offload_topology", _Topology) \
-                if pool is not None else _Topology()
-            # slots reference the previous topology's device states:
-            # rebuild them onto the new one at next dispatch
-            self._slots = None
-        return self._topo_obj
 
     # -- config --------------------------------------------------------------
 
@@ -695,9 +660,7 @@ class OffloadService:
         elif name == "ec_offload_device_count":
             self.device_count = int(value)
             # in-flight batches keep their slot refs; new flushes see
-            # the rebuilt topology (shared reset: the observer applies
-            # the change to every shard's service, each of which drops
-            # its own slot list here)
+            # the rebuilt topology
             self._slots = None
             self._topo.reset()
         elif name == "ec_offload_device_shard_bytes":
@@ -708,15 +671,15 @@ class OffloadService:
     # -- dispatch topology ---------------------------------------------------
 
     def _topology(self) -> list[_DeviceSlot]:
-        """This shard's device slots (built on first use): one per
+        """This service's device slots (built on first use): one per
         visible accelerator (capped by ec_offload_device_count), plus
         the mesh for stripe-sharded oversized batches — the stripe-only
         serving mesh where every chip does full-rate data-parallel work
         (the (stripe, shard) shape stays the dryrun/TP-validation
         config; its shard axis pays an all-gather plus padded parity
         rows, a net loss at m=3). Device identity/breaker state and the
-        mesh are the SHARED topology; the slot objects (pipeline
-        semaphore, staging pool) are this loop's own."""
+        mesh live in `_topo`; the slot objects (pipeline semaphore,
+        staging pool) are rebuilt onto it after a reset."""
         if self._slots is not None:
             return self._slots
         states = self._topo.device_states(self.device_count)
@@ -777,11 +740,11 @@ class OffloadService:
                     self.perf.inc("device_spills")
                     self.stats["device_spills"] += 1
             if chosen.degraded:
-                # half-open probe claim, ATOMIC across shards (anonymous
-                # token when the caller has none, so the window still
-                # admits only one batch). Losing the claim race to
-                # another shard's batch means the slot just left the
-                # allowed set — re-route around it.
+                # half-open probe claim, atomic under the state's lock
+                # (anonymous token when the caller has none, so the
+                # window still admits only one batch). Finding the
+                # claim taken by another batch means the slot just left
+                # the allowed set — re-route around it.
                 state = chosen.state
                 with state.lock:
                     if state.degraded and state.probe_owner is not None:
@@ -988,21 +951,6 @@ class OffloadService:
                                   dispatch, dispatch, uses_device=False)
 
     # -- admission -----------------------------------------------------------
-
-    def submit_threadsafe(self, method: str, *args,
-                          **kw) -> concurrent.futures.Future:
-        """Cross-loop submission seam: build one of the public job
-        coroutines (`encode`/`decode`/`crc32c_blocks`/`repair`) and
-        hand it to the owning shard's loop via run_coroutine_threadsafe
-        — the call_soon_threadsafe handoff, packaged. Callers on other
-        shards (or plain threads) get a concurrent Future; awaiting
-        shards wrap it with asyncio.wrap_future. The admission queue,
-        buckets, and staging stay loop-bound — only the HANDOFF crosses
-        threads, which is the whole loop-affinity discipline."""
-        if self._loop.is_closed():
-            raise RuntimeError("offload service's loop is closed")
-        coro = getattr(self, method)(*args, **kw)
-        return asyncio.run_coroutine_threadsafe(coro, self._loop)
 
     async def _submit(self, key: tuple, data: np.ndarray,
                       dispatch: Callable, fallback: Callable,
@@ -1429,8 +1377,7 @@ class OffloadService:
                     batch: np.ndarray) -> np.ndarray:
         """Stripe-shard `batch` across the whole mesh through the
         cached sharded kernel for matrix `M` (runs in the staging
-        pool; the kernel cache is pool-shared — one compile serves
-        every shard)."""
+        pool)."""
         fn = self._topo.mesh_fn(cache_key, M)
         nbytes = int(batch.nbytes)
         out = fn(batch)
@@ -1449,9 +1396,10 @@ class OffloadService:
             if (time.monotonic() - topo.mesh_degraded_since
                     >= self.breaker_reset_s) and \
                     not topo.mesh_probe_inflight:
-                # half-open: claim the single probe batch (one claim
-                # ACROSS shards — the lock makes it atomic); cleared on
-                # the probe's success, failure, or cancellation
+                # half-open: claim the single probe batch (the lock
+                # makes it atomic against a reset from an admin
+                # socket's thread); cleared on the probe's success,
+                # failure, or cancellation
                 topo.note("mesh_degraded", write=True)
                 topo.mesh_probe_inflight = True
                 return True
@@ -1808,9 +1756,9 @@ def _host_crc(batch: np.ndarray, block_size: int) -> np.ndarray:
 # -- per-loop instance + config plumbing -------------------------------------
 
 def get_service() -> OffloadService:
-    """The running loop's service (created on first use). Thread-safe:
-    under the sharded reactor every shard loop races this on first
-    dispatch."""
+    """The running loop's service (created on first use). Thread-safe
+    against the config observer's walk of the table from an admin
+    socket's thread."""
     loop = asyncio.get_running_loop()
     with _instances_lock:
         svc = _instances.get(loop)
@@ -1819,13 +1767,6 @@ def get_service() -> OffloadService:
                 del _instances[stale]
             svc = _instances[loop] = OffloadService(loop)
     return svc
-
-
-def service_for(loop) -> OffloadService | None:
-    """An existing service by loop (no creation) — the lookup a foreign
-    shard or plain thread uses before submit_threadsafe."""
-    with _instances_lock:
-        return _instances.get(loop)
 
 
 def get_service_or_none() -> OffloadService | None:
@@ -1904,8 +1845,9 @@ def register_config(config) -> None:
         key = name[len("ec_offload_"):]
         if key in _DEFAULTS:
             _DEFAULTS[key] = value
-        # snapshot under the lock: a shard loop's first get_service()
-        # can insert mid-iteration (observers fire on arbitrary threads)
+        # snapshot under the lock: a loop's first get_service() can
+        # insert mid-iteration (observers fire on an admin socket's
+        # thread)
         with _instances_lock:
             services = list(_instances.values())
         for svc in services:

@@ -613,10 +613,8 @@ class OSD(Dispatcher):
         from ceph_tpu import offload
         self._offload_svc = offload.get_service()
         self._loop = asyncio.get_running_loop()
-        # reactor placement: under the sharded runtime start() runs ON
-        # the owning shard's loop, so every loop-bound resource this
-        # daemon creates (messenger server, connections, op queue,
-        # offload front end) lands on that shard by construction
+        # reactor placement: in a worker of the process-backed runtime
+        # this is the pool-wide shard index the parent assigned
         from ceph_tpu.utils import reactor
         self.shard = reactor.shard_index_of(self._loop)
         sanitizer.maybe_install(self.config)

@@ -2,14 +2,13 @@
 
 PR 13 made op completion order a real degree of freedom (same-PG ops
 to different objects execute concurrently behind the ordered pg-log
-slice), PR 9 put daemons on N reactor threads, and PR 12 coalesces
-wire traffic opportunistically — so "the tests pass" increasingly
-means "the tests pass under the one schedule asyncio happened to
-pick". This module makes the schedule an *input*: it wraps an event
-loop so ready-callback order is bounded-shuffled and explicit yield
-points stretch the racy windows, with every decision derived from
-`(seed, site, per-site counter)` exactly like qa/faultinject — one
-seed IS one schedule, replayable bit-identically.
+slice), and PR 12 coalesces wire traffic opportunistically — so "the
+tests pass" increasingly means "the tests pass under the one schedule
+asyncio happened to pick". This module makes the schedule an *input*:
+it wraps an event loop so ready-callback order is bounded-shuffled and
+explicit yield points stretch the racy windows, with every decision
+derived from `(seed, site, per-site counter)` exactly like
+qa/faultinject — one seed IS one schedule, replayable bit-identically.
 
 Mechanics:
 
@@ -76,8 +75,8 @@ class Explorer:
         self.decisions = 0
         self._counts: dict[str, int] = {}
         self._hash = hashlib.sha256(str(self.seed).encode())
-        # counters/log mutate from every shard thread the explorer is
-        # installed on; decisions are lock-cheap
+        # `install` is per loop: one explorer on two loops mutates the
+        # counters/log from two threads; decisions are lock-cheap
         self._lock = threading.Lock()
 
     # -- deterministic decisions ---------------------------------------------

@@ -10,17 +10,11 @@ mid-loop), always through `bounded_stop`, so a wedged daemon stop is
 cancelled-and-awaited rather than abandoned. Pool/profile creation
 stays with the caller — that is what the call sites actually differ in.
 
-`reactor_shards` dials the sharded reactor runtime (utils/reactor.py):
-with N > 1 the OSDs are placed round-robin across N event-loop shards
-(shard 0 = the calling loop, which keeps the mon and the client — the
-control plane), each OSD's whole lifecycle (start, dispatch, stop)
-running on its owning shard. N = 1 is byte-for-byte the old single-loop
-boot: no pool, no threads.
-
-`reactor_procs` dials the PROCESS-backed runtime instead: N spawned
-worker processes (shards 1..N), OSDs placed round-robin into them and
-booted over the admin-socket control channel, while the mon and client
-stay in this process on shard 0. The yielded `osds` are
+`reactor_procs` dials the process-backed reactor runtime
+(utils/reactor.py): N spawned worker processes (shards 1..N), OSDs
+placed round-robin into them and booted over the admin-socket control
+channel, while the mon and client stay in this process on shard 0. 0 is
+the single-loop boot: no pool, no workers. The yielded `osds` are
 `WorkerOSDRef` handles — daemon state lives in the workers, so the
 refs marshal everything (config, admin verbs, status) as JSON; there
 is no in-process OSD object to poke.
@@ -34,7 +28,7 @@ import tempfile
 from typing import AsyncIterator, Callable
 
 from ceph_tpu.utils.async_util import bounded_stop
-from ceph_tpu.utils.reactor import ProcShardPool, ShardPool
+from ceph_tpu.utils.reactor import ProcShardPool
 
 
 class WorkerOSDRef:
@@ -56,7 +50,7 @@ class WorkerOSDRef:
     async def config_set(self, key: str, value) -> None:
         """Set one option on THIS OSD only (whoami-routed — co-hosted
         OSDs in the same worker keep their values, matching the
-        thread-mode `osd.config.set` semantics). Pool-wide broadcasts
+        in-process `osd.config.set` semantics). Pool-wide broadcasts
         go through `pool.config_set` instead. Recorded so a respawned
         worker replays it onto this daemon's fresh boot."""
         await self.admin({"prefix": "config set", "key": key,
@@ -78,29 +72,22 @@ async def ephemeral_cluster(
         n_osds: int, prefix: str = "ceph-tpu-",
         store_factory: Callable[[str, int], object] | None = None,
         stop_timeout: float = 20.0,
-        reactor_shards: int = 1,
         reactor_procs: int = 0) -> AsyncIterator[tuple]:
     """Boot mon + `n_osds` OSDs on localhost and a connected client;
     yield `(client, osds, mon)`; reap everything on exit.
 
     `store_factory(tmpdir, osd_id)` supplies a per-OSD ObjectStore
-    (None -> MemStore default). `reactor_shards` > 1 spreads the OSDs
-    over that many reactor shards; `reactor_procs` > 0 spreads them
-    over that many worker PROCESSES instead (see module doc) — the two
-    modes are mutually exclusive, and a store_factory cannot cross a
-    process boundary."""
+    (None -> MemStore default). `reactor_procs` > 0 spreads the OSDs
+    over that many worker PROCESSES (see module doc); a store_factory
+    cannot cross a process boundary."""
     from ceph_tpu.mon import MonMap, Monitor
     from ceph_tpu.osd.daemon import OSD
     from ceph_tpu.rados import RadosClient
 
-    if reactor_procs > 0:
-        if reactor_shards > 1:
-            raise ValueError("reactor_shards and reactor_procs are "
-                             "mutually exclusive")
-        if store_factory is not None:
-            raise ValueError("store_factory closures cannot cross the "
-                             "process boundary: process-backed OSDs "
-                             "build their own (MemStore) stores")
+    if reactor_procs > 0 and store_factory is not None:
+        raise ValueError("store_factory closures cannot cross the "
+                         "process boundary: process-backed OSDs "
+                         "build their own (MemStore) stores")
 
     s = socket.socket()
     s.bind(("127.0.0.1", 0))
@@ -110,17 +97,8 @@ async def ephemeral_cluster(
     monmap = MonMap({"m0": ("127.0.0.1", port)})
     mon = Monitor("m0", monmap, store_path=f"{tmp}/mon")
     await mon.start()
-    pool = None
     osds: list = []
-    shard_of: dict[int, int] = {}
     client = None
-
-    async def _on_shard(i: int, coro):
-        """Run `coro` on OSD i's shard (inline in the 1-shard world)."""
-        if pool is None:
-            return await coro
-        return await pool.run_on(shard_of[i], coro)
-
     try:
         # inside the try: a pool that fails to come up must still tear
         # the already-running mon down
@@ -128,8 +106,6 @@ async def ephemeral_cluster(
         if reactor_procs > 0:
             proc_pool = ProcShardPool(reactor_procs, base_dir=tmp)
             await proc_pool.start()
-        elif reactor_shards > 1:
-            pool = ShardPool(reactor_shards)
         while not (mon.paxos.is_leader() and mon.paxos.is_active()):
             await asyncio.sleep(0.05)
         mon_addrs = list(monmap.mons.values())
@@ -141,8 +117,7 @@ async def ephemeral_cluster(
                 continue
             store = store_factory(tmp, i) if store_factory else None
             osd = OSD(i, mon_addrs, store=store)
-            shard_of[i] = pool.place(i) if pool is not None else 0
-            await _on_shard(i, osd.start())
+            await osd.start()
             osds.append(osd)
         client = RadosClient(mon_addrs)
         await client.connect()
@@ -155,12 +130,7 @@ async def ephemeral_cluster(
             # (bounded, straggler-reaped), then the pool reaps the
             # processes themselves
             await proc_pool.shutdown(stop_timeout)
-        for i, osd in enumerate(osds):
-            if isinstance(osd, WorkerOSDRef):
-                continue
-            # stop each OSD ON its owning shard: its tasks, queues, and
-            # connections are that loop's objects (loop-affinity rule)
-            await _on_shard(i, bounded_stop(osd.stop(), stop_timeout))
+        for osd in osds:
+            if not isinstance(osd, WorkerOSDRef):
+                await bounded_stop(osd.stop(), stop_timeout)
         await bounded_stop(mon.stop(), stop_timeout)
-        if pool is not None:
-            await pool.shutdown()

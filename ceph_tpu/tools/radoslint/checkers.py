@@ -236,8 +236,8 @@ _LOOP_UNSAFE = {"call_soon", "call_later", "call_at", "create_task"}
 class _LoopAffinityVisitor(_AsyncScopeVisitor):
     """Driving ANOTHER object's event-loop handle with a non-threadsafe
     primitive: `svc._loop.call_soon(...)` / `conn.loop.create_task(...)`
-    where the receiver is not `self`. Under the sharded reactor the
-    other object's loop is routinely a different shard's, and
+    where the receiver is not `self`. The caller may be on an admin
+    socket's, an executor's or a store's commit thread, and
     call_soon/create_task from a foreign thread corrupts the loop's
     ready queue (asyncio only checks with debug mode on). `self._loop.X`
     stays legal — an object drives its own loop from its own methods —
@@ -258,8 +258,9 @@ class _LoopAffinityVisitor(_AsyncScopeVisitor):
                     node, "loop-affinity",
                     f"{owner}.{fn.value.attr}.{fn.attr}(...) drives "
                     f"another object's event loop without the "
-                    f"threadsafe handoff: under the sharded reactor "
-                    f"{owner}'s loop can be a different shard's thread, "
+                    f"threadsafe handoff: {owner}'s loop can run on "
+                    f"another thread than the caller's (admin socket, "
+                    f"executor, commit thread), "
                     f"and {fn.attr} from a foreign thread corrupts the "
                     f"loop's ready queue — use "
                     f"{owner}.{fn.value.attr}.call_soon_threadsafe or "
@@ -268,13 +269,13 @@ class _LoopAffinityVisitor(_AsyncScopeVisitor):
 
 
 @rule("loop-affinity", "file",
-      "cross-shard loop discipline (the sharded reactor's lockdep): "
+      "cross-thread loop discipline: "
       "loop-bound objects (OffloadService, Throttle waiters, messenger "
-      "connections) belong to exactly one shard, and scheduling onto "
+      "connections) belong to exactly one loop, and scheduling onto "
       "ANOTHER object's loop handle via call_soon/call_later/call_at/"
       "create_task is only safe from that loop's own thread. Foreign "
-      "owners must cross through call_soon_threadsafe / "
-      "run_coroutine_threadsafe (or reactor.ShardPool.run_on), which "
+      "owners (admin-socket, executor and commit threads) must cross "
+      "through call_soon_threadsafe / run_coroutine_threadsafe, which "
       "are loop-safe from any thread.")
 def check_loop_affinity(sf: SourceFile) -> list[Finding]:
     v = _LoopAffinityVisitor(sf)

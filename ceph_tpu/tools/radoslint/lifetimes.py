@@ -1,5 +1,4 @@
-"""Zero-copy lifetime & cross-shard dataflow rules: the interlock
-static half.
+"""Zero-copy lifetime dataflow rules: the interlock static half.
 
 PR 9/12 bought their speed by replacing copies with MEMORYVIEWS over
 buffers that outlive or get *recycled* under them — offload staging
@@ -17,11 +16,9 @@ the last byte, across any number of awaits. That is safe for the same
 reason and no other: what becomes `Message.data` is `bytes`, a view of
 an rx body, or an array nobody writes again, never a window onto a
 recycled source — which is what `view-escape` keeps off object
-attributes, `msg.data` among them. And PR 9's ShardPool put mutable
-service state (`shared()` objects, the offload device topology) in
-reach of N OS threads at once. Both disciplines were hand-audited;
+attributes, `msg.data` among them. The discipline was hand-audited;
 these rules make the audit mechanical, the way `loop-affinity` froze
-the reactor's loop-handle discipline:
+the loop-handle discipline:
 
   * `view-escape` — a view derived from a pooled/recycled source
     (staging pages via `get_staging`, frame `segments`, raw
@@ -34,20 +31,8 @@ the reactor's loop-handle discipline:
     exactly where another task can recycle the buffer, so the resumed
     code reads the next batch's bytes. Materialize before suspending,
     or re-derive the view after.
-  * `shard-shared-mutation` — attribute/container writes to a
-    ShardPool `shared()` object outside a lock-scoped `with` block.
-    `shared()` state is the one thing multiple reactor threads touch
-    by design (device topology, breakers, mesh caches); every mutation
-    must sit under the object's lock or cross a threadsafe seam —
-    this generalizes `loop-affinity` from loop-API calls to data.
-  * `proc-shared-state` — thread-backed conveniences reaching into a
-    PROCESS-backed pool (`ProcShardPool`): mutating a `shared()`
-    result (cross-process memory doesn't exist — no lock fixes it) or
-    handing `run_on()` a closure/coroutine whose captured parent state
-    cannot cross the interpreter boundary. The marshalling rule the
-    process-per-shard runtime enforces at runtime, caught statically.
 
-All four are local-dataflow rules (per function scope, no
+Both are local-dataflow rules (per function scope, no
 cross-function propagation) tuned for precision: a finding means the
 pattern is textually present, not merely possible. Designed-in
 zero-copy contracts (e.g. `Frame._parse_segments` returning views the
@@ -57,8 +42,8 @@ from __future__ import annotations
 
 import ast
 
-from ceph_tpu.tools.radoslint.checkers import (_FUNCS, _looks_like_lock,
-                                               dotted, terminal_name)
+from ceph_tpu.tools.radoslint.checkers import (_FUNCS, dotted,
+                                               terminal_name)
 from ceph_tpu.tools.radoslint.core import Finding, SourceFile, rule
 
 #: call attrs that hand out a window onto a RECYCLED pool (the staging
@@ -335,307 +320,4 @@ def check_view_escape(sf: SourceFile) -> list[Finding]:
                         f"document the refcount contract with a "
                         f"justified disable",
                         end_line=node.end_lineno or 0))
-    return out
-
-
-# -- rule: shard-shared-mutation ----------------------------------------------
-
-_MUTATORS = {"append", "add", "update", "setdefault", "pop", "remove",
-             "clear", "extend", "insert", "discard"}
-
-
-def _with_is_locked(node: ast.With | ast.AsyncWith) -> bool:
-    for item in node.items:
-        expr = item.context_expr
-        if _looks_like_lock(expr):
-            return True
-        if isinstance(expr, ast.Call) and _looks_like_lock(expr.func):
-            return True
-    return False
-
-
-def _shared_bindings(stmts):
-    """(names, dotted-paths) bound from `<pool>.shared(...)` calls in a
-    statement list."""
-    names: set[str] = set()
-    paths: set[str] = set()
-    for node in _stmt_walk(stmts):
-        if isinstance(node, ast.Assign) and len(node.targets) == 1 \
-                and isinstance(node.value, ast.Call) \
-                and isinstance(node.value.func, ast.Attribute) \
-                and node.value.func.attr == "shared":
-            tgt = node.targets[0]
-            if isinstance(tgt, ast.Name):
-                names.add(tgt.id)
-            else:
-                d = dotted(tgt)
-                if d is not None:
-                    paths.add(d)
-    return names, paths
-
-
-@rule("shard-shared-mutation", "file",
-      "attribute or container mutation of a ShardPool shared() object "
-      "outside a lock-scoped `with`: shared() state (offload device "
-      "topology, breakers, mesh caches) is touched by every reactor "
-      "thread in the pool, and an unlocked write races the other "
-      "shards — torn breaker state, lost mesh-cache entries. Mutate "
-      "under the object's lock (`with topo.lock:`) or marshal through "
-      "a threadsafe seam (run_on / call_soon_threadsafe). The data "
-      "half of the loop-affinity discipline.")
-def check_shard_shared_mutation(sf: SourceFile) -> list[Finding]:
-    out: list[Finding] = []
-    # class-level: `self._topo = pool.shared(...)` in ANY method (the
-    # real offload shape binds in __init__, mutates in routing methods)
-    # marks that self-path shared for every method of the class
-    class_paths: dict[ast.AST, set[str]] = {}
-    for cls in ast.walk(sf.tree):
-        if isinstance(cls, ast.ClassDef):
-            paths: set[str] = set()
-            for item in cls.body:
-                if isinstance(item, (ast.FunctionDef,
-                                     ast.AsyncFunctionDef)):
-                    _, p = _shared_bindings(item.body)
-                    paths |= {x for x in p if x.startswith("self.")}
-            for item in cls.body:
-                if isinstance(item, (ast.FunctionDef,
-                                     ast.AsyncFunctionDef)):
-                    class_paths[item] = paths
-    for fn in _iter_functions(sf.tree):
-        shared_names, shared_paths = _shared_bindings(fn.body)
-        shared_paths = shared_paths | class_paths.get(fn, set())
-        if not shared_names and not shared_paths:
-            continue
-
-        def receiver(expr: ast.AST) -> str | None:
-            """The tracked shared object an attribute chain hangs off:
-            `topo.states` -> 'topo'; `self._topo.mesh` -> 'self._topo'
-            when `self._topo = pool.shared(...)` was seen."""
-            d = dotted(expr)
-            if d is None:
-                return None
-            if d.split(".")[0] in shared_names:
-                return d.split(".")[0]
-            for sp in shared_paths:
-                if d == sp or d.startswith(sp + "."):
-                    return sp
-            return None
-
-        def walk(stmts, locked: bool):
-            for node in stmts:
-                if isinstance(node, _FUNCS):
-                    continue
-                if isinstance(node, (ast.With, ast.AsyncWith)):
-                    walk(node.body, locked or _with_is_locked(node))
-                    continue
-                if isinstance(node, (ast.Assign, ast.AugAssign)) \
-                        and not locked:
-                    targets = node.targets if isinstance(node, ast.Assign) \
-                        else [node.target]
-                    for tgt in targets:
-                        if not isinstance(tgt, (ast.Attribute,
-                                                ast.Subscript)):
-                            continue
-                        if isinstance(tgt, ast.Attribute) and \
-                                "lock" in tgt.attr.lower():
-                            continue        # installing the lock itself
-                        recv = receiver(tgt.value)
-                        if recv is not None:
-                            out.append(Finding(
-                                sf.path, node.lineno,
-                                "shard-shared-mutation",
-                                f"write to shared() object {recv!r} "
-                                f"outside its lock: every reactor "
-                                f"thread in the pool sees this state — "
-                                f"mutate under `with {recv}.lock:` or "
-                                f"cross a threadsafe seam",
-                                end_line=node.end_lineno or 0))
-                elif isinstance(node, ast.Expr) and not locked and \
-                        isinstance(node.value, ast.Call) and \
-                        isinstance(node.value.func, ast.Attribute) and \
-                        node.value.func.attr in _MUTATORS:
-                    recv = receiver(node.value.func.value)
-                    if recv is not None:
-                        out.append(Finding(
-                            sf.path, node.lineno, "shard-shared-mutation",
-                            f"{node.value.func.attr}() mutates shared() "
-                            f"object {recv!r} outside its lock — mutate "
-                            f"under `with {recv}.lock:` or cross a "
-                            f"threadsafe seam",
-                            end_line=node.end_lineno or 0))
-                for blk in ("body", "orelse", "finalbody"):
-                    part = getattr(node, blk, None)
-                    if part and isinstance(part, list) and \
-                            part and isinstance(part[0], ast.stmt):
-                        walk(part, locked)
-                for h in getattr(node, "handlers", []):
-                    walk(h.body, locked)
-
-        walk(fn.body, False)
-    return out
-
-
-# -- rule: proc-shared-state --------------------------------------------------
-
-def _proc_pool_bindings(stmts):
-    """(names, dotted-paths) bound from `ProcShardPool(...)` calls in a
-    statement list."""
-    names: set[str] = set()
-    paths: set[str] = set()
-    for node in _stmt_walk(stmts):
-        if isinstance(node, ast.Assign) and len(node.targets) == 1 \
-                and isinstance(node.value, ast.Call) \
-                and terminal_name(node.value.func) == "ProcShardPool":
-            tgt = node.targets[0]
-            if isinstance(tgt, ast.Name):
-                names.add(tgt.id)
-            else:
-                d = dotted(tgt)
-                if d is not None:
-                    paths.add(d)
-    return names, paths
-
-
-@rule("proc-shared-state", "file",
-      "thread-backed pool conveniences reaching into a PROCESS-backed "
-      "reactor pool: mutating the result of a ProcShardPool "
-      "`shared()`, or handing a `run_on()` closure/coroutine (which "
-      "captures parent-process state) to one. Cross-process memory "
-      "does not exist — the \"shared\" object is a parent-local "
-      "orphan the workers never see, and a closure cannot be shipped "
-      "to another interpreter. Marshal explicit JSON through the "
-      "control channel (`pool.call()` / `pool.config_set()` / "
-      "`pool.boot_osd()`), or let state flow over the cluster's own "
-      "wire protocol. The runtime raises on both; this rule catches "
-      "the pattern before it runs.")
-def check_proc_shared_state(sf: SourceFile) -> list[Finding]:
-    out: list[Finding] = []
-    # class-level: `self._pool = ProcShardPool(...)` in any method
-    # marks that self-path process-backed for every method; shared()
-    # results bound off it anywhere are tracked class-wide too (the
-    # shard-shared-mutation shape, minus the lock escape — a lock
-    # doesn't span processes)
-    class_pools: dict[ast.AST, set[str]] = {}
-    class_shared: dict[ast.AST, set[str]] = {}
-    for cls in ast.walk(sf.tree):
-        if isinstance(cls, ast.ClassDef):
-            paths: set[str] = set()
-            methods = [item for item in cls.body
-                       if isinstance(item, (ast.FunctionDef,
-                                            ast.AsyncFunctionDef))]
-            for item in methods:
-                _, p = _proc_pool_bindings(item.body)
-                paths |= {x for x in p if x.startswith("self.")}
-            # second pass: `self.X = self._pool.shared(...)` bound in
-            # any method (the __init__-binds / method-mutates shape) is
-            # proc-shared for every method of the class
-            shared: set[str] = set()
-            for item in methods:
-                for node in _stmt_walk(item.body):
-                    if isinstance(node, ast.Assign) \
-                            and len(node.targets) == 1 \
-                            and isinstance(node.value, ast.Call) \
-                            and isinstance(node.value.func,
-                                           ast.Attribute) \
-                            and node.value.func.attr == "shared":
-                        recv = dotted(node.value.func.value)
-                        if recv is not None and recv in paths:
-                            d = dotted(node.targets[0])
-                            if d is not None and d.startswith("self."):
-                                shared.add(d)
-            for item in methods:
-                class_pools[item] = paths
-                class_shared[item] = shared
-
-    for fn in _iter_functions(sf.tree):
-        pool_names, pool_paths = _proc_pool_bindings(fn.body)
-        pool_paths = pool_paths | class_pools.get(fn, set())
-        if not pool_names and not pool_paths:
-            continue
-
-        def is_pool(expr: ast.AST) -> bool:
-            d = dotted(expr)
-            return d is not None and (d in pool_names or d in pool_paths)
-
-        def is_pool_shared_call(expr: ast.AST) -> bool:
-            return isinstance(expr, ast.Call) \
-                and isinstance(expr.func, ast.Attribute) \
-                and expr.func.attr == "shared" \
-                and is_pool(expr.func.value)
-
-        # names/paths bound from `<procpool>.shared(...)`
-        shared_names: set[str] = set()
-        shared_paths: set[str] = set(class_shared.get(fn, set()))
-        for node in _stmt_walk(fn.body):
-            if isinstance(node, ast.Assign) and len(node.targets) == 1 \
-                    and is_pool_shared_call(node.value):
-                tgt = node.targets[0]
-                if isinstance(tgt, ast.Name):
-                    shared_names.add(tgt.id)
-                else:
-                    d = dotted(tgt)
-                    if d is not None:
-                        shared_paths.add(d)
-
-        def shared_receiver(expr: ast.AST) -> str | None:
-            if is_pool_shared_call(expr):
-                return "shared() result"
-            d = dotted(expr)
-            if d is None:
-                return None
-            if d.split(".")[0] in shared_names:
-                return d.split(".")[0]
-            for sp in shared_paths:
-                if d == sp or d.startswith(sp + "."):
-                    return sp
-            return None
-
-        for node in _stmt_walk(fn.body):
-            if isinstance(node, (ast.Assign, ast.AugAssign)):
-                targets = node.targets if isinstance(node, ast.Assign) \
-                    else [node.target]
-                for tgt in targets:
-                    if not isinstance(tgt, (ast.Attribute, ast.Subscript)):
-                        continue
-                    recv = shared_receiver(tgt.value)
-                    if recv is not None and not is_pool_shared_call(
-                            node.value):
-                        out.append(Finding(
-                            sf.path, node.lineno, "proc-shared-state",
-                            f"write to process-backed pool shared() "
-                            f"object {recv!r}: worker processes share "
-                            f"no memory with this one — the mutation "
-                            f"is a parent-local orphan. Marshal it "
-                            f"through the control channel "
-                            f"(pool.call/config_set)",
-                            end_line=node.end_lineno or 0))
-            elif isinstance(node, ast.Expr) and \
-                    isinstance(node.value, ast.Call) and \
-                    isinstance(node.value.func, ast.Attribute):
-                call = node.value
-                if call.func.attr in _MUTATORS:
-                    recv = shared_receiver(call.func.value)
-                    if recv is not None:
-                        out.append(Finding(
-                            sf.path, node.lineno, "proc-shared-state",
-                            f"{call.func.attr}() mutates process-"
-                            f"backed pool shared() object {recv!r}: "
-                            f"no worker process will ever see it — "
-                            f"marshal through the control channel",
-                            end_line=node.end_lineno or 0))
-            if isinstance(node, ast.Call) and \
-                    isinstance(node.func, ast.Attribute) and \
-                    node.func.attr == "run_on" and \
-                    is_pool(node.func.value) and \
-                    any(isinstance(a, (ast.Call, ast.Lambda))
-                        for a in node.args):
-                out.append(Finding(
-                    sf.path, node.lineno, "proc-shared-state",
-                    f"run_on() hands a closure/coroutine built in "
-                    f"THIS process to a process-backed pool: its "
-                    f"captured state cannot cross the interpreter "
-                    f"boundary — use "
-                    f"{dotted(node.func.value)}.call(index, request) "
-                    f"with JSON-marshalled arguments",
-                    end_line=node.end_lineno or 0))
     return out

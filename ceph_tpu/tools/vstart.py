@@ -45,8 +45,7 @@ class VCluster:
 
     def __init__(self, base_dir: str, n_mons: int = 1, n_osds: int = 3,
                  with_mgr: bool = False, with_mds: bool = False,
-                 with_rgw: bool = False, reactor_shards: int = 1,
-                 reactor_procs: int = 0):
+                 with_rgw: bool = False, reactor_procs: int = 0):
         ports = free_ports(n_mons)
         self.monmap = MonMap({f"m{i}": ("127.0.0.1", ports[i])
                               for i in range(n_mons)})
@@ -55,20 +54,13 @@ class VCluster:
         self.with_mgr = with_mgr
         self.with_mds = with_mds
         self.with_rgw = with_rgw
-        # sharded reactor: OSDs round-robin across N event-loop shards;
-        # mons, mgr, mds, rgw, and clients stay on shard 0 (the calling
-        # loop). 1 = the classic single-loop cluster, no pool at all.
-        # reactor_procs > 0 forks the shards into worker PROCESSES
-        # instead (`--procs`): OSDs boot over the admin-socket control
-        # channel and self.osds holds WorkerOSDRef handles, not OSDs.
-        self.reactor_shards = max(1, int(reactor_shards))
+        # reactor_procs > 0 places the OSDs round-robin in that many
+        # worker PROCESSES (`--procs`); mons, mgr, mds, rgw, and clients
+        # stay on the calling loop. OSDs boot over the admin-socket
+        # control channel and self.osds holds WorkerOSDRef handles, not
+        # OSDs. 0 = the classic single-loop cluster, no pool at all.
         self.reactor_procs = max(0, int(reactor_procs))
-        if self.reactor_procs and self.reactor_shards > 1:
-            raise ValueError("--shards and --procs are mutually "
-                             "exclusive")
-        self.pool = None
         self.proc_pool = None
-        self._shard_of: dict[int, int] = {}
         self.mons: dict[str, Monitor] = {}
         self.osds: dict[int, OSD] = {}
         self.mgr = None
@@ -87,9 +79,6 @@ class VCluster:
                                            name="vstart",
                                            base_dir=self.base_dir)
             await self.proc_pool.start()
-        elif self.reactor_shards > 1:
-            from ceph_tpu.utils.reactor import ShardPool
-            self.pool = ShardPool(self.reactor_shards, name="vstart")
         for name in self.monmap.mons:
             mon = Monitor(name, self.monmap,
                           store_path=f"{self.base_dir}/mon.{name}")
@@ -138,11 +127,7 @@ class VCluster:
             return ref
         osd = OSD(i, self.mon_addrs, store=store)
         self.osds[i] = osd
-        if self.pool is not None:
-            shard = self._shard_of.setdefault(i, self.pool.place(i))
-            await self.pool.run_on(shard, osd.start())
-        else:
-            await osd.start()
+        await osd.start()
         return osd
 
     async def kill_osd(self, i: int) -> None:
@@ -150,11 +135,7 @@ class VCluster:
         if self.proc_pool is not None:
             await self.proc_pool.stop_osd(i)
             return
-        shard = self._shard_of.get(i)
-        if self.pool is not None and shard is not None:
-            await self.pool.run_on(shard, osd.stop())
-        else:
-            await osd.stop()
+        await osd.stop()
 
     async def client(self) -> RadosClient:
         c = RadosClient(self.mon_addrs)
@@ -178,20 +159,10 @@ class VCluster:
             await self.proc_pool.shutdown()
             self.proc_pool = None
             self.osds.clear()
-        for i, osd in list(self.osds.items()):
-            shard = self._shard_of.get(i)
-            if self.pool is not None and shard is not None:
-                # stop on the owning shard: the daemon's tasks belong
-                # to that loop (loop-affinity rule)
-                await self.pool.run_on(shard,
-                                       bounded_stop(osd.stop(), 20))
-            else:
-                await bounded_stop(osd.stop(), 20)
+        for osd in list(self.osds.values()):
+            await bounded_stop(osd.stop(), 20)
         for mon in self.mons.values():
             await bounded_stop(mon.stop(), 20)
-        if self.pool is not None:
-            await self.pool.shutdown()
-            self.pool = None
 
     def status(self) -> dict:
         leader = next((m for m in self.mons.values()
@@ -214,13 +185,12 @@ class VCluster:
         }
 
 
-async def smoke(n_mons: int, n_osds: int, shards: int = 1,
-                procs: int = 0) -> dict:
+async def smoke(n_mons: int, n_osds: int, procs: int = 0) -> dict:
     """Boot, write/read through a replicated pool, report. Exit-code
     contract: raises on any failure, returns the status dict on success."""
     with tempfile.TemporaryDirectory(prefix="vstart-") as base:
         c = VCluster(base, n_mons=n_mons, n_osds=n_osds,
-                     reactor_shards=shards, reactor_procs=procs)
+                     reactor_procs=procs)
         try:
             await c.start()
             cl = await c.client()
@@ -267,19 +237,16 @@ def main() -> int:
     p.add_argument("--osds", type=int, default=3)
     p.add_argument("--smoke", action="store_true",
                    help="run a write/read workload and exit")
-    p.add_argument("--shards", type=int, default=1,
-                   help="reactor shards: OSDs round-robin across N "
-                        "event-loop threads (1 = single loop)")
     p.add_argument("--procs", type=int, default=0,
                    help="process-backed reactor: OSDs round-robin "
-                        "across N spawned worker processes (true GIL "
-                        "escape; 0 = in-process runtime)")
+                        "across N spawned worker processes (0 = all "
+                        "daemons on this process's one loop)")
     args = p.parse_args()
     if not args.smoke:
         p.error("only --smoke mode is supported (in-process daemons "
                 "cannot outlive the interpreter)")
     status = asyncio.run(asyncio.wait_for(
-        smoke(args.mons, args.osds, args.shards, args.procs), 120))
+        smoke(args.mons, args.osds, args.procs), 120))
     print(json.dumps(status, indent=1))
     return 0
 

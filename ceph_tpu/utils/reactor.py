@@ -1,44 +1,33 @@
-"""Sharded reactor runtime: N OS threads, each owning one asyncio loop.
+"""Process-per-shard reactor runtime: N worker processes, one loop each.
 
 A cluster in one process is bound by a single saturated Python event
 loop (`loop_busy_fraction` ~1.0 on the only loop in the process; the
 benchmark's `loop_busy_pct`): every OSD, the mon, the mgr, and the client
-all contend for the same reactor thread, so the cluster's ceiling is
-one core's worth of frame parsing and dispatch no matter how many
-devices the offload service fans across. This module is the
+all contend for the same reactor thread. This module is the
 Crimson/seastar analog the SURVEY names: a pool of reactor *shards*,
-each an OS thread running its own event loop, with daemons placed
-whole onto shards —
+each a spawned interpreter running its own event loop
+(`utils/reactor_worker.py`), with daemons placed whole onto shards —
 
   * shard 0 is the CALLING loop (the harness/main loop): the mon, mgr,
-    and clients stay there, exactly like the pre-shard world;
-  * OSDs are placed round-robin across all shards (`place()`), so the
-    data-plane daemons stop sharing one reactor;
+    and clients stay there, exactly like the single-loop world;
+  * OSDs are placed round-robin over the workers (`place()`, shard
+    indices 1..n);
   * connections between daemons on different shards are real localhost
     socket hops (the messenger already speaks TCP between daemons, so
-    cross-shard needs no new wire plumbing); same-shard messaging
-    stays in-loop;
-  * a `ShardPool(1)` is the degenerate case: no threads, no behavior
-    change — the knob dials concurrency without forking the code path.
+    the data path crosses the process boundary with no new wire
+    plumbing); same-shard messaging stays in-loop.
 
 Loop-affinity discipline (enforced by radoslint's `loop-affinity`
 rule): loop-bound objects (asyncio primitives, the OffloadService, a
-messenger Connection) belong to exactly one shard. Touching one from
-another shard must go through the threadsafe seams — `run_on()` /
-`run_on_each()` here, `loop.call_soon_threadsafe`, or
+messenger Connection) belong to exactly one loop. The threads that
+remain beside it (executor pools, the admin socket's, a store's commit
+thread) reach it through `loop.call_soon_threadsafe` or
 `asyncio.run_coroutine_threadsafe` — never a bare `call_soon`/
 `create_task` on a foreign loop handle.
-
-The pool also carries `shared(key, factory)`: process-level services
-that must span every shard (the offload device topology and its
-per-device circuit breakers) hang their one shared instance off the
-pool instead of the loop, so four shards see one breaker state per
-chip rather than four conflicting ones.
 """
 from __future__ import annotations
 
 import asyncio
-import concurrent.futures
 import functools
 import os
 import signal
@@ -47,90 +36,45 @@ import sys
 import tempfile
 import threading
 import time
-from typing import Any, Callable
+from typing import Any
 
 from ceph_tpu.utils import flight
 from ceph_tpu.utils.async_util import reap_all
 from ceph_tpu.utils.dout import dout
 
-#: process-wide switch-interval management: the 0.5 ms bound is a
-#: property of "any multi-shard pool is live", not of one pool — two
-#: overlapping pools with per-pool save/restore would let the first
-#: shutdown restore 5 ms under the second pool, then the second
-#: shutdown "restore" 0.5 ms forever. Refcounted instead.
-_switch_lock = threading.Lock()
-_multi_pool_count = 0
-_saved_interval: float | None = None
-
-
-def _switch_interval_enter(interval_s: float) -> None:
-    global _multi_pool_count, _saved_interval
-    with _switch_lock:
-        if _multi_pool_count == 0:
-            _saved_interval = sys.getswitchinterval()
-            sys.setswitchinterval(interval_s)
-        _multi_pool_count += 1
-
-
-def _switch_interval_exit() -> None:
-    global _multi_pool_count, _saved_interval
-    with _switch_lock:
-        if _multi_pool_count == 0:
-            return
-        _multi_pool_count -= 1
-        if _multi_pool_count == 0 and _saved_interval is not None:
-            sys.setswitchinterval(_saved_interval)
-            _saved_interval = None
-
-
-#: loop -> [(pool, shard_index), ...]; the process-wide placement
-#: registry. Lets loop-keyed services (offload, loopprof) answer "which
-#: shard am I, and which pool do I share state with" from any thread.
-#: A STACK per loop, not a single slot: the parent loop is shard 0 of a
-#: live ProcShardPool AND of a nested thread ShardPool in mixed mode —
-#: the inner pool's teardown must restore the outer registration, not
-#: erase it.
+#: loop -> (pool, shard_index); the process-wide placement registry.
+#: Lets loop-keyed services (loopprof, `OSD.shard`) answer "which shard
+#: am I" from any thread.
 _registry_lock = threading.Lock()
-_by_loop: dict[asyncio.AbstractEventLoop, list[tuple]] = {}
+_by_loop: dict[asyncio.AbstractEventLoop, tuple] = {}
 
 
 def _register(loop, pool, index: int) -> None:
     with _registry_lock:
         for stale in [lp for lp in _by_loop if lp.is_closed()]:
             del _by_loop[stale]
-        _by_loop.setdefault(loop, []).append((pool, index))
+        _by_loop[loop] = (pool, index)
 
 
-def _unregister(loop, pool=None) -> None:
-    """Remove `pool`'s registration of `loop` (the newest entry when
-    pool is None), restoring whatever outer pool registered it first."""
+def _unregister(loop, pool) -> None:
     with _registry_lock:
-        stack = _by_loop.get(loop)
-        if not stack:
-            return
-        if pool is None:
-            stack.pop()
-        else:
-            for i in range(len(stack) - 1, -1, -1):
-                if stack[i][0] is pool:
-                    del stack[i]
-                    break
-        if not stack:
+        if _by_loop.get(loop, (None, None))[0] is pool:
             del _by_loop[loop]
 
 
-def pool_for(loop) -> "ShardPool | None":
-    """The ShardPool `loop` belongs to (None for unpooled loops —
-    standalone tests and single-loop tools keep their private world)."""
+def _lookup(loop) -> tuple:
     with _registry_lock:
-        stack = _by_loop.get(loop)
-        return stack[-1][0] if stack else None
+        return _by_loop.get(loop, (None, None))
+
+
+def pool_for(loop) -> "ProcShardPool | _WorkerShard | None":
+    """The pool `loop` belongs to (None for unpooled loops —
+    standalone tests and single-loop tools keep their private world)."""
+    return _lookup(loop)[0]
 
 
 def shard_index_of(loop) -> int | None:
-    with _registry_lock:
-        stack = _by_loop.get(loop)
-        return stack[-1][1] if stack else None
+    return _lookup(loop)[1]
 
 
 def shard_label(loop) -> str | None:
@@ -139,7 +83,7 @@ def shard_label(loop) -> str | None:
     return None if idx is None else f"shard{idx}"
 
 
-def current_pool() -> "ShardPool | None":
+def current_pool() -> "ProcShardPool | _WorkerShard | None":
     """The running loop's pool, or None (callable from coroutines)."""
     try:
         return pool_for(asyncio.get_running_loop())
@@ -147,223 +91,14 @@ def current_pool() -> "ShardPool | None":
         return None
 
 
-class Shard:
-    """One reactor: an event loop plus the thread that runs it (thread
-    is None for shard 0, which borrows the creating loop)."""
-
-    __slots__ = ("index", "loop", "thread", "ready")
-
-    def __init__(self, index: int):
-        self.index = index
-        self.loop: asyncio.AbstractEventLoop | None = None
-        self.thread: threading.Thread | None = None
-        self.ready = threading.Event()
-
-
-class ShardPool:
-    """`n` reactor shards: the creating loop plus n-1 loop threads.
-
-    Must be constructed on a running event loop (it becomes shard 0).
-    `shutdown()` reaps every thread shard's leftover tasks before
-    stopping its loop, so a pool teardown is as tail-clean as a daemon
-    stop (no "Task was destroyed but it is pending")."""
-
-    START_TIMEOUT = 10.0
-
-    #: shards share this process's memory (the ProcShardPool analog is
-    #: "process"); consumers like the offload topology key their
-    #: shared-vs-private decision on this
-    backend = "thread"
-
-    #: GIL switch interval while a multi-shard pool is live. A
-    #: cross-shard hop (call_soon_threadsafe wakeup, socket readable on
-    #: another shard) can wait up to a FULL switch interval for the GIL
-    #: when every loop thread is busy; at CPython's default 5 ms that
-    #: convoys a multi-hop EC write into tens of ms of pure handoff
-    #: latency (measured: the 4-shard curve collapsed ~6x on a 2-core
-    #: box before this). 0.5 ms trades a little single-thread
-    #: throughput for bounded cross-shard latency.
-    SWITCH_INTERVAL_S = 0.0005
-
-    def __init__(self, num_shards: int, name: str = "reactor"):
-        if num_shards < 1:
-            raise ValueError("a reactor pool needs at least one shard")
-        self.name = name
-        self._closed = False
-        self._holds_switch_interval = num_shards > 1
-        if self._holds_switch_interval:
-            _switch_interval_enter(self.SWITCH_INTERVAL_S)
-        self._shared_lock = threading.Lock()
-        self._shared: dict[str, Any] = {}
-        shard0 = Shard(0)
-        shard0.loop = asyncio.get_running_loop()
-        shard0.ready.set()
-        self._shards = [shard0]
-        _register(shard0.loop, self, 0)
-        try:
-            for i in range(1, num_shards):
-                shard = Shard(i)
-                shard.thread = threading.Thread(
-                    target=self._shard_main, args=(shard,),
-                    name=f"{name}-shard{i}", daemon=True)
-                self._shards.append(shard)
-                shard.thread.start()
-            for shard in self._shards[1:]:
-                if not shard.ready.wait(self.START_TIMEOUT):
-                    raise RuntimeError(f"{name} shard {shard.index} "
-                                       f"never came up")
-        except BaseException:
-            # a failed boot must not leak running shard threads nor
-            # leave the process-wide switch interval degraded
-            self._abort_started_shards()
-            raise
-        dout("reactor", 1, f"{name}: {num_shards} shard(s) up")
-
-    def _abort_started_shards(self) -> None:
-        if self._holds_switch_interval:
-            _switch_interval_exit()
-            self._holds_switch_interval = False
-        for shard in self._shards[1:]:
-            loop = shard.loop
-            if loop is not None and not loop.is_closed():
-                loop.call_soon_threadsafe(loop.stop)
-            if shard.thread is not None:
-                shard.thread.join(self.START_TIMEOUT)
-        _unregister(self._shards[0].loop, self)
-        self._closed = True
-
-    # -- placement -----------------------------------------------------------
-
-    @property
-    def num_shards(self) -> int:
-        return len(self._shards)
-
-    def place(self, seq: int) -> int:
-        """Round-robin shard index for the seq-th data-plane daemon."""
-        return seq % len(self._shards)
-
-    def loop(self, index: int) -> asyncio.AbstractEventLoop:
-        return self._shards[index].loop
-
-    # -- cross-shard seams ---------------------------------------------------
-
-    async def run_on(self, index: int, coro) -> Any:
-        """Run `coro` on shard `index` and await its result from the
-        calling shard. Same-shard awaits inline; cross-shard hops via
-        run_coroutine_threadsafe (the call_soon_threadsafe handoff)."""
-        target = self._shards[index].loop
-        if target is asyncio.get_running_loop():
-            return await coro
-        cfut = asyncio.run_coroutine_threadsafe(coro, target)
-        return await asyncio.wrap_future(cfut)
-
-    async def run_on_each(self, fn: Callable[[], Any]) -> list:
-        """Run sync `fn()` ON every shard's loop thread (shard 0
-        inline) — the arming hook for per-loop instruments (loopprof
-        install/uninstall need the loop thread's ident)."""
-        out = []
-        for shard in self._shards:
-            if shard.loop is asyncio.get_running_loop():
-                out.append(fn())
-                continue
-            done: concurrent.futures.Future = concurrent.futures.Future()
-
-            def call(done=done):
-                try:
-                    done.set_result(fn())
-                except BaseException as e:   # marshal failures back whole
-                    done.set_exception(e)
-            shard.loop.call_soon_threadsafe(call)
-            out.append(await asyncio.wrap_future(done))
-        return out
-
-    # -- pool-scoped shared state --------------------------------------------
-
-    def shared(self, key: str, factory: Callable[[], Any]) -> Any:
-        """Get-or-create the pool-wide instance of a cross-shard
-        service (one offload device topology per pool, not per loop)."""
-        with self._shared_lock:
-            obj = self._shared.get(key)
-            if obj is None:
-                obj = self._shared[key] = factory()
-            return obj
-
-    # -- lifecycle -----------------------------------------------------------
-
-    def _shard_main(self, shard: Shard) -> None:
-        loop = asyncio.new_event_loop()
-        asyncio.set_event_loop(loop)
-        shard.loop = loop
-        _register(loop, self, shard.index)
-        shard.ready.set()
-        try:
-            loop.run_forever()
-            # post-stop drain: anything still pending here was created
-            # after the final reap (or leaked past a daemon stop) —
-            # cancel-and-await so loop.close() destroys nothing pending
-            leftovers = asyncio.all_tasks(loop)
-            if leftovers:
-                loop.run_until_complete(reap_all(leftovers))
-            loop.run_until_complete(loop.shutdown_asyncgens())
-            loop.run_until_complete(loop.shutdown_default_executor())
-        finally:
-            try:
-                from ceph_tpu.utils import loopprof
-                loopprof.uninstall(loop, owner=None)   # defensive
-            except Exception:
-                pass
-            _unregister(loop, self)
-            loop.close()
-
-    async def _drain_shard(self) -> None:
-        """Runs ON a thread shard: reap every task but ourselves."""
-        cur = asyncio.current_task()
-        await reap_all([t for t in asyncio.all_tasks() if t is not cur])
-
-    async def shutdown(self, timeout: float = 20.0) -> None:
-        """Reap and stop every thread shard (idempotent). The daemons
-        on each shard must already be stopped — this reaps stragglers,
-        parks the loop, and joins the thread."""
-        if self._closed:
-            return
-        self._closed = True
-        if self._holds_switch_interval:
-            _switch_interval_exit()
-            self._holds_switch_interval = False
-        for shard in self._shards[1:]:
-            loop = shard.loop
-            if loop is None or loop.is_closed():
-                continue
-            cfut = asyncio.run_coroutine_threadsafe(
-                self._drain_shard(), loop)
-            try:
-                await asyncio.wait_for(asyncio.wrap_future(cfut), timeout)
-            except Exception as e:
-                dout("reactor", 1,
-                     f"{self.name}: shard {shard.index} drain failed "
-                     f"({type(e).__name__}: {e}); stopping it anyway")
-                cfut.cancel()
-            loop.call_soon_threadsafe(loop.stop)
-            if shard.thread is not None:
-                await asyncio.get_running_loop().run_in_executor(
-                    None, shard.thread.join, timeout)
-        _unregister(self._shards[0].loop, self)
-        dout("reactor", 1, f"{self.name}: pool down")
-
-
 # ---------------------------------------------------------------------------
-# process-backed shards: the true GIL escape
+# process-backed shards
 # ---------------------------------------------------------------------------
 #
-# The thread-backed ShardPool buys loops, not parallelism: on a 2-core
-# box the 1->2 shard curve measured 0.74x because every loop thread
-# still serializes on one interpreter lock (ROADMAP, BENCH trend). The
-# process-backed mode below forks the shards into real OS processes —
-# each worker runs its own interpreter, its own event loop, its own
-# OffloadService front end over a PARTITIONED device topology — and the
-# messenger already speaks TCP between daemons, so the data path crosses
-# the process boundary with zero new wire plumbing. What needs building
-# is the lifecycle (spawn/supervise/reap/respawn) and the seams:
+# Each worker runs its own interpreter, its own event loop, its own
+# OffloadService front end over a PARTITIONED device topology. What is
+# built here is the lifecycle (spawn/supervise/reap/respawn) and the
+# seams:
 #
 #   * control channel: each worker binds an AdminSocket (the same
 #     plumbing every daemon already exposes) and the parent drives it
@@ -376,37 +111,28 @@ class ShardPool:
 #     the EXISTING reporter-quorum mark-down — peers stop hearing
 #     heartbeats, report failures, the mon marks down. `respawn()`
 #     re-spawns the worker and re-boots its recorded OSDs.
-#   * rejected conveniences: `shared()` and `run_on()` raise — there is
-#     no cross-process memory and a coroutine cannot be marshalled.
-#     State crosses through `call()` (JSON over the control channel) or
-#     the cluster's own wire protocol, full stop. radoslint's
-#     `proc-shared-state` rule enforces the same contract statically.
+#   * no shared memory: state crosses through `call()` (JSON over the
+#     control channel) or the cluster's own wire protocol, full stop. A
+#     worker's loop is not addressable from the parent (`loop(i)` raises
+#     for i > 0), and a coroutine cannot be marshalled.
 #
-# A ProcShardPool never touches the GIL switch interval: its shards do
-# not share an interpreter, so the 0.5 ms override would be a pure
-# context-switch tax on the parent (and the refcount above keeps a
-# concurrently-live thread pool's override correct in mixed mode).
+# There is no thread-backed pool (N loops on N threads of one
+# interpreter): its own 1->2 shard curve measured 0.74x on a 2-core box,
+# PR 43's probe on the chip host found that a second memory-moving thread
+# slows the loop's own memory-bound work by about half where another
+# process costs a fifth (PERF.md §7), and PR 45 read `loop_lag_p95_ms` 98
+# against 47 with BlueStore's commit threads on the loop's GIL.
 
 
 class _WorkerShard:
     """In-worker identity stub: `pool_for()` / `shard_index_of()` inside
     a spawned worker process resolve to this, so shard labels (loopprof
     gauges, `OSD.shard` in daemon status) carry the POOL-WIDE shard
-    index the parent assigned — not a pid-local counter. Cross-process
-    conveniences are structurally absent: state is marshalled over the
-    admin-socket control channel."""
-
-    backend = "process"
+    index the parent assigned — not a pid-local counter."""
 
     def __init__(self, name: str, index: int):
         self.name = name
         self.index = index
-
-    def shared(self, key: str, factory: Callable[[], Any]) -> Any:
-        raise NotImplementedError(
-            "shared() inside a process-backed shard: cross-process "
-            "memory does not exist — marshal state over the control "
-            "channel or the cluster wire protocol")
 
 
 def adopt_worker_shard(index: int, name: str = "reactor") -> None:
@@ -440,18 +166,17 @@ class _ProcWorker:
 class ProcShardPool:
     """`reactor_procs` worker PROCESSES plus the calling loop (shard 0).
 
-    Placement mirrors the thread pool — OSDs round-robin over the
-    workers (shard indices 1..n) while the mon/mgr/clients stay on the
-    parent loop — but each worker is a spawned interpreter running
-    `ceph_tpu.utils.reactor_worker`, so shard parallelism is deliverable
-    CPU parallelism, not GIL time-slicing. Construction spawns the
-    processes; `await start()` waits for every control channel to come
-    up and arms the supervisor. `shutdown()` drains workers through the
-    `shutdown` verb (each worker bounded-stops its daemons and reaps its
-    loop's stragglers before exiting), then reaps the processes — the
-    parent side leaves no pending tasks behind (conftest leak gate)."""
+    OSDs are placed round-robin over the workers (shard indices 1..n)
+    while the mon/mgr/clients stay on the parent loop; each worker is a
+    spawned interpreter running `ceph_tpu.utils.reactor_worker`, so
+    shard parallelism is deliverable CPU parallelism. Construction
+    spawns the processes; `await start()` waits for every control
+    channel to come up and arms the supervisor. `shutdown()` drains
+    workers through the `shutdown` verb (each worker bounded-stops its
+    daemons and reaps its loop's stragglers before exiting), then reaps
+    the processes — the parent side leaves no pending tasks behind
+    (conftest leak gate)."""
 
-    backend = "process"
     START_TIMEOUT = 30.0
     SUPERVISE_INTERVAL_S = 0.25
 
@@ -611,21 +336,6 @@ class ProcShardPool:
         if not 1 <= index <= self.num_procs:
             raise IndexError(f"no worker shard {index}")
         return self._workers[index - 1]
-
-    # -- rejected thread-pool conveniences ------------------------------------
-
-    def shared(self, key: str, factory: Callable[[], Any]) -> Any:
-        raise NotImplementedError(
-            "ProcShardPool.shared(): cross-process memory does not "
-            "exist — marshal explicit state through call() (the "
-            "admin-socket control channel) instead")
-
-    async def run_on(self, index: int, coro) -> Any:
-        coro.close()        # unawaited-coroutine warning suppression
-        raise NotImplementedError(
-            "ProcShardPool.run_on(): a coroutine (and anything its "
-            "closure captures) cannot cross a process boundary — use "
-            "call(index, request) with JSON-marshalled arguments")
 
     # -- control channel ------------------------------------------------------
 
@@ -796,10 +506,9 @@ class ProcShardPool:
         control channel), keyed by POOL-WIDE shard label, plus the
         cross-process busy skew."""
         from ceph_tpu.utils import loopprof
-        # the parent contributes ONLY its own shard-0 loop: the
-        # process-wide _per_loop store can carry stale shard1..N labels
-        # from an earlier THREAD-pool profiling run in this process,
-        # which would contaminate the identically-labeled worker stats
+        # the parent contributes ONLY its own shard-0 loop: a loop of
+        # this process that was armed outside the pool ("loop0") is not
+        # a shard of it
         parts = [{lbl: d for lbl, d in loopprof.shard_stats().items()
                   if lbl == "shard0"}]
         for w in self._workers:
@@ -814,9 +523,8 @@ class ProcShardPool:
                      f"failed ({type(e).__name__}: {e})")
         shards = loopprof.merge_shard_stats(*parts)
         # skew over the WORKER shards only: shard 0 is the control
-        # plane and hosts no OSDs by design here (unlike the thread
-        # pool), so including its near-idle loop would pin the skew at
-        # ~1.0 and bury real worker imbalance
+        # plane and hosts no OSDs by design, so including its near-idle
+        # loop would pin the skew at ~1.0 and bury real worker imbalance
         workers = {lbl: d for lbl, d in shards.items()
                    if lbl != "shard0"}
         return {"shards": shards,

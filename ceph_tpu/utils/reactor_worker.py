@@ -225,8 +225,7 @@ class _Worker:
                 self.osds.pop(whoami, None)
             self.asok.stop()
             # straggler reap: anything a daemon stop left behind must
-            # not be destroyed pending at loop close (the same
-            # discipline as ShardPool._shard_main)
+            # not be destroyed pending at loop close
             cur = asyncio.current_task()
             await reap_all([t for t in asyncio.all_tasks()
                             if t is not cur])

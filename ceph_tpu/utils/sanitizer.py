@@ -14,12 +14,11 @@ on the daemon's loop, plus the interlock concurrency probes:
     `UseAfterRecycleError` AT THE ACCESS SITE instead of silently
     reading the next batch's bytes (the runtime twin of radoslint's
     `view-escape`/`view-across-await` rules);
-  * LOCKSET RECORDER — TSan-lite for cross-shard shared state:
-    `make_lock()` locks record per-thread locksets, and
-    `note_shared_access()` on shared-object fields reports any pair of
-    accesses from different threads with no common lock (at least one
-    a write) through `san_lockset_conflicts` (the runtime twin of
-    `shard-shared-mutation`);
+  * LOCKSET RECORDER — TSan-lite for state a loop shares with the
+    threads beside it: `make_lock()` locks record per-thread locksets,
+    and `note_shared_access()` on shared-object fields reports any pair
+    of accesses from different threads with no common lock (at least
+    one a write) through `san_lockset_conflicts`;
   * FOREIGN call_soon RECORDER — `loop.call_soon` driven from a thread
     that doesn't own the loop is recorded (`san_foreign_call_soon`)
     before asyncio's own debug-mode raise, so teardown-time strays that
@@ -474,9 +473,10 @@ def unwrap(data):
 
 # -- lockset recorder (TSan-lite) ---------------------------------------------
 #
-# Cross-shard shared state (the offload device topology, ShardPool
-# shared() services) is mutated from N reactor threads; the contract
-# is "every access under the owning lock". `make_lock()` hands out
+# State a loop shares with the threads beside it (the offload device
+# topology: the loop, the `ec-offload` executor's threads, an admin
+# socket's thread); the contract is "every access under the owning
+# lock". `make_lock()` hands out
 # locks that record per-thread locksets, and `note_shared_access()`
 # at a shared field's touch points compares this access against the
 # most recent access from every OTHER thread: different threads, no
@@ -572,7 +572,7 @@ class TrackedLock:
 
 
 def make_lock(name: str) -> TrackedLock:
-    """A lockset-recorded lock for cross-shard shared state."""
+    """A lockset-recorded lock for state shared across threads."""
     return TrackedLock(name)
 
 

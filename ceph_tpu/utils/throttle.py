@@ -46,8 +46,8 @@ class AdjustableSemaphore(asyncio.Semaphore):
         self._limit = value
         self._debt = 0      # releases to absorb instead of freeing
         #: the loop the semaphore is bound to, captured at first
-        #: acquire. Under the sharded reactor a release/resize issued
-        #: from another shard's loop (or a plain thread) must NOT touch
+        #: acquire. A release/resize issued from another thread (an
+        #: executor's, an admin socket's observer) must NOT touch
         #: `_value`/`_debt`/the waiter queue directly — they are
         #: owner-loop state, and a cross-thread mutation corrupts the
         #: count or wakes a waiter on the wrong loop. Foreign callers
@@ -88,8 +88,8 @@ class AdjustableSemaphore(asyncio.Semaphore):
         return self._limit
 
     def _foreign_caller(self) -> bool:
-        """True when called off the owning loop (another shard's loop
-        thread, or no loop at all) while the owner is still alive."""
+        """True when called off the owning loop (another loop's thread,
+        or no loop at all) while the owner is still alive."""
         owner = self._owner_loop
         if owner is None or owner.is_closed():
             return False
@@ -127,8 +127,8 @@ class AdjustableSemaphore(asyncio.Semaphore):
             # holder entry when a semaphore is handed across contexts
             sanitizer.lockdep_unlocked(self.name)
         if self._foreign_caller():
-            # acquired on shard A, released on shard B: hand the
-            # release to the owning loop whole (count mutation AND
+            # acquired on the loop, released on another thread: hand
+            # the release to the owning loop whole (count mutation AND
             # waiter wakeup), so `_value` can never lose an update
             self._owner_loop.call_soon_threadsafe(self._release_impl)
             return

@@ -1,6 +1,6 @@
 """loop-affinity positives: driving another object's loop handle with
-non-threadsafe primitives (each flagged line is a foreign-shard bug
-under the sharded reactor)."""
+non-threadsafe primitives (each flagged line is a bug whenever the
+caller runs on another thread than the owner's loop)."""
 import asyncio
 
 
@@ -11,8 +11,8 @@ class Submitter:
         self._loop = asyncio.new_event_loop()
 
     def kick(self, fn):
-        # BAD: the service lives on another shard's loop; call_soon from
-        # this thread corrupts its ready queue
+        # BAD: the service lives on another thread's loop; call_soon
+        # from this thread corrupts its ready queue
         self.svc._loop.call_soon(fn)                      # finding 1
 
     def spawn(self, coro, other):

@@ -477,32 +477,6 @@ def _sweep_pipelined_cluster(tmp_path, seeds):
 
 
 @pytest.mark.interleave
-def test_interleave_reactor_roundtrip_sweep():
-    """Reactor slice of the qa tier: cross-shard run_on round-trips
-    stay bit-correct while shard 0's ready queue is shuffled (the
-    threadsafe seams must not depend on callback order)."""
-    from ceph_tpu.native import ec_native
-    from ceph_tpu.utils.reactor import ShardPool
-
-    async def body():
-        pool = ShardPool(2, name="ilv-reactor")
-        try:
-            payloads = [bytes([i]) * 1024 for i in range(8)]
-            want = [ec_native.crc32c(p) for p in payloads]
-            for seed in range(SMOKE_SEEDS):
-                async with interleave.explore(seed):
-                    async def job(p):
-                        return ec_native.crc32c(p)
-                    got = await asyncio.gather(*[
-                        pool.run_on(i % pool.num_shards, job(p))
-                        for i, p in enumerate(payloads)])
-                    assert got == want, f"seed {seed}"
-        finally:
-            await pool.shutdown()
-    run(body())
-
-
-@pytest.mark.interleave
 def test_interleave_sweep_smoke(tmp_path):
     """Tier-1 slice of the qa sweep: SMOKE_SEEDS seeded schedules over
     the pipelined cluster (messenger batching + PG pipelining +

@@ -216,14 +216,37 @@ def test_inline_bypass_when_disabled():
 # mesh fan-out: routing, sharding, per-device breakers, device rows
 # ---------------------------------------------------------------------------
 
-def test_device_affine_routing_with_least_busy_spillover():
+@pytest.mark.parametrize("hot_count", [0, 3])
+def test_device_affine_routing_with_least_busy_spillover(hot_count):
     """Same bucket key -> same device while it keeps up (compile-cache
     warmth); a backed-up preferred device spills to the least-busy one;
-    with every device out of rotation the router yields None (host)."""
+    with every device out of rotation the router yields None (host).
+    `hot_count`: the same over the slots the next dispatch rebuilds
+    after a hot `ec_offload_device_count` reset the service's one
+    topology, the shards coming back bit-identical."""
     async def body():
         svc = offload.get_service()
         slots = svc._topology()
         assert len(slots) == 8               # conftest: 8 virtual devices
+        if hot_count:
+            impl = _impl()
+            sinfo = ec_util.StripeInfo(4, 4 * 1024)
+            data = bytes(range(256)) * 64
+            ref = await ec_util.encode_async(sinfo, impl, data,
+                                             service=svc)
+            assert ref == ec_util.encode(sinfo, impl, data)
+            topo, before = svc._topo, svc._topo.states
+            assert [s.state for s in slots] == before
+            svc.apply_setting("ec_offload_device_count", hot_count)
+            assert svc._topo is topo         # reset, not replaced
+            assert topo.states is None and svc._slots is None
+            assert await ec_util.encode_async(sinfo, impl, data,
+                                              service=svc) == ref
+            slots = svc._slots               # rebuilt by that dispatch
+            assert len(slots) == hot_count
+            assert [s.state for s in slots] == topo.states
+            assert not set(map(id, topo.states)) & set(map(id, before))
+            await svc.drain()
         key = ("enc", b"matrix", 4096)
         pref = slots[hash(key) % len(slots)]
         for _ in range(4):                   # idle: affinity is stable

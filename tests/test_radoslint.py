@@ -22,8 +22,8 @@ ALL_RULES = {"detached-task", "blocking-in-coroutine", "await-under-lock",
              "cancellation-swallow", "loop-affinity",
              "registry-consistency", "decl-use",
              "report-export-consistency",
-             "view-escape", "view-across-await", "shard-shared-mutation",
-             "proc-shared-state", "lock-order-cycle", "await-in-gate"}
+             "view-escape", "view-across-await",
+             "lock-order-cycle", "await-in-gate"}
 
 
 def lint(path, rules):
@@ -63,10 +63,6 @@ def lint(path, rules):
     ("view-escape", "view_escape_pos.py", 5, "view_escape_neg.py"),
     ("view-across-await", "view_across_await_pos.py", 2,
      "view_across_await_neg.py"),
-    ("shard-shared-mutation", "shard_shared_mutation_pos.py", 3,
-     "shard_shared_mutation_neg.py"),
-    ("proc-shared-state", "proc_shared_state_pos.py", 4,
-     "proc_shared_state_neg.py"),
     ("lock-order-cycle", "lock_order_cycle_pos.py", 2,
      "lock_order_cycle_neg.py"),
     ("await-in-gate", "await_in_gate_pos.py", 3,

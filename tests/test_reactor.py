@@ -1,17 +1,13 @@
-"""Sharded reactor runtime tests: shard placement + registry, the
-cross-shard seams, a full cluster round-trip bit-identical to the
-single-loop runtime, concurrent offload submission from four shards
-under an injected device failure (the breaker/fallback contract must
-hold across the pool-shared topology), the AdjustableSemaphore/Throttle
-cross-shard audit, and clean pool teardown under the conftest
-pending-task leak gate (every test here runs under it)."""
+"""Reactor registry and cross-thread primitive tests: the one-slot
+placement registry a worker process registers itself in
+(`adopt_worker_shard`), and the AdjustableSemaphore/Throttle audit
+against the threads that remain beside a loop (executor pools, admin
+sockets). The process pool itself is tests/test_reactor_procs.py. Every
+test here runs under the conftest pending-task leak gate."""
 import asyncio
 import threading
 
-import pytest
-
 from ceph_tpu.utils import reactor
-from ceph_tpu.utils.reactor import ShardPool
 from ceph_tpu.utils.throttle import AdjustableSemaphore, Throttle
 
 
@@ -20,266 +16,89 @@ def run(coro, timeout=120):
 
 
 # ---------------------------------------------------------------------------
-# placement + registry + seams
+# placement registry
 # ---------------------------------------------------------------------------
 
 def test_shard_placement_and_registry():
-    async def body():
-        pool = ShardPool(3)
-        try:
-            assert pool.num_shards == 3
-            # round-robin placement: OSD i -> shard i % n
-            assert [pool.place(i) for i in range(7)] == [0, 1, 2, 0, 1, 2, 0]
-            # shard 0 IS the calling loop (mon/mgr/clients stay here)
-            assert pool.loop(0) is asyncio.get_running_loop()
-            for i in range(3):
-                assert reactor.pool_for(pool.loop(i)) is pool
-                assert reactor.shard_index_of(pool.loop(i)) == i
-            assert reactor.shard_label(pool.loop(2)) == "shard2"
-            # thread shards really are distinct OS threads
-            tids = await pool.run_on_each(threading.get_ident)
-            assert len(set(tids)) == 3
-            assert tids[0] == threading.get_ident()
-        finally:
-            await pool.shutdown()
+    async def adopt(index):
+        loop = asyncio.get_running_loop()
         # unpooled loops answer None (tests/tools keep their own world)
-        assert reactor.pool_for(asyncio.get_running_loop()) is None
-    run(body())
-
-
-def test_run_on_crosses_shards_and_returns_results():
-    async def body():
-        pool = ShardPool(2)
-        try:
-            async def where(x):
-                return (threading.get_ident(),
-                        asyncio.get_running_loop(), x * 2)
-            tid0, loop0, r0 = await pool.run_on(0, where(21))
-            tid1, loop1, r1 = await pool.run_on(1, where(4))
-            assert (r0, r1) == (42, 8)
-            assert tid0 == threading.get_ident()
-            assert loop0 is pool.loop(0)
-            assert tid1 != tid0 and loop1 is pool.loop(1)
-
-            # exceptions marshal back whole
-            async def boom():
-                raise RuntimeError("from shard 1")
-            with pytest.raises(RuntimeError, match="from shard 1"):
-                await pool.run_on(1, boom())
-        finally:
-            await pool.shutdown()
-    run(body())
-
-
-def test_shard_pool_teardown_reaps_stragglers():
-    """A task left running on a shard must be reaped at shutdown, not
-    destroyed pending (the conftest leak gate enforces the 'not')."""
-    async def body():
-        pool = ShardPool(2)
-
-        async def linger():
-            asyncio.get_running_loop().create_task(asyncio.sleep(60))
-            return True
-        assert await pool.run_on(1, linger())
-        await pool.shutdown()
-        assert pool.loop(1).is_closed()
-    run(body())
+        assert reactor.pool_for(loop) is None
+        assert reactor.shard_index_of(loop) is None
+        assert reactor.shard_label(loop) is None
+        assert reactor.current_pool() is None
+        reactor.adopt_worker_shard(index, "t-adopt")
+        stub = reactor.pool_for(loop)
+        assert (stub.name, stub.index) == ("t-adopt", index)
+        assert reactor.current_pool() is stub
+        assert reactor.shard_index_of(loop) == index
+        assert reactor.shard_label(loop) == f"shard{index}"
+        # one slot per loop: a second registration replaces the first
+        reactor.adopt_worker_shard(index + 1, "t-adopt")
+        assert reactor.shard_label(loop) == f"shard{index + 1}"
+        # the answers do not depend on the asking thread
+        seen = []
+        t = threading.Thread(
+            target=lambda: seen.append(reactor.shard_index_of(loop)))
+        t.start()
+        t.join(10)
+        assert seen == [index + 1]
+        return loop
+    first = run(adopt(2))
+    assert first.is_closed()
+    # a closed loop's entry is dropped at the next registration
+    second = run(adopt(5))
+    assert first not in reactor._by_loop
+    assert reactor.pool_for(first) is None
+    assert reactor.shard_index_of(second) == 6
 
 
 # ---------------------------------------------------------------------------
-# cross-shard cluster: op round-trip bit-identity vs the single loop
+# AdjustableSemaphore / Throttle cross-thread audit
 # ---------------------------------------------------------------------------
 
-def _cluster_roundtrip(shards: int):
-    async def body():
-        from ceph_tpu.tools.cluster_boot import ephemeral_cluster
-        payloads = {f"o{i}": bytes([i + 1]) * 9000 for i in range(6)}
-        got = {}
-        async with ephemeral_cluster(
-                3, prefix=f"reactor{shards}-",
-                reactor_shards=shards) as (client, osds, _mon):
-            await client.command({
-                "prefix": "osd erasure-code-profile set",
-                "name": "rtprof",
-                "profile": {"plugin": "jerasure", "k": "2", "m": "1",
-                            "technique": "reed_sol_van"}})
-            await client.pool_create("rt", pg_num=4,
-                                     pool_type="erasure",
-                                     erasure_code_profile="rtprof")
-            io = client.ioctx("rt")
-            for oid, data in payloads.items():
-                await io.write_full(oid, data)
-            for oid in payloads:
-                got[oid] = await io.read(oid)
-            if shards > 1:
-                # daemons really spread: every shard hosts an OSD
-                assert {o.shard for o in osds} == set(range(shards))
-            else:
-                assert all(o.shard is None for o in osds)
-        return payloads, got
-    return run(body(), timeout=120)
+def _on_thread(fn) -> None:
+    """Run `fn()` on a plain thread of its own and wait for it."""
+    t = threading.Thread(target=fn)
+    t.start()
+    t.join(10)
+    assert not t.is_alive()
 
-
-def test_cross_shard_op_roundtrip_bit_identical_vs_single_loop():
-    p1, g1 = _cluster_roundtrip(1)
-    p3, g3 = _cluster_roundtrip(3)
-    assert g1 == p1                  # single-loop ground truth
-    assert g3 == p3                  # sharded runtime: same bytes back
-    assert g1 == g3                  # and identical across runtimes
-
-
-# ---------------------------------------------------------------------------
-# offload from 4 shards under injected device failure
-# ---------------------------------------------------------------------------
-
-def test_offload_from_four_shards_with_injected_device_failure():
-    """Every shard's service front end feeds the ONE pool-shared device
-    topology: a single injected device failure means exactly one
-    fallback batch and one breaker trip across the whole pool, every
-    result stays bit-identical, and every shard sees the same rotation
-    state."""
-    from ceph_tpu import offload
-    from ceph_tpu.ec import registry as ecreg
-    from ceph_tpu.osd import ec_util
-    from ceph_tpu.qa import faultinject
-
-    async def body():
-        pool = ShardPool(4)
-        impl = ecreg.factory("tpu", {"k": "2", "m": "1"})
-        sinfo = ec_util.StripeInfo(2, 8192)
-        data = bytes(range(256)) * 32
-        ref = ec_util.encode(sinfo, impl, data)
-        services = []
-
-        async def submit_many(n=4):
-            svc = offload.get_service()
-            if svc not in services:
-                services.append(svc)
-            svc.linger_ms = 1.0
-            outs = await asyncio.gather(*[
-                ec_util.encode_async(sinfo, impl, data, service=svc)
-                for _ in range(n)])
-            return [o == ref for o in outs]
-        try:
-            # warm every shard's service + the shared topology first
-            # (XLA compiles outside the injected window)
-            warm = await asyncio.gather(*[pool.run_on(i, submit_many(1))
-                                          for i in range(4)])
-            assert all(ok for oks in warm for ok in oks)
-            topo_ids = {id(svc._topo) for svc in services}
-            assert len(topo_ids) == 1          # ONE shared topology
-            assert len({id(s) for s in services}) == 4  # per-shard fronts
-
-            base_fb = sum(s.stats["fallback_ops"] for s in services)
-            base_tr = sum(s.stats["breaker_trips"] for s in services)
-            faultinject.set_enabled(True)
-            faultinject.arm_device_failures(1)
-            results = await asyncio.gather(*[
-                pool.run_on(i, submit_many(4)) for i in range(4)])
-            assert all(ok for oks in results for ok in oks)
-            trips = sum(s.stats["breaker_trips"] for s in services) \
-                - base_tr
-            fallbacks = sum(s.stats["fallback_ops"] for s in services) \
-                - base_fb
-            # the deterministic injected contract holds pool-wide: ONE
-            # armed failure = ONE tripped chip and ONE host-fallback
-            # batch (its ops, bit-identical), no cascade across the
-            # other 15 concurrent batches
-            assert trips == 1
-            assert 1 <= fallbacks <= 4
-            # every shard reads the SAME shared rotation state. (The
-            # count itself may be 0 or 1: success evidence from a batch
-            # already in flight on the tripped chip legitimately closes
-            # the breaker again — the same evidence rule the pipelined
-            # single-loop service has.)
-            outs = {s.health_metrics()["devices_out"] for s in services}
-            assert len(outs) == 1 and outs <= {0, 1}
-            assert not any(s.degraded for s in services)
-        finally:
-            faultinject.set_enabled(False)
-            await pool.shutdown()
-    run(body(), timeout=180)
-
-
-# ---------------------------------------------------------------------------
-# cross-shard submission seam (submit_threadsafe)
-# ---------------------------------------------------------------------------
-
-def test_offload_submit_threadsafe_crosses_shards():
-    """A caller on shard 0 hands a job to shard 1's service through the
-    call_soon_threadsafe seam; the job runs on shard 1's loop and the
-    result marshals back bit-identical to the host reference."""
-    import numpy as np
-
-    from ceph_tpu import offload
-    from ceph_tpu.native import ec_native
-
-    async def body():
-        pool = ShardPool(2)
-        blocks = np.frombuffer(bytes(range(256)) * 64,
-                               dtype=np.uint8).reshape(4, 4096)
-        ref = ec_native.crc32c_blocks(blocks.reshape(-1), 4096)
-
-        async def _get_service():
-            svc = offload.get_service()
-            svc.linger_ms = 1.0
-            return svc
-        try:
-            svc1 = await pool.run_on(1, _get_service())
-            assert offload.service_for(pool.loop(1)) is svc1
-            cfut = svc1.submit_threadsafe("crc32c_blocks", blocks, 4096)
-            crcs = await asyncio.wrap_future(cfut)
-            assert np.array_equal(np.asarray(crcs), ref)
-        finally:
-            await pool.shutdown()
-    run(body(), timeout=120)
-
-
-# ---------------------------------------------------------------------------
-# AdjustableSemaphore / Throttle cross-shard audit
-# ---------------------------------------------------------------------------
 
 def test_adjustable_semaphore_cross_shard_release_and_resize():
-    """Acquire on shard A, release on shard B: the release must marshal
-    to the owning loop (waiters wake there), never corrupt `_value`."""
+    """Acquire on the loop, release on another thread: the release must
+    marshal to the owning loop (waiters wake there), never corrupt
+    `_value`."""
     async def body():
-        pool = ShardPool(2)
         sem = AdjustableSemaphore(1)
-        try:
-            await sem.acquire()              # binds to shard 0
-            woke = asyncio.Event()
+        await sem.acquire()              # binds to this loop
+        woke = asyncio.Event()
 
-            async def waiter():
-                await sem.acquire()
-                woke.set()
-            wt = asyncio.get_running_loop().create_task(waiter())
-            await asyncio.sleep(0.05)
-            assert not woke.is_set()
+        async def waiter():
+            await sem.acquire()
+            woke.set()
+        wt = asyncio.get_running_loop().create_task(waiter())
+        await asyncio.sleep(0.05)
+        assert not woke.is_set()
 
-            async def foreign_release():
-                sem.release()                # from shard 1's thread
-            await pool.run_on(1, foreign_release())
-            await asyncio.wait_for(woke.wait(), 5)
-            await wt
-            sem.release()
-            assert sem._value == 1 and sem._debt == 0
+        _on_thread(sem.release)          # from a foreign thread
+        await asyncio.wait_for(woke.wait(), 5)
+        await wt
+        sem.release()
+        assert sem._value == 1 and sem._debt == 0
 
-            async def foreign_resize():
-                sem.resize(3)
-            await pool.run_on(1, foreign_resize())
-            await asyncio.sleep(0.05)        # marshalled resize lands
-            assert sem.limit == 3
-            assert sem._value == 3           # grew by exactly 2
-        finally:
-            await pool.shutdown()
+        _on_thread(lambda: sem.resize(3))
+        await asyncio.sleep(0.05)        # marshalled resize lands
+        assert sem.limit == 3
+        assert sem._value == 3           # grew by exactly 2
     run(body())
 
 
 def test_throttle_cross_thread_budget_consistency():
-    """The byte-budget Throttle is driven from every shard loop (and
-    the admin thread): hammer get/put from 4 threads and the count must
-    return to exactly zero — no lost or doubled units."""
-    th = Throttle("xshard", 64)
+    """The byte-budget Throttle is driven from the loop, the executor's
+    threads and the admin thread: hammer get/put from 4 threads and the
+    count must return to exactly zero — no lost or doubled units."""
+    th = Throttle("xthread", 64)
     errs = []
 
     def worker():

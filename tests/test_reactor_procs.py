@@ -3,11 +3,10 @@ the admin-socket control channel (boot/config/inject verbs), a
 process-backed cluster round-trip bit-identical to the single-loop
 runtime, the SIGKILL -> supervisor-reap -> reporter-quorum-mark-down ->
 respawn-rejoin drill, cross-process loopprof attribution keyed by
-pool-wide shard index, mechanical rejection of the thread-pool
-conveniences (shared()/run_on), and the GIL switch-interval rule
-(process pools never install the 0.5 ms override; mixed-mode teardown
-restores correctly). Every test runs under the conftest pending-task
-leak gate, so a parent-side supervisor/executor leak fails loudly."""
+pool-wide shard index, a worker's loop being unaddressable from the
+parent, and the GIL switch interval staying untouched. Every test runs
+under the conftest pending-task leak gate, so a parent-side
+supervisor/executor leak fails loudly."""
 import asyncio
 import sys
 import time
@@ -15,7 +14,7 @@ import time
 import pytest
 
 from ceph_tpu.utils import reactor
-from ceph_tpu.utils.reactor import ProcShardPool, ShardPool
+from ceph_tpu.utils.reactor import ProcShardPool
 
 
 def run(coro, timeout=180):
@@ -23,7 +22,7 @@ def run(coro, timeout=180):
 
 
 # ---------------------------------------------------------------------------
-# pool identity + rejected conveniences + switch interval
+# pool identity + unaddressable worker loops + switch interval
 # ---------------------------------------------------------------------------
 
 def test_proc_pool_identity_and_rejected_conveniences():
@@ -43,45 +42,18 @@ def test_proc_pool_identity_and_rejected_conveniences():
             st = await pool.call(1, "worker status")
             assert st["shard"] == 1 and st["pid"] != 0
             assert st["pid"] == pool.worker_pid(1)
-            # thread-pool conveniences are rejected MECHANICALLY:
-            # cross-process memory doesn't exist, coroutines can't ship
-            with pytest.raises(NotImplementedError, match="cross-process"):
-                pool.shared("topo", dict)
-
-            async def c():
-                pass
-            with pytest.raises(NotImplementedError, match="process "
-                                                          "boundary"):
-                await pool.run_on(1, c())
             # a pool-wide broadcast onto (momentarily) OSD-less workers
             # is a no-op, not a half-propagated abort
             out = await pool.config_set("osd_heartbeat_grace", 2.0)
             assert all(r["applied"] == [] for r in out.values())
-            # a process pool never installs the 0.5 ms GIL override:
-            # its shards don't share an interpreter, so the override
-            # would be a pure context-switch tax on the parent
+            # a process pool never touches the GIL switch interval:
+            # its shards don't share an interpreter
             assert sys.getswitchinterval() == default_interval
-            # mixed mode: a concurrently-live THREAD pool still gets
-            # (and refcounts) the override; its teardown restores while
-            # the process pool stays up
-            tpool = ShardPool(2, name="t-mixed")
-            try:
-                assert sys.getswitchinterval() == \
-                    ShardPool.SWITCH_INTERVAL_S
-                # the nested thread pool owns shard 0 while live...
-                assert reactor.pool_for(
-                    asyncio.get_running_loop()) is tpool
-            finally:
-                await tpool.shutdown()
-            assert sys.getswitchinterval() == default_interval
-            # ...and its teardown RESTORES the outer proc pool's
-            # registration instead of erasing it (registry stack)
-            assert reactor.pool_for(asyncio.get_running_loop()) is pool
-            assert reactor.shard_index_of(
-                asyncio.get_running_loop()) == 0
         finally:
             await pool.shutdown()
         assert sys.getswitchinterval() == default_interval
+        # shutdown gives the parent loop its unpooled answer back
+        assert reactor.pool_for(asyncio.get_running_loop()) is None
         # every worker exited through the graceful shutdown verb
         assert all(not pool.worker_alive(i) for i in (1, 2))
     run(body())
@@ -127,7 +99,7 @@ def _cluster_roundtrip(procs: int):
                 assert st["reactor_shard"] == osds[0].shard
                 # per-OSD knob routing: osd.0 and osd.2 share worker
                 # shard1, and the handle's config_set must touch ONLY
-                # its own daemon (thread-mode semantics)
+                # its own daemon (in-process semantics)
                 await osds[0].config_set("osd_pg_pipeline_depth", 2)
                 assert await osds[0].config_get(
                     "osd_pg_pipeline_depth") == 2
