@@ -25,6 +25,9 @@ process-backed reactor, each over its own partition of the chips):
     work can share a device dispatch). A bucket flushes when its bytes
     reach `ec_offload_max_batch_bytes` or when the oldest job has
     lingered `ec_offload_linger_ms` (continuous batching's flush rule).
+    Which of the two shipped a batch is the `flush` tag of its
+    `offload_batch` span (`full` / `linger`) and counted in `stats`
+    (`flush_full`, `flush_linger`).
   * mesh fan-out: every visible accelerator is a dispatch slot with its
     own pipeline semaphore, double-buffered staging pool, and circuit
     breaker. Flushed buckets route DEVICE-AFFINE — same bucket key,
@@ -296,7 +299,8 @@ class _Bucket:
     """Pending jobs that can share one device dispatch."""
 
     __slots__ = ("key", "jobs", "nbytes", "dispatch", "fallback",
-                 "shard_dispatch", "linger_task", "uses_device", "pad_rows")
+                 "shard_dispatch", "linger_task", "uses_device", "pad_rows",
+                 "flush")
 
     def __init__(self, key: tuple, dispatch: Callable, fallback: Callable,
                  uses_device: bool, shard_dispatch: Callable | None = None,
@@ -317,6 +321,10 @@ class _Bucket:
         #: rows -> the rows its batch is staged at (None: as many as the
         #: jobs have); the results of the rows past the jobs' are dropped
         self.pad_rows = pad_rows
+        #: what shipped it, the `flush` tag of its `offload_batch` span:
+        #: "linger" (the deadline), "full" (`max_batch_bytes`) or
+        #: "asked" (`flush()` / `drain()`, counted under neither)
+        self.flush = "asked"
 
 
 class _DeviceState:
@@ -593,9 +601,13 @@ class OffloadService:
                       "fallback_ops": 0, "breaker_trips": 0,
                       "batched_ops": 0, "mesh_batches": 0,
                       "device_spills": 0, "device_failovers": 0,
+                      "enc_jobs": 0, "enc_batches": 0, "enc_bytes": 0,
                       "dec_jobs": 0, "dec_batches": 0, "dec_bytes": 0,
                       "dec_out_bytes": 0,
-                      "crc_jobs": 0, "crc_batches": 0, "crc_bytes": 0}
+                      "crc_jobs": 0, "crc_batches": 0, "crc_bytes": 0,
+                      # which rule shipped a batch: the linger's
+                      # deadline, or a bucket of `max_batch_bytes`
+                      "flush_linger": 0, "flush_full": 0}
         # block sizes whose device crc programs this service keeps
         # ready while `crc_device` is on (`prepare_crc`)
         self._crc_block_sizes: set[int] = set()
@@ -763,7 +775,10 @@ class OffloadService:
         """(S, k, C) data stripes -> (S, m, C) parity via the plugin's
         batched device API, coalesced with concurrent callers. With
         `finish` (parity -> result) the caller gets that result
-        instead, made in the staging pool beside the loop."""
+        instead, made in the staging pool beside the loop. An encode
+        batch is `kind` "enc" on its `offload_batch` span and on its
+        riders' `offload_queue_wait`, and counted in `stats` as
+        `enc_jobs`, `enc_batches`, `enc_bytes` (input, unpadded)."""
         key = ("enc", ec_impl.coding_matrix.tobytes(), stripes.shape[2])
 
         def dispatch(batch: np.ndarray) -> np.ndarray:
@@ -979,6 +994,8 @@ class OffloadService:
         bucket.jobs.append(job)
         bucket.nbytes += nbytes
         if bucket.nbytes >= self.max_batch_bytes:
+            bucket.flush = "full"
+            self.stats["flush_full"] += 1
             self._flush_bucket(key)
         try:
             return await fut
@@ -1071,6 +1088,8 @@ class OffloadService:
         await asyncio.sleep(self.linger_ms / 1000.0)
         bucket = self._buckets.pop(key, None)
         if bucket is not None and bucket.jobs:
+            bucket.flush = "linger"
+            self.stats["flush_linger"] += 1
             self._track(self._loop.create_task(self._run_batch(bucket)))
 
     def _flush_bucket(self, key: tuple) -> None:
@@ -1224,6 +1243,7 @@ class OffloadService:
                                            (now - j.t_submit) * 1e6)
                         if j.span is not None:
                             j.span.set_tag("batch_ops", len(jobs))
+                            j.span.set_tag("kind", bucket.key[0])
                             j.span.finish()
                     batch = self._stage(slot, jobs, bucket.pad_rows)
                     nbytes = sum(j.nbytes for j in jobs)
@@ -1245,6 +1265,7 @@ class OffloadService:
                             # enc / dec / crc / rep; a decode batch
                             # also carries its r and its pattern
                             sp.set_tag("kind", bucket.key[0])
+                            sp.set_tag("flush", bucket.flush)
                             if bucket.key[0] == "dec":
                                 sp.tags.update(decode_batch_tags(
                                     *bucket.key[2:4]))
@@ -1286,15 +1307,14 @@ class OffloadService:
                                 (time.perf_counter() - sp.t0) * 1e6
                                 - sum(sp.tags[h] for h in _HOPS), 1))
                     self._note_batch(len(jobs), nbytes)
-                    if bucket.key[0] == "dec":
-                        self.stats["dec_jobs"] += len(jobs)
-                        self.stats["dec_batches"] += 1
-                        self.stats["dec_bytes"] += nbytes
+                    kind = bucket.key[0]
+                    if kind in ("enc", "dec") \
+                            or (kind == "crc" and on_device != "host"):
+                        self.stats[kind + "_jobs"] += len(jobs)
+                        self.stats[kind + "_batches"] += 1
+                        self.stats[kind + "_bytes"] += nbytes
+                    if kind == "dec":
                         self.stats["dec_out_bytes"] += int(out.nbytes)
-                    elif bucket.key[0] == "crc" and on_device != "host":
-                        self.stats["crc_jobs"] += len(jobs)
-                        self.stats["crc_batches"] += 1
-                        self.stats["crc_bytes"] += nbytes
                 except asyncio.CancelledError:
                     if batch is not None:
                         # the staging pool may still be writing the

@@ -192,6 +192,49 @@ def test_decode_jobs_bucket_by_erasure_pattern():
     run(body(), timeout=60)
 
 
+def test_counters_and_tags_say_which_kind_and_which_flush_rule():
+    """`enc_*` count what the `kind` tag of an `offload_batch` span
+    marks (beside `dec_*` and `crc_*`), `flush_linger` / `flush_full`
+    what its `flush` tag says (a `drain()` is neither rule: `asked`),
+    and a rider's `offload_queue_wait` carries its batch's kind."""
+    from ceph_tpu.utils import tracer
+
+    async def body():
+        impl = _impl(2, 1)
+        stripes = np.arange(2 * 2 * 4096, dtype=np.uint8).reshape(2, 2, 4096)
+        svc = offload.get_service()
+        tracer.enable(max_spans=10_000)
+        cursor = tracer.collector().last_seq()
+        try:
+            await asyncio.gather(*[svc.encode(impl, stripes)
+                                   for _ in range(3)])       # the linger
+            svc.max_batch_bytes = 2 * stripes.nbytes
+            await asyncio.gather(*[svc.encode(impl, stripes)
+                                   for _ in range(4)])       # two full
+            lone = asyncio.ensure_future(svc.encode(impl, stripes))
+            await asyncio.sleep(0)
+            await svc.drain()                                # asked
+            await lone
+            await svc.decode(impl, (0, 2), (1,), stripes)
+        finally:
+            tracer.disable()
+        spans = [s for s in tracer.collector().spans() if s["seq"] > cursor]
+        batches = [s["tags"] for s in spans if s["name"] == "offload_batch"]
+        waits = [s["tags"] for s in spans
+                 if s["name"] == "offload_queue_wait"]
+        assert [(t["kind"], t["flush"], t["ops"]) for t in batches] == [
+            ("enc", "linger", 3), ("enc", "full", 2), ("enc", "full", 2),
+            ("enc", "asked", 1), ("dec", "linger", 1)]
+        assert [t["kind"] for t in waits] == ["enc"] * 8 + ["dec"]
+        st = svc.stats
+        assert (st["flush_linger"], st["flush_full"]) == (2, 2)
+        assert (st["enc_jobs"], st["enc_batches"], st["enc_bytes"]) == \
+            (8, 4, 8 * stripes.nbytes)
+        assert (st["dec_jobs"], st["dec_batches"]) == (1, 1)
+        assert (st["jobs"], st["batches"]) == (9, 5)
+    run(body(), timeout=60)
+
+
 def test_inline_bypass_when_disabled():
     async def body():
         impl = _impl()
