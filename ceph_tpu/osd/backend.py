@@ -137,7 +137,14 @@ class PGBackend:
     def local_apply(self, oid: str, op: str, data: bytes,
                     attrs: dict[str, bytes] | None = None,
                     shard: int = -1, off: int = 0,
-                    omap: dict[str, bytes] | None = None) -> None:
+                    omap: dict[str, bytes] | None = None,
+                    txn: Transaction | None = None) -> None:
+        """Apply `op` to the object in the local store. With `txn` the
+        ops are appended to it and the CALLER queues it (a shard's
+        sub-write, whose transaction carries the PG's log entry too);
+        the snapshot kinds queue what they build themselves either
+        way."""
+        queue = txn is None
         cid = self.coll(shard)
         gh = self.ghobject(oid, shard)
         if not isinstance(data, (bytes, bytearray)) and \
@@ -147,7 +154,8 @@ class PGBackend:
             # decoders below need bytes semantics; the BULK kinds above
             # keep the view — the store writes straight from it
             data = bytes(data)
-        txn = Transaction()
+        if queue:
+            txn = Transaction()
         if op == "write_full":
             # WRITEFULL replaces the DATA only — xattrs and omap survive
             # (the reference's CEPH_OSD_OP_WRITEFULL; an RBD header
@@ -234,7 +242,8 @@ class PGBackend:
             return
         else:
             raise StoreError("EINVAL", f"unknown backend op {op!r}")
-        self.host.store.queue_transaction(txn)
+        if queue:
+            self.host.store.queue_transaction(txn)
 
     def local_read(self, oid: str, shard: int = -1) -> bytes:
         return self.host.store.read(self.coll(shard),

@@ -306,6 +306,14 @@ class OSD(Dispatcher):
         self.perf.add("op_latency", type=TYPE_AVG,
                       description="client op latency (seconds)")
         self.perf.add("subop", description="replication sub-ops applied")
+        self.perf.add("meta_rode_txn",
+                      description="PG meta persists (the meta attr and "
+                                  "the log's dirty keys) that rode the "
+                                  "transaction of the data they "
+                                  "describe: an EC shard's sub-write")
+        self.perf.add("meta_alone_txn",
+                      description="PG meta persists queued as a "
+                                  "transaction of their own")
         self.perf.add("recovery_push",
                       description="objects pushed by recovery/backfill")
         self.perf.add("recovery_bytes_pushed",

@@ -605,7 +605,9 @@ class ECBackend(PGBackend):
         for idx, osd in live.items():
             sub, chunk = payloads[idx]
             if osd == me:
-                self._apply_sub_write(oid, idx, sub, chunk)
+                self.host.store.queue_transaction(
+                    self._sub_write_txn(oid, sub, chunk))
+                self._inject_bitrot(oid, sub, chunk)
                 self.host.store.flush_commit(
                     lambda: self.sub_op_ack(tid, me))
                 continue
@@ -672,28 +674,42 @@ class ECBackend(PGBackend):
                 moved.append(oid)
         return moved
 
-    def _apply_sub_write(self, oid: str, shard: int, sub: dict,
-                         chunk: bytes) -> None:
+    def _sub_write_txn(self, oid: str, sub: dict, chunk: bytes):
+        """This shard's part of a write as ONE store transaction, built
+        and not queued: the caller queues it, a replica with the PG's
+        log entry and meta appended (`handle_sub_op`). Only the
+        rollback generation of an object that is overwritten is a
+        transaction of its own, queued here, before."""
+        from ceph_tpu.objectstore.store import Transaction
         kind = sub["op"]
         self._stash_prev(oid)
+        txn = Transaction()
         if kind == "write_full":
             attrs = {k: v.encode("latin1") for k, v in sub["attrs"].items()}
-            self.local_apply(oid, "push", chunk, attrs=attrs)
+            self.local_apply(oid, "push", chunk, attrs=attrs, txn=txn)
         elif kind == "extent_write":
-            self._apply_extent(oid, sub, chunk)
+            self._apply_extent(txn, oid, sub, chunk)
         elif kind == "setxattr":
             # user xattrs replicate onto EVERY shard (the reference
             # stores object attrs alongside each shard the same way)
             self.local_apply(oid, "setxattr", json.dumps(
-                {"name": sub["name"], "value": sub["value"]}).encode())
+                {"name": sub["name"], "value": sub["value"]}).encode(),
+                txn=txn)
         elif kind == "rmxattr":
-            self.local_apply(oid, "rmxattr", sub["name"].encode())
+            self.local_apply(oid, "rmxattr", sub["name"].encode(), txn=txn)
         elif kind == "delete":
-            self.local_apply(oid, "delete", b"")
+            self.local_apply(oid, "delete", b"", txn=txn)
         elif kind in ("clone", "snaptrim", "purge"):
-            self.local_apply(oid, kind, sub["args"].encode("latin1"))
+            # the snapshot kinds queue what they build themselves and
+            # leave `txn` empty: what the caller adds follows them in
+            # the store's queue
+            self.local_apply(oid, kind, sub["args"].encode("latin1"),
+                             txn=txn)
         else:
             raise StoreError("EINVAL", f"unknown ec sub-op {kind!r}")
+        return txn
+
+    def _inject_bitrot(self, oid: str, sub: dict, chunk: bytes) -> None:
         if chunk and faultinject.armed():
             # injected shard bit-rot AFTER the apply: the per-chunk crc
             # attr now disagrees with the blob, exactly like silent
@@ -704,13 +720,13 @@ class ECBackend(PGBackend):
                     self.coll(), self.ghobject(oid),
                     sub.get("chunk_off", 0) + off)
 
-    def _apply_extent(self, oid: str, sub: dict, chunk: bytes) -> None:
-        """Apply a per-shard extent sub-write: splice the chunk extent
-        into the shard blob (gaps zero-fill via store semantics), merge
-        the per-chunk csum updates, refresh size/version attrs
-        (the per-shard ObjectStore::Transaction of
+    def _apply_extent(self, txn, oid: str, sub: dict,
+                      chunk: bytes) -> None:
+        """A per-shard extent sub-write, appended to `txn`: splice the
+        chunk extent into the shard blob (gaps zero-fill via store
+        semantics), merge the per-chunk csum updates, refresh
+        size/version attrs (the per-shard ObjectStore::Transaction of
         src/osd/ECTransaction.cc:97 generate_transactions)."""
-        from ceph_tpu.objectstore.store import Transaction
         cid, gh = self.coll(), self.ghobject(oid)
         store = self.host.store
         old_csum: list[int] = []
@@ -725,7 +741,6 @@ class ECBackend(PGBackend):
         for s, crc in sub["csum_updates"]:
             if s < new_chunks:
                 csums[s] = crc
-        txn = Transaction()
         if not store.exists(cid, gh):
             txn.touch(cid, gh)
         if chunk:
@@ -734,7 +749,6 @@ class ECBackend(PGBackend):
         txn.truncate(cid, gh, new_chunks * c)
         txn.setattrs(cid, gh, self._chunk_attrs(
             sub["shard"], sub["new_size"], sub["version"], csums))
-        store.queue_transaction(txn)
 
     # -- read path (ReadPipeline) --------------------------------------------
 
@@ -1095,25 +1109,30 @@ class ECBackend(PGBackend):
     async def handle_sub_op(self, conn, msg) -> None:
         p = msg.payload
         if isinstance(msg, MOSDECSubOpWrite):
-            self._apply_sub_write(p["oid"], p["shard"], p["sub"], msg.data)
-            entry = LogEntry.from_dict(p["entry"])
+            oid, sub = p["oid"], p["sub"]
+            txn = self._sub_write_txn(oid, sub, msg.data)
             # out-of-order-tolerant insert: pipelined same-PG fan-outs
             # to different objects can arrive v6-before-v5 (see
             # ReplicatedBackend.handle_rep_op)
-            self.pg.log.insert(entry)
-            if p["sub"]["op"] in ("write_full", "delete"):
+            self.pg.log.insert(LogEntry.from_dict(p["entry"]))
+            if sub["op"] in ("write_full", "delete"):
                 # full-state sub-ops supersede whatever was missing;
                 # an EXTENT write does not restore the base, so a
                 # recovering shard stays in the missing set
-                self.pg.log.mark_recovered(p["oid"])
-            # coalesced: one meta persist per batch drain, not per
-            # sub-op (pipelined primaries ship ~depth entries per
-            # envelope; the apply above is already durable store
-            # state). The reply rides the flush: the ack never outruns
-            # the durable log entry
-            self.pg.persist_meta_soon(ack=(conn, MOSDECSubOpWriteReply(
+                self.pg.log.mark_recovered(oid)
+            reply = MOSDECSubOpWriteReply(
                 {"pgid": p["pgid"], "tid": p["tid"],
-                 "from": self.host.whoami})))
+                 "from": self.host.whoami})
+            # the log entry and the PG's meta ride the shard's own
+            # transaction (upstream's `handle_sub_write` appends
+            # `log_operation`'s keys to `localt` and queues it once):
+            # bytes and entry commit together or not at all, and the
+            # reply leaves from that one commit. A `prepare` that
+            # raises leaves the sub-op unacknowledged: the primary's
+            # wait times out and the client sends again
+            self.pg.persist_meta(
+                on_commit=self.pg.acks_sender([(conn, reply)]), txn=txn)
+            self._inject_bitrot(oid, sub, msg.data)
             return
         # sub-read: serve our chunk extent, crc-verified per chunk
         # (ECBackend.cc:1015 handle_sub_read, crc verify :1092)

@@ -173,22 +173,18 @@ class PGInstance:
     def _meta_gh(self) -> Ghobject:
         return Ghobject(pool=self.pgid.pool, name=PGMETA_OID)
 
-    def persist_meta(self, on_commit=None) -> None:
-        """Queue the durable PG meta; `on_commit` runs once it IS
-        durable (inside this call on a store that commits there, later
-        on the loop on BlueStore: `objectstore/store.py`). Whoever
-        acknowledges a log entry to a peer does it from `on_commit`.
-        Callers that send nothing go on at once: the store commits in
-        the order queued, so whatever they queue next, and acknowledge
-        from its commit, is durable after this.
-
-        A small static attr (head/tail/missing/seq)
-        plus ONE omap key per log entry, written incrementally — only
-        entries that changed since the last persist are (re)written.
-        Re-serializing the whole 1000-entry window per op dominated the
-        write path (profiled); the reference stores log entries as
-        individual omap keys for the same reason
-        (src/osd/PGLog.cc _write_log_and_missing)."""
+    def append_meta(self, txn: Transaction) -> tuple:
+        """Append the durable PG meta to `txn`: a small static attr
+        (head/tail/missing/seq) plus ONE omap key per log entry,
+        written incrementally — only entries that changed since the
+        last persist are (re)written. Re-serializing the whole
+        1000-entry window per op dominated the write path (profiled);
+        the reference stores log entries as individual omap keys for
+        the same reason (src/osd/PGLog.cc _write_log_and_missing), and
+        appends them to the transaction that holds the data they
+        describe (`log_operation(.., localt)`).
+        -> the log's dirty delta as taken, for `restore_dirty` if the
+        transaction is never queued."""
         blob = json.dumps({"seq": self.seq,
                            "les": self.last_epoch_started,
                            "head": list(self.log.head),
@@ -199,7 +195,6 @@ class PGInstance:
                           ).encode()
         cid = self.backend.coll()
         gh = self._meta_gh()
-        txn = Transaction()
         if not self.host.store.exists(cid, gh):
             txn.touch(cid, gh)
         txn.setattr(cid, gh, "pgmeta", blob)
@@ -226,6 +221,27 @@ class PGInstance:
                     for k, v in dirty.items() if v is not None}
             if sets:
                 txn.omap_setkeys(cid, gh, sets)
+        return full, dirty
+
+    def persist_meta(self, on_commit=None,
+                     txn: Transaction | None = None) -> None:
+        """Queue the durable PG meta; `on_commit` runs once it IS
+        durable (inside this call on a store that commits there, later
+        on the loop on BlueStore: `objectstore/store.py`). Whoever
+        acknowledges a log entry to a peer does it from `on_commit`.
+        Callers that send nothing go on at once: the store commits in
+        the order queued, so whatever they queue next, and acknowledge
+        from its commit, is durable after this.
+
+        With `txn` (a shard's data transaction, not queued yet) the
+        meta rides it and the two are queued as ONE: the bytes and the
+        log entry that describes them commit together or not at all
+        (upstream's `ECBackend::handle_sub_write`). Without, the meta
+        is a transaction of its own."""
+        rode = txn is not None
+        if txn is None:
+            txn = Transaction()
+        taken = self.append_meta(txn)
         if on_commit is not None:
             txn.register_on_commit(on_commit)
         try:
@@ -237,16 +253,20 @@ class PGInstance:
             # the store is dead from then on (`BlueStore._fail_group`),
             # it refuses this OSD's next transaction, and nothing that
             # waited for `on_commit` is ever acknowledged
-            self.log.restore_dirty(full, dirty)
+            self.log.restore_dirty(*taken)
             raise
+        self.host.perf.inc("meta_rode_txn" if rode else "meta_alone_txn")
 
     def persist_meta_soon(self, ack: tuple | None = None) -> None:
-        """Coalesced replica-side persist: a pipelined primary's batch
-        envelopes deliver many sub-ops per loop slice, and each used to
-        re-serialize + write the meta blob individually. One call_soon
-        flush per slice persists them all (the in-memory log is updated
-        synchronously; only the disk write coalesces — the same
-        window a journaling store batches into one commit). The PRIMARY
+        """Coalesced replica-side persist of the REPLICATED backend
+        (`handle_rep_op`; an erasure pool's replica appends the meta to
+        its shard's own transaction, `persist_meta(txn=)`): a pipelined
+        primary's batch envelopes deliver many sub-ops per loop slice,
+        and each used to re-serialize + write the meta blob
+        individually. One call_soon flush per slice persists them all
+        (the in-memory log is updated synchronously; only the disk
+        write coalesces — the same window a journaling store batches
+        into one commit). The PRIMARY
         path queues its persist inside the ordered slice: the
         dup-replay invariant needs the intent durable before the op is
         answered, and it is, because the primary's own shard is queued
@@ -268,10 +288,9 @@ class PGInstance:
         self._persist_scheduled = True
         asyncio.get_running_loop().call_soon(self._persist_flush)
 
-    def _persist_flush(self) -> None:
-        self._persist_scheduled = False
-        acks, self._persist_acks = self._persist_acks, []
-
+    @staticmethod
+    def acks_sender(acks: list[tuple]):
+        """An `on_commit` that sends the deferred (conn, reply) pairs."""
         def send_acks() -> None:
             with tracer.section("osd.other"):    # not the store's
                 for conn, reply in acks:
@@ -279,8 +298,14 @@ class PGInstance:
                         conn.send_message(reply)
                     except Exception:
                         pass    # dead peer conn: its timeout handles it
+        return send_acks
+
+    def _persist_flush(self) -> None:
+        self._persist_scheduled = False
+        acks, self._persist_acks = self._persist_acks, []
         try:
-            self.persist_meta(on_commit=send_acks if acks else None)
+            self.persist_meta(
+                on_commit=self.acks_sender(acks) if acks else None)
         except Exception as e:
             # the delta was handed back by persist_meta's failure path;
             # the UNSENT acks make the primary time the sub-ops out, so

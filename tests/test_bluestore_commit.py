@@ -1009,10 +1009,11 @@ class _KeyEncodings:
 
 def _shard_pair(store, cid, name: str, data, csums=None):
     """A shard's data transaction and its PG-log transaction with the
-    probes in front of them, as `ECBackend._apply_sub_write` ->
+    probes in front of them, as `ECBackend._sub_write_txn` ->
     `_stash_prev`, `local_apply("push")` and `PG.persist_meta` build
-    them: every id made anew by each of the three, as `coll()` and
-    `ghobject()` make them. -> the two transactions."""
+    them (a primary's pair; a replica's are one since PR 50): every
+    id made anew by each of the three, as `coll()` and `ghobject()`
+    make them. -> the two transactions."""
     def gh(n):
         return Ghobject(pool=cid.pool, name=n, shard=cid.shard)
 

@@ -1,7 +1,10 @@
 """No acknowledgement leaves an OSD before the store's commit: a shard's
 sub-op reply, a replica's, the primary's own shard and with it the
 client's reply all wait for `on_commit`. On a store that commits inside
-`queue_transaction` (MemStore) the order of events is what it was."""
+`queue_transaction` (MemStore) the order of events is what it was. An
+erasure pool's replica queues ONE transaction a sub-write: it holds the
+shard's bytes and the PG's log entry, and the reply leaves from its
+commit."""
 from __future__ import annotations
 
 import asyncio
@@ -10,6 +13,9 @@ import pytest
 
 from ceph_tpu.msg import messenger
 from ceph_tpu.objectstore.bluestore import BlueStore
+from ceph_tpu.objectstore.store import Op, StoreError, Transaction
+from ceph_tpu.osd.pg import PGMETA_OID
+from ceph_tpu.osd.pglog import LogEntry, PGLog
 from ceph_tpu.rados import RadosClient
 
 from tests.test_bluestore_commit import Syncs, syncs  # noqa: F401
@@ -56,14 +62,52 @@ async def _cluster(tmp_path, kind: str, pool: str):
     return c, cl, cl.ioctx("p")
 
 
+def _pg_of(osd, pg):
+    """The OSD's instance of the map's placement group `pg`."""
+    return next(inst for key, inst in osd.pgs.items()
+                if (key.pool, key.ps) == (pg.pool, pg.ps))
+
+
+def _watch_transactions(osd, sent) -> list[dict]:
+    """Every transaction the OSD's store is given from here on: what it
+    writes (`wrote`: object names), the PG-log keys it sets (`log_keys`)
+    and, once it has committed, how many sub-op replies had left any
+    daemon when its own callbacks had run (`replies_at_commit`)."""
+    seen: list[dict] = []
+    real = osd.store.queue_transaction
+
+    def queue_transaction(txn):
+        rec = {"wrote": [op[2].name for op in txn.ops if op[0] is Op.WRITE],
+               "log_keys": [k for op in txn.ops
+                            if op[0] is Op.OMAP_SETKEYS
+                            and op[2].name == PGMETA_OID
+                            for k in op[3] if k.startswith(PGLog.KEY_PREFIX)],
+               "replies_at_commit": None}
+        seen.append(rec)
+        # registered last: it runs after what the OSD registered
+        txn.register_on_commit(lambda: rec.update(
+            replies_at_commit=len([m for m in sent if m in SUB_REPLIES])))
+        return real(txn)
+    osd.store.queue_transaction = queue_transaction
+    return seen
+
+
+def _rode(c) -> int:
+    return sum(o.perf.dump()["meta_rode_txn"] for o in c.osds.values())
+
+
 @pytest.mark.parametrize("pool", sorted(POOLS))
 @pytest.mark.parametrize("kind", ["bluestore", "memstore"])
 def test_no_ack_leaves_before_the_commit(tmp_path, syncs, sent, kind, pool):
     async def body():
-        c, _cl, io = await _cluster(tmp_path, kind, pool)
+        c, cl, io = await _cluster(tmp_path, kind, pool)
         try:
             await io.write_full("warm", b"w" * 70_000)  # peered, all imported
             del sent[:]
+            primary = cl.osdmap.primary(cl.osdmap.object_to_pg("p", "x"))
+            queued = {i: _watch_transactions(o, sent)
+                      for i, o in c.osds.items() if i != primary}
+            rode = _rode(c)
             syncs.hold()
             value = b"v" * 70_000
             write = asyncio.create_task(io.write_full("x", value))
@@ -85,6 +129,19 @@ def test_no_ack_leaves_before_the_commit(tmp_path, syncs, sent, kind, pool):
             assert set(sent[:2]) <= SUB_WRITES
             assert set(sent[2:4]) <= SUB_REPLIES
             assert sent[4] == "MOSDOpReply"
+            if pool == "erasure":
+                # a replica's sub-write is ONE transaction: the shard's
+                # bytes and the PG's log entry are both in it, and its
+                # reply left from that commit, neither before nor after
+                # (the n-th replica to commit finds n replies sent)
+                assert [len(txns) for txns in queued.values()] == [1, 1]
+                txns = [t for (t,) in queued.values()]
+                assert all(t["wrote"] == ["x"] and len(t["log_keys"]) == 1
+                           for t in txns), txns
+                assert sorted(t["replies_at_commit"] for t in txns) == [1, 2]
+                assert _rode(c) - rode == 2     # a replica each
+            else:
+                assert _rode(c) == rode         # the replicated backend's
             assert await io.read("x") == value
             if kind == "bluestore":
                 for o in c.osds.values():
@@ -227,14 +284,189 @@ def test_killed_with_the_intent_durable_and_no_shard_the_resend_reexecutes(
             monkeypatch.setattr(ECBackend, "_encode_csums", real)
             await _restart_all(c)
             # the restarted primary knows the request
-            again = c.osds[primary.whoami].pgs[
-                next(k for k in c.osds[primary.whoami].pgs
-                     if (k.pool, k.ps) == (pg.pool, pg.ps))]
+            again = _pg_of(c.osds[primary.whoami], pg)
             assert any(e.oid == "x" for e in again.log.entries)
             await asyncio.wait_for(write, 80)
             assert await io.read("x") == value
         finally:
             syncs.release()
+            await c.stop()
+    run(body())
+
+
+def _on_a_fresh_mount(path: str, cid, gh, meta) -> tuple[bool, set[str]]:
+    """Whether a second store mounted on the directory finds the
+    object, and the PG-log keys it finds on the PG's meta object."""
+    store = BlueStore(path)
+    store.mount()
+    try:
+        return store.exists(cid, gh), {
+            k for k in store.omap_get(cid, meta)
+            if k.startswith(PGLog.KEY_PREFIX)}
+    finally:
+        store.umount()
+
+
+def test_a_replica_killed_before_its_commit_keeps_neither_shard_nor_entry(
+        tmp_path, syncs, sent, monkeypatch):
+    """The atomicity that one transaction a sub-write buys. A replica
+    has queued a sub-write (the shard reads back from it, its log holds
+    the entry) and dies before that group's KV batch: a fresh mount of
+    its directory finds NEITHER the shard NOR the log entry, where two
+    transactions could leave the bytes without the entry that describes
+    them. Of a write that was acknowledged before, the same mount finds
+    both. The daemon comes back, the client's resend lands."""
+    monkeypatch.setattr(RadosClient, "OP_TIMEOUT", 90.0)
+    monkeypatch.setattr(RadosClient, "ATTEMPT_TIMEOUT", 2.0)
+
+    async def body():
+        c, cl, io = await _cluster(tmp_path, "bluestore", "erasure")
+        try:
+            await io.write_full("warm", b"w" * 70_000)
+            pg = cl.osdmap.object_to_pg("p", "x")
+            kept = next(f"kept{n}" for n in range(64) if
+                        cl.osdmap.object_to_pg("p", f"kept{n}") == pg)
+            await io.write_full(kept, b"k" * 70_000)    # acknowledged
+            primary = c.osds[cl.osdmap.primary(pg)]
+            r = next(i for i in c.osds if i != primary.whoami)
+            replica = c.osds[r]
+            inst = _pg_of(replica, pg)
+            cid, meta = inst.backend.coll(), inst._meta_gh()
+            gh = {o: inst.backend.ghobject(o) for o in ("x", kept)}
+            queued = _watch_transactions(replica, sent)
+            del sent[:]
+            syncs.only = replica.store._thread
+            syncs.hold()
+            value = b"v" * 150_000
+            write = asyncio.create_task(io.write_full("x", value))
+            await asyncio.sleep(0.6)
+            assert not write.done()
+            # queued, one transaction, readable, and not committed
+            assert [t["wrote"] for t in queued] == [["x"]]
+            assert queued[0]["replies_at_commit"] is None
+            assert replica.store.exists(cid, gh["x"])
+            key = {o: PGLog.entry_key(next(
+                e.version for e in inst.log.entries if e.oid == o))
+                for o in ("x", kept)}
+            assert queued[0]["log_keys"] == [key["x"]]
+            replica.store.fail_before_kv = True     # the kill, armed
+            syncs.release()
+            await asyncio.sleep(0.3)
+            assert replica.store.failed and replica._stopping
+            assert not write.done()
+            await c.kill_osd(r)
+            has, keys = _on_a_fresh_mount(str(tmp_path / f"osd{r}"),
+                                          cid, gh["x"], meta)
+            assert not has and key["x"] not in keys
+            has, keys = _on_a_fresh_mount(str(tmp_path / f"osd{r}"),
+                                          cid, gh[kept], meta)
+            assert has and key[kept] in keys
+            await c.start_osd(r)
+            await asyncio.wait_for(write, 80)
+            assert await io.read("x") == value
+            assert await io.read(kept) == b"k" * 70_000
+        finally:
+            syncs.release()
+            await c.stop()
+    run(body())
+
+
+@pytest.mark.parametrize("dirty", ["incremental", "full"])
+@pytest.mark.parametrize("kind", ["bluestore", "memstore"])
+def test_the_meta_a_transaction_is_given_is_the_meta_persisted_alone(
+        tmp_path, kind, dirty):
+    """`PG.append_meta` appends to the transaction it is given exactly
+    the ops that `persist_meta` queues alone for the same dirty state,
+    after whatever the transaction held, and counts which of the two a
+    persist was."""
+    async def body():
+        c, cl, io = await _cluster(tmp_path, kind, "erasure")
+        try:
+            await io.write_full("x", b"v" * 70_000)
+            pg = cl.osdmap.object_to_pg("p", "x")
+            osd = c.osds[cl.osdmap.primary(pg)]
+            inst = _pg_of(osd, pg)
+            inst.log.append(LogEntry(version=inst.next_version(),
+                                     op="modify", oid="y"))
+            if dirty == "full":
+                inst.log.restore_dirty(True, {})    # as an adopted log is
+            cid, gh = inst.backend.coll(), inst.backend.ghobject("y")
+            given = Transaction().touch(cid, gh)
+            taken = inst.append_meta(given)
+            assert taken[0] == (dirty == "full")
+            assert inst.log.take_dirty() == (False, {})     # consumed
+            inst.log.restore_dirty(*taken)
+            queued = []
+            real = osd.store.queue_transaction
+            osd.store.queue_transaction = \
+                lambda txn: (queued.append(txn), real(txn))[1]
+            before = osd.perf.dump()
+            inst.persist_meta()
+            assert len(queued) == 1
+            assert given.ops[0] == (Op.TOUCH, cid, gh)
+            assert given.ops[1:] == queued[0].ops
+            kinds = [op[0] for op in queued[0].ops]
+            assert kinds[0] is Op.SETATTRS and Op.OMAP_SETKEYS in kinds
+            sets = next(op[3] for op in queued[0].ops
+                        if op[0] is Op.OMAP_SETKEYS)
+            assert len(sets) == (len(inst.log.entries) if dirty == "full"
+                                 else 1)
+            inst.log.append(LogEntry(version=inst.next_version(),
+                                     op="modify", oid="z"))
+            inst.persist_meta(txn=Transaction().touch(cid, gh))
+            assert queued[1].ops[0] == (Op.TOUCH, cid, gh)
+            assert len(queued[1].ops) == 3      # and the attr, and a key
+            after = osd.perf.dump()
+            assert (after["meta_alone_txn"] - before["meta_alone_txn"],
+                    after["meta_rode_txn"] - before["meta_rode_txn"]) \
+                == (1, 1)
+        finally:
+            await c.stop()
+    run(body())
+
+
+@pytest.mark.parametrize("rides", [False, True], ids=["alone", "rides"])
+@pytest.mark.parametrize("dirty", ["incremental", "full"])
+def test_a_prepare_that_raises_hands_the_logs_delta_back(tmp_path, dirty,
+                                                         rides):
+    """The store refuses the transaction (a `prepare` that raises): the
+    dirty delta that `append_meta` took is the log's again, whether the
+    meta was queued alone or rode a data transaction, no persist is
+    counted, and the next persist writes it."""
+    async def body():
+        c, cl, io = await _cluster(tmp_path, "memstore", "erasure")
+        try:
+            await io.write_full("x", b"v" * 70_000)
+            pg = cl.osdmap.object_to_pg("p", "x")
+            osd = c.osds[cl.osdmap.primary(pg)]
+            inst = _pg_of(osd, pg)
+            entry = LogEntry(version=inst.next_version(), op="modify",
+                             oid="y")
+            inst.log.append(entry)
+            if dirty == "full":
+                inst.log.restore_dirty(True, {})
+            want = (dirty == "full", {PGLog.entry_key(entry.version): entry})
+            real = osd.store.queue_transaction
+
+            def refuse(txn):
+                raise StoreError("ENOSPC", "no room to prepare")
+            osd.store.queue_transaction = refuse
+            before = osd.perf.dump()
+            acked = []
+            given = Transaction().touch(inst.backend.coll(),
+                                        inst.backend.ghobject("y"))
+            with pytest.raises(StoreError):
+                inst.persist_meta(on_commit=lambda: acked.append(1),
+                                  txn=given if rides else None)
+            assert osd.perf.dump() == before and not acked
+            osd.store.queue_transaction = real
+            assert inst.log.take_dirty() == want
+            inst.log.restore_dirty(*want)
+            inst.persist_meta(on_commit=lambda: acked.append(2))
+            assert acked == [2]
+            assert PGLog.entry_key(entry.version) in osd.store.omap_get(
+                inst.backend.coll(), inst._meta_gh())
+        finally:
             await c.stop()
     run(body())
 
