@@ -288,37 +288,49 @@ class Frame:
         (pre_crc,) = _U32.unpack_from(rest, 4 * nseg)
         if crc32c(fixed + rest[:4 * nseg]) != pre_crc:
             raise FrameError("preamble crc mismatch")
-        body = await reader.readexactly(sum(ln + 4 for ln in seg_lens))
+        want = sum(ln + 4 for ln in seg_lens)
+        read_body = getattr(reader, "read_body", None)
+        if read_body is None:
+            body, bad = await reader.readexactly(want), None
+        else:
+            # the endpoint's: a large body comes with its crcs checked
+            # by the thread that received it (msg/rxworker.py)
+            body, bad = await read_body(want, seg_lens)
+        if bad is not None and bad >= 0:
+            raise FrameError("segment crc mismatch")
         try:
             tag = Tag(tag)
         except ValueError as e:
             raise FrameError(f"unknown tag {tag}") from e
         return cls(tag, cls._parse_segments(
-            seg_lens, memoryview(body).toreadonly()))
+            seg_lens, memoryview(body).toreadonly(), bad is not None))
 
     @classmethod
-    def _parse_segments(cls, seg_lens: list[int],
-                        body: memoryview) -> list[memoryview]:
+    def _parse_segments(cls, seg_lens: list[int], body: memoryview,
+                        verified: bool = False) -> list[memoryview]:
         """crc-verify and window each segment out of the body buffer —
         zero-copy: every returned segment is a view, and the buffer
         stays alive exactly as long as any segment does (refcounted).
         With the native codec the whole crc-over-segments pass is one
-        GIL-releasing C call; the view windowing stays in Python."""
+        GIL-releasing C call; the view windowing stays in Python.
+        `verified`: whoever received the body checked every segment's
+        crc already, and none is checked twice."""
         want = sum(ln + 4 for ln in seg_lens)
         if len(body) < want:
             raise FrameError("truncated segment")
-        if _frame_native is not None:
-            base = body.obj if isinstance(body, memoryview) else None
-            # the streamed-read path hands a view over EXACTLY the body
-            # (bytes out of the spill, the bytearray a large read
-            # filled): pass the object itself (ctypes converts either
-            # without the numpy fallback the sliced decode path needs)
-            buf = base if type(base) in (bytes, bytearray) \
-                and len(base) == want else body[:want]
-            with tracer.section("msgr.codec"):
-                bad = _frame_native.verify_body(buf, seg_lens)
-            if bad >= 0:
-                raise FrameError("segment crc mismatch")
+        if verified or _frame_native is not None:
+            if not verified:
+                base = body.obj if isinstance(body, memoryview) else None
+                # the streamed-read path hands a view over EXACTLY the body
+                # (bytes out of the spill, the bytearray a large read
+                # filled): pass the object itself (ctypes converts either
+                # without the numpy fallback the sliced decode path needs)
+                buf = base if type(base) in (bytes, bytearray) \
+                    and len(base) == want else body[:want]
+                with tracer.section("msgr.codec"):
+                    bad = _frame_native.verify_body(buf, seg_lens)
+                if bad >= 0:
+                    raise FrameError("segment crc mismatch")
             segments = []
             off = 0
             for ln in seg_lens:

@@ -43,7 +43,10 @@ owns (no asyncio stream pair): small reads come out of one fixed spill
 buffer per connection, and a frame's body is received by the kernel
 straight into the buffer its segments stay views of — one copy per
 payload byte, counted in the msgr logger (rx_direct_bytes,
-rx_spill_bytes, rx_recvs). The way out mirrors it: a frame whose
+rx_spill_bytes, rx_recvs); a body of msg/rxworker.py's LINE or more is
+received and crc-checked by that module's native thread, off the loop
+(rx_worker_bodies, rx_worker_bytes, rx_worker_cpu_ns,
+rx_worker_cancelled). The way out mirrors it: a frame whose
 payload is the spill's size or more leaves by reference, its segments
 read once for their crc and sent by the transport's scatter sendmsg
 from where they lie; a smaller one, and every frame of a secure or
@@ -150,6 +153,18 @@ def msgr_perf():
         pc.add("rx_recvs",
                description="recv_into calls that returned data "
                            "(buffer_updated callbacks)")
+        pc.add("rx_worker_bodies",
+               description="frame bodies the receive worker "
+                           "(msg/rxworker.py) received whole")
+        pc.add("rx_worker_bytes",
+               description="those of rx_direct_bytes that the receive "
+                           "worker's thread received, off the loop")
+        pc.add("rx_worker_cpu_ns",
+               description="CPU time of the receive worker's thread on "
+                           "its bodies: recv and crc32c")
+        pc.add("rx_worker_cancelled",
+               description="bodies taken back from the receive worker "
+                           "unfinished (connection lost, read cancelled)")
         pc.add("tx_direct_bytes",
                description="payload bytes of frames the write loop "
                            "sent by reference (Frame.encode_parts: "

@@ -2,8 +2,8 @@
 lets the kernel write a frame's body straight into the buffer the frame
 keeps.
 
-A read of `n` bytes goes one of two ways, chosen from `n` against the
-spill's size and from nothing else:
+A read of `n` bytes goes one of three ways, chosen from `n` against the
+spill's size and the receive worker's line, and from nothing else:
 
   * small (`n <= SPILL_SIZE`: the preamble, lengths and crc, ACK and
     keepalive frames, the handshake, control messages and small ops)
@@ -23,6 +23,21 @@ spill's size and from nothing else:
     adopts the read-only view it is handed, `objectstore/memstore.py`),
     so a recycling body pool, if one is ever built, is for the read
     direction only: bodies of replies, which die with their message.
+  * larger still (`n >= rxworker.LINE`: a 512 KiB sub-op, a 4 MiB op or
+    reply) gets the same destination and the same copy of the spill's
+    head, and then the loop does not receive it at all: the endpoint
+    pauses its transport and hands the socket, for exactly the rest of
+    the body, to the receive worker (`msg/rxworker.py`), a native
+    thread that `recv`s into the unfilled tail, checks the segments'
+    crcs as the bytes arrive (`read_body` says so to `Frame.read`,
+    which then checks none a second time) and wakes the loop once,
+    when the body is whole: the kernel's copy, the first touch of the
+    pages and the crc pass cost the loop nothing. Reading stays paused
+    from before the submit until the completion is reaped or the job
+    is taken back (`close`, a lost connection, a cancelled read), so a
+    body is never read by both, and the thread never reads past the
+    body's end, so the next preamble is the spill's. Where the native
+    library is missing the second way serves these bodies too.
 
 What a recv lands in the spill in front of a large body is copied a
 second time, so how much of the spill the kernel is offered follows
@@ -31,7 +46,8 @@ bytes, enough for the next preamble and little of what follows it;
 once small reads have taken that much with no large one between them,
 all of it, so that a run of small frames comes in one recv as it did
 through a stream reader. Reading is paused only when the spill is full
-of unread bytes, never in the middle of a body.
+of unread bytes, or for the worker; never in the middle of a body that
+the transport itself receives.
 
 The write side is the transport's own queue with `drain()` on its
 high-water mark, as asyncio's stream writer had it. `writelines` puts a
@@ -47,7 +63,9 @@ from __future__ import annotations
 
 import asyncio
 import functools
+import os
 
+from ceph_tpu.msg import rxworker
 from ceph_tpu.utils import tracer
 
 #: bytes of spill per connection, and the size above which a read gets
@@ -100,6 +118,9 @@ class Endpoint(asyncio.BufferedProtocol):
         self._wpos = 0
         self._dest: memoryview | None = None    # body being filled
         self._dest_pos = 0
+        self._fd = -1               # the socket, where the worker can have it
+        self._port = None           # the worker's, once a body went there
+        self._job: rxworker.Job | None = None   # body in the worker's hands
         self._need = 0              # spill bytes the parked read wants
         self._small_run = 0         # bytes of small reads since a body
         self._read_waiter: asyncio.Future | None = None
@@ -117,6 +138,9 @@ class Endpoint(asyncio.BufferedProtocol):
         self.transport = transport
         self._loop = asyncio.get_running_loop()
         self._closed = self._loop.create_future()
+        sock = transport.get_extra_info("socket")
+        if sock is not None:
+            self._fd = sock.fileno()
         if self._on_connect is not None:
             self._on_connect(self)
 
@@ -171,6 +195,11 @@ class Endpoint(asyncio.BufferedProtocol):
         self._eof = True
         if exc is not None and self._exc is None:
             self._exc = exc
+        self._fd = -1       # asyncio closes it when this returns
+        self._take_back()
+        if self._port is not None:
+            port, self._port = self._port, None
+            rxworker.release(port)
         self._wake_reader()
         self._wake_drainers()
         if not self._closed.done():
@@ -226,7 +255,7 @@ class Endpoint(asyncio.BufferedProtocol):
         a fresh `bytearray` the kernel filled for a large one. EOF or a
         lost connection short of `n` raises as asyncio's streams do."""
         if n > SPILL_SIZE:
-            return await self._read_body(n)
+            return (await self._read_body(n, None))[0]
         if self._rpos + n > SPILL_SIZE:
             self._compact()
         self._need = n
@@ -245,7 +274,16 @@ class Endpoint(asyncio.BufferedProtocol):
             self._rpos = start + n
         return out
 
-    async def _read_body(self, n: int) -> bytearray:
+    async def read_body(self, n: int, seg_lens: list[int]) -> tuple:
+        """A frame's body of `n` bytes, `seg_lens[i]` bytes and a crc32c
+        a segment: `(body, bad)` with `bad` None where nobody has
+        checked the crcs yet (the caller does), else -1 or the first
+        segment that failed (the worker checked them as it received)."""
+        if n > SPILL_SIZE:
+            return await self._read_body(n, seg_lens)
+        return await self.readexactly(n), None
+
+    async def _read_body(self, n: int, seg_lens) -> tuple:
         # `buf` is not zero-filled: until `_dest_pos == n` its tail is
         # stale heap (earlier messages, keys), so neither it nor `_dest`
         # is ever read, logged or dumped past `_dest_pos`
@@ -256,6 +294,11 @@ class Endpoint(asyncio.BufferedProtocol):
             buf[:have] = self._spill_mv[self._rpos:self._wpos]
             self._rpos = self._wpos = 0
         self._small_run = 0
+        if n >= rxworker.LINE and self._fd >= 0 and not self._eof \
+                and rxworker.available():
+            job = self._hand_over(buf, have, seg_lens)
+            if job is not None:
+                return await self._worker_body(job, bool(seg_lens))
         self._dest_pos = have
         # the kernel's window on `buf` while it fills, dropped when it
         # is full; `buf` itself is never reused
@@ -271,7 +314,77 @@ class Endpoint(asyncio.BufferedProtocol):
             # a cancelled or failed read gives the socket back to the
             # spill; the bytes it had taken are lost with the transport
             self._dest = None
-        return buf
+        return buf, None
+
+    def body_filled(self) -> int:
+        """Bytes there of the body being received, whoever receives it
+        (the transport or the receive worker); -1 between bodies."""
+        if self._job is not None:
+            return rxworker.progress(self._job)
+        return self._dest_pos if self._dest is not None else -1
+
+    def _hand_over(self, buf: bytearray, have: int, seg_lens):
+        """Give the socket to the receive worker for the rest of `buf`:
+        the transport does not read from before the submit until the
+        job is reaped or taken back, so a body is never read by both.
+        None where the worker cannot have it (no thread, no fd to
+        spare): the read goes on here."""
+        try:
+            if self._port is None:
+                self._port = rxworker.acquire(self._loop)
+            if not self._reading_paused:
+                self._reading_paused = True
+                self.transport.pause_reading()
+            self._job = rxworker.submit(self._port, self._fd, buf, have,
+                                        seg_lens, self._handed_back)
+        except OSError:
+            return None
+        return self._job
+
+    async def _worker_body(self, job, verifies: bool) -> tuple:
+        buf = job.buf
+        try:
+            got, recvs, cpu_ns, bad, status = await job.fut
+        finally:
+            # a cancelled read: the bytes are lost with the transport
+            self._take_back()
+        if status == rxworker.LOST:     # `_take_back` has counted it
+            raise self._read_failed(bytes(buf[:got]), len(buf))
+        self._count_worker(got - job.have, recvs, cpu_ns)
+        if status == rxworker.WHOLE:
+            self._perf.inc("rx_worker_bodies")
+            return buf, (bad if verifies else None)
+        if status == rxworker.EOF:
+            self._eof = True
+            raise asyncio.IncompleteReadError(bytes(buf[:got]), len(buf))
+        raise OSError(status, os.strerror(status))
+
+    def _count_worker(self, nbytes: int, recvs: int, cpu_ns: int) -> None:
+        perf = self._perf
+        perf.inc("rx_recvs", recvs)
+        perf.inc("rx_direct_bytes", nbytes)
+        perf.inc("rx_worker_bytes", nbytes)
+        perf.inc("rx_worker_cpu_ns", cpu_ns)
+
+    def _handed_back(self) -> None:
+        """The worker is done with the socket, whole body or not (called
+        from the reap, or from a cancel): the transport reads again at
+        once, and finds the EOF or the error the worker found, not a
+        turn of the loop later when the reader is back for the next
+        preamble."""
+        self._job = None
+        if self._reading_paused and not self._lost:
+            self._reading_paused = False
+            self.transport.resume_reading()
+
+    def _take_back(self) -> None:
+        """No job is the worker's when this returns (`close`, a lost
+        connection, a cancelled read)."""
+        job, self._job = self._job, None
+        got = rxworker.cancel(job) if job is not None else None
+        if got is not None:
+            self._perf.inc("rx_worker_cancelled")
+            self._count_worker(got - job.have, 0, 0)
 
     # -- write side ----------------------------------------------------------
 
@@ -308,6 +421,7 @@ class Endpoint(asyncio.BufferedProtocol):
             raise self._exc or ConnectionResetError("connection lost")
 
     def close(self) -> None:
+        self._take_back()
         self.transport.close()
 
     async def wait_closed(self) -> None:

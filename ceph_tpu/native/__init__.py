@@ -40,7 +40,8 @@ def _build() -> str:
     # build beside the target and rename into place: worker processes
     # that race the first import each install a complete library
     tmp = f"{so}.{os.getpid()}"
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread", _SRC,
+           "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
     except (OSError, subprocess.SubprocessError) as e:
@@ -49,6 +50,28 @@ def _build() -> str:
             f"building {so} failed: {e} {detail.decode(errors='replace')}") from e
     os.replace(tmp, so)
     return so
+
+
+def declare_rxw(lib) -> None:
+    """The receive worker's entry points (native/ec_native.cc, `rxw_*`)
+    on a handle of the library: `load()`'s, or msg/rxworker.py's second
+    one, whose calls keep the interpreter's lock."""
+    u64p = ctypes.POINTER(ctypes.c_uint64)
+    lib.rxw_submit.restype = ctypes.c_int
+    lib.rxw_submit.argtypes = [
+        ctypes.c_uint64, ctypes.c_int, ctypes.c_void_p, ctypes.c_uint64,
+        ctypes.c_uint64, u64p, ctypes.c_int, ctypes.c_int]
+    lib.rxw_cancel.restype = ctypes.c_int64
+    lib.rxw_cancel.argtypes = [ctypes.c_uint64]
+    lib.rxw_progress.restype = ctypes.c_int64
+    lib.rxw_progress.argtypes = [ctypes.c_uint64]
+    lib.rxw_reap.restype = ctypes.c_int     # six a completion, two signed
+    lib.rxw_reap.argtypes = [ctypes.POINTER(ctypes.c_int64), ctypes.c_int]
+    for name in ("rxw_start", "rxw_stop", "rxw_running", "rxw_jobs"):
+        getattr(lib, name).restype = ctypes.c_int
+        getattr(lib, name).argtypes = []
+    lib.rxw_forked.restype = None
+    lib.rxw_forked.argtypes = []
 
 
 def load() -> ctypes.CDLL:
@@ -88,6 +111,9 @@ def load() -> ctypes.CDLL:
                 lib.frame_verify_body.restype = ctypes.c_int
                 lib.frame_verify_body.argtypes = [ctypes.c_void_p, u64p,
                                                   ctypes.c_int]
+            # the messenger's receive worker (Linux only)
+            if hasattr(lib, "rxw_submit"):
+                declare_rxw(lib)
             lib.ec_native_have_avx2.restype = ctypes.c_int
             lib.ec_native_have_sse42.restype = ctypes.c_int
             _lib = lib

@@ -12,6 +12,15 @@ the event loop all the same: moving `pack` and `verify_body` of frames
 of 64 KiB and more to a helper thread (`run_in_executor`) read 8% FEWER
 ops a second in `rb4m_seqread` (CPU container, PR 28's issue) — the GIL
 hand-offs and the extra loop turns cost more than the overlap frees.
+That was a hop a FRAME: a Python worker thread, two hand-overs of the
+interpreter's lock and a `call_soon_threadsafe` for 50-80 us of crc.
+What did pay (PR 52) is a hand-over a BODY: a body of
+`msg/rxworker.py`'s `LINE` or more is received whole, and its crcs
+checked as it arrives, by a native thread that never takes the
+interpreter's lock; the loop pays one submit and one reap for a
+receive and a crc pass of half a millisecond and more, and
+`verify_body` is then not called for that frame at all. Bodies under
+the line, and every `pack` and `crcs`, are as they were.
 The wire layout is bit-identical to the pure-Python path; frames.py
 probes `available()` at import and silently keeps the Python fallback
 when the library (or a compiler to build it) is missing.

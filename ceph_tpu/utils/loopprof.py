@@ -68,6 +68,7 @@ LABEL_OF_PATH = (
     ("/ceph_tpu/msg/messenger.py", "_dispatch_loop", "msgr.dispatch"),
     ("/asyncio/selector_events.py", "_read_ready", "msgr.rx_sock"),
     ("/asyncio/selector_events.py", "_write_ready", "msgr.tx_sock"),
+    ("/ceph_tpu/msg/rxworker.py", "_reap", "msgr.rx_sock"),
     ("/ceph_tpu/msg/", None, "msgr.other"),
     ("/asyncio/selector_events.py", None, "msgr.other"),
     ("/asyncio/streams.py", None, "msgr.other"),

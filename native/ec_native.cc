@@ -25,6 +25,8 @@
 //   frame_crcs(...)                               preamble and crcs alone: the
 //                                                 segments go by reference
 //   frame_verify_body(...)                        a received body's crcs
+//   rxw_start/stop/submit/cancel/reap/...         the messenger's receive
+//                                                 worker (Linux; at the end)
 //   ec_native_have_avx2() / ec_native_have_sse42()
 
 #include <cstdint>
@@ -462,3 +464,391 @@ void planes_from_stripes(const uint8_t* src, size_t S, size_t n, size_t C,
 }
 
 }  // extern "C"
+
+// ---------------------------------------------------------------------------
+// The messenger's receive worker (ceph_tpu/msg/rxworker.py; upstream's
+// AsyncMessenger Worker, src/msg/async/Stack.h, cut down to what the
+// interpreter's lock leaves worth moving): ONE native thread with an epoll
+// set of the sockets that have a large frame body in progress. A job is a
+// dup of the connection's fd, the body's buffer, how much of it is already
+// there and the segments' lengths. The thread recvs into the unfilled tail,
+// never past the body's end, chains the segments' crc32c over the bytes as
+// they arrive, and posts a completion the event loop reaps through one call
+// (`rxw_reap`) after a wake-up on an eventfd of its own. The thread runs no
+// Python and never takes the interpreter's lock. A cancel waits until the
+// thread has let go of the job's fd and buffer.
+// ---------------------------------------------------------------------------
+
+#if defined(__linux__)
+
+#include <atomic>
+#include <cerrno>
+#include <deque>
+#include <mutex>
+#include <new>
+#include <condition_variable>
+#include <thread>
+#include <unordered_map>
+#include <vector>
+
+#include <fcntl.h>
+#include <signal.h>
+#include <sys/epoll.h>
+#include <sys/eventfd.h>
+#include <sys/socket.h>
+#include <time.h>
+#include <unistd.h>
+
+namespace {
+
+constexpr int RXW_MAX_SEGMENTS = 4;
+constexpr uint64_t RXW_WAKE = ~0ull;    // the wake eventfd's epoll datum
+constexpr int RXW_FIELDS = 6;           // u64s a completion, see rxw_reap
+
+struct RxJob {
+  uint64_t token;
+  int fd;                 // a dup, the worker's own: closed when let go
+  int notify_fd;          // the submitting loop's eventfd
+  uint8_t* dst;
+  std::atomic<uint64_t> have;   // bytes there (read by rxw_progress)
+  uint64_t len;           // bytes of the whole body
+  int nseg;               // 0: no crc (an onwire blob)
+  uint64_t seg_lens[RXW_MAX_SEGMENTS];
+  // the crc pass: everything before `crc_pos` is hashed or compared
+  uint64_t crc_pos = 0, seg_start = 0;
+  int seg = 0;
+  uint32_t crc = 0;
+  int bad = -1;           // first segment whose crc mismatched
+  uint64_t recvs = 0, cpu_ns = 0;
+  bool in_epoll = false;
+};
+
+struct RxDone {
+  uint64_t token, got, recvs, cpu_ns;
+  int64_t bad, status;    // status: 0 whole, -1 EOF, else errno
+};
+
+struct RxWorker {
+  std::mutex m;
+  std::condition_variable let_go;
+  std::unordered_map<uint64_t, RxJob*> jobs;   // submitted, not yet done
+  std::vector<uint64_t> fresh;                 // submitted, not yet seen
+  std::deque<RxDone> done;
+  uint64_t running = 0;   // the token the thread works on outside `m`
+  bool stop = false;
+  int epfd = -1, wakefd = -1;
+  std::thread thread;
+};
+
+RxWorker* g_rxw = nullptr;      // guarded by g_rxw_m
+std::mutex g_rxw_m;
+
+uint64_t thread_cpu_ns() {
+  struct timespec ts;
+  clock_gettime(CLOCK_THREAD_CPUTIME_ID, &ts);
+  return (uint64_t)ts.tv_sec * 1000000000ull + (uint64_t)ts.tv_nsec;
+}
+
+// Hash what has arrived and is not hashed yet; compare each segment's
+// trailing crc once its four bytes are there.
+void rxw_crc_advance(RxJob* j) {
+  const uint64_t have = j->have;
+  while (j->seg < j->nseg) {
+    uint64_t seg_end = j->seg_start + j->seg_lens[j->seg];
+    if (j->crc_pos < seg_end) {
+      uint64_t upto = have < seg_end ? have : seg_end;
+      if (upto <= j->crc_pos) return;
+      j->crc = crc32c(j->crc, j->dst + j->crc_pos, (size_t)(upto - j->crc_pos));
+      j->crc_pos = upto;
+      if (upto < seg_end) return;
+    }
+    if (have < seg_end + 4) return;
+    const uint8_t* p = j->dst + seg_end;
+    uint32_t want = (uint32_t)p[0] | ((uint32_t)p[1] << 8) |
+                    ((uint32_t)p[2] << 16) | ((uint32_t)p[3] << 24);
+    if (j->crc != want && j->bad < 0) j->bad = j->seg;
+    j->seg++;
+    j->seg_start = j->crc_pos = seg_end + 4;
+    j->crc = 0;
+  }
+}
+
+// The job's fd and buffer are the worker's no longer. Called under `m`.
+void rxw_release(RxWorker* w, RxJob* j) {
+  if (j->in_epoll) epoll_ctl(w->epfd, EPOLL_CTL_DEL, j->fd, nullptr);
+  close(j->fd);
+  w->jobs.erase(j->token);
+  delete j;
+}
+
+// Work on one job until its body is whole, the peer is gone, or the socket
+// is empty. `w->running` is the job's token: nobody else touches it.
+void rxw_work(RxWorker* w, RxJob* j) {
+  uint64_t t0 = thread_cpu_ns();
+  int64_t status = 1;               // 1: still waiting for bytes
+  if (j->have) rxw_crc_advance(j);  // the head the spill held
+  while (j->have < j->len) {
+    ssize_t r = recv(j->fd, j->dst + j->have, (size_t)(j->len - j->have),
+                     MSG_DONTWAIT);
+    if (r > 0) {
+      j->have += (uint64_t)r;
+      j->recvs++;
+      rxw_crc_advance(j);
+    } else if (r == 0) {
+      status = -1;
+      break;
+    } else if (errno == EINTR) {
+      continue;
+    } else if (errno == EAGAIN || errno == EWOULDBLOCK) {
+      break;
+    } else {
+      status = errno;
+      break;
+    }
+  }
+  if (j->have == j->len) status = 0;
+  if (status == 1 && !j->in_epoll) {
+    struct epoll_event ev;
+    ev.events = EPOLLIN;
+    ev.data.u64 = j->token;
+    if (epoll_ctl(w->epfd, EPOLL_CTL_ADD, j->fd, &ev) == 0)
+      j->in_epoll = true;
+    else
+      status = errno;
+  }
+  j->cpu_ns += thread_cpu_ns() - t0;
+  std::lock_guard<std::mutex> g(w->m);
+  w->running = 0;
+  if (status != 1) {
+    w->done.push_back(RxDone{j->token, j->have.load(), j->recvs, j->cpu_ns,
+                             (int64_t)j->bad, status});
+    // inside the lock: whoever reaps this completion may close the
+    // eventfd next, and cannot reap before the lock is free
+    uint64_t one = 1;
+    ssize_t wr = write(j->notify_fd, &one, 8);
+    (void)wr;
+    rxw_release(w, j);
+  }
+  w->let_go.notify_all();
+}
+
+// Take the job `token` for the thread, unless it was cancelled meanwhile.
+RxJob* rxw_take(RxWorker* w, uint64_t token) {
+  std::lock_guard<std::mutex> g(w->m);
+  auto it = w->jobs.find(token);
+  if (it == w->jobs.end()) return nullptr;
+  w->running = token;
+  return it->second;
+}
+
+void rxw_main(RxWorker* w) {
+  sigset_t all;           // the interpreter's signals are its own threads'
+  sigfillset(&all);
+  pthread_sigmask(SIG_BLOCK, &all, nullptr);
+  struct epoll_event evs[64];
+  std::vector<uint64_t> fresh;
+  for (;;) {
+    int n = epoll_wait(w->epfd, evs, 64, -1);
+    if (n < 0) {
+      if (errno == EINTR) continue;
+      return;
+    }
+    for (int i = 0; i < n; i++) {
+      uint64_t token = evs[i].data.u64;
+      if (token != RXW_WAKE) {
+        if (RxJob* j = rxw_take(w, token)) rxw_work(w, j);
+        continue;
+      }
+      uint64_t count;
+      ssize_t rd = read(w->wakefd, &count, 8);
+      (void)rd;
+      {
+        std::lock_guard<std::mutex> g(w->m);
+        if (w->stop) return;
+        fresh.swap(w->fresh);
+      }
+      for (uint64_t t : fresh)
+        if (RxJob* j = rxw_take(w, t)) rxw_work(w, j);
+      fresh.clear();
+    }
+  }
+}
+
+void rxw_close_fds(RxWorker* w) {
+  for (auto& kv : w->jobs) {
+    close(kv.second->fd);
+    delete kv.second;
+  }
+  w->jobs.clear();
+  if (w->epfd >= 0) close(w->epfd);
+  if (w->wakefd >= 0) close(w->wakefd);
+}
+
+}  // namespace
+
+extern "C" {
+
+// Start the thread if it does not run. 0, or -errno.
+int rxw_start() {
+  std::lock_guard<std::mutex> g(g_rxw_m);
+  if (g_rxw) return 0;
+  RxWorker* w = new RxWorker();
+  w->epfd = epoll_create1(EPOLL_CLOEXEC);
+  w->wakefd = eventfd(0, EFD_NONBLOCK | EFD_CLOEXEC);
+  struct epoll_event ev;
+  ev.events = EPOLLIN;
+  ev.data.u64 = RXW_WAKE;
+  if (w->epfd < 0 || w->wakefd < 0 ||
+      epoll_ctl(w->epfd, EPOLL_CTL_ADD, w->wakefd, &ev) != 0) {
+    int e = errno ? errno : EINVAL;
+    rxw_close_fds(w);
+    delete w;
+    return -e;
+  }
+  try {
+    w->thread = std::thread(rxw_main, w);
+  } catch (...) {
+    rxw_close_fds(w);
+    delete w;
+    return -EAGAIN;
+  }
+  g_rxw = w;
+  return 0;
+}
+
+// Stop the thread, drop every job (their buffers are the caller's again)
+// and close the worker's fds. The jobs still there, which the caller
+// should have cancelled: their count.
+int rxw_stop() {
+  std::lock_guard<std::mutex> g(g_rxw_m);
+  RxWorker* w = g_rxw;
+  if (!w) return 0;
+  g_rxw = nullptr;
+  {
+    std::lock_guard<std::mutex> g2(w->m);
+    w->stop = true;
+  }
+  uint64_t one = 1;
+  ssize_t wr = write(w->wakefd, &one, 8);
+  (void)wr;
+  w->thread.join();
+  int left = (int)w->jobs.size();
+  rxw_close_fds(w);
+  delete w;
+  return left;
+}
+
+// In the child of a fork, which has the worker's fds and not its thread:
+// close the two fds that are the worker's alone and start anew. Whatever
+// the parent's threads held or were changing at the fork is left alone
+// (the tables are not walked, the struct is leaked); a job's dup stays
+// open in the child as the socket it was made from does.
+void rxw_forked() {
+  new (&g_rxw_m) std::mutex();
+  RxWorker* w = g_rxw;
+  g_rxw = nullptr;
+  if (w) {
+    close(w->epfd);
+    close(w->wakefd);
+  }
+}
+
+// 1 while the thread runs.
+int rxw_running() {
+  std::lock_guard<std::mutex> g(g_rxw_m);
+  return g_rxw ? 1 : 0;
+}
+
+// Jobs submitted and not yet completed or cancelled.
+int rxw_jobs() {
+  std::lock_guard<std::mutex> g(g_rxw_m);
+  if (!g_rxw) return 0;
+  std::lock_guard<std::mutex> g2(g_rxw->m);
+  return (int)g_rxw->jobs.size();
+}
+
+// Hand the worker `fd` (it works on a dup) for the rest of a body of `len`
+// bytes at `dst`, of which `have` are there. `seg_lens[nseg]`: the frame's
+// segments, each followed by its crc32c in the body; nseg 0 means no crc.
+// `notify_fd` is written to when the completion can be reaped.
+// 0, or -errno.
+int rxw_submit(uint64_t token, int fd, uint8_t* dst, uint64_t have,
+               uint64_t len, const uint64_t* seg_lens, int nseg,
+               int notify_fd) {
+  if (nseg < 0 || nseg > RXW_MAX_SEGMENTS || have >= len) return -EINVAL;
+  std::lock_guard<std::mutex> g(g_rxw_m);
+  RxWorker* w = g_rxw;
+  if (!w) return -ESRCH;
+  int dupfd = fcntl(fd, F_DUPFD_CLOEXEC, 0);
+  if (dupfd < 0) return -errno;
+  RxJob* j = new RxJob();
+  j->token = token;
+  j->fd = dupfd;
+  j->notify_fd = notify_fd;
+  j->dst = dst;
+  j->have = have;
+  j->len = len;
+  j->nseg = nseg;
+  for (int i = 0; i < nseg; i++) j->seg_lens[i] = seg_lens[i];
+  {
+    std::lock_guard<std::mutex> g2(w->m);
+    w->jobs[token] = j;
+    w->fresh.push_back(token);
+  }
+  uint64_t one = 1;
+  ssize_t wr = write(w->wakefd, &one, 8);
+  (void)wr;
+  return 0;
+}
+
+// Take a job back. Returns once the thread has let go of its fd and buffer:
+// the bytes that are there (>= 0), or -1 if the job is not the worker's any
+// more (its completion is in the ring, or was reaped).
+int64_t rxw_cancel(uint64_t token) {
+  std::lock_guard<std::mutex> g(g_rxw_m);
+  RxWorker* w = g_rxw;
+  if (!w) return -1;
+  std::unique_lock<std::mutex> l(w->m);
+  w->let_go.wait(l, [&] { return w->running != token; });
+  auto it = w->jobs.find(token);
+  if (it == w->jobs.end()) return -1;
+  int64_t got = (int64_t)it->second->have.load();
+  rxw_release(w, it->second);
+  return got;
+}
+
+// Bytes of job `token`'s body that are there, or -1 if the worker has no
+// such job (any more).
+int64_t rxw_progress(uint64_t token) {
+  std::lock_guard<std::mutex> g(g_rxw_m);
+  RxWorker* w = g_rxw;
+  if (!w) return -1;
+  std::lock_guard<std::mutex> g2(w->m);
+  auto it = w->jobs.find(token);
+  return it == w->jobs.end() ? -1 : (int64_t)it->second->have.load();
+}
+
+// Up to `max` completions into `out`, six u64 each: token, bytes there,
+// recv calls that returned bytes, the thread's CPU ns on the job, the
+// first segment whose crc mismatched or -1, and the status (0 the body is
+// whole, -1 EOF, else the errno). Returns how many.
+int rxw_reap(uint64_t* out, int max) {
+  std::lock_guard<std::mutex> g(g_rxw_m);
+  RxWorker* w = g_rxw;
+  if (!w) return 0;
+  std::lock_guard<std::mutex> g2(w->m);
+  int n = 0;
+  while (n < max && !w->done.empty()) {
+    const RxDone& d = w->done.front();
+    uint64_t* o = out + n * RXW_FIELDS;
+    o[0] = d.token; o[1] = d.got; o[2] = d.recvs; o[3] = d.cpu_ns;
+    o[4] = (uint64_t)d.bad; o[5] = (uint64_t)d.status;
+    w->done.pop_front();
+    n++;
+  }
+  return n;
+}
+
+}  // extern "C"
+
+#endif  // __linux__

@@ -281,3 +281,220 @@ def test_a_parents_receiver_reads_the_changes_frames(both_codecs, n,
     # and the other way round: the parent's bytes through today's reader
     got = Frame.decode(_parent_encode(int(Tag.MESSAGE), flat))
     assert [bytes(s) for s in got.segments] == flat
+
+
+# -- the receive worker's native side (native/ec_native.cc rxw_*) -------------
+
+import ctypes  # noqa: E402
+import os  # noqa: E402
+import select  # noqa: E402
+import socket  # noqa: E402
+
+
+@pytest.fixture
+def rxw():
+    """The library with the worker's thread running, and stopped after."""
+    _native_or_skip()
+    from ceph_tpu.msg import rxworker
+    if not rxworker.available():
+        pytest.skip("the library has no receive worker (not Linux)")
+    assert not rxworker._ports, "an earlier test left the worker in use"
+    lib = rxworker._lib
+    assert lib.rxw_start() == 0 and lib.rxw_start() == 0    # idempotent
+    yield lib
+    assert lib.rxw_stop() == 0, "a job was left with the worker"
+    assert lib.rxw_running() == 0
+
+
+class _Wire:
+    """A socket pair, a body buffer and an eventfd: what a job needs."""
+
+    def __init__(self, n: int):
+        self.a, self.b = socket.socketpair()
+        self.a.setblocking(False)
+        self.buf = bytearray(b"\xEE" * n)
+        self.keep = ctypes.c_char.from_buffer(self.buf)
+        self.efd = os.eventfd(0, os.EFD_NONBLOCK)
+
+    def submit(self, lib, token, have, seg_lens):
+        lens = (ctypes.c_uint64 * len(seg_lens))(*seg_lens) \
+            if seg_lens else None
+        return lib.rxw_submit(token, self.b.fileno(),
+                              ctypes.addressof(self.keep), have,
+                              len(self.buf), lens, len(seg_lens), self.efd)
+
+    def reap(self, lib, timeout=10.0):
+        """[(token, got, recvs, cpu_ns, bad, status)] once woken."""
+        assert select.select([self.efd], [], [], timeout)[0], "no wake-up"
+        os.eventfd_read(self.efd)
+        out = (ctypes.c_int64 * 60)()
+        done, n = [], 10
+        while n == 10:      # the wake-up is for all that is in the ring
+            n = lib.rxw_reap(out, 10)
+            done += [tuple(out[i:i + 6]) for i in range(0, 6 * n, 6)]
+        return done
+
+    def close(self):
+        del self.keep
+        self.a.close()
+        self.b.close()
+        os.close(self.efd)
+
+
+def _feed(sock, data: bytes, chunks) -> None:
+    off = 0
+    for c in chunks:
+        if off >= len(data):
+            break
+        end = min(len(data), off + c)
+        while off < end:
+            try:
+                off += sock.send(data[off:end])
+            except BlockingIOError:
+                select.select([], [sock], [], 1.0)
+    while off < len(data):
+        try:
+            off += sock.send(data[off:])
+        except BlockingIOError:
+            select.select([], [sock], [], 1.0)
+
+
+_SEGS = [[700_000], [1, 300_000, 0, 5], [0, 0, 262_144], [65_536] * 4]
+
+
+@pytest.mark.parametrize("bad_seg", [-1, 0, "last"])
+@pytest.mark.parametrize("have", [0, 1, 4093, "into_the_second"])
+@pytest.mark.parametrize("seg_lens", _SEGS, ids=lambda s: "x".join(map(str, s)))
+def test_the_worker_checks_what_verify_body_checks(rxw, seg_lens, have,
+                                                   bad_seg):
+    """crc chained over arbitrary recv sizes and over a head that was
+    there before the job: the first bad segment, or -1, as
+    `frame_verify_body` says of the same bytes; and the body is the
+    sender's."""
+    from ceph_tpu.native import frame_native
+    rng = random.Random(hash((tuple(seg_lens), str(have), str(bad_seg))))
+    blob = bytearray(Frame(Tag.MESSAGE, [rng.randbytes(n) for n in seg_lens]
+                           ).encode())
+    body = blob[8 + 4 * len(seg_lens):]
+    if bad_seg == "last":
+        bad_seg = len(seg_lens) - 1
+    if bad_seg >= 0:
+        # a bit of the segment's bytes, or of its crc where it has none
+        at = sum(n + 4 for n in seg_lens[:bad_seg])
+        body[at + (seg_lens[bad_seg] // 2 if seg_lens[bad_seg] else 2)] ^= 4
+    want = frame_native.verify_body(bytes(body), seg_lens)
+    assert want == bad_seg
+    if have == "into_the_second":
+        have = min(seg_lens[0] + 4 + 2, len(body) - 1)
+    w = _Wire(len(body))
+    try:
+        w.buf[:have] = body[:have]
+        assert w.submit(rxw, 11, have, seg_lens) == 0
+        _feed(w.a, bytes(body[have:]) + b"NEXT",
+              [rng.randrange(1, 100_000) for _ in range(50)])
+        (done,) = w.reap(rxw)
+        token, got, recvs, cpu_ns, bad, status = done
+        assert (token, got, bad, status) == (11, len(body), want, 0)
+        assert recvs >= 1 and cpu_ns > 0
+        assert w.buf == body
+        # not one byte too many: the next frame's are still the socket's
+        assert w.b.recv(100) == b"NEXT"
+        assert rxw.rxw_jobs() == 0
+    finally:
+        w.close()
+
+
+@pytest.mark.parametrize("how", ["eof", "cancel", "cancel_unstarted",
+                                 "cancel_done", "no_crc"])
+def test_a_job_ends_once_and_lets_go_of_fd_and_buffer(rxw, how):
+    n = 300_000
+    fds = len(os.listdir("/proc/self/fd"))
+    w = _Wire(n)
+    try:
+        assert w.submit(rxw, 5, 0, [] if how == "no_crc" else [n - 4]) == 0
+        if how == "cancel_unstarted":
+            assert rxw.rxw_cancel(5) == 0
+        else:
+            w.a.send(b"z" * 1000)
+            while rxw.rxw_progress(5) < 1000:
+                pass
+        if how == "eof":
+            w.a.close()
+            (done,) = w.reap(rxw)
+            assert done[:3] + done[4:] == (5, 1000, 1, -1, -1)
+        elif how == "cancel":
+            assert rxw.rxw_cancel(5) == 1000
+            assert rxw.rxw_cancel(5) == -1          # once
+        elif how in ("cancel_done", "no_crc"):
+            _feed(w.a, b"z" * (n - 1000), [n])
+            while rxw.rxw_progress(5) >= 0:
+                pass
+            if how == "cancel_done":
+                assert rxw.rxw_cancel(5) == -1      # the ring has it
+            (done,) = w.reap(rxw)
+            # `z`s are no crc of `z`s: segment 0 is bad, unless none is
+            # checked
+            assert done[:2] + done[4:] == \
+                (5, n, -1 if how == "no_crc" else 0, 0)
+        assert rxw.rxw_jobs() == 0 and rxw.rxw_progress(5) == -1
+        # the buffer is the caller's: what is written now stays
+        w.buf[1000:] = bytes(n - 1000)
+        if how not in ("eof", "cancel_done", "no_crc"):
+            w.a.send(b"late")
+        assert w.buf[1000:] == bytes(n - 1000)
+    finally:
+        w.close()
+    assert len(os.listdir("/proc/self/fd")) == fds
+
+
+def test_submit_refuses_what_it_cannot_do(rxw):
+    import errno
+    w = _Wire(1000)
+    try:
+        assert w.submit(rxw, 1, 1000, []) == -errno.EINVAL     # nothing left
+        assert w.submit(rxw, 1, 0, [1] * 5) == -errno.EINVAL   # 5 segments
+        fd = w.b.fileno()
+        w.b.close()
+        lens = (ctypes.c_uint64 * 1)(996)
+        assert rxw.rxw_submit(1, fd, ctypes.addressof(w.keep), 0, 1000,
+                              lens, 1, w.efd) == -errno.EBADF
+        assert rxw.rxw_jobs() == 0
+        assert rxw.rxw_stop() == 0
+        assert w.submit(rxw, 1, 0, []) == -errno.ESRCH         # no thread
+        assert rxw.rxw_cancel(1) == -1 and rxw.rxw_reap(None, 0) == 0
+    finally:
+        del w.keep
+        w.a.close()
+        os.close(w.efd)
+
+
+def test_sixty_jobs_at_once_share_the_thread_and_the_wake_ups(rxw):
+    """Completions queue in the ring; one reap call drains them all."""
+    n = 200_000
+    wires = [_Wire(n) for _ in range(60)]
+    efd = wires[0].efd
+    rng = random.Random(5)
+    bodies = []
+    try:
+        for i, w in enumerate(wires):
+            body = bytes(Frame(Tag.MESSAGE, [rng.randbytes(n - 4)]
+                               ).encode())[12:]
+            bodies.append(body)
+            lens = (ctypes.c_uint64 * 1)(n - 4)
+            assert rxw.rxw_submit(100 + i, w.b.fileno(),
+                                  ctypes.addressof(w.keep), 0, n, lens, 1,
+                                  efd) == 0
+        for half in (0, 1):
+            for w, body in zip(wires, bodies):
+                _feed(w.a, body[half * n // 2:(half + 1) * n // 2], [n])
+        got = {}
+        while len(got) < len(wires):
+            for d in wires[0].reap(rxw):
+                got[d[0]] = d
+        assert sorted(got) == list(range(100, 160))
+        assert all(d[1] == n and d[4] == -1 and d[5] == 0
+                   for d in got.values())
+        assert all(w.buf == b for w, b in zip(wires, bodies))
+    finally:
+        for w in wires:
+            w.close()

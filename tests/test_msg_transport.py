@@ -7,6 +7,7 @@ from __future__ import annotations
 import asyncio
 import os
 import random
+import socket
 import zlib
 
 import pytest
@@ -16,7 +17,7 @@ from ceph_tpu.msg.frames import (Frame, FrameError, Onwire, Tag,
                                  encode_trace_ctx)
 from ceph_tpu.msg.messages import MOSDECSubOpWrite, MPing
 from ceph_tpu.msg.messenger import Messenger, Policy, msgr_perf
-from ceph_tpu.msg import transport
+from ceph_tpu.msg import rxworker, transport
 from ceph_tpu.msg.transport import NARROW, SPILL_SIZE, Endpoint
 
 from tests.test_msg import Collector
@@ -61,6 +62,9 @@ class _FakeTransport:
 
     def close(self):
         self.closing = True
+
+    def get_extra_info(self, name, default=None):
+        return default      # no socket: nothing the receive worker can have
 
 
 class _Perf:
@@ -396,7 +400,7 @@ def test_eof_or_abort_in_mid_body_raises_in_the_pending_read(how):
         read = asyncio.create_task(srv.readexactly(n))
         cli.write(b"x" * (n // 2))
         await cli.drain()
-        while srv._dest is None or srv._dest_pos < n // 2:
+        while srv.body_filled() < n // 2:
             await asyncio.sleep(0.01)
             assert not read.done()
         if how == "eof":
@@ -472,6 +476,18 @@ def test_drain_waits_for_the_peer_and_fails_when_the_transport_is_lost():
 
 # -- sessions on top ---------------------------------------------------------------
 
+def _slow_wire(conn) -> None:
+    """A small send buffer on `conn`'s socket (on each new one after a
+    reconnect: call it again), so that a body crosses in many sends, a
+    turn of the loop apart, and whoever receives it, the transport or
+    the receive worker, is seen with it half received."""
+    ep = conn._writer
+    if ep is not None and not getattr(ep, "slowed", False):
+        ep.get_extra_info("socket").setsockopt(
+            socket.SOL_SOCKET, socket.SO_SNDBUF, 131072)
+        ep.slowed = True
+
+
 async def _wait_for(col: Collector, n: int) -> None:
     while len(col.messages) < n:
         col.got.clear()
@@ -528,10 +544,10 @@ def test_lossless_pair_survives_aborts_in_mid_body(codec, side):
             nonlocal aborts
             while aborts < 3:
                 await asyncio.sleep(0)
+                _slow_wire(conn)
                 for c in list(server._sessions.values()):
                     ep = c._reader
-                    if ep is None or ep._dest is None or \
-                            ep._dest_pos < 100000:
+                    if ep is None or ep.body_filled() < 100000:
                         continue
                     aborts += 1
                     victims = {"initiator": [conn], "acceptor": [c],
@@ -668,3 +684,443 @@ def test_radoslint_sees_the_two_views_the_endpoint_keeps(tmp_path):
     assert sorted((f.rule, f.message.split(" stored on ")[1].split(":")[0])
                   for f in found) == [("view-escape", "self._dest"),
                                       ("view-escape", "self._spill_mv")]
+
+
+# -- the receive worker (msg/rxworker.py): a large body crosses the socket on
+# -- a native thread, and everything above holds with it and without it -------
+
+LINE = rxworker.LINE
+
+
+@pytest.fixture(params=["worker", "no_native"])
+def rx(request, monkeypatch):
+    """With the receive worker, and with the native library made
+    unavailable: the endpoint's own path then serves the same cases."""
+    if request.param == "no_native":
+        monkeypatch.setattr(rxworker, "_checked", True)
+        monkeypatch.setattr(rxworker, "_lib", None)
+    elif not rxworker.available():
+        pytest.skip("the native library is not built here")
+    return request.param
+
+
+def _fds() -> int:
+    return len(os.listdir("/proc/self/fd"))
+
+
+def _threads() -> int:
+    return len(os.listdir("/proc/self/task"))
+
+
+def _worker_is_gone() -> None:
+    assert not rxworker._jobs and not rxworker._ports
+    assert not rxworker.running()
+    if rxworker.available():
+        assert rxworker._lib.rxw_jobs() == 0
+
+
+def _worker_delta(before: dict) -> dict:
+    now = msgr_perf().dump()
+    return {k: now[k] - before[k] for k in now if k.startswith("rx_")}
+
+
+@pytest.mark.parametrize("head", ["head_in_spill", "no_head"])
+@pytest.mark.parametrize("n", [LINE - 1, LINE, LINE + 1],
+                         ids=["under", "at", "over"])
+def test_a_body_round_the_line_is_the_senders_bytes(rx, n, head):
+    """The body's length alone says who receives it; whoever does, it is
+    the sender's bytes, and the small frame behind it is intact."""
+    payload = os.urandom(n - 4)
+    blob = Frame(Tag.MESSAGE, [payload]).encode()
+    assert len(blob) == 12 + n
+    small = Frame(Tag.ACK, [b"[7]"]).encode()
+
+    async def main():
+        srv, cli, server = await _socket_pair()
+        before = dict(msgr_perf().dump())
+        read = asyncio.create_task(Frame.read(srv))
+        if head == "no_head":
+            cli.write(blob[:12])
+            while srv.body_filled() != 0:
+                await asyncio.sleep(0.005)
+            cli.write(blob[12:] + small)
+        else:
+            cli.write(blob + small)
+        got = await read
+        ack = await Frame.read(srv)
+        d = _worker_delta(before)
+        await _close(server, srv, cli)
+        return got, ack, d
+
+    got, ack, d = run(main())
+    assert bytes(got.segments[0]) == payload
+    assert (ack.tag, bytes(ack.segments[0])) == (Tag.ACK, b"[7]")
+    worked = rx == "worker" and n >= LINE
+    assert d["rx_worker_bodies"] == (1 if worked else 0)
+    if head == "no_head":
+        assert d["rx_worker_bytes"] == (n if worked else 0)
+    else:
+        assert (0 < n - d["rx_worker_bytes"] <= NARROW) if worked \
+            else d["rx_worker_bytes"] == 0
+    assert d["rx_direct_bytes"] + d["rx_spill_bytes"] == len(blob) + len(small)
+    _worker_is_gone()
+
+
+def test_the_worker_takes_not_one_byte_past_the_bodys_end(rx):
+    """Large bodies and small frames written in one go: every small
+    frame behind a body is read whole after it."""
+    rng = random.Random(3)
+    frames_ = []
+    for i in range(12):
+        frames_.append(Frame(Tag.MESSAGE,
+                             [b"h%d" % i, rng.randbytes(LINE + rng.randrange(
+                                 3 * LINE)), b"t"]))
+        frames_.append(Frame(Tag.ACK, [b"[%d]" % i]))
+    wire = b"".join(bytes(f.encode()) for f in frames_)
+
+    async def main():
+        srv, cli, server = await _socket_pair()
+        cli.write(wire)
+        got = [await Frame.read(srv) for _ in frames_]
+        await _close(server, srv, cli)
+        return got
+
+    got = run(main())
+    assert [(g.tag, [bytes(s) for s in g.segments]) for g in got] == \
+        [(f.tag, [bytes(s) for s in f.segments]) for f in frames_]
+    _worker_is_gone()
+
+
+@pytest.mark.parametrize("seg", [0, 1, 2])
+def test_a_bit_flipped_on_the_wire_above_the_line_faults_the_read(rx, seg):
+    """Every segment's crc is checked before the frame is parsed, by
+    whoever received the body."""
+    segs = [os.urandom(LINE), os.urandom(2 * LINE), os.urandom(LINE // 2)]
+    blob = bytearray(Frame(Tag.MESSAGE, segs).encode())
+    at = 8 + 4 * 3 + sum(len(s) + 4 for s in segs[:seg]) + 1000
+    blob[at] ^= 0x10
+
+    async def main():
+        srv, cli, server = await _socket_pair()
+        cli.write(bytes(blob))
+        with pytest.raises(FrameError, match="segment crc mismatch"):
+            await Frame.read(srv)
+        await _close(server, srv, cli)
+
+    run(main())
+    _worker_is_gone()
+
+
+class _FlipOnce:
+    """A relay in front of `addr` that flips one bit of the bytes going
+    to it, `at` bytes into the first connection, and is honest after."""
+
+    def __init__(self, addr, at):
+        self.addr, self.at, self.flipped = addr, at, 0
+        self._tasks: set = set()
+
+    async def start(self):
+        self.server = await asyncio.start_server(self._serve, "127.0.0.1", 0)
+        return self.server.sockets[0].getsockname()[:2]
+
+    async def _serve(self, r, w):
+        self._tasks.add(asyncio.current_task())
+        ur, uw = await asyncio.open_connection(*self.addr)
+        first = not self.flipped
+
+        async def pump(src, dst, flip):
+            seen = 0
+            try:
+                while data := await src.read(1 << 16):
+                    if flip and seen <= self.at < seen + len(data) \
+                            and not self.flipped:
+                        data = bytearray(data)
+                        data[self.at - seen] ^= 0x01
+                        self.flipped += 1
+                    seen += len(data)
+                    dst.write(data)
+                    await dst.drain()
+            except (ConnectionError, asyncio.CancelledError):
+                pass
+            finally:
+                dst.close()
+
+        await asyncio.gather(pump(r, uw, first), pump(ur, w, False))
+
+    async def stop(self):
+        self.server.close()
+        for t in list(self._tasks):
+            t.cancel()
+        await asyncio.gather(*self._tasks, return_exceptions=True)
+
+
+def test_a_flipped_bit_faults_the_connection_and_the_session_replays(rx):
+    """As today: the crc mismatch is a fault of the transport, the
+    lossless session reconnects, and every message arrives once."""
+    N = 5
+
+    async def main():
+        server = Messenger("osd.1")
+        col = Collector()
+        server.add_dispatcher(col)
+        addr = await server.bind()
+        relay = _FlipOnce(addr, 3 * (1 << 20))
+        client = Messenger("osd.2")
+        conn = await client.connect(await relay.start(),
+                                    Policy.lossless_peer())
+        datas = [os.urandom((1 << 20) + i) for i in range(N)]
+        for i, d in enumerate(datas):
+            conn.send_message(MOSDECSubOpWrite({"i": i}, d))
+        await _wait_for(col, N)
+        assert relay.flipped == 1
+        assert [m.payload["i"] for m in col.messages] == list(range(N))
+        assert all(m.data == d for m, d in zip(col.messages, datas))
+        await client.shutdown()
+        await server.shutdown()
+        await relay.stop()
+
+    run(main(), timeout=90)
+    _worker_is_gone()
+
+
+@pytest.mark.filterwarnings("ignore:unclosed:ResourceWarning")
+@pytest.mark.parametrize("how", ["close", "cancelled_read", "abort",
+                                 "peer_closes", "loop_dies"])
+def test_a_body_given_up_half_received_leaves_nothing_behind(how):
+    """No job, no fd and no thread are left, and the worker does not
+    write into the buffer it was handed once the endpoint has it back
+    (the body's unfilled half stays as the test left it)."""
+    if not rxworker.available():
+        pytest.skip("the native library is not built here")
+    n = 4 << 20
+    fds0 = _fds()
+
+    async def main():
+        srv, cli, server = await _socket_pair()
+        before = dict(msgr_perf().dump())
+        read = asyncio.create_task(srv.readexactly(n))
+        cli.write(b"x" * (n // 2))
+        while srv.body_filled() < n // 2:
+            await asyncio.sleep(0.005)
+        job = srv._job
+        buf = job.buf
+        assert rxworker._lib.rxw_jobs() == 1
+        if how == "loop_dies":
+            return buf      # nothing is closed, the read is cancelled
+        if how == "close":
+            srv.close()
+        elif how == "cancelled_read":
+            read.cancel()
+        elif how == "abort":
+            srv.transport.abort()
+        else:
+            cli.close()
+        with pytest.raises((asyncio.IncompleteReadError, ConnectionError,
+                            asyncio.CancelledError)):
+            await read
+        assert rxworker._lib.rxw_jobs() == 0 and not rxworker._jobs
+        del job
+        buf[n // 2:] = bytes(n // 2)
+        cli.write(b"y" * 100000) if how != "peer_closes" else None
+        await asyncio.sleep(0.05)
+        assert buf[n // 2:] == bytes(n // 2) and buf[:n // 2] == \
+            b"x" * (n // 2)
+        d = _worker_delta(before)
+        assert d["rx_worker_cancelled"] == (0 if how == "peer_closes" else 1)
+        assert d["rx_worker_bytes"] == n // 2
+        await _close(server, srv, cli)
+        return buf
+
+    buf = run(main())
+    if how == "loop_dies":
+        # the cancelled read took its job back; the endpoints were never
+        # closed, so their loop's port stands until another loop asks
+        assert rxworker._lib.rxw_jobs() == 0 and not rxworker._jobs
+
+        async def again():
+            srv, cli, server = await _socket_pair()
+            cli.write(bytes(LINE))
+            assert await srv.readexactly(LINE) == bytes(LINE)
+            await _close(server, srv, cli)
+        import gc
+        gc.collect()        # the dead loop's transports close their sockets
+        run(again())
+    del buf
+    _worker_is_gone()
+    assert _fds() <= fds0
+
+
+@pytest.mark.parametrize("seed", range(5))
+def test_a_reconnect_storm_loses_nothing_and_leaves_nothing(rx, seed):
+    """Lossless sessions under aborts in mid-body from either end, with
+    duplicated and delayed messages injected (qa/faultinject): every
+    message arrives once, in order, byte for byte, and when the
+    messengers are shut down no job, fd or thread of the worker is
+    left."""
+    from ceph_tpu.qa import faultinject
+    N = 24
+    fds0, threads0 = _fds(), _threads()
+
+    async def main():
+        rng = random.Random(seed)
+        server = Messenger("osd.1")
+        col = Collector()
+        server.add_dispatcher(col)
+        addr = await server.bind()
+        client = Messenger("osd.2")
+        back = Collector()
+        client.add_dispatcher(back)
+        conn = await client.connect(addr, Policy.lossless_peer())
+        datas = [rng.randbytes(rng.choice([LINE - 5, LINE, 3 * LINE, 1 << 20])
+                               + i) for i in range(N)]
+        aborts = 0
+
+        def arrived() -> int:       # an injected dup is dispatched twice
+            return len({m.payload["i"] for m in col.messages})
+
+        async def storm():
+            nonlocal aborts
+            while arrived() < N:
+                await asyncio.sleep(0)
+                _slow_wire(conn)
+                for c in list(server._sessions.values()):
+                    ep = c._reader
+                    if aborts >= 6 or ep is None or \
+                            ep.body_filled() < rng.randrange(LINE // 4):
+                        continue
+                    aborts += 1
+                    for v in rng.choice([[conn], [c], [conn, c]]):
+                        if v._writer is not None:
+                            v._writer.transport.abort()
+                    await asyncio.sleep(rng.random() * 0.02)
+                    break
+
+        inj = faultinject.get_injector()
+        inj.reset(seed)
+        was = inj.msg_dup, inj.msg_delay, inj.msg_delay_ms
+        inj.msg_dup, inj.msg_delay, inj.msg_delay_ms = 0.1, 0.1, 5.0
+        faultinject.set_enabled(True)
+        try:
+            stormer = asyncio.create_task(storm())
+            for i, d in enumerate(datas):
+                conn.send_message(MOSDECSubOpWrite({"i": i}, d))
+                if i % 5 == 0:
+                    await asyncio.sleep(0.01)
+            await asyncio.wait_for(stormer, 60)
+        finally:
+            faultinject.set_enabled(False)
+            inj.msg_dup, inj.msg_delay, inj.msg_delay_ms = was
+            inj.reset(0)
+        got = {}
+        for m in col.messages:
+            got.setdefault(m.payload["i"], m)
+        assert sorted(got) == list(range(N))
+        assert all(got[i].data == d for i, d in enumerate(datas))
+        assert aborts >= 1
+        await client.shutdown()
+        await server.shutdown()
+
+    run(main(), timeout=120)
+    _worker_is_gone()
+    assert _fds() <= fds0 and _threads() <= threads0
+
+
+@pytest.mark.parametrize("mode", _MODES)
+def test_onwire_sessions_carry_a_4mib_message(rx, mode):
+    """A secure or compressed session's envelope is one read of its
+    length: above the line the worker receives it, and verifies no crc
+    (the envelope has none; GCM and the inner frame's crcs do)."""
+    async def main():
+        key = b"k" * 16
+        server = Messenger("osd.1", auth_key=key, **mode)
+        col = Collector()
+        server.add_dispatcher(col)
+        addr = await server.bind()
+        client = Messenger("osd.2", auth_key=key, **mode)
+        conn = await client.connect(addr, Policy.lossless_peer())
+        data = os.urandom(4 << 20)
+        before = dict(msgr_perf().dump())
+        conn.send_message(MOSDECSubOpWrite({"i": 0}, data))
+        conn.send_message(MPing({"i": 1}))
+        await _wait_for(col, 2)
+        d = _worker_delta(before)
+        assert bytes(col.messages[0].data) == data
+        assert col.messages[1].payload["i"] == 1
+        await client.shutdown()
+        await server.shutdown()
+        return d
+
+    d = run(main())
+    if rx == "worker":
+        assert d["rx_worker_bodies"] == 1
+        assert d["rx_worker_bytes"] >= (4 << 20) - NARROW
+    else:
+        assert d["rx_worker_bodies"] == d["rx_worker_bytes"] == 0
+    _worker_is_gone()
+
+
+def test_no_fd_and_no_thread_outlives_the_last_messenger():
+    if not rxworker.available():
+        pytest.skip("the native library is not built here")
+    fds0, threads0 = _fds(), _threads()
+
+    async def main():
+        a, b = Messenger("osd.1"), Messenger("osd.2")
+        col = Collector()
+        a.add_dispatcher(col)
+        conn = await b.connect(await a.bind(), Policy.lossless_peer())
+        conn.send_message(MOSDECSubOpWrite({"i": 0}, bytes(1 << 20)))
+        await _wait_for(col, 1)
+        assert rxworker.running() and _threads() == threads0 + 1
+        await b.shutdown()
+        # the acceptor's endpoint used the worker, and it still stands
+        assert rxworker.running()
+        await a.shutdown()
+        assert not rxworker.running()
+
+    run(main())
+    _worker_is_gone()
+    assert _fds() <= fds0 and _threads() == threads0
+
+
+@pytest.mark.filterwarnings("ignore:This process .* is multi-threaded")
+def test_a_forked_child_starts_a_worker_of_its_own():
+    if not rxworker.available():
+        pytest.skip("the native library is not built here")
+
+    async def body_through_worker(keep: list):
+        srv, cli, server = await _socket_pair()
+        before = dict(msgr_perf().dump())
+        data = os.urandom(2 * LINE)
+        cli.write(data)
+        assert await srv.readexactly(len(data)) == data
+        assert _worker_delta(before)["rx_worker_bodies"] == 1
+        keep += [srv, cli, server]
+
+    loop = asyncio.new_event_loop()
+    keep: list = []
+    try:
+        loop.run_until_complete(body_through_worker(keep))
+        assert rxworker.running()       # the parent's, its endpoints open
+        pid = os.fork()
+        if pid == 0:
+            code = 1
+            try:
+                assert not rxworker.running() and not rxworker._ports
+                asyncio.run(asyncio.wait_for(body_through_worker([]), 30))
+                assert rxworker.running()
+                code = 0
+            finally:
+                os._exit(code)
+        assert os.waitpid(pid, 0)[1] == 0
+        # and the parent's worker still serves the parent
+
+        async def again():
+            srv, cli = keep[0], keep[1]
+            cli.write(bytes(LINE))
+            assert await srv.readexactly(LINE) == bytes(LINE)
+            await _close(keep[2], srv, cli)
+        loop.run_until_complete(again())
+    finally:
+        loop.close()
+    _worker_is_gone()

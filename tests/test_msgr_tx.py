@@ -20,7 +20,8 @@ from ceph_tpu.msg.messenger import Messenger, Policy, msgr_perf
 from ceph_tpu.msg.transport import SPILL_SIZE, Endpoint
 
 from tests.test_msg import Collector
-from tests.test_msg_transport import _wait_for, codec, run  # noqa: F401
+from tests.test_msg_transport import (_slow_wire, _wait_for, codec,  # noqa: F401
+                                      run)
 
 TX = ("tx_direct_bytes", "tx_copied_bytes")
 
@@ -265,10 +266,10 @@ def test_a_reset_in_the_middle_of_a_4mib_frame_replays_the_same_bytes(
             nonlocal aborts
             while aborts < 2:
                 await asyncio.sleep(0)
+                _slow_wire(conn)
                 for c in list(server._sessions.values()):
                     ep = c._reader
-                    if ep is None or ep._dest is None or \
-                            ep._dest_pos < 1 << 20:
+                    if ep is None or ep.body_filled() < 1 << 20:
                         continue
                     aborts += 1
                     conn._writer.transport.abort()

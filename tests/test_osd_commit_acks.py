@@ -17,9 +17,19 @@ from ceph_tpu.objectstore.store import Op, StoreError, Transaction
 from ceph_tpu.osd.pg import PGMETA_OID
 from ceph_tpu.osd.pglog import LogEntry, PGLog
 from ceph_tpu.rados import RadosClient
+from ceph_tpu.utils import crash
 
 from tests.test_bluestore_commit import Syncs, syncs  # noqa: F401
 from tests.test_cluster import ClusterHarness, fast_timers, run  # noqa: F401
+
+
+@pytest.fixture(autouse=True)
+def no_crash_records_left():
+    """A daemon whose store is killed here posts a crash record, and the
+    registry is the process's: a later test in this worker that reads
+    health (tests/test_mgr_report.py) would find RECENT_CRASH."""
+    yield
+    crash.reset()
 
 SUB_WRITES = {"MOSDECSubOpWrite", "MOSDRepOp"}
 SUB_REPLIES = {"MOSDECSubOpWriteReply", "MOSDRepOpReply"}
