@@ -51,6 +51,13 @@ class KeyValueDB:
                            sync: bool = True) -> None:
         raise NotImplementedError
 
+    def maintain(self) -> None:
+        """Do the upkeep that is due (a flush, a compaction), on the
+        writer's thread. No submit does it: the owner calls this
+        between submits, where nobody waits on it (BlueStore: once a
+        group's acknowledgements have left). Nothing, for an engine that
+        has none."""
+
     def get(self, prefix: str, key: str) -> bytes | None:
         raise NotImplementedError
 

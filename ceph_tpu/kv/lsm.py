@@ -5,10 +5,16 @@ Re-creation of the reference's RocksDBStore essentials
 log-structured merge engine:
 
   * every batch is appended to a crc-framed WAL and fsync'd before it
-    is acknowledged (rocksdb WriteBatch + WAL semantics);
+    is acknowledged (rocksdb WriteBatch + WAL semantics). A record is
+    binary: a value costs its own length on the log and a few bytes of
+    framing, whatever its bytes are (BlueStore's deferred writes ride
+    here: a shard's 8 KiB are 8 KiB of log);
   * the memtable absorbs writes; when it exceeds the flush threshold it
     is written out as an immutable sorted-run file (SSTable role) and
-    the WAL is truncated;
+    the WAL is truncated. A key that was set AND deleted inside one
+    memtable's life, with no older run holding it, leaves nothing: no
+    value and no tombstone reaches a run (a deferred write's record
+    lives a fraction of a second);
   * lookups go memtable -> runs newest-to-oldest; deletes are
     tombstones that shadow older runs;
   * when the run count exceeds the compaction trigger, runs are merged
@@ -18,15 +24,18 @@ log-structured merge engine:
     mid-flush/mid-compaction falls back to the previous run set plus
     WAL replay.
 
-Threads: one writer, any readers. `submit_transaction`, and with it a
-memtable flush or a compaction that falls due, runs on whatever thread
-calls it (BlueStore's commit thread); `get` and `iterate` may run on
-another meanwhile. `_lock` is held only while tables change hands or
-are iterated, never across file I/O.
+Threads: one writer, any readers. `submit_transaction` appends, syncs
+and applies, and never flushes or compacts: the owner calls
+`maintain()` on the same thread between submits, where nobody waits on
+it (BlueStore's commit thread, once a group's acknowledgements have
+left). `get` and `iterate` may run on another thread meanwhile. `_lock`
+is held only while tables change hands or are iterated, never across
+file I/O. A flush and a compaction each leave a
+span (`kv_flush`, `kv_compact`) from the thread that ran them.
 
 Idiomatic divergences: runs are loaded into memory at open (block
 cache = whole-file residency — state here is control-plane-sized);
-values are latin1-mapped JSON rather than varint-framed blocks.
+records and runs are length-framed, not varint-framed blocks.
 """
 from __future__ import annotations
 
@@ -34,12 +43,23 @@ import json
 import os
 import struct
 import threading
+import time
 
 from ceph_tpu.kv.keyvaluedb import KeyValueDB, KVTransaction
+from ceph_tpu.utils import tracer
 from ceph_tpu.utils.crash import SimulatedCrash  # noqa: F401 (re-export)
 
 _TOMB = None          # tombstone marker inside tables
 _MISSING = object()   # no entry in a table, where None is a tombstone
+_WAL_V = b"\x02"      # a log record's first byte: binary ops follow
+_RUN_MAGIC = b"RUN2"  # a sorted run's first bytes, after its crc
+#: one op of a log record: kind, and the lengths of its prefix, key and
+#: value (-1: it has none); the three follow
+_OP = struct.Struct("<BHIi")
+_KINDS = ("set", "rm", "rmprefix")
+#: one entry of a sorted run: the lengths of its key and of its value
+#: (-1: a tombstone); the two follow
+_ENTRY = struct.Struct("<Ii")
 
 
 def _crc32c(data: bytes) -> int:
@@ -55,9 +75,46 @@ def _fsync_dir(path: str) -> None:
         os.close(fd)
 
 
+def _encode_ops(ops: list[tuple]) -> bytes:
+    """A batch as one log record's body."""
+    parts = [_WAL_V]
+    for op in ops:
+        prefix = op[1].encode()
+        key = op[2].encode() if len(op) > 2 else b""
+        value = op[3] if len(op) > 3 else None
+        parts += (_OP.pack(_KINDS.index(op[0]), len(prefix), len(key),
+                           -1 if value is None else len(value)),
+                  prefix, key, value or b"")
+    return b"".join(parts)
+
+
+def _decode_ops(rec: bytes):
+    """The ops of a log record's body, as `KVTransaction.ops` has them."""
+    if rec[:1] != _WAL_V:
+        raise IOError("wal.log: a record of another format (written by "
+                      "an older program?)")
+    at = 1
+    while at < len(rec):
+        kind, np, nk, nv = _OP.unpack_from(rec, at)
+        at += _OP.size
+        prefix = rec[at:at + np].decode()
+        key = rec[at + np:at + np + nk].decode()
+        at += np + nk
+        if kind == 0:
+            yield ("set", prefix, key, rec[at:at + nv])
+            at += nv
+        else:
+            yield (_KINDS[kind], prefix, key)
+
+
 class LSMStore(KeyValueDB):
 
     FLUSH_BYTES = 4 * 1024 * 1024     # memtable flush threshold
+    #: the log's own threshold, in memtables: values that were set and
+    #: deleted again (deferred writes) fill the log and not the
+    #: memtable, and a mount replays the log whole (rocksdb's
+    #: max_total_wal_size role)
+    WAL_FLUSHES = 4
     COMPACT_RUNS = 6                  # full-compaction trigger
 
     def __init__(self, path: str, flush_bytes: int | None = None):
@@ -67,9 +124,14 @@ class LSMStore(KeyValueDB):
         # "prefix\x00key" -> bytes | None(tombstone)
         self._memtable: dict[str, bytes | None] = {}
         self._mem_bytes = 0
+        #: keys of the memtable that no run holds: deleted, such a key
+        #: leaves no tombstone behind
+        self._born: set[str] = set()
+        self._born_dropped = 0          # of them, deleted since the flush
         self._runs: list[dict[str, bytes | None]] = []   # newest first
         self._run_files: list[str] = []
         self._wal = None
+        self._wal_bytes = 0
         self._next_file = 1
         self.fail_after_wal = False     # SimulatedCrash hook
         self._lock = threading.Lock()
@@ -93,6 +155,7 @@ class LSMStore(KeyValueDB):
             self._runs = [self._load_run(fn) for fn in self._run_files]
         self._replay_wal()
         self._wal = open(os.path.join(self.path, "wal.log"), "ab")
+        self._wal_bytes = self._wal.tell()
 
     def close(self) -> None:
         if self._wal is not None:
@@ -116,14 +179,7 @@ class LSMStore(KeyValueDB):
             rec = blob[off + 8:off + 8 + length]
             if len(rec) < length or _crc32c(rec) != crc:
                 break                       # torn tail: stop replay here
-            for op in json.loads(rec):
-                if op[0] == "set":
-                    self._mem_set(f"{op[1]}\x00{op[2]}",
-                                  op[3].encode("latin1"))
-                elif op[0] == "rm":
-                    self._mem_set(f"{op[1]}\x00{op[2]}", _TOMB)
-                elif op[0] == "rmprefix":
-                    self._rm_prefix_mem(op[1])
+            self._apply(_decode_ops(rec))
             off += 8 + length
 
     # -- batch submit --------------------------------------------------------
@@ -132,33 +188,57 @@ class LSMStore(KeyValueDB):
                            sync: bool = True) -> None:
         if not txn.ops:
             return
-        rec = json.dumps(
-            [(o[0], o[1], *([] if len(o) < 3 else [o[2]]),
-              *([] if len(o) < 4 else [o[3].decode("latin1")]))
-             for o in txn.ops]).encode()
+        rec = _encode_ops(txn.ops)
         self._wal.write(struct.pack("<II", len(rec), _crc32c(rec)) + rec)
         self._wal.flush()
         self.stats["bytes_written"] += 8 + len(rec)
+        self._wal_bytes += 8 + len(rec)
         if sync:
             os.fsync(self._wal.fileno())
             self.stats["fsyncs"] += 1
         if self.fail_after_wal:
             raise SimulatedCrash("crash between WAL append and apply")
         with self._lock:
-            for op in txn.ops:
-                if op[0] == "set":
-                    self._mem_set(f"{op[1]}\x00{op[2]}", op[3])
-                elif op[0] == "rm":
-                    self._mem_set(f"{op[1]}\x00{op[2]}", _TOMB)
-                elif op[0] == "rmprefix":
-                    self._rm_prefix_mem(op[1])
-        if self._mem_bytes >= self.FLUSH_BYTES:
+            self._apply(txn.ops)
+
+    def maintain(self) -> None:
+        """Flush the memtable if it, or the log, is over its threshold,
+        and compact if that made one run too many. On the writer's
+        thread."""
+        if self._mem_bytes >= self.FLUSH_BYTES or \
+                self._wal_bytes >= self.WAL_FLUSHES * self.FLUSH_BYTES:
             self._flush()
 
+    def _apply(self, ops) -> None:
+        for op in ops:
+            if op[0] == "set":
+                self._mem_set(f"{op[1]}\x00{op[2]}", op[3])
+            elif op[0] == "rm":
+                self._mem_set(f"{op[1]}\x00{op[2]}", _TOMB)
+            elif op[0] == "rmprefix":
+                self._rm_prefix_mem(op[1])
+
     def _mem_set(self, fq: str, value: bytes | None) -> None:
-        old = self._memtable.get(fq)
-        self._memtable[fq] = value
-        self._mem_bytes += len(fq) + (len(value) if value else 0) \
+        mem = self._memtable
+        old = mem.get(fq, _MISSING)
+        if old is _MISSING:
+            held = any(fq in run for run in self._runs)
+            if value is _TOMB and not held:
+                return                  # nothing anywhere to shadow
+            if not held:
+                self._born.add(fq)
+            old = None
+            self._mem_bytes += len(fq)
+        elif value is _TOMB and fq in self._born:
+            # set and deleted inside this memtable's life: no run ever
+            # sees the value, and there is nothing older to shadow
+            del mem[fq]
+            self._born.discard(fq)
+            self._born_dropped += 1
+            self._mem_bytes -= len(fq) + len(old)
+            return
+        mem[fq] = value
+        self._mem_bytes += (len(value) if value else 0) \
             - (len(old) if old else 0)
 
     def _rm_prefix_mem(self, prefix: str) -> None:
@@ -168,7 +248,7 @@ class LSMStore(KeyValueDB):
         for run in self._runs:
             names.update(k for k in run if k.startswith(p))
         for k in names:
-            self._memtable[k] = _TOMB
+            self._mem_set(k, _TOMB)
 
     # -- flush / compaction --------------------------------------------------
 
@@ -182,25 +262,43 @@ class LSMStore(KeyValueDB):
         body = blob[4:]
         if _crc32c(body) != crc:
             raise IOError(f"sst {name}: crc mismatch")
-        raw = json.loads(body)
-        return {k: (v.encode("latin1") if v is not None else _TOMB)
-                for k, v in raw.items()}
+        if body[:4] != _RUN_MAGIC:
+            raise IOError(f"sst {name}: another format (written by an "
+                          f"older program?)")
+        table: dict[str, bytes | None] = {}
+        at, end, size = 4, len(body), _ENTRY.size
+        while at < end:
+            nk, nv = _ENTRY.unpack_from(body, at)
+            at += size
+            key = body[at:at + nk].decode()
+            at += nk
+            if nv < 0:
+                table[key] = _TOMB
+            else:
+                table[key] = body[at:at + nv]
+                at += nv
+        return table
 
-    def _write_run(self, table: dict[str, bytes | None]) -> str:
+    def _write_run(self, table: dict[str, bytes | None]) -> tuple[str, int]:
+        """`table` as a new run file, sorted. -> (its name, its bytes)"""
         name = f"{self._next_file:06d}.sst"
         self._next_file += 1
-        body = json.dumps(
-            {k: (v.decode("latin1") if v is not None else None)
-             for k, v in sorted(table.items())}).encode()
+        parts = [_RUN_MAGIC]
+        for k, v in sorted(table.items()):
+            key = k.encode()
+            parts += (_ENTRY.pack(len(key), -1 if v is None else len(v)),
+                      key, v or b"")
+        body = b"".join(parts)
         tmp = self._run_path(name) + ".tmp"
         with open(tmp, "wb") as f:
-            f.write(struct.pack("<I", _crc32c(body)) + body)
+            f.write(struct.pack("<I", _crc32c(body)))
+            f.write(body)
             f.flush()
             os.fsync(f.fileno())
         self.stats["bytes_written"] += 4 + len(body)
         self.stats["fsyncs"] += 1
         os.replace(tmp, self._run_path(name))
-        return name
+        return name, 4 + len(body)
 
     def _commit_manifest(self) -> None:
         tmp = os.path.join(self.path, "MANIFEST.tmp")
@@ -214,33 +312,45 @@ class LSMStore(KeyValueDB):
         self.stats["fsyncs"] += 2
 
     def _flush(self) -> None:
-        if not self._memtable:
+        if not self._memtable and not self._wal_bytes:
             return
-        # the writer alone changes the memtable, and this is the writer
-        name = self._write_run(self._memtable)
-        self._run_files.insert(0, name)
-        with self._lock:
-            self._runs.insert(0, dict(self._memtable))
-        self._commit_manifest()
-        with self._lock:            # the run above holds every key of it
-            self._memtable.clear()
+        t0 = time.perf_counter()
+        entries, mem_bytes, nbytes = len(self._memtable), self._mem_bytes, 0
+        if self._memtable:
+            # the writer alone changes the memtable, and this is it
+            name, nbytes = self._write_run(self._memtable)
+            self._run_files.insert(0, name)
+            with self._lock:
+                self._runs.insert(0, dict(self._memtable))
+            self._commit_manifest()
+            with self._lock:        # the run above holds every key of it
+                self._memtable.clear()
+        dropped, self._born_dropped = self._born_dropped, 0
+        self._born.clear()
         self._mem_bytes = 0
         self.stats["memtable_flushes"] += 1
-        # WAL content is now durable in the run: start a fresh log
+        # what the log held is durable in the run, or was deleted again:
+        # start a fresh log
         self._wal.close()
         os.truncate(self._wal_path(), 0)
         self._wal = open(self._wal_path(), "ab")
+        self._wal_bytes = 0
+        self._span("kv_flush", t0, mem_bytes, nbytes, entries, dropped)
         if len(self._run_files) > self.COMPACT_RUNS:
             self._compact()
 
     def _compact(self) -> None:
         """Merge every run into one; tombstones drop out (nothing older
         remains to shadow)."""
+        t0 = time.perf_counter()
         merged: dict[str, bytes | None] = {}
         for run in reversed(self._runs):         # oldest first
             merged.update(run)
         merged = {k: v for k, v in merged.items() if v is not None}
-        name = self._write_run(merged)
+        entries_in = sum(len(run) for run in self._runs)
+        bytes_in = sum(os.path.getsize(self._run_path(fn))
+                       for fn in self._run_files)
+        name, nbytes = self._write_run(merged)
         old_files = self._run_files
         self._run_files = [name]
         with self._lock:
@@ -252,6 +362,22 @@ class LSMStore(KeyValueDB):
                 os.unlink(self._run_path(fn))
             except OSError:
                 pass
+        self._span("kv_compact", t0, bytes_in, nbytes, len(merged),
+                   entries_in - len(merged))
+
+    def _span(self, name: str, t0: float, bytes_in: int, bytes_out: int,
+              entries: int, dropped: int) -> None:
+        """`kv_flush`: the memtable's bytes in, the run's out, the
+        entries written, and the keys that were set and deleted since
+        the last flush and so were not. `kv_compact`: the old runs'
+        bytes in, the one run's out, its entries, and the shadowed
+        values and tombstones left behind."""
+        if tracer.active():
+            tracer.record_span(
+                name, t0, (time.perf_counter() - t0) * 1e6,
+                {"bytes_in": bytes_in, "bytes_out": bytes_out,
+                 "entries": entries, "dropped": dropped},
+                getattr(self, "name", type(self).__name__))
 
     def compact(self) -> None:
         """Explicit full compaction (rocksdb CompactRange)."""

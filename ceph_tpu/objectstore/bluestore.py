@@ -13,10 +13,26 @@ Re-creation of the reference BlueStore's architecture
     read verifies the stored csum and raises EIO on mismatch
     (bluestore_blob_t::verify_csum, bluestore_types.cc:840, read-time
     check BlueStore.cc:12234);
-  * small objects are DEFERRED: their bytes live inline in the onode's
-    KV value and never touch the block file (the deferred-write WAL
-    role, BlueStore.cc :14191 _kv_sync_thread) — one fsync'd KV batch
-    is the whole commit;
+  * small writes are DEFERRED (_do_alloc_write sends a new blob shorter
+    than bluestore_prefer_deferred_size down this path; `INLINE_MAX` is
+    that line's default here, upstream's hdd value): the write is
+    allocated, checksummed and staged as any other, the same extents in
+    its onode, and its bytes ride the group's KV batch as a record under
+    `P_DEFERRED` (bluestore_deferred_transaction_t under
+    PREFIX_DEFERRED). `on_commit` fires from that ONE sync of the KV
+    log, with no sync of the block file for it. The commit thread writes
+    what is acknowledged to its units later, `DEFERRED_BATCH_OPS`
+    extents to one `fdatasync` (_deferred_queue,
+    _deferred_submit_unlock), and a later KV batch removes the records
+    (_deferred_aio_finish). Until a write has landed, reads are served
+    from its staged view; units that a later transaction frees do not
+    return to the allocator while a deferred write to them is pending;
+    `deferred_max_bytes` bounds what may be pending and `prepare` waits
+    on it (bluestore_throttle_deferred_bytes); nothing waits longer
+    than `DEFERRED_MAX_AGE_S` (bluestore_max_defer_interval); `flush()`
+    drains; a mount applies the records a kill left (_deferred_replay).
+    An empty object is an onode of size 0 with no extents: there is one
+    representation of data;
   * large writes go data-first: extents are written to the block file
     and synced BEFORE the KV batch commits, so a crash in between
     leaves the old onode pointing at the old extents (BlueStore's txc
@@ -36,11 +52,21 @@ Re-creation of the reference BlueStore's architecture
     allocated, extents staged, csums made or taken from the write that
     brought them, the KV batch built), queues the context and returns.
     One commit thread a mounted store
-    takes every context queued, writes their staged extents, syncs the
-    block file once, submits ONE synced KV batch for all of them and
-    hands each context's `on_commit` back to the loop that queued it,
-    in queue order. The group is whatever queued while the last sync
-    ran: no timer, no knob. Reads see a queued transaction at once
+    takes every context queued, writes their staged extents (the
+    deferred ones apart), syncs the block file once if it wrote any,
+    submits ONE synced KV batch for all of them and hands each context's
+    `on_commit` back to the loop that queued it, in queue order; then,
+    with the acknowledgements gone, it lands a batch of deferred writes
+    if one is due and lets the KV flush or compact if that is due. The
+    group is whatever queued while the last sync ran: no timer, no knob.
+    A group's `bstore_kv_sync` span counts every extent its contexts
+    staged, the deferred ones with them (`block_bytes`, `block_writes`:
+    the thread that acknowledged them is the one that writes them), and
+    what the thread synced and the KV wrote between two groups (a
+    deferred batch's `fdatasync`, the KV's maintenance) is counted in
+    the next group's span (`block_synced`, `kv_bytes`, `kv_fsyncs`):
+    volumes are whole from span to span, times are the group's own.
+    Reads see a queued transaction at once
     (`on_applied` is immediate, as upstream's is on BlueStore). A
     caller with no running loop (the tools, a plain test) waits for its
     context and gets the callbacks, or the commit's exception, before
@@ -57,6 +83,8 @@ import asyncio
 import collections
 import json
 import os
+import statistics
+import struct
 import threading
 import time
 import weakref
@@ -75,7 +103,19 @@ from ceph_tpu.utils.crash import SimulatedCrash  # noqa: F401 (re-export)
 from ceph_tpu.utils.dout import dout
 
 AU = 4096                    # allocation unit (min_alloc_size)
-INLINE_MAX = 64 * 1024       # deferred/inline object size ceiling
+#: the line under which a write is deferred: the default of
+#: `BlueStore.prefer_deferred_size` (bluestore_prefer_deferred_size_hdd).
+#: As upstream's, the rule is strict: a write of exactly this goes to
+#: the block file first
+INLINE_MAX = 64 * 1024
+#: deferred extents that make a batch due (bluestore_deferred_batch_ops_hdd)
+DEFERRED_BATCH_OPS = 64
+#: bytes a store may hold staged for a deferred write and not landed
+#: (bluestore_throttle_deferred_bytes); a batch is due past half of it
+DEFERRED_MAX_BYTES = 128 * 1024 * 1024
+#: seconds an acknowledged deferred write may wait for its batch on an
+#: idle store (bluestore_max_defer_interval)
+DEFERRED_MAX_AGE_S = 3.0
 #: identities (a collection's, an object's in its collection) whose key
 #: a store remembers. One store of the benchmark's deployment touches
 #: about a hundred in a window: 16 ops in flight x a new object and its
@@ -89,6 +129,7 @@ P_SUPER = "S"
 P_COLL = "C"
 P_ONODE = "O"
 P_OMAP = "M"
+P_DEFERRED = "L"
 
 _CLEAR = "\x00CLEAR\x00"     # in an omap overlay: the keys under it are gone
 #: a context's states (the txc state machine) are `prepare`, on the
@@ -212,9 +253,39 @@ class _CommitQueue:
     def __init__(self):
         self.cond = threading.Condition()
         self.queued: list[_TxnCtx] = []     # prepared, in queue order
-        self.busy = False                   # a group is being committed
+        self.busy = False                   # the thread is at work
         self.stop = False
         self.failed: BaseException | None = None    # a group's; for good
+        # deferred writes; the commit thread alone changes the first
+        # three, under `cond`: the acknowledged ones in the order they
+        # are to land, and how many extents they are; the keys of the
+        # records whose extents the block file holds, synced, for the
+        # next KV batch to remove; the bytes staged for a deferred write
+        # and not landed (the bound's); and whether someone waits for
+        # all of it to be gone (`flush`, a `prepare` at the bound)
+        self.deferred_ready: list[_Deferred] = []
+        self.deferred_ops = 0
+        self.deferred_done: list[str] = []
+        self.deferred_bytes = 0
+        self.drain = False
+
+    def wait_for_work(self) -> None:
+        """Under `cond`: until something is queued, the thread is told
+        to stop, or deferred writes are to land (asked for, or waiting
+        `DEFERRED_MAX_AGE_S`) or their records to go (asked for)."""
+        while not self.queued and not self.stop:
+            if self.failed is None:
+                if self.drain and (self.deferred_ready
+                                   or self.deferred_done):
+                    return
+                if self.deferred_ready:
+                    left = self.deferred_ready[0].t_acked \
+                        + DEFERRED_MAX_AGE_S - time.perf_counter()
+                    if left <= 0:
+                        return
+                    self.cond.wait(left)
+                    continue
+            self.cond.wait()
 
 
 def _stop_queue(q: _CommitQueue) -> None:
@@ -225,13 +296,13 @@ def _stop_queue(q: _CommitQueue) -> None:
 
 def _kv_sync_thread(ref, q: _CommitQueue) -> None:
     """The commit thread's body (BlueStore::_kv_sync_thread): take ALL
-    that queued, commit it as one group, again. It ends when told to
-    and the queue is empty, or when its store is gone."""
+    that queued, commit it as one group, do what is due behind the
+    acknowledgements, again. It ends when told to and the queue is
+    empty, or when its store is gone."""
     while True:
         with q.cond:
-            while not q.queued and not q.stop:
-                q.cond.wait()
-            if not q.queued:
+            q.wait_for_work()
+            if not q.queued and q.stop:
                 return
             group, q.queued = q.queued, []
             q.busy = True
@@ -239,7 +310,9 @@ def _kv_sync_thread(ref, q: _CommitQueue) -> None:
         try:
             if store is None:
                 return
-            store._commit_group(group)
+            if group:
+                store._commit_group(group)
+            store._after_group()
         except Exception as e:
             # a fault of the pipeline itself, past what `_commit_group`
             # takes for a failed sync: the same end, a dead store whose
@@ -252,6 +325,40 @@ def _kv_sync_thread(ref, q: _CommitQueue) -> None:
                 q.cond.notify_all()
 
 
+class _Deferred:
+    """One context's deferred write: the key of its record in the KV,
+    the extents to land, when it was acknowledged, and the units that a
+    later transaction freed while it was pending."""
+
+    __slots__ = ("key", "extents", "nbytes", "t_acked", "held")
+
+    def __init__(self, key: str):
+        self.key = key
+        self.extents: list[tuple[int, int, memoryview]] = []
+        self.nbytes = 0
+        self.t_acked = 0.0
+        self.held: list[tuple[int, int]] = []
+
+    def record(self) -> bytes:
+        """The KV value (bluestore_deferred_transaction_t): how many
+        extents, the first unit and the count of each, their bytes."""
+        return b"".join((
+            struct.pack("<I", len(self.extents)),
+            *(struct.pack("<QI", unit, count)
+              for unit, count, _chunk in self.extents),
+            *(chunk for _unit, _count, chunk in self.extents)))
+
+
+def _record_extents(value: bytes):
+    """(unit, bytes) of each extent in a deferred record."""
+    n, = struct.unpack_from("<I", value, 0)
+    at = 4 + 12 * n
+    for i in range(n):
+        unit, count = struct.unpack_from("<QI", value, 4 + 12 * i)
+        yield unit, value[at:at + count * AU]
+        at += count * AU
+
+
 class BlueStore(ObjectStore):
 
     csum_block = AU
@@ -261,6 +368,10 @@ class BlueStore(ObjectStore):
         self.kv = kv if kv is not None else LSMStore(
             os.path.join(path, "db"))
         self._fd: int | None = None         # the block file
+        #: a write shorter than this is deferred; an OSD sets it from
+        #: `bluestore_prefer_deferred_size`. 0: none is
+        self.prefer_deferred_size = INLINE_MAX
+        self.deferred_max_bytes = DEFERRED_MAX_BYTES
         self.alloc = BitmapAllocator()
         # per-AU block checksums through the shared Checksummer engine
         # (bluestore_blob_t csum_data at csum_block_size granularity:
@@ -293,12 +404,24 @@ class BlueStore(ObjectStore):
         # extents staged and not yet in a group the KV holds, by first
         # unit: what a read takes in place of the block file's bytes
         self._pend_extents: dict[int, memoryview] = {}
+        # deferred writes staged and not landed, by the first unit of
+        # each extent (under `_lock`), and records made (`_CommitQueue`
+        # has the rest)
+        self._deferred_by_unit: dict[int, _Deferred] = {}
+        self._deferred_seq = 0
         # the allocator as the KV holds it: the commit thread's alone
         self._durable_bits = bytearray()
         self._stats = dict.fromkeys(
             ("txcs", "kv_syncs", "block_syncs", "block_writes",
              "block_bytes_written", "block_bytes_by_ref",
-             "csum_bytes_reused", "acks_before_sync"), 0)
+             "csum_bytes_reused", "acks_before_sync", "deferred_ops",
+             "deferred_bytes", "deferred_flushes", "deferred_pending_peak",
+             "deferred_replayed"), 0)
+        # the block file's syncs behind a group's back (the deferred
+        # batches'), for the next `bstore_kv_sync` span to count, and
+        # the KV's counters as the last span left them
+        self._deferred_syncs = 0
+        self._kv_seen: dict = {}
         # id -> key, a collection's under the id and an onode's under
         # (cid, oid), and the encodings made since the last context
         # was queued
@@ -328,6 +451,8 @@ class BlueStore(ObjectStore):
         self.alloc = BitmapAllocator.from_bytes(blob) if blob \
             else BitmapAllocator()
         self._durable_bits = bytearray(self.alloc.bits)
+        self._deferred_replay()
+        self._kv_seen = dict(self._kv_stats())
         self._q = q = _CommitQueue()
         self._fatal_told = False
         self._thread = threading.Thread(
@@ -336,13 +461,35 @@ class BlueStore(ObjectStore):
         self._thread.start()
         weakref.finalize(self, _stop_queue, q)
 
+    def _deferred_replay(self) -> None:
+        """At mount: what a kill left under `P_DEFERRED` is written to
+        its units, in the order it was queued, and synced; then the
+        records go. Run twice (a kill in here) it writes the same bytes
+        to the same units."""
+        records = list(self.kv.iterate(P_DEFERRED))
+        if not records:
+            return
+        batch = self.kv.transaction()
+        for key, value in records:
+            for unit, data in _record_extents(value):
+                _pwrite_all(self._fd, data, unit * AU)
+            batch.rmkey(P_DEFERRED, key)
+        os.fdatasync(self._fd)
+        self.kv.submit_transaction(batch, sync=True)
+        self._stats["deferred_replayed"] += len(records)
+
     def flush(self) -> None:
-        """Wait until everything queued is committed and its callbacks
-        have run (ObjectStore::flush). Nothing is closed; a store that
-        was flushed and gets nothing more writes nothing more."""
+        """Wait until everything queued is committed, its callbacks
+        have run and every deferred write is on the block file, synced,
+        its record gone (ObjectStore::flush). Nothing is closed; a store
+        that was flushed and gets nothing more writes nothing more."""
         q = self._q
         with q.cond:
-            while q.queued or q.busy:
+            while q.queued or q.busy or (
+                    (q.deferred_ready or q.deferred_done)
+                    and q.failed is None):
+                q.drain = bool(q.deferred_bytes or q.deferred_done)
+                q.cond.notify_all()
                 q.cond.wait()
         self._deliver()
 
@@ -363,8 +510,12 @@ class BlueStore(ObjectStore):
         extents written and bytes written to each (`block_bytes_by_ref`
         of them from a transaction's own buffer, `csum_bytes_reused`
         checksummed by whoever wrote them), the KV's flushes and
-        compactions, and `acks_before_sync`: callbacks delivered before
-        the group that covers them had finished, which must read 0."""
+        compactions, `acks_before_sync`: callbacks delivered before
+        the group that covers them had finished, which must read 0, and
+        the deferred writes: extents and bytes landed (`deferred_ops`,
+        `deferred_bytes`, counted among the block file's too), the
+        batches they landed in, the most bytes ever pending, and the
+        records a mount found and applied."""
         kv = self._kv_stats()
         return {**self._stats,
                 "kv_fsyncs": kv.get("fsyncs", 0),
@@ -440,13 +591,14 @@ class BlueStore(ObjectStore):
     # -- data path -----------------------------------------------------------
 
     def _read_extents(self, on: dict) -> bytes:
-        if "inline" in on:
-            return on["inline"].encode("latin1")
+        extents = on.get("extents")
+        if not extents:
+            return b""
         out = bytearray()
         with self._lock:
             staged = [self._pend_extents.get(unit)
-                      for unit, _count, _crc in on["extents"]]
-        for (unit, count, crc), chunk in zip(on["extents"], staged):
+                      for unit, _count, _crc in extents]
+        for (unit, count, crc), chunk in zip(extents, staged):
             if chunk is None:       # retired: the block file has it
                 chunk = os.pread(self._fd, count * AU, unit * AU)
             if len(chunk) != count * AU:
@@ -474,10 +626,12 @@ class BlueStore(ObjectStore):
     def _stage_data(self, on: dict, data, ctx: "_TxnCtx",
                     by_ref: bool = False, csums=None) -> None:
         """Replace the onode's data with `data`, bytes-like and nobody's
-        to change any more (`by_ref`: the transaction's own buffer):
-        inline when small, block extents when large. The extents are
-        allocated, checksummed and STAGED here, as views of `data` on
-        the context; the commit thread writes them. Old extents are
+        to change any more (`by_ref`: the transaction's own buffer).
+        The extents are allocated, checksummed and STAGED here, as views
+        of `data` on the context; the commit thread writes them: ahead
+        of the group's sync, or, where `data` is under the store's
+        `prefer_deferred_size`, behind its acknowledgement, from the
+        record that the group's KV batch carries. Old extents are
         freed AFTER the batch commits. `csums` is what the write said
         of its blocks (`Transaction.write`): taken for the extents'
         where it is of these very blocks, the buffer staged whole and
@@ -485,17 +639,18 @@ class BlueStore(ObjectStore):
         every other case. A read verifies either the same."""
         if "extents" in on:
             ctx.free_after.extend((u, c) for u, c, _ in on["extents"])
-        on.pop("inline", None)
         on.pop("extents", None)
         on["size"] = len(data)
-        if len(data) <= INLINE_MAX:
-            on["inline"] = str(data, "latin1")
+        if not len(data):
             return
+        deferred = len(data) < self.prefer_deferred_size
         pad = (-len(data)) % AU
         if pad:
             data, by_ref = b"".join((data, bytes(pad))), False
         view = memoryview(data).toreadonly()
         given = _unit_csums(csums, len(view) // AU) if by_ref else None
+        if deferred:
+            self._reserve_deferred(len(view), ctx)
         staged = []
         off = 0
         with self._lock:
@@ -510,7 +665,13 @@ class BlueStore(ObjectStore):
                 self._pend_extents[unit] = chunk
                 staged.append((unit, count, chunk))
                 off += count * AU
-        ctx.block_writes.extend(staged)
+                if deferred:
+                    self._deferred_by_unit[unit] = ctx.deferred
+        if deferred:
+            ctx.deferred.extents.extend(staged)
+            ctx.deferred.nbytes += len(view)
+        else:
+            ctx.block_writes.extend(staged)
         if given is None:
             on["extents"] = [
                 [unit, count, self.csum.calculate(chunk).tolist()]
@@ -525,16 +686,64 @@ class BlueStore(ObjectStore):
         if by_ref:
             ctx.by_ref_bytes += len(view)
 
+    def _reserve_deferred(self, nbytes: int, ctx: "_TxnCtx") -> None:
+        """Take `nbytes` of the bound on deferred bytes for the context,
+        waiting, on the caller's thread, while others hold too much of
+        it (the throttle `queue_transactions` blocks in): the commit
+        thread is asked to land what it has."""
+        q = self._q
+        if ctx.deferred is None:
+            self._deferred_seq += 1
+            ctx.deferred = _Deferred(f"{self._deferred_seq:016x}")
+        mine = ctx.deferred.nbytes
+        t0 = None
+        with q.cond:
+            while q.deferred_bytes > mine and q.failed is None and \
+                    q.deferred_bytes + nbytes > self.deferred_max_bytes:
+                if t0 is None:
+                    t0 = time.perf_counter()
+                q.drain = True
+                q.cond.notify_all()
+                q.cond.wait()
+            if t0 is not None:
+                ctx.deferred_wait_us += (time.perf_counter() - t0) * 1e6
+            q.deferred_bytes += nbytes
+            peak = self._stats["deferred_pending_peak"]
+            self._stats["deferred_pending_peak"] = max(peak,
+                                                       q.deferred_bytes)
+
     def _unstage(self, ctxs: "list[_TxnCtx]", free: bool) -> None:
         """These contexts' staged extents leave the reads' map: the
-        block file has them (`_retire`), or nothing ever will, and then
-        their units go back as well (`free`). Under `_lock`."""
+        block file has them (`_retire`; a deferred one stays until its
+        batch has landed), or nothing ever will, and then their units go
+        back as well (`free`). Under `_lock`."""
         for ctx in ctxs:
             for unit, _count, _chunk in ctx.block_writes:
                 self._pend_extents.pop(unit, None)
             ctx.block_writes = []
-            if free:
-                self.alloc.free(ctx.allocated)
+            if not free:
+                continue
+            if ctx.deferred is not None:
+                for unit, _count, _chunk in ctx.deferred.extents:
+                    self._pend_extents.pop(unit, None)
+                    self._deferred_by_unit.pop(unit, None)
+                with self._q.cond:
+                    self._q.deferred_bytes -= ctx.deferred.nbytes
+                    self._q.cond.notify_all()
+                ctx.deferred = None
+            self.alloc.free(ctx.allocated)
+
+    def _free(self, extents: list[tuple[int, int]]) -> None:
+        """Units that a committed transaction let go return to the
+        allocator, but for those a deferred write is still to land on:
+        handed out now, they would be written twice, the older bytes
+        last. They go back when it has landed. Under `_lock`."""
+        for unit, count in extents:
+            pending = self._deferred_by_unit.get(unit)
+            if pending is None:
+                self.alloc.free([(unit, count)])
+            else:
+                pending.held.append((unit, count))
 
     # -- the commit pipeline -------------------------------------------------
 
@@ -563,6 +772,9 @@ class BlueStore(ObjectStore):
                 ctx.batch.rmkey(P_ONODE, key)
             else:
                 ctx.batch.set(P_ONODE, key, json.dumps(on).encode())
+        if ctx.deferred is not None:
+            ctx.batch.set(P_DEFERRED, ctx.deferred.key,
+                          ctx.deferred.record())
         ctx.n_ops = len(txn.ops)
         ctx.on_applied, ctx.on_commit = txn.on_applied, txn.on_commit
         try:
@@ -626,14 +838,14 @@ class BlueStore(ObjectStore):
             self._pend_omap[key] = (over, seq)
 
     def _retire(self, group: "list[_TxnCtx]") -> None:
-        """The KV holds the group now: its frees reach the allocator,
-        its extents are read from the block file, and what it laid over
-        the KV goes unless a later context has laid its own there
-        since. Under `_lock`."""
+        """The KV holds the group now: its frees reach the allocator
+        (`_free`), its extents are read from the block file, the
+        deferred ones apart, and what it laid over the KV goes unless a
+        later context has laid its own there since. Under `_lock`."""
         hi = group[-1].seq
         self._unstage(group, free=False)
         for ctx in group:
-            self.alloc.free(ctx.free_after)
+            self._free(ctx.free_after)
             for pend, keys in ((self._pend_onodes, ctx.onodes),
                                (self._pend_colls, ctx.colls),
                                (self._pend_omap, ctx.omap_over)):
@@ -643,10 +855,11 @@ class BlueStore(ObjectStore):
 
     def _commit_group(self, group: "list[_TxnCtx]") -> None:
         """On the commit thread: the contexts' staged extents written
-        in queue order, one sync of the block file if there were any,
-        one synced KV batch for all the contexts (the freelist key
-        once), the frees, then the callbacks handed back in queue
-        order."""
+        in queue order, the deferred ones apart, one sync of the block
+        file if there were any, one synced KV batch for all the contexts
+        (the freelist key once; the deferred records; the removal of the
+        records that have landed), the frees, then the callbacks handed
+        back in queue order."""
         if self._q.failed is not None:
             # prepared while the group before it failed: nothing
             # commits behind a hole
@@ -656,11 +869,14 @@ class BlueStore(ObjectStore):
         self._groups = seqno = self._groups + 1
         for ctx in group:
             ctx.group, ctx.t_taken = seqno, t0
-        kv0 = dict(self._kv_stats())
+        deferred = [ctx.deferred for ctx in group
+                    if ctx.deferred is not None]
         block_bytes = sum(ctx.block_bytes for ctx in group)
+        direct_bytes = block_bytes - sum(d.nbytes for d in deferred)
         by_ref_bytes = sum(ctx.by_ref_bytes for ctx in group)
         csum_reused = sum(ctx.csum_reused_bytes for ctx in group)
-        block_writes = sum(len(ctx.block_writes) for ctx in group)
+        direct_writes = sum(len(ctx.block_writes) for ctx in group)
+        block_writes = direct_writes + sum(len(d.extents) for d in deferred)
         freelist_bytes = 0
         try:
             # data before metadata: the txc ordering (BlueStore.cc
@@ -670,7 +886,7 @@ class BlueStore(ObjectStore):
                 for unit, _count, chunk in ctx.block_writes:
                     _pwrite_all(self._fd, chunk, unit * AU)
             tw = time.perf_counter()
-            if block_bytes:
+            if direct_bytes:
                 os.fdatasync(self._fd)
             t1 = time.perf_counter()
             for ctx in group:
@@ -698,12 +914,15 @@ class BlueStore(ObjectStore):
                         bits[unit:unit + count] = bytes(count)
                 # deflated: a byte a unit is nearly all ones on a device
                 # that fills, and raw it was most of every group's log
-                # record, a megabyte of JSON escapes by the end of a
-                # window, encoded in ONE call that holds the GIL against
-                # the caller's loop; `zlib` works without the GIL
+                # record; `zlib` works without the GIL
                 value = zlib.compress(bits, 1)
                 batch.set(P_SUPER, "freelist", value)
                 freelist_bytes = len(value)
+            # records whose extents the block file holds, synced: gone
+            # with this batch, or found again and applied again
+            removed = self._q.deferred_done
+            for key in removed:
+                batch.rmkey(P_DEFERRED, key)
             if batch.ops:
                 self.kv.submit_transaction(batch, sync=True)
             t2 = time.perf_counter()
@@ -713,13 +932,23 @@ class BlueStore(ObjectStore):
         self._durable_bits = bits
         with self._lock:
             self._retire(group)
+        # acknowledged from here on: the deferred ones are the thread's
+        # to land, in this order
+        for d in deferred:
+            d.t_acked = t2
+        with self._q.cond:
+            self._q.deferred_done = []
+            self._q.deferred_ready.extend(deferred)
+            self._q.deferred_ops += block_writes - direct_writes
         kv1 = self._kv_stats()
+        kv0, self._kv_seen = self._kv_seen, dict(kv1)
+        behind, self._deferred_syncs = self._deferred_syncs, 0
         st = self._stats
         st["txcs"] += len(group)
         st["kv_syncs"] += bool(batch.ops)
-        st["block_syncs"] += bool(block_bytes)
-        st["block_writes"] += block_writes
-        st["block_bytes_written"] += block_bytes
+        st["block_syncs"] += bool(direct_bytes)
+        st["block_writes"] += direct_writes
+        st["block_bytes_written"] += direct_bytes
         st["block_bytes_by_ref"] += by_ref_bytes
         st["csum_bytes_reused"] += csum_reused
         perf = self.commit_perf
@@ -729,7 +958,7 @@ class BlueStore(ObjectStore):
             tracer.record_span(
                 "bstore_kv_sync", t0, (t2 - t0) * 1e6,
                 {"group": seqno, "txcs": len(group),
-                 "block_synced": int(bool(block_bytes)),
+                 "block_synced": int(bool(direct_bytes)) + behind,
                  "block_writes": block_writes,
                  "block_write_us": (tw - t0) * 1e6,
                  "block_sync_us": (t1 - t0) * 1e6,
@@ -738,7 +967,9 @@ class BlueStore(ObjectStore):
                  "block_bytes": block_bytes,
                  "kv_bytes": kv1.get("bytes_written", 0)
                  - kv0.get("bytes_written", 0),
-                 "freelist_bytes": freelist_bytes},
+                 "freelist_bytes": freelist_bytes,
+                 "deferred_in": len(deferred),
+                 "deferred_removed": len(removed)},
                 getattr(self, "name", type(self).__name__))
         for ctx in group:
             ctx.block_write_us = (tw - t0) * 1e6
@@ -748,6 +979,86 @@ class BlueStore(ObjectStore):
             ctx.state = "kv_submitted"
         self._groups_done = seqno
         self._hand_back(group)
+
+    def _after_group(self) -> None:
+        """On the commit thread, with a group's acknowledgements gone
+        (or woken with none to commit): land the deferred writes if a
+        batch is due, and let the KV flush or compact if that is due.
+        Neither keeps an acknowledgement waiting that was ready."""
+        q = self._q
+        if q.failed is not None:
+            return
+        if q.deferred_ready and (
+                q.drain or q.deferred_ops >= DEFERRED_BATCH_OPS
+                or 2 * q.deferred_bytes >= self.deferred_max_bytes
+                or time.perf_counter() - q.deferred_ready[0].t_acked
+                >= DEFERRED_MAX_AGE_S):
+            self._deferred_flush()
+        gone = q.drain and bool(q.deferred_done)
+        if gone:
+            # someone waits for the store to be still (`flush`): the
+            # records go now, in a batch of their own
+            batch = self.kv.transaction()
+            for key in q.deferred_done:
+                batch.rmkey(P_DEFERRED, key)
+            self.kv.submit_transaction(batch, sync=True)
+        with q.cond:
+            if gone:
+                q.deferred_done = []
+            if not q.deferred_ready and not q.deferred_done:
+                q.drain = False
+        self.kv.maintain()
+
+    def _deferred_flush(self) -> None:
+        """Write every acknowledged deferred extent to its units, in the
+        order acknowledged, and sync the block file once
+        (_deferred_submit_unlock, _deferred_aio_finish). From then on
+        reads take them from the block file, the units that were freed
+        under them are free, and their records are the next KV batch's
+        to remove."""
+        q = self._q
+        landing = q.deferred_ready
+        t0 = time.perf_counter()
+        ops = nbytes = 0
+        for d in landing:
+            for unit, _count, chunk in d.extents:
+                _pwrite_all(self._fd, chunk, unit * AU)
+            ops += len(d.extents)
+            nbytes += d.nbytes
+        tw = time.perf_counter()
+        os.fdatasync(self._fd)
+        ts = time.perf_counter()
+        with self._lock:
+            for d in landing:
+                for unit, _count, _chunk in d.extents:
+                    self._pend_extents.pop(unit, None)
+                    self._deferred_by_unit.pop(unit, None)
+                self.alloc.free(d.held)
+        with q.cond:
+            q.deferred_ready = []
+            q.deferred_ops -= ops
+            q.deferred_bytes -= nbytes
+            q.deferred_done = q.deferred_done + [d.key for d in landing]
+            pending = q.deferred_bytes
+            q.cond.notify_all()
+        st = self._stats
+        st["deferred_ops"] += ops
+        st["deferred_bytes"] += nbytes
+        st["deferred_flushes"] += 1
+        st["block_syncs"] += 1
+        st["block_writes"] += ops
+        st["block_bytes_written"] += nbytes
+        self._deferred_syncs += 1
+        if tracer.active():
+            lags = [(ts - d.t_acked) * 1e6 for d in landing]
+            tracer.record_span(
+                "bstore_deferred_flush", t0, (ts - t0) * 1e6,
+                {"ops": ops, "bytes": nbytes, "records": len(landing),
+                 "write_us": (tw - t0) * 1e6, "sync_us": (ts - tw) * 1e6,
+                 "oldest_lag_us": lags[0],
+                 "median_lag_us": statistics.median(lags),
+                 "pending_bytes": pending},
+                getattr(self, "name", type(self).__name__))
 
     def _fail_group(self, group: "list[_TxnCtx]", e: BaseException) -> None:
         """A block write, a sync or the KV failed (ENOSPC, EIO, a test's
@@ -766,6 +1077,8 @@ class BlueStore(ObjectStore):
             self._unstage(group, free=True)
         for ctx in group:
             ctx.error, ctx.state = e, "failed"
+        with q.cond:
+            q.cond.notify_all()     # a `prepare` at the bound, a `flush`
         if self._fatal_told:
             return
         self._fatal_told = True
@@ -816,6 +1129,8 @@ class BlueStore(ObjectStore):
                      "block_write_us": ctx.block_write_us,
                      "ops": ctx.n_ops, "bytes": ctx.block_bytes,
                      "by_ref_bytes": ctx.by_ref_bytes,
+                     "deferred_bytes": ctx.deferred_bytes,
+                     "deferred_wait_us": ctx.deferred_wait_us,
                      "csum_reused_bytes": ctx.csum_reused_bytes,
                      "key_encodes": ctx.key_encodes,
                      "group": ctx.group, "ran_ahead": bool(ran_ahead)},
@@ -885,13 +1200,13 @@ class BlueStore(ObjectStore):
         if kind == Op.TOUCH:
             self._require_coll(cid, ctx)
             if self._staged(ctx, key) is None:
-                ctx.onodes[key] = {"size": 0, "inline": "", "attrs": {}}
+                ctx.onodes[key] = {"size": 0, "attrs": {}}
             return
         if kind == Op.WRITE:
             self._require_coll(cid, ctx)
             offset, data, csums = op[3], op[4], op[5]
             on = self._staged(ctx, key) or \
-                {"size": 0, "inline": "", "attrs": {}}
+                {"size": 0, "attrs": {}}
             if offset == 0 and on["size"] <= len(data):
                 # the object replaced whole (every push and write_full):
                 # `Transaction.write` made the buffer the store's, and
@@ -910,7 +1225,7 @@ class BlueStore(ObjectStore):
             self._require_coll(cid, ctx)
             offset, length = op[3], op[4]
             on = self._staged(ctx, key) or \
-                {"size": 0, "inline": "", "attrs": {}}
+                {"size": 0, "attrs": {}}
             cur = bytearray(self._read_staged(on))
             if len(cur) < offset + length:
                 cur.extend(b"\x00" * (offset + length - len(cur)))
@@ -922,7 +1237,7 @@ class BlueStore(ObjectStore):
             self._require_coll(cid, ctx)
             size = op[3]
             on = self._staged(ctx, key) or \
-                {"size": 0, "inline": "", "attrs": {}}
+                {"size": 0, "attrs": {}}
             cur = bytearray(self._read_staged(on))
             if len(cur) < size:
                 cur.extend(b"\x00" * (size - len(cur)))
@@ -942,7 +1257,7 @@ class BlueStore(ObjectStore):
         if kind == Op.SETATTRS:
             self._require_coll(cid, ctx)
             on = self._staged(ctx, key) or \
-                {"size": 0, "inline": "", "attrs": {}}
+                {"size": 0, "attrs": {}}
             on.setdefault("attrs", {}).update(
                 {k: v.decode("latin1") for k, v in op[3].items()})
             ctx.onodes[key] = on
@@ -958,8 +1273,7 @@ class BlueStore(ObjectStore):
             if son is None:
                 raise StoreError("ENOENT", f"no object {src}")
             data = self._read_staged(son)
-            don = {"size": 0, "inline": "", "attrs":
-                   dict(son.get("attrs", {}))}
+            don = {"size": 0, "attrs": dict(son.get("attrs", {}))}
             old = self._staged(ctx, dkey)
             if old is not None and "extents" in old:
                 ctx.free_after.extend((u, c)
@@ -986,7 +1300,7 @@ class BlueStore(ObjectStore):
                 raise StoreError("ENOENT", f"no object {src}")
             sdata = self._read_staged(son)[src_off:src_off + length]
             don = self._staged(ctx, dkey) or \
-                {"size": 0, "inline": "", "attrs": {}}
+                {"size": 0, "attrs": {}}
             cur = bytearray(self._read_staged(don))
             if len(cur) < dst_off:
                 cur.extend(b"\x00" * (dst_off - len(cur)))
@@ -1021,7 +1335,7 @@ class BlueStore(ObjectStore):
         if kind == Op.OMAP_SETKEYS:
             self._require_coll(cid, ctx)
             on = self._staged(ctx, key) or \
-                {"size": 0, "inline": "", "attrs": {}}
+                {"size": 0, "attrs": {}}
             ctx.onodes[key] = on
             pre = P_OMAP + "\x01" + key
             over = ctx.omap_over.setdefault(key, {})
@@ -1032,7 +1346,7 @@ class BlueStore(ObjectStore):
         if kind == Op.OMAP_RMKEYS:
             self._require_coll(cid, ctx)
             on = self._staged(ctx, key) or \
-                {"size": 0, "inline": "", "attrs": {}}
+                {"size": 0, "attrs": {}}
             ctx.onodes[key] = on
             pre = P_OMAP + "\x01" + key
             over = ctx.omap_over.setdefault(key, {})
@@ -1178,8 +1492,9 @@ def _overlaid(base: dict[str, bytes], over: dict) -> dict[str, bytes]:
 
 class _TxnCtx:
     """A transaction context (upstream's TransContext): what `prepare`
-    staged, onode edits + omap overlay + extents to write + deferred
-    frees + the slice of the group's KV batch, and where the context
+    staged, onode edits + omap overlay + extents to write, ahead of the
+    sync or behind the acknowledgement, + frees to make once it has
+    committed + the slice of the group's KV batch, and where the context
     stands in the pipeline (`_SETTLED`'s comment) with the clock at
     each step."""
 
@@ -1193,6 +1508,10 @@ class _TxnCtx:
         self.allocated: list[tuple[int, int]] = []
         #: (unit, count, read-only view): for the commit thread to write
         self.block_writes: list[tuple[int, int, memoryview]] = []
+        #: the context's deferred write, if it stages one: its extents
+        #: are the commit thread's to write once this is acknowledged
+        self.deferred: _Deferred | None = None
+        self.deferred_wait_us = 0.0         # `prepare` waited at the bound
         self.block_bytes = 0                # staged for the block file
         self.by_ref_bytes = 0               # of them, the txn's own buffer
         self.csum_reused_bytes = 0          # of them, csums came with it
@@ -1208,3 +1527,8 @@ class _TxnCtx:
         self.t0 = time.perf_counter()       # prepare began
         self.t_queued = self.t_taken = self.t_synced = 0.0
         self.block_write_us = self.block_sync_us = self.kv_submit_us = 0.0
+
+    @property
+    def deferred_bytes(self) -> int:
+        """Of `block_bytes`, those acknowledged from the KV sync alone."""
+        return self.deferred.nbytes if self.deferred is not None else 0

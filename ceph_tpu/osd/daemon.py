@@ -35,6 +35,7 @@ from ceph_tpu.msg.messages import (MBackfillReserve, Message,
                                    MOSDScrubReserve, MPing, MPingReply)
 from ceph_tpu.msg.messenger import Connection, Dispatcher, Messenger, Policy
 from ceph_tpu.mon.mon_client import MonClient
+from ceph_tpu.objectstore.bluestore import INLINE_MAX
 from ceph_tpu.objectstore.memstore import MemStore
 from ceph_tpu.objectstore.store import StoreError
 from ceph_tpu.osd import scrub as scrub_mod
@@ -255,6 +256,15 @@ class OSD(Dispatcher):
                    "seconds an op picked by osd_debug_inject_dispatch_"
                    "delay_probability is held; the hold sleeps the op, "
                    "not a thread, and burns no CPU (hot)", minimum=0.0),
+            Option("bluestore_prefer_deferred_size", "size", INLINE_MAX,
+                   "a BlueStore write shorter than this is deferred: "
+                   "its bytes ride the transaction's KV batch, it is "
+                   "acknowledged from the KV's sync alone and written "
+                   "to its allocated units afterwards, in batches "
+                   "(upstream's option of this name overrides its "
+                   "_hdd / _ssd flavours; the default is the hdd "
+                   "flavour's). 0 defers nothing; ignored by a store "
+                   "that has no such path (hot)", minimum=0),
             *pool_options(),
         ])
         # op tracing rides the same config (hot-togglable: `config set
@@ -430,6 +440,12 @@ class OSD(Dispatcher):
         self.config.add_observer(
             ("osd_debug_inject_dispatch_delay_probability",),
             self._on_dispatch_delay)
+        # the store's one knob rides the daemon's config, as upstream's
+        # bluestore_* options do
+        self._on_prefer_deferred("", self.config.get(
+            "bluestore_prefer_deferred_size"))
+        self.config.add_observer(("bluestore_prefer_deferred_size",),
+                                 self._on_prefer_deferred)
         # dmclock arbiter wiring: seed the scheduler from the knobs,
         # then keep it live via the observer (every osd_mclock_* knob
         # is hot, including the enable toggle — queued work migrates)
@@ -934,6 +950,13 @@ class OSD(Dispatcher):
                               bool(value))
         else:
             self._run_on_loop(self._apply_qos_knobs)
+
+    def _on_prefer_deferred(self, _name: str, value) -> None:
+        """bluestore_prefer_deferred_size observer: the line under
+        which the store defers a write, for every write prepared from
+        now on."""
+        if hasattr(self.store, "prefer_deferred_size"):
+            self.store.prefer_deferred_size = int(value)
 
     def _on_recovery_limits(self, name: str, value) -> None:
         """osd_max_backfills / osd_recovery_max_active observer: resize

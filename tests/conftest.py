@@ -65,9 +65,11 @@ def pytest_collection_modifyitems(config, items):
     its line). The same holds for `rb64k_write` (PR 49): its shards are
     kept by reference as `rb4m_write`'s are, but the list that would
     say so is not this PR's to extend; tests/benchmarks/
-    test_rb64k_cell.py holds what the case meant. The next `benchmark`
-    PR drops the per-cell `len(mine) == 1` there and takes both marks
-    out too.
+    test_rb64k_cell.py holds what the case meant. And for
+    `rb64k_bluestore_write` (PR 53), for both reasons at once;
+    tests/benchmarks/test_rb64k_bluestore_cell.py holds what the case
+    meant. The next `benchmark` PR drops the per-cell `len(mine) == 1`
+    there and takes all three marks out too.
 
     (The three marks that stood here before, for cases of
     test_loop_account.py, test_store_direct.py's fast-read case and the
@@ -82,7 +84,10 @@ def pytest_collection_modifyitems(config, items):
         "rb4m_bluestore_write": why % (
             "; BlueStore keeps no body by reference",
             "test_bluestore_cell.py (PR 45)"),
-        "rb64k_write": why % ("", "test_rb64k_cell.py (PR 49)")}
+        "rb64k_write": why % ("", "test_rb64k_cell.py (PR 49)"),
+        "rb64k_bluestore_write": why % (
+            "; BlueStore keeps no body by reference",
+            "test_rb64k_bluestore_cell.py (PR 53)")}
     for item in items:
         for cell, reason in marks.items():
             if item.nodeid.endswith(case % cell):

@@ -85,10 +85,11 @@ def test_lsm_flush_compaction_and_tombstones(tmp_path):
     db.open()
     for i in range(50):
         _put(db, "P", f"k{i:03d}", bytes(64))
+        db.maintain()           # the owner's call: no submit flushes
     t = db.transaction()
     t.rmkey("P", "k010")
     db.submit_transaction(t)
-    assert len(db._run_files) >= 1           # flushed at least once
+    assert len(db._run_files) > 1            # flushed more than once
     db.compact()
     assert len(db._run_files) == 1           # fully merged
     assert db.get("P", "k010") is None       # tombstone won the merge
@@ -120,13 +121,20 @@ def test_bluestore_large_write_extents_and_remount(tmp_path):
     t.write(CID, oid, 0, data)
     s.queue_transaction(t)
     on = s._onode(CID, oid)
-    assert "extents" in on and "inline" not in on
+    # one representation of data, extents, over the deferred line and
+    # under it: nothing of an object's bytes lives in its onode
+    assert set(on) == {"size", "extents", "attrs"} and not s._q.deferred_ops
+    small = Ghobject(pool=3, name="small")
+    s.queue_transaction(Transaction().write(CID, small, 0, data[:AU + 7]))
+    assert set(s._onode(CID, small)) == {"size", "extents", "attrs"}
+    assert s._q.deferred_ops == 1 and s.read(CID, small) == data[:AU + 7]
     assert s.read(CID, oid) == data
     s.umount()
     s2 = BlueStore(str(tmp_path / "bs"))
     s2.mount()
     assert s2.read(CID, oid) == data
     assert s2.stat(CID, oid)["size"] == len(data)
+    assert s2.read(CID, Ghobject(pool=3, name="small")) == data[:AU + 7]
     s2.umount()
 
 

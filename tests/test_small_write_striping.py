@@ -2,8 +2,9 @@
 `benchmarks/reference_small.py`: how `write_full` stripes n bytes, what
 each of the k+m OSDs then holds, and what `read` gives back, at the
 sizes round a chunk (4,096), a stripe of 8+3 (32,768) and the 64 KiB
-line the messenger and BlueStore draw (`msg/transport.SPILL_SIZE`,
-`bluestore.INLINE_MAX`), one byte either side of each.
+line the messenger and BlueStore draw (`msg/transport.SPILL_SIZE`;
+`bluestore.INLINE_MAX`, under which a BlueStore write is deferred: KV
+log first, block file after), one byte either side of each.
 
 One live cluster a profile serves every size in a module fixture (2+1
 on three OSDs; the north-star pool's own 8+3 on eleven), on the pool's
