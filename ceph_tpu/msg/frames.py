@@ -207,7 +207,9 @@ class Frame:
 
         Ownership: the list, and after it the transport's queue, holds
         a reference to every part until the kernel has taken its last
-        byte, so the buffers stay alive; whoever handed them in must
+        byte, so the buffers stay alive (a frame of `rxworker.LINE` or
+        more does not come through here: `send_args` below, and the
+        send worker's job holds the parts); whoever handed them in must
         leave them UNWRITTEN that long (a write in between sends bytes
         the crc does not cover, and the peer faults the connection).
         That holds for everything the messenger frames: `Message.data`
@@ -233,6 +235,17 @@ class Frame:
                 off += 4
         copytrack.referenced("frame_tx", self.payload_len())
         return parts
+
+    def send_args(self) -> tuple:
+        """`(magic, tag, segments)`: what the messenger's send worker is
+        handed in place of `encode_parts()` (`msg/rxworker.py`
+        `submit_tx`, through `Endpoint.send_frame`). The thread builds
+        the same list from them, the crcs included, and sends it; the
+        job holds the reference to every part that the transport's queue
+        holds on the other path, until it is reaped or cancelled, and
+        the buffers stay unwritten as long, as above."""
+        self._check_count()
+        return MAGIC, int(self.tag), self.segments
 
     def encode(self) -> bytes | bytearray:
         """Wire form as ONE packed blob: each payload byte is copied

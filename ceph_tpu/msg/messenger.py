@@ -51,7 +51,12 @@ payload is the spill's size or more leaves by reference, its segments
 read once for their crc and sent by the transport's scatter sendmsg
 from where they lie; a smaller one, and every frame of a secure or
 compressed session, as one packed blob (tx_direct_bytes,
-tx_copied_bytes).
+tx_copied_bytes); and a plain-crc frame of msg/rxworker.py's LINE or
+more is handed to that module's native thread, which computes the crcs
+and sends it off the loop, and keeps its parts alive until the kernel
+has them or the job is taken back (tx_worker_bodies, tx_worker_bytes,
+tx_worker_cpu_ns, tx_worker_cancelled, tx_worker_declined); the write
+loop awaits that one frame as it awaits drain().
 Auth: `none` by default, cephx-lite mutual HMAC when
 an auth_key is set; on top of that the handshake can negotiate AES-GCM
 secure mode and/or zlib on-wire compression (frames.Onwire), with the
@@ -77,7 +82,7 @@ from ceph_tpu.msg.messages import Message, _json_seg
 from ceph_tpu.msg.transport import SPILL_SIZE, Endpoint
 from ceph_tpu.native import ec_native
 from ceph_tpu.qa import faultinject, interleave
-from ceph_tpu.utils import tracer
+from ceph_tpu.utils import copytrack, tracer
 from ceph_tpu.utils.async_util import drain_all, reap, reap_all
 from ceph_tpu.utils.dout import dout
 from ceph_tpu.utils.perf_counters import (TYPE_HISTOGRAM,
@@ -175,6 +180,23 @@ def msgr_perf():
                            "sent through a packed blob (Frame.encode: "
                            "frames under the spill size, and every "
                            "frame of a secure or compressed session)")
+        pc.add("tx_worker_bodies",
+               description="frames the send worker (msg/rxworker.py) "
+                           "sent whole")
+        pc.add("tx_worker_bytes",
+               description="those of tx_direct_bytes that the send "
+                           "worker's thread sent, off the loop")
+        pc.add("tx_worker_cpu_ns",
+               description="CPU time of the send worker's thread on its "
+                           "frames: crc32c and sendmsg")
+        pc.add("tx_worker_cancelled",
+               description="frames taken back from the send worker "
+                           "unfinished (connection lost, closed, write "
+                           "loop cancelled)")
+        pc.add("tx_worker_declined",
+               description="frames over the worker's line that the "
+                           "transport sent all the same: its queue was "
+                           "not empty, or the submit failed")
         pc.add("ctrl_frames_tx",
                description="ACK, KEEPALIVE and KEEPALIVE_ACK frames the "
                            "write loops framed")
@@ -873,7 +895,15 @@ class Connection:
         An ACK frame is built only where the send has no MESSAGE frame
         to carry it. Gathering stops at SPILL_SIZE bytes of payload: a
         large frame still leaves from where its bytes lie, one at a
-        time."""
+        time. One of `rxworker.LINE` or more on a plain-crc session is
+        not encoded here: the endpoint's send worker takes it
+        (`Endpoint.send_frame`), with what was gathered in front of it,
+        computes its crcs and sends it, and this loop awaits that job
+        where it awaits `drain()` otherwise, so one send a connection
+        is in flight and frames leave in the queue's order. The job
+        holds the frame's parts until it is reaped or taken back; the
+        message stays in `_sent` until it is acked, and is framed anew
+        after a fault, whoever was sending it."""
         perf = self.messenger.perf
         out = self._out
         pending: tuple | None = None
@@ -886,6 +916,7 @@ class Connection:
                 # the dispatch loop's lazy _schedule_ack_flush timer
                 item = await out.get()
             parts: list = []
+            large: Frame | None = None      # the send worker's, the last
             gathered = ctrl = msgs = carried = 0
             while True:
                 kind, arg = item
@@ -902,11 +933,16 @@ class Connection:
                             isinstance(arg, (_messages.MOSDECSubOpBatch,
                                              _messages.MOSDECSubOpBatchReply)):
                         perf.inc("data_frames_tx")
-                    gathered += self._frame_into(parts, frame, onwire)
+                    nbytes = frame.payload_len()
+                    gathered += nbytes
+                    if onwire is None and writer.worker_sends(nbytes):
+                        large = frame       # not encoded here: no crc pass
+                    else:
+                        self._frame_into(parts, frame, onwire, nbytes)
                 elif kind in _PROBE_TAGS:
                     ctrl += 1
                     self._frame_into(parts, Frame(_PROBE_TAGS[kind], []),
-                                     onwire)
+                                     onwire, 0)
                 # an ("ack", seq) is a wake-up and no more: what the
                 # peer is owed is read by _take_ack, so one that a
                 # header has overtaken since it was queued sends nothing
@@ -925,15 +961,31 @@ class Connection:
                 # flush's, a probe's): upstream's case for the frame
                 ctrl += 1
                 perf.inc("ack_frames_tx")
-                self._frame_into(parts, Frame(Tag.ACK, [b"[%d]" % ack]),
-                                 onwire)
-            if not parts:
+                seg = b"[%d]" % ack
+                self._frame_into(parts, Frame(Tag.ACK, [seg]), onwire,
+                                 len(seg))
+            if not parts and large is None:
                 continue
             if ctrl:
                 perf.inc("ctrl_frames_tx", ctrl)
                 if msgs:
                     perf.inc("ctrl_rode_tx", ctrl)
             perf.inc("tx_sends")
+            if large is not None:
+                nbytes = large.payload_len()
+                with tracer.section("msgr.tx_sock"):    # the hand-over
+                    job = writer.send_frame(parts, large)
+                if job is not None:
+                    copytrack.referenced("frame_tx", nbytes)
+                    try:
+                        await writer.frame_sent(job)
+                    finally:
+                        # counted when tx_worker_bytes is, sent or given
+                        # up: the one is a share of the other in any
+                        # window (`msgr_tx_worker_pct`)
+                        perf.inc("tx_direct_bytes", nbytes)
+                    continue
+                self._frame_into(parts, large, None, nbytes)    # declined
             with tracer.section("msgr.tx_sock"):    # `sendmsg`, tried inline
                 writer.writelines(parts)
             await writer.drain()
@@ -951,17 +1003,19 @@ class Connection:
         return self._last_acked_in
 
     def _frame_into(self, parts: list, frame: Frame,
-                    onwire: Onwire | None) -> int:
-        """Append `frame`'s wire form to `parts`; returns its payload
-        length."""
+                    onwire: Onwire | None, nbytes: int) -> None:
+        """Append the wire form of `frame`, of `nbytes` of payload, to
+        `parts`."""
         perf = self.messenger.perf
-        nbytes = frame.payload_len()
         if onwire is None and nbytes >= SPILL_SIZE:
             # plain crc mode, a payload the receiver will take
             # into a body of its own: sent from where its bytes
             # lie. The parts stay referenced by the transport's
             # queue until the kernel has them (and, on a lossless
             # session, by the message in _sent until it is acked).
+            # (From rxworker.LINE up the write loop hands the frame
+            # to the send worker instead, and comes here only where
+            # that declined it.)
             parts.extend(frame.encode_parts())
             perf.inc("tx_direct_bytes", nbytes)
         else:
@@ -971,7 +1025,6 @@ class Connection:
             blob = frame.encode()
             parts.append(blob if onwire is None else onwire.wrap(blob))
             perf.inc("tx_copied_bytes", nbytes)
-        return nbytes
 
     def _trim_sent(self, acked_seq: int) -> None:
         while self._sent and self._sent[0].seq <= acked_seq:

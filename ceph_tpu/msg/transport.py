@@ -3,7 +3,7 @@ lets the kernel write a frame's body straight into the buffer the frame
 keeps.
 
 A read of `n` bytes goes one of three ways, chosen from `n` against the
-spill's size and the receive worker's line, and from nothing else:
+spill's size and the worker's line, and from nothing else:
 
   * small (`n <= SPILL_SIZE`: the preamble, lengths and crc, ACK and
     keepalive frames, the handshake, control messages and small ops)
@@ -49,15 +49,34 @@ through a stream reader. Reading is paused only when the spill is full
 of unread bytes, or for the worker; never in the middle of a body that
 the transport itself receives.
 
-The write side is the transport's own queue with `drain()` on its
-high-water mark, as asyncio's stream writer had it. `writelines` puts a
-frame's parts there by reference and the transport sends them with one
-scatter `sendmsg`; what a partial send leaves stays views of the same
-objects. The messenger's write loop sends a frame that way when its
-payload is `SPILL_SIZE` or more, the same line the read side draws
-between the spill and a body of its own, and as one packed blob below
-it. One object is both ends: the messenger keeps it as reader and
-writer.
+The write side draws the same two lines. Under `rxworker.LINE` it is
+the transport's own queue with `drain()` on its high-water mark, as
+asyncio's stream writer had it. `writelines` puts a frame's parts there
+by reference and the transport sends them with one scatter `sendmsg`;
+what a partial send leaves stays views of the same objects, and the
+queue keeps them alive until the kernel has the last byte. The
+messenger's write loop sends a frame that way when its payload is
+`SPILL_SIZE` or more, the same line the read side draws between the
+spill and a body of its own, and as one packed blob below it. A
+plain-crc frame of `rxworker.LINE` or more the loop does not send at
+all: `send_frame` hands its segments, and the small blobs the same
+wake-up framed in front of it, to the worker (`msg/rxworker.py`), whose
+thread computes the segments' crcs, `sendmsg`s from where the parts lie
+on a dup of the socket that the endpoint keeps for the connection's
+life, waits for `EPOLLOUT` itself where the socket is full, and wakes
+the loop once, when the kernel has the last byte (`frame_sent`, where
+the write loop awaits `drain()` on the other path). The job, not the
+transport's queue, keeps every part alive until it is reaped or taken
+back. A frame is handed over only while the transport's own queue is
+empty, and nothing is written to the transport while a job is out (the
+write loop is the one writer and it is waiting), so the bytes of two
+owners never interleave; where the queue is not empty or the submit
+fails the frame goes the transport's way (`tx_worker_declined`). A job
+is taken back by `close`, a lost connection or a cancelled write loop
+before the fault is raised; a frame it leaves cut short on the wire
+takes the transport with it (`abort`), so no byte follows it. Where the
+native library is missing `writelines` sends these frames too. One
+object is both ends: the messenger keeps it as reader and writer.
 """
 from __future__ import annotations
 
@@ -121,6 +140,8 @@ class Endpoint(asyncio.BufferedProtocol):
         self._fd = -1               # the socket, where the worker can have it
         self._port = None           # the worker's, once a body went there
         self._job: rxworker.Job | None = None   # body in the worker's hands
+        self._tx_fd = -1            # a dup the worker sends on, once it has
+        self._tx_job: rxworker.Job | None = None    # frame in its hands
         self._need = 0              # spill bytes the parked read wants
         self._small_run = 0         # bytes of small reads since a body
         self._read_waiter: asyncio.Future | None = None
@@ -197,6 +218,10 @@ class Endpoint(asyncio.BufferedProtocol):
             self._exc = exc
         self._fd = -1       # asyncio closes it when this returns
         self._take_back()
+        self._take_back_tx()
+        if self._tx_fd >= 0:
+            os.close(self._tx_fd)
+            self._tx_fd = -1
         if self._port is not None:
             port, self._port = self._port, None
             rxworker.release(port)
@@ -388,6 +413,81 @@ class Endpoint(asyncio.BufferedProtocol):
 
     # -- write side ----------------------------------------------------------
 
+    def worker_sends(self, nbytes: int) -> bool:
+        """True where a plain-crc frame of `nbytes` of payload is the
+        send worker's to send (`send_frame`) and not the transport's:
+        from the line up, on a socket, with the native library."""
+        return nbytes >= rxworker.LINE and self._fd >= 0 \
+            and not self._lost and rxworker.available()
+
+    def send_frame(self, head: list, frame) -> rxworker.Job | None:
+        """Hand `frame` to the send worker, the packed blobs of `head`
+        (what the same wake-up framed in front of it) leaving first in
+        the same job: the thread computes the segments' crcs and
+        `sendmsg`s from where the parts lie, and `frame_sent` awaits it.
+        The job holds every part until it is reaped or taken back.
+        None, and `tx_worker_declined` counted, where the transport's
+        own queue still holds bytes (two owners' bytes would interleave)
+        or the worker cannot have the frame (no thread, no fd to spare):
+        the caller sends it through `writelines`."""
+        if not (self.transport.is_closing()
+                or self.transport.get_write_buffer_size()):
+            try:
+                if self._port is None:
+                    self._port = rxworker.acquire(self._loop)
+                if self._tx_fd < 0:
+                    self._tx_fd = os.dup(self._fd)
+                self._tx_job = rxworker.submit_tx(
+                    self._port, self._tx_fd, self._fd, b"".join(head),
+                    *frame.send_args(), self._sent_back)
+                return self._tx_job
+            except OSError:
+                pass
+        self._perf.inc("tx_worker_declined")
+        return None
+
+    async def frame_sent(self, job) -> None:
+        """Wait until the kernel has the last byte of `job`'s frame: the
+        worker's counterpart of `drain()`."""
+        try:
+            sent, sends, cpu_ns, _bad, status = await job.fut
+        finally:
+            # a cancelled write loop: the frame is cut short on the wire
+            self._take_back_tx()
+        if status == rxworker.LOST:     # `_take_back_tx` has counted it
+            raise self._exc or ConnectionResetError("connection lost")
+        self._count_tx_worker(job, sent, cpu_ns)
+        if status != rxworker.WHOLE:
+            # the thread met the fault before the transport did; the
+            # frame is cut short on the wire and no byte may follow it
+            if not self._lost:
+                self.transport.abort()
+            raise OSError(status, os.strerror(status))
+        self._perf.inc("tx_worker_bodies")
+
+    def _count_tx_worker(self, job, sent: int, cpu_ns: int) -> None:
+        # of `sent`, what was payload: not what leaves in front of it
+        # (`job.have`: the head and the preamble), nor the crcs
+        self._perf.inc("tx_worker_bytes",
+                       min(max(sent - job.have, 0), job.payload))
+        self._perf.inc("tx_worker_cpu_ns", cpu_ns)
+
+    def _sent_back(self) -> None:
+        self._tx_job = None
+
+    def _take_back_tx(self) -> None:
+        """No frame is the send worker's when this returns (`close`, a
+        lost connection, a cancelled write loop). One it had not
+        finished is cut short on the wire: the transport is aborted, so
+        that no byte follows it."""
+        job, self._tx_job = self._tx_job, None
+        sent = rxworker.cancel(job) if job is not None else None
+        if sent is not None:
+            self._perf.inc("tx_worker_cancelled")
+            self._count_tx_worker(job, sent, 0)
+            if not self._lost:
+                self.transport.abort()
+
     def write(self, data) -> None:
         self.transport.write(data)
 
@@ -422,7 +522,18 @@ class Endpoint(asyncio.BufferedProtocol):
 
     def close(self) -> None:
         self._take_back()
+        self._take_back_tx()
         self.transport.close()
+
+    def __del__(self) -> None:
+        # a loop that died with the endpoint open never called
+        # `connection_lost`; the socket closes with its object, the
+        # send dup is a bare number
+        if self._tx_fd >= 0:
+            try:
+                os.close(self._tx_fd)
+            except OSError:
+                pass
 
     async def wait_closed(self) -> None:
         # shielded: a waiter that is cancelled must not cancel the

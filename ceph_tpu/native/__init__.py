@@ -53,7 +53,7 @@ def _build() -> str:
 
 
 def declare_rxw(lib) -> None:
-    """The receive worker's entry points (native/ec_native.cc, `rxw_*`)
+    """The socket worker's entry points (native/ec_native.cc, `rxw_*`)
     on a handle of the library: `load()`'s, or msg/rxworker.py's second
     one, whose calls keep the interpreter's lock."""
     u64p = ctypes.POINTER(ctypes.c_uint64)
@@ -61,13 +61,21 @@ def declare_rxw(lib) -> None:
     lib.rxw_submit.argtypes = [
         ctypes.c_uint64, ctypes.c_int, ctypes.c_void_p, ctypes.c_uint64,
         ctypes.c_uint64, u64p, ctypes.c_int, ctypes.c_int]
+    lib.rxw_submit_tx.restype = ctypes.c_int
+    lib.rxw_submit_tx.argtypes = [      # ..., then frame_crcs' own
+        ctypes.c_uint64, ctypes.c_int, ctypes.c_int, ctypes.c_int,
+        ctypes.c_void_p, ctypes.c_uint64,
+        ctypes.c_uint32, ctypes.c_uint32, ctypes.c_int,
+        u64p, ctypes.POINTER(ctypes.c_char_p), u64p, ctypes.c_void_p]
     lib.rxw_cancel.restype = ctypes.c_int64
     lib.rxw_cancel.argtypes = [ctypes.c_uint64]
     lib.rxw_progress.restype = ctypes.c_int64
     lib.rxw_progress.argtypes = [ctypes.c_uint64]
     lib.rxw_reap.restype = ctypes.c_int     # six a completion, two signed
     lib.rxw_reap.argtypes = [ctypes.POINTER(ctypes.c_int64), ctypes.c_int]
-    for name in ("rxw_start", "rxw_stop", "rxw_running", "rxw_jobs"):
+    lib.rxw_start.restype = ctypes.c_int
+    lib.rxw_start.argtypes = [ctypes.c_int]
+    for name in ("rxw_stop", "rxw_running", "rxw_jobs"):
         getattr(lib, name).restype = ctypes.c_int
         getattr(lib, name).argtypes = []
     lib.rxw_forked.restype = None
@@ -111,7 +119,7 @@ def load() -> ctypes.CDLL:
                 lib.frame_verify_body.restype = ctypes.c_int
                 lib.frame_verify_body.argtypes = [ctypes.c_void_p, u64p,
                                                   ctypes.c_int]
-            # the messenger's receive worker (Linux only)
+            # the messenger's socket worker (Linux only)
             if hasattr(lib, "rxw_submit"):
                 declare_rxw(lib)
             lib.ec_native_have_avx2.restype = ctypes.c_int

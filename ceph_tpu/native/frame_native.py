@@ -19,8 +19,11 @@ What did pay (PR 52) is a hand-over a BODY: a body of
 checked as it arrives, by a native thread that never takes the
 interpreter's lock; the loop pays one submit and one reap for a
 receive and a crc pass of half a millisecond and more, and
-`verify_body` is then not called for that frame at all. Bodies under
-the line, and every `pack` and `crcs`, are as they were.
+`verify_body` is then not called for that frame at all. The send side
+followed (PR 54): for a plain-crc frame of that line or more `crcs` is
+not called either; `_flatten` below gives its parts to the same worker
+(`rxworker.submit_tx`), which computes the crcs as it sends. Bodies and
+frames under the line, and every `pack`, are as they were.
 The wire layout is bit-identical to the pure-Python path; frames.py
 probes `available()` at import and silently keeps the Python fallback
 when the library (or a compiler to build it) is missing.

@@ -466,17 +466,27 @@ void planes_from_stripes(const uint8_t* src, size_t S, size_t n, size_t C,
 }  // extern "C"
 
 // ---------------------------------------------------------------------------
-// The messenger's receive worker (ceph_tpu/msg/rxworker.py; upstream's
+// The messenger's socket worker (ceph_tpu/msg/rxworker.py; upstream's
 // AsyncMessenger Worker, src/msg/async/Stack.h, cut down to what the
-// interpreter's lock leaves worth moving): ONE native thread with an epoll
-// set of the sockets that have a large frame body in progress. A job is a
-// dup of the connection's fd, the body's buffer, how much of it is already
-// there and the segments' lengths. The thread recvs into the unfilled tail,
-// never past the body's end, chains the segments' crc32c over the bytes as
-// they arrive, and posts a completion the event loop reaps through one call
-// (`rxw_reap`) after a wake-up on an eventfd of its own. The thread runs no
-// Python and never takes the interpreter's lock. A cancel waits until the
-// thread has let go of the job's fd and buffer.
+// interpreter's lock leaves worth moving): native threads, each with an
+// epoll set of the sockets that have a large frame in progress; a
+// connection's jobs go to one thread, chosen by its fd. A job is one of two
+// kinds.
+//   A receive: a dup of the connection's fd, the body's buffer, how much of
+// it is already there and the segments' lengths. The thread recvs into the
+// unfilled tail, never past the body's end, and chains the segments' crc32c
+// over the bytes as they arrive.
+//   A send: the connection's send dup (the caller's, for the connection's
+// life), the frame's parts where they lie and a header buffer of the job's
+// own (the preamble, written at the submit, and four bytes of crc a
+// segment). The thread chains each segment's crc32c over its parts a chunk
+// ahead of the send, writes it into its slot, and sendmsgs from where the
+// bytes lie until the kernel has them all, waiting for EPOLLOUT where the
+// socket is full: the bytes on the wire are frame_pack's.
+// Either way the thread posts a completion the event loop reaps through one
+// call (`rxw_reap`) after a wake-up on an eventfd of its own. The threads
+// run no Python and never take the interpreter's lock. A cancel waits until
+// the thread has let go of the job's fd and buffers.
 // ---------------------------------------------------------------------------
 
 #if defined(__linux__)
@@ -496,22 +506,37 @@ void planes_from_stripes(const uint8_t* src, size_t S, size_t n, size_t C,
 #include <sys/epoll.h>
 #include <sys/eventfd.h>
 #include <sys/socket.h>
+#include <sys/uio.h>
 #include <time.h>
 #include <unistd.h>
 
 namespace {
 
 constexpr int RXW_MAX_SEGMENTS = 4;
+constexpr int RXW_MAX_THREADS = 8;
 constexpr uint64_t RXW_WAKE = ~0ull;    // the wake eventfd's epoll datum
 constexpr int RXW_FIELDS = 6;           // u64s a completion, see rxw_reap
+constexpr uint64_t TXW_CHUNK = 1 << 20;     // crc this far ahead of the send
+constexpr int TXW_IOV = 64;             // parts a sendmsg
 
-struct RxJob {
+// One stretch of a send: as it lies (the head, the preamble), hashed into
+// its segment's crc on the way, or the four bytes that crc is written to.
+enum TxKind { TX_PLAIN, TX_HASHED, TX_SLOT };
+
+struct TxPart {
+  uint8_t* base;
+  uint64_t len;
+  TxKind kind;
+};
+
+struct RxJob {            // a receive, or (`tx`) a send
   uint64_t token;
-  int fd;                 // a dup, the worker's own: closed when let go
+  int fd;                 // a receive's: a dup, closed when let go
+  bool tx = false;        // a send's fd is the caller's, never closed here
   int notify_fd;          // the submitting loop's eventfd
   uint8_t* dst;
-  std::atomic<uint64_t> have;   // bytes there (read by rxw_progress)
-  uint64_t len;           // bytes of the whole body
+  std::atomic<uint64_t> have;   // bytes there / sent (read by rxw_progress)
+  uint64_t len;           // bytes of the whole body / of everything to send
   int nseg;               // 0: no crc (an onwire blob)
   uint64_t seg_lens[RXW_MAX_SEGMENTS];
   // the crc pass: everything before `crc_pos` is hashed or compared
@@ -519,8 +544,14 @@ struct RxJob {
   int seg = 0;
   uint32_t crc = 0;
   int bad = -1;           // first segment whose crc mismatched
-  uint64_t recvs = 0, cpu_ns = 0;
+  uint64_t recvs = 0, cpu_ns = 0;   // recvs: system calls that moved bytes
   bool in_epoll = false;
+  // a send: `parts[crc_i]` from `crc_off` is the next byte to hash, the
+  // first `ready` bytes are final, `parts[send_i]` from `send_off` is the
+  // next to leave
+  std::vector<TxPart> parts;
+  size_t crc_i = 0, send_i = 0;
+  uint64_t crc_off = 0, send_off = 0, ready = 0;
 };
 
 struct RxDone {
@@ -540,7 +571,7 @@ struct RxWorker {
   std::thread thread;
 };
 
-RxWorker* g_rxw = nullptr;      // guarded by g_rxw_m
+std::vector<RxWorker*> g_rxw;   // the threads; guarded by g_rxw_m
 std::mutex g_rxw_m;
 
 uint64_t thread_cpu_ns() {
@@ -573,19 +604,17 @@ void rxw_crc_advance(RxJob* j) {
   }
 }
 
-// The job's fd and buffer are the worker's no longer. Called under `m`.
+// The job's fd and buffers are the worker's no longer. Called under `m`.
 void rxw_release(RxWorker* w, RxJob* j) {
   if (j->in_epoll) epoll_ctl(w->epfd, EPOLL_CTL_DEL, j->fd, nullptr);
-  close(j->fd);
+  if (!j->tx) close(j->fd);
   w->jobs.erase(j->token);
   delete j;
 }
 
-// Work on one job until its body is whole, the peer is gone, or the socket
-// is empty. `w->running` is the job's token: nobody else touches it.
-void rxw_work(RxWorker* w, RxJob* j) {
-  uint64_t t0 = thread_cpu_ns();
-  int64_t status = 1;               // 1: still waiting for bytes
+// Receive until the body is whole (0), the peer is gone (-1, or the errno)
+// or the socket is empty (1).
+int64_t rxw_recv(RxJob* j) {
   if (j->have) rxw_crc_advance(j);  // the head the spill held
   while (j->have < j->len) {
     ssize_t r = recv(j->fd, j->dst + j->have, (size_t)(j->len - j->have),
@@ -595,21 +624,101 @@ void rxw_work(RxWorker* w, RxJob* j) {
       j->recvs++;
       rxw_crc_advance(j);
     } else if (r == 0) {
-      status = -1;
-      break;
+      return -1;
     } else if (errno == EINTR) {
       continue;
     } else if (errno == EAGAIN || errno == EWOULDBLOCK) {
-      break;
+      return 1;
     } else {
-      status = errno;
-      break;
+      return errno;
     }
   }
-  if (j->have == j->len) status = 0;
+  return 0;
+}
+
+// Make up to `budget` more bytes of a send final: hash segment parts into
+// the running crc and write it into the slot that follows them.
+void txw_crc_advance(RxJob* j, uint64_t budget) {
+  while (j->crc_i < j->parts.size()) {
+    TxPart& p = j->parts[j->crc_i];
+    if (p.kind == TX_HASHED) {
+      if (!budget) return;
+      uint64_t n = p.len - j->crc_off;
+      if (n > budget) n = budget;
+      j->crc = crc32c(j->crc, p.base + j->crc_off, (size_t)n);
+      j->crc_off += n;
+      j->ready += n;
+      budget -= n;
+      if (j->crc_off < p.len) return;
+    } else {
+      if (p.kind == TX_SLOT) {
+        put_u32le(p.base, j->crc);
+        j->crc = 0;
+      }
+      j->ready += p.len;
+    }
+    j->crc_i++;
+    j->crc_off = 0;
+  }
+}
+
+// Send what is final until the kernel has all of it (0), the connection is
+// gone (the errno) or the socket is full (1: every crc is computed by then).
+int64_t txw_send(RxJob* j) {
+  for (;;) {
+    uint64_t sent = j->have;
+    if (sent == j->len) return 0;
+    if (j->ready < j->len && j->ready - sent < TXW_CHUNK)
+      txw_crc_advance(j, TXW_CHUNK);
+    struct iovec iov[TXW_IOV];
+    int n = 0;
+    uint64_t room = j->ready - sent, off = j->send_off;
+    for (size_t i = j->send_i; room && n < TXW_IOV; i++, off = 0) {
+      const TxPart& p = j->parts[i];
+      uint64_t take = p.len - off < room ? p.len - off : room;
+      iov[n].iov_base = p.base + off;
+      iov[n].iov_len = (size_t)take;
+      n++;
+      room -= take;
+    }
+    struct msghdr mh = {};
+    mh.msg_iov = iov;
+    mh.msg_iovlen = (size_t)n;
+    ssize_t r = sendmsg(j->fd, &mh, MSG_DONTWAIT | MSG_NOSIGNAL);
+    if (r >= 0) {
+      j->recvs++;
+      j->have += (uint64_t)r;
+      uint64_t left = (uint64_t)r;
+      while (left) {
+        uint64_t in_part = j->parts[j->send_i].len - j->send_off;
+        if (left < in_part) {
+          j->send_off += left;
+          break;
+        }
+        left -= in_part;
+        j->send_i++;
+        j->send_off = 0;
+      }
+    } else if (errno == EINTR) {
+      continue;
+    } else if (errno == EAGAIN || errno == EWOULDBLOCK) {
+      txw_crc_advance(j, ~0ull);    // the wait pays for the rest of it
+      return 1;
+    } else {
+      return errno;
+    }
+  }
+}
+
+// Work on one job until it is whole, the peer is gone, or the socket is
+// empty (a receive) or full (a send). `w->running` is the job's token:
+// nobody else touches it.
+void rxw_work(RxWorker* w, RxJob* j) {
+  uint64_t t0 = thread_cpu_ns();
+  int64_t status = j->tx ? txw_send(j) : rxw_recv(j);
   if (status == 1 && !j->in_epoll) {
     struct epoll_event ev;
-    ev.events = EPOLLIN;
+    ev.events = j->tx ? EPOLLOUT : EPOLLIN;
     ev.data.u64 = j->token;
     if (epoll_ctl(w->epfd, EPOLL_CTL_ADD, j->fd, &ev) == 0)
       j->in_epoll = true;
@@ -676,7 +785,7 @@ void rxw_main(RxWorker* w) {
 
 void rxw_close_fds(RxWorker* w) {
   for (auto& kv : w->jobs) {
-    close(kv.second->fd);
+    if (!kv.second->tx) close(kv.second->fd);
     delete kv.second;
   }
   w->jobs.clear();
@@ -684,14 +793,8 @@ void rxw_close_fds(RxWorker* w) {
   if (w->wakefd >= 0) close(w->wakefd);
 }
 
-}  // namespace
-
-extern "C" {
-
-// Start the thread if it does not run. 0, or -errno.
-int rxw_start() {
-  std::lock_guard<std::mutex> g(g_rxw_m);
-  if (g_rxw) return 0;
+// One more thread. Under g_rxw_m. 0, or -errno with nothing left behind.
+int rxw_start_one() {
   RxWorker* w = new RxWorker();
   w->epfd = epoll_create1(EPOLL_CLOEXEC);
   w->wakefd = eventfd(0, EFD_NONBLOCK | EFD_CLOEXEC);
@@ -712,59 +815,101 @@ int rxw_start() {
     delete w;
     return -EAGAIN;
   }
-  g_rxw = w;
+  g_rxw.push_back(w);
   return 0;
 }
 
-// Stop the thread, drop every job (their buffers are the caller's again)
-// and close the worker's fds. The jobs still there, which the caller
-// should have cancelled: their count.
-int rxw_stop() {
-  std::lock_guard<std::mutex> g(g_rxw_m);
-  RxWorker* w = g_rxw;
-  if (!w) return 0;
-  g_rxw = nullptr;
+// Stop every thread, drop every job and close the workers' fds. Under
+// g_rxw_m. The jobs that were still there.
+int rxw_stop_all() {
+  int left = 0;
+  for (RxWorker* w : g_rxw) {
+    {
+      std::lock_guard<std::mutex> g2(w->m);
+      w->stop = true;
+    }
+    uint64_t one = 1;
+    ssize_t wr = write(w->wakefd, &one, 8);
+    (void)wr;
+    w->thread.join();
+    left += (int)w->jobs.size();
+    rxw_close_fds(w);
+    delete w;
+  }
+  g_rxw.clear();
+  return left;
+}
+
+// Enter `j` with the thread that serves the connection whose socket is
+// `pin`. Under g_rxw_m, with threads running.
+void rxw_enter(int pin, RxJob* j) {
+  RxWorker* w = g_rxw[(size_t)(pin < 0 ? 0 : pin) % g_rxw.size()];
   {
     std::lock_guard<std::mutex> g2(w->m);
-    w->stop = true;
+    w->jobs[j->token] = j;
+    w->fresh.push_back(j->token);
   }
   uint64_t one = 1;
   ssize_t wr = write(w->wakefd, &one, 8);
   (void)wr;
-  w->thread.join();
-  int left = (int)w->jobs.size();
-  rxw_close_fds(w);
-  delete w;
-  return left;
 }
 
-// In the child of a fork, which has the worker's fds and not its thread:
-// close the two fds that are the worker's alone and start anew. Whatever
+}  // namespace
+
+extern "C" {
+
+// Start `threads` threads if none runs. 0, or -errno.
+int rxw_start(int threads) {
+  std::lock_guard<std::mutex> g(g_rxw_m);
+  if (!g_rxw.empty()) return 0;
+  if (threads < 1 || threads > RXW_MAX_THREADS) return -EINVAL;
+  for (int i = 0; i < threads; i++) {
+    int err = rxw_start_one();
+    if (err) {
+      rxw_stop_all();
+      return err;
+    }
+  }
+  return 0;
+}
+
+// Stop the threads, drop every job (their buffers are the caller's again)
+// and close the workers' fds. The jobs still there, which the caller
+// should have cancelled: their count.
+int rxw_stop() {
+  std::lock_guard<std::mutex> g(g_rxw_m);
+  return rxw_stop_all();
+}
+
+// In the child of a fork, which has the workers' fds and not their threads:
+// close the two fds a worker that are its alone and start anew. Whatever
 // the parent's threads held or were changing at the fork is left alone
-// (the tables are not walked, the struct is leaked); a job's dup stays
+// (the tables are not walked, the structs are leaked); a job's dup stays
 // open in the child as the socket it was made from does.
 void rxw_forked() {
   new (&g_rxw_m) std::mutex();
-  RxWorker* w = g_rxw;
-  g_rxw = nullptr;
-  if (w) {
+  for (RxWorker* w : g_rxw) {
     close(w->epfd);
     close(w->wakefd);
   }
+  new (&g_rxw) std::vector<RxWorker*>();
 }
 
-// 1 while the thread runs.
+// The threads that run.
 int rxw_running() {
   std::lock_guard<std::mutex> g(g_rxw_m);
-  return g_rxw ? 1 : 0;
+  return (int)g_rxw.size();
 }
 
 // Jobs submitted and not yet completed or cancelled.
 int rxw_jobs() {
   std::lock_guard<std::mutex> g(g_rxw_m);
-  if (!g_rxw) return 0;
-  std::lock_guard<std::mutex> g2(g_rxw->m);
-  return (int)g_rxw->jobs.size();
+  int n = 0;
+  for (RxWorker* w : g_rxw) {
+    std::lock_guard<std::mutex> g2(w->m);
+    n += (int)w->jobs.size();
+  }
+  return n;
 }
 
 // Hand the worker `fd` (it works on a dup) for the rest of a body of `len`
@@ -777,8 +922,7 @@ int rxw_submit(uint64_t token, int fd, uint8_t* dst, uint64_t have,
                int notify_fd) {
   if (nseg < 0 || nseg > RXW_MAX_SEGMENTS || have >= len) return -EINVAL;
   std::lock_guard<std::mutex> g(g_rxw_m);
-  RxWorker* w = g_rxw;
-  if (!w) return -ESRCH;
+  if (g_rxw.empty()) return -ESRCH;
   int dupfd = fcntl(fd, F_DUPFD_CLOEXEC, 0);
   if (dupfd < 0) return -errno;
   RxJob* j = new RxJob();
@@ -790,61 +934,97 @@ int rxw_submit(uint64_t token, int fd, uint8_t* dst, uint64_t have,
   j->len = len;
   j->nseg = nseg;
   for (int i = 0; i < nseg; i++) j->seg_lens[i] = seg_lens[i];
-  {
-    std::lock_guard<std::mutex> g2(w->m);
-    w->jobs[token] = j;
-    w->fresh.push_back(token);
-  }
-  uint64_t one = 1;
-  ssize_t wr = write(w->wakefd, &one, 8);
-  (void)wr;
+  rxw_enter(fd, j);
   return 0;
 }
 
-// Take a job back. Returns once the thread has let go of its fd and buffer:
-// the bytes that are there (>= 0), or -1 if the job is not the worker's any
-// more (its completion is in the ring, or was reaped).
+// Hand the worker one frame to send on `fd`, which stays the caller's: a
+// dup of the connection's socket `pin` that it keeps for the connection's
+// life and closes after the last job on it is reaped or cancelled. The
+// frame is frame_crcs' arguments; `hdr` (8 + 8*nseg bytes, the job's own)
+// gets the preamble here and each segment's crc from the thread. `head`
+// (`head_len` bytes, may be 0) leaves in front of the frame. Every part,
+// `head` and `hdr` stay alive and unwritten until the job is reaped or
+// cancelled. 0, or -errno.
+int rxw_submit_tx(uint64_t token, int fd, int pin, int notify_fd,
+                  uint8_t* head, uint64_t head_len,
+                  uint32_t magic, uint32_t tag, int nseg,
+                  const uint64_t* seg_parts, uint8_t* const* parts,
+                  const uint64_t* part_lens, uint8_t* hdr) {
+  if (nseg < 0 || nseg > RXW_MAX_SEGMENTS) return -EINVAL;
+  std::lock_guard<std::mutex> g(g_rxw_m);
+  if (g_rxw.empty()) return -ESRCH;
+  RxJob* j = new RxJob();
+  j->token = token;
+  j->fd = fd;
+  j->tx = true;
+  j->notify_fd = notify_fd;
+  j->have = 0;
+  j->len = 0;
+  auto add = [j](uint8_t* base, uint64_t len, TxKind kind) {
+    j->parts.push_back(TxPart{base, len, kind});
+    j->len += len;
+  };
+  uint8_t* slot = put_preamble(magic, tag, nseg, seg_parts, part_lens, hdr);
+  if (head_len) add(head, head_len, TX_PLAIN);
+  add(hdr, (uint64_t)(slot - hdr), TX_PLAIN);
+  size_t part = 0;
+  for (int s = 0; s < nseg; s++, slot += 4) {
+    for (uint64_t k = 0; k < seg_parts[s]; k++, part++)
+      if (part_lens[part]) add(parts[part], part_lens[part], TX_HASHED);
+    add(slot, 4, TX_SLOT);
+  }
+  rxw_enter(pin, j);
+  return 0;
+}
+
+// Take a job back. Returns once the thread has let go of its fd and
+// buffers: the bytes that are there or were sent (>= 0), or -1 if the job
+// is not the worker's any more (its completion is in the ring, or was
+// reaped).
 int64_t rxw_cancel(uint64_t token) {
   std::lock_guard<std::mutex> g(g_rxw_m);
-  RxWorker* w = g_rxw;
-  if (!w) return -1;
-  std::unique_lock<std::mutex> l(w->m);
-  w->let_go.wait(l, [&] { return w->running != token; });
-  auto it = w->jobs.find(token);
-  if (it == w->jobs.end()) return -1;
-  int64_t got = (int64_t)it->second->have.load();
-  rxw_release(w, it->second);
-  return got;
+  for (RxWorker* w : g_rxw) {
+    std::unique_lock<std::mutex> l(w->m);
+    w->let_go.wait(l, [&] { return w->running != token; });
+    auto it = w->jobs.find(token);
+    if (it == w->jobs.end()) continue;
+    int64_t got = (int64_t)it->second->have.load();
+    rxw_release(w, it->second);
+    return got;
+  }
+  return -1;
 }
 
-// Bytes of job `token`'s body that are there, or -1 if the worker has no
-// such job (any more).
+// Bytes of job `token` that are there or were sent, or -1 if the worker
+// has no such job (any more).
 int64_t rxw_progress(uint64_t token) {
   std::lock_guard<std::mutex> g(g_rxw_m);
-  RxWorker* w = g_rxw;
-  if (!w) return -1;
-  std::lock_guard<std::mutex> g2(w->m);
-  auto it = w->jobs.find(token);
-  return it == w->jobs.end() ? -1 : (int64_t)it->second->have.load();
+  for (RxWorker* w : g_rxw) {
+    std::lock_guard<std::mutex> g2(w->m);
+    auto it = w->jobs.find(token);
+    if (it != w->jobs.end()) return (int64_t)it->second->have.load();
+  }
+  return -1;
 }
 
-// Up to `max` completions into `out`, six u64 each: token, bytes there,
-// recv calls that returned bytes, the thread's CPU ns on the job, the
-// first segment whose crc mismatched or -1, and the status (0 the body is
+// Up to `max` completions into `out`, six u64 each: token, bytes there or
+// sent, system calls that moved some, the thread's CPU ns on the job, the
+// first segment whose crc mismatched or -1 (a send: -1), and the status (0
 // whole, -1 EOF, else the errno). Returns how many.
 int rxw_reap(uint64_t* out, int max) {
   std::lock_guard<std::mutex> g(g_rxw_m);
-  RxWorker* w = g_rxw;
-  if (!w) return 0;
-  std::lock_guard<std::mutex> g2(w->m);
   int n = 0;
-  while (n < max && !w->done.empty()) {
-    const RxDone& d = w->done.front();
-    uint64_t* o = out + n * RXW_FIELDS;
-    o[0] = d.token; o[1] = d.got; o[2] = d.recvs; o[3] = d.cpu_ns;
-    o[4] = (uint64_t)d.bad; o[5] = (uint64_t)d.status;
-    w->done.pop_front();
-    n++;
+  for (RxWorker* w : g_rxw) {
+    std::lock_guard<std::mutex> g2(w->m);
+    while (n < max && !w->done.empty()) {
+      const RxDone& d = w->done.front();
+      uint64_t* o = out + n * RXW_FIELDS;
+      o[0] = d.token; o[1] = d.got; o[2] = d.recvs; o[3] = d.cpu_ns;
+      o[4] = (uint64_t)d.bad; o[5] = (uint64_t)d.status;
+      w->done.pop_front();
+      n++;
+    }
   }
   return n;
 }

@@ -286,21 +286,24 @@ def test_a_parents_receiver_reads_the_changes_frames(both_codecs, n,
 # -- the receive worker's native side (native/ec_native.cc rxw_*) -------------
 
 import ctypes  # noqa: E402
+import errno  # noqa: E402
 import os  # noqa: E402
 import select  # noqa: E402
 import socket  # noqa: E402
 
 
-@pytest.fixture
-def rxw():
-    """The library with the worker's thread running, and stopped after."""
+@pytest.fixture(params=[1, 2], ids=["one_thread", "two_threads"])
+def rxw(request):
+    """The library with the worker's threads running, and stopped after."""
     _native_or_skip()
     from ceph_tpu.msg import rxworker
     if not rxworker.available():
         pytest.skip("the library has no receive worker (not Linux)")
     assert not rxworker._ports, "an earlier test left the worker in use"
     lib = rxworker._lib
-    assert lib.rxw_start() == 0 and lib.rxw_start() == 0    # idempotent
+    n = request.param
+    assert lib.rxw_start(n) == 0 and lib.rxw_start(n) == 0  # idempotent
+    assert lib.rxw_running() == n
     yield lib
     assert lib.rxw_stop() == 0, "a job was left with the worker"
     assert lib.rxw_running() == 0
@@ -498,3 +501,235 @@ def test_sixty_jobs_at_once_share_the_thread_and_the_wake_ups(rxw):
     finally:
         for w in wires:
             w.close()
+
+
+# -- the worker's sends (rxw_submit_tx): a frame's parts and a header buffer ---
+
+_TX_SEGS = {
+    "one_segment": lambda r: [r.randbytes(700_000)],
+    "two_segments": lambda r: [b'{"type":112,"seq":9}', r.randbytes(524_288)],
+    "four_segments": lambda r: [r.randbytes(100_000), b"",
+                                r.randbytes(600_001), b"\x7c\xec" * 9],
+    "several_live_parts": lambda r: [
+        b'{"type":115}', b'{"msgs":[]}',
+        [r.randbytes(300_000), memoryview(r.randbytes(300_000)), b"",
+         bytearray(r.randbytes(300)), memoryview(r.randbytes(9)).toreadonly()]],
+    "empty_segments": lambda r: [b"", b"", b"", b""],
+    "no_segment": lambda r: [],
+}
+
+
+class _TxWire:
+    """A socket pair and an eventfd, and what a send job is handed: the
+    worker sends on `a` (the caller's fd, never closed by the worker)."""
+
+    def __init__(self, sndbuf=None):
+        self.a, self.b = socket.socketpair()
+        self.a.setblocking(False)
+        self.b.setblocking(False)
+        if sndbuf:
+            self.a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, sndbuf)
+            self.b.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, sndbuf)
+        self.efd = os.eventfd(0, os.EFD_NONBLOCK)
+        self.keep = []
+
+    def submit(self, lib, token, segments, head=b"", tag=int(Tag.MESSAGE)):
+        from ceph_tpu.native import frame_native
+        nseg = len(segments)
+        seg_parts, ptrs, lens, _n, keep = frame_native._flatten(segments)
+        hdr = bytearray(8 + 8 * nseg)
+        self.keep.append((head, hdr, keep, segments))
+        return lib.rxw_submit_tx(
+            token, self.a.fileno(), self.a.fileno(), self.efd, head,
+            len(head), frames.MAGIC, tag, nseg, seg_parts, ptrs, lens,
+            ctypes.addressof(ctypes.c_char.from_buffer(hdr)))
+
+    def drain(self, n=None, timeout=10.0) -> bytes:
+        """What the peer reads: `n` bytes, or all there is for a while."""
+        got = bytearray()
+        while n is None or len(got) < n:
+            if not select.select([self.b], [], [],
+                                 0.2 if n is None else timeout)[0]:
+                assert n is None, f"only {len(got)} of {n} bytes came"
+                break
+            chunk = self.b.recv(1 << 20)
+            if not chunk:
+                break
+            got += chunk
+        return bytes(got)
+
+    reap = _Wire.reap
+
+    def close(self):
+        self.keep.clear()
+        self.a.close()
+        self.b.close()
+        os.close(self.efd)
+
+
+@pytest.mark.parametrize("head", [b"", b"HEAD" * 500], ids=["bare", "head"])
+@pytest.mark.parametrize("shape", list(_TX_SEGS))
+def test_the_worker_sends_what_encode_packs(rxw, shape, head):
+    """The bytes a peer reads are `Frame.encode()`'s, crcs included,
+    behind the head the job was given, and the thread wrote the crcs
+    into the job's own header buffer."""
+    segs = _TX_SEGS[shape](random.Random(shape))
+    want = head + bytes(Frame(Tag.MESSAGE, segs).encode())
+    w = _TxWire()
+    try:
+        assert w.submit(rxw, 21, segs, head) == 0
+        got = w.drain(len(want))
+        (done,) = w.reap(rxw)
+        token, sent, sends, cpu_ns, bad, status = done
+        assert (token, sent, bad, status) == (21, len(want), -1, 0)
+        assert sends >= 1 and cpu_ns > 0
+        assert got == want
+        hdr = w.keep[0][1]
+        pre = 8 + 4 * len(segs)
+        assert bytes(hdr[:pre]) == want[len(head):len(head) + pre]
+        assert w.drain() == b"" and rxw.rxw_jobs() == 0
+    finally:
+        w.close()
+
+
+def test_a_send_into_a_small_socket_buffer_takes_several_rounds(rxw):
+    """4 MiB into a socket that holds a few KiB: the thread waits for
+    `EPOLLOUT` between rounds, holds up nobody meanwhile (a second job
+    on another socket ends first), and the bytes are the frame's."""
+    rng = random.Random(8)
+    segs = [b"h", [rng.randbytes(3 << 20), rng.randbytes(1 << 20)]]
+    want = bytes(Frame(Tag.MESSAGE, segs).encode())
+    small = [rng.randbytes(70_000)]
+    slow, fast = _TxWire(sndbuf=4096), _TxWire()
+    fast.efd, spare = slow.efd, fast.efd    # one loop's eventfd for both
+    try:
+        assert slow.submit(rxw, 31, segs) == 0
+        while rxw.rxw_progress(31) <= 0:
+            pass
+        assert 0 < rxw.rxw_progress(31) < len(want) // 2
+        assert fast.submit(rxw, 32, small) == 0
+        (first,) = slow.reap(rxw)
+        assert first[0] == 32 and first[5] == 0
+        assert 0 < rxw.rxw_progress(31) < len(want)
+        got = slow.drain(len(want))
+        (done,) = slow.reap(rxw)
+        assert done[:2] + done[4:] == (31, len(want), -1, 0)
+        assert done[2] > 4, "one sendmsg took 4 MiB through a 4 KiB buffer"
+        assert got == want
+        assert fast.drain() == bytes(Frame(Tag.MESSAGE, small).encode())
+    finally:
+        fast.efd = spare
+        slow.close()
+        fast.close()
+
+
+@pytest.mark.parametrize("how", ["cancel", "cancel_unstarted", "cancel_done",
+                                 "peer_closes"])
+def test_a_send_ends_once_and_lets_go_of_fd_and_parts(rxw, how):
+    """A cancel returns only once the thread has let go: not one byte
+    leaves after it, the fd stays the caller's (open, and usable), and
+    nothing is left with the worker."""
+    rng = random.Random(how)
+    segs = [rng.randbytes(2 << 20)]
+    wire = bytes(Frame(Tag.MESSAGE, segs).encode())
+    fds = len(os.listdir("/proc/self/fd"))
+    w = _TxWire(sndbuf=4096)
+    try:
+        if how == "cancel_done":
+            w.a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 8 << 20)
+        assert w.submit(rxw, 41, segs) == 0
+        if how == "cancel_unstarted":
+            sent = rxw.rxw_cancel(41)
+            assert 0 <= sent < len(wire)
+        else:
+            while rxw.rxw_progress(41) == 0:
+                pass
+        if how == "cancel":
+            sent = rxw.rxw_cancel(41)
+            assert 0 < sent < len(wire)
+            assert rxw.rxw_cancel(41) == -1             # once
+        elif how == "cancel_done":
+            got = w.drain(len(wire))
+            while rxw.rxw_progress(41) >= 0:
+                pass
+            assert rxw.rxw_cancel(41) == -1             # the ring has it
+            (done,) = w.reap(rxw)
+            assert done[:2] + done[4:] == (41, len(wire), -1, 0)
+            assert got == wire
+        elif how == "peer_closes":
+            w.b.close()
+            (done,) = w.reap(rxw)
+            assert done[0] == 41 and 0 < done[1] < len(wire)
+            assert done[5] in (errno.EPIPE, errno.ECONNRESET)
+        assert rxw.rxw_jobs() == 0 and rxw.rxw_progress(41) == -1
+        if how.startswith("cancel") and how != "cancel_done":
+            # what left before the cancel is a prefix of the frame, and
+            # the fd is the caller's: its own bytes follow, nothing else
+            got = w.drain()
+            assert len(got) == sent and got == wire[:sent]
+            w.a.send(b"mine")
+            assert w.drain() == b"mine"
+    finally:
+        if how == "peer_closes":
+            w.b = socket.socket()
+        w.close()
+    assert len(os.listdir("/proc/self/fd")) == fds
+
+
+def test_submit_tx_refuses_what_it_cannot_do(rxw):
+    w = _TxWire()
+    try:
+        assert w.submit(rxw, 1, [b"x"] * 5) == -errno.EINVAL    # 5 segments
+        assert rxw.rxw_jobs() == 0
+        assert rxw.rxw_stop() == 0
+        assert w.submit(rxw, 1, [b"x"]) == -errno.ESRCH         # no thread
+        assert rxw.rxw_cancel(1) == -1
+    finally:
+        w.close()
+
+
+def test_receives_and_sends_of_sixty_connections_share_the_threads(rxw):
+    """Each connection's send job and its peer's receive job at once,
+    all through one eventfd: every body arrives whole, crc checked by
+    the receiver's pass over what the sender's pass wrote."""
+    n = 600_000
+    rng = random.Random(6)
+    txs = [_TxWire() for _ in range(60)]
+    efd = txs[0].efd
+    bufs, datas = [], []
+    try:
+        for i, w in enumerate(txs):
+            data = rng.randbytes(n)
+            datas.append(data)
+            buf = bytearray(n + 4)
+            keep = ctypes.c_char.from_buffer(buf)
+            bufs.append((buf, keep))
+            lens = (ctypes.c_uint64 * 1)(n)
+            # the receiver takes the body; the 12 bytes of preamble are
+            # read here, as the endpoint's spill would
+            w.efd, w.own_efd = efd, w.efd
+            assert w.submit(rxw, 1000 + i, [data]) == 0
+            pre = b""
+            while len(pre) < 12:
+                select.select([w.b], [], [], 5.0)
+                pre += w.b.recv(12 - len(pre))
+            assert rxw.rxw_submit(2000 + i, w.b.fileno(),
+                                  ctypes.addressof(keep), 0, n + 4, lens, 1,
+                                  efd) == 0
+        got = {}
+        while len(got) < 2 * len(txs):
+            for d in txs[0].reap(rxw):
+                got[d[0]] = d
+        assert all(got[1000 + i][1] == n + 16 and got[1000 + i][5] == 0
+                   for i in range(60))
+        assert all(got[2000 + i][1] == n + 4 and got[2000 + i][4] == -1
+                   and got[2000 + i][5] == 0 for i in range(60))
+        assert all(bytes(b[:n]) == d for (b, _k), d in zip(bufs, datas))
+    finally:
+        for (buf, keep) in bufs:
+            del keep
+        bufs.clear()
+        for w in txs:
+            w.efd = getattr(w, "own_efd", w.efd)
+            w.close()
+

@@ -8,6 +8,7 @@ import asyncio
 import os
 import random
 import socket
+import time
 import zlib
 
 import pytest
@@ -478,14 +479,67 @@ def test_drain_waits_for_the_peer_and_fails_when_the_transport_is_lost():
 
 def _slow_wire(conn) -> None:
     """A small send buffer on `conn`'s socket (on each new one after a
-    reconnect: call it again), so that a body crosses in many sends, a
-    turn of the loop apart, and whoever receives it, the transport or
-    the receive worker, is seen with it half received."""
+    reconnect: call it again): with a `_Relay` behind it, whoever sends
+    a 4 MiB frame is still sending it when a quarter has arrived."""
     ep = conn._writer
     if ep is not None and not getattr(ep, "slowed", False):
         ep.get_extra_info("socket").setsockopt(
             socket.SOL_SOCKET, socket.SO_SNDBUF, 131072)
         ep.slowed = True
+
+
+class _Relay:
+    """A relay in front of `addr` that hands on at most 64 KiB a turn of
+    the loop and holds little itself (its sockets' buffers are small),
+    so that a body crosses in many pieces, a turn of the loop apart, and
+    whoever sends or receives it, a transport or the worker's thread, is
+    seen with it half done. A connection lost on one side is closed on
+    the other."""
+    BUF = 65536
+
+    def __init__(self, addr):
+        self.addr = addr
+        self._tasks: set = set()
+
+    async def start(self):
+        sock = socket.socket()
+        sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, self.BUF)
+        sock.bind(("127.0.0.1", 0))
+        self.server = await asyncio.start_server(self._serve, sock=sock)
+        return self.server.sockets[0].getsockname()[:2]
+
+    def _passing(self, data: bytes, seen: int, to_addr: bool) -> bytes:
+        """`data`, `seen` bytes into its connection and direction, as it
+        is handed on."""
+        return data
+
+    async def _serve(self, r, w):
+        self._tasks.add(asyncio.current_task())
+        ur, uw = await asyncio.open_connection(*self.addr)
+        for x in (w, uw):
+            x.get_extra_info("socket").setsockopt(
+                socket.SOL_SOCKET, socket.SO_SNDBUF, self.BUF)
+
+        async def pump(src, dst, to_addr):
+            seen = 0
+            try:
+                while data := await src.read(self.BUF):
+                    dst.write(self._passing(data, seen, to_addr))
+                    seen += len(data)
+                    await dst.drain()
+                    await asyncio.sleep(0)
+            except (ConnectionError, asyncio.CancelledError):
+                pass
+            finally:
+                dst.close()
+
+        await asyncio.gather(pump(r, uw, True), pump(ur, w, False))
+
+    async def stop(self):
+        self.server.close()
+        for t in list(self._tasks):
+            t.cancel()
+        await asyncio.gather(*self._tasks, return_exceptions=True)
 
 
 async def _wait_for(col: Collector, n: int) -> None:
@@ -534,8 +588,10 @@ def test_lossless_pair_survives_aborts_in_mid_body(codec, side):
         col = Collector()
         server.add_dispatcher(col)
         addr = await server.bind()
+        relay = _Relay(addr)
         client = Messenger("osd.2")
-        conn = await client.connect(addr, Policy.lossless_peer())
+        conn = await client.connect(await relay.start(),
+                                    Policy.lossless_peer())
         rng = random.Random(7)
         datas = [rng.randbytes((1 << 20) + i) for i in range(N)]
         aborts = 0
@@ -544,7 +600,6 @@ def test_lossless_pair_survives_aborts_in_mid_body(codec, side):
             nonlocal aborts
             while aborts < 3:
                 await asyncio.sleep(0)
-                _slow_wire(conn)
                 for c in list(server._sessions.values()):
                     ep = c._reader
                     if ep is None or ep.body_filled() < 100000:
@@ -569,6 +624,7 @@ def test_lossless_pair_survives_aborts_in_mid_body(codec, side):
         assert all(m.data == d for m, d in zip(col.messages, datas))
         await client.shutdown()
         await server.shutdown()
+        await relay.stop()
 
     run(main(), timeout=90)
 
@@ -692,15 +748,21 @@ def test_radoslint_sees_the_two_views_the_endpoint_keeps(tmp_path):
 LINE = rxworker.LINE
 
 
-@pytest.fixture(params=["worker", "no_native"])
-def rx(request, monkeypatch):
-    """With the receive worker, and with the native library made
-    unavailable: the endpoint's own path then serves the same cases."""
-    if request.param == "no_native":
+def _with_native(monkeypatch, wanted: bool) -> None:
+    """The worker's native library for this test: there (skipped where
+    it is not built), or made unavailable."""
+    if not wanted:
         monkeypatch.setattr(rxworker, "_checked", True)
         monkeypatch.setattr(rxworker, "_lib", None)
     elif not rxworker.available():
         pytest.skip("the native library is not built here")
+
+
+@pytest.fixture(params=["worker", "no_native"])
+def rx(request, monkeypatch):
+    """With the receive worker, and with the native library made
+    unavailable: the endpoint's own path then serves the same cases."""
+    _with_native(monkeypatch, request.param == "worker")
     return request.param
 
 
@@ -708,8 +770,15 @@ def _fds() -> int:
     return len(os.listdir("/proc/self/fd"))
 
 
-def _threads() -> int:
-    return len(os.listdir("/proc/self/task"))
+def _threads(settle: int | None = None) -> int:
+    """Tasks of this process; with `settle`, once no more than that many
+    are left or two seconds have passed: a thread that was joined is in
+    `/proc` a moment longer (the join returns when it clears its tid)."""
+    end = time.monotonic() + 2.0
+    while (n := len(os.listdir("/proc/self/task"))) > (settle or n) \
+            and time.monotonic() < end:
+        time.sleep(0.01)
+    return n
 
 
 def _worker_is_gone() -> None:
@@ -811,47 +880,20 @@ def test_a_bit_flipped_on_the_wire_above_the_line_faults_the_read(rx, seg):
     _worker_is_gone()
 
 
-class _FlipOnce:
+class _FlipOnce(_Relay):
     """A relay in front of `addr` that flips one bit of the bytes going
     to it, `at` bytes into the first connection, and is honest after."""
 
     def __init__(self, addr, at):
-        self.addr, self.at, self.flipped = addr, at, 0
-        self._tasks: set = set()
+        super().__init__(addr)
+        self.at, self.flipped = at, 0
 
-    async def start(self):
-        self.server = await asyncio.start_server(self._serve, "127.0.0.1", 0)
-        return self.server.sockets[0].getsockname()[:2]
-
-    async def _serve(self, r, w):
-        self._tasks.add(asyncio.current_task())
-        ur, uw = await asyncio.open_connection(*self.addr)
-        first = not self.flipped
-
-        async def pump(src, dst, flip):
-            seen = 0
-            try:
-                while data := await src.read(1 << 16):
-                    if flip and seen <= self.at < seen + len(data) \
-                            and not self.flipped:
-                        data = bytearray(data)
-                        data[self.at - seen] ^= 0x01
-                        self.flipped += 1
-                    seen += len(data)
-                    dst.write(data)
-                    await dst.drain()
-            except (ConnectionError, asyncio.CancelledError):
-                pass
-            finally:
-                dst.close()
-
-        await asyncio.gather(pump(r, uw, first), pump(ur, w, False))
-
-    async def stop(self):
-        self.server.close()
-        for t in list(self._tasks):
-            t.cancel()
-        await asyncio.gather(*self._tasks, return_exceptions=True)
+    def _passing(self, data, seen, to_addr):
+        if to_addr and not self.flipped and seen <= self.at < seen + len(data):
+            data = bytearray(data)
+            data[self.at - seen] ^= 0x01
+            self.flipped += 1
+        return data
 
 
 def test_a_flipped_bit_faults_the_connection_and_the_session_replays(rx):
@@ -967,10 +1009,12 @@ def test_a_reconnect_storm_loses_nothing_and_leaves_nothing(rx, seed):
         col = Collector()
         server.add_dispatcher(col)
         addr = await server.bind()
+        relay = _Relay(addr)
         client = Messenger("osd.2")
         back = Collector()
         client.add_dispatcher(back)
-        conn = await client.connect(addr, Policy.lossless_peer())
+        conn = await client.connect(await relay.start(),
+                                    Policy.lossless_peer())
         datas = [rng.randbytes(rng.choice([LINE - 5, LINE, 3 * LINE, 1 << 20])
                                + i) for i in range(N)]
         aborts = 0
@@ -982,7 +1026,6 @@ def test_a_reconnect_storm_loses_nothing_and_leaves_nothing(rx, seed):
             nonlocal aborts
             while arrived() < N:
                 await asyncio.sleep(0)
-                _slow_wire(conn)
                 for c in list(server._sessions.values()):
                     ep = c._reader
                     if aborts >= 6 or ep is None or \
@@ -1019,10 +1062,11 @@ def test_a_reconnect_storm_loses_nothing_and_leaves_nothing(rx, seed):
         assert aborts >= 1
         await client.shutdown()
         await server.shutdown()
+        await relay.stop()
 
     run(main(), timeout=120)
     _worker_is_gone()
-    assert _fds() <= fds0 and _threads() <= threads0
+    assert _fds() <= fds0 and _threads(threads0) <= threads0
 
 
 @pytest.mark.parametrize("mode", _MODES)
@@ -1071,7 +1115,8 @@ def test_no_fd_and_no_thread_outlives_the_last_messenger():
         conn = await b.connect(await a.bind(), Policy.lossless_peer())
         conn.send_message(MOSDECSubOpWrite({"i": 0}, bytes(1 << 20)))
         await _wait_for(col, 1)
-        assert rxworker.running() and _threads() == threads0 + 1
+        assert rxworker.running() and \
+            _threads() == threads0 + rxworker.WORKERS
         await b.shutdown()
         # the acceptor's endpoint used the worker, and it still stands
         assert rxworker.running()
@@ -1080,11 +1125,12 @@ def test_no_fd_and_no_thread_outlives_the_last_messenger():
 
     run(main())
     _worker_is_gone()
-    assert _fds() <= fds0 and _threads() == threads0
+    assert _fds() <= fds0 and _threads(threads0) == threads0
 
 
 @pytest.mark.filterwarnings("ignore:This process .* is multi-threaded")
-def test_a_forked_child_starts_a_worker_of_its_own():
+@pytest.mark.parametrize("direction", ["receive", "send"])
+def test_a_forked_child_starts_a_worker_of_its_own(direction):
     if not rxworker.available():
         pytest.skip("the native library is not built here")
 
@@ -1092,8 +1138,16 @@ def test_a_forked_child_starts_a_worker_of_its_own():
         srv, cli, server = await _socket_pair()
         before = dict(msgr_perf().dump())
         data = os.urandom(2 * LINE)
-        cli.write(data)
-        assert await srv.readexactly(len(data)) == data
+        if direction == "send":
+            frame = Frame(Tag.MESSAGE, [data])
+            sent = asyncio.create_task(_send(cli, [], frame))
+            assert await srv.readexactly(12 + len(data) + 4) == \
+                frame.encode()
+            assert await sent == "worker"
+            assert _tx_delta(before)["tx_worker_bodies"] == 1
+        else:
+            cli.write(data)
+            assert await srv.readexactly(len(data)) == data
         assert _worker_delta(before)["rx_worker_bodies"] == 1
         keep += [srv, cli, server]
 
@@ -1124,3 +1178,381 @@ def test_a_forked_child_starts_a_worker_of_its_own():
     finally:
         loop.close()
     _worker_is_gone()
+
+
+# -- the send worker: a large frame leaves the socket on the native thread, and
+# -- the wire is what it was -------------------------------------------------
+
+def _tx_delta(before: dict) -> dict:
+    now = msgr_perf().dump()
+    return {k: now[k] - before[k] for k in now if k.startswith("tx_")}
+
+
+async def _send(ep: Endpoint, head: list, frame: Frame) -> str:
+    """What the write loop does with a plain-crc frame and the small
+    frames gathered in front of it; returns who sent it."""
+    parts = [f.encode() for f in head]
+    nbytes = frame.payload_len()
+    if ep.worker_sends(nbytes):
+        job = ep.send_frame(parts, frame)
+        if job is not None:
+            await ep.frame_sent(job)
+            return "worker"
+    parts.extend(frame.encode_parts() if nbytes >= SPILL_SIZE
+                 else [frame.encode()])
+    ep.writelines(parts)
+    await ep.drain()
+    return "transport"
+
+
+_TX_SHAPES = {
+    "one_segment": lambda r, n: [r.randbytes(n)],
+    "two_segments": lambda r, n: [b'{"type":112}', r.randbytes(n - 12)],
+    "four_segments": lambda r, n: [b"h" * 30, b"", r.randbytes(n - 48),
+                                   b"\x7c\xec" * 9],
+    "several_live_parts": lambda r, n: [
+        b"h" * 30, [r.randbytes(n // 3), b"",
+                    memoryview(r.randbytes(n // 3)).toreadonly(),
+                    bytearray(r.randbytes(n - 30 - 2 * (n // 3)))]],
+}
+
+
+@pytest.mark.parametrize("shape", list(_TX_SHAPES))
+@pytest.mark.parametrize("n", [LINE - 1, LINE, 4 << 20],
+                         ids=["under", "at", "4m"])
+def test_a_frame_round_the_line_is_encode_byte_for_byte(rx, codec, n, shape):
+    """The payload's length alone says who sends a frame; whoever does,
+    the peer reads `Frame.encode()`'s bytes, crcs included, with the
+    small frames framed in front of it before it and one after it."""
+    rng = random.Random(f"{n}{shape}")
+    frame = Frame(Tag.MESSAGE, _TX_SHAPES[shape](rng, n))
+    assert frame.payload_len() == n
+    ack, ping = Frame(Tag.ACK, [b"[7]"]), Frame(Tag.KEEPALIVE, [])
+    want = b"".join(bytes(f.encode()) for f in (ack, ping, frame, ack))
+
+    async def main():
+        srv, cli, server = await _socket_pair()
+        before = dict(msgr_perf().dump())
+        read = asyncio.create_task(srv.readexactly(len(want)))
+        who = await _send(cli, [ack, ping], frame)
+        assert await _send(cli, [], ack) == "transport"
+        got = await read
+        d = _tx_delta(before)
+        await _close(server, srv, cli)
+        return who, bytes(got), d
+
+    who, got, d = run(main())
+    assert got == want
+    worked = rx == "worker" and n >= LINE
+    assert who == ("worker" if worked else "transport")
+    assert d["tx_worker_bodies"] == (1 if worked else 0)
+    assert d["tx_worker_bytes"] == (n if worked else 0)
+    assert (d["tx_worker_cpu_ns"] > 0) == worked
+    assert d["tx_worker_declined"] == d["tx_worker_cancelled"] == 0
+    _worker_is_gone()
+
+
+def test_a_send_into_a_small_buffer_completes_over_several_rounds(rx):
+    """4 MiB through a socket whose send buffer holds 128 KiB: the
+    worker waits for `EPOLLOUT` between rounds while the loop reads the
+    other end, and the loop is woken once."""
+    frame = Frame(Tag.MESSAGE, [b"h", os.urandom(4 << 20)])
+
+    async def main():
+        srv, cli, server = await _socket_pair()
+        cli.get_extra_info("socket").setsockopt(
+            socket.SOL_SOCKET, socket.SO_SNDBUF, 131072)
+        woken = 0
+        if rx == "worker":
+            port = rxworker.acquire(asyncio.get_running_loop())
+            real = port._reap
+
+            def reap():
+                nonlocal woken
+                woken += 1
+                real()
+            asyncio.get_running_loop().remove_reader(port.efd)
+            asyncio.get_running_loop().add_reader(port.efd, reap)
+        sent = asyncio.create_task(_send(cli, [], frame))
+        await asyncio.sleep(0.05)       # the socket is full by now
+        assert not sent.done()
+        # the reader takes it from the transport, a chunk a turn
+        got = bytearray()
+        while len(got) < frame.payload_len() + 24:
+            got += await srv.readexactly(
+                min(SPILL_SIZE, frame.payload_len() + 24 - len(got)))
+        who = await sent
+        if rx == "worker":
+            rxworker.release(port)
+        await _close(server, srv, cli)
+        return who, bytes(got), woken
+
+    who, got, woken = run(main())
+    assert got == frame.encode()
+    assert who == ("worker" if rx == "worker" else "transport")
+    assert woken == (1 if rx == "worker" else 0)
+    _worker_is_gone()
+
+
+def test_frames_leave_in_queue_order_round_large_ones(rx, codec):
+    """Small and large messages queued in turn on one session arrive in
+    the queue's order, each byte for byte; the large ones were the
+    worker's where there is one and the small ones never."""
+    N = 30
+
+    async def main():
+        server = Messenger("osd.1")
+        col = Collector()
+        server.add_dispatcher(col)
+        addr = await server.bind()
+        client = Messenger("osd.2")
+        conn = await client.connect(addr, Policy.lossless_peer())
+        rng = random.Random(2)
+        datas = [rng.randbytes(rng.choice([LINE, 3 * LINE]) if i % 3 == 1
+                               else rng.randrange(0, 3000))
+                 for i in range(N)]
+        before = dict(msgr_perf().dump())
+        for i, d in enumerate(datas):
+            conn.send_message(MOSDECSubOpWrite({"i": i}, d) if i % 3
+                              else MPing({"i": i, "d": d.hex()}))
+            if i % 7 == 0:
+                await asyncio.sleep(0)
+        await _wait_for(col, N)
+        d = _tx_delta(before)
+        assert [m.payload["i"] for m in col.messages] == list(range(N))
+        for m, want in zip(col.messages, datas):
+            got = bytes.fromhex(m.payload["d"]) if "d" in m.payload \
+                else bytes(m.data)
+            assert got == want
+        await client.shutdown()
+        await server.shutdown()
+        return d
+
+    d = run(main())
+    large = N // 3
+    assert d["tx_worker_bodies"] == (large if rx == "worker" else 0)
+    assert d["tx_worker_declined"] == d["tx_worker_cancelled"] == 0
+    _worker_is_gone()
+
+
+@pytest.mark.parametrize("case", [
+    "over_the_line", "under_the_line", "no_native",
+    pytest.param("compressed", id="onwire_compressed"),
+    pytest.param("secure", id="onwire_secure", marks=pytest.mark.skipif(
+        not _HAVE_CRYPTO, reason="needs 'cryptography'"))])
+def test_what_reaches_the_send_worker_and_what_never_does(case, monkeypatch):
+    """A plain-crc frame from the line up, and nothing else: not a frame
+    under it, not a secure or compressed session's, and none where the
+    library is missing; those leave as at the parent commit."""
+    _with_native(monkeypatch, case != "no_native")
+    mode = {"compressed": {"compress": True},
+            "secure": {"secure": True}}.get(case, {})
+    key = b"k" * 16 if mode else None
+    n = LINE - 200 if case == "under_the_line" else 2 * LINE
+    calls = []
+    real = rxworker.submit_tx
+    monkeypatch.setattr(rxworker, "submit_tx",
+                        lambda *a: calls.append(a) or real(*a))
+
+    async def main():
+        server = Messenger("osd.1", auth_key=key, **mode)
+        col = Collector()
+        server.add_dispatcher(col)
+        addr = await server.bind()
+        client = Messenger("osd.2", auth_key=key, **mode)
+        conn = await client.connect(addr, Policy.lossless_peer())
+        data = os.urandom(n)
+        before = dict(msgr_perf().dump())
+        conn.send_message(MOSDECSubOpWrite({"i": 0}, data))
+        await _wait_for(col, 1)
+        d = _tx_delta(before)
+        assert bytes(col.messages[0].data) == data
+        await client.shutdown()
+        await server.shutdown()
+        return d
+
+    d = run(main())
+    worked = case == "over_the_line"
+    assert len(calls) == d["tx_worker_bodies"] == (1 if worked else 0)
+    assert d["tx_worker_declined"] == 0
+    if worked:
+        assert n <= d["tx_worker_bytes"] == d["tx_direct_bytes"] < n + 200
+    else:
+        assert d["tx_worker_bytes"] == d["tx_worker_cpu_ns"] == 0
+        assert (d["tx_copied_bytes"] if mode else d["tx_direct_bytes"]) >= n
+    _worker_is_gone()
+
+
+def _submit_fails(*a):
+    raise OSError(24, "Too many open files")
+
+
+@pytest.mark.parametrize("why", ["queue_not_empty", "submit_fails"])
+def test_a_frame_the_worker_cannot_have_goes_the_transports_way(
+        why, monkeypatch):
+    """Bytes still in the transport's own queue, or a submit that fails:
+    the frame is declined (`tx_worker_declined`), the transport sends it
+    behind what it holds, and the peer reads both, in order."""
+    if not rxworker.available():
+        pytest.skip("the native library is not built here")
+    frame = Frame(Tag.MESSAGE, [b"h", os.urandom(2 * LINE)])
+    first = os.urandom(3 << 20) if why == "queue_not_empty" else b"first"
+
+    async def main():
+        srv, cli, server = await _socket_pair()
+        if why == "submit_fails":
+            monkeypatch.setattr(rxworker, "submit_tx", _submit_fails)
+        before = dict(msgr_perf().dump())
+        want = first + bytes(frame.encode())
+        read = asyncio.create_task(srv.readexactly(len(want)))
+        if why == "queue_not_empty":
+            cli.get_extra_info("socket").setsockopt(
+                socket.SOL_SOCKET, socket.SO_SNDBUF, 65536)
+        cli.write(first)
+        if why == "queue_not_empty":
+            assert cli.transport.get_write_buffer_size() > 0
+        assert cli.worker_sends(frame.payload_len())
+        assert cli.send_frame([], frame) is None
+        who = await _send(cli, [], frame)
+        got = await read
+        d = _tx_delta(before)
+        await _close(server, srv, cli)
+        return who, bytes(got) == want, d
+
+    who, same, d = run(main())
+    assert who == "transport" and same
+    assert d["tx_worker_declined"] == 2 and d["tx_worker_bodies"] == 0
+    _worker_is_gone()
+
+
+def test_a_declined_frame_of_a_session_arrives_all_the_same(monkeypatch):
+    """The write loop's own fall-back: every submit fails, every message
+    arrives, and each large one is counted declined and by reference."""
+    if not rxworker.available():
+        pytest.skip("the native library is not built here")
+    monkeypatch.setattr(rxworker, "submit_tx", _submit_fails)
+
+    async def main():
+        server = Messenger("osd.1")
+        col = Collector()
+        server.add_dispatcher(col)
+        addr = await server.bind()
+        client = Messenger("osd.2")
+        conn = await client.connect(addr, Policy.lossless_peer())
+        datas = [os.urandom(LINE + i) for i in range(4)]
+        before = dict(msgr_perf().dump())
+        for i, d in enumerate(datas):
+            conn.send_message(MOSDECSubOpWrite({"i": i}, d))
+            await asyncio.sleep(0.01)
+        await _wait_for(col, 4)
+        d = _tx_delta(before)
+        assert [bytes(m.data) for m in col.messages] == datas
+        await client.shutdown()
+        await server.shutdown()
+        return d
+
+    d = run(main())
+    assert d["tx_worker_declined"] == 4 and d["tx_worker_bodies"] == 0
+    assert d["tx_direct_bytes"] >= 4 * LINE
+    _worker_is_gone()
+
+
+@pytest.mark.filterwarnings("ignore:unclosed:ResourceWarning")
+@pytest.mark.parametrize("how", ["close", "cancelled_write", "abort",
+                                 "peer_closes"])
+def test_a_frame_given_up_half_sent_leaves_nothing_behind(how):
+    """The job is taken back before the fault is raised: no job, no fd
+    and no thread are left, the thread lets go of every part (not one
+    byte leaves after the cancel), and a write loop that is cancelled
+    with a frame cut short on the wire takes the transport with it."""
+    if not rxworker.available():
+        pytest.skip("the native library is not built here")
+    fds0 = _fds()
+    frame = Frame(Tag.MESSAGE, [b"h", os.urandom(4 << 20)])
+    wire = bytes(frame.encode())
+
+    async def main():
+        srv, cli, server = await _socket_pair()
+        cli.get_extra_info("socket").setsockopt(
+            socket.SOL_SOCKET, socket.SO_SNDBUF, 65536)
+        srv.transport.pause_reading()       # the peer takes nothing
+        before = dict(msgr_perf().dump())
+        sent = asyncio.create_task(_send(cli, [], frame))
+        while cli._tx_job is None or rxworker.progress(cli._tx_job) <= 0:
+            await asyncio.sleep(0.005)
+        await asyncio.sleep(0.05)
+        assert rxworker._lib.rxw_jobs() == 1 and not sent.done()
+        if how == "close":
+            cli.close()
+        elif how == "cancelled_write":
+            sent.cancel()
+        elif how == "abort":
+            cli.transport.abort()
+        else:
+            srv.transport.abort()
+        with pytest.raises((ConnectionError, OSError,
+                            asyncio.CancelledError)):
+            await sent
+        assert rxworker._lib.rxw_jobs() == 0 and not rxworker._jobs
+        assert cli._tx_job is None
+        # cut short on the wire: nobody may send behind it
+        assert cli.transport.is_closing()
+        d = _tx_delta(before)
+        if how == "peer_closes":
+            # the thread saw the reset itself, or the loop took the job
+            # back when it saw it: either way it is whole or counted
+            assert d["tx_worker_cancelled"] + d["tx_worker_bodies"] <= 1
+        else:
+            assert d["tx_worker_cancelled"] == 1
+            assert 0 < d["tx_worker_bytes"] < 4 << 20
+            srv.transport.resume_reading()
+            got = bytearray()
+            try:
+                while chunk := await srv.readexactly(1):
+                    got += chunk
+            except (asyncio.IncompleteReadError, ConnectionError) as e:
+                got += getattr(e, "partial", b"")
+            assert len(got) < len(wire) and wire.startswith(bytes(got))
+        assert d["tx_worker_bodies"] == 0 or how == "peer_closes"
+        await _close(server, srv, cli)
+
+    run(main())
+    _worker_is_gone()
+    assert _fds() <= fds0
+
+
+@pytest.mark.filterwarnings("ignore:unclosed:ResourceWarning")
+def test_a_loop_that_dies_with_endpoints_open_leaks_no_send_dup():
+    """`connection_lost` never runs for an endpoint whose loop is gone:
+    the dup the worker sent on goes with the endpoint, as the socket
+    goes with its object."""
+    if not rxworker.available():
+        pytest.skip("the native library is not built here")
+    import gc
+    gc.collect()
+    fds0 = _fds()
+
+    async def main():
+        srv, cli, server = await _socket_pair()
+        frame = Frame(Tag.MESSAGE, [os.urandom(LINE)])
+        read = asyncio.create_task(Frame.read(srv))
+        assert await _send(cli, [], frame) == "worker"
+        assert bytes((await read).segments[0]) == bytes(frame.segments[0])
+        assert cli._tx_fd >= 0
+        server.close()      # the two endpoints stay open
+
+    run(main())
+    # the dead loop's port, which holds the loop and so its endpoints,
+    # goes when the next loop asks for one
+    run(_a_body_crosses())
+    gc.collect()
+    _worker_is_gone()
+    assert _fds() <= fds0
+
+
+async def _a_body_crosses():
+    srv, cli, server = await _socket_pair()
+    cli.write(bytes(LINE))
+    assert await srv.readexactly(LINE) == bytes(LINE)
+    await _close(server, srv, cli)
+

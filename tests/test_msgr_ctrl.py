@@ -59,9 +59,9 @@ class Tap:
         real_frame, real_write = Connection._frame_into, Endpoint.writelines
         tap = self
 
-        def _frame_into(conn, parts, frame, onwire):
+        def _frame_into(conn, parts, frame, onwire, nbytes):
             framed.setdefault(id(parts), [conn]).append(frame)
-            return real_frame(conn, parts, frame, onwire)
+            return real_frame(conn, parts, frame, onwire, nbytes)
 
         def writelines(ep, parts):
             got = framed.pop(id(parts), None)

@@ -1,9 +1,11 @@
 """The messenger's send side: frames at or over the spill size leave by
-reference (`Frame.encode_parts` into the transport's scatter `sendmsg`),
+reference (`Frame.encode_parts` into the transport's scatter `sendmsg`,
+or, from `rxworker.LINE` up, the same parts handed to the send worker),
 smaller ones and every frame of an onwire session through a packed blob;
 a partial send keeps views of the same objects; a lossless session that
-is reset in the middle of a 4 MiB frame replays the same bytes; and the
-`tx_direct_bytes` / `tx_copied_bytes` counters say which way it went."""
+is reset in the middle of a 4 MiB frame replays the same bytes, whoever
+was sending it; and the `tx_direct_bytes` / `tx_copied_bytes` /
+`tx_worker_*` counters say which way it went."""
 from __future__ import annotations
 
 import asyncio
@@ -13,17 +15,27 @@ import socket
 
 import pytest
 
-from ceph_tpu.msg import frames
+from ceph_tpu.msg import frames, rxworker
 from ceph_tpu.msg.frames import Frame, Tag
 from ceph_tpu.msg.messages import MOSDECSubOpWrite, pack_batch
 from ceph_tpu.msg.messenger import Messenger, Policy, msgr_perf
 from ceph_tpu.msg.transport import SPILL_SIZE, Endpoint
 
 from tests.test_msg import Collector
-from tests.test_msg_transport import (_slow_wire, _wait_for, codec,  # noqa: F401
-                                      run)
+from tests.test_msg_transport import (_Relay, _slow_wire, _wait_for,  # noqa: F401
+                                      _with_native, codec, run)
 
-TX = ("tx_direct_bytes", "tx_copied_bytes")
+TX = ("tx_direct_bytes", "tx_copied_bytes", "tx_worker_bytes",
+      "tx_worker_bodies", "tx_worker_cancelled", "tx_worker_declined")
+
+
+@pytest.fixture(params=["worker", "transport"])
+def tx(request, monkeypatch):
+    """Who sends a frame of `rxworker.LINE` or more: the send worker,
+    or, with the native library made unavailable, the transport, as it
+    sends every frame under the line."""
+    _with_native(monkeypatch, request.param == "worker")
+    return request.param
 
 
 def _tx() -> dict:
@@ -119,28 +131,38 @@ def test_nothing_is_queued_on_a_transport_that_is_lost():
 
 
 def _capture_writes(monkeypatch) -> list:
-    """Every list the write loops hand to `Endpoint.writelines`."""
+    """Every list the write loops hand to `Endpoint.writelines`, and the
+    parts of every frame the send worker took (`Endpoint.send_frame`)."""
     seen: list = []
-    real = Endpoint.writelines
+    real, real_frame = Endpoint.writelines, Endpoint.send_frame
 
     def writelines(self, parts):
         parts = list(parts)
         seen.append(parts)
         real(self, parts)
 
+    def send_frame(self, head, frame):
+        job = real_frame(self, head, frame)
+        if job is not None:
+            seen.append([p for seg in frame.segments for p in
+                         (seg if isinstance(seg, (list, tuple)) else [seg])])
+        return job
+
     monkeypatch.setattr(Endpoint, "writelines", writelines)
+    monkeypatch.setattr(Endpoint, "send_frame", send_frame)
     return seen
 
 
 @pytest.mark.parametrize("n", [0, 1000, SPILL_SIZE - 1, SPILL_SIZE,
                                SPILL_SIZE + 1, 4 << 20],
                          ids=lambda n: f"{n}B")
-def test_the_payload_object_is_the_one_on_the_wire(codec, monkeypatch, n):
-    """A message's data goes to the transport as the object the sender
-    handed in when its frame is at or over the line (counted in
-    `tx_direct_bytes`), and inside one packed blob under it (counted in
-    `tx_copied_bytes`); the line is on the frame's payload, header and
-    JSON segments included."""
+def test_the_payload_object_is_the_one_on_the_wire(codec, tx, monkeypatch,
+                                                   n):
+    """A message's data goes to the transport, or to the send worker, as
+    the object the sender handed in when its frame is at or over the
+    line (counted in `tx_direct_bytes`), and inside one packed blob
+    under it (counted in `tx_copied_bytes`); the line is on the frame's
+    payload, header and JSON segments included."""
     async def main():
         server = Messenger("osd.1")
         col = Collector()
@@ -169,17 +191,21 @@ def test_the_payload_object_is_the_one_on_the_wire(codec, monkeypatch, n):
         assert d["tx_direct_bytes"] == framed
         # the acks and keepalives of the pair are all that was copied
         assert d["tx_copied_bytes"] < 1000
+        worked = tx == "worker" and framed >= rxworker.LINE
+        assert d["tx_worker_bytes"] == (framed if worked else 0)
+        assert d["tx_worker_bodies"] == (1 if worked else 0)
     else:
         assert not carrying and all(len(parts) == 1 for parts in seen)
         assert d["tx_direct_bytes"] == 0
         assert d["tx_copied_bytes"] >= framed
 
 
-def test_a_batch_envelopes_scatter_parts_go_by_reference(codec,
+def test_a_batch_envelopes_scatter_parts_go_by_reference(codec, tx,
                                                          monkeypatch):
     """Sub-op writes that pile up for one peer leave as one envelope
     whose data segment is a scatter list: every inner message's data is
-    still the sender's object on the way to `sendmsg`."""
+    still the sender's object on the way to `sendmsg`, the transport's
+    or the send worker's."""
     async def main():
         server = Messenger("osd.1")
         col = Collector()
@@ -207,6 +233,9 @@ def test_a_batch_envelopes_scatter_parts_go_by_reference(codec,
                for parts in seen)
     assert d["tx_direct_bytes"] >= sum(map(len, datas))
     assert d["tx_copied_bytes"] < 1000
+    # the envelope of four (800 kB) is over the worker's line
+    assert (d["tx_worker_bodies"] >= 1) == (tx == "worker")
+    assert d["tx_worker_declined"] == 0
 
 
 def test_pack_batch_frames_by_reference():
@@ -243,10 +272,12 @@ def test_an_onwire_session_keeps_the_packed_blob(codec, mode):
 
 
 def test_a_reset_in_the_middle_of_a_4mib_frame_replays_the_same_bytes(
-        codec, monkeypatch):
+        codec, tx, monkeypatch):
     """Yank the wire while 4 MiB frames are half sent: the session
     reconnects and frames the same message objects again, by reference
-    again, and every message arrives once, in order, byte for byte."""
+    again, and every message arrives once, in order, byte for byte. A
+    frame the send worker was in the middle of is taken back from it
+    (`tx_worker_cancelled`) before the session sees the fault."""
     N = 6
 
     async def main():
@@ -254,8 +285,10 @@ def test_a_reset_in_the_middle_of_a_4mib_frame_replays_the_same_bytes(
         col = Collector()
         server.add_dispatcher(col)
         addr = await server.bind()
+        relay = _Relay(addr)
         client = Messenger("osd.2")
-        conn = await client.connect(addr, Policy.lossless_peer())
+        conn = await client.connect(await relay.start(),
+                                    Policy.lossless_peer())
         rng = random.Random(11)
         datas = [rng.randbytes((4 << 20) + i) for i in range(N)]
         seen = _capture_writes(monkeypatch)
@@ -285,6 +318,7 @@ def test_a_reset_in_the_middle_of_a_4mib_frame_replays_the_same_bytes(
         assert all(m.data == want for m, want in zip(col.messages, datas))
         await client.shutdown()
         await server.shutdown()
+        await relay.stop()
         return d, seen, datas
 
     d, seen, datas = run(main(), timeout=90)
@@ -293,6 +327,16 @@ def test_a_reset_in_the_middle_of_a_4mib_frame_replays_the_same_bytes(
     # every message went by reference, and the yanked ones twice or more
     assert all(n >= 1 for n in sends) and sum(sends) >= N + 2
     assert d["tx_direct_bytes"] >= sum(map(len, datas)) + (8 << 20)
+    if tx == "worker":
+        # the first yank found a frame in the worker's hands; the second
+        # that, or one the transport was sending (an ack stuck in its
+        # queue in front of the frame: declined)
+        assert 1 <= d["tx_worker_cancelled"] <= 2
+        assert d["tx_worker_bodies"] + d["tx_worker_declined"] >= N
+        assert d["tx_worker_bodies"] >= 1
+    else:
+        assert d["tx_worker_bodies"] == d["tx_worker_cancelled"] == 0
+    assert not rxworker._jobs and not rxworker.running()
 
 
 def test_the_codec_is_logged_once_at_messenger_start():
