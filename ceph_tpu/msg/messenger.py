@@ -25,8 +25,8 @@ The reference contract this keeps (src/msg/Messenger.h, ProtocolV2.cc):
     lossless end sends a KEEPALIVE only once it has received nothing for
     KEEPALIVE_INTERVAL, since any frame shows the peer alive; a dead peer
     is faulted KEEPALIVE_TIMEOUT after its last frame (counters:
-    acks_carried_tx, ack_frames_tx, ctrl_frames_tx, ctrl_rode_tx,
-    tx_sends, keepalives_skipped).
+    acks_carried_tx, ack_frames_tx, ctrl_frames_tx, tx_sends,
+    keepalives_skipped).
 
 Idiomatic divergences: one asyncio event loop per DAEMON (under the
 process-backed reactor runtime, utils/reactor.py, each daemon's
@@ -200,9 +200,6 @@ def msgr_perf():
         pc.add("ctrl_frames_tx",
                description="ACK, KEEPALIVE and KEEPALIVE_ACK frames the "
                            "write loops framed")
-        pc.add("ctrl_rode_tx",
-               description="those of them that left in a send which "
-                           "also carried a MESSAGE frame")
         pc.add("acks_carried_tx",
                description="acks that left in a MESSAGE frame's header")
         pc.add("ack_frames_tx",
@@ -403,15 +400,11 @@ class Connection:
                     # entered the transport, as a child of whatever op is
                     # running; its OWN id rides the wire so the receiving
                     # end nests under it
-                    sp = tracer.start_span("ms_send",
-                                           self.messenger.entity_name)
-                    if sp is not None:
-                        sp.set_tag("type", type(msg).__name__)
-                        sp.set_tag("peer",
-                                   self.peer_name or str(self.peer_addr))
-                        sp.set_tag("bytes", len(msg.data))
-                        msg.trace = sp.context()
-                        sp.finish()
+                    msg.trace = tracer.point(
+                        "ms_send", self.messenger.entity_name,
+                        type=type(msg).__name__,
+                        peer=self.peer_name or str(self.peer_addr),
+                        bytes=len(msg.data))
                 else:
                     # unsampled (tail-retention regime): a per-message
                     # span is ~1/4 of all spans on the hot path, and the
@@ -968,8 +961,6 @@ class Connection:
                 continue
             if ctrl:
                 perf.inc("ctrl_frames_tx", ctrl)
-                if msgs:
-                    perf.inc("ctrl_rode_tx", ctrl)
             perf.inc("tx_sends")
             if large is not None:
                 nbytes = large.payload_len()

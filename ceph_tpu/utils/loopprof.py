@@ -15,6 +15,18 @@ watchdog catches a callback that holds the loop 0.5 s: a `loop_pause`.
 `tracer.enable()` arms the running loop, or the first a mapped span is
 entered on; `profiler_enabled` arms without tracing. Disarmed, nothing
 is installed: no hook, no `gc.callbacks` entry, no thread.
+
+The account books itself too, as an "of which": a slice's `instr` tag
+says how many of its microseconds were the instruments' own
+(`by_kind`: `span`, a span's life outside its body; `section`, a
+section's way in and out; `hook`, `_run`'s own lines; `roll`, the
+ticker and the slice's closing) and which parts had been charged them
+(`in_part`). No part's time moves: the tag only says how much of a part
+is its observer. One span or section in `tracer.TIMED_EVERY` is timed,
+between clock reads of its own at both ends of both stretches, and
+stands for that many. `hook` is CALIBRATED, not timed: the callbacks of
+the slice times a unit cost measured once, when the first loop is armed,
+by running the hook and asyncio's own `Handle._run` on a no-op handle.
 """
 from __future__ import annotations
 
@@ -94,6 +106,7 @@ LAG_EDGES_MS = tuple(2.0 ** i for i in range(-2, 12))  # and one above
 
 _ORIG_RUN = _events.Handle._run
 _now, _current_task = time.perf_counter_ns, asyncio.current_task
+_running_loop = _events._get_running_loop
 _lock = threading.Lock()
 _states: dict = {}              # loop -> _Acct, while armed
 _tracked_loops = weakref.WeakSet()  # where a `config set` is marshalled to
@@ -103,6 +116,9 @@ _gc_t0 = 0                      # the running collection's start, and
 _gcs: collections.deque = collections.deque(maxlen=64)  # (end, ns) of late
 _code_labels: dict = {}         # id(code object or type) -> (part, it)
 _watchdog = None                # (thread, its stop event) while any loop
+_hook_unit_ns = 0.0             # what `_run` adds to a callback, calibrated
+INSTR_KINDS = ("span", "section", "hook", "roll")
+CB_EVERY = 16                   # a callback in so many is counted by part
 
 
 class _Acct:
@@ -110,17 +126,25 @@ class _Acct:
     callback is charged when the next one starts (so is the loop's own
     machinery after it): the hook does nothing once the callback ran."""
     __slots__ = (               # one object, no dict: the hook's are first
-        "acc", "cur", "mark", "t_cb", "handle", "n", "loop", "label",
+        "acc", "cur", "mark", "t_cb", "handle", "n", "task", "loop", "label",
         "owners", "thread_id", "cpu_clock", "acc50", "t50", "t1", "n50",
         "lag", "lag50", "cpus", "due", "selector", "parked",
+        # the instruments' own, since the slice opened: the open stretch's
+        # start, ns by kind and by part, spans and sections closed,
+        # callbacks by part (one in `CB_EVERY`)
+        "i0", "i_kind", "i_part", "n_spans", "n_sections", "cbs",
         # the watchdog's: `n` last seen, its catch, worst lateness, last wake
         "seen", "pause", "late", "woke")
 
     def __init__(self, loop, label: str):
         self.loop, self.label, self.owners = loop, label, set()
         self.cur, self.handle, self.n, self.n50 = "unattributed", None, 0, 0
+        self.task = None        # the task the running callback steps
         self.due, self.selector, self.parked = 0.0, None, False
         self.seen, self.pause, self.late, self.woke = -1, None, 0, 0
+        self.i0, self.n_spans, self.n_sections = 0, 0, 0
+        self.i_kind, self.i_part, self.cbs = \
+            dict.fromkeys(INSTR_KINDS, 0), {}, {}
         self.thread_id = threading.get_ident()
         self.cpu_clock = time.pthread_getcpuclockid(self.thread_id)
         self.acc = _books.setdefault(label, dict.fromkeys(KEYS, 0))
@@ -145,11 +169,13 @@ def _by_label(acc: dict) -> dict:
     return by
 
 
-def _label_of(cb, owner) -> str:
+def _label_of(cb, owner, st: _Acct | None = None) -> str:
     """Part of a callback by its code's file and name; a task (`owner` of
     its step or wake-up) keeps it as `loop_label`, where an open span or
-    section overrides it."""
+    section overrides it, and is noted on `st` as the one being stepped."""
     task = owner if isinstance(owner, asyncio.Task) else None
+    if st is not None:
+        st.task = task
     if task is not None:
         key = getattr(task.get_coro(), "cr_code", None) or type(task)
     else:
@@ -171,39 +197,151 @@ def _label_of(cb, owner) -> str:
     return label
 
 
-def _enter(what):
-    """A span CM's enter, or a section's (`what` is then its part):
-    switch to the part (None: it moves none)."""
-    if type(what) is str:
-        label = what
-    elif what.name == "ms_dispatch":
-        label = LABEL_OF_SERVICE.get(what.service.partition(".")[0],
-                                     LABEL_OF_SPAN["ms_dispatch"])
-    else:
-        label = LABEL_OF_SPAN.get(what.name)
+#: `ms_dispatch`'s part by the receiving daemon's name, as met
+_dispatch_labels: dict[str, str] = {}
+_current = tracer._current
+
+
+def _state_for(label):
+    """The running loop's state; one armed now where the tracer wants
+    every loop armed and this is the first mapped span entered on it."""
     loop = _events._get_running_loop()
-    if label is None or loop is None:
-        return None
     st = _states.get(loop)
-    if st is None:
-        if not _by_tracer:
-            return None
+    if st is None and loop is not None and _by_tracer and label is not None:
         st = install(loop, owner="tracer")      # deferred arming
-    task = _current_task(loop)
-    if task is not None:        # kept on the task for when it resumes
-        back = getattr(task, "loop_label", None) or _label_of(None, task)
-        task.loop_label = label
-    else:
-        back = st.cur
-    st.switch(label)
-    return st, task, back
+    return st
 
 
-def _exit(token) -> None:
-    st, task, back = token
-    if task is not None:
-        task.loop_label = back
-    st.switch(back)
+class _Span(tracer.Span):
+    """What `tracer.span()` makes while an account is armed: entered, the
+    span becomes the current context and the loop switches to its part
+    (a span the table does not map stays in the part it is in: the
+    interval is split, nothing moves). The loop's state knows the task
+    that is being stepped since the callback began (`_run`). `_timed`:
+    the state, where this span is the one in `tracer.TIMED_EVERY` whose
+    own code is timed; the way in and the way out are charged to the
+    part outside."""
+
+    __slots__ = ("_st", "_task", "_back", "_timed")
+
+    def __enter__(self):
+        self._token = _current.set((self.trace_id, self.span_id, self.flags))
+        name = self.name
+        if name == "ms_dispatch":
+            label = _dispatch_labels.get(self.service)
+            if label is None:
+                label = _dispatch_labels[self.service] = \
+                    LABEL_OF_SERVICE.get(self.service.partition(".")[0],
+                                         LABEL_OF_SPAN["ms_dispatch"])
+        else:
+            label = LABEL_OF_SPAN.get(name)
+        st = self._st = _states.get(_running_loop()) or \
+            _state_for(label)
+        if st is not None:
+            back = self._back = st.cur  # a task's `loop_label` where one steps
+            if label is None:
+                label = back
+            task = self._task = st.task
+            if task is not None:        # kept on the task for when it resumes
+                task.loop_label = label
+            now = _now()
+            st.acc[back] += now - st.mark
+            st.mark, st.cur = now, label
+            if self._timed is not None:
+                _close(self._timed, "span", back)
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        timed = self._timed
+        if timed is not None:
+            timed.i0 = _now()
+        _current.reset(self._token)
+        st, back = self._st, None
+        if st is not None:
+            back = self._back
+            if self._task is not None:
+                self._task.loop_label = back
+            now = _now()
+            st.acc[st.cur] += now - st.mark
+            st.mark, st.cur = now, back
+            st.n_spans += 1
+        if et is not None:
+            self.tags.setdefault("error", f"{et.__name__}: {ev}")
+        self._finish()
+        if timed is not None:
+            _close(timed, "span", back)
+        return False
+
+
+class _SectionCM:
+    """`tracer.section()`'s CM while an account is armed: the stretch is
+    charged to `part`, the rest of the callback to its own. Timed as a
+    span is; its way out is its own part's."""
+
+    __slots__ = ("_part", "_st", "_task", "_back", "_timed")
+
+    def __init__(self, part: str, timed):
+        self._part = part
+        self._timed = timed
+
+    def __enter__(self) -> None:
+        part = self._part
+        st = self._st = _states.get(_running_loop()) or \
+            _state_for(part)
+        if st is not None:
+            back = self._back = st.cur
+            task = self._task = st.task
+            if task is not None:
+                task.loop_label = part
+            now = _now()
+            st.acc[back] += now - st.mark
+            st.mark, st.cur = now, part
+            if self._timed is not None:
+                _close(self._timed, "section", back)
+
+    def __exit__(self, et, ev, tb) -> bool:
+        st = self._st
+        if st is not None:
+            timed = self._timed
+            if timed is not None:
+                timed.i0 = _now()
+            back = self._back
+            if self._task is not None:
+                self._task.loop_label = back
+            now = _now()
+            st.acc[st.cur] += now - st.mark
+            st.mark, st.cur = now, back
+            st.n_sections += 1
+            if timed is not None:
+                _close(timed, "section", self._part)
+        return False
+
+
+def _open():
+    """A stretch of a span's or a section's own code starts here, on
+    this thread's loop if it is armed (its state is returned)."""
+    st = _states.get(_running_loop())
+    if st is not None:
+        st.i0 = _now()          # a collection inside the stretch moves it
+    return st
+
+
+def _close(st: _Acct, kind: str, part: str | None) -> None:
+    """The stretch ends: booked as `kind`, for `tracer.TIMED_EVERY` of
+    its like, against the part that was charged it (None: the running
+    one)."""
+    ns = (_now() - st.i0) * tracer.TIMED_EVERY
+    if part is None:
+        part = st.cur
+    st.i_kind[kind] += ns
+    st.i_part[part] = st.i_part.get(part, 0) + ns
+
+
+def _closed() -> None:
+    """A span finished outside a CM, on this thread's loop if armed."""
+    st = _states.get(_running_loop())
+    if st is not None:
+        st.n_spans += 1
 
 
 def _run(self):
@@ -216,11 +354,68 @@ def _run(self):
             _long_callback(st, now, now - st.t_cb)
         cb = self._callback
         owner = getattr(cb, "__self__", None)
-        st.cur = getattr(owner, "loop_label", None) or _label_of(cb, owner)
+        label = getattr(owner, "loop_label", None)
+        if label is not None:
+            st.task = owner     # only a task carries one
+        else:                   # a plain callback's code, met before?
+            hit = _code_labels.get(id(getattr(
+                getattr(cb, "__func__", cb), "__code__", None)))
+            if hit is None:
+                label = _label_of(cb, owner, st)
+            else:
+                label, st.task = hit[0], None
+        st.cur = label
         st.mark = st.t_cb = now
         st.handle = self
         st.n += 1
+        if not st.n % CB_EVERY:
+            st.cbs[label] = st.cbs.get(label, 0) + 1
     return _ORIG_RUN(self)
+
+
+class _NoLoop:
+    """What a handle made for the calibration asks of its loop."""
+
+    @staticmethod
+    def get_debug() -> bool:
+        return False
+
+    is_closed = get_debug       # (the watchdog asks every loop it finds)
+
+
+def _calibrate_hook(rounds: int = 2000) -> float:
+    """ns a callback that `_run` adds to asyncio's own `Handle._run`:
+    both run on one no-op handle, the hook with a state of its own that
+    no loop has, the least of five rounds each. Called once a process,
+    as its first loop is armed and before that loop's books open."""
+    loop = _NoLoop()
+    handle = _events.Handle(_NoLoop.get_debug, (), loop)
+    st = object.__new__(_Acct)
+    st.acc, st.cur, st.cbs = dict.fromkeys(KEYS, 0), "unattributed", {}
+    st.mark = st.t_cb = _now()
+    st.handle, st.n = None, 0
+
+    def least(run) -> float:
+        best = None
+        for _ in range(5):
+            t0 = _now()
+            for _ in range(rounds):
+                run(handle)
+            took = (_now() - t0) / rounds
+            best = took if best is None else min(best, took)
+        return best
+    plain = least(_ORIG_RUN)
+    _states[loop] = st
+    try:
+        hooked = least(_run)
+    finally:
+        del _states[loop]
+    return max(hooked - plain, 0.0)
+
+
+def hook_unit_ns() -> float:
+    """The calibrated unit, 0.0 before any loop was armed."""
+    return _hook_unit_ns
 
 
 def _wrap_select(st: _Acct) -> None:
@@ -252,6 +447,7 @@ def _on_gc(phase: str, info: dict) -> None:
     if st is not None:          # out of the interrupted label, into gc
         st.acc["gc"] += now - _gc_t0
         st.mark += now - _gc_t0
+        st.i0 += now - _gc_t0   # and out of an instrument's open stretch
 
 
 def _tick(st: _Acct) -> None:
@@ -259,12 +455,16 @@ def _tick(st: _Acct) -> None:
     loop = _events._get_running_loop()
     if _states.get(loop) is not st:
         return                  # disarmed: the ticker stops itself
+    st.i0 = _now()              # the ticker's own: `roll`, booked at its end
     now = loop.time()
     st.lag[bisect.bisect_left(LAG_EDGES_MS, (now - st.due) * 1e3)] += 1
     if st.mark - st.t50 >= SLICE50_NS:      # charged up to this tick
         _roll(st, st.mark)
     st.due = max(st.due + TICK_S, now + TICK_S / 2)
     loop.call_at(st.due, _tick, st)
+    ns = _now() - st.i0         # (in the slice this tick belongs to)
+    st.i_kind["roll"] += ns
+    st.i_part[st.cur] = st.i_part.get(st.cur, 0) + ns
 
 
 def _annotation():
@@ -273,11 +473,34 @@ def _annotation():
     return getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
 
 
+def _instr(st: _Acct, callbacks: int, busy_us: float) -> dict:
+    """The slice's `instr` tag, and the books it is made from emptied. A
+    timed stretch stands for sixteen, so one that the machine held up
+    can outweigh its slice: what is over the slice's busy time stays in
+    the books for the next one, by kind and by part alike."""
+    kinds, in_part = st.i_kind, st.i_part
+    hook = callbacks * _hook_unit_ns
+    kinds["hook"] = kinds.get("hook", 0) + hook
+    seen = sum(st.cbs.values())
+    for part, n in (st.cbs if seen else {st.cur: 1}).items():
+        in_part[part] = in_part.get(part, 0) + hook * n / (seen or 1)
+    whole = sum(kinds.values())
+    now = min(1.0, busy_us * 1e3 / whole) if whole else 1.0
+    tag = {"by_kind": {k: v * now / 1e3 for k, v in kinds.items()},
+           "in_part": {k: v * now / 1e3 for k, v in in_part.items()},
+           "spans": st.n_spans, "sections": st.n_sections}
+    st.i_kind = {k: v * (1.0 - now) for k, v in kinds.items()}
+    st.i_part = {k: v * (1.0 - now) for k, v in in_part.items()} \
+        if now < 1.0 else {}
+    st.cbs, st.n_spans, st.n_sections = {}, 0, 0
+    return tag
+
+
 def _roll(st: _Acct, now: int) -> None:
     """50 ms: the slice as a `loop_slice` span (microseconds by label,
-    and under `parts` by part of the labels that have them) and, for the
-    profiler's trace, a `loop_slice50` annotation (by label); once a
-    second the gauges."""
+    under `parts` by part of the labels that have them, under `instr`
+    the instruments' own among them) and, for the profiler's trace, a
+    `loop_slice50` annotation (by label); once a second the gauges."""
     parts = {k: (v - st.acc50[k]) / 1e3 for k, v in st.acc.items()}
     us = {k + "_us": v for k, v in _by_label(parts).items()}
     mark = _annotation()
@@ -288,6 +511,8 @@ def _roll(st: _Acct, now: int) -> None:
     tracer.record_span(
         "loop_slice", st.t50 / 1e9, (now - st.t50) / 1e3,
         dict(us, parts={k: v for k, v in parts.items() if "." in k},
+             instr=_instr(st, st.n - st.n50,
+                          sum(v for k, v in parts.items() if k != IDLE)),
              callbacks=st.n - st.n50, lag_edges_ms=LAG_EDGES_MS,
              lag_hist=[a - b for a, b in zip(st.lag, st.lag50)]),
         service=st.label)
@@ -352,23 +577,34 @@ def _watch(stop: threading.Event) -> None:
             st.cpus.append((now, cpu))
 
 
+_HOOKS = (_Span, _SectionCM, _open, _close, _closed)
+
+
 def install(loop: asyncio.AbstractEventLoop | None = None,
             owner: str = "operator") -> _Acct:
     """Arm `loop` (default: the running one) for `owner`, on its thread."""
-    global _watchdog
+    global _watchdog, _hook_unit_ns
     loop = loop or asyncio.get_running_loop()
     from ceph_tpu.utils import reactor
     with _lock:
         st = _states.get(loop)
         if st is None:          # loops outside a reactor share one label
+            if not _hook_unit_ns:   # once a process, before the books open
+                _hook_unit_ns = _calibrate_hook()
             st = _Acct(loop, reactor.shard_label(loop) or "loop0")
+            task = _current_task(loop) \
+                if _events._get_running_loop() is loop else None
+            if task is not None:    # armed inside its step
+                st.cur = getattr(task, "loop_label", None) or \
+                    _label_of(None, task, st)
+                st.task = task
             _wrap_select(st)
             st.due = loop.time() + TICK_S
             loop.call_at(st.due, _tick, st)
             if not _states:
                 _events.Handle._run = _run
                 gc.callbacks.append(_on_gc)
-                tracer.set_account(_enter, _exit)
+                tracer.set_account(*_HOOKS)
                 stop = threading.Event()
                 _watchdog = (threading.Thread(
                     target=_watch, args=(stop,), daemon=True,
@@ -401,7 +637,7 @@ def uninstall(loop: asyncio.AbstractEventLoop | None = None,
         _events.Handle._run = _ORIG_RUN
         gc.callbacks.remove(_on_gc)
         if not _by_tracer:
-            tracer.set_account(None, None)
+            tracer.set_account()
         (thread, stop), _watchdog = _watchdog, None
     stop.set()
     if thread is not threading.current_thread():
@@ -418,8 +654,7 @@ def tracer_armed(on: bool) -> None:
     for lp in [] if on else list(_states):
         uninstall(lp, owner="tracer")
     if on or not _states:
-        tracer.set_account(*((_enter, _exit) if on
-                             else (None, None)))
+        tracer.set_account(*(_HOOKS if on else ()))
 
 
 def installed_loops() -> list:
@@ -462,7 +697,7 @@ def shard_busy_skew(shards: dict[str, dict] | None = None) -> float:
 
 def dump() -> dict:
     """`profile dump`: us by label and by part, busy fraction, the armed
-    loops' lag."""
+    loops' lag, and what the hook was calibrated to add to a callback."""
     st = _states.get(_events._get_running_loop())
     if st is not None:
         st.switch(st.cur)       # the running callback, so far
@@ -478,6 +713,7 @@ def dump() -> dict:
             "loop_busy_fraction": round((wall - labels[IDLE]) / wall, 4)
             if wall else 0.0,
             "callbacks": sum(st.n for st in live),
+            "hook_unit_ns": round(_hook_unit_ns, 1),
             "shards": shards, "shard_busy_skew": shard_busy_skew(shards),
             "lag_edges_ms": list(LAG_EDGES_MS),
             "lag_hist": [sum(c) for c in zip(*(st.lag for st in live))]}
@@ -492,6 +728,8 @@ def reset() -> dict:
             books.update(dict.fromkeys(books, 0))
         for st in _states.values():
             st.lag, st.lag50 = [0] * len(st.lag), [0] * len(st.lag)
+            st.i_kind, st.i_part, st.cbs = dict.fromkeys(st.i_kind, 0), {}, {}
+            st.n_spans = st.n_sections = 0
             # the open slice starts anew where its books do: one that
             # straddled a reset was as long as before and held less
             st.t50 = st.mark

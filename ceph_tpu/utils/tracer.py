@@ -11,11 +11,13 @@ Design:
   * `Span`: trace/span/parent ids, service + name, wall-clock start,
     monotonic duration, free-form tags, and optional *links* to other
     traces (a coalesced offload batch span links every rider op's
-    trace, OTel span-link style). Finished spans land in a
-    process-wide bounded `SpanCollector` (the in-memory stand-in for a
-    Jaeger agent; every daemon in this stack can dump it over its admin
-    socket as `trace dump`, and MgrClient ships it incrementally via
-    `export_since`).
+    trace, OTel span-link style); it is its own context manager.
+    Finished spans land in a process-wide bounded `SpanCollector` (the
+    in-memory stand-in for a Jaeger agent; every daemon in this stack
+    can dump it over its admin socket as `trace dump`, and MgrClient
+    ships it incrementally via `export_since`) as RECORDS, tuples of
+    atoms: a sampled span's takes no lock on its way in, and the
+    garbage collector does not walk what is held (PR 55).
   * context propagation: a contextvar carries (trace_id, span_id,
     flags); tasks inherit it at creation, `span()` nests under it, and
     `current_context()` / `span(parent=ctx)` move it across the wire
@@ -59,19 +61,45 @@ FLAG_SAMPLED = 1
 _current: contextvars.ContextVar[tuple[int, int, int] | None] = \
     contextvars.ContextVar("trace_ctx", default=None)
 
-#: loopprof's `_enter(span or part) -> token | None` / `_exit(token)`
-#: while an account is armed on any loop, else None: a span CM or a
-#: `section` then closes the loop's running interval and opens the next,
-#: and keeps the part of the innermost mapped span or section a task is
-#: inside on the task itself (`task.loop_label`), which is what the
-#: account charges the task's next step to when it resumes
-_acct_enter = _acct_exit = None
+#: while a loop account is armed (`utils/loopprof.py`): its class of span
+#: and its section CM, which `span()` and `section()` hand out in place
+#: of the plain ones. Their enter and exit close the loop's running
+#: interval and open the next, and keep the part of the innermost mapped
+#: span or section a task is inside on the task itself
+#: (`task.loop_label`), which is what the account charges the task's
+#: next step to when it resumes. And its books of the instruments
+#: themselves: `_acct_open() -> state | None`
+#: starts a stretch of a span's or a section's own code on this thread's
+#: loop, `_acct_close(state, kind, part)` ends it and books it, as that
+#: kind, against the part that was charged it; `_acct_closed()` counts a
+#: span finished outside a CM. One stretch in `TIMED_EVERY` is timed and
+#: stands for that many
+_acct_span = _acct_section = None
+_acct_open = _acct_close = _acct_closed = None
+TIMED_EVERY = 16
+_timed_k = 0                    # spans and sections met since arming
 
 
-def set_account(enter, exit) -> None:
-    """Armed by loopprof with its span hooks, disarmed with None."""
-    global _acct_enter, _acct_exit
-    _acct_enter, _acct_exit = enter, exit
+def set_account(span=None, section=None, open=None, close=None,
+                closed=None) -> None:
+    """Armed by loopprof with its CMs and hooks, disarmed with none."""
+    global _acct_span, _acct_section, _acct_open, _acct_close, _acct_closed
+    _acct_span, _acct_section = span, section
+    _acct_open, _acct_close, _acct_closed = open, close, closed
+
+
+def _timed():
+    """The account's state where this span or section is the one in
+    `TIMED_EVERY` whose own code is timed (its stretch is open from
+    here), else None; None too while no account is armed."""
+    global _timed_k
+    if _acct_open is None:
+        return None
+    _timed_k += 1
+    if _timed_k % TIMED_EVERY:
+        return None
+    return _acct_open()
+
 
 _enabled = False
 _sample_rate = 0.0
@@ -92,8 +120,11 @@ def boot_token() -> str:
     return _boot
 
 
+_getrandbits = random.getrandbits
+
+
 def _new_id() -> int:
-    return random.getrandbits(63) or 1
+    return _getrandbits(63) or 1
 
 
 _perf_counters = None
@@ -144,29 +175,57 @@ def perf():
 _WALL_ANCHOR = time.time() - time.perf_counter()
 
 
+def _flat(tags: dict | None) -> tuple | None:
+    """A span's tags as its record holds them: keys, then values."""
+    return (*tags, *tags.values()) if tags else None
+
+
+def _record_dict(rec: tuple, seq: int) -> dict:
+    """The dump form of a finished span's record (`Span._record`)."""
+    trace_id, span_id, parent_id, name, service, t0, dur, tags, links = rec
+    n = len(tags) // 2 if tags else 0
+    d = {"trace_id": format(trace_id, "016x"),
+         "span_id": format(span_id, "016x"),
+         "parent_id": format(parent_id, "016x") if parent_id else None,
+         "name": name, "service": service,
+         "start": _WALL_ANCHOR + t0, "duration_us": round(dur, 1),
+         "tags": dict(zip(tags[:n], tags[n:])) if tags else {}, "seq": seq}
+    if links:
+        d["links"] = [{"trace_id": format(t, "016x"),
+                       "span_id": format(s, "016x")}
+                      for t, s in zip(links[::2], links[1::2])]
+    return d
+
+
 class Span:
     """One timed operation stage within a trace."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "service",
-                 "_t0", "duration_us", "tags", "flags", "links",
-                 "seq", "_done", "_emitted", "_seg")
+                 "_t0", "duration_us", "_tags", "flags", "links",
+                 "_done", "_emitted", "_seg", "_token")
 
     def __init__(self, name: str, service: str, trace_id: int,
                  parent_id: int | None, flags: int = 0):
         self.trace_id = trace_id
-        self.span_id = _new_id()
+        self.span_id = _getrandbits(63) or 1
         self.parent_id = parent_id
         self.name = name
         self.service = service
         self._t0 = time.perf_counter()
         self.duration_us = 0.0
-        self.tags: dict[str, Any] = {}
+        self._tags = None               # lazy: made when one is set
         self.flags = flags
         self.links: list[dict] | None = None    # lazy: most spans never link
-        self.seq = 0
         self._done = False
         self._emitted = False
         self._seg = None                # opener thread's segment buffer
+
+    @property
+    def tags(self) -> dict[str, Any]:
+        tags = self._tags
+        if tags is None:
+            tags = self._tags = {}
+        return tags
 
     @property
     def start(self) -> float:
@@ -179,7 +238,11 @@ class Span:
         return self._t0
 
     def set_tag(self, key: str, value: Any) -> None:
-        self.tags[key] = value
+        tags = self._tags
+        if tags is None:
+            self._tags = {key: value}
+        else:
+            tags[key] = value
 
     def add_link(self, ctx: dict | None) -> None:
         """Link this span to another trace (OTel span link): the
@@ -191,89 +254,142 @@ class Span:
             self.links.append({"t": int(ctx["t"]), "s": int(ctx["s"]),
                                "f": int(ctx.get("f", 0) or 0)})
 
+    # `with tracer.span(...) as sp:` makes it the current trace context
+
+    def __enter__(self) -> "Span":
+        self._token = _current.set((self.trace_id, self.span_id, self.flags))
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        _current.reset(self._token)
+        if et is not None:
+            self.tags.setdefault("error", f"{et.__name__}: {ev}")
+        self._finish()
+        return False
+
     def finish(self) -> None:
         if self._done:
             return
+        st = _timed()                   # a span closed outside a `with`
+        self._finish()
+        if st is not None:
+            _acct_close(st, "span", None)
+        if _acct_closed is not None:
+            _acct_closed()
+
+    def _finish(self) -> None:
         self._done = True
         # raw float; rounded once at export (to_dict), not per span
         self.duration_us = (time.perf_counter() - self._t0) * 1e6
-        _route(self)
+        if not self.flags & FLAG_SAMPLED:
+            return _route(self)
+        # the sampled route, lock-free: the record into the collector and
+        # nowhere else (`op_stages` reads a sampled trace's skeleton there)
+        _collector.add_record(self._record())
+
+    def _record(self) -> tuple:
+        """What the collector keeps of a finished span: a tuple of atoms
+        (the tags and the links as flat tuples, keys then values), which
+        the garbage collector stops tracking at the first round that
+        meets it, so a full round's cost does not grow with the spans
+        held. A tag whose value is a container keeps its record tracked."""
+        tags, links = self._tags, self.links
+        return (self.trace_id, self.span_id, self.parent_id, self.name,
+                self.service, self._t0, self.duration_us, _flat(tags),
+                tuple(x for l in links for x in (l["t"], l["s"]))
+                if links else None)
 
     def context(self) -> dict:
         """Wire form of this span as a parent ({"t","s","f"})."""
         return {"t": self.trace_id, "s": self.span_id, "f": self.flags}
 
     def to_dict(self) -> dict:
-        d = {"trace_id": format(self.trace_id, "016x"),
-             "span_id": format(self.span_id, "016x"),
-             "parent_id": (format(self.parent_id, "016x")
-                           if self.parent_id else None),
-             "name": self.name, "service": self.service,
-             "start": self.start, "duration_us": round(self.duration_us, 1),
-             "tags": dict(self.tags), "seq": self.seq}
-        if self.links:
-            d["links"] = [{"trace_id": format(l["t"], "016x"),
-                           "span_id": format(l["s"], "016x")}
-                          for l in self.links]
-        return d
+        return _record_dict(self._record(), 0)
 
 
 class SpanCollector:
     """Bounded per-process store of finished spans (Jaeger-agent role).
 
-    Every admitted span gets a process-monotonic `seq`, so MgrClient
+    Every admitted span has a process-monotonic `seq`, so MgrClient
     can ship the collector incrementally (`export_since`), flight-ring
-    style, and the mgr can dedup replays by (pid, boot, seq)."""
+    style, and the mgr can dedup replays by (pid, boot, seq). It is the
+    record's place: `_base` is the seq of the oldest one held, an append
+    on the right is one atomic call and takes no lock, and only what
+    removes on the left (the bound, a reset) or reads takes it."""
 
     def __init__(self, max_spans: int = 4096):
         self._lock = threading.Lock()
-        self._spans: collections.deque[Span] = \
-            collections.deque(maxlen=max_spans)
+        self._spans: collections.deque[tuple] = collections.deque()
+        self._max = max_spans
+        self._base = 1
         self.dropped = 0
-        self._seq = 0
 
     def set_max_spans(self, n: int) -> None:
+        """The bound, and what is over it dropped from the old end."""
         with self._lock:
-            self._spans = collections.deque(self._spans, maxlen=max(n, 16))
+            self._max = max(n, 16)
+            spans = self._spans
+            while len(spans) > self._max:
+                spans.popleft()
+                self._base += 1
+                self.dropped += 1
+
+    def add_record(self, rec: tuple) -> None:
+        self._spans.append(rec)
+        if len(self._spans) > self._max:
+            self.set_max_spans(self._max)
 
     def add(self, span: Span) -> None:
+        """A span off the tail route, which may come twice (linked into
+        several promoted traces)."""
         with self._lock:
-            if span._emitted:       # linked into several promoted traces
+            if span._emitted:
                 return
             span._emitted = True
-            self._seq += 1
-            span.seq = self._seq
-            if len(self._spans) == self._spans.maxlen:
-                self.dropped += 1
-            self._spans.append(span)
+        self.add_record(span._record())
 
     def last_seq(self) -> int:
         with self._lock:
-            return self._seq
+            return self._base + len(self._spans) - 1
+
+    #: how far back `newest_of` looks: a historic op's spans are recent
+    STAGE_SCAN = 16384
+
+    def newest_of(self, trace_id: int) -> list[tuple]:
+        """The records of `trace_id` among the newest `STAGE_SCAN`."""
+        with self._lock:        # nothing leaves on the left meanwhile
+            held = self._spans
+            n = len(held)
+            return [held[i] for i in range(max(n - self.STAGE_SCAN, 0), n)
+                    if held[i][0] == trace_id]
 
     def export_since(self, cursor: int, limit: int = 512) -> dict:
         """Spans with seq > cursor (oldest first, bounded), wrapped in
         the process-identity envelope the mgr's TraceIndex dedups on."""
         with self._lock:
-            new = [s for s in self._spans if s.seq > cursor]
-        new = new[:max(limit, 1)]
+            base, held = self._base, list(self._spans)
+        at = max(cursor + 1 - base, 0)
+        new = held[at:at + max(limit, 1)]
         return {"pid": os.getpid(), "boot": boot_token(),
-                "next": (new[-1].seq if new else cursor),
-                "spans": [s.to_dict() for s in new]}
+                "next": base + at + len(new) - 1 if new else cursor,
+                "spans": [_record_dict(r, base + at + i)
+                          for i, r in enumerate(new)]}
 
     def __len__(self) -> int:
-        with self._lock:
-            return len(self._spans)
+        return len(self._spans)
 
     def spans(self) -> list[dict]:
         with self._lock:
-            return [s.to_dict() for s in self._spans]
+            base, held = self._base, list(self._spans)
+        return [_record_dict(r, base + i) for i, r in enumerate(held)]
 
     def reset(self) -> int:
-        # _seq is NOT reset: the mgr's per-(pid, boot) cursor must stay
-        # monotonic or a reset daemon would replay into the dedup hole.
+        # seqs go on where they were: the mgr's per-(pid, boot) cursor
+        # must stay monotonic or a reset daemon would replay into the
+        # dedup hole.
         with self._lock:
             n = len(self._spans)
+            self._base += n
             self._spans.clear()
             self.dropped = 0
             return n
@@ -331,23 +447,12 @@ class _Reservoir:
             entries.move_to_end(trace_id)
         return e
 
-    def _note_stages(self, e: dict, span: Span) -> None:
-        st = e["stages"]
-        if span.duration_us > st.get(span.name, -1.0):
-            st[span.name] = span.duration_us
-        qw = span.tags.get("queue_wait_us")
+    @staticmethod
+    def _note_stages(st: dict, name: str, dur: float, qw) -> None:
+        if dur > st.get(name, -1.0):
+            st[name] = dur
         if isinstance(qw, (int, float)) and qw > st.get("queue_wait", -1.0):
             st["queue_wait"] = float(qw)
-
-    def note_sampled(self, span: Span) -> None:
-        """Head-sampled span: keep the skeleton stages (historic-ops
-        triage) but mark the entry promoted — spans already flow to
-        the collector directly."""
-        st = self._stripe(span.trace_id)
-        with st["lock"]:
-            e = self._entry(st, span.trace_id)
-            e["promoted"] = True
-            self._note_stages(e, span)
 
     def merge(self, groups: dict[int, list[Span]]) -> list[Span]:
         """Bulk-admit finished unsampled spans (one thread-local batch,
@@ -377,12 +482,15 @@ class _Reservoir:
                 e = self._entry(st, trace_id)
                 slowest = spans[0]
                 for span in spans:
-                    self._note_stages(e, span)
+                    tags = span._tags or {}
+                    self._note_stages(e["stages"], span.name,
+                                      span.duration_us,
+                                      tags.get("queue_wait_us"))
                     if span.duration_us > e["max_dur"]:
                         e["max_dur"] = span.duration_us
                     if span.duration_us >= slowest.duration_us:
                         slowest = span
-                    if "error" in span.tags:
+                    if "error" in tags:
                         # a child's swallowed error still marks the
                         # whole trace for promotion
                         e["errored"] = True
@@ -550,12 +658,9 @@ def _flush_local() -> None:
 
 
 def _route(span: Span) -> None:
-    """Finished-span routing: sampled -> collector, else the thread's
-    segment buffer (merged into the reservoir on quiesce/cap)."""
-    if span.flags & FLAG_SAMPLED:
-        _reservoir.note_sampled(span)
-        _collector.add(span)
-        return
+    """Where a finished unsampled span goes: the thread's segment buffer
+    (merged into the reservoir on quiesce/cap). A sampled one is in the
+    collector already (`Span._finish`)."""
     st = span._seg
     if st is None:          # bare Span() (tests) — adopt locally
         st = _seg_state()
@@ -586,58 +691,16 @@ class _NoopSpanCM:
 _NOOP = _NoopSpanCM()
 
 
-class _SpanCM:
-    """Context manager making a live span the current trace context."""
-
-    __slots__ = ("span", "_token", "_acct")
-
-    def __init__(self, span: Span):
-        self.span = span
-
-    def __enter__(self) -> Span:
-        self._token = _current.set((self.span.trace_id, self.span.span_id,
-                                    self.span.flags))
-        enter = _acct_enter             # only while a loop account is armed
-        self._acct = enter(self.span) if enter is not None else None
-        return self.span
-
-    def __exit__(self, et, ev, tb) -> bool:
-        _current.reset(self._token)
-        if self._acct is not None and _acct_exit is not None:
-            _acct_exit(self._acct)
-        if et is not None:
-            self.span.tags.setdefault("error", f"{et.__name__}: {ev}")
-        self.span.finish()
-        return False
-
-
-class _SectionCM:
-    """A stretch of a callback that the loop account charges to `part`."""
-
-    __slots__ = ("_part", "_acct")
-
-    def __init__(self, part: str):
-        self._part = part
-
-    def __enter__(self) -> None:
-        enter = _acct_enter
-        self._acct = enter(self._part) if enter is not None else None
-
-    def __exit__(self, *exc) -> bool:
-        if self._acct is not None and _acct_exit is not None:
-            _acct_exit(self._acct)
-        return False
-
-
 def section(part: str):
     """`with tracer.section("msgr.codec"):` charges the stretch to that
     part of the loop account (`utils/loopprof.py`) and the rest of the
     callback to its own. No span: nothing reaches the collector. With no
     account armed the shared no-op is returned, nothing is allocated and
     no clock is read; so is it a no-op where no loop runs."""
-    if _acct_enter is None:
+    cm = _acct_section
+    if cm is None:
         return _NOOP
-    return _SectionCM(part)
+    return cm(part, _timed())
 
 
 def _parse_parent(parent) -> tuple[int, int, int] | None:
@@ -649,8 +712,10 @@ def _parse_parent(parent) -> tuple[int, int, int] | None:
         return (parent.trace_id, parent.span_id, parent.flags)
     if isinstance(parent, dict):
         try:
-            return (int(parent["t"]), int(parent["s"]),
-                    int(parent.get("f", 0) or 0))
+            t, s, f = parent["t"], parent["s"], parent.get("f", 0) or 0
+            if type(t) is type(s) is type(f) is int:
+                return (t, s, f)        # what the wire's decoder hands in
+            return (int(t), int(s), int(f))
         except (KeyError, TypeError, ValueError):
             return None
     try:
@@ -679,13 +744,22 @@ def start_span(name: str, service: str = "",
     """Create a span (child of `parent`, else of the current context,
     else a new root). Returns None while tracing is inactive — callers
     on hot paths must treat None as "do nothing"."""
-    if not active():
+    if not (_enabled or _sample_rate > 0.0 or _tail_slow_ms > 0.0):
         return None
-    ctx = _parse_parent(parent) or _current.get()
+    st = _timed()
+    s = _start_span(name, service, parent)
+    if st is not None:                  # a span opened outside a CM
+        _acct_close(st, "span", None)
+    return s
+
+
+def _start_span(name: str, service: str, parent, cls=Span) -> Span:
+    ctx = _current.get() if parent is None else \
+        _parse_parent(parent) or _current.get()
     if ctx is None:
-        s = Span(name, service, _new_id(), None, _root_flags())
+        s = cls(name, service, _new_id(), None, _root_flags())
     else:
-        s = Span(name, service, ctx[0], ctx[1], ctx[2])
+        s = cls(name, service, ctx[0], ctx[1], ctx[2])
     if not (s.flags & FLAG_SAMPLED):
         # lock-free open accounting on the opener's segment buffer:
         # the buffer merges when this count drains (thread quiesced)
@@ -701,12 +775,43 @@ def span(name: str, service: str = "", parent=None):
     """`with tracer.span("pg_op") as sp:` — sp is the Span, or None when
     tracing is off (the same shared no-op is returned, nothing is
     allocated)."""
-    if not active():
+    if not (_enabled or _sample_rate > 0.0 or _tail_slow_ms > 0.0):
         return _NOOP
-    s = start_span(name, service, parent)
-    if s is None:                       # deactivated raced mid-call
-        return _NOOP
-    return _SpanCM(s)
+    cls = _acct_span
+    if cls is None:
+        return _start_span(name, service, parent)
+    timed = _timed()            # its own code is timed from here
+    s = _start_span(name, service, parent, cls)
+    s._timed = timed
+    return s
+
+
+def point(name: str, service: str = "", **tags) -> dict | None:
+    """A span that covers no body, made, tagged and finished in one
+    call, and its wire context (None while tracing is inactive):
+    `ms_send`, the moment a message entered the transport, whose own id
+    rides the wire so that the receiving end nests under it. What
+    `start_span` .. `set_tag` .. `context()` .. `finish()` gave, less
+    the object: on a sampled trace the record goes straight to the
+    collector, its duration 0 where it read the time the tags took."""
+    if not (_enabled or _sample_rate > 0.0 or _tail_slow_ms > 0.0):
+        return None
+    ctx = _current.get()
+    if ctx is None or not ctx[2] & FLAG_SAMPLED:
+        s = start_span(name, service)   # a root, or the tail's route
+        if tags:
+            s._tags = tags
+        s.finish()
+        return s.context()
+    timed = _timed()
+    span_id = _new_id()
+    _collector.add_record((ctx[0], span_id, ctx[1], name, service,
+                           time.perf_counter(), 0.0, _flat(tags), None))
+    if timed is not None:
+        _acct_close(timed, "span", None)
+    if _acct_closed is not None:
+        _acct_closed()
+    return {"t": ctx[0], "s": span_id, "f": ctx[2]}
 
 
 def record_span(name: str, start: float, duration_us: float, tags: dict,
@@ -718,10 +823,8 @@ def record_span(name: str, start: float, duration_us: float, tags: dict,
     flags = _root_flags() if active() else 0
     if not flags:
         return
-    s = Span(name, service, _new_id(), None, flags)
-    s._t0, s.duration_us, s._done = start, duration_us, True
-    s.tags.update(tags)
-    _route(s)
+    _collector.add_record((_new_id(), _new_id(), None, name, service, start,
+                           duration_us, _flat(tags), None))
 
 
 class _CtxCM:
@@ -757,8 +860,7 @@ def span_sampled_only(name: str, service: str = "", parent=None):
     ctx = _parse_parent(parent) or _current.get()
     if ctx is not None and not (ctx[2] & FLAG_SAMPLED):
         return _NOOP
-    s = start_span(name, service, parent)
-    return _SpanCM(s) if s is not None else _NOOP
+    return span(name, service, parent)
 
 
 def dispatch_scope(name: str, service: str = "", parent=None):
@@ -775,8 +877,7 @@ def dispatch_scope(name: str, service: str = "", parent=None):
     if ctx is None:
         return span(name, service)
     if ctx[2] & FLAG_SAMPLED:
-        s = start_span(name, service, parent)
-        return _SpanCM(s) if s is not None else _NOOP
+        return span(name, service, parent)
     return _CtxCM(ctx)
 
 
@@ -792,10 +893,20 @@ def current_context() -> dict | None:
 
 
 def op_stages(trace_id: int) -> dict | None:
-    """Span-skeleton stage durations (name -> max us) of a trace, from
-    the reservoir — dump_historic_ops triage on unsampled daemons."""
+    """Span-skeleton stage durations (name -> max us) of a trace: from
+    the reservoir (dump_historic_ops triage on unsampled daemons) and,
+    for a sampled trace, from its spans among the collector's newest
+    `SpanCollector.STAGE_SCAN`: a finished sampled span is noted nowhere
+    else, so the one who asks pays, not every span."""
     _flush_local()
-    return _reservoir.stages(trace_id)
+    stages = _reservoir.stages(trace_id) or {}
+    for rec in _collector.newest_of(trace_id):
+        tags = rec[7]
+        _Reservoir._note_stages(
+            stages, rec[3], rec[6],
+            tags[len(tags) // 2 + tags.index("queue_wait_us")]
+            if tags and "queue_wait_us" in tags else None)
+    return stages or None
 
 
 def export_since(cursor: int, limit: int = 512) -> dict:

@@ -303,7 +303,7 @@ def test_account_closes_slices_that_add_up_to_their_length():
         assert tags["callbacks"] > 0
         assert len(tags["lag_hist"]) == len(tags["lag_edges_ms"]) + 1
         assert set(tags) == {k + "_us" for k in loopprof.LABELS + ("idle",)} \
-            | {"callbacks", "lag_hist", "lag_edges_ms", "parts"}
+            | {"callbacks", "lag_hist", "lag_edges_ms", "parts", "instr"}
         assert s["parent_id"] is None
     assert sum(sum(s["tags"]["lag_hist"]) for s in slices) > 10
 
@@ -383,6 +383,130 @@ def test_a_slices_parts_sum_to_their_labels_and_the_labels_to_its_length():
              for k in slices[0]["tags"]["parts"]}
     assert total["osd.pg"] > total["osd.ec"] > total["osd.subop"] > 10_000
     assert total["osd.other"] == total["msgr.other"] == 0
+
+
+def _instr_of(slices: list[dict]) -> dict:
+    """The slices' `instr` tags summed: `by_kind`, `in_part`, counts."""
+    out = {"by_kind": {}, "in_part": {}, "spans": 0, "sections": 0}
+    for s in slices:
+        tag = s["tags"]["instr"]
+        for key in ("by_kind", "in_part"):
+            for k, v in tag[key].items():
+                out[key][k] = out[key].get(k, 0.0) + v
+        out["spans"] += tag["spans"]
+        out["sections"] += tag["sections"]
+    return out
+
+
+@pytest.mark.parametrize("n_spans,n_sections", [(0, 0), (48, 0), (0, 80),
+                                                (160, 320), (33, 17)])
+def test_a_slice_counts_the_spans_and_sections_that_closed_on_its_loop(
+        n_spans, n_sections):
+    """N spans (mapped, unmapped, and one in three opened and finished
+    outside a CM) and M sections on the loop: the slices' `instr` counts
+    exactly them, whatever share of them was timed."""
+    async def body():
+        for i in range(max(n_spans, n_sections)):
+            if i < n_spans:
+                if i % 3 == 2:
+                    tracer.start_span("ms_send", "osd.1").finish()
+                else:
+                    with tracer.span(("osd_op", "bench_event")[i % 3]):
+                        pass
+            if i < n_sections:
+                with tracer.section("msgr.codec"):
+                    pass
+            if i % 16 == 0:
+                await asyncio.sleep(0.005)
+        await asyncio.sleep(0.12)       # the last slice closes
+
+    _labels, _wall, spans = _account(body)
+    got = _instr_of([s for s in spans if s["name"] == "loop_slice"])
+    assert (got["spans"], got["sections"]) == (n_spans, n_sections)
+    assert len([s for s in spans if s["name"] != "loop_slice"]) == n_spans
+
+
+def test_a_slices_instruments_are_booked_by_kind_and_by_part_alike():
+    """The observer's own time, as an "of which": by kind and by part it
+    is the same sum, it is within the slice's busy time, every kind is
+    named, the parts are the account's, and the labels still add up to
+    the slice's length with nothing moved out of them."""
+    async def body():
+        for _ in range(40):
+            for _ in range(8):
+                with tracer.span("osd_op"):
+                    with tracer.section("msgr.codec"):
+                        _spin(0.0002)
+                with tracer.span("ms_dispatch", "osd.3"):
+                    pass
+            await asyncio.sleep(0.004)
+        await asyncio.sleep(0.12)
+
+    _labels, _wall, spans = _account(body)
+    slices = [s for s in spans if s["name"] == "loop_slice"]
+    assert len(slices) >= 3
+    for s in slices:
+        tags = s["tags"]
+        instr = tags["instr"]
+        assert set(instr) == {"by_kind", "in_part", "spans", "sections"}
+        assert set(instr["by_kind"]) == set(loopprof.INSTR_KINDS)
+        assert set(instr["in_part"]) <= set(loopprof.KEYS) - {"idle"}
+        assert not any(k.endswith("_us") for k in instr)
+        assert all(v >= 0 for v in instr["by_kind"].values())
+        whole = sum(instr["by_kind"].values())
+        assert whole == pytest.approx(sum(instr["in_part"].values()),
+                                      rel=1e-6, abs=1e-3)
+        assert whole <= s["duration_us"] - tags["idle_us"]
+        assert sum(tags[k + "_us"] for k in loopprof.LABELS + ("idle",)) \
+            == pytest.approx(s["duration_us"], rel=0.01)
+    got = _instr_of(slices)
+    assert got["spans"] == 640 and got["sections"] == 320
+    # calibrated, not timed: a unit a callback (what a slice held over
+    # for the next, were it too full, the last one may still hold)
+    assert got["by_kind"]["hook"] == pytest.approx(
+        sum(s["tags"]["callbacks"] for s in slices)
+        * loopprof.hook_unit_ns() / 1e3, rel=0.05)
+    # 960 of them, one in sixteen timed: each kind was met and costs a
+    # span more than a section, the hook a callback about what it was
+    # calibrated at, the ticker its few lines
+    assert got["by_kind"]["span"] > got["by_kind"]["section"] > 0
+    assert 0 < got["by_kind"]["span"] / 640 < 200       # us a span
+    assert 100 < loopprof.hook_unit_ns() < 50_000
+    assert got["by_kind"]["roll"] > 0
+    # a span's and a section's way in is charged to the part outside it
+    assert got["in_part"].get("osd.pg", 0) > 0          # codec inside osd_op
+    assert "background" in got["in_part"]               # the ticker's own
+
+
+def test_a_span_leaves_no_instr_while_the_account_is_disarmed():
+    """Head sampling without the account: spans are made and kept, no
+    slice is closed, nothing of the self-account is counted, and the
+    tracer holds no hook of it."""
+    tracer.reset()
+    tracer.set_sampling(rate=1.0)
+    try:
+        async def main():
+            for _ in range(64):
+                with tracer.span("osd_op"):
+                    with tracer.section("msgr.codec"):
+                        pass
+                tracer.start_span("ms_send").finish()
+            await asyncio.sleep(0.06)
+            assert loopprof.installed_loops() == []
+        before = tracer._timed_k
+        asyncio.run(main())
+        assert tracer._timed_k == before
+        assert tracer._acct_span is tracer._acct_section is None
+        assert tracer._acct_open is tracer._acct_close is None
+        assert tracer._acct_closed is None
+        spans = tracer.collector().spans()
+        assert len(spans) == 128
+        assert not any(s["name"] == "loop_slice" or "instr" in s["tags"]
+                       for s in spans)
+    finally:
+        tracer.set_sampling(rate=0.0)
+        tracer.reset()
+    assert loopprof.hook_unit_ns() >= 0.0
 
 
 def test_section_with_no_loop_running_is_a_no_op():
@@ -493,7 +617,8 @@ def test_profile_dump_admin_socket_command(tmp_path):
     asok = AdminSocket(str(tmp_path / "t.asok"))
     out = asok.execute({"prefix": "profile dump"})["result"]
     assert set(out) >= {"enabled", "loop_busy_fraction", "labels_us",
-                        "parts_us", "wall_us", "shards", "lag_hist"}
+                        "parts_us", "wall_us", "shards", "lag_hist",
+                        "hook_unit_ns"}
     assert asok.execute({"prefix": "profile reset"})[
         "result"]["cleared_wall_us"] >= 0
 

@@ -301,6 +301,81 @@ def test_historic_ops_carry_stage_skeleton():
     assert st["queue_wait"] == 42.5
 
 
+@pytest.mark.parametrize("regime", ["enabled", "head_sampled"])
+def test_historic_ops_read_a_sampled_traces_stages_from_the_collector(
+        regime, monkeypatch):
+    """A sampled span is noted nowhere but in the collector (PR 55: the
+    reservoir's lock and entry a span are gone), so the one who asks for
+    an op's stages reads them there, among the newest spans held."""
+    if regime == "enabled":
+        tracer.enable()
+    else:
+        tracer.set_sampling(rate=1.0)
+    with tracer.span("osd_op", "osd.0") as sp:
+        sp.set_tag("queue_wait_us", 17.25)
+        sp.set_tag("oid", "x")
+        ctx = tracer.current_context()
+        for _ in range(2):
+            with tracer.span("store_commit") as inner:
+                inner.set_tag("bytes", 4096)
+                time.sleep(0.001)
+    with tracer.span("osd_op", "osd.0"):        # another trace
+        other = tracer.current_context()
+    assert ctx["f"] & tracer.FLAG_SAMPLED
+    assert tracer.sampling()["reservoir"]["traces"] == 0
+    st = tracer.op_stages(ctx["t"])
+    assert set(st) == {"osd_op", "store_commit", "queue_wait"}
+    assert st["osd_op"] >= st["store_commit"] >= 1000.0
+    assert st["queue_wait"] == 17.25
+    assert set(tracer.op_stages(other["t"])) == {"osd_op"}
+    assert tracer.op_stages(12345) is None
+
+    trkr = OpTracker()
+    trk = trkr.create("osd_op(write x)")
+    trk.trace = ctx
+    trk.finish()
+    assert trkr.dump_historic_ops()["ops"][0]["stages_us"] == st
+    # only the newest are searched: a trace older than that reads none
+    monkeypatch.setattr(tracer.SpanCollector, "STAGE_SCAN", 1)
+    assert tracer.op_stages(ctx["t"]) is None
+    assert set(tracer.op_stages(other["t"])) == {"osd_op"}
+
+
+@pytest.mark.parametrize("regime", ["enabled", "tail", "off"])
+def test_point_is_a_finished_span_and_its_wire_context(regime):
+    """`ms_send`'s form: made, tagged and finished in one call; its own
+    id rides the wire, its parent is the span it was made in."""
+    if regime == "enabled":
+        tracer.enable()
+    elif regime == "tail":
+        tracer.set_sampling(rate=0.0, tail_slow_ms=0.0001)
+    if regime == "off":
+        assert tracer.point("ms_send", "client.1", bytes=3) is None
+        return
+    with tracer.span("rados_op", "client.1") as root:
+        wire = tracer.point("ms_send", "client.1", type="MOSDOp",
+                            peer="osd.3", bytes=4096)
+        bare = tracer.point("ms_send")
+        time.sleep(0.001)
+    assert wire["t"] == bare["t"] == root.trace_id
+    assert wire["f"] == root.flags and wire["s"] != bare["s"]
+    sends = {s["span_id"]: s for s in _collected()
+             if s["name"] == "ms_send"}
+    assert set(sends) == {format(wire["s"], "016x"),
+                          format(bare["s"], "016x")}
+    sent = sends[format(wire["s"], "016x")]
+    assert sent["parent_id"] == format(root.span_id, "016x")
+    assert sent["service"] == "client.1"
+    assert sent["tags"] == {"type": "MOSDOp", "peer": "osd.3",
+                            "bytes": 4096}
+    assert sends[format(bare["s"], "016x")]["tags"] == {}
+    assert 0.0 <= sent["duration_us"] < 1000.0
+    assert root.start <= sent["start"] <= root.start + 0.5
+    # a root of its own where no span is open
+    alone = tracer.point("ms_send", "osd.1")
+    assert alone["t"] != root.trace_id
+
+
 def test_tail_promotion_drops_resolvable_flight_crumb():
     """A tail promotion records a `trace_slow` flight event whose
     trace_id resolves to the promoted trace in the collector, carrying
